@@ -48,6 +48,7 @@ import contextlib
 import hashlib
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -95,11 +96,44 @@ def enabled() -> bool:
         "0", "false", "no")
 
 
+# <checkout>/.jax_cache: a FIXED path (the directory is part of JAX's
+# cache key, so one that moves — a tempdir, a pid, a timestamp — never
+# hits), inside the checkout, listed in .gitignore.
+_DEFAULT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def jax_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives: where
+    JAX_COMPILATION_CACHE_DIR says when it is set, else the fixed
+    default inside the checkout."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_JAX_CACHE
+
+
+def enable_persistent_cache() -> str:
+    """Place JAX's persistent compilation cache. Called once per
+    process, before its first JAX use, by every process that compiles
+    (worker start-up; bench/smoke children that jit on their own). With
+    JAX_COMPILATION_CACHE_DIR set JAX reads the variable itself and no
+    code sets another directory; the variable reaches spawned workers
+    through the environment the raylet hands them. Unset, the default is
+    put in this process's environment — JAX reads it when it is first
+    imported, so a worker's start-up does not pay the import here."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = _DEFAULT_JAX_CACHE
+        jax = sys.modules.get("jax")
+        if jax is not None:  # already imported: the variable was read
+            jax.config.update("jax_compilation_cache_dir",
+                              _DEFAULT_JAX_CACHE)
+    return jax_cache_dir()
+
+
 def cache_dir() -> str:
-    d = os.environ.get("RAY_TPU_COMPILE_CACHE_DIR")
-    if not d:
-        d = os.path.join(tempfile.gettempdir(), "ray_tpu_compile_cache")
-    return d
+    """The export cache's own directory: RAY_TPU_COMPILE_CACHE_DIR, else
+    a fixed path beside JAX's default cache."""
+    return (os.environ.get("RAY_TPU_COMPILE_CACHE_DIR")
+            or os.path.join(_DEFAULT_JAX_CACHE, "export"))
 
 
 def runtime_fingerprint() -> str:
@@ -405,11 +439,15 @@ class CachedFunction:
     the wrapper adds a single `is None` check to the steady state."""
 
     def __init__(self, seam: str, parts, jitted, donate_argnums=(),
-                 record_key: str | None = None,
+                 out_shardings=None, record_key: str | None = None,
                  fingerprint_computation: bool = False):
         self.seam = seam
         self.parts = tuple(parts)
+        # call-site properties of `jitted` the serialized module does
+        # not carry (it keeps the layout, not the sharding OBJECTS a
+        # caller pinned): repeated on the jit around the module
         self.donate_argnums = tuple(donate_argnums)
+        self.out_shardings = out_shardings
         self._jitted = jitted
         self._record_key = record_key or (
             seam + ":" + ":".join(map(str, parts)))
@@ -435,7 +473,7 @@ class CachedFunction:
     def _resolve(self, args):
         if not enabled():
             self.resolved = "disabled"
-            return self._first_dispatch(args, record=True)
+            return self._first_dispatch(args)
         parts = self.parts
         if self._fp_computation:
             try:
@@ -448,7 +486,7 @@ class CachedFunction:
                 # can't prove computation identity -> never share
                 M_ERRORS.inc()
                 self.resolved = "disabled"
-                return self._first_dispatch(args, record=True)
+                return self._first_dispatch(args)
         key = make_key(self.seam, parts)
         blob = lookup(key)
         if blob is not None:
@@ -459,8 +497,7 @@ class CachedFunction:
                 from jax import export as _export
 
                 exported = _export.deserialize(bytearray(blob))
-                fn = jax.jit(exported.call,
-                             donate_argnums=self.donate_argnums)
+                fn = self._jit_exported(exported)
                 if self.donate_argnums:
                     # dispatching a donated jit consumes the input
                     # buffers — AOT-compile the deserialized module
@@ -494,21 +531,45 @@ class CachedFunction:
                     return out
         M_MISSES.inc()
         self.resolved = "miss"
+        fn = None
         try:
             from jax import export as _export
 
-            blob = _export.export(self._jitted)(*args).serialize()
-            store(key, blob, seam=self.seam, parts=parts)
+            exported = _export.export(self._jitted)(*args)
+            store(key, exported.serialize(), seam=self.seam, parts=parts)
+            # dispatch THROUGH the exported module, as a later process
+            # will on a hit: both then hand XLA the same program, so
+            # the restarted process's compile is a hit in JAX's own
+            # persistent cache too. Dispatching the original jit here
+            # made every warm restart pay one full XLA compile (the
+            # exported wrapper is a different program to XLA's cache).
+            fn = self._jit_exported(exported)
         except Exception:
             M_ERRORS.inc()
-        return self._first_dispatch(args, record=True)
+        return self._first_dispatch(args, fn)
 
-    def _first_dispatch(self, args, record: bool):
+    def _jit_exported(self, exported):
+        import jax
+
+        return jax.jit(exported.call, donate_argnums=self.donate_argnums,
+                       out_shardings=self.out_shardings)
+
+    def compiled_text(self, *args) -> str:
+        """Optimised HLO of the program this seam dispatches for `args`
+        (lowering consumes no donated buffer). What a chip run reads to
+        check that a kernel (`tpu_custom_call`) or a collective is
+        really in the step, not its XLA fallback."""
+        fn = self._fn if self._fn is not None else self._jitted
+        if hasattr(fn, "lower"):  # a jit; a hit on a donating seam
+            fn = fn.lower(*args).compile()  # already holds a Compiled
+        return fn.as_text()
+
+    def _first_dispatch(self, args, fn=None):
         from ray_tpu._private import profiling as _profiling
 
+        fn = fn if fn is not None else self._jitted
         t0 = time.time()
-        out = self._jitted(*args)
-        if record:
-            _profiling.record_compile(self._record_key, t0, time.time())
-        self._fn = self._jitted
+        out = fn(*args)
+        _profiling.record_compile(self._record_key, t0, time.time())
+        self._fn = fn
         return out

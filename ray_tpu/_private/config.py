@@ -127,7 +127,7 @@ class Config:
     lease_idle_grace_s: float = 0.25
     # Initial worker-pool size per node; workers are also started on demand.
     # -1 = auto (min(num_cpus, 8)). Prestarting matters on TPU hosts: every
-    # Python start pays the jax/plugin import cost, so cold workers are slow.
+    # Python start pays the jax import cost, so cold workers are slow.
     num_initial_workers: int = -1
     # Hard cap on worker processes per node (0 = num_cpus).
     max_workers_per_node: int = 0

@@ -414,11 +414,16 @@ class CoreWorker:
             director = rpc.ReconnectingConnection(
                 self._maybe_uds(gcs_address),
                 name="cw->gcs", on_reconnect=_gcs_reconnected,
-                retry_timeout=self.config.gcs_reconnect_timeout_s,
                 # a worker is spawned into a RUNNING cluster: a dead GCS
                 # at bootstrap means the cluster is gone — die fast
                 # (the raylet respawns workers if it's actually alive)
-                # instead of lingering 10s as an un-registered orphan
+                # instead of lingering as an un-registered orphan. The
+                # same holds for a GCS that dies MID-bootstrap: the
+                # redial budget stays short until the worker registered
+                # (a cluster torn down while a worker was starting used
+                # to leave it redialling for the full reconnect budget).
+                retry_timeout=(3.0 if self.mode == WORKER
+                               else self.config.gcs_reconnect_timeout_s),
                 dial_timeout=(3.0 if self.mode == WORKER else 10.0))
             # Sharded control plane: key-partitioned table ops (KV,
             # object directory, actor/pg reads) route shard-direct; the
@@ -477,6 +482,8 @@ class CoreWorker:
                 "task_channel": self.task_channel_address,
             })
             self.node_id = NodeID(reply["node_id"])
+            # registered: from here a GCS restart is survivable
+            director._retry_timeout = self.config.gcs_reconnect_timeout_s
             if self.mode == DRIVER:
                 job = await self.gcs.call(
                     "register_job",

@@ -765,9 +765,13 @@ class HostGroup:
     def _ensure_pallas(self):
         if self._pallas is None:
             from ray_tpu.collective.backends.pallas_backend import (
-                PallasTransport)
+                PallasTransport, pallas_supported)
 
-            # raises when rank != process_index — surfaces as a 0 vote
+            # either raise surfaces as a 0 vote: the tier is unavailable
+            # in this process (a live TPU backend), or rank !=
+            # process_index
+            if not pallas_supported():
+                raise RuntimeError("pallas tier unavailable here")
             self._pallas = PallasTransport(self.world_size, self.rank)
         return self._pallas
 

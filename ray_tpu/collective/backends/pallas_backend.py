@@ -43,9 +43,12 @@ Interpreter-mode contract: with `interpret=True` the remote-DMA
 primitive discharges to `lax.all_gather` + dynamic indexing over the
 mapped axis — real XLA collectives — so the IDENTICAL kernel runs on
 CPU (including across jax.distributed process groups over gloo) and is
-bit-exactness- and chaos-tested in tier-1; on a live TPU backend the
-same schedule compiles through Mosaic. `interpret` is chosen per
-process from `jax.default_backend()`.
+bit-exactness- and chaos-tested in tier-1. That is the only way the
+tier has ever run: for a v5e the chip's compiler REFUSES these kernels
+(direct loads from ANY-space refs; they would have to stage through
+VMEM scratch with local async copies), so on a live TPU backend
+`pallas_supported()` is False and the tier votes itself unavailable —
+it never runs interpreted on a chip.
 
 PallasTransport subclasses DeviceTransport so every host-semantics
 guarantee (integer MEAN promoting to float64 on the host, f16 MEAN
@@ -72,6 +75,7 @@ except Exception:  # noqa: BLE001 - jax missing: the vote never turns 1
     jax = None
     jnp = None
 
+from ray_tpu._private.accelerator import is_tpu  # noqa: E402
 from ray_tpu.collective.backends.xla_backend import (  # noqa: E402
     DeviceTransport, _DeviceOps, _shard_map, dequantize_blocks,
     quantize_blocks)
@@ -95,29 +99,14 @@ _COMBINE_FNS = {
 }
 
 
-def _interpret_mode() -> bool:
-    """interpret=True everywhere but a real TPU backend: the pure-JAX
-    reference path IS the tier on CPU test rigs (tier-1 runs the same
-    kernel the TPU compiles through Mosaic)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # noqa: BLE001
-        return True
-
-
 def _compiler_params(collective_id: int):
-    """Mosaic compiler params for the non-interpret path (the kernel
-    performs remote DMAs, so it must be marked side-effecting and carry
-    a collective id); None under interpret where they are unused."""
-    if _interpret_mode():
-        return None
+    """Mosaic compiler params (the kernel performs remote DMAs, so it is
+    marked side-effecting and carries a collective id); ignored under
+    interpret."""
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.TPUCompilerParams(has_side_effects=True,
-                                       collective_id=collective_id)
-    except TypeError:  # older field set: stay with defaults
-        return None
+    return pltpu.CompilerParams(has_side_effects=True,
+                                collective_id=collective_id)
 
 
 def _ring_ids(axis: str, world: int):
@@ -286,7 +275,9 @@ class _PallasOps:
         self.mesh = mesh
         self.axis = axis
         self.world = world
-        self.interpret = _interpret_mode()
+        # never interpreted on a chip: pallas_supported() keeps the tier
+        # off a live TPU backend until Mosaic accepts these kernels
+        self.interpret = not is_tpu()
         self._cache: dict = {}
         self._fallback = _DeviceOps(mesh, axis, world)
 
@@ -297,22 +288,16 @@ class _PallasOps:
         import jax.experimental.pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
-        kwargs = {}
-        params = _compiler_params(collective_id)
-        if params is not None:
-            kwargs["compiler_params"] = params
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((1, out_len), dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                in_specs=[pl.BlockSpec(
-                    memory_space=pltpu.TPUMemorySpace.ANY)],
-                out_specs=pl.BlockSpec(
-                    memory_space=pltpu.TPUMemorySpace.ANY),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
                 scratch_shapes=scratch),
-            interpret=self.interpret,
-            **kwargs)
+            compiler_params=_compiler_params(collective_id),
+            interpret=self.interpret)
 
     def _jit(self, key, wrapper, out_specs=None):
         """First-call compile-recording cache, same contract as
@@ -503,16 +488,15 @@ class PallasTransport(DeviceTransport):
 
 @functools.lru_cache(maxsize=1)
 def pallas_supported() -> bool:
-    """Whether this process can build the fused-kernel tier at all
-    (pallas importable; jax present). Cheap group-uniform fact for the
-    topology deriver and the routing vote."""
-    if jax is None:
-        return False
-    try:
-        import importlib
+    """Whether this process can run the fused-kernel tier. Cheap
+    group-uniform fact for the topology deriver and the routing vote.
 
-        importlib.import_module("jax.experimental.pallas")
-        importlib.import_module("jax.experimental.pallas.tpu")
-        return True
-    except Exception:  # noqa: BLE001
-        return False
+    False on a live TPU backend: the chip's compiler refuses these
+    kernels ("Loads are only allowed on VMEM and SMEM references. ANY
+    memory space can only be accessed using async_copy" — they load
+    directly from ANY-space refs; tests/test_chip_compile.py pins the
+    refusal), and the tier never runs interpreted on a chip. A forced
+    pin then raises the typed unavailability error and a derived pin
+    demotes, through the vote that already exists. Off the chip the
+    tier is the interpreted reference the CPU test rigs run."""
+    return jax is not None and not is_tpu()

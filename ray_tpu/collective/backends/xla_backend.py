@@ -52,10 +52,8 @@ AXIS = "ranks"
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    # the one version-portable shim, shared with the sharded kernels
-    from ray_tpu.parallel.mesh import shard_map
-
-    return shard_map(fn, mesh, in_specs, out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _bucket(n: int) -> int:

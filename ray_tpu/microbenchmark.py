@@ -91,8 +91,8 @@ def timeit_ab(name: str, arms: dict, multiplier: int = 1,
 
 
 def calibrate(results: list) -> None:
-    """Same-process calibration controls captured with EVERY run
-    (VERDICT next-round #5): a pure-python loop rate (interpreter speed
+    """Same-process calibration controls captured with EVERY run:
+    a pure-python loop rate (interpreter speed
     under the current box load) and a raw-socket echo rate (syscall +
     scheduler round-trip, zero framework). Cross-session comparisons of
     the framework metrics should be read against these — if calibration

@@ -18,6 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ray_tpu._private.accelerator import is_tpu
+from ray_tpu.ops.partition import over_leading_dim
+
 NEG_INF = -1e30
 
 
@@ -91,6 +94,18 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
         return _dense_attention(q, k, v, causal, scale)
     block_q = min(block_q, t)
     block_k = min(block_k, t)
+    # batch rows are independent kernel instances: under a sharded jit
+    # each device runs the kernel on its own [b, T, H, D] slice
+    return over_leading_dim(
+        functools.partial(_flash_call, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret),
+        (True, True, True))(q, k, v)
+
+
+def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
+                block_k: int, interpret: bool):
+    b, t, h, d = q.shape
     # fold batch and heads; layout [B*H, T, D]
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, t, d)
@@ -135,13 +150,6 @@ def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
     return _dense_attention(q, k, v, causal, scale, pad_mask=pad_mask)
 
 
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:
-        return False
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128):
@@ -149,7 +157,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=not _is_tpu())
+                           interpret=not is_tpu())
 
 
 def _fwd(q, k, v, causal, scale, block_q, block_k):

@@ -14,6 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ray_tpu._private.accelerator import is_tpu
+from ray_tpu.ops.partition import over_leading_dim
+
 
 def _layernorm_kernel(x_ref, w_ref, b_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -30,18 +33,19 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[...] = (y * w_ref[...]).astype(o_ref.dtype)
 
 
-def _is_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:
-        return False
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def layernorm(x, weight, bias, eps: float = 1e-5):
     """x: [..., D]; weight/bias: [D]. Fused pallas forward; analytic
     backward in plain JAX (XLA fuses it into adjacent matmul epilogues)."""
-    return _layernorm_fwd_impl(x, weight, bias, eps=eps)
+    return _over_rows(functools.partial(_layernorm_fwd_impl, eps=eps),
+                      x, weight, bias)
+
+
+def _over_rows(fn, x, *params):
+    """Rows are independent kernel instances: under a sharded jit each
+    device normalises its own slice of x; weight and bias are whole."""
+    return over_leading_dim(fn, (True,) + (False,) * len(params))(
+        x, *params)
 
 
 def _layernorm_fwd_impl(x, weight, bias, *, eps: float,
@@ -65,7 +69,7 @@ def _layernorm_fwd_impl(x, weight, bias, *, eps: float,
         ],
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        interpret=not _is_tpu(),
+        interpret=not is_tpu(),
     )(xf, weight, bias)
     return out.reshape(orig_shape)
 
@@ -97,7 +101,8 @@ layernorm.defvjp(_layernorm_fwd, _layernorm_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rmsnorm(x, weight, eps: float = 1e-6):
-    return _rmsnorm_fwd_impl(x, weight, eps=eps)
+    return _over_rows(functools.partial(_rmsnorm_fwd_impl, eps=eps),
+                      x, weight)
 
 
 def _rmsnorm_fwd_impl(x, weight, *, eps: float, block_rows: int = 256):
@@ -119,7 +124,7 @@ def _rmsnorm_fwd_impl(x, weight, *, eps: float, block_rows: int = 256):
         ],
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        interpret=not _is_tpu(),
+        interpret=not is_tpu(),
     )(xf, weight)
     return out.reshape(orig_shape)
 

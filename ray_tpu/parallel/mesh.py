@@ -39,29 +39,6 @@ from ray_tpu._private.topology import (  # noqa: E402  (re-export)
 )
 
 
-def axis_size(axis_name: str) -> int:
-    """Version-portable mapped-axis size (call INSIDE shard_map):
-    jax.lax.axis_size is newer API; on older jax the classic
-    `psum(1, axis)` idiom folds to the same static int."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (the ONE shim every sharded kernel and
-    the collective device tier use): jax >= 0.6 exposes `jax.shard_map`
-    with `check_vma`; older releases only have
-    jax.experimental.shard_map with `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:
     dp: int = 1

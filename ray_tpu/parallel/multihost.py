@@ -90,11 +90,7 @@ def initialize(group_name: str, world_size: int, rank: int,
         # init, or every cross-process computation fails with
         # "Multiprocess computations aren't implemented on the CPU
         # backend" — which also starves the collective DEVICE tier.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            logger.debug("jax_cpu_collectives_implementation knob absent; "
-                         "assuming this jax defaults to a working one")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=addr, num_processes=world_size,
         process_id=rank, local_device_ids=local_device_ids)

@@ -28,9 +28,7 @@ def _pipeline_local(stage_params, x_micro, *, stage_fn: Callable,
     stage axis already sliced to size 1 — squeezed here). x_micro:
     [M, mb, ...] microbatched input (replicated; only stage 0 reads it).
     Returns [M, mb, ...] outputs (replicated via masked psum)."""
-    from ray_tpu.parallel.mesh import axis_size
-
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     params = jax.tree.map(lambda p: p[0], stage_params)
     m = x_micro.shape[0]
@@ -68,14 +66,13 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
     x_micro = x.reshape((num_microbatches, b // num_microbatches)
                         + x.shape[1:])
 
-    from ray_tpu.parallel.mesh import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(axis_name), P(None, data_axis)),
         out_specs=P(None, data_axis),
+        check_vma=False,
     )
     y_micro = fn(stage_params, x_micro)
     return y_micro.reshape((b,) + y_micro.shape[2:])

@@ -28,9 +28,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = "sp",
                       causal: bool = True, scale: float | None = None):
     """Call INSIDE shard_map: q,k,v local [B, S_local, H, D], sequence
     sharded over `axis_name`. Returns the local output shard."""
-    from ray_tpu.parallel.mesh import axis_size
-
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     b, s_local, h, d = q.shape
     if h % sp:
         raise ValueError(
@@ -59,10 +57,9 @@ def ulysses_attention_sharded(q, k, v, mesh: Mesh, *,
     """Driver-level entry: q,k,v global [B, S, H, D]; batch over dp,
     sequence over sp (heads stay replicated outside, sharded inside)."""
     spec = P(batch_axis, seq_axis, None, None)
-    from ray_tpu.parallel.mesh import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=seq_axis,
                           causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)

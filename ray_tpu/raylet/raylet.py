@@ -60,8 +60,10 @@ class Raylet:
                  resources: dict[str, float], store_root: str,
                  is_head: bool, labels: dict[str, str], config: Config,
                  tpu_slice: dict | None = None,
-                 topology: dict | None = None):
+                 topology: dict | None = None,
+                 tpu_worker_platforms: str = "tpu"):
         self.node_id = node_id
+        self.tpu_worker_platforms = tpu_worker_platforms
         self.session_dir = session_dir
         self.gcs_address = gcs_address
         self.config = config
@@ -92,11 +94,12 @@ class Raylet:
         self.store = make_store(store_root, config)
         self.store_root = store_root
 
-        # worker pool — two flavors: plain CPU workers (TPU-plugin env
-        # stripped) and TPU workers (plugin env restored). A worker's
-        # flavor is fixed at spawn; leases route to the matching pool so
-        # only leases that declare TPU resources ever run in a process
-        # that can claim the chip.
+        # worker pool — two flavors: plain CPU workers (started with
+        # JAX_PLATFORMS=cpu) and TPU workers (the one process that may
+        # initialise the TPU backend). A worker's flavor is fixed at
+        # spawn; leases route to the matching pool so only leases that
+        # declare TPU resources ever run in a process that can claim
+        # the chip.
         self.workers: dict[bytes, WorkerHandle] = {}  # registered, by worker_id
         self.idle: list[WorkerHandle] = []
         self.idle_tpu: list[WorkerHandle] = []
@@ -104,7 +107,7 @@ class Raylet:
         self.starting_tpu = 0
         self._worker_waiters: list[tuple[asyncio.Future, bool]] = []
         # Spawned-but-unregistered worker processes, so a worker that dies
-        # during startup (plugin import error, chip already claimed, OOM)
+        # during startup (import error, chip already claimed, OOM)
         # is reaped and its `starting` slot released instead of wedging
         # _pop_worker forever.
         self._starting_procs: list = []  # [(Popen, flavor)]
@@ -279,23 +282,16 @@ class Raylet:
             f"-{time.time():.0f}.log")
         env = dict(os.environ)
         env.update(self.config.child_env())
-        # Only workers serving TPU-resource leases get the TPU-plugin env
-        # (process-exclusive chip claim + ~2s jax import at python start);
-        # everyone else runs with it stripped.
-        from ray_tpu._private.node import (restore_tpu_plugin_env,
-                                           strip_tpu_plugin_env)
-
-        if tpu:
-            restore_tpu_plugin_env(env)
-            # Tells worker/main.py not to pin JAX_PLATFORMS=cpu, and the
-            # worker echoes the flavor back at registration.
-            env["RAY_TPU_WORKER_TPU"] = "1"
-            env["RAY_TPU_WORKER_FLAVOR"] = "tpu"
-        else:
-            strip_tpu_plugin_env(env)
-            env.pop("RAY_TPU_TPU_ENV", None)
-            env.pop("RAY_TPU_WORKER_TPU", None)
-            env["RAY_TPU_WORKER_FLAVOR"] = "cpu"
+        # One rule for the chip (_private/accelerator.py): the
+        # TPU-flavour worker is the only process on the node that may
+        # initialise the TPU backend; every other worker is started with
+        # JAX_PLATFORMS=cpu SET (an inherited "" or "tpu" would let JAX
+        # auto-select the chip and take libtpu's lock away from the
+        # worker that was leased it). No probe here: a worker leased a
+        # chip that cannot be opened fails with libtpu's error. The
+        # worker echoes its flavour back at registration.
+        env["JAX_PLATFORMS"] = self.tpu_worker_platforms if tpu else "cpu"
+        env["RAY_TPU_WORKER_FLAVOR"] = "tpu" if tpu else "cpu"
         cmd = [
             sys.executable, "-m", "ray_tpu.worker.main",
             "--raylet-address", self.address,
@@ -2600,6 +2596,8 @@ def main():
                         help="explicit TopologyCoord JSON "
                              '({"slice_id","coords","dims"}); empty = '
                              "derive from RAY_TPU_TOPOLOGY / tpu-slice")
+    parser.add_argument("--tpu-worker-platforms", default="tpu",
+                        help="JAX_PLATFORMS of a TPU-flavour worker")
     parser.add_argument("--is-head", action="store_true")
     parser.add_argument("--ready-file", default=None)
     parser.add_argument("--log-file", default=None)
@@ -2634,6 +2632,7 @@ def main():
         config=get_config(),
         tpu_slice=json.loads(args.tpu_slice) if args.tpu_slice else None,
         topology=json.loads(args.topology) if args.topology else None,
+        tpu_worker_platforms=args.tpu_worker_platforms,
     )
     asyncio.run(raylet.run(args.port, args.ready_file))
 

@@ -101,7 +101,7 @@ def cmd_start(args) -> int:
         # Ray-Client analog: remote drivers connect here with no local
         # runtime (reference: `ray start --ray-client-server-port`).
         # Spawned like the other services (_spawn: config overrides via
-        # child_env, TPU-plugin env stripped) and health-checked via the
+        # child_env, JAX_PLATFORMS=cpu) and health-checked via the
         # ready file, which also reports the actual port for --port 0.
         import uuid as _uuid
 

@@ -141,6 +141,13 @@ class TrainingOperator:
             # (zeros_like preserves sharding), so optimizer state is laid
             # out like the params without extra plumbing.
             self.opt_state = optimizer.init(self.params)
+            if mesh is not None:
+                # ...except the scalars optax makes itself (adam's
+                # count): on the default device, so whole on the mesh
+                self.opt_state = jax.tree.map(
+                    lambda x: x if isinstance(x.sharding, NamedSharding)
+                    else jax.device_put(x, to_sharding(None)),
+                    self.opt_state)
         from ray_tpu.train import metrics as _tm
         from ray_tpu.train import sharding as _shard
 
@@ -189,6 +196,36 @@ class TrainingOperator:
             # instead of XLA replicating intermediates.
             return (params if shardings is None
                     else jax.lax.with_sharding_constraint(params, shardings))
+
+        def traced_on_mesh(fn):
+            # mesh mode: tell the Pallas kernels in the model how THIS
+            # operator shards its batches, so each runs per device on
+            # its rows (ops/partition.py) — the SPMD partitioner cannot
+            # split a Mosaic call itself
+            if self._mesh is None:
+                return fn
+            from ray_tpu.ops import partition as _partition
+
+            def on_mesh(*args):
+                with _partition.batch_sharded(self._mesh,
+                                              self._batch_sharding.spec):
+                    return fn(*args)
+
+            return on_mesh
+        # mesh mode: the step hands its state back laid out EXACTLY as
+        # it took it (the same sharding objects), so step N+1 dispatches
+        # step N's program; left to XLA, an equivalent layout spelled
+        # another way costs a second lowering and compile
+        self._fused_out = None
+        if self._mesh is not None:
+            def layout(tree):
+                return jax.tree.map(lambda x: x.sharding, tree)
+
+            self._fused_out = (
+                layout(self.params), layout(self.model_state),
+                layout(self.opt_state),
+                jax.sharding.NamedSharding(self._mesh,
+                                           jax.sharding.PartitionSpec()))
         # compile observability (profiling.py): the first dispatch of a
         # NEW batch shape class recompiles the jitted step — record it
         # (jax.compiles_total / jax.compile_s / a `jax.compile` span) so
@@ -214,7 +251,9 @@ class TrainingOperator:
                     loss_fn, has_aux=True)(params, mstate, batch)
                 return loss, new_mstate, ravel_pytree(grads)[0]
 
-            self._fused_step = jax.jit(fused, donate_argnums=(0, 1, 2))
+            self._fused_step = jax.jit(traced_on_mesh(fused),
+                                       donate_argnums=(0, 1, 2),
+                                       out_shardings=self._fused_out)
             self._fused_donate = (0, 1, 2)
             self._grad_step = jax.jit(grad_step)
         else:
@@ -230,7 +269,9 @@ class TrainingOperator:
                 loss, grads = jax.value_and_grad(loss_fn)(params, batch)
                 return loss, mstate, ravel_pytree(grads)[0]
 
-            self._fused_step = jax.jit(fused, donate_argnums=(0, 2))
+            self._fused_step = jax.jit(traced_on_mesh(fused),
+                                       donate_argnums=(0, 2),
+                                       out_shardings=self._fused_out)
             self._fused_donate = (0, 2)
             self._grad_step = jax.jit(grad_step)
 
@@ -266,14 +307,14 @@ class TrainingOperator:
         self._step_cache = {}
 
         if self._eval_fn is not None:
-            self._jit_eval = jax.jit(self._eval_fn)
+            eval_fn = self._eval_fn
         elif stateful:
-            self._jit_eval = jax.jit(
-                lambda params, mstate, batch:
-                {"val_loss": loss_fn(params, mstate, batch)[0]})
+            def eval_fn(params, mstate, batch):
+                return {"val_loss": loss_fn(params, mstate, batch)[0]}
         else:
-            self._jit_eval = jax.jit(
-                lambda params, batch: {"val_loss": loss_fn(params, batch)})
+            def eval_fn(params, batch):
+                return {"val_loss": loss_fn(params, batch)}
+        self._jit_eval = jax.jit(traced_on_mesh(eval_fn))
 
     def _allreduce_grads(self, flat_grads: jax.Array):
         from ray_tpu.collective import collective as col
@@ -337,7 +378,8 @@ class TrainingOperator:
             return multihost.shard_host_batch(batch, self._batch_sharding)
         return jax.device_put(batch, self._batch_sharding)
 
-    def _cached_step(self, name: str, shape_key: str, jitted, donate=()):
+    def _cached_step(self, name: str, shape_key: str, jitted, donate=(),
+                     out_shardings=None):
         """The per-(step, shape-class) CachedFunction — compile
         observability moves inside it: a persistent-cache HIT records no
         compile event (jax.compiles_total stays flat on a warm restart),
@@ -349,9 +391,22 @@ class TrainingOperator:
         if fn is None:
             fn = self._step_cache[key] = _cc.CachedFunction(
                 "train.step", key, jitted, donate_argnums=donate,
+                out_shardings=out_shardings,
                 record_key=f"train.step:{name}:{shape_key}",
                 fingerprint_computation=True)
         return fn
+
+    def compiled_step_text(self, batch) -> str:
+        """Optimised HLO of the fused step as dispatched for `batch`'s
+        shape class (single-worker and mesh groups: the paths with ONE
+        jit per step). Runs nothing and donates nothing."""
+        name, shape_key = "fused", _profiling.shape_class(batch)
+        if self._mesh is not None:
+            name, batch = "fused-mesh", self._place_batch(batch)
+        step = self._cached_step(name, shape_key, self._fused_step,
+                                 self._fused_donate, self._fused_out)
+        return step.compiled_text(self.params, self.model_state,
+                                  self.opt_state, batch)
 
     def _dispatch_batch(self, batch):
         """Run one step, returning the (possibly device-resident) loss."""
@@ -360,7 +415,8 @@ class TrainingOperator:
             # SPMD over the (global) mesh — no HOST allreduce.
             batch = self._place_batch(batch)
             step = self._cached_step("fused-mesh", shape_key,
-                                     self._fused_step, self._fused_donate)
+                                     self._fused_step, self._fused_donate,
+                                     self._fused_out)
             self.params, self.model_state, self.opt_state, loss = step(
                 self.params, self.model_state, self.opt_state, batch)
             return loss
@@ -524,6 +580,12 @@ class TrainingOperator:
                 lambda ref, x: jnp.asarray(x) if isinstance(
                     x, np.ndarray) else x,
                 self.opt_state, state["opt_state"])
+        if self._fused_out is not None:
+            # mesh mode: back onto the mesh, laid out as registered (the
+            # step's program was built for exactly that layout)
+            self.params, self.model_state, self.opt_state = jax.device_put(
+                (self.params, self.model_state, self.opt_state),
+                self._fused_out[:3])
         self.epoch = state["epoch"]
         self.global_step = state["global_step"]
 

@@ -6,7 +6,6 @@ from __future__ import annotations
 
 import argparse
 import logging
-import os
 
 
 def main():
@@ -28,12 +27,12 @@ def main():
     failpoints.set_role("worker")
     set_config(Config.load())
 
-    # Workers default to CPU JAX so they never fight the driver for the TPU;
-    # tasks that declare TPU resources run in a worker the raylet started
-    # with TPU visibility (round-1: inherit node env when RAY_TPU_WORKER_TPU
-    # is set).
-    if not os.environ.get("RAY_TPU_WORKER_TPU"):
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # JAX_PLATFORMS arrives set by the raylet (cpu for a CPU-flavour
+    # worker, tpu for the chip-owning one); JAX's persistent compile
+    # cache is placed here, before this process's first JAX use.
+    from ray_tpu._private import compile_cache
+
+    compile_cache.enable_persistent_cache()
 
     cw = CoreWorker(
         mode=WORKER,

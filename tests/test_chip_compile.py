@@ -1,0 +1,153 @@
+"""The main-path kernels compiled at real widths for a described (not
+attached) v5e:2x2 — what the chip's compiler accepts or refuses, asked
+without a chip (on-chip-measurement guide, section 2.3). Nothing runs.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and every xdist worker
+imports this file. Keep these tests in this one file."""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # such a compile can be written to the persistent cache but not read
+    # back without a chip: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """jax.default_backend() is the CPU here, so the ops would choose
+    interpret mode: steer them to Mosaic, in the test."""
+    from ray_tpu.collective.backends import pallas_backend
+    from ray_tpu.ops import attention, batchnorm, layernorm
+
+    for mod in (attention, batchnorm, layernorm, pallas_backend):
+        monkeypatch.setattr(mod, "is_tpu", lambda: True)
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def test_flash_attention_fwd(one_chip, on_tpu):
+    from ray_tpu.ops import attention
+
+    qkv = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v: attention.flash_attention(q, k, v, True),
+        qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("norm", ["layernorm", "rmsnorm"])
+def test_norm_fwd(one_chip, on_tpu, norm):
+    from ray_tpu.ops import layernorm
+
+    x = jax.ShapeDtypeStruct((8, 1024, 768), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((768,), jnp.bfloat16, sharding=one_chip)
+    if norm == "layernorm":
+        text = _compiled_text(layernorm.layernorm, x, w, w)
+    else:
+        text = _compiled_text(layernorm.rmsnorm, x, w)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m,c", [(256 * 112 * 112, 64), (256 * 56 * 56, 256),
+                                 (256 * 14 * 14, 1024), (256 * 7 * 7, 2048)])
+def test_batchnorm_bwd_sums_resnet50_shapes(one_chip, on_tpu, m, c):
+    from ray_tpu.ops import batchnorm
+
+    act = jax.ShapeDtypeStruct((m, c), jnp.bfloat16, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((1, c), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, dy, mean, inv: batchnorm._bn_bwd_sums(
+            x, dy, mean, inv, interpret=False), act, act, stat, stat)
+    assert "tpu_custom_call" in text  # not the XLA-reduction fallback
+
+
+@pytest.mark.parametrize("shape, batch_spec", [
+    ((4, 1), P("data")),             # Trainer(mesh_mode="fsdp") on 4 chips
+    ((2, 2), P("data")),             # rows repeated over 'fsdp'
+    ((1, 4), P(("data", "fsdp"))),   # the smoke's four-chip phase
+])
+def test_kernels_under_a_sharded_jit(topo, on_tpu, shape, batch_spec):
+    """A Mosaic kernel cannot be partitioned by XLA; where the layer
+    that shards the batch declares how (the training operator, from its
+    batch_spec), each device runs it on its rows (ops/partition.py).
+    Without that the compiler refuses the step."""
+    from ray_tpu.ops import attention, layernorm, partition
+
+    mesh = Mesh(np.array(topo.devices).reshape(shape), ("data", "fsdp"))
+    rows = NamedSharding(mesh, batch_spec)
+    qkv = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=rows)
+    w = jax.ShapeDtypeStruct((64,), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+
+    def block(q, k, v, w):
+        return layernorm.layernorm(
+            attention.flash_attention(q, k, v, True), w, w)
+
+    def declared(*args):
+        with partition.batch_sharded(mesh, batch_spec):
+            return block(*args)
+
+    assert _compiled_text(declared, qkv, qkv, qkv, w).count(
+        "tpu_custom_call") == 2
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        _compiled_text(block, qkv, qkv, qkv, w)
+
+
+def test_pallas_ring_tier_is_refused_by_the_chips_compiler(topo, on_tpu,
+                                                           monkeypatch):
+    """Transport.PALLAS loads directly from ANY-space refs; Mosaic
+    refuses that for a v5e, which is why pallas_supported() is False on a
+    live TPU backend (the tier votes itself unavailable and never runs
+    interpreted on a chip). When the kernels are rewritten to stage
+    through VMEM, this test turns into a compile check."""
+    from ray_tpu.collective.backends import pallas_backend
+    from ray_tpu.collective.types import ReduceOp
+
+    mesh = Mesh(np.array(topo.devices), ("ranks",))
+    ops = pallas_backend._PallasOps(mesh, "ranks", 4)
+    assert ops.interpret is False
+    assert pallas_backend.pallas_supported.__wrapped__() is False
+    spec = P("ranks", None)
+
+    def compile_only(key, wrapper, out_specs=None):
+        return lambda x: jax.jit(pallas_backend._shard_map(
+            wrapper, mesh, spec,
+            out_specs if out_specs is not None else spec)).lower(x).compile()
+
+    monkeypatch.setattr(ops, "_jit", compile_only)
+    x = jax.ShapeDtypeStruct((4, 4096), jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    with pytest.raises(Exception, match="Loads are only allowed on VMEM"):
+        ops.allreduce(x, ReduceOp.SUM)

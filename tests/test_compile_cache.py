@@ -238,7 +238,11 @@ def test_donated_hit_path_validates_before_consuming(cache_sandbox):
 @ray_tpu.remote
 class CacheWorker:
     def setup(self, world, rank, group_name, multihost_name,
-              failpoint=None):
+              cache_dir, failpoint=None):
+        # this process's cache dir, set BEFORE any cache access: the
+        # driver's environment does not reach a worker of the running
+        # shared cluster, and the session's dir has seen other tests
+        os.environ["RAY_TPU_COMPILE_CACHE_DIR"] = cache_dir
         if failpoint:  # armed BEFORE any cache access in this process
             from ray_tpu._private import failpoints
 
@@ -281,10 +285,21 @@ class CacheWorker:
         return True
 
 
-def _gang(tag, failpoint=None):
+def _rank_cache_dirs(tmp_path):
+    """One fresh cache dir per rank, as on a gang with one rank per
+    host: a cold rank can only ever find what IT stored (ranks sharing
+    a dir race — a slow rank may find a fast one's blob), and a
+    restarted rank finds its predecessor's blobs."""
+    dirs = [str(tmp_path / f"rank{i}") for i in range(WORLD)]
+    for d in dirs:
+        os.makedirs(d)
+    return dirs
+
+
+def _gang(tag, cache_dirs, failpoint=None):
     workers = [CacheWorker.remote() for _ in range(WORLD)]
     ray_tpu.get([w.setup.remote(WORLD, i, f"g_cc_{tag}", f"cc{tag}",
-                                failpoint)
+                                cache_dirs[i], failpoint)
                  for i, w in enumerate(workers)],
                 timeout=scale_timeout(240))
     return workers
@@ -297,16 +312,15 @@ def _teardown(workers):
 
 
 def test_gang_restart_hits_cache_and_skips_compiles(ray_start_shared,
-                                                    monkeypatch):
+                                                    tmp_path):
     """THE acceptance gate: a cold gang populates the cache (misses +
     compiles recorded); the gang is killed; a restarted gang running
     the SAME shape-classes records >=1 cache hit per rank, ZERO new
     `jax.compiles_total` for the cached seam, and strictly fewer
     compiles than the cold start."""
-    monkeypatch.setenv("RAY_TPU_COMPILE_CACHE_DIR",
-                       tempfile.mkdtemp(prefix="ray_tpu_cc_gang_"))
+    dirs = _rank_cache_dirs(tmp_path)
     n = 1 << 16  # 256KB: above pallas_max_bytes, squarely device-tier
-    cold = _gang("cold")
+    cold = _gang("cold", dirs)
     stats_a = ray_tpu.get([w.warm_and_stats.remote(n) for w in cold],
                           timeout=scale_timeout(240))
     for s in stats_a:
@@ -316,7 +330,7 @@ def test_gang_restart_hits_cache_and_skips_compiles(ray_start_shared,
         assert s["hits"] == 0, stats_a
     _teardown(cold)  # kill the gang: executables outlive the processes
 
-    warm = _gang("warm")
+    warm = _gang("warm", dirs)
     stats_b = ray_tpu.get([w.warm_and_stats.remote(n) for w in warm],
                           timeout=scale_timeout(240))
     for a, b in zip(stats_a, stats_b):
@@ -331,20 +345,19 @@ def test_gang_restart_hits_cache_and_skips_compiles(ray_start_shared,
 
 
 def test_cache_load_failpoint_degrades_to_retrace(ray_start_shared,
-                                                  monkeypatch):
+                                                  tmp_path):
     """Chaos satellite: `compile_cache.load` raising during a gang
     restart must NOT fail the op — every rank re-traces (compiles
     recorded), serves the collective, and counts the typed
     `jax.compile_cache_errors_total`."""
-    monkeypatch.setenv("RAY_TPU_COMPILE_CACHE_DIR",
-                       tempfile.mkdtemp(prefix="ray_tpu_cc_fp_"))
+    dirs = _rank_cache_dirs(tmp_path)
     n = 1 << 16
-    cold = _gang("fpcold")
+    cold = _gang("fpcold", dirs)
     ray_tpu.get([w.warm_and_stats.remote(n) for w in cold],
                 timeout=scale_timeout(240))
     _teardown(cold)
 
-    broken = _gang("fpwarm", failpoint="compile_cache.load")
+    broken = _gang("fpwarm", dirs, failpoint="compile_cache.load")
     stats_c = ray_tpu.get([w.warm_and_stats.remote(n) for w in broken],
                           timeout=scale_timeout(240))
     for s in stats_c:

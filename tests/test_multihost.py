@@ -88,7 +88,7 @@ def test_two_actor_processes_one_global_mesh(ray_start_regular):
 
 def test_four_process_rendezvous(ray_start_regular):
     """4 worker processes rendezvous into one global runtime and jointly
-    train (VERDICT round-4 weak #7: >2-process rendezvous untested)."""
+    train (>2-process rendezvous was untested before)."""
     trainer = Trainer(MultiHostOp, num_workers=4,
                       config={"multihost": True, "expected_procs": 4},
                       resources_per_worker={"CPU": 1})

@@ -507,6 +507,49 @@ def test_fsdp_mesh_mode_smoke(ray_start_shared):
     assert last < first * 0.5
 
 
+def test_mesh_step_compiles_once_and_restores_onto_the_mesh():
+    """Mesh mode hands its state back laid out exactly as it took it
+    (adam's count, which optax makes on the default device, included),
+    so only the first step compiles — and a restored state goes back
+    onto the mesh instead of whole onto the first device."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    class MeshAdam(WideAdamOperator):
+        def register(self, **kw):
+            mesh = Mesh(np.array(jax.devices()).reshape(2, 4),
+                        ("data", "fsdp"))
+            super().register(mesh=mesh, param_spec={"w": P("fsdp", None)},
+                             batch_spec=P("data"), **kw)
+
+    built = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **kw: built.append(event)
+        if event.endswith("backend_compile_duration") else None)
+    op = MeshAdam({}, 0, 1)
+    batch = next(iter(op._train_loader))
+
+    def layout():
+        return jax.tree.map(lambda x: x.sharding,
+                            (op.params, op.model_state, op.opt_state))
+
+    registered = layout()
+    assert {type(s).__name__ for s in jax.tree.leaves(registered)} == {
+        "NamedSharding"}
+    op.train_batch(batch)
+    assert built  # the first step compiles...
+    saved = op.state_dict()
+    del built[:]
+    losses = [op.train_batch(batch)["train_loss"] for _ in range(2)]
+    assert not built, built  # ...and only the first
+    assert layout() == registered
+    op.load_state_dict(saved)
+    assert layout() == registered
+    del built[:]
+    assert op.train_batch(batch)["train_loss"] == losses[0]
+    assert not built, built
+
+
 # ---------------------------------------------------------------------------
 # CI gate: recorded paired-arm bench (reads MICROBENCH.json; no
 # benchmarking in CI — same pattern as the serve_mixed gate)
