@@ -691,6 +691,14 @@ class CoreWorker:
         — when every copy is gone — reconstructing it from lineage
         (reference: object_recovery_manager.h:87-103: pin existing copy →
         else re-submit the creating task)."""
+        # `object.get` (inside a trace): map + deserialise, and the pull
+        # if the object is remote; the wait for the task's reply is over
+        counts: dict = {}
+        with tracing.span("object.get",
+                          tracing.child_of_current(per_op=True), counts):
+            return self._read_plasma_body(object_id, timeout, owner, counts)
+
+    def _read_plasma_body(self, object_id, timeout, owner, counts):
         deadline = (time.monotonic() + timeout) if timeout is not None else None
         while True:
             buf = self.store.get(object_id)
@@ -731,6 +739,7 @@ class CoreWorker:
                 self.memstore.wait([object_id], 1,
                                    remaining if remaining is not None else 30.0)
         try:
+            counts["bytes"] = memoryview(buf.view).nbytes
             value = serialization.deserialize(buf.view)
         finally:
             # Note: zero-copy numpy views keep the mmap alive via memoryview.
@@ -1558,6 +1567,10 @@ class CoreWorker:
         task_id = spec["task_id"]
         rec = self.submitted.pop(task_id, None)
         M_TASKS_COMPLETED.inc()
+        if isinstance(reply, dict) and "spans" in reply:
+            # before the returns land: a getter woken by them finds the
+            # worker's side of the call already on its tree
+            tracing.adopt(reply["spans"])
         if rec is not None:
             now = time.time()
             t0 = rec.get("t0")
@@ -2868,6 +2881,8 @@ class CoreWorker:
             self._cancelled_tasks.discard(spec["task_id"])
         reply["exec_s"] = scope["exec_s"]
         reply["held_s"] = scope["held_s"]
+        if scope["spans"]:
+            reply["spans"] = scope["spans"]
         return reply
 
     @contextlib.contextmanager
@@ -2896,19 +2911,22 @@ class CoreWorker:
             "trace_id": (sender.trace_id.hex()
                          if sender is not None else ""),
         }
-        try:
-            yield scope
-        finally:
-            self._executing.pop(exec_token, None)
-            end = time.time()
-            tracing.pop(token)
-            tracing.record_span("task", start, end, exec_ctx,
-                                {"name": spec.get("name", "?")})
-            M_EXEC_S.observe(end - start,
-                             exemplar=tracing.exemplar_of(exec_ctx))
-            scope["exec_s"] = end - start
-            scope["held_s"] = end - (arrived if arrived is not None
-                                     else start)
+        # a traced task hands the spans recorded under it (this `task`
+        # span and _pack_returns' included) back in its reply
+        with tracing.collect_reply(exec_ctx) as scope["spans"]:
+            try:
+                yield scope
+            finally:
+                self._executing.pop(exec_token, None)
+                end = time.time()
+                tracing.pop(token)
+                tracing.record_span("task", start, end, exec_ctx,
+                                    {"name": spec.get("name", "?")})
+                M_EXEC_S.observe(end - start,
+                                 exemplar=tracing.exemplar_of(exec_ctx))
+                scope["exec_s"] = end - start
+                scope["held_s"] = end - (arrived if arrived is not None
+                                         else start)
 
     def _execute_task(self, spec) -> dict:
         with self._exec_scope(spec) as scope:
@@ -2918,6 +2936,8 @@ class CoreWorker:
             # round trip without comparing cross-process clocks
             reply["exec_s"] = scope["exec_s"]
             reply["held_s"] = scope["held_s"]
+            if scope["spans"]:
+                reply["spans"] = scope["spans"]
         # a cancel that raced this execution leaves a marker nothing else
         # will ever consume — drop it so the set stays bounded
         self._cancelled_tasks.discard(spec["task_id"])
@@ -3050,6 +3070,7 @@ class CoreWorker:
         returns = []
         for i, value in enumerate(values):
             return_id = ObjectID.for_return(TaskID(spec["task_id"]), i)
+            t_ser = time.time()
             header, buffers = serialization.serialize(value)
             size = serialization.total_size(header, buffers)
             if size <= self.config.max_direct_call_object_size:
@@ -3057,9 +3078,14 @@ class CoreWorker:
                 returns.append({"kind": "inline", "data": payload,
                                 "err": False})
             else:
-                self.store.put_serialized(return_id, header, buffers)
-                self._io.run(self.raylet.call("notify_object_sealed", {
-                    "object_id": return_id.binary(), "size": size}))
+                # `object.return_put` (traced tasks): serialise (no
+                # copy: buffers are views) + copy into the arena + seal
+                with tracing.span("object.return_put",
+                                  tracing.child_of_current(),
+                                  {"bytes": size}, start=t_ser):
+                    self.store.put_serialized(return_id, header, buffers)
+                    self._io.run(self.raylet.call("notify_object_sealed", {
+                        "object_id": return_id.binary(), "size": size}))
                 returns.append({"kind": "plasma", "size": size})
         return {"returns": returns}
 

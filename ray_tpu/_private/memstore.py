@@ -31,7 +31,11 @@ class _Entry:
 
 class MemoryStore:
     def __init__(self):
-        self._lock = threading.Lock()
+        # Reentrant: an ObjectRef collected by the cyclic GC runs its
+        # __del__ wherever an allocation happens — also inside these
+        # locked regions (an `_Entry()` under the lock is enough), and
+        # __del__ ends in `delete` on the SAME thread.
+        self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         self._entries: dict[ObjectID, _Entry] = {}
 

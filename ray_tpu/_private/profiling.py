@@ -57,7 +57,8 @@ def record_compile(key: str, start: float, end: float) -> None:
     M_COMPILE_S.observe(seconds)
     with _compile_lock:
         _compile_recent.append((end, seconds, key))
-    tracing.record_span("jax.compile", start, end, tracing.current(),
+    tracing.record_span("jax.compile", start, end,
+                        tracing.child_of_current(),
                         {"name": f"jax.compile {key}", "key": key,
                          "compile_s": round(seconds, 4)})
 

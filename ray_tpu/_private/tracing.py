@@ -16,6 +16,18 @@ live cluster-wide via `ray_tpu.set_trace_sampling(rate)` — the rate
 rides the internal KV (KV_KEY) + pubsub (CHANNEL), exactly like the
 failpoints arming plane. Propagated contexts are always honored: the
 sampling decision is made once, at the trace root.
+
+Call-level entry points (`Trainer.train()`: a handful of spans every few
+seconds) mint a context whatever the rate (`always_trace`); what head
+sampling decides for them is only the FINE level (`ctx.fine`: per-leaf
+spans of a snapshot). A worker returns the spans it recorded under a
+traced task in the reply (`collect_reply` / `adopt`), so the owner that
+opened a tree for the trace (`open_tree`) holds the whole call when its
+`get` returns, without waiting on the GCS flush. Every span is stamped
+with `time.time()`: a tree is comparable within one host only. While a
+jax profiler session runs in the process (`set_annotating`), `span()`
+also enters a `jax.profiler.TraceAnnotation` of the same name, so the
+host spans sit in the `.xplane.pb` beside the device's operations.
 """
 
 from __future__ import annotations
@@ -59,13 +71,24 @@ class TraceContext:
     unsampled entry point yields None everywhere, so the unsampled hot
     path carries no per-call state at all."""
 
-    __slots__ = ("trace_id", "span_id", "parent_id")
+    __slots__ = ("trace_id", "span_id", "parent_id", "fine", "relay")
 
     def __init__(self, trace_id: bytes, span_id: bytes,
-                 parent_id: bytes | None = None):
+                 parent_id: bytes | None = None, fine: bool = True,
+                 relay: bool = False):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
+        # head-sampled (or explicitly traced) tree: record the fine
+        # level too. False only under an always_trace() root that the
+        # sampler did not pick: a COARSE context, whose tree holds the
+        # call-level spans only.
+        self.fine = fine
+        # coarse context minted in THIS process: its own submits carry
+        # it (one hop). A coarse context that came over the wire is not
+        # continued by per-operation entry points (maybe_trace), or an
+        # epoch's every collective op and ingest fetch would be a span.
+        self.relay = relay
 
     def __repr__(self):
         return (f"TraceContext({self.trace_id.hex()}, {self.span_id.hex()},"
@@ -121,27 +144,58 @@ def new_context() -> TraceContext:
 
 
 def child(ctx: TraceContext) -> TraceContext:
-    return TraceContext(ctx.trace_id, os.urandom(8), ctx.span_id)
+    return TraceContext(ctx.trace_id, os.urandom(8), ctx.span_id, ctx.fine,
+                        ctx.relay)
+
+
+def child_of_current(per_op: bool = False) -> TraceContext | None:
+    """A child of the ambient context, or None outside any trace.
+    `per_op`: the caller runs once per operation, not once per call —
+    None as well under a coarse context this process did not mint."""
+    cur = _CTX.get()
+    if cur is None or (per_op and not (cur.fine or cur.relay)):
+        return None
+    return child(cur)
 
 
 def maybe_trace() -> TraceContext | None:
     """Entry-point mint: continue the ambient trace when one is active
-    (nested submit, traced request handler), else head-sample a fresh
-    root at the current rate. Returns None when not sampled."""
-    cur = _CTX.get()
-    if cur is not None:
-        return child(cur)
+    (nested submit, traced request handler; not a coarse context from
+    another process), else head-sample a fresh root at the current
+    rate. Returns None when not sampled."""
+    ctx = child_of_current(per_op=True)
+    if ctx is not None:
+        return ctx
     if _rate <= 0.0 or _rng.random() >= _rate:
         return None
     return new_context()
 
 
+def always_trace(fine: bool = False) -> TraceContext:
+    """Entry-point mint for call-level spans that are recorded whatever
+    the sampling rate: continue the ambient trace, else a fresh root
+    whose FINE level is on when the caller asks for it or the head
+    sampler picks the call."""
+    cur = _CTX.get()
+    if cur is not None:
+        ctx = child(cur)
+        ctx.fine = cur.fine or fine
+    else:
+        ctx = new_context()
+        ctx.fine = fine or (_rate > 0.0 and _rng.random() < _rate)
+    ctx.relay = True
+    return ctx
+
+
 # --- wire format -----------------------------------------------------------
 # msgpack-plain [trace_id, span_id, parent_span_id, sampled]: span_id is
 # the SENDER's span — the receiver records its spans as children of it.
+# sampled: 1 = every level, 2 = call-level spans only (always_trace root
+# the sampler did not pick).
 
 def to_wire(ctx: TraceContext) -> list:
-    return [ctx.trace_id, ctx.span_id, ctx.parent_id or b"", 1]
+    return [ctx.trace_id, ctx.span_id, ctx.parent_id or b"",
+            1 if ctx.fine else 2]
 
 
 def from_wire(wire) -> TraceContext | None:
@@ -154,7 +208,7 @@ def from_wire(wire) -> TraceContext | None:
     if not sampled:
         return None
     return TraceContext(bytes(trace_id), bytes(span_id),
-                        bytes(parent) or None)
+                        bytes(parent) or None, fine=sampled == 1)
 
 
 # --- ambient context -------------------------------------------------------
@@ -199,6 +253,67 @@ def use(ctx: TraceContext | None):
 
 # --- span recording --------------------------------------------------------
 
+# Executing side: the spans recorded under one traced task, handed back
+# in its reply (core_worker._exec_scope). Bounded: what does not fit
+# still goes to the ProfileBuffer.
+REPLY_SPANS_MAX = 256
+_REPLY: contextvars.ContextVar = contextvars.ContextVar(
+    "ray_tpu_trace_reply", default=None)
+# Owning side: trees open in this process by hex trace id (open_tree).
+_trees: dict[str, list] = {}
+_annotating = False  # a jax profiler session runs in this process
+
+
+@contextlib.contextmanager
+def collect_reply(ctx: TraceContext | None):
+    """Gather the spans recorded in this execution context (a traced
+    task's) as `[name, start, end, fields]` rows; yields None untraced."""
+    if ctx is None:
+        yield None
+        return
+    rows: list = []
+    token = _REPLY.set(rows)
+    try:
+        yield rows
+    finally:
+        try:
+            _REPLY.reset(token)
+        except ValueError:
+            pass  # token from another context (executor-pool reuse)
+
+
+@contextlib.contextmanager
+def open_tree(ctx: TraceContext):
+    """Keep every span of `ctx`'s trace that this process records or
+    adopts from a reply, for the duration of the block."""
+    rows = _trees[ctx.trace_id.hex()] = []
+    try:
+        yield rows
+    finally:
+        _trees.pop(ctx.trace_id.hex(), None)
+
+
+def adopt(rows) -> None:
+    """Hang a reply's spans on the tree open for their trace, if any
+    (they are already in the executing process's ProfileBuffer)."""
+    if not _trees or not rows:
+        return
+    for row in rows:
+        tree = _trees.get(row[3].get("tid"))
+        if tree is not None:
+            tree.append(row)
+
+
+def set_annotating(on: bool) -> None:
+    """A jax profiler session started / stopped in this process."""
+    global _annotating
+    _annotating = bool(on)
+
+
+def annotating() -> bool:
+    return _annotating
+
+
 def record_span(name: str, start: float, end: float,
                 ctx: TraceContext | None, extra: dict | None = None) -> None:
     """Record one span into the bound ProfileBuffer. With ctx=None this
@@ -210,25 +325,44 @@ def record_span(name: str, start: float, end: float,
         fields["sid"] = ctx.span_id.hex()
         if ctx.parent_id:
             fields["psid"] = ctx.parent_id.hex()
+        row = [name, start, end, fields]
+        reply = _REPLY.get()
+        if reply is not None and len(reply) < REPLY_SPANS_MAX:
+            reply.append(row)
+        tree = _trees.get(fields["tid"]) if _trees else None
+        if tree is not None:
+            tree.append(row)
     _get_buffer().record(name, start, end, fields)
 
 
 @contextlib.contextmanager
 def span(name: str, ctx: TraceContext | None, extra: dict | None = None,
-         ambient: bool = False):
+         ambient: bool = False, start: float | None = None):
     """Context manager recording `name` over the with-block when ctx is
     not None; `ambient=True` additionally makes ctx the current context
-    inside the block (so nested entry points join the tree)."""
+    inside the block (so nested entry points join the tree). `extra` is
+    read when the block ends, so counts may be filled in inside it;
+    `start` back-dates the span to work done just before the block."""
     import time
 
-    if ctx is None:
+    if ctx is None and not _annotating:
         yield None
         return
-    token = _CTX.set(ctx) if ambient else None
-    start = time.time()
+    note = None
+    if _annotating:
+        import jax.profiler
+
+        note = jax.profiler.TraceAnnotation(name)
+        note.__enter__()
+    token = _CTX.set(ctx) if ambient and ctx is not None else None
+    if start is None:
+        start = time.time()
     try:
         yield ctx
     finally:
-        record_span(name, start, time.time(), ctx, extra)
+        if note is not None:
+            note.__exit__(None, None, None)
+        if ctx is not None:
+            record_span(name, start, time.time(), ctx, extra)
         if token is not None:
             pop(token)

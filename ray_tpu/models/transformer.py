@@ -120,24 +120,30 @@ def _block(x, p, cfg: TransformerConfig, pad_mask=None):
     b, t, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
 
-    y = layernorm(x, p["ln1_w"].astype(x.dtype), p["ln1_b"].astype(x.dtype))
-    qkv = y @ p["wqkv"].astype(x.dtype)                     # [B,T,3D]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, hd)
-    k = k.reshape(b, t, h, hd)
-    v = v.reshape(b, t, h, hd)
-    if pad_mask is None:
-        attn = flash_attention(q, k, v, cfg.causal)
-    else:
-        # masked (padded-batch) attention: dense path with key masking
-        attn = masked_attention(q, k, v, pad_mask, causal=cfg.causal)
-    attn = attn.reshape(b, t, d) @ p["wo"].astype(x.dtype)
-    x = x + attn
+    # the scopes name the block's halves in the device trace
+    with jax.named_scope("attention"):
+        y = layernorm(x, p["ln1_w"].astype(x.dtype),
+                      p["ln1_b"].astype(x.dtype))
+        qkv = y @ p["wqkv"].astype(x.dtype)                 # [B,T,3D]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, t, h, hd)
+        k = k.reshape(b, t, h, hd)
+        v = v.reshape(b, t, h, hd)
+        if pad_mask is None:
+            attn = flash_attention(q, k, v, cfg.causal)
+        else:
+            # masked (padded-batch) attention: dense path, key masking
+            attn = masked_attention(q, k, v, pad_mask, causal=cfg.causal)
+        attn = attn.reshape(b, t, d) @ p["wo"].astype(x.dtype)
+        x = x + attn
 
-    y = layernorm(x, p["ln2_w"].astype(x.dtype), p["ln2_b"].astype(x.dtype))
-    y = jax.nn.gelu(y @ p["w_in"].astype(x.dtype) + p["b_in"].astype(x.dtype))
-    y = y @ p["w_out"].astype(x.dtype) + p["b_out"].astype(x.dtype)
-    return x + y
+    with jax.named_scope("mlp"):
+        y = layernorm(x, p["ln2_w"].astype(x.dtype),
+                      p["ln2_b"].astype(x.dtype))
+        y = jax.nn.gelu(y @ p["w_in"].astype(x.dtype)
+                        + p["b_in"].astype(x.dtype))
+        y = y @ p["w_out"].astype(x.dtype) + p["b_out"].astype(x.dtype)
+        return x + y
 
 
 def encode(params, x, cfg: TransformerConfig, pad_mask=None):
@@ -162,11 +168,12 @@ def apply(params, tokens, cfg: TransformerConfig, pad_mask=None):
     x = params["wte"][tokens].astype(cfg.dtype)
     x = x + params["wpe"][:t].astype(cfg.dtype)[None]
     x = encode(params, x, cfg, pad_mask)
-    if cfg.tie_embeddings:
-        logits = x @ params["wte"].T.astype(x.dtype)
-    else:
-        logits = x @ params["lm_head"].astype(x.dtype)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("logits_loss"):
+        if cfg.tie_embeddings:
+            logits = x @ params["wte"].T.astype(x.dtype)
+        else:
+            logits = x @ params["lm_head"].astype(x.dtype)
+        return logits.astype(jnp.float32)
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig):
@@ -176,10 +183,11 @@ def loss_fn(params, tokens, cfg: TransformerConfig):
     flash kernel engages); the last position's logits are dropped after.
     """
     logits = apply(params, tokens, cfg)[:, :-1]
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return nll.mean()
+    with jax.named_scope("logits_loss"):
+        targets = tokens[:, 1:]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+        return nll.mean()
 
 
 def num_params(params) -> int:

@@ -124,6 +124,7 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
         out_specs=pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 

@@ -70,6 +70,7 @@ def _layernorm_fwd_impl(x, weight, bias, *, eps: float,
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=not is_tpu(),
+        name="layernorm",
     )(xf, weight, bias)
     return out.reshape(orig_shape)
 
@@ -125,6 +126,7 @@ def _rmsnorm_fwd_impl(x, weight, *, eps: float, block_rows: int = 256):
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=not is_tpu(),
+        name="rmsnorm",
     )(xf, weight)
     return out.reshape(orig_shape)
 

@@ -27,6 +27,10 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 
 from ray_tpu._private import profiling as _profiling
+from ray_tpu._private import tracing as _tracing
+
+# snapshot leaves above this get a span of their own at the fine level
+_LEAF_SPAN_BYTES = 1 << 20
 
 
 class TrainingOperator:
@@ -233,52 +237,47 @@ class TrainingOperator:
         # mystery slowdown
         self._compile_probe = _profiling.CompileProbe("train.step")
 
+        # The step's two halves. The scopes are names in the device
+        # trace ("forward_backward", "optimizer"), nothing else.
+        def update(grads, opt_state, params):
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                return (jax.tree.map(lambda p, u: p + u, params, updates),
+                        opt_state)
+
+        if stateful:
+            def value_and_grads(params, mstate, batch):
+                with jax.named_scope("forward_backward"):
+                    (loss, mstate), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(params, mstate, batch)
+                return loss, mstate, grads
+        else:
+            def value_and_grads(params, mstate, batch):
+                with jax.named_scope("forward_backward"):
+                    loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                return loss, mstate, grads
+
         # Fused path (single worker): grads + update in one jit, buffers
         # donated so XLA updates params/opt_state in place; loss stays on
         # device — the epoch loop issues pure async dispatches.
-        if stateful:
-            def fused(params, mstate, opt_state, batch):
-                (loss, new_mstate), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, mstate, batch)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = pin(jax.tree.map(lambda p, u: p + u, params,
-                                          updates))
-                return params, new_mstate, opt_state, loss
+        def fused(params, mstate, opt_state, batch):
+            loss, mstate, grads = value_and_grads(params, mstate, batch)
+            params, opt_state = update(grads, opt_state, params)
+            return pin(params), mstate, opt_state, loss
 
-            def grad_step(params, mstate, batch):
-                (loss, new_mstate), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, mstate, batch)
-                return loss, new_mstate, ravel_pytree(grads)[0]
+        def grad_step(params, mstate, batch):
+            loss, mstate, grads = value_and_grads(params, mstate, batch)
+            return loss, mstate, ravel_pytree(grads)[0]
 
-            self._fused_step = jax.jit(traced_on_mesh(fused),
-                                       donate_argnums=(0, 1, 2),
-                                       out_shardings=self._fused_out)
-            self._fused_donate = (0, 1, 2)
-            self._grad_step = jax.jit(grad_step)
-        else:
-            def fused(params, mstate, opt_state, batch):
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = pin(jax.tree.map(lambda p, u: p + u, params,
-                                          updates))
-                return params, mstate, opt_state, loss
-
-            def grad_step(params, mstate, batch):
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-                return loss, mstate, ravel_pytree(grads)[0]
-
-            self._fused_step = jax.jit(traced_on_mesh(fused),
-                                       donate_argnums=(0, 2),
-                                       out_shardings=self._fused_out)
-            self._fused_donate = (0, 2)
-            self._grad_step = jax.jit(grad_step)
+        self._fused_donate = (0, 1, 2) if stateful else (0, 2)
+        self._fused_step = jax.jit(traced_on_mesh(fused),
+                                   donate_argnums=self._fused_donate,
+                                   out_shardings=self._fused_out)
+        self._grad_step = jax.jit(grad_step)
 
         def apply_step(params, opt_state, flat_grads):
-            grads = unravel(flat_grads)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            return jax.tree.map(lambda p, u: p + u, params, updates), opt_state
+            return update(unravel(flat_grads), opt_state, params)
 
         self._apply_step = jax.jit(apply_step, donate_argnums=(0, 1))
         if self._sharded:
@@ -446,41 +445,63 @@ class TrainingOperator:
             self.params, self.opt_state, flat_grads)
         return loss
 
+    def start_profile(self, profile_dir: str) -> bool:
+        """Start a jax profiler session in this process; while it runs,
+        the tracing spans recorded here are also host annotations in
+        the trace (`_private/tracing.py`)."""
+        jax.profiler.start_trace(profile_dir)
+        _tracing.set_annotating(True)
+        return True
+
+    def stop_profile(self) -> bool:
+        """Stop the session `start_profile` began (none running: False)."""
+        if not _tracing.annotating():
+            return False
+        _tracing.set_annotating(False)
+        jax.profiler.stop_trace()
+        return True
+
     def train_epoch(self, num_steps: int | None = None,
                     profile_dir: str | None = None) -> dict:
         if self._train_loader is None:
             raise RuntimeError("no train_loader registered")
         from ray_tpu.train import metrics as _tm
 
-        if profile_dir:
-            jax.profiler.start_trace(profile_dir)
+        if profile_dir:   # a direct caller; Trainer.train brackets the call
+            self.start_profile(profile_dir)
         try:
-            t0 = time.perf_counter()
             losses, samples = [], 0
             step = 0
-            t_step = t0
-            for batch in self._train_loader:
-                # step_s spans loader wait + dispatch: together with
-                # ingest_wait_s (observed inside IngestStream's get)
-                # the pair answers "is training input-bound?"
-                losses.append(self._dispatch_batch(batch))
-                self.global_step += 1
-                bs = _batch_size(batch)
-                samples += bs
-                if bs:
-                    _tm.TOKENS_TOTAL.inc(bs)
-                now = time.perf_counter()
-                _tm.STEP_S.observe(now - t_step)
-                t_step = now
-                step += 1
-                if num_steps is not None and step >= num_steps:
-                    break
+            counts = {}
+            # `train.dispatch`: loop entry (the first batch's fetch) to
+            # the last dispatch returned; the device works from its
+            # first dispatch until `train.sync`'s drain returns
+            with _tracing.span("train.dispatch",
+                               _tracing.child_of_current(), counts):
+                t_step = t0 = time.perf_counter()
+                for batch in self._train_loader:
+                    losses.append(self._dispatch_batch(batch))
+                    self.global_step += 1
+                    bs = _batch_size(batch)
+                    samples += bs
+                    if bs:
+                        _tm.SAMPLES_TOTAL.inc(bs)
+                    now = time.perf_counter()
+                    # with ingest_wait_s (observed inside IngestStream's
+                    # get) this answers "is training input-bound?"
+                    _tm.STEP_DISPATCH_S.observe(now - t_step)
+                    t_step = now
+                    step += 1
+                    if num_steps is not None and step >= num_steps:
+                        break
+                counts.update(steps=step, samples=samples)
             # One sync for the whole epoch: the loop was async dispatch.
-            losses = [float(x) for x in losses]
-            dt = time.perf_counter() - t0
+            with _tracing.span("train.sync", _tracing.child_of_current()):
+                losses = [float(x) for x in losses]
+                dt = time.perf_counter() - t0
         finally:
             if profile_dir:
-                jax.profiler.stop_trace()
+                self.stop_profile()
         self.epoch += 1
         return {
             "epoch": self.epoch,
@@ -517,7 +538,10 @@ class TrainingOperator:
     # checkpointing (reference: torch_trainer.py:543 save / :552 load)
     # ------------------------------------------------------------------
 
-    def state_dict(self) -> dict:
+    def _to_host(self, tree, counts: dict, ctx):
+        """`tree` with its arrays on the host, leaf by leaf; adds to
+        `counts` (`bytes`, `leaves`). At a trace's fine level every
+        leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
         def to_np(x):
             if not isinstance(x, (jnp.ndarray, np.ndarray)):
                 return x
@@ -529,23 +553,38 @@ class TrainingOperator:
                 from jax.experimental import multihost_utils
 
                 x = multihost_utils.process_allgather(x)
-            return np.asarray(x)
+            counts["bytes"] += x.nbytes
+            counts["leaves"] += 1
+            if ctx is None or not ctx.fine or x.nbytes < _LEAF_SPAN_BYTES:
+                return np.asarray(x)
+            with _tracing.span(
+                    "train.snapshot.d2h.leaf", _tracing.child(ctx),
+                    {"bytes": x.nbytes, "dtype": str(x.dtype),
+                     "shape": list(x.shape)}):
+                return np.asarray(x)
 
-        out = {
-            "params": jax.tree.map(to_np, self.params),
-            "model_state": (None if self.model_state is None
-                            else jax.tree.map(to_np, self.model_state)),
-            "epoch": self.epoch,
-            "global_step": self.global_step,
-        }
-        if self._sharded:
-            # no replicated opt blob exists in sharded mode — the state
-            # carries THIS rank's shard (train/sharding.py dict format)
-            out["sharded_update"] = True
-            out["opt_shard"] = self.opt_shard_state()
-        else:
-            out["opt_state"] = jax.tree.map(to_np, self.opt_state)
-        return out
+        return jax.tree.map(to_np, tree)
+
+    def state_dict(self) -> dict:
+        counts = {"bytes": 0, "leaves": 0}
+        ctx = _tracing.child_of_current()
+        with _tracing.span("train.snapshot.d2h", ctx, counts):
+            out = {
+                "params": self._to_host(self.params, counts, ctx),
+                "model_state": self._to_host(self.model_state, counts, ctx),
+                "epoch": self.epoch,
+                "global_step": self.global_step,
+            }
+            if self._sharded:
+                # no replicated opt blob exists in sharded mode — the
+                # state carries THIS rank's shard (train/sharding.py
+                # dict format)
+                out["sharded_update"] = True
+                out["opt_shard"] = self._opt_shard(counts, ctx)
+            else:
+                out["opt_state"] = self._to_host(self.opt_state, counts,
+                                                 ctx)
+            return out
 
     def load_state_dict(self, state: dict):
         self.params = jax.tree.map(jnp.asarray, state["params"])
@@ -593,8 +632,13 @@ class TrainingOperator:
         """This rank's optimizer-state shard in the train/sharding.py
         dict format (numpy leaves) — the unit of sharded checkpoints and
         elastic resharding."""
-        leaves = [np.asarray(x) if isinstance(x, (jnp.ndarray, np.ndarray))
-                  else x for x in jax.tree.leaves(self.opt_state)]
+        counts = {"bytes": 0, "leaves": 0}
+        ctx = _tracing.child_of_current()
+        with _tracing.span("train.snapshot.d2h", ctx, counts):
+            return self._opt_shard(counts, ctx)
+
+    def _opt_shard(self, counts: dict, ctx) -> dict:
+        leaves = self._to_host(jax.tree.leaves(self.opt_state), counts, ctx)
         return {"rank": self.world_rank, "world_size": self.world_size,
                 "span": (self._shard_lo, self._shard_hi),
                 "numel": self._numel, "pad_numel": self._pad_numel,

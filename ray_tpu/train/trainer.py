@@ -11,6 +11,7 @@ local device mesh, so ICI collectives come from XLA, not this layer)."""
 
 from __future__ import annotations
 
+import collections
 import pickle
 import time
 import uuid
@@ -21,12 +22,40 @@ import ray_tpu
 from ray_tpu import exceptions as exc
 from ray_tpu._private import failpoints as _fp
 from ray_tpu._private import global_state
+from ray_tpu._private import tracing
 from ray_tpu.collective.collective import CollectiveActorMixin
 
 # Sharded checkpoint manifest marker (Trainer.save/load): `path` holds a
 # small index dict with this format tag; params + per-rank optimizer
 # shards live in sibling files it names.
 _SHARDED_CKPT_FORMAT = "ray_tpu.sharded_ckpt"
+
+# The span trees of this process's last Trainer.train() calls, oldest
+# first (call_log()). Raw rows; turned into dicts when read.
+CALL_LOG_MAX = 256
+_call_log: collections.deque = collections.deque(maxlen=CALL_LOG_MAX)
+
+
+def call_log() -> list[dict]:
+    """The finished span trees of this process's last `Trainer.train()`
+    calls (at most 256, oldest first). One entry a call: `trace_id` and
+    `spans`, each span a dict of `name`, `start`, `end` (seconds on
+    `time.time()`, comparable within one host), `span`, `parent` (ids;
+    the root's parent is None) and `attrs` (its counts). Root:
+    `train.call`; the worker's spans arrive in the task replies, so an
+    entry is whole when `train()` returns (ARCHITECTURE.md, "Span
+    catalogue")."""
+    out = []
+    for trace_id, rows in list(_call_log):
+        spans = []
+        for name, start, end, fields in list(rows):
+            attrs = {k: v for k, v in fields.items()
+                     if k not in ("tid", "sid", "psid")}
+            spans.append({"name": name, "start": start, "end": end,
+                          "span": fields["sid"],
+                          "parent": fields.get("psid"), "attrs": attrs})
+        out.append({"trace_id": trace_id, "spans": spans})
+    return out
 
 
 class TrainWorker(CollectiveActorMixin):
@@ -58,6 +87,16 @@ class TrainWorker(CollectiveActorMixin):
 
     def train_epoch(self, num_steps=None, profile_dir=None):
         return self.operator.train_epoch(num_steps, profile_dir=profile_dir)
+
+    def start_profile(self, profile_dir):
+        """Trainer.train(profile_dir=) brackets the worker's side of the
+        call with these two (an operator without a profiler: no-ops)."""
+        start = getattr(self.operator, "start_profile", None)
+        return bool(start and start(profile_dir))
+
+    def stop_profile(self):
+        stop = getattr(self.operator, "stop_profile", None)
+        return bool(stop and stop())
 
     def validate(self, num_steps=None):
         return self.operator.validate(num_steps)
@@ -463,10 +502,13 @@ class Trainer:
     # draining in a loop must not keep a train() call alive forever
     _MAX_PLANNED_REGANGS = 8
 
-    def _run_with_retries(self, fn_name: str, num_steps, **kw):
+    def _run_with_retries(self, fn_name: str, num_steps,
+                          counts: dict | None = None, **kw):
         attempt = 0
         planned_regangs = 0
         while True:
+            if counts is not None:   # the caller's span carries them
+                counts["attempts"] = attempt + planned_regangs + 1
             try:
                 if not self.workers:
                     raise exc.WorkerCrashedError("worker group is empty")
@@ -507,18 +549,53 @@ class Trainer:
 
     def train(self, num_steps: int | None = None,
               reduce_results: bool = True, profile_dir: str | None = None):
-        kw = {"profile_dir": profile_dir} if profile_dir else {}
-        results = self._run_with_retries("train_epoch", num_steps, **kw)
-        self._last_state = _own(ray_tpu.get(
-            self.workers[0].state_dict.remote(), timeout=120))
-        if self._sharded:
-            # the epoch-boundary snapshot is params (rank 0; identical
-            # everywhere) + ALL optimizer shards — the reshardable unit
-            # the elastic restore path consumes
-            self._last_state.pop("opt_shard", None)
-            self._last_shards = _own(ray_tpu.get(
-                [w.opt_shard_state.remote() for w in self.workers],
-                timeout=120))
+        """One epoch (or `num_steps`) on every worker, then the
+        epoch-boundary snapshot the elastic restore consumes. The call
+        is ONE trace rooted at `train.call` (always recorded; kept in
+        `call_log()`); `profile_dir` brackets the workers' whole side of
+        the call — epoch, snapshot, return put — with a jax profiler
+        session, and turns the per-leaf spans of the snapshot on."""
+        root = tracing.always_trace(fine=bool(profile_dir))
+        counts = {"num_steps": num_steps, "workers": len(self.workers)}
+        with tracing.open_tree(root) as rows:
+            try:
+                with tracing.span("train.call", root, counts, ambient=True):
+                    return self._train_traced(num_steps, reduce_results,
+                                              profile_dir)
+            finally:
+                _call_log.append((root.trace_id.hex(), rows))
+
+    def _train_traced(self, num_steps, reduce_results, profile_dir):
+        if profile_dir:
+            ray_tpu.get([w.start_profile.remote(profile_dir)
+                         for w in self.workers], timeout=120)
+        try:
+            counts = {}
+            with tracing.span("train.epoch", tracing.child_of_current(),
+                              counts, ambient=True):
+                results = self._run_with_retries("train_epoch", num_steps,
+                                                 counts)
+            with tracing.span("train.snapshot", tracing.child_of_current(),
+                              ambient=True):
+                self._last_state = _own(ray_tpu.get(
+                    self.workers[0].state_dict.remote(), timeout=120))
+                if self._sharded:
+                    # the epoch-boundary snapshot is params (rank 0;
+                    # identical everywhere) + ALL optimizer shards — the
+                    # reshardable unit the elastic restore path consumes
+                    self._last_state.pop("opt_shard", None)
+                    self._last_shards = _own(ray_tpu.get(
+                        [w.opt_shard_state.remote() for w in self.workers],
+                        timeout=120))
+        finally:
+            if profile_dir:
+                # a worker restarted mid-call has no session (a no-op);
+                # one that died must not hide the call's own error
+                try:
+                    ray_tpu.get([w.stop_profile.remote()
+                                 for w in self.workers], timeout=300)
+                except exc.RayTpuError:
+                    pass
         return _reduce(results) if reduce_results else results
 
     def validate(self, num_steps: int | None = None,
@@ -634,8 +711,17 @@ def _own(snapshot):
     import jax
     import numpy as np
 
-    return jax.tree.map(
-        lambda x: np.array(x) if isinstance(x, np.ndarray) else x, snapshot)
+    counts = {"bytes": 0}
+
+    def own(x):
+        if not isinstance(x, np.ndarray):
+            return x
+        counts["bytes"] += x.nbytes
+        return np.array(x)
+
+    with tracing.span("train.snapshot.copy", tracing.child_of_current(),
+                      counts):
+        return jax.tree.map(own, snapshot)
 
 
 def _reduce(results: list[dict]) -> dict:

@@ -277,6 +277,45 @@ def test_memstore_callback_create_flag_and_removal():
     assert fired == [1]
 
 
+def test_memstore_survives_a_finalizer_inside_its_lock(monkeypatch):
+    """The cyclic GC may run an ObjectRef's __del__ (which ends in
+    `delete`) at any allocation, also at the `_Entry()` a locked region
+    of the store makes — on the same thread. That must not deadlock."""
+    import threading
+
+    from ray_tpu._private import memstore
+    from ray_tpu._private.ids import ObjectID
+
+    store = memstore.MemoryStore()
+    doomed, fresh = ObjectID(b"d" * 24), ObjectID(b"f" * 24)
+    store.open(doomed)
+    fired = []
+    store.add_ready_callback(doomed, lambda: fired.append(1))
+
+    class Finalizing(memstore._Entry):
+        __slots__ = ()
+
+        def __init__(self):          # what a collection at this point does
+            super().__init__()
+            store.delete(doomed)
+
+    monkeypatch.setattr(memstore, "_Entry", Finalizing)
+    done = []
+
+    def locked_regions():
+        store.open(fresh)
+        store.add_ready_callback(ObjectID(b"g" * 24), lambda: None)
+        store.put(ObjectID(b"h" * 24), b"v")
+        done.append(True)
+
+    t = threading.Thread(target=locked_regions, daemon=True)
+    t.start()
+    t.join(scale_timeout(10))
+    assert done == [True], "the store deadlocked on its own lock"
+    assert fired == [1] and not store.contains(doomed)
+    assert store.get_if_ready(ObjectID(b"h" * 24))[:2] == (True, b"v")
+
+
 def test_cancel_still_reaches_channel_queued_tasks(ray_start_regular):
     """Tasks buffered behind the direct channel must still be
     cancellable before they start (the socket is not a blind spot)."""

@@ -1,0 +1,247 @@
+"""One span tree per `Trainer.train()` call (train/trainer.py,
+_private/tracing.py): the call is ONE trace rooted at `train.call`,
+crossing the driver and the worker, whole in `call_log()` when the call
+returns (the worker's spans ride the task replies) and in the GCS trace
+table after the flush. CPU, tiny models."""
+
+import numpy as np
+import pytest
+
+import ray_tpu
+from benchmark import span_log
+from ray_tpu._private import serialization, tracing
+from ray_tpu.train import Trainer, TrainingOperator, call_log
+from ray_tpu.train import trainer as trainer_mod
+from tests.conftest import scale_timeout
+from tests.test_observability import (_assert_connected, _tree_of,
+                                      _wait_spans)
+
+# every span of a call, by the name ARCHITECTURE.md's catalogue lists
+CALL_SPANS = {"train.call", "train.epoch", "train.dispatch", "train.sync",
+              "train.snapshot", "train.snapshot.d2h", "object.return_put",
+              "object.get", "train.snapshot.copy"}
+LEAF = "train.snapshot.d2h.leaf"
+
+
+class WideOperator(TrainingOperator):
+    """One 1.2 MB weight under adam: a 3.6 MB snapshot (a plasma return)
+    with three leaves above the 1 MiB the fine level gives a span."""
+
+    def setup(self, config):
+        import jax.numpy as jnp
+        import optax
+
+        def model_init(rng):
+            return {"w": jnp.zeros((300_000,)), "b": jnp.zeros((4,))}
+
+        def loss_fn(params, batch):
+            x, y = batch
+            return jnp.mean((x * params["w"][:4] + params["b"] - y) ** 2)
+
+        self.register(model_init=model_init, loss_fn=loss_fn,
+                      optimizer=optax.adam(1e-3))
+        x = np.ones((8, 4), np.float32)
+        self.register_data(train_loader=[(x, 2 * x)] * 4)
+
+
+class ShardOperator(TrainingOperator):
+    """(512, 4) weights under adam, for the sharded (ZeRO) trainer."""
+
+    def setup(self, config):
+        import jax.numpy as jnp
+        import optax
+
+        def loss_fn(params, batch):
+            x, y = batch
+            return jnp.mean((x @ params["w"] - y) ** 2)
+
+        self.register(model_init=lambda rng: {"w": jnp.zeros((512, 4))},
+                      loss_fn=loss_fn, optimizer=optax.adam(1e-3))
+        x = np.ones((8, 512), np.float32) / 4.0
+        self.register_data(train_loader=[(x, np.ones((8, 4), np.float32))]
+                           * 2)
+
+
+class NoOp:
+    """The least a TrainWorker needs of an operator: no jax at all."""
+
+    def __init__(self, config, world_rank, world_size, group_name=None):
+        pass
+
+    def train_epoch(self, num_steps=None, profile_dir=None):
+        return {"num_samples": 0}
+
+    def state_dict(self):
+        return {"epoch": 0}
+
+
+def _names(entry):
+    return [s["name"] for s in entry["spans"]]
+
+
+def _leaf_bytes(tree) -> int:
+    import jax
+
+    return sum(x.nbytes for x in jax.tree.leaves(tree)
+               if isinstance(x, np.ndarray))
+
+
+@pytest.fixture(scope="module")
+def wide(ray_start_shared):
+    tr = Trainer(WideOperator, num_workers=1)
+    try:
+        yield tr
+    finally:
+        tr.shutdown(force=True)
+
+
+def test_one_call_is_one_tree_across_driver_and_worker(wide):
+    before = len(call_log())
+    wide.train(num_steps=2)
+    assert len(call_log()) == before + 1
+    entry = call_log()[-1]
+    # whole when train() returns: no wait on the GCS flush
+    assert CALL_SPANS <= set(_names(entry)), _names(entry)
+    ids = {s["span"] for s in entry["spans"]}
+    roots = [s for s in entry["spans"] if s["parent"] not in ids]
+    assert [r["name"] for r in roots] == ["train.call"]
+    assert roots[0]["attrs"] == {"num_steps": 2, "workers": 1}
+    dispatch = next(s for s in entry["spans"]
+                    if s["name"] == "train.dispatch")
+    assert dispatch["attrs"] == {"steps": 2, "samples": 16}
+    epoch = next(s for s in entry["spans"] if s["name"] == "train.epoch")
+    assert epoch["attrs"] == {"attempts": 1}
+    assert LEAF not in _names(entry)   # the fine level is off
+
+    # the same tree through the GCS trace table, by process
+    tid = entry["trace_id"]
+
+    def whole(spans):
+        tree = _tree_of(spans, tid)
+        return tree if len(tree) >= len(entry["spans"]) else None
+
+    tree = _wait_spans(whole, timeout=scale_timeout(30))
+    assert _assert_connected(tree)["event_type"] == "train.call"
+    where = {t["event_type"]: t["component_type"] for t in tree}
+    for name in ("train.call", "train.epoch", "train.snapshot",
+                 "object.get", "train.snapshot.copy", "task.e2e"):
+        assert where[name] == "driver", (name, where[name])
+    for name in ("train.dispatch", "train.sync", "train.snapshot.d2h",
+                 "object.return_put", "task"):
+        assert where[name] == "worker", (name, where[name])
+    # ... and in the Perfetto export, with flow arrows into the worker
+    export = ray_tpu.timeline()
+    worker_sids = {t["extra_data"]["sid"] for t in tree
+                   if t["component_type"] == "worker"}
+    finishes = {e["id"] for e in export if e.get("ph") == "f"}
+    assert worker_sids <= finishes
+
+
+def test_parts_of_a_call_add_up(wide):
+    for _ in range(2):
+        out = wide.train(num_steps=3)
+        entry = call_log()[-1]
+        parts = span_log.split(entry)
+        root = next(s for s in entry["spans"] if s["name"] == "train.call")
+        call_s = root["end"] - root["start"]
+        for key in ("d2h_s", "put_s", "get_s", "copy_s", "hop_s"):
+            assert parts[key] >= 0, (key, parts)
+        assert abs(parts["d2h_s"] + parts["put_s"] + parts["get_s"]
+                   + parts["copy_s"] + parts["hop_s"]
+                   - (call_s - parts["epoch_s"])) < 1e-3
+        # the span's epoch is the operator's own (samples over its rate)
+        assert abs(parts["epoch_s"]
+                   - out["num_samples"] / out["samples_per_s"]) < 1e-3
+        # every leaf span lies inside the call, after the epoch
+        for s in entry["spans"]:
+            if s["name"] in span_log.LEAVES:
+                assert root["start"] <= s["start"] <= s["end"] <= root["end"]
+        assert parts["bytes"] == _leaf_bytes(wide._last_state)
+        copy = next(s for s in entry["spans"]
+                    if s["name"] == "train.snapshot.copy")
+        assert copy["attrs"]["bytes"] == parts["bytes"]
+
+
+def test_leaf_spans_only_when_the_call_is_traced(wide, tmp_path):
+    wide.train(num_steps=1)
+    assert LEAF not in _names(call_log()[-1])
+    wide.train(num_steps=1, profile_dir=str(tmp_path))
+    entry = call_log()[-1]
+    leaves = [s for s in entry["spans"] if s["name"] == LEAF]
+    # w and adam's two moments of it; b and the counters are too small
+    assert [s["attrs"] for s in leaves] == [
+        {"bytes": 1_200_000, "dtype": "float32", "shape": [300_000]}] * 3
+    d2h = next(s for s in entry["spans"]
+               if s["name"] == "train.snapshot.d2h")
+    assert all(s["parent"] == d2h["span"] for s in leaves)
+    # the session bracketed the worker's side of the call, and ended
+    assert list(tmp_path.rglob("*.xplane.pb"))
+    tasks = {s["attrs"]["name"] for s in entry["spans"]
+             if s["name"] == "task"}
+    assert {"TrainWorker.start_profile", "TrainWorker.stop_profile"} <= tasks
+    wide.train(num_steps=1)
+    assert LEAF not in _names(call_log()[-1])
+    # head sampling turns the fine level on as well
+    ray_tpu.set_trace_sampling(1.0)
+    try:
+        wide.train(num_steps=1)
+    finally:
+        ray_tpu.set_trace_sampling(0.01)
+    assert _names(call_log()[-1]).count(LEAF) == 3
+
+
+def test_call_log_is_a_bounded_ring(ray_start_shared):
+    tr = Trainer(NoOp, num_workers=1)
+    try:
+        for _ in range(300):
+            tr.train()
+    finally:
+        tr.shutdown(force=True)
+    assert len(call_log()) == trainer_mod.CALL_LOG_MAX == 256
+    # a small state rides the reply inline: no object-plane span
+    assert not {"object.return_put", "object.get"} & set(
+        _names(call_log()[-1]))
+
+
+def test_return_put_only_for_plasma_returns(ray_start_shared):
+    @ray_tpu.remote
+    def zeros(n):
+        return np.zeros(n, np.uint8)
+
+    ctx = tracing.new_context()
+    with tracing.open_tree(ctx) as rows, tracing.use(ctx):
+        small = ray_tpu.get(zeros.remote(1000), timeout=60)
+        names_small = [r[0] for r in rows]
+        big = ray_tpu.get(zeros.remote(2_000_000), timeout=60)
+    assert "task" in names_small        # the worker's spans came back
+    assert "object.return_put" not in names_small
+    assert "object.get" not in names_small
+    puts = [r for r in rows if r[0] == "object.return_put"]
+    gets = [r for r in rows if r[0] == "object.get"]
+    assert len(puts) == len(gets) == 1
+    size = serialization.total_size(*serialization.serialize(big))
+    assert puts[0][3]["bytes"] == gets[0][3]["bytes"] == size > big.nbytes
+    assert small.nbytes == 1000
+
+
+def test_sharded_call_carries_the_shard_pulls(ray_start_shared):
+    tr = Trainer(ShardOperator, num_workers=2, sharded=True)
+    try:
+        tr.train()
+        entry = call_log()[-1]
+    finally:
+        tr.shutdown(force=True)
+    ids = {s["span"] for s in entry["spans"]}
+    assert [s["name"] for s in entry["spans"]
+            if s["parent"] not in ids] == ["train.call"]
+    pulls = [s for s in span_log.under(entry, "task", "train.snapshot")
+             if s["attrs"]["name"] == "TrainWorker.opt_shard_state"]
+    assert len(pulls) == 2
+    d2h = span_log.under(entry, "train.snapshot.d2h", "train.snapshot")
+    # rank 0's state (with its shard) and one pull a rank
+    assert len(d2h) == 3
+    for pull in pulls:
+        assert any(s["parent"] == pull["span"] for s in d2h)
+    # the state's copy-out and the shards'
+    assert _names(entry).count("train.snapshot.copy") == 2
+    assert _names(entry).count("train.dispatch") == 2
