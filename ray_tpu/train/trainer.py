@@ -275,6 +275,9 @@ class Trainer:
         self.workers: list = []
         self._last_state: dict | None = None
         self._last_shards: list | None = None
+        # The two (state, shards) sets `_own` built last, newest first:
+        # the older one is the next copy's destination (train).
+        self._owned = ((None, None), (None, None))
         self._start_workers(num_workers)
 
     # ------------------------------------------------------------------
@@ -577,16 +580,32 @@ class Trainer:
                                                  counts)
             with tracing.span("train.snapshot", tracing.child_of_current(),
                               ambient=True):
-                self._last_state = _own(ray_tpu.get(
-                    self.workers[0].state_dict.remote(), timeout=120))
+                # Two sets of buffers take turns: this call's views are
+                # copied into the set `_own` built two calls ago. Only
+                # trees `_own` built are in `_owned`, so a caller's
+                # arrays (load_state_dict, load) are never written to;
+                # neither is the newer set, which is the installed
+                # snapshot unless the caller's took its place. Both
+                # parts are installed, and the sets turned, only once
+                # both are whole: a copy that raises changes nothing.
+                newer, older = self._owned
+                state = ray_tpu.get(
+                    self.workers[0].state_dict.remote(), timeout=120)
                 if self._sharded:
                     # the epoch-boundary snapshot is params (rank 0;
                     # identical everywhere) + ALL optimizer shards — the
-                    # reshardable unit the elastic restore path consumes
-                    self._last_state.pop("opt_shard", None)
-                    self._last_shards = _own(ray_tpu.get(
+                    # reshardable unit the elastic restore path consumes.
+                    # Rank 0's own shard goes before the copy: it was
+                    # never kept, and the tree then matches the spare's.
+                    state.pop("opt_shard", None)
+                state = _own(state, older[0])   # the views die here
+                shards = None
+                if self._sharded:
+                    shards = _own(ray_tpu.get(
                         [w.opt_shard_state.remote() for w in self.workers],
-                        timeout=120))
+                        timeout=120), older[1])
+                self._last_state, self._last_shards = state, shards
+                self._owned = ((state, shards), newer)
         finally:
             if profile_dir:
                 # a worker restarted mid-call has no session (a no-op);
@@ -700,28 +719,54 @@ class Trainer:
         self._release_gang()
 
 
-def _own(snapshot):
-    """The snapshot with its arrays copied out of the object store. What
-    `get` returns are zero-copy views PINNED in the node's shared arena;
-    a snapshot the trainer keeps for the next elastic restore would hold
-    those bytes for ever. With a GPT-2-small + AdamW state (1.5 GB) in
-    the default 2 GiB arena that made the second `train()` fail in the
-    worker's put, and the restore itself (which puts the state back for
-    the new worker) fail in the driver's."""
+def _own(snapshot, spare=None):
+    """A whole copy of `snapshot` in memory the driver owns, with no
+    view into the object store left in it. What `get` returns are
+    zero-copy views PINNED in the node's shared arena; a snapshot the
+    trainer keeps for the next elastic restore would hold those bytes
+    for ever (a GPT-2-small + AdamW state, 1.5 GB in the default 2 GiB
+    arena, made the second `train()` fail in the worker's put).
+
+    `spare` is a tree an earlier `_own` built and nothing uses any more
+    (`Trainer.train`: the snapshot retired a call ago). A leaf is copied
+    INTO the spare's leaf at the same path when that is an owned,
+    writable array of the same shape, dtype and strides — a flat copy
+    into pages already mapped and resident, where a fresh array over
+    glibc's mmap threshold takes a page fault per 4 KiB. (Strides, not
+    C-order: on a TPU some leaves arrive transposed, ResNet's `fc_w`
+    for one, and `np.array` keeps their layout.) Any other leaf — a
+    first call, a changed tree, optimizer shards re-partitioned to
+    another world size — is allocated, so the copy is bit-identical
+    either way. `spare` is only ever written to, never returned as a
+    whole: a copy that raises half-way leaves a half-written spare and
+    the installed snapshot untouched. In steady state the driver holds
+    two sets of buffers (2 x the snapshot's bytes: 2.8 GiB for
+    GPT-2-small), which was the peak before — the old snapshot was
+    alive while the new one was built. The span's `reused_bytes` says
+    how much went into the spare (0 on a Trainer's first two calls,
+    then = `bytes`)."""
     import jax
     import numpy as np
 
-    counts = {"bytes": 0}
+    counts = {"bytes": 0, "reused_bytes": 0}
+    spares = dict(jax.tree_util.tree_flatten_with_path(spare)[0])
 
-    def own(x):
+    def own(path, x):
         if not isinstance(x, np.ndarray):
             return x
         counts["bytes"] += x.nbytes
+        dst = spares.get(path)
+        if (isinstance(dst, np.ndarray) and dst.flags.owndata
+                and dst.flags.writeable and dst.shape == x.shape
+                and dst.dtype == x.dtype and dst.strides == x.strides):
+            np.copyto(dst, x)
+            counts["reused_bytes"] += x.nbytes
+            return dst
         return np.array(x)
 
     with tracing.span("train.snapshot.copy", tracing.child_of_current(),
                       counts):
-        return jax.tree.map(own, snapshot)
+        return jax.tree_util.tree_map_with_path(own, snapshot)
 
 
 def _reduce(results: list[dict]) -> dict:

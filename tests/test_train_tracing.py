@@ -160,34 +160,40 @@ def test_parts_of_a_call_add_up(wide):
         copy = next(s for s in entry["spans"]
                     if s["name"] == "train.snapshot.copy")
         assert copy["attrs"]["bytes"] == parts["bytes"]
+        # how much of it went into the buffers of a retired snapshot
+        assert 0 <= copy["attrs"]["reused_bytes"] <= parts["bytes"]
 
 
 def test_leaf_spans_only_when_the_call_is_traced(wide, tmp_path):
-    wide.train(num_steps=1)
-    assert LEAF not in _names(call_log()[-1])
-    wide.train(num_steps=1, profile_dir=str(tmp_path))
-    entry = call_log()[-1]
-    leaves = [s for s in entry["spans"] if s["name"] == LEAF]
-    # w and adam's two moments of it; b and the counters are too small
-    assert [s["attrs"] for s in leaves] == [
-        {"bytes": 1_200_000, "dtype": "float32", "shape": [300_000]}] * 3
-    d2h = next(s for s in entry["spans"]
-               if s["name"] == "train.snapshot.d2h")
-    assert all(s["parent"] == d2h["span"] for s in leaves)
-    # the session bracketed the worker's side of the call, and ended
-    assert list(tmp_path.rglob("*.xplane.pb"))
-    tasks = {s["attrs"]["name"] for s in entry["spans"]
-             if s["name"] == "task"}
-    assert {"TrainWorker.start_profile", "TrainWorker.stop_profile"} <= tasks
-    wide.train(num_steps=1)
-    assert LEAF not in _names(call_log()[-1])
-    # head sampling turns the fine level on as well
-    ray_tpu.set_trace_sampling(1.0)
+    # off: at the default rate the head sampler picks one call in a
+    # hundred, and that call has leaf spans
+    ray_tpu.set_trace_sampling(0.0)
     try:
         wide.train(num_steps=1)
+        assert LEAF not in _names(call_log()[-1])
+        wide.train(num_steps=1, profile_dir=str(tmp_path))
+        entry = call_log()[-1]
+        leaves = [s for s in entry["spans"] if s["name"] == LEAF]
+        # w and adam's two moments of it; b and the counters are too small
+        assert [s["attrs"] for s in leaves] == [
+            {"bytes": 1_200_000, "dtype": "float32", "shape": [300_000]}] * 3
+        d2h = next(s for s in entry["spans"]
+                   if s["name"] == "train.snapshot.d2h")
+        assert all(s["parent"] == d2h["span"] for s in leaves)
+        # the session bracketed the worker's side of the call, and ended
+        assert list(tmp_path.rglob("*.xplane.pb"))
+        tasks = {s["attrs"]["name"] for s in entry["spans"]
+                 if s["name"] == "task"}
+        assert {"TrainWorker.start_profile",
+                "TrainWorker.stop_profile"} <= tasks
+        wide.train(num_steps=1)
+        assert LEAF not in _names(call_log()[-1])
+        # head sampling turns the fine level on as well
+        ray_tpu.set_trace_sampling(1.0)
+        wide.train(num_steps=1)
+        assert _names(call_log()[-1]).count(LEAF) == 3
     finally:
         ray_tpu.set_trace_sampling(0.01)
-    assert _names(call_log()[-1]).count(LEAF) == 3
 
 
 def test_call_log_is_a_bounded_ring(ray_start_shared):
