@@ -265,6 +265,9 @@ class CoreWorker:
         self.task_channel_address = ""
         self._actor_instance = None
         self._actor_id: ActorID | None = None
+        # what this worker's actor was leased, by resource name (its
+        # creation task's demand; {} in a driver or a task worker)
+        self.actor_resources: dict[str, float] = {}
         self._actor_reorder: dict[bytes, dict] = {}  # caller -> {next, heap}
         self._async_loop: rpc.EventLoopThread | None = None
         self._exec_pool = None  # ThreadPoolExecutor when max_concurrency>1
@@ -651,7 +654,7 @@ class CoreWorker:
                 # (reference: plasma create retries after SpillObjects)
                 self._io.run(self.raylet.call(
                     "spill_now", {"need_bytes": size}))
-                self.store.put_serialized(object_id, header, buffers)
+                self._put_patiently(object_id, header, buffers)
             self._io.run(self.raylet.call("notify_object_sealed", {
                 "object_id": object_id.binary(), "size": size}))
             self.memstore.put(object_id, IN_PLASMA)
@@ -1618,6 +1621,11 @@ class CoreWorker:
                         # not clobber live lineage with retries=0.
                         if rec is not None or owned.lineage_task is None:
                             owned.lineage_task = lineage
+                if owned is None:
+                    # the last ref went while the task ran: nobody will
+                    # ever free what it put into the store, so do it now
+                    self._io.submit(self._free_plasma([return_id.binary()]))
+                    continue
                 self.memstore.put(return_id, IN_PLASMA)
         if inline_puts:
             # one lock/notify for the whole return set (a serve batch is
@@ -2969,6 +2977,8 @@ class CoreWorker:
             if spec["type"] == common.ACTOR_CREATION_TASK:
                 cls = self.fetch_function(spec["fn_id"], spec["job_id"],
                                           kind="cls")
+                self.actor_resources = common.ResourceSet.from_raw(
+                    spec.get("resources") or {}).to_dict()
                 self._actor_instance = cls(*args, **kwargs)
                 self._actor_id = ActorID(spec["actor_id"])
                 if spec.get("restore"):
@@ -3083,11 +3093,34 @@ class CoreWorker:
                 with tracing.span("object.return_put",
                                   tracing.child_of_current(),
                                   {"bytes": size}, start=t_ser):
-                    self.store.put_serialized(return_id, header, buffers)
+                    self._put_patiently(return_id, header, buffers)
                     self._io.run(self.raylet.call("notify_object_sealed", {
                         "object_id": return_id.binary(), "size": size}))
                 returns.append({"kind": "plasma", "size": size})
         return {"returns": returns}
+
+    # how long a put waits for room in a full store
+    _PUT_PATIENCE_S = 10.0
+
+    def _put_patiently(self, object_id, header, buffers):
+        """Into the store, waiting a little for room. A full (or
+        fragmented) arena is most often full of EARLIER objects their
+        reader is about to let go — a driver copying a snapshot piece
+        out while this worker brings the next, a worker placing a
+        restored piece while the driver puts the next — and an owner's
+        free reaches the store a moment after the last ref dies. Only
+        then does the store's MemoryError stand."""
+        deadline = None
+        while True:
+            try:
+                return self.store.put_serialized(object_id, header, buffers)
+            except MemoryError:
+                now = time.monotonic()
+                if deadline is None:
+                    deadline = now + self._PUT_PATIENCE_S
+                elif now > deadline:
+                    raise
+                time.sleep(0.005)
 
     def _pack_error(self, spec, error) -> dict:
         payload = serialization.dumps(error)

@@ -8,6 +8,7 @@ import atexit
 import json
 import logging
 import os
+import shutil
 import signal
 import subprocess
 import sys
@@ -321,6 +322,14 @@ class Node:
         for svc in reversed(self.processes):
             svc.kill()
         self.processes.clear()
+        if self.is_head:
+            # the session's shared memory goes with its services: the
+            # object store's arena file (2 GiB by default, as resident
+            # as the run made it) and the collective segments beside it
+            # are nobody's once the raylet is dead. A process that still
+            # maps the arena keeps its mapping until it exits.
+            shutil.rmtree(os.path.dirname(default_store_root(
+                self.session_dir)), ignore_errors=True)
 
     def kill_gcs(self):
         """Fault injection: kill the GCS process (it will be auto-restarted

@@ -270,6 +270,17 @@ def mesh_shape_for(num_devices: int) -> tuple[int, int]:
     shape = MESH_SHAPES.get(num_devices)
     if shape is not None:
         return shape
+    return host_mesh_shape(num_devices)
+
+
+def host_mesh_shape(num_devices: int) -> tuple[int, int]:
+    """(data, fsdp) for the chips ONE process holds — the chips of one
+    host, all on ICI: fsdp is the largest power-of-two divisor up to 4
+    (the tray width), data the rest. The table's pure-DP rows for tiny
+    slices are about gangs of hosts; on one host's chips the state is
+    sharded (a v5e host's four chips: (1, 4))."""
+    if num_devices < 1:
+        raise ValueError(f"num_devices must be >= 1, got {num_devices}")
     fsdp = 4 if num_devices % 4 == 0 else (2 if num_devices % 2 == 0 else 1)
     return (num_devices // fsdp, fsdp)
 

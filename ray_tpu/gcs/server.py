@@ -700,9 +700,22 @@ class GcsServer:
     async def heartbeat_checker(self):
         cfg = self.config
         timeout = cfg.heartbeat_interval_s * cfg.num_heartbeats_timeout
+        woke = time.monotonic()
         while True:
             await asyncio.sleep(cfg.heartbeat_interval_s)
             now = time.monotonic()
+            # This process overslept: while it did not run it received
+            # no beat either, so the silence it would count is its own.
+            # (A TPU host stops as a whole for seconds while libtpu
+            # initialises its chips — 5-9 s with one chip, longer with
+            # four — and a checker that wakes first would declare the
+            # raylet dead before its queued beats are read.) Every node
+            # is credited the time this loop was late.
+            late = now - woke - cfg.heartbeat_interval_s
+            woke = now
+            if late > cfg.heartbeat_interval_s:
+                for node_id in self.last_heartbeat:
+                    self.last_heartbeat[node_id] += late
             for node_id, last in list(self.last_heartbeat.items()):
                 limit = timeout
                 info = self.nodes.get(node_id)

@@ -35,6 +35,7 @@ AXES = ("dp", "pp", "sp", "tp", "ep")
 # jax) shares it; this is its public home.
 from ray_tpu._private.topology import (  # noqa: E402  (re-export)
     MESH_SHAPES as _MESH_SHAPES,
+    host_mesh_shape,
     mesh_shape_for,
 )
 
@@ -102,29 +103,37 @@ class MeshSpec:
 
 
 def fsdp_mesh(devices=None) -> Mesh:
-    """The topology-derived ('data', 'fsdp') mesh for
-    Trainer(mesh_mode="fsdp"): device count -> mesh_shape_for's
-    predefined (data, fsdp) factorization — the SAME table the ICI_RING
+    """The topology-derived ('data', 'fsdp') mesh of the Trainer's mesh
+    mode (`mesh_mode="fsdp"`, or one worker that leases several chips).
+    The chips of ONE process — one host, all on ICI — shard:
+    `host_mesh_shape`, (1, 4) on a v5e host's four. Devices of several
+    processes take `mesh_shape_for`'s table, the SAME one the ICI_RING
     placement record carries, so gang rank order and mesh layout agree.
-    Batch shards over 'data', params/optimizer state over 'fsdp'."""
+    Params and optimizer state shard over 'fsdp', the batch over both
+    axes."""
     devices = list(devices) if devices is not None else jax.devices()
-    shape = mesh_shape_for(len(devices))
+    one_host = len({d.process_index for d in devices}) == 1
+    shape = (host_mesh_shape if one_host else mesh_shape_for)(len(devices))
     n = shape[0] * shape[1]
     arr = np.array(devices[:n]).reshape(shape)
     return Mesh(arr, ("data", "fsdp"))
 
 
 def fsdp_param_specs(params, mesh: Mesh):
-    """Per-leaf PartitionSpecs sharding each param over the 'fsdp' axis
-    along its leading dimension when that divides evenly; small or
-    indivisible leaves (biases, scalars) stay replicated — the standard
-    FSDP layout compromise."""
+    """Per-leaf PartitionSpecs: each leaf is split over the 'fsdp' axis
+    along its FIRST dimension that divides evenly (GPT-2's embedding,
+    50257 x 1280, along 1280); leaves with none (odd biases, scalars)
+    stay replicated — the standard FSDP layout compromise. Leaves need
+    a `shape` only (`jax.eval_shape` of an init will do)."""
     fsdp = mesh.shape["fsdp"]
 
     def spec(p):
-        shape = getattr(p, "shape", ())
-        if shape and shape[0] % fsdp == 0 and shape[0] >= fsdp > 1:
-            return P("fsdp", *([None] * (len(shape) - 1)))
+        if fsdp > 1:
+            shape = getattr(p, "shape", ())
+            for dim, n in enumerate(shape):
+                if n >= fsdp and n % fsdp == 0:
+                    return P(*[None] * dim, "fsdp",
+                             *[None] * (len(shape) - dim - 1))
         return P()
 
     return jax.tree.map(spec, params)

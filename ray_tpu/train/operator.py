@@ -83,6 +83,8 @@ class TrainingOperator:
             data axis).
         """
         self._registered = True
+        self._facts = None      # _layout_facts, reckoned at the first epoch
+        self._loading = None    # a state arriving in pieces (_LoadPlan)
         self._loss_fn = loss_fn
         self._eval_fn = eval_fn
         self._optimizer = optimizer
@@ -92,22 +94,31 @@ class TrainingOperator:
         else:
             self.params = model_init(jax.random.key(seed))
             self.model_state = None
-        if mesh is None and self.config.get("mesh_mode") == "fsdp":
-            # FSDP mesh mode: the topology-derived ('data','fsdp') mesh
-            # (parallel.mesh.mesh_shape_for — the same table the
-            # ICI_RING placement record carries), params sharded over
-            # the fsdp axis, batch over data. The fused step stays ONE
-            # jit: with_sharding_constraint pins the updated params so
-            # XLA keeps every optimizer buffer on its shard.
+        chips = _leased_chips()
+        if mesh is None and (self.config.get("mesh_mode") == "fsdp" or (
+                chips > 1 and not self.config.get("sharded_update")
+                and (self.world_size == 1 or self.config.get("multihost")))):
+            # FSDP mesh mode, asked for or DERIVED FROM THE LEASE: one
+            # worker that was granted several chips trains on all of
+            # them. The ('data','fsdp') mesh spans the chips this worker
+            # holds (a multihost group: the group's), params — so every
+            # optimizer buffer — split over the fsdp axis, the batch
+            # over both. The fused step stays ONE jit:
+            # with_sharding_constraint pins the updated params so XLA
+            # keeps every optimizer buffer on its shard.
             from jax.sharding import PartitionSpec as P
 
             from ray_tpu.parallel import mesh as _meshlib
 
-            mesh = _meshlib.fsdp_mesh()
+            if self.config.get("multihost") or chips <= 1:
+                devices = jax.devices()
+            else:   # no more than leased; no more than there are
+                devices = jax.local_devices()[:chips]
+            mesh = _meshlib.fsdp_mesh(devices)
             if param_spec is None:
                 param_spec = _meshlib.fsdp_param_specs(self.params, mesh)
             if batch_spec is None:
-                batch_spec = P("data")
+                batch_spec = P(("data", "fsdp"))
         self._mesh = mesh
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -445,6 +456,33 @@ class TrainingOperator:
             self.params, self.opt_state, flat_grads)
         return loss
 
+    def _layout_facts(self) -> dict:
+        """What `train.dispatch` says of where the state lives: the
+        devices the step runs on (`chips`), the mesh's shape (mesh mode
+        only), the bytes of parameters, model and optimizer state as a
+        whole (`state_bytes`) and the addressable shard bytes of them on
+        the fullest device (`state_bytes_fullest_chip`). Layouts are
+        pinned at register(), so this is reckoned once."""
+        facts = self._facts
+        if facts is None:
+            held: dict = {}
+            whole = 0
+            for x in jax.tree.leaves((self.params, self.model_state,
+                                      self.opt_state)):
+                if not isinstance(x, jax.Array):
+                    continue
+                whole += x.nbytes
+                shard = int(np.prod(x.sharding.shard_shape(x.shape))
+                            ) * x.dtype.itemsize
+                for d in x.sharding.addressable_devices:
+                    held[d] = held.get(d, 0) + shard
+            facts = self._facts = {
+                "chips": max(len(held), 1), "state_bytes": whole,
+                "state_bytes_fullest_chip": max(held.values(), default=0)}
+            if self._mesh is not None:
+                facts["mesh"] = [int(n) for n in self._mesh.shape.values()]
+        return facts
+
     def start_profile(self, profile_dir: str) -> bool:
         """Start a jax profiler session in this process; while it runs,
         the tracing spans recorded here are also host annotations in
@@ -494,7 +532,8 @@ class TrainingOperator:
                     step += 1
                     if num_steps is not None and step >= num_steps:
                         break
-                counts.update(steps=step, samples=samples)
+                counts.update(steps=step, samples=samples,
+                              **self._layout_facts())
             # One sync for the whole epoch: the loop was async dispatch.
             with _tracing.span("train.sync", _tracing.child_of_current()):
                 losses = [float(x) for x in losses]
@@ -565,68 +604,71 @@ class TrainingOperator:
 
         return jax.tree.map(to_np, tree)
 
+    def _state_tree(self, drop=()) -> dict:
+        """The training state with its arrays where they are (on the
+        devices), less the top-level keys in `drop`."""
+        out = {
+            "params": self.params,
+            "model_state": self.model_state,
+            "epoch": self.epoch,
+            "global_step": self.global_step,
+        }
+        if self._sharded:
+            # no replicated opt blob exists in sharded mode — the
+            # state carries THIS rank's shard (train/sharding.py
+            # dict format)
+            out["sharded_update"] = True
+            if "opt_shard" not in drop:
+                out["opt_shard"] = self._opt_shard_tree()
+        else:
+            out["opt_state"] = self.opt_state
+        return {k: v for k, v in out.items() if k not in drop}
+
     def state_dict(self) -> dict:
         counts = {"bytes": 0, "leaves": 0}
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
-            out = {
-                "params": self._to_host(self.params, counts, ctx),
-                "model_state": self._to_host(self.model_state, counts, ctx),
-                "epoch": self.epoch,
-                "global_step": self.global_step,
-            }
-            if self._sharded:
-                # no replicated opt blob exists in sharded mode — the
-                # state carries THIS rank's shard (train/sharding.py
-                # dict format)
-                out["sharded_update"] = True
-                out["opt_shard"] = self._opt_shard(counts, ctx)
-            else:
-                out["opt_state"] = self._to_host(self.opt_state, counts,
-                                                 ctx)
-            return out
+            return self._to_host(self._state_tree(), counts, ctx)
+
+    def state_piece(self, index: int, usable: int, drop=()) -> dict:
+        """Piece `index` of the state as `train/snapshot.py` cuts it for
+        a store that holds `usable` bytes: only this piece's leaves are
+        brought to the host (one `train.snapshot.d2h` span a piece)."""
+        from ray_tpu.train import snapshot as _snapshot
+
+        counts = {"bytes": 0, "leaves": 0}
+        ctx = _tracing.child_of_current()
+        with _tracing.span("train.snapshot.d2h", ctx, counts):
+            return _snapshot.piece(
+                self._state_tree(drop), index, usable,
+                to_host=lambda part: self._to_host(part, counts, ctx))
 
     def load_state_dict(self, state: dict):
-        self.params = jax.tree.map(jnp.asarray, state["params"])
-        if state.get("model_state") is not None:
-            self.model_state = jax.tree.map(jnp.asarray,
-                                            state["model_state"])
-        if self._sharded:
-            if "opt_state" in state:
-                raise ValueError(
-                    "replicated checkpoint (full opt_state) cannot load "
-                    "into a sharded-update trainer; re-save it sharded "
-                    "or construct Trainer(sharded=False)")
-            # rebuild the local param shard from the restored params;
-            # the optimizer shard arrives separately (load_opt_shard,
-            # possibly resharded) unless this state happens to carry a
-            # geometry-matching shard (same-rank broadcast restore).
-            flat, _ = ravel_pytree(self.params)
-            self._param_shard = jnp.pad(
-                flat, (0, self._pad_numel - self._numel)
-            )[self._shard_lo:self._shard_hi]
-            sh = state.get("opt_shard")
-            if (sh is not None and sh["world_size"] == self.world_size
-                    and sh["rank"] == self.world_rank):
-                self.load_opt_shard(sh)
-        else:
-            if state.get("sharded_update"):
-                raise ValueError(
-                    "sharded checkpoint cannot load into an unsharded "
-                    "trainer; construct Trainer(sharded=True) or load "
-                    "the sharded manifest via Trainer.load()")
-            self.opt_state = jax.tree.map(
-                lambda ref, x: jnp.asarray(x) if isinstance(
-                    x, np.ndarray) else x,
-                self.opt_state, state["opt_state"])
-        if self._fused_out is not None:
-            # mesh mode: back onto the mesh, laid out as registered (the
-            # step's program was built for exactly that layout)
-            self.params, self.model_state, self.opt_state = jax.device_put(
-                (self.params, self.model_state, self.opt_state),
-                self._fused_out[:3])
-        self.epoch = state["epoch"]
-        self.global_step = state["global_step"]
+        leaves, treedef = jax.tree.flatten(state)
+        self.load_state_piece(0, leaves, treedef)
+
+    def load_state_piece(self, first: int, leaves: list, treedef=None):
+        """Install leaves [first, first + len(leaves)) of a state dict
+        whose structure is `treedef` (given with leaf 0). Every array
+        goes straight to where the registered layout keeps it — on a
+        mesh each device gets its shard from the host array, nothing is
+        made whole on one chip first — and takes the old leaf's place at
+        once, so a restore never holds two states. True when whole."""
+        if first == 0:
+            if treedef is None:
+                raise ValueError("leaf 0 of a state comes with its treedef")
+            self._loading = _LoadPlan(self, treedef)
+        plan = self._loading
+        if plan is None or first != plan.next:
+            raise ValueError(
+                f"state piece starting at leaf {first} arrived out of "
+                "order: pieces come in order, from leaf 0")
+        plan.place(leaves)
+        if plan.next < plan.total:
+            return False
+        self._loading = None
+        plan.install()
+        return True
 
     def opt_shard_state(self) -> dict:
         """This rank's optimizer-state shard in the train/sharding.py
@@ -635,14 +677,13 @@ class TrainingOperator:
         counts = {"bytes": 0, "leaves": 0}
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
-            return self._opt_shard(counts, ctx)
+            return self._to_host(self._opt_shard_tree(), counts, ctx)
 
-    def _opt_shard(self, counts: dict, ctx) -> dict:
-        leaves = self._to_host(jax.tree.leaves(self.opt_state), counts, ctx)
+    def _opt_shard_tree(self) -> dict:
         return {"rank": self.world_rank, "world_size": self.world_size,
                 "span": (self._shard_lo, self._shard_hi),
                 "numel": self._numel, "pad_numel": self._pad_numel,
-                "leaves": leaves}
+                "leaves": jax.tree.leaves(self.opt_state)}
 
     def load_opt_shard(self, shard: dict):
         """Install a shard produced by opt_shard_state (or
@@ -660,6 +701,108 @@ class TrainingOperator:
         leaves = [jnp.asarray(x) if isinstance(x, np.ndarray) else x
                   for x in shard["leaves"]]
         self.opt_state = jax.tree.unflatten(self._opt_treedef, leaves)
+
+
+def _leased_chips() -> int:
+    """TPU chips the runtime leased to the actor this process hosts; 0
+    in a driver, a task worker or with no runtime at all."""
+    from ray_tpu._private import global_state
+
+    cw = global_state.get_core_worker()
+    return int(getattr(cw, "actor_resources", {}).get("TPU", 0))
+
+
+class _LoadPlan:
+    """Where each leaf of an incoming state dict goes in an operator:
+    (field, position among the field's own leaves), by tree order of the
+    incoming `treedef`. Leaves of other keys (`sharded_update`, a
+    geometry-matching `opt_shard`) are kept as they come."""
+
+    ARRAYS = ("params", "model_state", "opt_state")
+
+    def __init__(self, op: "TrainingOperator", treedef):
+        self.op, self.treedef = op, treedef
+        self.total, self.next = treedef.num_leaves, 0
+        index = jax.tree.unflatten(treedef, range(self.total))
+        if index.get("sharded_update") and not op._sharded:
+            raise ValueError(
+                "sharded checkpoint cannot load into an unsharded "
+                "trainer; construct Trainer(sharded=True) or load "
+                "the sharded manifest via Trainer.load()")
+        if op._sharded and "opt_state" in index:
+            raise ValueError(
+                "replicated checkpoint (full opt_state) cannot load "
+                "into a sharded-update trainer; re-save it sharded "
+                "or construct Trainer(sharded=False)")
+        self.where, self.fields, self.kept = {}, {}, {}
+        for key in self.ARRAYS:
+            if index.get(key) is None or (key == "opt_state"
+                                          and op._sharded):
+                continue
+            own, own_def = jax.tree.flatten(getattr(op, key))
+            at = jax.tree.leaves(index[key])
+            if len(at) != len(own):
+                raise ValueError(
+                    f"the state's {key} has {len(at)} leaves, this "
+                    f"operator's {len(own)}")
+            self.fields[key] = (own, own_def)
+            self.where.update((i, (key, pos)) for pos, i in enumerate(at))
+        for key in self.fields:
+            # the lists are the only holders now: a leaf's old buffers
+            # go when the new leaf takes its place, so a restore never
+            # holds two states on the devices
+            setattr(op, key, None)
+
+    def place(self, leaves: list):
+        placed = []
+        for i, x in enumerate(leaves, self.next):
+            key, pos = self.where.get(i, (None, None))
+            if key is None:
+                # a view into the arena dies with the call's arguments
+                self.kept[i] = (np.array(x) if isinstance(x, np.ndarray)
+                                else x)
+                continue
+            own = self.fields[key][0]
+            if isinstance(x, (np.ndarray, jax.Array)):
+                old = own[pos]
+                if isinstance(x, np.ndarray) and not x.flags.owndata \
+                        and jax.default_backend() == "cpu":
+                    # the CPU backend may alias a host buffer it is
+                    # given; a view into the arena must not live on
+                    x = np.array(x)
+                # mesh mode: laid out as registered (the step's program
+                # was built for exactly that layout)
+                x = (jax.device_put(x, old.sharding)
+                     if self.op._fused_out is not None
+                     and isinstance(old, jax.Array) else jnp.asarray(x))
+            own[pos] = x
+            placed.append(x)
+        self.next += len(leaves)
+        # a transfer in flight holds its host source — a view into the
+        # arena — so the piece is done only when its arrays are there
+        jax.block_until_ready(placed)
+
+    def install(self):
+        op = self.op
+        for key, (own, own_def) in self.fields.items():
+            setattr(op, key, jax.tree.unflatten(own_def, own))
+        rest = jax.tree.unflatten(self.treedef, [
+            self.kept.get(i) for i in range(self.total)])
+        if op._sharded:
+            # rebuild the local param shard from the restored params;
+            # the optimizer shard arrives separately (load_opt_shard,
+            # possibly resharded) unless this state happens to carry a
+            # geometry-matching shard (same-rank broadcast restore).
+            flat, _ = ravel_pytree(op.params)
+            op._param_shard = jnp.pad(
+                flat, (0, op._pad_numel - op._numel)
+            )[op._shard_lo:op._shard_hi]
+            sh = rest.get("opt_shard")
+            if (sh is not None and sh["world_size"] == op.world_size
+                    and sh["rank"] == op.world_rank):
+                op.load_opt_shard(sh)
+        op.epoch = rest["epoch"]
+        op.global_step = rest["global_step"]
 
 
 def _batch_size(batch) -> int:

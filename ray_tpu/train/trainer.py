@@ -14,6 +14,7 @@ from __future__ import annotations
 import collections
 import pickle
 import time
+import traceback
 import uuid
 
 import cloudpickle
@@ -104,7 +105,36 @@ class TrainWorker(CollectiveActorMixin):
     def state_dict(self):
         return self.operator.state_dict()
 
-    def load_state_dict(self, state):
+    def state_piece(self, index, usable, drop=()):
+        """One piece of the operator's state (`train/snapshot.py`). An
+        operator that only has `state_dict` gives its host tree, cut the
+        same way."""
+        from ray_tpu._private import global_state
+        from ray_tpu.train import snapshot
+
+        # no more than THIS node's store holds either
+        usable = min(usable, snapshot.usable_bytes(
+            global_state.require_core_worker()))
+        own = getattr(self.operator, "state_piece", None)
+        if own is not None:
+            return own(index, usable, drop)
+        state = self.operator.state_dict()
+        return snapshot.piece({k: v for k, v in state.items()
+                               if k not in drop}, index, usable)
+
+    def load_state_piece(self, first, leaves, treedef=None):
+        """The other direction: leaves [first, ...) of a state, in
+        order from 0; True once the state is whole and installed."""
+        own = getattr(self.operator, "load_state_piece", None)
+        if own is not None:
+            return own(first, leaves, treedef)
+        from ray_tpu.train import snapshot
+
+        if first == 0:
+            self._assembler = snapshot.Assembler()
+        state = self._assembler.add(first, leaves, treedef)
+        if state is None:
+            return False
         self.operator.load_state_dict(state)
         return True
 
@@ -224,7 +254,10 @@ class Trainer:
         mesh (parallel.mesh.fsdp_mesh) inside each worker and shards
         params over the fsdp axis — single-worker or multihost groups
         only (host-backend data parallelism would not sync mesh-local
-        shards).
+        shards). One worker (or a multihost group) whose
+        resources_per_worker grant it more than one TPU chip takes this
+        mode by itself, over the chips it holds: on a v5e host's four,
+        state and batch sharded four ways (operator.register).
 
         ingest: an ingest.IngestSpec — one DatasetShard actor per rank
         streaming prefetched batches through the object plane
@@ -408,15 +441,11 @@ class Trainer:
                 # driver ships ONE copy to rank 0; the group's shm/ring
                 # transport fans it out node-locally (the elastic-resize
                 # restore used to pickle the state num_workers times).
-                ray_tpu.get(
-                    self.workers[0].load_state_dict.remote(self._last_state),
-                    timeout=self._setup_timeout)
+                self._push_state(self.workers[:1], self._last_state)
                 ray_tpu.get([w.sync_state.remote(0) for w in self.workers],
                             timeout=self._setup_timeout)
             else:
-                ray_tpu.get([w.load_state_dict.remote(self._last_state)
-                             for w in self.workers],
-                            timeout=self._setup_timeout)
+                self._push_state(self.workers, self._last_state)
         if self._sharded and self._last_shards:
             shards = self._last_shards
             if len(shards) != num_workers:
@@ -578,8 +607,9 @@ class Trainer:
                               counts, ambient=True):
                 results = self._run_with_retries("train_epoch", num_steps,
                                                  counts)
+            counts = {}
             with tracing.span("train.snapshot", tracing.child_of_current(),
-                              ambient=True):
+                              counts, ambient=True):
                 # Two sets of buffers take turns: this call's views are
                 # copied into the set `_own` built two calls ago. Only
                 # trees `_own` built are in `_owned`, so a caller's
@@ -589,16 +619,14 @@ class Trainer:
                 # parts are installed, and the sets turned, only once
                 # both are whole: a copy that raises changes nothing.
                 newer, older = self._owned
-                state = ray_tpu.get(
-                    self.workers[0].state_dict.remote(), timeout=120)
-                if self._sharded:
-                    # the epoch-boundary snapshot is params (rank 0;
-                    # identical everywhere) + ALL optimizer shards — the
-                    # reshardable unit the elastic restore path consumes.
-                    # Rank 0's own shard goes before the copy: it was
-                    # never kept, and the tree then matches the spare's.
-                    state.pop("opt_shard", None)
-                state = _own(state, older[0])   # the views die here
+                # sharded: the epoch-boundary snapshot is params (rank
+                # 0; identical everywhere) + ALL optimizer shards — the
+                # reshardable unit the elastic restore path consumes.
+                # Rank 0's own shard is never kept, so it stays where it
+                # is and the tree matches the spare's.
+                state = self._pull_state(
+                    self.workers[0], older[0], counts,
+                    drop=("opt_shard",) if self._sharded else ())
                 shards = None
                 if self._sharded:
                     shards = _own(ray_tpu.get(
@@ -626,13 +654,90 @@ class Trainer:
     # checkpointing
     # ------------------------------------------------------------------
 
+    def _pull_state(self, worker, spare=None, counts: dict | None = None,
+                    drop=()) -> dict:
+        """`worker`'s training state, whole, in memory the driver owns.
+        It crosses the object plane as the pieces `train/snapshot.py`
+        cuts (a state the store holds is ONE piece): each goes
+        device→host and into the arena on the worker, out through `_own`
+        into `spare`'s leaves here (a tree an earlier pull built, see
+        `_own`), and is released. The driver asks ahead — the actor runs
+        the calls in order, so the worker brings the next piece to the
+        host while this side copies the last — as long as what is in
+        the store or on its way there never exceeds what it holds.
+        Nothing of `spare` or of the result is installed here: a piece
+        that raises leaves the caller's snapshot as it was."""
+        import jax
+
+        from ray_tpu.train import snapshot
+
+        usable = snapshot.usable_bytes(global_state.require_core_worker())
+        pending = collections.deque(
+            [(0, worker.state_piece.remote(0, usable, drop))])
+        asked, held, leaves, ranges, sizes = 1, 0, [], (), ()
+
+        def ask_ahead():
+            nonlocal asked, held
+            while asked < len(ranges) and held + sizes[asked] <= usable:
+                pending.append((asked, worker.state_piece.remote(
+                    asked, usable, drop)))
+                held += sizes[asked]
+                asked += 1
+
+        try:
+            while pending:
+                index, ref = pending.popleft()
+                piece = ray_tpu.get(ref, timeout=120)
+                del ref
+                if index == 0:
+                    treedef, ranges = piece["treedef"], piece["ranges"]
+                    sizes, held = piece["bytes"], piece["bytes"][0]
+                    spares, spare_def = jax.tree.flatten(spare)
+                    if spare_def != treedef:
+                        spares = []    # a changed tree: every leaf is new
+                ask_ahead()                 # what fits beside this piece
+                first, stop = ranges[index]
+                leaves.extend(_own(piece["leaves"], spares[first:stop]))
+                piece = None                # the views die here
+                held -= sizes[index]
+                ask_ahead()                 # ... and what fits without it
+        except BaseException as e:
+            # whoever keeps the exception keeps its frames: let go of
+            # what they pin in the arena (views, pieces asked ahead)
+            pending.clear()
+            piece = None
+            traceback.clear_frames(e.__traceback__)
+            raise
+        if counts is not None:
+            counts.update(pieces=len(ranges), bytes=sum(sizes))
+        return jax.tree.unflatten(treedef, leaves)
+
+    def _push_state(self, workers: list, state: dict):
+        """The other direction (`load_state_dict`, the elastic restore):
+        `state` to every worker in `workers`, cut the same way, one put
+        a piece whatever the number of workers; the next piece goes when
+        every worker has placed the last."""
+        import jax
+
+        from ray_tpu.train import snapshot
+
+        leaves, treedef = jax.tree.flatten(state)
+        usable = snapshot.usable_bytes(global_state.require_core_worker())
+        for first, stop in snapshot.plan(
+                [snapshot.leaf_bytes(x) for x in leaves], usable):
+            part = ray_tpu.put(leaves[first:stop])
+            ray_tpu.get(
+                [w.load_state_piece.remote(
+                    first, part, treedef if first == 0 else None)
+                 for w in workers], timeout=self._setup_timeout)
+            del part    # out of the arena before the next piece goes in
+
     def state_dict(self) -> dict:
-        return ray_tpu.get(self.workers[0].state_dict.remote(), timeout=120)
+        return self._pull_state(self.workers[0])
 
     def load_state_dict(self, state: dict):
         self._last_state = state
-        ray_tpu.get([w.load_state_dict.remote(state) for w in self.workers],
-                    timeout=120)
+        self._push_state(self.workers, state)
 
     def save(self, path: str) -> str:
         """Unsharded: one pickle, as before. Sharded: each worker's

@@ -93,9 +93,11 @@ def test_batchnorm_bwd_sums_resnet50_shapes(one_chip, on_tpu, m, c):
 
 
 @pytest.mark.parametrize("shape, batch_spec", [
-    ((4, 1), P("data")),             # Trainer(mesh_mode="fsdp") on 4 chips
+    # the Trainer's own mesh for one worker that leases a host's four
+    # chips (parallel.mesh.fsdp_mesh): (data=1, fsdp=4), rows over both
+    (None, P(("data", "fsdp"))),
     ((2, 2), P("data")),             # rows repeated over 'fsdp'
-    ((1, 4), P(("data", "fsdp"))),   # the smoke's four-chip phase
+    ((4, 1), P("data")),             # register(mesh=): pure data parallel
 ])
 def test_kernels_under_a_sharded_jit(topo, on_tpu, shape, batch_spec):
     """A Mosaic kernel cannot be partitioned by XLA; where the layer
@@ -103,8 +105,14 @@ def test_kernels_under_a_sharded_jit(topo, on_tpu, shape, batch_spec):
     batch_spec), each device runs it on its rows (ops/partition.py).
     Without that the compiler refuses the step."""
     from ray_tpu.ops import attention, layernorm, partition
+    from ray_tpu.parallel import mesh as meshlib
 
-    mesh = Mesh(np.array(topo.devices).reshape(shape), ("data", "fsdp"))
+    if shape is None:
+        mesh = meshlib.fsdp_mesh(topo.devices)
+        assert dict(mesh.shape) == {"data": 1, "fsdp": 4}
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(shape),
+                    ("data", "fsdp"))
     rows = NamedSharding(mesh, batch_spec)
     qkv = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=rows)
     w = jax.ShapeDtypeStruct((64,), jnp.bfloat16,

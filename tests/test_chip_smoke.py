@@ -73,14 +73,16 @@ def test_gpt_phase_tiny(declared_tpus, capsys):
 
 def test_mesh_phase_tiny(declared_tpus, capsys):
     """The four-chip phase on four of the virtual CPU devices: one
-    worker, state and batch sharded over a (data=1, fsdp=4) mesh,
-    against one device of the same process."""
+    worker with a lease of four chips, so the Trainer's derived
+    (data=1, fsdp=4) mesh shards state and batch, against one device of
+    the same process."""
     import chip_smoke
 
     out = chip_smoke.phase_mesh(TINY_GPT, chips=4)
     line = _line(capsys)
     assert line == json.loads(json.dumps(out))
-    assert line["shape"]["mesh"] == [1, 4] and line["device"]["count"] == 8
+    assert line["phase"] == "tiny_mesh"
+    assert line["mesh"] == [1, 4] and line["device"]["count"] == 8
     # parameters live sharded (gathered for use), gradients are combined
     assert line["collectives"]["all-gather"] > 0
     assert line["collectives"]["all-reduce"] > 0

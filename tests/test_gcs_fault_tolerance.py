@@ -105,3 +105,46 @@ def test_actor_restart_after_gcs_restart(gcs_cluster):
         except Exception:
             time.sleep(0.2)
     assert pid2 is not None and pid2 != pid1
+
+
+def test_the_checkers_own_stall_is_not_a_nodes_silence(monkeypatch):
+    """A host that stops as a whole (libtpu initialising four chips
+    freezes the machine for seconds) stops the GCS too: when the checker
+    wakes late it credits every node the time it overslept, instead of
+    declaring dead a raylet whose queued beats it has not read yet. A
+    node that really is silent still goes after the normal timeout."""
+    import asyncio
+    import types
+
+    from ray_tpu._private.config import Config
+    from ray_tpu.gcs import server
+
+    cfg = Config()
+    timeout = cfg.heartbeat_interval_s * cfg.num_heartbeats_timeout
+    clock = {"now": 100.0}
+    naps = iter([cfg.heartbeat_interval_s] * 3 + [timeout + 5.0]
+                + [cfg.heartbeat_interval_s] * 200)
+    removed = []
+
+    async def nap(_):
+        try:
+            clock["now"] += next(naps)
+        except StopIteration:
+            raise asyncio.CancelledError
+
+    async def remove(node_id, reason):
+        removed.append((node_id, clock["now"], reason))
+        del gcs.last_heartbeat[node_id]
+
+    monkeypatch.setattr(server.asyncio, "sleep", nap)
+    monkeypatch.setattr(server.time, "monotonic", lambda: clock["now"])
+    gcs = types.SimpleNamespace(config=cfg, nodes={}, _remove_node=remove,
+                                last_heartbeat={b"n": 100.0})
+    with pytest.raises(asyncio.CancelledError):
+        asyncio.run(server.GcsServer.heartbeat_checker(gcs))
+    (node, when, reason), = removed
+    # alive through the stall (which alone outlasted the timeout); dead
+    # one timeout of real silence later
+    stall_end = 100.0 + 3 * cfg.heartbeat_interval_s + timeout + 5.0
+    assert node == b"n" and reason == "heartbeat timeout"
+    assert stall_end + timeout - 2.0 < when <= stall_end + timeout + 1.0

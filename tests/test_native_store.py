@@ -92,6 +92,29 @@ def test_runtime_end_to_end_with_native_backend():
         ray_tpu.shutdown()
 
 
+def test_shutdown_unlinks_the_sessions_arena():
+    """`shutdown()` leaves nothing of the session in /dev/shm: the arena
+    file (2 GiB by default, resident as far as the run touched it) goes
+    with the raylet that served it. What the driver still holds of it —
+    an array it got zero-copy — stays readable until dropped."""
+    import os
+
+    import ray_tpu
+    from ray_tpu._private.object_store import default_store_root
+
+    session = ray_tpu.init(num_cpus=1)["session_dir"]
+    shm = os.path.dirname(default_store_root(session))
+    try:
+        kept = ray_tpu.get(ray_tpu.put(np.arange(1 << 20, dtype=np.int32)))
+        arenas = [os.path.join(d, f) for d, _, fs in os.walk(shm)
+                  for f in fs if f == "arena.rts"]
+        assert len(arenas) == 1 and os.path.getsize(arenas[0]) > 1 << 30
+    finally:
+        ray_tpu.shutdown()
+    assert not os.path.exists(shm)
+    assert int(kept[-1]) == (1 << 20) - 1     # the mapping outlives the file
+
+
 def test_pinned_read_survives_delete(store):
     """Reader pins: deleting (or overwriting) an object under a live
     zero-copy view must not corrupt the view; the block frees only when

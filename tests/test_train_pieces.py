@@ -1,0 +1,476 @@
+"""One worker that leases several chips shards by itself, and a training
+state crosses the object plane in pieces the arena can hold
+(train/operator.py `register`, train/snapshot.py, `Trainer._pull_state`
+/ `_push_state`). CPU: four of the virtual devices stand for a v5e
+host's four chips, the TPU resource is declared, the model is the tiny
+GPT with seeded weights, and the arena is 8 MiB so that a 20 MB state is
+several times what it holds."""
+
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import ray_tpu
+from ray_tpu._private import global_state
+from ray_tpu.train import Trainer, TrainingOperator, call_log
+from ray_tpu.train import operator as operator_mod
+from ray_tpu.train import snapshot
+
+ARENA = 8 << 20
+TINY = {"batch": 8, "seq": 128, "seed": 3}
+
+
+def _tiny_pieces(optimizer="adamw"):
+    import optax
+
+    from ray_tpu.models import transformer
+
+    cfg = transformer.TINY
+    tokens = jax.random.randint(jax.random.key(TINY["seed"] + 1),
+                                (TINY["batch"], TINY["seq"]), 0,
+                                cfg.vocab_size)
+    opt = optax.adamw(3e-4) if optimizer == "adamw" else optax.sgd(1.0)
+    return (lambda key: transformer.init(key, cfg),
+            lambda p, b: transformer.loss_fn(p, b, cfg), opt, tokens)
+
+
+class TinyGPT(TrainingOperator):
+    """The tiny GPT on one repeated seeded batch; no mesh argument."""
+
+    def setup(self, config):
+        init, loss_fn, opt, tokens = _tiny_pieces(
+            config.get("optimizer", "adamw"))
+        self.register(model_init=init, loss_fn=loss_fn, optimizer=opt,
+                      seed=TINY["seed"])
+        self.register_data(train_loader=[tokens] * 3)
+
+
+class Wide(TrainingOperator):
+    """Six (512, 512) weights, one (1024, 1024) and a bias under adam:
+    10 MiB of parameters, 30 MiB of state — four arenas' worth. The
+    4 MiB leaf is a piece of its own, and no two such pieces fit the
+    6.4 MiB the store holds: the next is asked when the last is let go."""
+
+    def setup(self, config):
+        import optax
+
+        def model_init(rng):
+            keys = jax.random.split(rng, 7)
+            p = {f"w{i}": jax.random.normal(keys[i], (512, 512)) / 512
+                 for i in range(6)}
+            p["big"] = jax.random.normal(keys[6], (1024, 1024)) / 512
+            p["b"] = jnp.zeros((512,))
+            return p
+
+        def loss_fn(params, batch):
+            x = batch
+            for i in range(6):
+                x = jnp.tanh(x @ params[f"w{i}"])
+            return jnp.mean((x @ params["big"][:512])[:, :512] ** 2
+                            + params["b"])
+
+        self.register(model_init=model_init, loss_fn=loss_fn,
+                      optimizer=optax.adam(1e-2),
+                      seed=config.get("seed", 0))
+        self.register_data(
+            train_loader=[np.ones((4, 512), np.float32)] * 2)
+
+
+class Small(TrainingOperator):
+    """One (256, 128) weight under adam: 0.4 MB of state, one piece."""
+
+    def setup(self, config):
+        import optax
+
+        self.register(
+            model_init=lambda rng: {"w": jax.random.normal(rng, (256, 128))},
+            loss_fn=lambda p, b: jnp.mean((b @ p["w"]) ** 2),
+            optimizer=optax.adam(1e-2))
+        self.register_data(
+            train_loader=[np.ones((4, 256), np.float32)] * 2)
+
+
+@pytest.fixture(scope="module")
+def host():
+    """A 'host' with four declared chips and an 8 MiB object store."""
+    ray_tpu.init(num_cpus=4, num_tpus=4, object_store_memory=ARENA)
+    try:
+        yield
+    finally:
+        ray_tpu.shutdown()
+
+
+def _span(entry, name):
+    return [s["attrs"] for s in entry["spans"] if s["name"] == name]
+
+
+def _bits(tree):
+    return [(x.dtype.str, x.shape, x.tobytes()) if isinstance(x, np.ndarray)
+            else x for x in jax.tree.leaves(tree)]
+
+
+# ---------------------------------------------------------------------
+# the mesh comes from the lease
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("chips, mesh", [(4, [1, 4]), (1, None)])
+def test_a_lease_of_several_chips_shards_without_mesh_mode(host, chips,
+                                                           mesh):
+    tr = Trainer(TinyGPT, num_workers=1, use_tpu=True,
+                 resources_per_worker={"CPU": 1, "TPU": chips})
+    try:
+        tr.train(num_steps=1)
+        (facts,) = _span(call_log()[-1], "train.dispatch")
+    finally:
+        tr.shutdown(force=True)
+    assert facts.get("mesh") == mesh and facts["chips"] == chips
+    whole, fullest = facts["state_bytes"], facts["state_bytes_fullest_chip"]
+    if chips == 1:      # untouched: the state whole on the one device
+        assert fullest == whole
+    else:               # every leaf of the tiny GPT divides by four
+        assert fullest <= 0.3 * whole
+
+
+def _operator(monkeypatch, chips, **config):
+    monkeypatch.setattr(operator_mod, "_leased_chips", lambda: chips)
+    return TinyGPT(config, 0, 1)
+
+
+def test_every_leaf_that_divides_is_split_along_its_first_such_dim(
+        monkeypatch):
+    op = _operator(monkeypatch, 4)
+    assert dict(op._mesh.shape) == {"data": 1, "fsdp": 4}
+    assert len(op._mesh.devices.flat) == 4      # of the 8 there are
+    state = jax.tree.leaves((op.params, op.opt_state))
+    assert len(state) > 20
+    for x in state:
+        dims = [d for d, n in enumerate(x.shape) if n % 4 == 0]
+        spec = tuple(x.sharding.spec) + (None,) * x.ndim
+        if dims:
+            assert spec[dims[0]] == "fsdp", (x.shape, x.sharding.spec)
+            assert x.addressable_shards[0].data.size * 4 == x.size
+        else:
+            assert x.is_fully_replicated
+    # the embedding goes along its width, not its 256 rows... here both
+    # divide; GPT-2's 50257 rows do not
+    from ray_tpu.parallel import mesh as meshlib
+    from jax.sharding import PartitionSpec as P
+
+    specs = meshlib.fsdp_param_specs(
+        {"wte": jax.ShapeDtypeStruct((50257, 1280), jnp.float32),
+         "wqkv": jax.ShapeDtypeStruct((36, 1280, 3840), jnp.float32),
+         "odd": jax.ShapeDtypeStruct((3, 5), jnp.float32)}, op._mesh)
+    assert specs == {"wte": P(None, "fsdp"), "odd": P(),
+                     "wqkv": P("fsdp", None, None)}
+    facts = op._layout_facts()
+    assert facts["state_bytes_fullest_chip"] <= 0.3 * facts["state_bytes"]
+    assert facts["mesh"] == [1, 4] and facts["chips"] == 4
+
+
+def _reference(init, tokens):
+    """Loss and gradients of the plain float32 reference."""
+    from benchmark.families import gpt_reference
+
+    rows, t = tokens.shape
+
+    def mean_nll(params):
+        return gpt_reference._nll_sum(params, tokens, n_head=4,
+                                      eps=1e-5) / (rows * (t - 1))
+
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(mean_nll)(init)
+
+
+def test_three_steps_on_the_derived_mesh_match_one_device_and_reference(
+        monkeypatch):
+    init_fn, _, _, tokens = _tiny_pieces()
+    mesh_op = _operator(monkeypatch, 4)
+    one = _operator(monkeypatch, 1)
+    assert one._mesh is None
+    mesh_losses = [mesh_op.train_batch(tokens)["train_loss"]
+                   for _ in range(3)]
+    one_losses = [one.train_batch(tokens)["train_loss"] for _ in range(3)]
+    # same program, same seed, same global batch: only the order in
+    # which bf16 partial sums are added differs between one device and
+    # four (measured here up to 2e-5); a gradient taken from one shard
+    # of the batch, or a reduction left out, moves steps 1 and 2 by
+    # 1e-3 or more
+    assert mesh_losses == pytest.approx(one_losses, rel=2e-4)
+    assert mesh_losses[2] < mesh_losses[0]
+    # step 0 against the float32 reference: bf16 compute, averaged over
+    # 8 x 127 targets (the benchmark's own check; 5e-5 on the chip at
+    # 32 x 1023 targets)
+    init = init_fn(jax.random.key(TINY["seed"]))
+    ref_loss, ref_grads = _reference(init, tokens)
+    assert mesh_losses[0] == pytest.approx(float(ref_loss), rel=5e-4)
+
+    # the gradients the FUSED sharded step applied: under SGD with a
+    # learning rate of 1 they are what one step took off the parameters
+    sgd = _operator(monkeypatch, 4, optimizer="sgd")
+    before = jax.tree.map(np.asarray, sgd.params)
+    sgd.train_batch(tokens)
+    applied = jax.tree.map(lambda a, b: a - np.asarray(b), before,
+                           sgd.params)
+    for (path, g), ref in zip(
+            jax.tree_util.tree_flatten_with_path(applied)[0],
+            jax.tree.leaves(ref_grads)):
+        ref = np.asarray(ref)
+        # bf16 forward and backward against float32: 2**-8 an operation,
+        # a few operations deep, relative to the leaf's norm (measured
+        # up to 1.3e-2); a sum where a mean belongs is off by 3.0, a
+        # shard's own rows alone by about 1
+        err = np.linalg.norm(g - ref) / np.linalg.norm(ref)
+        assert err < 4e-2, (jax.tree_util.keystr(path), err)
+
+
+def test_load_state_dict_puts_each_leaf_straight_onto_its_sharding(
+        monkeypatch):
+    op = _operator(monkeypatch, 4)
+    tokens = _tiny_pieces()[-1]
+    op.train_batch(tokens)
+    saved = op.state_dict()
+    layout = jax.tree.map(lambda x: x.sharding, (op.params, op.opt_state))
+    op.train_batch(tokens)
+    placed = []
+    real = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, s=None, **kw: placed.append(s) or real(x, s, **kw))
+    op.load_state_dict(saved)
+    monkeypatch.undo()
+    # every array went from the host to a NamedSharding of the mesh —
+    # none whole onto one device first
+    assert len(placed) == len(jax.tree.leaves(layout))
+    assert all(isinstance(s, jax.sharding.NamedSharding) for s in placed)
+    assert jax.tree.map(lambda x: x.sharding,
+                        (op.params, op.opt_state)) == layout
+    assert _bits(op.state_dict()) == _bits(saved)
+    built = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _s, **kw: built.append(event)
+        if event.endswith("backend_compile_duration") else None)
+    op.train_batch(tokens)      # the step's program still fits the layout
+    assert not built
+    # pieces out of order, or a stranger's tree, are refused whole
+    held = _bits(op.state_dict())
+    with pytest.raises(ValueError, match="out of order"):
+        op.load_state_piece(5, [np.zeros(3)])
+    with pytest.raises(ValueError, match="leaves"):
+        op.load_state_dict(dict(saved, params={"w": np.zeros(3)}))
+    assert _bits(op.state_dict()) == held
+
+
+def test_a_killed_worker_restores_onto_the_mesh_with_equal_losses(host):
+    def run(kill):
+        tr = Trainer(TinyGPT, num_workers=1, use_tpu=True, max_retries=2,
+                     resources_per_worker={"CPU": 1, "TPU": 4})
+        try:
+            losses = []
+            for call in range(4):
+                if kill and call == 2:
+                    ray_tpu.kill(tr.workers[0])
+                losses.append(tr.train(num_steps=2)["last_train_loss"])
+            attempts = _span(call_log()[-2], "train.epoch")[0]["attempts"]
+            facts = _span(call_log()[-1], "train.dispatch")[0]
+            return losses, attempts, facts
+        finally:
+            tr.shutdown(force=True)
+
+    straight, _, _ = run(kill=False)
+    resumed, attempts, facts = run(kill=True)
+    assert attempts == 2            # the third call met a dead worker
+    assert facts["mesh"] == [1, 4]  # ... and its successor shards too
+    assert resumed == straight      # bit for bit
+
+
+# ---------------------------------------------------------------------
+# a snapshot crosses in pieces
+# ---------------------------------------------------------------------
+
+def test_the_plan():
+    mib = 1 << 20
+    usable = 8 * mib
+    # what fits is one piece, whatever its leaves
+    assert snapshot.plan([3 * mib, 4 * mib, mib], usable) == [(0, 3)]
+    assert snapshot.plan([], usable) == [(0, 0)]
+    # else runs of whole leaves of at most a quarter of what fits; a
+    # larger leaf is a piece of its own
+    sizes = [mib, mib, mib, 3 * mib, 4, mib, 2 * mib, mib]
+    assert snapshot.plan(sizes, usable) == [
+        (0, 2), (2, 3), (3, 4), (4, 6), (6, 7), (7, 8)]
+    with pytest.raises(ValueError, match="larger_than|more than the "
+                                         "object store's arena"):
+        snapshot.plan([mib, 9 * mib], usable)
+
+
+def test_usable_bytes_reads_the_store(host):
+    cw = global_state.require_core_worker()
+    assert cw.store.stats()["capacity"] == ARENA
+    assert snapshot.usable_bytes(cw) == int(
+        ARENA * cw.config.object_spilling_threshold)
+
+
+@pytest.fixture(scope="module")
+def wide(host):
+    tr = Trainer(Wide, num_workers=1)
+    try:
+        yield tr
+    finally:
+        tr.shutdown(force=True)
+
+
+def test_a_state_several_arenas_large_crosses_in_pieces(wide):
+    used = global_state.require_core_worker().store.stats()["used"]
+    reused = []
+    for _ in range(4):
+        wide.train()
+        entry = call_log()[-1]
+        (snap,) = _span(entry, "train.snapshot")
+        copies = _span(entry, "train.snapshot.copy")
+        # one of each leaf span a piece (a piece under 100 KiB — the
+        # biases, the step counts — returns inline: no put, no get)
+        assert snap["pieces"] == len(copies) > 4
+        assert len(_span(entry, "train.snapshot.d2h")) == snap["pieces"]
+        for name in ("object.return_put", "object.get"):
+            assert 4 < len(_span(entry, name)) <= snap["pieces"], name
+        assert snap["bytes"] == sum(c["bytes"] for c in copies)
+        assert snap["bytes"] > 2 * ARENA
+        assert sum(s["bytes"] for s in _span(
+            entry, "train.snapshot.d2h")) == snap["bytes"]
+        reused.append(sum(c["reused_bytes"] for c in copies))
+    assert reused == [0, 0, snap["bytes"], snap["bytes"]]  # as PR 26 left it
+    state = wide._last_state
+    for x in jax.tree.leaves(state):
+        if isinstance(x, np.ndarray):
+            assert x.flags.owndata and x.flags.writeable
+    # bit-identical: pulled again (fresh buffers), the same bits
+    assert _bits(wide.state_dict()) == _bits(state)
+    # nothing of it is left in the arena
+    deadline = time.monotonic() + 10
+    store = global_state.require_core_worker().store
+    while store.stats()["used"] > used and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert store.stats()["used"] <= used
+
+
+def test_a_state_several_arenas_large_goes_back_in_pieces(wide):
+    wide.train()
+    saved = wide.state_dict()
+    wide.train()
+    assert _bits(wide.state_dict()) != _bits(saved)
+    wide.load_state_dict(saved)
+    assert wide._last_state is saved
+    assert _bits(wide.state_dict()) == _bits(saved)
+    # ... and the elastic restore takes the same road
+    ray_tpu.kill(wide.workers[0])
+    wide.train()
+    assert _span(call_log()[-1], "train.epoch")[0]["attempts"] == 2
+    assert wide._last_state["epoch"] == saved["epoch"] + 1
+
+
+def test_a_piece_that_raises_changes_nothing(wide, monkeypatch):
+    wide.train()
+    wide.train()
+    before, owned = wide._last_state, wide._owned
+    bits = _bits(before)
+    real, calls = np.copyto, []
+
+    def copyto(dst, src, *a, **kw):
+        calls.append(dst)
+        if len(calls) == 9:     # in a later piece: earlier ones are whole
+            raise MemoryError("injected: a piece's copy-out failed")
+        return real(dst, src, *a, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(np, "copyto", copyto)
+        with pytest.raises(MemoryError, match="injected"):
+            wide.train()
+    assert wide._last_state is before and wide._owned is owned
+    assert _bits(wide._last_state) == bits
+    wide.train()                # the half-written spare is a destination
+    copies = _span(call_log()[-1], "train.snapshot.copy")
+    assert all(c["reused_bytes"] == c["bytes"] for c in copies)
+    assert _bits(wide.state_dict()) == _bits(wide._last_state)
+
+
+def test_a_state_that_fits_crosses_in_one_piece(host):
+    tr = Trainer(Small, num_workers=1)
+    try:
+        for _ in range(3):
+            tr.train()
+        entry = call_log()[-1]
+        (snap,) = _span(entry, "train.snapshot")
+        (copy,) = _span(entry, "train.snapshot.copy")
+        assert snap == {"pieces": 1, "bytes": copy["bytes"]}
+        assert copy["reused_bytes"] == copy["bytes"] > 100 * 1024
+        for name in ("train.snapshot.d2h", "object.return_put",
+                     "object.get"):
+            assert len(_span(entry, name)) == 1, name
+    finally:
+        tr.shutdown(force=True)
+
+
+def test_the_benchmarks_new_readers(wide):
+    """`state_shard_share` and `snapshot_pieces` read the call log the
+    way the benchmark does; a log without the counts gives None."""
+    from benchmark.layer_metrics import snapshot_pieces, state_shard_share
+
+    wide.train()
+    wide.train()
+    log = call_log()[-2:]
+    host = {"attempted": len(call_log()), "calls": [
+        {"wall_s": s["end"] - s["start"]} for e in log
+        for s in e["spans"] if s["name"] == "train.call"]}
+    # positions 2.. of the log are the window's: drop what came before
+    import ray_tpu.train.trainer as trainer_mod
+    kept = list(trainer_mod._call_log)
+    try:
+        trainer_mod._call_log.clear()
+        trainer_mod._call_log.extend(kept[-4:])
+        host["attempted"] = 4
+        assert snapshot_pieces.read(host, None) > 4
+        assert state_shard_share.read(host, None) == 100.0
+    finally:
+        trainer_mod._call_log.clear()
+        trainer_mod._call_log.extend(kept)
+    assert snapshot_pieces.read({"attempted": 0, "calls": []}, None) is None
+
+
+def test_collective_time_share_reads_operations_by_name():
+    from benchmark.layer_metrics import collective_time_share
+
+    trace = {"busy_s": 2.0, "op_self_s": {
+        "all-gather.3": 0.1, "all-gather-start.1": 0.02,
+        "all-gather-done.1": 0.08, "all-reduce.7": 0.1,
+        "reduce-scatter.2": 0.05, "collective-permute-done": 0.03,
+        "all-to-all.1": 0.02, "async-collective-start": 0.01,
+        "async-collective-done": 0.03, "fusion.12": 1.0,
+        "flash_fwd.3": 0.5, "reduce.4": 0.1, "async-copy-done": 0.2}}
+    assert collective_time_share.read({}, trace) == pytest.approx(22.0)
+    assert collective_time_share.read(
+        {}, {"busy_s": 1.0, "op_self_s": {"fusion": 1.0}}) == 0.0
+    assert collective_time_share.read({}, None) is None
+
+
+def test_a_return_nobody_waits_for_leaves_the_store(host):
+    """The driver asks pieces ahead and may drop them unread (a piece
+    that raised): a plasma return whose last ref went while the task
+    ran is freed when the reply comes, not kept for ever."""
+    @ray_tpu.remote
+    def slow_megabyte():
+        time.sleep(0.5)
+        return np.ones(1 << 20, np.uint8)
+
+    store = global_state.require_core_worker().store
+    used = store.stats()["used"]
+    ref = slow_megabyte.remote()
+    del ref
+    deadline = time.monotonic() + 20
+    time.sleep(1.0)
+    while store.stats()["used"] > used and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert store.stats()["used"] <= used

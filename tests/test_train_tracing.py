@@ -108,7 +108,13 @@ def test_one_call_is_one_tree_across_driver_and_worker(wide):
     assert roots[0]["attrs"] == {"num_steps": 2, "workers": 1}
     dispatch = next(s for s in entry["spans"]
                     if s["name"] == "train.dispatch")
-    assert dispatch["attrs"] == {"steps": 2, "samples": 16}
+    held = dispatch["attrs"]["state_bytes"]   # whole, on the one device
+    assert held > 0 and dispatch["attrs"] == {
+        "steps": 2, "samples": 16, "chips": 1, "state_bytes": held,
+        "state_bytes_fullest_chip": held}
+    snapshot = next(s for s in entry["spans"]
+                    if s["name"] == "train.snapshot")
+    assert snapshot["attrs"] == {"pieces": 1, "bytes": held}
     epoch = next(s for s in entry["spans"] if s["name"] == "train.epoch")
     assert epoch["attrs"] == {"attempts": 1}
     assert LEAF not in _names(entry)   # the fine level is off
