@@ -31,6 +31,8 @@ from ray_tpu._private import tracing as _tracing
 
 # snapshot leaves above this get a span of their own at the fine level
 _LEAF_SPAN_BYTES = 1 << 20
+# joined leaves start on multiples of this in the staging area
+_STAGE_ALIGN = 64
 
 
 class TrainingOperator:
@@ -85,6 +87,7 @@ class TrainingOperator:
         self._registered = True
         self._facts = None      # _layout_facts, reckoned at the first epoch
         self._loading = None    # a state arriving in pieces (_LoadPlan)
+        self._stage = None      # the staging area (_staging), once needed
         self._loss_fn = loss_fn
         self._eval_fn = eval_fn
         self._optimizer = optimizer
@@ -577,11 +580,23 @@ class TrainingOperator:
     # checkpointing (reference: torch_trainer.py:543 save / :552 load)
     # ------------------------------------------------------------------
 
-    def _to_host(self, tree, counts: dict, ctx):
-        """`tree` with its arrays on the host, leaf by leaf; adds to
-        `counts` (`bytes`, `leaves`). At a trace's fine level every
-        leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
+    def _to_host(self, tree, counts: dict, ctx, room: int = 0):
+        """`tree` with its arrays on the host; adds to `counts`
+        (`bytes`, `leaves`, `staged_bytes`, `shards`). Every leaf's
+        transfer is started before the first is waited for. With `room`
+        (bytes, `_stage_room`) a leaf that has to be joined from shards
+        is written, shard by shard as they arrive, into the staging area
+        — pages this operator has written before, where `np.asarray`
+        joins into freshly mapped ones — and comes back as a VIEW of it,
+        good until the next call with `room`. Leaves with nothing to
+        join go through `np.asarray` either way. At a trace's fine level
+        every leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
+        leaves, treedef = jax.tree.flatten(tree)
+        _start_transfers(leaves)
+        at = 0
+
         def to_np(x):
+            nonlocal at
             if not isinstance(x, (jnp.ndarray, np.ndarray)):
                 return x
             # Cross-process (multihost) shards aren't addressable locally:
@@ -594,15 +609,37 @@ class TrainingOperator:
                 x = multihost_utils.process_allgather(x)
             counts["bytes"] += x.nbytes
             counts["leaves"] += 1
-            if ctx is None or not ctx.fine or x.nbytes < _LEAF_SPAN_BYTES:
+            if not (room and _is_joined(x)):
                 return np.asarray(x)
+            out = self._staging(room)[at:at + x.nbytes].view(
+                x.dtype).reshape(x.shape)
+            at += _padded(x.nbytes)
+            for shard in x.addressable_shards:
+                if shard.replica_id == 0:   # each index once
+                    out[shard.index] = np.asarray(shard.data)
+                    counts["shards"] += 1
+            counts["staged_bytes"] += x.nbytes
+            return out
+
+        def traced(x):
+            if (ctx is None or not ctx.fine
+                    or getattr(x, "nbytes", 0) < _LEAF_SPAN_BYTES):
+                return to_np(x)
             with _tracing.span(
                     "train.snapshot.d2h.leaf", _tracing.child(ctx),
                     {"bytes": x.nbytes, "dtype": str(x.dtype),
                      "shape": list(x.shape)}):
-                return np.asarray(x)
+                return to_np(x)
 
-        return jax.tree.map(to_np, tree)
+        return jax.tree.unflatten(treedef, [traced(x) for x in leaves])
+
+    def _staging(self, room: int) -> np.ndarray:
+        """The staging area: at least `room` bytes of host memory this
+        operator keeps, piece after piece and call after call, so the
+        pages a join writes are resident from the second use on."""
+        if self._stage is None or self._stage.nbytes < room:
+            self._stage = np.empty(room, np.uint8)
+        return self._stage
 
     def _state_tree(self, drop=()) -> dict:
         """The training state with its arrays where they are (on the
@@ -625,7 +662,7 @@ class TrainingOperator:
         return {k: v for k, v in out.items() if k not in drop}
 
     def state_dict(self) -> dict:
-        counts = {"bytes": 0, "leaves": 0}
+        counts = _d2h_counts()
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
             return self._to_host(self._state_tree(), counts, ctx)
@@ -633,15 +670,26 @@ class TrainingOperator:
     def state_piece(self, index: int, usable: int, drop=()) -> dict:
         """Piece `index` of the state as `train/snapshot.py` cuts it for
         a store that holds `usable` bytes: only this piece's leaves are
-        brought to the host (one `train.snapshot.d2h` span a piece)."""
+        brought to the host (one `train.snapshot.d2h` span a piece), and
+        the next piece's transfers are started before this returns, so
+        they run under the caller's put of this one.
+
+        Leaves joined from shards are views of the operator's staging
+        area: they are good until the NEXT `state_piece` call and no
+        longer. That is what the actor's lane gives: `TrainWorker` runs
+        one method at a time and the runtime has serialised and copied a
+        reply into the store before it starts the next. Who keeps what
+        he gets calls `state_dict` (or copies)."""
         from ray_tpu.train import snapshot as _snapshot
 
-        counts = {"bytes": 0, "leaves": 0}
+        counts = _d2h_counts()
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
-            return _snapshot.piece(
-                self._state_tree(drop), index, usable,
-                to_host=lambda part: self._to_host(part, counts, ctx))
+            whole = _snapshot.cut(self._state_tree(drop), usable)
+            part = self._to_host(whole.part(index), counts, ctx,
+                                 room=_stage_room(whole))
+            _start_transfers(whole.part(index + 1))
+        return whole.reply(index, part)
 
     def load_state_dict(self, state: dict):
         leaves, treedef = jax.tree.flatten(state)
@@ -674,7 +722,7 @@ class TrainingOperator:
         """This rank's optimizer-state shard in the train/sharding.py
         dict format (numpy leaves) — the unit of sharded checkpoints and
         elastic resharding."""
-        counts = {"bytes": 0, "leaves": 0}
+        counts = _d2h_counts()
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
             return self._to_host(self._opt_shard_tree(), counts, ctx)
@@ -701,6 +749,41 @@ class TrainingOperator:
         leaves = [jnp.asarray(x) if isinstance(x, np.ndarray) else x
                   for x in shard["leaves"]]
         self.opt_state = jax.tree.unflatten(self._opt_treedef, leaves)
+
+
+def _d2h_counts() -> dict:
+    """What a `train.snapshot.d2h` span counts: `staged_bytes` of
+    `bytes` were joined from `shards` shards in the staging area."""
+    return {"bytes": 0, "leaves": 0, "staged_bytes": 0, "shards": 0}
+
+
+def _is_joined(x) -> bool:
+    """A leaf whose host copy is put together from several shards (one
+    device, or every device holding it whole: nothing to join)."""
+    return (isinstance(x, jax.Array) and x.is_fully_addressable
+            and not x.is_fully_replicated)
+
+
+def _start_transfers(leaves: list):
+    """Start the device→host copy of every array in `leaves` (of a
+    sharded one: of each of its shards) without waiting for any."""
+    for x in leaves:
+        if isinstance(x, jax.Array) and (x.is_fully_addressable
+                                         or x.is_fully_replicated):
+            x.copy_to_host_async()
+
+
+def _padded(nbytes: int) -> int:
+    return -(-nbytes // _STAGE_ALIGN) * _STAGE_ALIGN
+
+
+def _stage_room(whole) -> int:
+    """Bytes the staging area needs for any piece of `whole` (a
+    `snapshot.Cut`): the joined leaves of its fullest piece, each on an
+    aligned offset. 0 when no leaf has anything to join."""
+    joined = [_padded(size) if _is_joined(x) else 0
+              for x, size in zip(whole.leaves, whole.sizes)]
+    return max(sum(joined[a:b]) for a, b in whole.ranges)
 
 
 def _leased_chips() -> int:

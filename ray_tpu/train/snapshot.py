@@ -14,6 +14,8 @@ code. The worker's side of both directions is here; the driver's is
 
 from __future__ import annotations
 
+from typing import Any, NamedTuple
+
 import jax
 import numpy as np
 
@@ -63,23 +65,47 @@ def plan(sizes: list[int], usable: int) -> list[tuple[int, int]]:
     return pieces
 
 
-def piece(tree, index: int, usable: int, to_host=None) -> dict:
-    """Piece `index` of `tree` (a state dict): its leaves, `first` (the
-    tree-order index of the first one) and, with piece 0, the plan:
-    `treedef` and every piece's leaf range and `bytes`.
-    `to_host(leaves) -> leaves` brings just this piece's leaves to the
-    host; the plan reads sizes only."""
+class Cut(NamedTuple):
+    """A state dict cut for a store that holds `usable` bytes: its
+    leaves in tree order (where they are: nothing is moved), their
+    sizes, and the leaf range of every piece."""
+
+    leaves: list
+    treedef: Any
+    sizes: list[int]
+    ranges: list[tuple[int, int]]
+
+    def part(self, index: int) -> list:
+        """The leaves of piece `index`; none past the last piece."""
+        if index >= len(self.ranges):
+            return []
+        first, stop = self.ranges[index]
+        return self.leaves[first:stop]
+
+    def reply(self, index: int, leaves: list) -> dict:
+        """What crosses for piece `index`: `leaves` (the piece's, on
+        the host), `first` (the tree-order index of the first one) and,
+        with piece 0, the plan: `treedef` and every piece's leaf range
+        and `bytes`."""
+        out = {"first": self.ranges[index][0], "leaves": leaves}
+        if index == 0:
+            out["treedef"] = self.treedef
+            out["ranges"] = self.ranges
+            out["bytes"] = [sum(self.sizes[a:b]) for a, b in self.ranges]
+        return out
+
+
+def cut(tree, usable: int) -> Cut:
     leaves, treedef = jax.tree.flatten(tree)
     sizes = [leaf_bytes(x) for x in leaves]
-    ranges = plan(sizes, usable)
-    first, stop = ranges[index]
-    part = leaves[first:stop]
-    out = {"first": first, "leaves": to_host(part) if to_host else part}
-    if index == 0:
-        out["treedef"] = treedef
-        out["ranges"] = ranges
-        out["bytes"] = [sum(sizes[a:b]) for a, b in ranges]
-    return out
+    return Cut(leaves, treedef, sizes, plan(sizes, usable))
+
+
+def piece(tree, index: int, usable: int) -> dict:
+    """Piece `index` of `tree`, a state dict whose leaves are on the
+    host already (`Cut.reply`)."""
+    whole = cut(tree, usable)
+    return whole.reply(index, whole.part(index))
 
 
 class Assembler:

@@ -357,6 +357,191 @@ def test_a_state_several_arenas_large_crosses_in_pieces(wide):
     assert store.stats()["used"] <= used
 
 
+def test_nothing_is_staged_on_one_device(wide):
+    """One device: no leaf has shards to join, so no staging area."""
+    wide.train()
+    parts = _span(call_log()[-1], "train.snapshot.d2h")
+    assert len(parts) > 4
+    assert all(p["staged_bytes"] == 0 and p["shards"] == 0 for p in parts)
+    assert sum(p["bytes"] for p in parts) > 2 * ARENA
+
+
+# ---------------------------------------------------------------------
+# a sharded leaf is joined in memory the operator keeps
+# ---------------------------------------------------------------------
+
+def _traced_d2h(call):
+    """`call()` and the counts of the `train.snapshot.d2h` spans under it."""
+    from ray_tpu._private import tracing
+
+    root = tracing.always_trace()
+    with tracing.open_tree(root) as rows, tracing.use(root):
+        out = call()
+    return out, [row[3] for row in rows if row[0] == "train.snapshot.d2h"]
+
+
+def _pull(op, usable=1 << 30):
+    """Every piece of `op`'s state in order, as the driver asks them;
+    each piece's leaves are COPIED on arrival (what `_pack_returns`
+    does), and the views themselves are kept to be looked at later."""
+    copies, views, index, pieces = [], [], 0, 1
+    while index < pieces:
+        piece = op.state_piece(index, usable)
+        if index == 0:
+            pieces = len(piece["ranges"])
+        views.append(piece["leaves"])
+        copies.extend(np.array(x) if isinstance(x, np.ndarray) else x
+                      for x in piece["leaves"])
+        index += 1
+    return copies, views
+
+
+def _sharded_cases(mesh):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    rng = np.random.default_rng(7)
+
+    def put(shape, spec, dtype=np.float32, on=mesh):
+        host = (rng.standard_normal(shape) * 100).astype(dtype)
+        return jax.device_put(host, NamedSharding(on, spec))
+
+    two_by_four = Mesh(np.array(jax.devices()).reshape(2, 4),
+                       ("data", "fsdp"))
+    return {
+        "first_dim": ({"w": put((8, 6, 5), P("fsdp"))}, 4),
+        "later_dim": ({"w": put((3, 5, 8), P(None, None, "fsdp")),
+                       "v": put((6, 12), P(None, "fsdp"))}, 8),
+        # sizes that are no multiple of the staging area's alignment,
+        # dtypes of 1, 2 and 4 bytes, side by side in one piece
+        "odd_sizes": ({"a": put((4, 3), P("fsdp")),
+                       "b": put((8, 5), P("fsdp"), jnp.bfloat16),
+                       "c": put((4, 7), P("fsdp"), np.int8),
+                       "d": put((12,), P("fsdp")),
+                       "e": put((5, 4, 3), P(None, "fsdp"))}, 20),
+        # every index held twice: each is written once
+        "partly_replicated": (
+            {"w": put((8, 9), P("fsdp"), on=two_by_four)}, 4),
+        "replicated": ({"w": put((8, 6), P()),
+                        "host": np.arange(5.0)}, 0),
+    }
+
+
+@pytest.mark.parametrize("case", ["first_dim", "later_dim", "odd_sizes",
+                                  "partly_replicated", "replicated"])
+def test_a_joined_leaf_equals_np_asarray_bit_for_bit(monkeypatch, case):
+    op = _operator(monkeypatch, 4)
+    params, shards = _sharded_cases(op._mesh)[case]
+    op.params, op.model_state, op.opt_state = params, {}, {}
+    piece, (counts,) = _traced_d2h(lambda: op.state_piece(0, 1 << 30))
+    arrays = jax.tree.leaves(params)
+    want = [np.asarray(x) for x in arrays]
+    got = piece["leaves"][-len(arrays):]    # after epoch, global_step
+    assert _bits(got) == _bits(want)
+    joined = [x.nbytes for x in arrays
+              if isinstance(x, jax.Array) and not x.is_fully_replicated]
+    assert counts["bytes"] == sum(x.nbytes for x in arrays)
+    assert counts["staged_bytes"] == sum(joined)
+    assert counts["shards"] == shards
+    if case == "replicated":    # nothing to join: no staging area at all
+        assert op._stage is None
+        return
+    for x, leaf in zip(arrays, got):
+        assert np.shares_memory(leaf, op._stage)
+        assert leaf.flags.aligned and leaf.dtype == x.dtype
+    # side by side, not on top of each other
+    assert sum(np.shares_memory(a, b) for a in got for b in got) == len(got)
+
+
+def test_state_dict_is_the_callers_whatever_is_pulled_later(monkeypatch):
+    op = _operator(monkeypatch, 4)
+    tokens = _tiny_pieces()[-1]
+    op.train_batch(tokens)
+    kept = op.state_dict()
+    bits = _bits(kept)
+    for _ in range(2):          # two later pulls, of a state that moved
+        op.train_batch(tokens)
+        copies, _ = _pull(op, usable=1 << 19)
+        assert _bits(copies) != bits
+    assert op._stage is not None
+    assert _bits(kept) == bits
+    for x in jax.tree.leaves(kept):
+        if isinstance(x, np.ndarray):
+            assert not np.shares_memory(x, op._stage)
+
+
+def test_one_staging_area_serves_every_piece_of_every_call(monkeypatch):
+    from ray_tpu.train.operator import _stage_room
+
+    op = _operator(monkeypatch, 4)
+    tokens = _tiny_pieces()[-1]
+    usable = 1 << 19            # the 1.5 MB state in several pieces
+    areas = []
+    for _ in range(3):
+        op.train_batch(tokens)
+        (copies, views), parts = _traced_d2h(lambda: _pull(op, usable))
+        assert len(parts) == len(views) > 4
+        areas.append(op._stage)
+        # what each piece was when it arrived is what state_dict gives
+        assert _bits(copies) == _bits(op.state_dict())
+        # ... and every piece's views lie in the one area, so an early
+        # piece's are NOT good after a later piece (the lifetime rule)
+        arrays = [x for leaves in views for x in leaves
+                  if isinstance(x, np.ndarray)]
+        staged = [x for x in arrays if np.shares_memory(x, op._stage)]
+        # all but the optimizer's step counts (scalars: nothing to join)
+        assert sum(x.nbytes for x in arrays if x.ndim) == sum(
+            x.nbytes for x in staged) > 1 << 20
+        assert _bits([x for v in views for x in v]) != _bits(copies)
+        assert sum(p["staged_bytes"] for p in parts) == sum(
+            x.nbytes for x in staged)
+        assert all(p["staged_bytes"] <= p["bytes"] for p in parts)
+    assert areas[1] is areas[0] and areas[2] is areas[0]
+    from ray_tpu.train import snapshot as snapshot_mod
+
+    room = _stage_room(snapshot_mod.cut(op._state_tree(), usable))
+    assert areas[0].nbytes == room <= usable
+
+
+def test_a_sharded_state_of_several_pieces_reaches_the_driver_intact(
+        host, monkeypatch):
+    """The `wide` case on a lease of four chips: 30 MiB of four-way
+    sharded leaves through an 8 MiB arena and ONE staging area. Compared
+    only after the last piece arrived, so a piece written over before
+    its put was done would show."""
+    tr = Trainer(Wide, num_workers=1, use_tpu=True, config={"seed": 5},
+                 resources_per_worker={"CPU": 1, "TPU": 4})
+    try:
+        for _ in range(3):
+            tr.train()
+        entry = call_log()[-1]
+        pulled = tr._last_state
+        again = tr.state_dict()
+    finally:
+        tr.shutdown(force=True)
+    (snap,) = _span(entry, "train.snapshot")
+    parts = _span(entry, "train.snapshot.d2h")
+    assert snap["pieces"] == len(parts) > 4
+    assert sum(p["bytes"] for p in parts) == snap["bytes"] > 2 * ARENA
+    # the same operator in this process, on four devices too
+    monkeypatch.setattr(operator_mod, "_leased_chips", lambda: 4)
+    ref = Wide({"seed": 5}, 0, 1)
+    for _ in range(3):
+        ref.train_epoch()
+    arrays = [x for x in jax.tree.leaves(ref._state_tree())
+              if isinstance(x, jax.Array)]
+    joined = [x for x in arrays if not x.is_fully_replicated]
+    assert len(joined) >= 24    # weights and both moments; not the counts
+    # staged + unstaged = bytes
+    staged = sum(p["staged_bytes"] for p in parts)
+    assert staged == sum(x.nbytes for x in joined)
+    assert snap["bytes"] - staged == sum(
+        x.nbytes for x in arrays if x.is_fully_replicated)
+    assert sum(p["shards"] for p in parts) == 4 * len(joined)
+    want = _bits(ref.state_dict())
+    assert _bits(pulled) == want
+    assert _bits(again) == want
+
+
 def test_a_state_several_arenas_large_goes_back_in_pieces(wide):
     wide.train()
     saved = wide.state_dict()
