@@ -41,8 +41,6 @@ import math
 import sys
 import time
 
-import bench
-
 RESNET50 = {"model": "resnet50", "batch": 256, "hw": 224,
             "stem": "standard", "bn": "xla"}
 GPT2_SMALL = {"model": "gpt2_small", "batch": 8, "seq": 1024}
@@ -141,7 +139,7 @@ class _Measured:
             # programs these steps had to compile (or load from JAX's
             # persistent cache): none once a step's shapes are warm
             "programs_built": programs,
-            "device": bench._device_facts(),
+            "device": _device_facts(),
             # the training state as a whole, and the part of it the
             # layout puts on one device
             "state_bytes": sum(x.nbytes for x in state),
@@ -161,15 +159,110 @@ class _Measured:
         }
 
 
+# ResNet-50 pieces (synthetic batch, operator, raw-jit control); they were
+# bench.py's until that file went (PR 29)
+
+def _make_batch(cfg_dict):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import resnet
+
+    cfg = (resnet.resnet50(stem_mode=cfg_dict.get("stem", "standard"),
+                           bn_mode=cfg_dict.get("bn", "xla"))
+           if cfg_dict["model"] == "resnet50"
+           else resnet.resnet18(num_classes=10, small_images=True))
+    key = jax.random.key(0)
+    images = jax.random.normal(
+        key, (cfg_dict["batch"], cfg_dict["hw"], cfg_dict["hw"], 3),
+        jnp.bfloat16)
+    labels = jax.random.randint(key, (cfg_dict["batch"],), 0,
+                                cfg.num_classes)
+    return cfg, (images, labels)
+
+
+class _Repeat:
+    """Synthetic loader: yields the same device-resident batch N times."""
+
+    def __init__(self, batch, n):
+        self.batch, self.n = batch, n
+
+    def __iter__(self):
+        for _ in range(self.n):
+            yield self.batch
+
+
+def _operator_cls():
+    from ray_tpu.train import TrainingOperator
+
+    class Op(TrainingOperator):
+        def setup(self, config):
+            import optax
+
+            from ray_tpu.models import resnet
+
+            cfg, batch = _make_batch(config)
+            self.register(
+                model_init=lambda key: resnet.init(key, cfg),
+                loss_fn=lambda p, s, b: resnet.loss_fn(
+                    p, s, b[0], b[1], cfg),
+                optimizer=optax.sgd(0.1, momentum=0.9),
+                stateful=True)
+            self.register_data(
+                train_loader=_Repeat(batch, config["steps"] + 4))
+
+        def train_epoch(self, num_steps=None, profile_dir=None):
+            # the driver never touches a JAX backend: device facts are
+            # read here, in the chip-owning actor, and ride the result
+            out = super().train_epoch(num_steps, profile_dir=profile_dir)
+            out["device"] = _device_facts()
+            return out
+
+    return Op
+
+
+def _device_facts() -> dict:
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count()}
+
+
+def _raw_step(cfg_d):
+    """The operator's step with no framework around it:
+    (jitted step, [params, state, opt_state], batch)."""
+    import jax
+
+    import optax
+
+    from ray_tpu.models import resnet
+
+    cfg, batch = _make_batch(cfg_d)
+    params, state = resnet.init(jax.random.key(0), cfg)
+    opt = optax.sgd(0.1, momentum=0.9)
+
+    def step(params, state, opt_state, batch):
+        (loss, new_state), grads = jax.value_and_grad(
+            resnet.loss_fn, has_aux=True)(
+                params, state, batch[0], batch[1], cfg)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        return params, new_state, opt_state, loss
+
+    return (jax.jit(step, donate_argnums=(0, 1, 2)),
+            [params, state, opt.init(params)], batch)
+
+
 def _resnet_operator():
-    class ResNetSmoke(_Measured, bench._operator_cls()):
+    class ResNetSmoke(_Measured, _operator_cls()):
         def validate(self, num_steps=None):
             """Raw-jit control: the same step with no framework around
             it, in THIS process (a second chip-owning process could not
             get the chip)."""
             import jax
 
-            step, carry, batch = bench._raw_step(self.config)
+            step, carry, batch = _raw_step(self.config)
             step_s, losses = [], []
             for _ in range(1 + num_steps):  # the first one compiles
                 t0 = time.perf_counter()
@@ -193,7 +286,7 @@ def _gpt_operator():
             self.register(model_init=model_init, loss_fn=loss_fn,
                           optimizer=optimizer, seed=config["seed"])
             self.register_data(
-                train_loader=bench._Repeat(tokens, config["steps"]))
+                train_loader=_Repeat(tokens, config["steps"]))
 
         def validate(self, num_steps=None):
             """The same steps on ONE device of this process (the mesh

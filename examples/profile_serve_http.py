@@ -4,15 +4,13 @@ Builds the rate ladder the 1-core qps gap analysis needs (PERF.md "Serve
 HTTP path"), every step measured in THIS process within one window:
 
   1. raw aiohttp echo        — the Python HTTP stack ceiling, no ray
-  2. router-only control     — assign_async + await ref, no HTTP
+  2. router-only control     — call_async, no HTTP
   3. in-process proxy        — real Router + aiohttp handler on the MAIN
                                thread, cProfile enabled on that thread so
                                the profile shows where request handling
                                actually spends its time (handler, router
                                bridge, result delivery, response encode)
-  4. full Serve HTTP         — out-of-process proxy actor, optimized
-                               (call_async) AND legacy-path control
-                               (assign_async + wrap_future), interleaved
+  4. full Serve HTTP         — out-of-process proxy actor (call_async)
 
 Run:  JAX_PLATFORMS=cpu python examples/profile_serve_http.py
 """
@@ -125,8 +123,7 @@ def router_only_qps(router):
             async def worker():
                 n = 0
                 while time.perf_counter() < stop:
-                    ref = await router.assign_async(None)
-                    await ref
+                    await router.call_async(None)
                     n += 1
                 return n
 
@@ -231,24 +228,13 @@ def main():
     report, layers = summarize_profile(res["prof"])
     ladder["inprocess_proxy_tottime_by_layer_s"] = layers
 
-    # full path: optimized proxy from serve.start, legacy control proxy
-    from ray_tpu.serve.http_proxy import HTTPProxy
-
-    legacy = ray_tpu.remote(HTTPProxy).remote(
-        client._controller, "127.0.0.1", 0, False, True)
-    legacy_port = ray_tpu.get(legacy.port.remote(), timeout=60)
+    # full path: the proxy actor from serve.start
     http_load(pool, client.http_port, 0.2)
-    http_load(pool, legacy_port, 0.2)
-    opt, leg = [], []
-    for _ in range(REPS):
-        opt.append(http_load(pool, client.http_port))
-        leg.append(http_load(pool, legacy_port))
-    ladder["serve_http_qps"] = round(median(opt), 1)
-    ladder["serve_http_qps_legacy_path"] = round(median(leg), 1)
+    ladder["serve_http_qps"] = round(median(
+        [http_load(pool, client.http_port) for _ in range(REPS)]), 1)
 
     print(report)
     print(json.dumps(ladder, indent=1))
-    ray_tpu.kill(legacy)
     pool.shutdown()
     serve.shutdown()
     ray_tpu.shutdown()

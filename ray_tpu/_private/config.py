@@ -103,14 +103,11 @@ class Config:
     # (reference: direct_task_transport.h max_tasks_in_flight_per_worker).
     max_tasks_in_flight_per_worker: int = 10
     # Raylet→raylet lease spillback: a raylet that can't grant FORWARDS
-    # the lease request to its chosen peer (hop-capped, cycle-guarded)
-    # and relays the grant, instead of bouncing the owner back out for
-    # another round trip per hop. False restores the owner-mediated
-    # redial chain (the legacy A/B arm; also RAY_TPU_SPILLBACK_LEGACY=1).
-    lease_spillback_forwarding: bool = True
-    # Max raylet hops a forwarded lease request may chain through before
-    # the last raylet queues it locally (stops ping-pong on a saturated
-    # cluster; matches the legacy hop cap).
+    # the lease request to its chosen peer (cycle-guarded) and relays
+    # the grant, instead of bouncing the owner back out for another
+    # round trip per hop. Max raylet hops a forwarded request may chain
+    # through before the last raylet queues it locally (stops ping-pong
+    # on a saturated cluster).
     lease_spillback_max_hops: int = 3
     # Lease pre-warm: max leases asked for in one batched
     # request_worker_lease RPC (soft target is ceil(queue / in-flight
@@ -200,10 +197,6 @@ class Config:
         # not already pin the knob, so _system_config stays authoritative).
         if "gcs_shards" not in merged and os.environ.get("RAY_TPU_GCS_SHARDS"):
             merged["gcs_shards"] = int(os.environ["RAY_TPU_GCS_SHARDS"])
-        if ("lease_spillback_forwarding" not in merged
-                and os.environ.get("RAY_TPU_SPILLBACK_LEGACY", "")
-                not in ("", "0", "false", "False")):
-            merged["lease_spillback_forwarding"] = False
         known = {f.name for f in dataclasses.fields(cls)}
         for key, value in merged.items():
             if key not in known:

@@ -94,13 +94,6 @@ M_E2E_S = _stats.Histogram(
     "submit -> reply handled (owner side)")
 
 
-def _legacy_task_path() -> bool:
-    """RAY_TPU_TASK_LEGACY=1 re-enables the round-7 task path (per-reply
-    call_soon_threadsafe, per-task profile-flush submit, one-at-a-time
-    hard lease requests, per-push lease-return timers, uncached specs) —
-    the control arm of the microbenchmark's interleaved A/B."""
-    return os.environ.get("RAY_TPU_TASK_LEGACY", "") not in ("", "0")
-
 def _collective_debug() -> list[dict]:
     """Debug rows for this process's live collective groups — only when
     the collective layer was actually imported (a snapshot must never be
@@ -246,7 +239,6 @@ class CoreWorker:
         self._pending_since: dict[tuple, float] = {}
         self._soft_backoff: dict[tuple, float] = {}
         self._lease_reaper_running = False
-        self._legacy = _legacy_task_path()
 
         # actors
         self.actor_clients: dict[bytes, _ActorClient] = {}
@@ -315,7 +307,7 @@ class CoreWorker:
         self.server = rpc.Server(self._handlers(), name=f"cw-{mode}")
         self.address = ""
 
-        if mode == WORKER and not self._legacy:
+        if mode == WORKER:
             self._start_task_channel()
         self._connect(raylet_address, gcs_address)
         serialization.set_context(None, None)
@@ -327,16 +319,10 @@ class CoreWorker:
     # ------------------------------------------------------------------
 
     def _handlers(self):
-        if self._legacy:
-            push_task = self.h_push_task_legacy
-            push_actor_task = self.h_push_actor_task_legacy
-        else:
-            push_task = self.h_push_task
-            push_actor_task = self.h_push_actor_task
         return {
-            "push_task": push_task,
+            "push_task": self.h_push_task,
             "create_actor": self.h_create_actor,
-            "push_actor_task": push_actor_task,
+            "push_actor_task": self.h_push_actor_task,
             "get_object": self.h_get_object,
             "recover_object": self.h_recover_object,
             "add_borrow": self.h_add_borrow,
@@ -1130,9 +1116,9 @@ class CoreWorker:
     def _maybe_request_leases(self, key):
         """Request leases ahead of demand, up to a soft target of
         ceil(outstanding work / max_tasks_in_flight_per_worker) leases —
-        the round-7 path requested exactly one lease at a time, each
-        granted only after the previous grant's drain, which serialized
-        burst ramp-up behind worker-spawn latency. One batched request
+        one lease at a time, each granted only after the previous
+        grant's drain, would serialize burst ramp-up behind worker-spawn
+        latency. One batched request
         RPC is outstanding per key at a time; while ≥1 lease is already
         working the request is SOFT (the raylet grants only from idle
         workers, never spawning), escalating to a hard request when the
@@ -1306,63 +1292,11 @@ class CoreWorker:
                     out.append(g)
         return out
 
-    async def _maybe_request_lease(self, key, spec):
-        # Round-7 control arm (RAY_TPU_TASK_LEGACY): one outstanding
-        # single-lease hard request per scheduling key at a time.
-        if self._lease_requests.get(key, 0) > 0:
-            return
-        self._lease_requests[key] = 1
-        try:
-            target = self.raylet
-            hops = 0
-            attempts = 0
-            while True:
-                M_LEASE_RPCS.inc()
-                reply = await target.call("request_worker_lease",
-                                          {"spec": spec, "hops": hops})
-                if reply.get("spillback"):
-                    target = await self._peer(reply["spillback"])
-                    hops = int(reply.get("hops", hops + 1))
-                    continue
-                claimed = await self._claim_forwarded_grants([reply])
-                if claimed:
-                    reply = claimed[0]
-                    break
-                # adoption raced the granting raylet's unadopted deadline
-                # (or its dial transiently failed): the lease is back in
-                # that raylet's idle pool — re-request instead of failing
-                # a healthy cluster's tasks
-                attempts += 1
-                if attempts >= 3:
-                    raise exc.WorkerCrashedError(
-                        "spillback lease reclaimed before adoption "
-                        f"({attempts} attempts)")
-                target = self.raylet
-                hops = 0
-                await asyncio.sleep(0.1 * attempts)
-            conn = await self._peer(reply["worker_address"])
-            lease = _Lease(reply["lease_id"], reply["worker_id"],
-                           reply["worker_address"], conn,
-                           reply.pop("_raylet_conn", None) or target)
-            self.leases.setdefault(key, []).append(lease)
-        except Exception as e:
-            pending = self._pending_by_key.pop(key, [])
-            for p in pending:
-                self._fail_task(p, exc.WorkerCrashedError(
-                    f"lease request failed: {e}"), release=True)
-            return
-        finally:
-            self._lease_requests[key] = 0
-        await self._drain_pending(key)
-
     async def _drain_pending(self, key, inline_ok=True):
         pending = self._pending_by_key.get(key, [])
         while pending:
             lease = self._find_lease(key)
             if lease is None:
-                if self._legacy:
-                    await self._maybe_request_lease(key, pending[0])
-                    return
                 break
             spec = pending.pop(0)
             # Reserve the in-flight slot synchronously so concurrent drains
@@ -1375,7 +1309,7 @@ class CoreWorker:
                 # queue depth behind the task being pushed
                 lease.burst_channel = len(pending) < 2
             lease.last_used = time.monotonic()
-            if inline_ok and not pending and not self._legacy:
+            if inline_ok and not pending:
                 # SOLE task of this drain (the sync-call pattern): run the
                 # push in THIS coroutine instead of spawning a Task for
                 # it. Only when nothing else was popped in this drain —
@@ -1391,8 +1325,7 @@ class CoreWorker:
             asyncio.ensure_future(self._push_to_lease(lease, spec, key))
         if not pending:
             self._pending_since.pop(key, None)
-        if not self._legacy:
-            self._maybe_request_leases(key)
+        self._maybe_request_leases(key)
 
     async def _task_channel_conn(self, address) -> rpc.Connection | None:
         """Dial a lease's direct task channel when its socket file is
@@ -1455,51 +1388,10 @@ class CoreWorker:
             return
         lease.inflight -= 1
         lease.last_used = time.monotonic()
-        if self._legacy:
-            await self._maybe_return_lease(key, lease)
         await self._drain_pending(key, inline_ok=False)
 
-    async def _maybe_return_lease(self, key, lease: _Lease):
-        # Round-7 control arm: per-push grace timer (one asyncio.sleep
-        # coroutine + loop timer PER completed task — the optimized path
-        # runs one shared reaper instead, _lease_reaper).
-        if lease.inflight > 0 or self._pending_by_key.get(key):
-            return
-        # grace period for bursty submission patterns
-        await asyncio.sleep(0.25)
-        if (lease.inflight > 0 or self._pending_by_key.get(key)
-                or lease not in self.leases.get(key, [])):
-            return
-        self.leases[key].remove(lease)
-        try:
-            await lease.raylet_conn.call(
-                "return_worker", {"lease_id": lease.lease_id,
-                                  "worker_exiting": lease.conn.closed})
-        except Exception:
-            pass
-
-    async def _return_all_leases(self):
-        """Hand every idle lease back to its raylet now (arm switches in
-        the microbenchmark A/B, tests): a lease built by one arm must not
-        leak into the other's window (legacy leases lack the direct task
-        channel)."""
-        for key, leases in list(self.leases.items()):
-            for lease in list(leases):
-                if lease.inflight > 0:
-                    continue
-                leases.remove(lease)
-                try:
-                    await lease.raylet_conn.call(
-                        "return_worker",
-                        {"lease_id": lease.lease_id,
-                         "worker_exiting": lease.conn.closed})
-                except Exception:
-                    pass
-            if not leases:
-                self.leases.pop(key, None)
-
     def _ensure_lease_reaper(self):
-        if self._lease_reaper_running or self._legacy or self._shutdown:
+        if self._lease_reaper_running or self._shutdown:
             return
         self._lease_reaper_running = True
         asyncio.ensure_future(self._lease_reaper())
@@ -2213,8 +2105,7 @@ class CoreWorker:
                 self._schedule_actor_poll(client)
                 return
             client.task_conn = None
-        if (client.task_conn is None and client.task_channel
-                and not self._legacy):
+        if client.task_conn is None and client.task_channel:
             client.task_conn = await self._task_channel_conn(
                 client.task_channel)
         # swap-drain: pop(0) per task is O(n²) on a deep queue, and the
@@ -2463,13 +2354,12 @@ class CoreWorker:
 
     h_push_task._rpc_deferred = True
 
-    async def h_push_task_legacy(self, conn, d):
-        # Round-7 control arm (RAY_TPU_TASK_LEGACY in the worker's env):
-        # future + task + coroutine resume per pushed task.
-        return await self._enqueue_exec(d["spec"])
-
     async def h_create_actor(self, conn, d):
-        return await self._enqueue_exec(d["spec"])
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        self._dispatch_exec(
+            d["spec"], lambda reply: self._deliver_reply(reply, fut, loop))
+        return await fut
 
     def _actor_push_common(self, spec, complete):
         """Per-caller seq reorder, then hand to the single execution lane
@@ -2517,24 +2407,6 @@ class CoreWorker:
                 m, "push_actor_task", reply))
 
     h_push_actor_task._rpc_deferred = True
-
-    async def h_push_actor_task_legacy(self, conn, d):
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
-        self._actor_push_common(d["spec"], self._fut_completer(fut, loop))
-        return await fut
-
-    async def _enqueue_exec(self, spec):
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
-        self._dispatch_exec(spec, self._fut_completer(fut, loop))
-        return await fut
-
-    def _fut_completer(self, fut, loop):
-        def complete(reply):
-            self._deliver_reply(reply, fut, loop)
-
-        return complete
 
     def _dispatch_exec(self, spec, complete):
         # Worker-side arrival stamp (_exec_scope pops it): held_s in the
@@ -2795,16 +2667,11 @@ class CoreWorker:
                     os._exit(1)
 
     def _deliver_reply(self, reply, fut, loop):
-        """Resolve a push handler's future from the dispatcher thread.
-        Delivery rides the loop's coalesced call queue: a burst of task
+        """Resolve h_create_actor's future from the dispatcher thread.
+        Delivery rides the loop's coalesced call queue: a burst of
         completions costs one self-pipe wakeup, not one syscall per reply
-        (call_soon_threadsafe — the round-7 path, kept as the legacy
-        control arm — writes the pipe every call)."""
+        (call_soon_threadsafe writes the pipe every call)."""
         if loop.is_closed():
-            return
-        if self._legacy:
-            loop.call_soon_threadsafe(
-                lambda f=fut, r=reply: f.done() or f.set_result(r))
             return
 
         def _set(f=fut, r=reply):
@@ -2955,8 +2822,7 @@ class CoreWorker:
         # submit itself on the same 0.25s limiter so a 1000-task/s worker
         # schedules ~4 flushes/s, not 1000 (the 2s periodic loop
         # guarantees the tail is flushed either way).
-        if (self._legacy or time.monotonic() - self._last_profile_flush
-                >= 0.25):
+        if time.monotonic() - self._last_profile_flush >= 0.25:
             self._io.submit(self._flush_profile_now())
         return reply
 

@@ -51,7 +51,7 @@ class ActorMethod:
     def _remote(self, args, kwargs, opts):
         cw = global_state.require_core_worker()
         num_returns = opts.get("num_returns", self._num_returns)
-        if not opts and not getattr(cw, "_legacy", False):
+        if not opts:
             if self._template is None or self._template_cw is not cw:
                 self._template = cw.make_actor_task_template(
                     self._handle._actor_id.binary(),

@@ -27,9 +27,7 @@ ring  — direct rank-to-rank TCP ring for large tensors: reduce-scatter
         Steps are chunk-pipelined (the reduce of chunk k overlaps the
         receive of chunk k+1) and zero-copy (memoryview slices of the
         work buffer go straight to sendall; recv_into fills scratch or
-        the destination — no tobytes per step). The unpipelined ring
-        allreduce is preserved verbatim as `ring_unpipelined`, the
-        control arm of the perf A/B. With `quantize="int8"` the
+        the destination — no tobytes per step). With `quantize="int8"` the
         allreduce wire format becomes block-scaled int8 (EQuARX-style:
         per-QUANT_BLOCK f32 scales ride ahead of each chunk's int8
         payload, the reduce runs on dequantized float32) — ~4x fewer
@@ -1098,86 +1096,6 @@ class HostGroup:
                     pass
             setattr(self, name, None)
 
-    # -- legacy (unpipelined) ring: the A/B control arm ----------------
-
-    @staticmethod
-    def _ring_send(sock: socket.socket, data: bytes):
-        sock.sendall(_HDR.pack(len(data)) + data)
-
-    @staticmethod
-    def _ring_recv(sock: socket.socket) -> bytes:
-        (n,) = _HDR.unpack(_recv_exact(sock, 4))
-        return _recv_exact(sock, n)
-
-    def _ring_step(self, send_bytes: bytes) -> bytes:
-        """Full-duplex: push to next while pulling from prev (the send
-        rides a thread so neither side can deadlock on full buffers;
-        socket timeouts bound both directions)."""
-        err: list = []
-
-        def _send():
-            try:
-                self._ring_send(self._ring_next, send_bytes)
-            except Exception as e:
-                err.append(e)
-
-        t = threading.Thread(target=_send, daemon=True)
-        t.start()
-        data = self._ring_recv(self._ring_prev)
-        t.join(self._timeout)
-        if t.is_alive() or err:
-            # a lingering send thread would interleave with the next
-            # step's frames — the ring is no longer trustworthy
-            raise TimeoutError(
-                f"ring send stalled/failed: {err or 'timeout'}")
-        return data
-
-    def _ring_allreduce(self, arr: np.ndarray, op: ReduceOp) -> np.ndarray:
-        """Unpipelined ring allreduce — one tobytes frame per step.
-        Preserved as the control arm of the pipelined-ring perf A/B
-        (force_transport='ring_unpipelined')."""
-        w = self.world_size
-        flat = arr.reshape(-1)
-        pad = (-len(flat)) % w
-        if pad:
-            flat = np.concatenate([flat, np.zeros(pad, arr.dtype)])
-        # MEAN matches the hub's np.mean semantics: float64 accumulate
-        # and a float result for integer inputs (also dodges overflow)
-        if op == ReduceOp.MEAN and not np.issubdtype(arr.dtype,
-                                                     np.floating):
-            flat = flat.astype(np.float64)
-        work = flat.copy()
-        chunk = len(work) // w
-        combine = getattr(
-            np, _NUMPY_REDUCE[ReduceOp.SUM if op == ReduceOp.MEAN
-                              else ReduceOp(op)])
-
-        def view(i):
-            i %= w
-            return work[i * chunk:(i + 1) * chunk]
-
-        for step in range(w - 1):  # reduce-scatter
-            send_idx = self.rank - step
-            recv_idx = self.rank - step - 1
-            incoming = self._ring_step(view(send_idx).tobytes())
-            recv = view(recv_idx)
-            # parse with the wire dtype (work.dtype): for integer MEAN the
-            # work buffer — and therefore every frame on the ring — is
-            # float64, not arr.dtype
-            np.copyto(recv, combine(
-                recv, np.frombuffer(incoming, work.dtype)))
-        for step in range(w - 1):  # allgather of reduced chunks
-            send_idx = self.rank + 1 - step
-            recv_idx = self.rank - step
-            incoming = self._ring_step(view(send_idx).tobytes())
-            np.copyto(view(recv_idx), np.frombuffer(incoming, work.dtype))
-        if op == ReduceOp.MEAN:
-            work = work / w  # float result, like the hub's np.mean
-        out = work[:arr.size].reshape(arr.shape)
-        if op == ReduceOp.MEAN:
-            return out
-        return out.astype(arr.dtype, copy=False)
-
     # -- pipelined zero-copy ring --------------------------------------
 
     def _ring_recv_into(self, mv: memoryview):
@@ -1561,7 +1479,7 @@ class HostGroup:
                     hub_fn):
         """One route/fallback/poison dispatch for the uniform-geometry
         collectives (allgather is bespoke: its geometry may be ragged).
-        shm_fn(transport), ring_fn(pipelined: bool), hub_fn(). A
+        shm_fn(transport), ring_fn(), hub_fn(). A
         placement-derived pin whose tier can't be built demotes
         (group-uniformly — shm's ok-flag exchange / the uniform ring
         build result) and re-routes, instead of raising like a
@@ -1577,15 +1495,13 @@ class HostGroup:
                             break
                         continue
                     return self._shm_op(lambda: shm_fn(t))
-                if tr in (Transport.RING.value,
-                          Transport.RING_UNPIPELINED.value):
+                if tr == Transport.RING.value:
                     if not self._ring_op(self._ensure_ring):
                         if self._tier_unavailable(tr):
                             rerouted = True
                             break
                         continue
-                    pipelined = tr == Transport.RING.value
-                    return self._ring_op(lambda: ring_fn(pipelined))
+                    return self._ring_op(ring_fn)
                 return hub_fn()
             if not rerouted:
                 raise RuntimeError("no collective transport available")
@@ -1609,15 +1525,12 @@ class HostGroup:
                 arr.tobytes())
             return _arr_from(reply["meta"], data)
 
-        def ring(pipelined):
-            # the quantized wire format lives on the pipelined ring (the
-            # unpipelined arm is the exact A/B control); int payloads
-            # and PRODUCT stay exact by definition
-            if (pipelined and q and op in _QUANT_OPS
+        def ring():
+            # int payloads and PRODUCT stay exact by definition
+            if (q and op in _QUANT_OPS
                     and np.issubdtype(arr.dtype, np.floating)):
                 return self._ring_allreduce_quantized(arr, op)
-            return (self._ring_allreduce_pipelined(arr, op) if pipelined
-                    else self._ring_allreduce(arr, op))
+            return self._ring_allreduce_pipelined(arr, op)
 
         return self._run_routed(
             arr, self._shm_need(arr, op),
@@ -1651,7 +1564,7 @@ class HostGroup:
         return self._run_routed(
             arr, self._shm_need(arr, None),
             lambda t: t.broadcast(arr, src_rank),
-            lambda pipelined: self._ring_broadcast_pipelined(arr, src_rank),
+            lambda: self._ring_broadcast_pipelined(arr, src_rank),
             hub)
 
     @_op_entry("allgather")
@@ -1689,7 +1602,7 @@ class HostGroup:
                 if out is not None:
                     return out
                 continue  # defense-in-depth: shm saw ragged metas
-            if tr in (Transport.RING.value, Transport.RING_UNPIPELINED.value):
+            if tr == Transport.RING.value:
                 if not self._ring_op(self._ensure_ring):
                     self._tier_unavailable(tr)
                     continue
@@ -1719,12 +1632,12 @@ class HostGroup:
                 arr.tobytes())
             return _arr_from(reply["meta"], data)
 
-        def ring(pipelined):
-            # quantized wire only on the pipelined ring, and only for
-            # flat world*QUANT_BLOCK-aligned float buckets (uniform
-            # block-aligned chunks — the sharded-trainer grad layout);
-            # anything else takes the exact tier
-            if (pipelined and q and op in _QUANT_OPS
+        def ring():
+            # quantized wire only for flat world*QUANT_BLOCK-aligned
+            # float buckets (uniform block-aligned chunks — the
+            # sharded-trainer grad layout); anything else takes the
+            # exact tier
+            if (q and op in _QUANT_OPS
                     and np.issubdtype(arr.dtype, np.floating)
                     and arr.ndim == 1
                     and arr.size % (self.world_size * QUANT_BLOCK) == 0):

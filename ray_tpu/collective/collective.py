@@ -122,7 +122,7 @@ def init_collective_group(world_size: int, rank: int,
     """Initialize this process's membership in a collective group
     (reference: collective.py:93). Call from inside each participating
     actor/task with its rank. `transport` pins the HOST data plane to
-    one tier (hub/ring/ring_unpipelined/shm/device); "auto" routes per
+    one tier (hub/ring/shm/device); "auto" routes per
     op. `quantize="int8"` makes this group's default allreduce wire
     format block-scaled int8 (EQuARX-style, lossy) on the tiers that
     have a wire (ring/device); per-op `allreduce(..., quantize=...)`

@@ -31,10 +31,6 @@ class Transport(str, enum.Enum):
           control-sized tensors, carries every op kind.
     RING — direct rank-to-rank TCP ring, chunk-pipelined and zero-copy;
           the bandwidth path for large tensors across nodes.
-    RING_UNPIPELINED — the pre-pipelining ring ALLREDUCE, preserved as
-          the control arm of the perf A/B. Allreduce-only: the other
-          collectives never had an unpipelined ring, so under this pin
-          they run the pipelined ring data plane.
     SHM — one mmap'd tmpfs segment per group when every rank shares a
           node: collectives become pure memory traffic.
     DEVICE — the accelerator's own interconnect: when every rank's
@@ -61,7 +57,6 @@ class Transport(str, enum.Enum):
     AUTO = "auto"
     HUB = "hub"
     RING = "ring"
-    RING_UNPIPELINED = "ring_unpipelined"
     SHM = "shm"
     DEVICE = "device"
     PALLAS = "pallas"

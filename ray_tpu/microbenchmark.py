@@ -54,7 +54,7 @@ def timeit_ab(name: str, arms: dict, multiplier: int = 1,
     """Paired interleaved A/B: every arm runs once inside EACH window
     (so a box-load swing hits all arms equally), median of N windows per
     arm. `arms` maps suffix -> (setup, fn): setup() flips the process
-    into that arm (e.g. the legacy task path) before its slice runs."""
+    into that arm (e.g. tracing off) before its slice runs."""
     rates: dict[str, list] = {suffix: [] for suffix in arms}
     for suffix, (setup, fn) in arms.items():
         setup()
@@ -165,30 +165,6 @@ def main(seconds_per_case: float = 2.0) -> list[dict]:
     results.append({"name": "single client put gigabytes",
                     "per_second": gb_s, "sd": 0.0})
 
-    from ray_tpu._private import global_state
-
-    def _arm(legacy: bool):
-        """Flip the driver between the optimized task path and the
-        preserved round-7 control (RAY_TPU_TASK_LEGACY semantics) —
-        spec caching, batched/soft lease prewarm, shared lease reaper
-        vs per-call rebuilds, one-at-a-time hard leases, per-push grace
-        timers. Worker-side changes (coalesced reply delivery, gated
-        profile flush) are active in BOTH arms; see PERF.md round 8."""
-
-        def setup():
-            cw = global_state.get_core_worker()
-            if cw is not None:
-                cw._legacy = legacy
-                # each arm builds its own leases: a lease granted to the
-                # other arm differs structurally (no direct task channel
-                # on legacy leases) and must not leak across windows
-                cw._io.run(cw._return_all_leases(), timeout=30)
-
-        return setup
-
-    AB = lambda fn: {"": (_arm(False), fn),  # noqa: E731
-                     "legacy-path control": (_arm(True), fn)}
-
     @ray_tpu.remote
     def small_task():
         return b"ok"
@@ -196,13 +172,13 @@ def main(seconds_per_case: float = 2.0) -> list[dict]:
     def task_sync():
         ray_tpu.get(small_task.remote())
 
-    timeit_ab("single client tasks sync", AB(task_sync), results=results)
+    timeit("single client tasks sync", task_sync, results=results)
 
     def tasks_async():
         ray_tpu.get([small_task.remote() for _ in range(100)])
 
-    timeit_ab("single client tasks async", AB(tasks_async),
-              multiplier=100, results=results)
+    timeit("single client tasks async", tasks_async, multiplier=100,
+           results=results)
 
     @ray_tpu.remote
     class TaskClient:
@@ -233,7 +209,7 @@ def main(seconds_per_case: float = 2.0) -> list[dict]:
     def actor_sync():
         ray_tpu.get(a.small_value.remote())
 
-    timeit_ab("1:1 actor calls sync", AB(actor_sync), results=results)
+    timeit("1:1 actor calls sync", actor_sync, results=results)
 
     def actor_async():
         ray_tpu.get([a.small_value.remote() for _ in range(100)])
@@ -324,16 +300,13 @@ def main(seconds_per_case: float = 2.0) -> list[dict]:
 
 
 def _cross_node_bench(results: list[dict], windows: int = 5):
-    """Cross-node object pull A/B (needs real raylet process boundaries,
-    so it runs on its own cluster_utils cluster AFTER the single-node
-    suite). Per size, each window times ONE pull per arm — streaming
-    bulk-channel pull vs the preserved round-8 stop-and-wait fetch_chunk
-    control (set_transfer_mode flips the puller raylet live, so the arms
-    interleave inside the same windows) — median of N windows. Also: a
-    2-source striped pull, and the control-plane probe: peer_ping RTTs
-    over the shared raylet<->raylet CONTROL connection while a 64MB pull
-    is in flight (legacy chunks head-of-line-block that conn; streaming
-    must leave it idle)."""
+    """Cross-node object pull (needs real raylet process boundaries, so
+    it runs on its own cluster_utils cluster AFTER the single-node
+    suite). Per size, each window times ONE streaming bulk-channel pull
+    — median of N windows. Also: a 2-source striped pull, and the
+    control-plane probe: peer_ping RTTs over the shared raylet<->raylet
+    CONTROL connection while a 16MB pull is in flight (streaming must
+    leave that conn idle)."""
     from ray_tpu._private import global_state
     from ray_tpu.cluster_utils import Cluster
 
@@ -359,9 +332,6 @@ def _cross_node_bench_body(results: list[dict], windows: int, cluster):
 
     def rcall(method, data, timeout=180.0):
         return cw._io.run(head.call(method, data), timeout=timeout)
-
-    def set_mode(legacy):
-        rcall("set_transfer_mode", {"legacy": legacy})
 
     def pull(oid, free_after=True) -> float:
         t0 = time.perf_counter()
@@ -412,18 +382,9 @@ def _cross_node_bench_body(results: list[dict], windows: int, cluster):
 
     for mb in (1, 16, 64):
         oid = refs[mb].id().binary()
-        for legacy in (False, True):  # warm both arms' connections
-            set_mode(legacy)
-            pull(oid)
-        rates: dict[bool, list] = {False: [], True: []}
-        for _ in range(windows):
-            for legacy in (False, True):  # interleaved within the window
-                set_mode(legacy)
-                rates[legacy].append(1.0 / pull(oid))
-        record(f"cross_node_pull {mb}MB", rates[False], mb * 1024 * 1024)
-        record(f"cross_node_pull {mb}MB (legacy-path control)",
-               rates[True], mb * 1024 * 1024)
-    set_mode(None)
+        pull(oid)  # warm the connections
+        rates = [1.0 / pull(oid) for _ in range(windows)]
+        record(f"cross_node_pull {mb}MB", rates, mb * 1024 * 1024)
 
     # --- 1src vs 2src striped pull (64MB), PAIRED interleaved: the
     # second source's directory entry is removed for the 1src slice of
@@ -463,7 +424,7 @@ def _cross_node_bench_body(results: list[dict], windows: int, cluster):
 
     # --- control-plane RTT during a 64MB bulk pull ---
     # peer_ping rides the head raylet's shared control connection to the
-    # source — exactly where legacy bulk frames also travel.
+    # source — the one the control-path pull fallback would also use.
     async def ping_during_pull(oid):
         lats = []
         pull_fut = asyncio.ensure_future(head.call(
@@ -477,26 +438,23 @@ def _cross_node_bench_body(results: list[dict], windows: int, cluster):
         return lats
 
     oid = refs[16].id().binary()  # single-source (B) object
-    for legacy, suffix in ((False, ""), (True, " (legacy-path control)")):
-        set_mode(legacy)
-        lats: list[float] = []
-        for _ in range(windows):
-            lats.extend(cw._io.run(ping_during_pull(oid), timeout=300))
-        name = f"cross_node_pull control ping during 16MB pull{suffix}"
-        if not lats:
-            # pull outraced every ping this window: no row (NaN would
-            # make MICROBENCH.json invalid JSON for strict parsers)
-            print(f"{name}: no pings completed during the pull; skipped")
-            continue
-        p99 = float(np.percentile(lats, 99))
-        p50 = float(np.median(lats))
-        print(f"{name}: p50 {p50 * 1e3:.2f}ms p99 {p99 * 1e3:.2f}ms "
-              f"({len(lats)} pings)")
-        results.append({"name": name, "per_second": 1.0 / p99,
-                        "sd": 0.0, "p99_ms": round(p99 * 1e3, 3),
-                        "p50_ms": round(p50 * 1e3, 3),
-                        "samples": len(lats)})
-    set_mode(None)
+    lats: list[float] = []
+    for _ in range(windows):
+        lats.extend(cw._io.run(ping_during_pull(oid), timeout=300))
+    name = "cross_node_pull control ping during 16MB pull"
+    if not lats:
+        # pull outraced every ping this window: no row (NaN would
+        # make MICROBENCH.json invalid JSON for strict parsers)
+        print(f"{name}: no pings completed during the pull; skipped")
+        return
+    p99 = float(np.percentile(lats, 99))
+    p50 = float(np.median(lats))
+    print(f"{name}: p50 {p50 * 1e3:.2f}ms p99 {p99 * 1e3:.2f}ms "
+          f"({len(lats)} pings)")
+    results.append({"name": name, "per_second": 1.0 / p99,
+                    "sd": 0.0, "p99_ms": round(p99 * 1e3, 3),
+                    "p50_ms": round(p50 * 1e3, 3),
+                    "samples": len(lats)})
 
 
 def _collective_bench(results: list[dict], nbytes: int = 16 * 1024 * 1024,
@@ -504,8 +462,7 @@ def _collective_bench(results: list[dict], nbytes: int = 16 * 1024 * 1024,
     """Host collective data-plane A/B: one 16MB float32 allreduce across
     4 single-node ranks per window, every transport forced in turn
     inside the SAME window (interleaved — a box-load swing hits all arms
-    equally), median of N windows, GB/s/rank. `ring_unpipelined` is the
-    preserved pre-pipelining control arm; the small-hub case guards
+    equally), median of N windows, GB/s/rank. The small-hub case guards
     control-plane latency against regressions from the routing layer.
     Round-12 arms: `device` (the Transport.DEVICE tier over the shared
     jax runtime — device-resident payload, timed to block_until_ready)
@@ -568,8 +525,7 @@ def _collective_bench(results: list[dict], nbytes: int = 16 * 1024 * 1024,
                  for i, r in enumerate(ranks)], timeout=300)
     col.create_collective_group(ranks, world, list(range(world)),
                                 backend="host", group_name="bench_col")
-    cases = ["shm", "ring", "ring_quantized", "ring_unpipelined", "hub",
-             "device"]
+    cases = ["shm", "ring", "ring_quantized", "hub", "device"]
     for tr in cases:  # warm at FULL size: segment sized+faulted in, ring
         ray_tpu.get(   # built, hub buffers grown, device bodies jitted —
             [r.timed_allreduce.remote(tr, nbytes // 4) for r in ranks],
@@ -660,8 +616,8 @@ def _http_qps_window(pool, tls, port: int, route: str,
     """Keep-alive HTTP throughput over one timed window: 16 pooled
     client threads, one persistent conn per (thread, port) — urllib
     reconnects per request, which would measure TCP handshakes, not the
-    proxy. Shared by the legacy-proxy and tracing A/Bs so both rows
-    measure through the identical harness."""
+    proxy. Shared by the serve qps row and the tracing and state A/Bs
+    so every row measures through the identical harness."""
     import http.client
 
     stop = time.perf_counter() + seconds
@@ -711,10 +667,7 @@ def _serve_qps(results: list[dict]):
     """Serve noop throughput (reference: serve release bench, ~3-4k qps
     noop via HTTP). Measured through the handle (router batching path),
     through a router-only asyncio control (no HTTP), and through the
-    HTTP proxy as a PAIRED interleaved A/B: the optimized request path
-    (call_async + coalesced wakeups) against a legacy-path control proxy
-    (assign_async + wrap_future per ref) serving the same backend in the
-    same process window — so a box-load swing hits both sides equally."""
+    HTTP proxy (call_async + coalesced wakeups)."""
     import asyncio
 
     from ray_tpu import serve
@@ -743,9 +696,8 @@ def _serve_qps(results: list[dict]):
     timeit("serve handle noop calls", handle_call, multiplier=64,
            results=results)
 
-    # Router-only control (round-5 definition): assign_async + await ref
-    # at concurrency 16, no HTTP anywhere. Bounds what any proxy in this
-    # process could deliver.
+    # Router-only control: call_async at concurrency 16, no HTTP
+    # anywhere. Bounds what any proxy in this process could deliver.
     router = handle._router
 
     def router_window(seconds: float = 0.7) -> float:
@@ -755,8 +707,7 @@ def _serve_qps(results: list[dict]):
             async def worker():
                 n = 0
                 while time.perf_counter() < stop:
-                    ref = await router.assign_async(None)
-                    await ref
+                    await router.call_async(None)
                     n += 1
                 return n
 
@@ -775,15 +726,6 @@ def _serve_qps(results: list[dict]):
                     "sd": float(np.std(router_rates)),
                     "trials": [round(r, 2) for r in router_rates]})
 
-    # Legacy-path control proxy: same controller, same backend, own
-    # port. Coexists with the optimized proxy so the A/B interleaves
-    # within one window.
-    from ray_tpu.serve.http_proxy import HTTPProxy
-
-    legacy = ray_tpu.remote(HTTPProxy).remote(
-        client._controller, "127.0.0.1", 0, False, True)
-    legacy_port = ray_tpu.get(legacy.port.remote(), timeout=60)
-
     import threading as _threading
 
     tls = _threading.local()
@@ -791,16 +733,10 @@ def _serve_qps(results: list[dict]):
     def http_window(port: int, seconds: float = 0.7) -> float:
         return _http_qps_window(pool, tls, port, "/noop", seconds)
 
-    http_window(client.http_port, 0.2)  # warm both proxies' conns
-    http_window(legacy_port, 0.2)
-    opt_rates, leg_rates = [], []
-    for _ in range(5):  # interleaved: load swings hit both sides
-        opt_rates.append(http_window(client.http_port))
-        leg_rates.append(http_window(legacy_port))
-    _rate_rows(results, [("serve http noop qps", opt_rates),
-                         ("serve http noop qps (legacy-path control)",
-                          leg_rates)], windows=5)
-    ray_tpu.kill(legacy)
+    http_window(client.http_port, 0.2)  # warm the proxy's conns
+    _rate_rows(results, [("serve http noop qps",
+                          [http_window(client.http_port)
+                           for _ in range(5)])], windows=5)
     pool.shutdown()
     serve.shutdown()
 
@@ -1460,8 +1396,7 @@ def _tracing_ab(results: list[dict]):
 
     timeit_ab("tracing A/B tasks sync", TR(task_sync), results=results)
 
-    # serve http: optimized proxy only (the legacy A/B lives in
-    # _serve_qps); the sampling rate toggles between the two slices of
+    # serve http: the sampling rate toggles between the two slices of
     # EACH window so box-load swings hit both arms equally.
     client = serve.start(http=True)
     client.create_backend("noop_tr", lambda _=None: "ok", config={

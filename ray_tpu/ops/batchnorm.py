@@ -12,7 +12,7 @@ it with the neighboring conv backward exactly like the baseline.
 
 Semantically identical to the XLA path in models/resnet.py `_bn` (same
 one-pass E[x²]−E[x]² variance with the same clamp), selected by
-`ResNetConfig(bn_mode="pallas")` and A/B-able via RAY_TPU_BENCH_BN.
+`ResNetConfig(bn_mode="pallas")`.
 
 Reference analog: the reference trains ResNet through cuDNN's fused
 batchnorm backward (torch BatchNorm2d → cudnnBatchNormalizationBackward);

@@ -186,13 +186,11 @@ class Raylet:
         self._pull_sem_obj = None
 
         # bulk transfer data plane (raylet/transfer.py): dedicated
-        # streaming channel for object bytes, sender-side transfer pins,
-        # and the A/B switch back to the round-8 stop-and-wait path
+        # streaming channel for object bytes and sender-side transfer pins
         self.transfer_pins = transfer.TransferPins()
         self.bulk = transfer.BulkTransferServer(self)
         self.bulk_address = ""
         self._loop: asyncio.AbstractEventLoop | None = None
-        self._pull_mode_legacy: bool | None = None  # None = env-driven
         # arg-id set -> monotonic expiry of a NO-redirect locality
         # decision: repeated lease requests for the SAME pending task's
         # args (the retry/escalation pattern) skip the per-request GCS
@@ -256,7 +254,6 @@ class Raylet:
             "push_hint": self.h_push_hint,
             "push_objects_to": self.h_push_objects_to,
             "transfer_done": self.h_transfer_done,
-            "set_transfer_mode": self.h_set_transfer_mode,
             "peer_ping": self.h_peer_ping,
             "debug_state": self.h_debug_state,
             "debug_stacks": lambda conn, d: _debug.collect_stacks(),
@@ -405,11 +402,11 @@ class Raylet:
     async def _on_disconnect(self, conn):
         if self._shutting_down:
             return
-        # A legacy puller's transfer pins die with its connection (the
+        # A control-path puller's transfer pins die with its connection (the
         # TTL sweep is only the backstop for pullers that wedge without
         # closing); deferred frees they were blocking run now.
         freeable = self.transfer_pins.release_token(
-            self._legacy_pin_token(conn))
+            self._control_pin_token(conn))
         if freeable:
             await self._complete_deferred_frees(freeable)
         # Lease-holder death: leases granted to this connection (a
@@ -882,18 +879,16 @@ class Raylet:
         return result
 
     async def _spill(self, d: dict, addr: str, hops: int):
-        """Redirect a lease request to the raylet at `addr`. Forwarding
-        mode (lease_spillback_forwarding, the tentpole path) CHAINS the
-        request raylet→raylet — this raylet relays the peer's grant back
+        """Redirect a lease request to the raylet at `addr` by CHAINING
+        it raylet→raylet — this raylet relays the peer's grant back
         toward the owner, so a cross-node burst costs the owner ONE lease
         RPC instead of a redial per hop. The chain is hop-capped
         (lease_spillback_max_hops), cycle-guarded (`visited` addresses are
         never re-picked), and carries the spec unchanged — locality hints
-        (args) and the PR 6 trace context ride along. Legacy mode (or a
-        failed forward, or an exhausted hop budget) bounces the
-        owner-visible {"spillback": addr} reply exactly as before."""
-        if (not self.config.lease_spillback_forwarding
-                or hops > self.config.lease_spillback_max_hops):
+        (args) and the PR 6 trace context ride along. A failed forward or
+        an exhausted hop budget degrades to the owner-visible
+        {"spillback": addr} reply: the owner redials `addr` itself."""
+        if hops > self.config.lease_spillback_max_hops:
             return {"spillback": addr, "hops": hops}
         if _fp.ARMED:
             # forward seam: `raise` degrades to the owner-mediated bounce
@@ -912,8 +907,8 @@ class Raylet:
             conn = await self._raylet_conn(addr)
             reply = await conn.call("request_worker_lease", fwd)
         except Exception as e:
-            # peer died / unreachable mid-chain: degrade to the legacy
-            # bounce so the owner can redial (or re-spill elsewhere)
+            # peer died / unreachable mid-chain: degrade to the bounce
+            # so the owner can redial (or re-spill elsewhere)
             logger.warning("lease spillback forward to %s failed (%s); "
                            "bouncing to owner", addr, e)
             return {"spillback": addr, "hops": hops}
@@ -1398,14 +1393,6 @@ class Raylet:
                     asyncio.ensure_future(old.close())
         return conn
 
-    def _use_legacy_pull(self) -> bool:
-        """RAY_TPU_PULL_LEGACY=1 (or set_transfer_mode) re-enables the
-        round-8 stop-and-wait fetch_chunk pull path — the control arm of
-        the cross_node_pull microbenchmark's interleaved A/B."""
-        if self._pull_mode_legacy is not None:
-            return self._pull_mode_legacy
-        return os.environ.get("RAY_TPU_PULL_LEGACY", "") not in ("", "0")
-
     def _bulk_addr(self, address: str) -> str | None:
         """Map a peer raylet's control address to its bulk channel
         (advertised via the GCS node table), preferring the same-node UDS
@@ -1423,27 +1410,26 @@ class Raylet:
     async def _pull_any(self, oid: bytes, addresses: list[str]):
         """Pull `oid` given candidate holder control addresses: the
         streaming bulk plane (striped across every source with a bulk
-        channel) by default, the legacy one-source-at-a-time chunked rpc
-        path under RAY_TPU_PULL_LEGACY or when no source serves a bulk
-        channel."""
-        if not self._use_legacy_pull():
-            bulk = [b for b in (self._bulk_addr(a) for a in addresses) if b]
-            if bulk:
-                try:
-                    await self._pull_streaming(oid, bulk)
-                    return
-                except Exception as e:
-                    # advertised-but-unreachable bulk channels (firewalled
-                    # ephemeral port, half-up peer) must degrade to the
-                    # control-path pull for THIS attempt, not hang the
-                    # retry loop on streaming forever
-                    logger.warning(
-                        "streaming pull of %s failed (%s); falling back "
-                        "to the control-path pull", oid[:6].hex(), e)
+        channel) first; the one-source-at-a-time chunked pull over the
+        control connection when no source serves a bulk channel or the
+        streaming pull raises."""
+        bulk = [b for b in (self._bulk_addr(a) for a in addresses) if b]
+        if bulk:
+            try:
+                await self._pull_streaming(oid, bulk)
+                return
+            except Exception as e:
+                # advertised-but-unreachable bulk channels (firewalled
+                # ephemeral port, half-up peer) must degrade to the
+                # control-path pull for THIS attempt, not hang the
+                # retry loop on streaming forever
+                logger.warning(
+                    "streaming pull of %s failed (%s); falling back "
+                    "to the control-path pull", oid[:6].hex(), e)
         last: Exception | None = None
         for address in addresses:
             try:
-                await self._pull_from_legacy(oid, address)
+                await self._pull_control_path(oid, address)
                 return
             except Exception as e:
                 logger.warning("pull of %s from %s failed: %s",
@@ -1482,12 +1468,14 @@ class Raylet:
         self._pulled_local(oid, size)
         await self._wake_object_waiters(oid)
 
-    async def _pull_from_legacy(self, oid: bytes, address: str):
-        """Round-8 control arm: one fetch_chunk request-response at a
-        time over the shared raylet<->raylet CONTROL connection — pays a
-        full RTT per chunk, a bytes() copy out of the arena plus a pickle
-        frame per chunk, and head-of-line-blocks control RPCs behind the
-        bulk frames (quantified in PERF.md round 9)."""
+    async def _pull_control_path(self, oid: bytes, address: str):
+        """The pull _pull_any falls back to when the bulk plane cannot
+        carry the object: one fetch_chunk request-response at a time
+        over the shared raylet<->raylet CONTROL connection — a full RTT,
+        a bytes() copy out of the arena and a pickle frame per chunk,
+        and control RPCs queue behind the frames. Error handling, not an
+        alternative: it needs nothing but the connection every raylet
+        already has to every peer."""
         conn = await self._raylet_conn(address)
         info = await conn.call("object_info", {"object_id": oid})
         if info is None:
@@ -1568,11 +1556,11 @@ class Raylet:
                 logger.debug("push hint to %s failed: %s", target, e)
         return True
 
-    def _legacy_pin_token(self, conn):
+    def _control_pin_token(self, conn):
         return ("rpc", id(conn))
 
     async def h_object_info(self, conn, d):
-        """Legacy-path transfer registration: reports size AND takes a
+        """Control-path transfer registration: reports size AND takes a
         transfer pin (TTL-leased, refreshed by each fetch_chunk) so the
         object can't be freed/evicted between the puller's chunks — the
         old mid-pull KeyError race."""
@@ -1584,7 +1572,7 @@ class Raylet:
             return None
         if rec["spilled"]:
             await self._restore_spilled(oid)
-        self.transfer_pins.pin(oid, self._legacy_pin_token(conn),
+        self.transfer_pins.pin(oid, self._control_pin_token(conn),
                                self.config.transfer_pin_ttl_s)
         return {"size": rec["size"]}
 
@@ -1599,7 +1587,7 @@ class Raylet:
             await self._restore_spilled(oid)
         if rec is not None:
             # refresh the transfer-pin lease for this puller
-            self.transfer_pins.pin(oid, self._legacy_pin_token(conn),
+            self.transfer_pins.pin(oid, self._control_pin_token(conn),
                                    self.config.transfer_pin_ttl_s)
         buf = self.store.get(object_id)
         if buf is None:
@@ -1612,32 +1600,24 @@ class Raylet:
             buf.close()
 
     async def h_transfer_done(self, conn, d):
-        """Legacy puller announces its transfer finished: release the
-        pin NOW instead of waiting out the TTL lease — the raylet<->raylet
-        control connection the pin is keyed to is cached indefinitely, so
-        disconnect-release never fires for this path, and a TTL-only
-        release would block frees/spill of the object for
+        """A control-path puller announces its transfer finished: release
+        the pin NOW instead of waiting out the TTL lease — the
+        raylet<->raylet control connection the pin is keyed to is cached
+        indefinitely, so disconnect-release never fires for this path,
+        and a TTL-only release would block frees/spill of the object for
         transfer_pin_ttl_s after every pull."""
         freeable = self.transfer_pins.unpin(d["object_id"],
-                                            self._legacy_pin_token(conn))
+                                            self._control_pin_token(conn))
         if freeable:
             await self._complete_deferred_frees(freeable)
         return True
 
-    async def h_set_transfer_mode(self, conn, d):
-        """A/B switch for the pull path (microbench + tests): `legacy`
-        True forces the round-8 stop-and-wait fetch_chunk path for this
-        raylet's future pulls, False forces streaming, absent reverts to
-        the RAY_TPU_PULL_LEGACY env default."""
-        self._pull_mode_legacy = (bool(d["legacy"]) if "legacy" in d
-                                  and d["legacy"] is not None else None)
-        return {"legacy": self._use_legacy_pull()}
-
     async def h_peer_ping(self, conn, d):
         """Round-trip a ping to `address` over THIS raylet's shared
-        raylet<->raylet CONTROL connection — the one legacy bulk pulls
-        also ride. The cross_node_pull bench uses it to measure
-        control-plane head-of-line blocking during a bulk transfer."""
+        raylet<->raylet CONTROL connection — the one the control-path
+        pull fallback also rides. The cross_node_pull bench uses it to
+        measure control-plane head-of-line blocking during a bulk
+        transfer."""
         t0 = time.monotonic()
         peer = await self._raylet_conn(d["address"])
         await peer.call("ping", {})

@@ -179,8 +179,8 @@ class TransferPins:
     """Thread-safe registry of sender-side transfer pins with TTL leases.
 
     A pin names (token, oid): the bulk server uses one token per
-    connection (released when the connection dies), the legacy
-    object_info/fetch_chunk path uses one per rpc connection (released
+    connection (released when the connection dies), the control-path
+    object_info/fetch_chunk pull uses one per rpc connection (released
     only by TTL/disconnect). While any unexpired pin exists for an oid,
     free/eviction is deferred: callers record the free via defer_free()
     and complete it when release/sweep reports the oid freeable."""

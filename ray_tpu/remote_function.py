@@ -78,7 +78,7 @@ class RemoteFunction:
             self._pickled = cloudpickle.dumps(self._function)
         fn_id = cw.export_function(self._pickled)
         self._fn_id = fn_id
-        if not opts and not getattr(cw, "_legacy", False):
+        if not opts:
             # hot path: the whole static spec prefix (descriptor, owner,
             # quantized resources) is built once per (function, worker)
             # and submit pays one dict copy per call

@@ -11,7 +11,7 @@ artifact exists so every round records the framework's sampling+learning
 pipeline rate under the SAME workload, with run metadata for cross-round
 provenance. Results are written like MICROBENCH.json.
 
-Usage: python -m ray_tpu.rlbench [--out RLBENCH_rNN.json] [--seconds 20]
+Usage: python -m ray_tpu.rlbench [--out <file>.json] [--seconds 20]
 """
 
 from __future__ import annotations

@@ -55,12 +55,8 @@ class HTTPProxy:
     """Actor: runs an aiohttp server on a thread; one Router per endpoint."""
 
     def __init__(self, controller, host: str = "127.0.0.1", port: int = 0,
-                 reuse_port: bool = False, legacy_path: bool = False):
+                 reuse_port: bool = False):
         self._controller = controller
-        # legacy_path keeps the pre-coalescing request path (assign_async
-        # + wrap_future per ref) alive as the A/B control for the
-        # microbenchmark, and as a fallback switch for call_async
-        self._legacy_path = legacy_path
         self._routers: dict[str, object] = {}
         self._routes: dict[str, dict] = {}
         self._thresholds: dict[str, int] = {}
@@ -271,12 +267,7 @@ class HTTPProxy:
                 if self._streaming.get(endpoint):
                     return await stream_handler(request, endpoint,
                                                 router, data)
-                if self._legacy_path:
-                    ref = await router.assign_async(data)
-                    result = await asyncio.wait_for(
-                        asyncio.wrap_future(ref.future()), 60)
-                else:
-                    result = await router.call_async(data, timeout=60.0)
+                result = await router.call_async(data, timeout=60.0)
                 if isinstance(result, _payload.LargePayload):
                     # zero-copy response: resolve the plasma ref off the
                     # event loop (first touch may pull over the bulk

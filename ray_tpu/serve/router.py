@@ -57,7 +57,7 @@ def _parse_session(data):
 
 class _PendingQuery:
     __slots__ = ("data", "event", "ref", "error", "abandoned", "loop",
-                 "future", "want_result", "trace", "t_enqueue")
+                 "future", "trace", "t_enqueue")
 
     def __init__(self, data):
         self.data = data
@@ -65,9 +65,10 @@ class _PendingQuery:
         self.ref = None
         self.error = None
         self.abandoned = False
-        self.loop = None    # set by assign_async/call_async: asyncio bridge
+        # set by call_async (asyncio bridge): such a query resolves its
+        # future with the VALUE; assign()'s waits on `event` for the ref
+        self.loop = None
         self.future = None
-        self.want_result = False  # call_async: resolve with the VALUE
         # the caller's ambient trace context (the HTTP proxy mints one
         # per sampled request): carried to the flusher thread so the
         # dispatched batch task joins the request's trace tree
@@ -75,10 +76,10 @@ class _PendingQuery:
         self.t_enqueue = time.time()
 
     def _notify(self):
-        """Dispatch outcome is ready: wake the sync waiter and, for async
-        callers, resolve their future on its own event loop (the flusher
-        thread can't touch asyncio state directly). Result-mode queries
-        only land here on dispatch ERRORS — their success path resolves at
+        """Dispatch outcome is ready: wake the sync waiter. Async
+        (result-mode) queries only land here on dispatch ERRORS — raised
+        into their future on its own event loop (the flusher thread can't
+        touch asyncio state directly); their success path resolves at
         completion with the value, with zero per-query dispatch wakeups."""
         self.event.set()
         if self.future is not None:
@@ -89,10 +90,7 @@ class _PendingQuery:
                 # exception set now would only surface as "Future
                 # exception was never retrieved" GC spam
                 if not q.future.done() and not q.abandoned:
-                    if q.error is not None:
-                        q.future.set_exception(q.error)
-                    else:
-                        q.future.set_result(q.ref)
+                    q.future.set_exception(q.error)
             try:
                 rpc.loop_call_queue(self.loop).call(_done)
             except RuntimeError:
@@ -276,40 +274,17 @@ class Router:
             raise q.error
         return q.ref
 
-    async def assign_async(self, data, timeout: float = 30.0):
-        """assign() for asyncio callers (the HTTP proxy): enqueue and
-        await dispatch WITHOUT parking a thread per request — the proxy's
-        request concurrency is then bounded by the event loop, not an
-        executor pool."""
-        import asyncio
-
-        q = _PendingQuery(data)
-        q.loop = asyncio.get_running_loop()
-        q.future = q.loop.create_future()
-        self._admit(q)
-        try:
-            return await asyncio.wait_for(asyncio.shield(q.future),
-                                          timeout)
-        except asyncio.TimeoutError:
-            self._abandon(q)
-            raise TimeoutError(
-                f"no replica accepted the query within {timeout}s")
-        except asyncio.CancelledError:
-            self._abandon(q)  # caller task cancelled (client disconnect)
-            raise
-
     async def call_async(self, data, timeout: float = 30.0):
         """One round trip for asyncio callers (the HTTP proxy): enqueue and
-        await the RESULT VALUE directly. Versus assign_async + `await ref`
-        this removes both per-request cross-thread wakeups: dispatch does
-        not notify the caller at all, and the reply's deserialized values
-        are delivered for the whole batch in one coalesced loop tick."""
+        await the RESULT VALUE directly, without parking a thread per
+        request. No per-request cross-thread wakeup: dispatch does not
+        notify the caller at all, and the reply's deserialized values are
+        delivered for the whole batch in one coalesced loop tick."""
         import asyncio
 
         q = _PendingQuery(data)
         q.loop = asyncio.get_running_loop()
         q.future = q.loop.create_future()
-        q.want_result = True
         self._admit(q)
         try:
             return await asyncio.wait_for(asyncio.shield(q.future), timeout)
@@ -733,7 +708,7 @@ class Router:
             refs = [out] if len(batch) == 1 else list(out)
             if not shadow:
                 for q, ref in zip(batch, refs):
-                    if q.want_result:
+                    if q.future is not None:
                         continue  # resolved at completion with the value
                     q.ref = ref
                     q._notify()
@@ -775,10 +750,10 @@ class Router:
         cw = global_state.get_core_worker()
         state = {"left": len(refs)}
         waiters = {ref.id(): q for q, ref in zip(batch, refs)
-                   if q.want_result}
+                   if q.future is not None}
         if batch:
             owned = {ref.id(): ref for q, ref in zip(batch, refs)
-                     if q.want_result}
+                     if q.future is not None}
         else:  # shadow: every result is nobody's — all router-owned
             owned = {ref.id(): ref for ref in refs}
 

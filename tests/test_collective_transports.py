@@ -283,8 +283,7 @@ def test_transport_exactness_matrix(device_workers):
     forcible and runs the same payloads over the XLA plane; 'pallas'
     runs the fused-kernel tier in interpret mode over the same
     runtime — the 6th tier must agree bitwise with the other 5.)"""
-    transports = ["hub", "shm", "ring", "ring_unpipelined", "device",
-                  "pallas"]
+    transports = ["hub", "shm", "ring", "device", "pallas"]
     workers = device_workers
     outs = ray_tpu.get(
         [w.run_matrix.remote(transports, 10_007) for w in workers],
@@ -671,13 +670,12 @@ def test_quantized_ring_wire_bytes_saved(ray_start_shared):
 
 def test_mean_product_parity_across_tiers(device_workers):
     """Satellite: ReduceOp.MEAN and PRODUCT agree across ALL tiers
-    (hub/shm/ring/ring_unpipelined/device) — PRODUCT bit-exact on
+    (hub/shm/ring/device) — PRODUCT bit-exact on
     small-integer payloads, MEAN with identical promotion semantics
     (float64 accumulate + float64 result for integer inputs)."""
     workers = device_workers
     _extra_group(workers, "g_parity")
-    transports = ["hub", "shm", "ring", "ring_unpipelined", "device",
-                  "pallas"]
+    transports = ["hub", "shm", "ring", "device", "pallas"]
     outs = ray_tpu.get(
         [w.parity_matrix.remote(transports, 4_099) for w in workers],
         timeout=scale_timeout(300))

@@ -211,16 +211,19 @@ def test_shard_kill_recovery(sharded_cluster):
 # lease spillback: raylet->raylet forwarding
 # ---------------------------------------------------------------------------
 
-def _lease_burst_rpcs(forwarding: bool, n_tasks: int = 100):
+def _lease_burst_rpcs(forward_fails: bool, n_tasks: int = 100):
     """Run a cross-node lease burst on a 2-node cluster (head has no
     CPUs, so every lease must come from the second node) and return
-    (owner lease RPCs, cluster metric snapshots)."""
+    (owner lease RPCs, cluster metric snapshots). `forward_fails` arms
+    the `lease.spillback` failpoint in the raylets at spawn: every
+    forward degrades to the owner-visible bounce, the fallback a dead
+    or unreachable peer takes."""
     from ray_tpu._private import global_state
     from ray_tpu.cluster_utils import Cluster
 
-    cluster = Cluster(
-        initialize_head=False,
-        _system_config={"lease_spillback_forwarding": forwarding})
+    if forward_fails:
+        os.environ[fp.ENV_VAR] = "lease.spillback=raise(role=raylet)"
+    cluster = Cluster(initialize_head=False)
     try:
         from ray_tpu._private.node import start_gcs
 
@@ -245,6 +248,8 @@ def _lease_burst_rpcs(forwarding: bool, n_tasks: int = 100):
         metrics = ray_tpu.cluster_metrics()
         return rpcs, metrics
     finally:
+        if forward_fails:
+            del os.environ[fp.ENV_VAR]
         cw = global_state.get_core_worker()
         if cw is not None:
             cw.shutdown()
@@ -252,22 +257,24 @@ def _lease_burst_rpcs(forwarding: bool, n_tasks: int = 100):
 
 
 def test_spillback_forwarding_cuts_owner_lease_rpcs():
-    """The tentpole claim, counter-verified: a 100-task cross-node burst
-    costs the owner >= 50% fewer request_worker_lease RPCs with
-    raylet->raylet forwarding than with the legacy owner-mediated bounce
-    (each legacy round trips owner->head, bounces, then owner->peer)."""
-    legacy_rpcs, legacy_metrics = _lease_burst_rpcs(forwarding=False)
-    fwd_rpcs, fwd_metrics = _lease_burst_rpcs(forwarding=True)
+    """Counter-verified: a 100-task cross-node burst costs the owner
+    >= 50% fewer request_worker_lease RPCs when the raylet->raylet
+    forward works than when every forward fails and degrades to the
+    owner-mediated bounce (each bounced round trips owner->head, then
+    owner->peer) — and the burst completes either way: the bounce is the
+    fallback liveness rests on."""
+    bounce_rpcs, bounce_metrics = _lease_burst_rpcs(forward_fails=True)
+    fwd_rpcs, fwd_metrics = _lease_burst_rpcs(forward_fails=False)
 
     # Structurally 2 owner RPCs/round (request -> bounce -> redial)
     # become 1 (the chain relays the grant): a >= 50% cut. +2 slack
     # tolerates ONE adoption-deadline race re-request (the owner drops a
     # grant the granting raylet already reaped and asks again) without
     # masking a broken chain.
-    assert fwd_rpcs * 2 <= legacy_rpcs + 2, (
-        f"forwarding used {fwd_rpcs} owner lease RPCs vs {legacy_rpcs} "
-        f"legacy — less than a 50% cut")
-    assert fwd_rpcs < legacy_rpcs
+    assert fwd_rpcs * 2 <= bounce_rpcs + 2, (
+        f"forwarding used {fwd_rpcs} owner lease RPCs vs {bounce_rpcs} "
+        f"bounced — less than a 50% cut")
+    assert fwd_rpcs < bounce_rpcs
 
     def counter(metrics, name):
         return sum(snap.get(name, {}).get("value", 0)
@@ -276,9 +283,9 @@ def test_spillback_forwarding_cuts_owner_lease_rpcs():
     # the chain really ran: the head forwarded, the peer granted for it
     assert counter(fwd_metrics, "raylet.spillback_forwards_total") > 0
     assert counter(fwd_metrics, "raylet.spillback_grants_total") > 0
-    # and the legacy arm really bounced (no forwarding)
-    assert counter(legacy_metrics, "raylet.spillback_forwards_total") == 0
-    assert counter(legacy_metrics, "raylet.spillbacks_total") > 0
+    # and the failed forwards really bounced (nothing was forwarded)
+    assert counter(bounce_metrics, "raylet.spillback_forwards_total") == 0
+    assert counter(bounce_metrics, "raylet.spillbacks_total") > 0
 
 
 # ---------------------------------------------------------------------------
@@ -396,12 +403,12 @@ def test_actor_subscriber_after_gcs_restart(gcs_cluster):
 def test_scalesim_smoke():
     """Tiny tier-1 scale-sim: a seeded shard kill mid-workload must lose
     ZERO acked ops and journal-replay bit-identical, and the sharded
-    arm's steady-state stream must bypass the director (its CPU/op
-    collapses vs the legacy arm). The raw-throughput comparison only
-    binds where the box has enough cores to host the shard tier
-    (>= shards+2): below that every process timeshares the same cores
-    and the extra per-tick syscalls of 4 sockets dominate (see
-    MICROBENCH control_plane notes)."""
+    arm's steady-state stream must bypass the director — asserted as a
+    COUNT (of the arm's acked KV writes, the director's own table holds
+    none: every one was routed to a shard), not as the CPU-per-op ratio
+    or the ops/s comparison between the arms, which a box loaded by five
+    other test workers decides (those stay in the result for the
+    microbenchmark rows)."""
     from ray_tpu.scalesim.harness import run_scalesim
 
     kwargs = dict(shards=4, raylets=4, windows=3, window_s=0.5,
@@ -419,15 +426,12 @@ def test_scalesim_smoke():
     assert kill["acked_ops_verified"] > 0
     assert kill["replay_identical"] is True
     # director bypass: steady-state table ops route around the director
-    ratio = result["director_bypass_ratio"]
-    assert ratio < 0.5, (
-        f"sharded arm still burns {ratio:.0%} of the legacy arm's "
-        f"director CPU per op — shard routing is not bypassing it")
-    if (os.cpu_count() or 2) >= result["shards"] + 2:
-        a = result["arms"][f"shards{result['shards']}"]
-        b = result["arms"]["shards1"]
-        assert (a["gcs_ops_per_s"]["median"]
-                >= b["gcs_ops_per_s"]["median"]), result["arms"]
+    bypass = result["director_bypass"]
+    assert bypass["acked_kv_writes"] == kill["acked_ops_verified"]
+    assert bypass["held_by_director"] == 0, (
+        f"{bypass['held_by_director']} of {bypass['acked_kv_writes']} "
+        f"acked KV writes landed in the director's own table — shard "
+        f"routing is not bypassing it")
 
 
 # ---------------------------------------------------------------------------

@@ -113,6 +113,55 @@ def test_streaming_pull_bit_exact(ray_start_cluster):
         "pulls did not ride the bulk data plane (pull_bytes_total flat)"
 
 
+def test_control_path_pull_when_streaming_raises(ray_start_cluster):
+    """The fallback _pull_any keeps: when the streaming pull raises (the
+    transfer.chunk_recv failpoint, armed at spawn on the PULLER only —
+    what an advertised-but-unreachable bulk channel looks like from
+    there), the chunked pull over the control connection carries the
+    object to the end, bit-exact, and leaves no build file and no pin."""
+    cluster = ray_start_cluster
+    from ray_tpu._private.node import start_gcs
+
+    cluster.gcs_svc, cluster.gcs_address = start_gcs(
+        cluster.session_dir, cluster.config)
+    os.environ[fp.ENV_VAR] = "transfer.chunk_recv=raise(role=raylet)"
+    try:
+        cluster.add_node(num_cpus=2, is_head=True)
+    finally:
+        del os.environ[fp.ENV_VAR]
+    src = cluster.add_node(num_cpus=1, resources={"src": 2})
+    cw = _connect(cluster)
+    produce = _producer("src")
+    head = cluster.head_node.address
+
+    before = _metric(cw, head, "raylet.pull_bytes_total")
+    # several control-path chunks (5 MiB each) with an odd tail, and one
+    # object smaller than a chunk
+    total = 0
+    for n, dtype in [(12 * 1024 * 1024 + 13, "uint8"),
+                     (777_777, "int32")]:
+        ref = produce.remote(n, dtype)
+        got = ray_tpu.get(ref, timeout=scale_timeout(90))
+        want = _expected(n, dtype)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, want), f"corruption at {n} {dtype}"
+        total += want.nbytes
+        del ref, got
+    # the streaming pull was tried and raised at its first chunk, so
+    # every byte counted came over the control connection
+    assert _metric(
+        cw, head, "failpoints.transfer.chunk_recv.fired_total") >= 2
+    assert _metric(cw, head, "raylet.pull_bytes_total") - before >= total
+    assert not glob.glob(os.path.join(
+        cluster.head_node.store_root, "*.build")), "leaked arena create"
+    deadline = time.monotonic() + scale_timeout(15)
+    while (time.monotonic() < deadline
+           and _metric(cw, src.address, "raylet.transfer_pins") != 0):
+        time.sleep(0.2)
+    assert _metric(cw, src.address, "raylet.transfer_pins") == 0, \
+        "the control-path pull left a transfer pin on the source"
+
+
 @pytest.mark.slow
 def test_streaming_pull_64mb_bit_exact(ray_start_cluster):
     """>=64MB with an odd tail through the streaming path, bit-exact."""
@@ -340,9 +389,9 @@ def test_chaos_source_death_mid_stream(seed, ray_start_cluster):
     finally:
         del os.environ[fp.ENV_VAR]
     cw = _connect(cluster)
-    produce = _producer("srcb")
+    produce = _producer("srcc")
 
-    @ray_tpu.remote(num_cpus=1, resources={"srcc": 1})
+    @ray_tpu.remote(num_cpus=1, resources={"srcb": 1})
     def touch(arr):
         return int(arr.nbytes)
 
@@ -350,12 +399,11 @@ def test_chaos_source_death_mid_stream(seed, ray_start_cluster):
     ref = produce.remote(n, "uint8")
     oid = ref.id().binary()
     _wait_locations(cw, oid, 1)
-    # Replicate to the survivor over the LEGACY path so the doomed
-    # node's chunk_send counter is untouched until the measured pull.
-    _call(cw, survivor.address, "set_transfer_mode", {"legacy": True})
+    # Produced on the survivor, replicated TO the doomed node: the
+    # doomed raylet only receives, so its chunk_send counter is
+    # untouched until the measured pull.
     assert ray_tpu.get(touch.remote(ref),
                        timeout=scale_timeout(120)) == n
-    _call(cw, survivor.address, "set_transfer_mode", {})
     _wait_locations(cw, oid, 2)
 
     # the striped pull: the doomed source exits at its nth chunk; the

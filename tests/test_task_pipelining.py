@@ -206,36 +206,6 @@ def test_task_path_survives_chaos(monkeypatch):
         ray_tpu.shutdown()
 
 
-def test_legacy_control_arm_still_works():
-    """The preserved round-7 control path (RAY_TPU_TASK_LEGACY — the
-    microbenchmark's A/B arm) must stay functional."""
-    ray_tpu.init(num_cpus=4)
-    try:
-        from ray_tpu._private import global_state
-
-        cw = global_state.require_core_worker()
-        cw._legacy = True
-
-        @ray_tpu.remote
-        def small(x):
-            return x + 1
-
-        assert ray_tpu.get(small.remote(1), timeout=scale_timeout(30)) == 2
-        assert ray_tpu.get([small.remote(i) for i in range(20)],
-                           timeout=scale_timeout(60)) == list(range(1, 21))
-
-        @ray_tpu.remote
-        class A:
-            def f(self):
-                return "ok"
-
-        a = A.remote()
-        assert ray_tpu.get(a.f.remote(), timeout=scale_timeout(30)) == "ok"
-        cw._legacy = False
-    finally:
-        ray_tpu.shutdown()
-
-
 # ---- memstore ready-callback semantics (h_get_object owner service) ----
 
 def test_memstore_delete_fires_callbacks():

@@ -76,10 +76,43 @@ def test_function_api_generator(ray_start_shared):
     assert analysis.best_result["acc"] == pytest.approx(1.5)
 
 
-def test_asha_stops_bad_trials_early(ray_start_shared):
+class GatedQuadratic(Quadratic):
+    """Quadratic whose hopeless trials (x far from 3) take their first
+    step only after every good one has begun its last (`horizon`): the
+    runner asks a trial for step k+1 only once it has handled result k,
+    so by then the scheduler holds all but the last result of each good
+    trial — what a bad trial is judged against no longer depends on which
+    actor the box happened to run first. `gate` is a directory the good
+    trials leave a marker in; `good` says how many to wait for."""
+
+    def setup(self, config):
+        super().setup(config)
+        self.gate = config["gate"]
+        self.good = config["good"]
+        self.horizon = config["horizon"]
+        self.steps = 0
+
+    def step(self):
+        import os
+        import time
+
+        self.steps += 1
+        if abs(self.x - 3) >= 1:
+            deadline = time.monotonic() + 120
+            while (self.steps == 1
+                   and len(os.listdir(self.gate)) < self.good
+                   and time.monotonic() < deadline):
+                time.sleep(0.02)
+        elif self.steps == self.horizon:
+            open(os.path.join(self.gate, f"good-{self.x}"), "w").close()
+        return super().step()
+
+
+def test_asha_stops_bad_trials_early(ray_start_shared, tmp_path):
     analysis = tune.run(
-        Quadratic,
-        config={"x": tune.grid_search([3, 30, 40, 50])},
+        GatedQuadratic,
+        config={"x": tune.grid_search([3, 30, 40, 50]),
+                "gate": str(tmp_path), "good": 1, "horizon": 20},
         stop={"training_iteration": 20},
         scheduler=ASHAScheduler(metric="score", mode="max",
                                 grace_period=2, reduction_factor=2,
@@ -87,20 +120,31 @@ def test_asha_stops_bad_trials_early(ray_start_shared):
         metric="score", mode="max")
     assert analysis.best_config["x"] == 3
     iters = {t.config["x"]: t.iteration for t in analysis.trials}
-    # the hopeless configs must have been cut before the horizon
-    assert min(iters[30], iters[40], iters[50]) < 20
+    # the good trial met every rung (2, 4, 8, 16) first and ran to the
+    # horizon; each hopeless one is cut at the first rung where the good
+    # one's record is in the top half above it: rung 2, or rung 4 for
+    # the one that arrives at rung 2 as the best of the rest
+    assert iters[3] == 20
+    assert sorted(iters[x] for x in (30, 40, 50))[:2] == [2, 2]
+    assert max(iters[30], iters[40], iters[50]) <= 4
 
 
-def test_median_stopping(ray_start_shared):
+def test_median_stopping(ray_start_shared, tmp_path):
+    grace, horizon = 3, 12
     analysis = tune.run(
-        Quadratic,
-        config={"x": tune.grid_search([3, 3.1, 2.9, 50])},
-        stop={"training_iteration": 12},
+        GatedQuadratic,
+        config={"x": tune.grid_search([3, 3.1, 2.9, 50]),
+                "gate": str(tmp_path), "good": 3, "horizon": horizon},
+        stop={"training_iteration": horizon},
         scheduler=MedianStoppingRule(metric="score", mode="max",
-                                     grace_period=3),
+                                     grace_period=grace),
         metric="score", mode="max")
-    bad = next(t for t in analysis.trials if t.config["x"] == 50)
-    assert bad.iteration < 12
+    iters = {t.config["x"]: t.iteration for t in analysis.trials}
+    # the bad trial is cut at the first result past its grace period,
+    # when three peers' running means at that step exist to judge it by;
+    # no peer is cut (each one's latest score beats the others' means)
+    assert iters[50] == grace
+    assert [iters[x] for x in (3, 3.1, 2.9)] == [horizon] * 3
 
 
 def test_pbt_perturbs_and_improves(ray_start_shared):

@@ -36,18 +36,30 @@ def test_hyperband_culls_bad_trials(ray_start_shared):
     assert iters[0] < 9, f"nothing was culled early: {iters}"
 
 
-def test_pb2_perturbs_within_bounds(ray_start_shared):
+def test_pb2_perturbs_within_bounds(ray_start_shared, tmp_path):
     scheduler = PB2(metric="score", mode="max", perturbation_interval=2,
                     hyperparam_bounds={"lr": (1e-4, 1e-1)}, seed=0)
 
     def trainable(config):
+        # PB2 exploits within a population that is alive TOGETHER: no
+        # trial reports before all four have started (a trial restarted
+        # by a perturbation finds the gate open), so a loaded box that
+        # starts the actors one after another cannot run them in turn
+        import time
+        import uuid
+
+        open(os.path.join(config["gate"], uuid.uuid4().hex), "w").close()
+        deadline = time.monotonic() + 120
+        while (len(os.listdir(config["gate"])) < 4
+               and time.monotonic() < deadline):
+            time.sleep(0.02)
         lr = config["lr"]
         for i in range(12):
             tune.report(score=lr * (i + 1), training_iteration=i + 1)
 
     analysis = tune.run(
         trainable,
-        config={"lr": tune.loguniform(1e-4, 1e-1)},
+        config={"lr": tune.loguniform(1e-4, 1e-1), "gate": str(tmp_path)},
         num_samples=4,
         metric="score",
         mode="max",
