@@ -121,19 +121,35 @@ def fsdp_mesh(devices=None) -> Mesh:
 
 def fsdp_param_specs(params, mesh: Mesh):
     """Per-leaf PartitionSpecs: each leaf is split over the 'fsdp' axis
-    along its FIRST dimension that divides evenly (GPT-2's embedding,
-    50257 x 1280, along 1280); leaves with none (odd biases, scalars)
-    stay replicated — the standard FSDP layout compromise. Leaves need
-    a `shape` only (`jax.eval_shape` of an init will do)."""
+    along the first dimension AFTER its leading one that divides evenly
+    (a stacked block weight, 36 x 1280 x 5120, along 1280; GPT-2's
+    embedding, 50257 x 1280, along 1280), along the leading one only
+    when no later one does (and a one-dimensional leaf has no other);
+    leaves with none (odd biases, scalars) stay replicated — the
+    standard FSDP layout compromise. Leaves need a `shape` only
+    (`jax.eval_shape` of an init will do).
+
+    The leading dimension comes last because it is the one a model
+    stacks its layers along and `lax.scan` walks: a stack split there
+    puts whole layers on each device, every device needs every layer,
+    and the compiler gathers the WHOLE stack for each layer's step of
+    the scan (36 gathers of 36 layers a pass on GPT-2 large). Split
+    along a later dimension, the scan's body gathers its own layer's
+    slice and no more. Shapes cannot tell a stack from a plain matrix
+    and need not: either dimension of a matrix serves ZeRO-3 equally.
+    The EARLIEST later dimension, so that a shard stays a few long runs
+    of the host copy a snapshot joins it into (`operator._to_host`)."""
     fsdp = mesh.shape["fsdp"]
 
     def spec(p):
         if fsdp > 1:
             shape = getattr(p, "shape", ())
-            for dim, n in enumerate(shape):
-                if n >= fsdp and n % fsdp == 0:
-                    return P(*[None] * dim, "fsdp",
-                             *[None] * (len(shape) - dim - 1))
+            divides = [dim for dim, n in enumerate(shape)
+                       if n >= fsdp and n % fsdp == 0]
+            if divides:
+                dim = next((d for d in divides if d > 0), 0)
+                return P(*[None] * dim, "fsdp",
+                         *[None] * (len(shape) - dim - 1))
         return P()
 
     return jax.tree.map(spec, params)

@@ -464,24 +464,31 @@ class TrainingOperator:
         devices the step runs on (`chips`), the mesh's shape (mesh mode
         only), the bytes of parameters, model and optimizer state as a
         whole (`state_bytes`) and the addressable shard bytes of them on
-        the fullest device (`state_bytes_fullest_chip`). Layouts are
+        the fullest device (`state_bytes_fullest_chip`), and the bytes
+        of leaves of two or more dimensions that are split along their
+        LEADING one (`state_bytes_split_leading`: the axis a layer scan
+        walks, so the step gathers such a stack whole; 0 under
+        `fsdp_param_specs` when a later dimension divides). Layouts are
         pinned at register(), so this is reckoned once."""
         facts = self._facts
         if facts is None:
             held: dict = {}
-            whole = 0
+            whole = split_leading = 0
             for x in jax.tree.leaves((self.params, self.model_state,
                                       self.opt_state)):
                 if not isinstance(x, jax.Array):
                     continue
                 whole += x.nbytes
-                shard = int(np.prod(x.sharding.shard_shape(x.shape))
-                            ) * x.dtype.itemsize
+                shard_shape = x.sharding.shard_shape(x.shape)
+                shard = int(np.prod(shard_shape)) * x.dtype.itemsize
                 for d in x.sharding.addressable_devices:
                     held[d] = held.get(d, 0) + shard
+                if x.ndim >= 2 and shard_shape[0] < x.shape[0]:
+                    split_leading += x.nbytes
             facts = self._facts = {
                 "chips": max(len(held), 1), "state_bytes": whole,
-                "state_bytes_fullest_chip": max(held.values(), default=0)}
+                "state_bytes_fullest_chip": max(held.values(), default=0),
+                "state_bytes_split_leading": split_leading}
             if self._mesh is not None:
                 facts["mesh"] = [int(n) for n in self._mesh.shape.values()]
         return facts

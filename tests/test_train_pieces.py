@@ -20,16 +20,27 @@ from ray_tpu.train import Trainer, TrainingOperator, call_log
 from ray_tpu.train import operator as operator_mod
 from ray_tpu.train import snapshot
 
+P = jax.sharding.PartitionSpec
+
 ARENA = 8 << 20
 TINY = {"batch": 8, "seq": 128, "seed": 3}
 
 
-def _tiny_pieces(optimizer="adamw"):
+STACK = 12      # layers that divide by four chips, and no other extent
+
+
+def _tiny_pieces(optimizer="adamw", layers=None):
+    import dataclasses
+
     import optax
 
     from ray_tpu.models import transformer
 
+    # TINY's two layers do not divide by four chips, so no rule splits
+    # its stacks along them; `layers=STACK` makes stacks a rule could
     cfg = transformer.TINY
+    if layers:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
     tokens = jax.random.randint(jax.random.key(TINY["seed"] + 1),
                                 (TINY["batch"], TINY["seq"]), 0,
                                 cfg.vocab_size)
@@ -43,7 +54,7 @@ class TinyGPT(TrainingOperator):
 
     def setup(self, config):
         init, loss_fn, opt, tokens = _tiny_pieces(
-            config.get("optimizer", "adamw"))
+            config.get("optimizer", "adamw"), config.get("layers"))
         self.register(model_init=init, loss_fn=loss_fn, optimizer=opt,
                       seed=TINY["seed"])
         self.register_data(train_loader=[tokens] * 3)
@@ -140,9 +151,9 @@ def _operator(monkeypatch, chips, **config):
     return TinyGPT(config, 0, 1)
 
 
-def test_every_leaf_that_divides_is_split_along_its_first_such_dim(
+def test_every_leaf_that_divides_is_split_past_its_leading_dim_if_it_can(
         monkeypatch):
-    op = _operator(monkeypatch, 4)
+    op = _operator(monkeypatch, 4, layers=STACK)
     assert dict(op._mesh.shape) == {"data": 1, "fsdp": 4}
     assert len(op._mesh.devices.flat) == 4      # of the 8 there are
     state = jax.tree.leaves((op.params, op.opt_state))
@@ -150,25 +161,173 @@ def test_every_leaf_that_divides_is_split_along_its_first_such_dim(
     for x in state:
         dims = [d for d, n in enumerate(x.shape) if n % 4 == 0]
         spec = tuple(x.sharding.spec) + (None,) * x.ndim
-        if dims:
-            assert spec[dims[0]] == "fsdp", (x.shape, x.sharding.spec)
+        if dims:    # the first that divides after the leading one
+            dim = ([d for d in dims if d] or dims)[0]
+            assert spec[dim] == "fsdp", (x.shape, x.sharding.spec)
+            assert spec.count("fsdp") == 1
             assert x.addressable_shards[0].data.size * 4 == x.size
         else:
             assert x.is_fully_replicated
-    # the embedding goes along its width, not its 256 rows... here both
-    # divide; GPT-2's 50257 rows do not
+    # the twelve layers divide by four and are left whole all the same
+    assert op.params["blocks"]["wqkv"].sharding.spec == P(None, "fsdp", None)
+    assert op.params["blocks"]["b_in"].sharding.spec == P(None, "fsdp")
+    # at GPT-2 large's shapes: no stack along its layers, the embedding
+    # along its width (50257 rows do not divide), the positions too now
     from ray_tpu.parallel import mesh as meshlib
-    from jax.sharding import PartitionSpec as P
 
     specs = meshlib.fsdp_param_specs(
         {"wte": jax.ShapeDtypeStruct((50257, 1280), jnp.float32),
+         "wpe": jax.ShapeDtypeStruct((1024, 1280), jnp.float32),
          "wqkv": jax.ShapeDtypeStruct((36, 1280, 3840), jnp.float32),
+         "w_out": jax.ShapeDtypeStruct((36, 5120, 1280), jnp.float32),
+         "ln1_w": jax.ShapeDtypeStruct((36, 1280), jnp.float32),
+         "lnf_w": jax.ShapeDtypeStruct((1280,), jnp.float32),
          "odd": jax.ShapeDtypeStruct((3, 5), jnp.float32)}, op._mesh)
-    assert specs == {"wte": P(None, "fsdp"), "odd": P(),
-                     "wqkv": P("fsdp", None, None)}
+    assert specs == {"wte": P(None, "fsdp"), "wpe": P(None, "fsdp"),
+                     "wqkv": P(None, "fsdp", None),
+                     "w_out": P(None, "fsdp", None),
+                     "ln1_w": P(None, "fsdp"), "lnf_w": P("fsdp"),
+                     "odd": P()}
     facts = op._layout_facts()
     assert facts["state_bytes_fullest_chip"] <= 0.3 * facts["state_bytes"]
+    assert facts["state_bytes_split_leading"] == 0
     assert facts["mesh"] == [1, 4] and facts["chips"] == 4
+
+
+def test_split_leading_counts_the_stacks_a_layout_splits_along_layers(
+        monkeypatch):
+    """`state_bytes_split_leading` under the layout the rule used to
+    give: every stacked leaf and its two moments, and nothing of one
+    dimension."""
+    facts = _stack_operator(monkeypatch, "leading")._layout_facts()
+    shapes = jax.tree.leaves(jax.eval_shape(
+        _tiny_pieces(layers=STACK)[0], jax.random.key(0)))
+    stacked = sum(4 * int(np.prod(x.shape)) for x in shapes
+                  if x.ndim >= 2 and x.shape[0] % 4 == 0)
+    assert facts["state_bytes_split_leading"] == 3 * stacked > 0
+    assert stacked < sum(4 * int(np.prod(x.shape)) for x in shapes)
+
+
+def _leading_spec(x):
+    """The first dimension that divides by four: the rule before PR 30."""
+    for dim, n in enumerate(x.shape):
+        if n % 4 == 0:
+            return P(*[None] * dim, "fsdp")
+    return P()
+
+
+def _stack_operator(monkeypatch, layout):
+    """The twelve-layer tiny GPT on four chips, laid out by the rule
+    (`layout="rule"`) or as the rule did before (`"leading"`: handed to
+    `register` as any `param_spec` is)."""
+    init, loss_fn, opt, _ = _tiny_pieces(layers=STACK)
+    monkeypatch.setattr(operator_mod, "_leased_chips", lambda: 4)
+
+    class Op(TrainingOperator):
+        def setup(self, config):
+            spec = None if layout == "rule" else jax.tree.map(
+                _leading_spec, jax.eval_shape(init, jax.random.key(0)))
+            self.register(model_init=init, loss_fn=loss_fn, optimizer=opt,
+                          param_spec=spec)
+
+    return Op({}, 0, 1)
+
+
+# ---------------------------------------------------------------------
+# a layer's weights are gathered inside the layer scan
+# ---------------------------------------------------------------------
+
+def _collectives(text):
+    """(operation, result shapes, inside a while body?) of every
+    collective in a compiled module's text. A computation is inside when
+    a while names it as its body or one that is inside calls it."""
+    import re
+
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name and line.startswith(" "):
+            comps[name].append(line)
+    inside = set()
+    grow = {m for lines in comps.values() for line in lines
+            for m in re.findall(r"body=%?([\w.\-]+)", line)}
+    while grow:
+        inside |= grow
+        grow = {m for c in grow for line in comps.get(c, ())
+                for m in re.findall(
+                    r"(?:calls|to_apply|body|condition|branch_computations)"
+                    r"=\{?%?([\w.\-]+)", line)} - inside
+    found = []
+    for comp, lines in comps.items():
+        for line in lines:
+            m = re.search(r"= (.*?) (all-gather|all-reduce|reduce-scatter|"
+                          r"all-to-all|collective-permute)(?:-start)?\(",
+                          line)
+            if m:
+                shapes = [tuple(int(n) for n in dims.split(",") if n)
+                          for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                                 m.group(1))]
+                found.append((m.group(2), shapes, comp in inside))
+    return found
+
+
+@pytest.mark.parametrize("layout", ["rule", "leading"])
+def test_the_mesh_step_gathers_a_layer_inside_the_scan_not_stacks_before(
+        monkeypatch, layout):
+    """The operator's fused mesh step for a twelve-layer tiny GPT, as
+    the partitioner compiles it: under the rule no all-gather outside a
+    while body has the stack's extent, and the scans' bodies hold the
+    per-layer ones. `leading` is the control that the reading can fail:
+    the layout the rule gave before, and its gathers of whole stacks."""
+    tokens = _tiny_pieces()[-1]
+    found = _collectives(
+        _stack_operator(monkeypatch, layout).compiled_step_text(tokens))
+    gathers = [(shapes, inside) for op, shapes, inside in found
+               if op == "all-gather"]
+    assert len(gathers) >= 8, found
+    stacks_outside = [s for shapes, inside in gathers if not inside
+                      for s in shapes if len(s) >= 2 and STACK in s[:1]]
+    # one layer of a stacked matrix: (64, 192), or (1, 64, 192)
+    a_layer = [s for shapes, inside in gathers if inside for s in shapes
+               if STACK not in s and len([n for n in s if n > 1]) == 2]
+    if layout == "rule":
+        assert not stacks_outside
+        assert not [s for shapes, _ in gathers for s in shapes
+                    if STACK in s]          # nor anywhere else
+        assert len(a_layer) >= 8, gathers   # four a pass, at the least
+    else:
+        assert len(stacks_outside) >= 4, gathers
+        assert not a_layer
+
+
+def test_three_steps_with_the_stacks_split_past_their_layers_match_one_device(
+        monkeypatch):
+    tokens = _tiny_pieces()[-1]
+    mesh_op = _operator(monkeypatch, 4, layers=STACK)
+    one = _operator(monkeypatch, 1, layers=STACK)
+    assert one._mesh is None
+    assert one._layout_facts()["state_bytes_split_leading"] == 0
+    w_in = mesh_op.params["blocks"]["w_in"]
+    assert w_in.sharding.spec == P(None, "fsdp", None)
+    assert w_in.sharding.shard_shape(w_in.shape) == (STACK, 16, 256)
+    mesh_losses = [mesh_op.train_batch(tokens)["train_loss"]
+                   for _ in range(3)]
+    one_losses = [one.train_batch(tokens)["train_loss"] for _ in range(3)]
+    # the tolerance of the two-layer test above, and its reasons
+    assert mesh_losses == pytest.approx(one_losses, rel=2e-4)
+    assert mesh_losses[2] < mesh_losses[0]
+    # a leaf split along dimension 1 comes back from `state_piece` as
+    # `np.asarray` gives it, joined from four shards of twelve runs each
+    want = _bits(jax.tree.map(
+        lambda x: np.asarray(x) if isinstance(x, jax.Array) else x,
+        mesh_op._state_tree()))
+    copies, _ = _pull(mesh_op, usable=1 << 21)      # in several pieces
+    assert _bits(copies) == want
+    _, (counts,) = _traced_d2h(lambda: mesh_op.state_piece(0, 1 << 30))
+    assert counts["staged_bytes"] > 0.9 * counts["bytes"]
 
 
 def _reference(init, tokens):
@@ -397,7 +556,7 @@ def _pull(op, usable=1 << 30):
 
 
 def _sharded_cases(mesh):
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh, NamedSharding
 
     rng = np.random.default_rng(7)
 

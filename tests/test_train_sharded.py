@@ -193,25 +193,37 @@ def test_merge_rejects_bad_rank_set():
         shardlib.merge_opt_shards([])
 
 
-def test_fsdp_param_spec_rules():
+@pytest.mark.parametrize("fsdp, shape, spec", [
+    # a stack of layers: never along the axis the layer scan walks
+    (4, (36, 1280, 3840), (None, "fsdp", None)),
+    (4, (8, 64), (None, "fsdp")),             # stacked norms and biases
+    (4, (8, 6, 64), (None, None, "fsdp")),    # the earliest LATER one
+    # the leading dimension only when no later one divides
+    (4, (8, 3), ("fsdp", None)),
+    (4, (50257, 1280), (None, "fsdp")),       # GPT-2's embedding
+    (4, (1024, 1280), (None, "fsdp")),        # ... and its positions
+    (4, (3, 3, 64, 64), (None, None, "fsdp", None)),    # a conv kernel
+    (4, (2048, 1000), (None, "fsdp")),
+    (4, (4,), ("fsdp",)),                     # one dimension: that one
+    (4, (6,), ()),
+    (4, (3, 5), ()),                          # nothing divides: whole
+    (4, (2, 3), ()),                          # ... 2 < 4 is not a split
+    (4, (), ()),
+    (2, (36, 1280, 3840), (None, "fsdp", None)),
+    (1, (36, 1280, 3840), ()),                # nothing to split over
+    (1, (4,), ()),
+])
+def test_fsdp_param_spec_rules(fsdp, shape, spec):
     import types
 
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.parallel import mesh as meshlib
 
-    mesh = types.SimpleNamespace(shape={"fsdp": 4})
-    params = {"w": np.zeros((8, 3)), "v": np.zeros((4,)),
-              "odd": np.zeros((3, 5)), "s": np.zeros(())}
-    specs = meshlib.fsdp_param_specs(params, mesh)
-    assert specs["w"] == P("fsdp", None)       # 8 % 4 == 0: sharded
-    assert specs["v"] == P("fsdp")
-    assert specs["odd"] == P()                 # 3 % 4 != 0: replicated
-    assert specs["s"] == P()                   # scalar: replicated
-    # fsdp axis of 1 means nothing to shard over
-    none = meshlib.fsdp_param_specs(params, types.SimpleNamespace(
-        shape={"fsdp": 1}))
-    assert all(s == P() for s in none.values())
+    mesh = types.SimpleNamespace(shape={"fsdp": fsdp})
+    specs = meshlib.fsdp_param_specs(
+        {"leaf": np.zeros(shape), "nested": [np.zeros(shape)]}, mesh)
+    assert specs == {"leaf": P(*spec), "nested": [P(*spec)]}
 
 
 def test_trainer_mode_validation():

@@ -111,7 +111,7 @@ def test_one_call_is_one_tree_across_driver_and_worker(wide):
     held = dispatch["attrs"]["state_bytes"]   # whole, on the one device
     assert held > 0 and dispatch["attrs"] == {
         "steps": 2, "samples": 16, "chips": 1, "state_bytes": held,
-        "state_bytes_fullest_chip": held}
+        "state_bytes_fullest_chip": held, "state_bytes_split_leading": 0}
     snapshot = next(s for s in entry["spans"]
                     if s["name"] == "train.snapshot")
     assert snapshot["attrs"] == {"pieces": 1, "bytes": held}
