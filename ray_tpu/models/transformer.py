@@ -10,7 +10,8 @@ python/ray/util/sgd/torch/examples/, rllib/models/) — designed TPU-first:
 - layers are stacked along a leading axis and applied with `lax.scan`
   (one trace per block → fast compiles, XLA-friendly).
 - attention is `ops.flash_attention` (pallas on TPU, dense fallback on CPU);
-  norms are `ops.rmsnorm`/`layernorm` pallas kernels.
+  norms are the `ops.layernorm` pallas kernel (`ops.rmsnorm` is the
+  pattern decoder's, `models/decoder.py`).
 - compute dtype bfloat16 for the MXU, params fp32.
 """
 

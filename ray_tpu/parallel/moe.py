@@ -10,6 +10,7 @@ model] buffers riding ICI.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -88,3 +89,170 @@ def moe_apply(x, router_w, w_in, w_out, *, mesh: Mesh,
     )
     out, aux = fn(x, router_w, w_in, w_out)
     return out, jnp.mean(aux)
+
+
+# ----------------------------------------------------------------------
+# Dropless top-k routing over a HELD share of the experts
+# ----------------------------------------------------------------------
+#
+# The Switch path above pads every expert to a capacity and drops what
+# does not fit; its [N, E, C] masks grow with tokens x experts x
+# capacity. The layer below drops nothing and is told which experts it
+# holds (`held = (first, count)`, the chip's share of an expert-parallel
+# deployment): it routes over ALL `n_experts`, groups the token-expert
+# assignments that fall on its own experts by expert (a counting sort),
+# multiplies each group by its expert (`ops/moe_gmm.py`) and combines by
+# routing weight. What the absent experts would add is left out: on one
+# chip the layer runs without its exchange, and nothing stands in for
+# the other chips.
+
+GMM_TILE = 512     # rows a tile of the grouped matmul holds
+
+
+class Grouping(NamedTuple):
+    """Where every assignment of a step sits among the rows the grouped
+    matmul walks. Assignment a = token * k + slot."""
+
+    row_of: jax.Array         # [N, k] its row; meaningless unless `held`
+    held: jax.Array           # [N, k] bool: it falls on an expert held here
+    assign_of_row: jax.Array  # [R] the assignment in a row
+    row_valid: jax.Array      # [R] bool: the row holds one
+    tile_group: jax.Array     # [R // tile] the expert of each row tile
+    n_tiles: jax.Array        # [1] the tiles that hold rows
+    expert_tokens: jax.Array  # [count] assignments each held expert got
+
+
+def route_topk(router_logits, top_k: int):
+    """router_logits: [N, E] -> (experts [N, k] int32, weights [N, k]
+    float32): the k largest logits and their softmax — the softmax over
+    all E renormalised over the chosen k."""
+    top, idx = jax.lax.top_k(router_logits.astype(jnp.float32), top_k)
+    return idx, jax.nn.softmax(top, axis=-1)
+
+
+def group_by_expert(expert_idx, held: tuple[int, int],
+                    tile: int = GMM_TILE) -> Grouping:
+    """Lay the assignments that fall on experts [first, first + count)
+    out by expert, every expert's run padded to whole tiles (at least
+    one, so every expert's weight gradient is written). A counting sort:
+    an assignment's rank in its expert is a running count, no sort. The
+    static row count is the worst case, all N * k assignments held here;
+    the tiles really filled are counted in `n_tiles`."""
+    first, count = held
+    n, k = expert_idx.shape
+    a = n * k
+    local = expert_idx.reshape(a) - first
+    is_held = (local >= 0) & (local < count)
+    onehot = (local[:, None] == jnp.arange(count)[None, :])       # [A, count]
+    running = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    sizes = running[-1]                                           # [count]
+    rank = jnp.take_along_axis(
+        running, jnp.clip(local, 0, count - 1)[:, None], axis=1)[:, 0] - 1
+    tiles = jnp.maximum(1, -(-sizes // tile))
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile
+    rows = -(-a // tile) * tile + count * tile
+    row_of = jnp.where(is_held,
+                       row_start[jnp.clip(local, 0, count - 1)] + rank, rows)
+    assign_of_row = jnp.full((rows,), a, jnp.int32).at[row_of].set(
+        jnp.arange(a, dtype=jnp.int32), mode="drop")
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(rows // tile), side="right"),
+        count - 1).astype(jnp.int32)
+    return Grouping(
+        row_of=jnp.where(is_held, row_of, 0).reshape(n, k),
+        held=is_held.reshape(n, k), assign_of_row=assign_of_row,
+        row_valid=assign_of_row < a, tile_group=tile_group,
+        n_tiles=tile_end[-1:].astype(jnp.int32), expert_tokens=sizes)
+
+
+@jax.custom_vjp
+def _dispatch(y, g: Grouping):
+    """[N, D] tokens -> [R, D] rows: row r holds the token of its
+    assignment, zeros where it holds none. Gathers both ways: the
+    backward sums, per token, the rows of its held assignments."""
+    k = g.row_of.shape[1]
+    tok = jnp.minimum(g.assign_of_row // k, y.shape[0] - 1)
+    return jnp.where(g.row_valid[:, None], y[tok], 0)
+
+
+def _dispatch_fwd(y, g):
+    return _dispatch(y, g), g
+
+
+def _dispatch_bwd(g, dx):
+    dy = 0
+    for slot in range(g.row_of.shape[1]):
+        dy = dy + jnp.where(g.held[:, slot, None], dx[g.row_of[:, slot]], 0)
+    return dy, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weights, g: Grouping):
+    """[R, D] expert outputs, [N, k] routing weights -> [N, D]: every
+    token's held assignments, weighted, summed in float32."""
+    out = 0
+    for slot in range(g.row_of.shape[1]):
+        out = out + jnp.where(
+            g.held[:, slot, None],
+            weights[:, slot, None] * rows[g.row_of[:, slot]], 0)
+    return out.astype(rows.dtype)
+
+
+def _combine_fwd(rows, weights, g):
+    return _combine(rows, weights, g), (rows, weights, g)
+
+
+def _combine_bwd(res, dout):
+    rows, weights, g = res
+    n, k = weights.shape
+    a = jnp.minimum(g.assign_of_row, n * k - 1)
+    w_row = weights.reshape(n * k)[a]
+    drows = jnp.where(g.row_valid[:, None],
+                      w_row[:, None] * dout[a // k], 0).astype(rows.dtype)
+    dw = jnp.stack([
+        jnp.where(g.held[:, slot],
+                  (rows[g.row_of[:, slot]].astype(jnp.float32)
+                   * dout.astype(jnp.float32)).sum(-1), 0)
+        for slot in range(k)], axis=1)
+    return drows, dw.astype(weights.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
+                 held: tuple[int, int], tile: int = GMM_TILE):
+    """Top-k gated-ReLU experts over a held share, no token dropped.
+
+    y: [N, D] tokens (compute dtype); router_logits: [N, n_experts], the
+    router's output over ALL experts, float32; w_gate, w_up: [count, D,
+    F] and w_down: [count, F, D], the held experts' weights. Returns
+    (out [N, D]: sum over the chosen AND held experts e of
+    p_e * W_down,e (relu(W_gate,e y) * (W_up,e y)), counts): `counts`
+    holds `expert_tokens` [count] (assignments each held expert got),
+    `assignments` (N * k), `held` (those on held experts) and `dropped`
+    (held assignments that found no row: 0, by construction, and
+    counted from the layout rather than assumed)."""
+    from ray_tpu.ops.moe_gmm import moe_gmm
+
+    idx, weights = route_topk(router_logits, top_k)
+    g = group_by_expert(idx, held, tile)
+    f = w_gate.shape[-1]
+    with jax.named_scope("experts"):
+        x = _dispatch(y, g)
+        gate_up = moe_gmm(x, jnp.concatenate([w_gate, w_up], axis=-1),
+                          g.tile_group, g.n_tiles, tile)
+        act = jax.nn.relu(gate_up[:, :f]) * gate_up[:, f:]
+        rows = moe_gmm(act, w_down, g.tile_group, g.n_tiles, tile)
+        out = _combine(rows, weights, g)
+    n_held = g.held.sum()
+    counts = {
+        "expert_tokens": g.expert_tokens.astype(jnp.int32),
+        "assignments": jnp.asarray(idx.size, jnp.int32),
+        "held": n_held.astype(jnp.int32),
+        "dropped": (n_held - g.row_valid.sum()).astype(jnp.int32)}
+    return out, counts

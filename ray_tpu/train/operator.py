@@ -75,6 +75,13 @@ class TrainingOperator:
         optimizer: optax GradientTransformation.
         eval_fn(params[, state], batch) -> metrics dict (defaults to
             loss_fn in eval position).
+            A state dict with the key "epoch_counters" — a flat dict
+            of SCALAR arrays the loss_fn updates every step on the
+            device (adds to, takes a maximum into, sets: tokens an
+            expert got, ...) — gets them zeroed when an epoch starts and
+            read ONCE, in `train.sync` with the losses: no sync inside
+            the epoch. Each goes on the `train.sync` span under its key
+            and into the epoch's result ("counters").
 
         mesh: a jax Mesh (possibly GLOBAL, spanning worker processes via
             parallel.multihost) — the step runs SPMD over it and gradient
@@ -97,6 +104,12 @@ class TrainingOperator:
         else:
             self.params = model_init(jax.random.key(seed))
             self.model_state = None
+        self._epoch_counters = (isinstance(self.model_state, dict)
+                                and "epoch_counters" in self.model_state)
+        if self._epoch_counters and any(
+                jnp.ndim(x) for x in
+                self.model_state["epoch_counters"].values()):
+            raise ValueError("epoch_counters is a flat dict of scalars")
         chips = _leased_chips()
         if mesh is None and (self.config.get("mesh_mode") == "fsdp" or (
                 chips > 1 and not self.config.get("sharded_update")
@@ -527,6 +540,11 @@ class TrainingOperator:
             with _tracing.span("train.dispatch",
                                _tracing.child_of_current(), counts):
                 t_step = t0 = time.perf_counter()
+                if self._epoch_counters:
+                    self.model_state = {
+                        **self.model_state, "epoch_counters": jax.tree.map(
+                            jnp.zeros_like,
+                            self.model_state["epoch_counters"])}
                 for batch in self._train_loader:
                     losses.append(self._dispatch_batch(batch))
                     self.global_step += 1
@@ -545,14 +563,20 @@ class TrainingOperator:
                 counts.update(steps=step, samples=samples,
                               **self._layout_facts())
             # One sync for the whole epoch: the loop was async dispatch.
-            with _tracing.span("train.sync", _tracing.child_of_current()):
+            counters = {}
+            with _tracing.span("train.sync", _tracing.child_of_current(),
+                               counters):
                 losses = [float(x) for x in losses]
                 dt = time.perf_counter() - t0
+                if self._epoch_counters:
+                    counters.update(
+                        (k, v.item()) for k, v in jax.device_get(
+                            self.model_state["epoch_counters"]).items())
         finally:
             if profile_dir:
                 self.stop_profile()
         self.epoch += 1
-        return {
+        out = {
             "epoch": self.epoch,
             "batch_count": len(losses),
             "num_samples": samples,
@@ -560,6 +584,9 @@ class TrainingOperator:
             "last_train_loss": losses[-1] if losses else float("nan"),
             "samples_per_s": samples / dt if dt > 0 else 0.0,
         }
+        if self._epoch_counters:
+            out["counters"] = counters
+        return out
 
     def validate(self, num_steps: int | None = None) -> dict:
         if self._val_loader is None:

@@ -45,9 +45,9 @@ def on_tpu(monkeypatch):
     """jax.default_backend() is the CPU here, so the ops would choose
     interpret mode: steer them to Mosaic, in the test."""
     from ray_tpu.collective.backends import pallas_backend
-    from ray_tpu.ops import attention, batchnorm, layernorm
+    from ray_tpu.ops import attention, batchnorm, layernorm, moe_gmm
 
-    for mod in (attention, batchnorm, layernorm, pallas_backend):
+    for mod in (attention, batchnorm, layernorm, moe_gmm, pallas_backend):
         monkeypatch.setattr(mod, "is_tpu", lambda: True)
 
 
@@ -64,6 +64,46 @@ def test_flash_attention_fwd(one_chip, on_tpu):
         lambda q, k, v: attention.flash_attention(q, k, v, True),
         qkv, qkv, qkv)
     assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("window", [4096, None])
+def test_flash_attention_window_and_grouped_heads(one_chip, on_tpu, window):
+    """SmallThinker's widths at 8k: 28 query heads on 4 key/value heads
+    of 128, the forward kernel's 256 x 512 tiles, forward and backward
+    (the backward is XLA: one Mosaic call, the forward's)."""
+    from ray_tpu.ops import attention
+
+    q = jax.ShapeDtypeStruct((1, 8192, 28, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    text = _compiled_text(jax.value_and_grad(
+        lambda q, k, v: attention.flash_attention(
+            q, k, v, True, None, 256, 512, window).astype(
+                jnp.float32).sum(), (0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
+    """The dropless expert layer at SmallThinker's widths (16 held
+    experts of 2560 -> 768, top-6 of 64) over 8 192 tokens: the forward
+    products and, under grad, both backward products of each are Mosaic
+    kernels the chip's compiler accepts."""
+    from ray_tpu.parallel.moe import dropless_moe
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(y, r, w_gate, w_up, w_down):
+        out, _ = dropless_moe(y, r, w_gate, w_up, w_down, top_k=6,
+                              held=(0, 16))
+        return out.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.grad(loss, (0, 1, 2, 3, 4)), spec((8192, 2560)),
+        spec((8192, 64), jnp.float32), spec((16, 2560, 768)),
+        spec((16, 2560, 768)), spec((16, 768, 2560)))
+    assert text.count("tpu_custom_call") == 6
 
 
 @pytest.mark.parametrize("norm", ["layernorm", "rmsnorm"])

@@ -1,0 +1,312 @@
+"""The pattern decoder (`models/decoder.py`), the dropless top-k expert
+layer over a held share (`parallel/moe.py`, `ops/moe_gmm.py`) and the
+window / grouped-query paths of `ops/attention.py`, against the plain
+float32 reference `benchmark/families/smallthinker_reference.py`. CPU,
+tiny widths: hidden 64, two periods of four layers, 8 experts top-3,
+window 16, T 64, the 7-to-1 head grouping kept as 2 query heads a
+key/value head; the kernels run in interpret mode.
+
+Tolerances. Program and reference both compute in float32 here, so what
+separates them is the order of float32 sums (blockwise softmax against
+dense, a grouped matmul against a masked loop): measured 9e-8 on the
+loss and 3e-7 on a logit. LOSS_RTOL, LOGIT_ATOL and GRAD_RTOL sit about
+an order of magnitude above that, and below what the smallest mutation
+of `test_mutation_is_told_apart` moves (rotary off on one window layer:
+9e-6 on the loss, 9e-3 on a logit; the window mask off: 1.6e-3, 0.5)."""
+
+import dataclasses
+import functools
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.families import smallthinker_reference as reference
+from ray_tpu.models import decoder
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.parallel.moe import dropless_moe
+
+LOSS_RTOL = 5e-7
+LOGIT_ATOL = 5e-6
+GRAD_RTOL = 5e-6      # of the leaf's largest reference gradient
+
+CFG = dataclasses.replace(decoder.TINY, n_layers=8, dtype=jnp.float32)
+# the reference's view of the same model: the source's keys
+MODEL = {
+    "head_dim": 16, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "rms_norm_eps": 1e-6, "rope_theta": 1.5e6, "sliding_window_size": 16,
+    "sliding_window_layout": [0, 1, 1, 1] * 2, "rope_layout": [0, 1, 1, 1] * 2,
+    "moe_num_active_primary_experts": 3, "held_experts_first": 0}
+HELD = {"all": (0, 8), "subset": (2, 4)}
+
+
+def _setup(held, seed=0):
+    cfg = dataclasses.replace(CFG, held=held)
+    params = decoder.init(jax.random.key(seed), cfg)
+    tokens = jax.random.randint(jax.random.key(seed + 1), (2, 64), 0,
+                                cfg.vocab_size)
+    return cfg, params, tokens, dict(MODEL, held_experts_first=held[0])
+
+
+def _reference(params, tokens, model, **kw):
+    """(mean loss, logits [B, T, V]) of the plain reference: one pass."""
+    logits = jnp.stack([reference.logits(params, row, model, **kw)
+                        for row in tokens])
+    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+    nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+    return nll.mean(), logits
+
+
+@pytest.fixture(scope="module")
+def program():
+    """The program's loss, logits and gradients, once a held share."""
+    out = {}
+    for name, held in HELD.items():
+        cfg, params, tokens, _ = _setup(held)
+        (loss, counts), grads = jax.jit(jax.value_and_grad(
+            lambda p: decoder.loss_fn(p, tokens, cfg), has_aux=True))(params)
+        logits = jax.jit(lambda p: decoder.apply(p, tokens, cfg))(params)
+        out[name] = (float(loss), logits, grads, counts)
+    return out
+
+
+@pytest.mark.parametrize("share", list(HELD))
+def test_decoder_matches_reference(program, share):
+    """Loss, logits and every leaf's gradient, with all experts held
+    and with a held subset (experts 2..5 of 8)."""
+    _, params, tokens, model = _setup(HELD[share])
+    loss, logits, grads, counts = program[share]
+    with jax.default_matmul_precision("highest"):
+        (ref_loss, ref_logits), ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: _reference(p, tokens, model), has_aux=True))(params)
+    assert abs(loss - float(ref_loss)) <= LOSS_RTOL * float(ref_loss)
+    assert float(jnp.abs(logits - ref_logits).max()) <= LOGIT_ATOL
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(ref_grads)):
+        scale = float(jnp.abs(want).max())
+        assert scale > 0, path       # every leaf is reached by the loss
+        assert float(jnp.abs(got - want).max()) <= GRAD_RTOL * scale, path
+    n = tokens.size * CFG.top_k
+    assert counts["assignments"].tolist() == [n] * CFG.n_layers
+    assert counts["dropped"].tolist() == [0] * CFG.n_layers
+    assert (counts["expert_tokens"].sum(-1) == counts["held"]).all()
+    if share == "all":
+        assert counts["held"].tolist() == [n] * CFG.n_layers
+    else:
+        assert 0 < int(counts["held"].sum()) < n * CFG.n_layers
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+def test_shares_add_up_to_the_uncut_layer(windowed):
+    """The share test: the layer outputs of the four shares (experts
+    0-1, 2-3, 4-5, 6-7 of 8), attention and residual counted once, add
+    up to the uncut reference's layer output."""
+    cfg, params, tokens, model = _setup((0, 8))
+    layer = 1 if windowed else 0
+    p = {k: v[layer] for k, v in params["layers"].items()}
+    h = jax.random.normal(jax.random.key(7), (1, 64, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        whole, m = reference.layer(h[0], p, windowed=windowed,
+                                   rotary=windowed, model=model)
+    attention_and_residual = whole - m        # what every chip computes alike
+    total = attention_and_residual
+    for first in (0, 2, 4, 6):
+        share = dataclasses.replace(cfg, held=(first, 2))
+        mine = dict(p, **{k: p[k][first:first + 2]
+                          for k in ("w_gate", "w_up", "w_down")})
+        out, counts = jax.jit(functools.partial(
+            decoder._layer, cfg=share, mlp="experts",
+            attention="window" if windowed else "full"))(
+                h, mine, decoder.rope_tables(64, share))
+        assert int(counts["dropped"]) == 0
+        total = total + (out[0] - attention_and_residual)
+    assert float(jnp.abs(total - whole).max()) <= LOGIT_ATOL
+
+
+def test_no_token_is_dropped_under_a_biased_router():
+    """A router biased so that ONE expert gets every token: the layer
+    still matches the reference, that expert's count is every token and
+    the dropped-assignment counter reads 0. (A capacity of 1.25 x the
+    mean would have dropped five tokens in six.)"""
+    n, d, f, experts, k = 96, 32, 16, 8, 3
+    keys = jax.random.split(jax.random.key(3), 5)
+    y = jax.random.normal(keys[0], (n, d))
+    r = jax.random.normal(keys[1], (n, experts)) + 50.0 * jax.nn.one_hot(
+        5, experts)
+    p = {"w_gate": jax.random.normal(keys[2], (experts, d, f)) * 0.2,
+         "w_up": jax.random.normal(keys[3], (experts, d, f)) * 0.2,
+         "w_down": jax.random.normal(keys[4], (experts, f, d)) * 0.2}
+    out, counts = jax.jit(functools.partial(
+        dropless_moe, top_k=k, held=(0, experts), tile=8))(
+            y, r, p["w_gate"], p["w_up"], p["w_down"])
+    with jax.default_matmul_precision("highest"):
+        want = reference.routed(y, r, p, first=0, k_active=k)
+    assert float(jnp.abs(out - want).max()) <= LOGIT_ATOL
+    assert int(counts["expert_tokens"][5]) == n
+    assert int(counts["dropped"]) == 0 and int(counts["held"]) == n * k
+
+
+MUTATIONS = {
+    # name -> (changes to the reference's model, its keyword arguments,
+    #          a change to the parameters it is given)
+    "window mask off": ({"sliding_window_size": 10 ** 9}, {}, None),
+    "rotary off on a window layer": (
+        {"rope_layout": [0, 0, 1, 1] + [0, 1, 1, 1]}, {}, None),
+    "top-3 -> top-2": ({"moe_num_active_primary_experts": 2}, {}, None),
+    "one held expert dropped": ({}, {}, lambda x: x[:, :-1]),
+    "router fed the raw residual": ({}, {"router_input": "residual"}, None),
+}
+
+
+@pytest.mark.parametrize("name", list(MUTATIONS))
+def test_mutation_is_told_apart(program, name):
+    """A reference with one term changed must fail
+    `test_decoder_matches_reference` by its tolerances: by ten times
+    LOGIT_ATOL on the logits, and on the loss."""
+    changes, kwargs, cut = MUTATIONS[name]
+    _, params, tokens, model = _setup(HELD["all"])
+    if cut is not None:
+        params = dict(params, layers=dict(params["layers"], **{
+            k: cut(params["layers"][k])
+            for k in ("w_gate", "w_up", "w_down")}))
+    loss, logits, _, _ = program["all"]
+    model = dict(model, **changes)
+    with jax.default_matmul_precision("highest"):
+        ref_loss, ref_logits = jax.jit(lambda p: _reference(
+            p, tokens, model, **kwargs))(params)
+    assert float(jnp.abs(logits - ref_logits).max()) > 10 * LOGIT_ATOL
+    assert abs(loss - float(ref_loss)) > LOSS_RTOL * float(ref_loss)
+
+
+def _dense(q, k, v, window):
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(q.shape[1])[:, None], jnp.arange(q.shape[1])[None, :]
+    mask = i >= j
+    if window is not None:
+        mask &= i - j < window
+    s = jnp.where(mask, s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("t,window,heads,kv_heads,block", [
+    (64, 16, 4, 2, 16),        # T above the window
+    (256, 48, 6, 2, 32),       # a window that is no multiple of the block
+    (64, 100, 4, 2, 16),       # T below the window
+    (64, None, 4, 2, 16),      # grouped heads alone (a full layer)
+    (256, None, 4, 2, 32),     # ... its backward in four stages of keys
+    (64, 16, 4, 4, 16),        # a window alone
+    (40, 16, 4, 2, 16),        # unaligned T: the dense fallback
+])
+def test_window_and_grouped_attention(t, window, heads, kv_heads, block):
+    """`flash_attention` with `window` and grouped heads against dense
+    masked attention, forward and backward. float32 throughout: the
+    difference is the blockwise softmax's order of sums (measured
+    6e-7 forward, 1.5e-6 on a gradient)."""
+    keys = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(keys[0], (2, t, heads, 16))
+    k = jax.random.normal(keys[1], (2, t, kv_heads, 16))
+    v = jax.random.normal(keys[2], (2, t, kv_heads, 16))
+    w = jax.random.normal(keys[3], (2, t, heads, 16))
+
+    def ours(q, k, v):
+        return flash_attention(q, k, v, True, None, block, block, window)
+
+    assert float(jnp.abs(jax.jit(ours)(q, k, v)
+                         - _dense(q, k, v, window)).max()) <= 5e-6
+    got = jax.jit(jax.grad(lambda *a: (ours(*a) * w).sum(),
+                           (0, 1, 2)))(q, k, v)
+    want = jax.grad(lambda *a: (_dense(*a, window) * w).sum(),
+                    (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(a - b).max()) <= 1e-5
+
+
+# sha256 of the jaxpr of value_and_grad(flash_attention) at GPT-tiny's
+# shapes, taken on the tree before `window` and grouped heads existed
+# (commit 7f398b5). Change it only with a change MEANT to alter the
+# kernel the GPT-2 cells run.
+PLAIN_JAXPR = "92343d1a06e51faced2876a69776a203573a65abe4dced880517d63e2966313b"
+
+
+def _plain_jaxpr(*extra):
+    qkv = jax.ShapeDtypeStruct((8, 128, 4, 16), jnp.bfloat16)
+    return str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: flash_attention(q, k, v, True, *extra).astype(
+            jnp.float32).sum(), (0, 1, 2)))(qkv, qkv, qkv))
+
+
+def test_window_none_is_the_program_the_gpt_cells_ran():
+    """With `window=None` and equal head counts the traced program,
+    forward kernel and backward, is the parent's, text for text — so its
+    output is bit-identical — however the new arguments are spelled."""
+    text = _plain_jaxpr()
+    assert hashlib.sha256(text.encode()).hexdigest() == PLAIN_JAXPR
+    assert _plain_jaxpr(None, 128, 128, None) == text
+
+
+TINY_SHARE = dataclasses.replace(decoder.TINY, held=(0, 4))
+
+
+def _operator_cls():
+    import optax
+
+    from ray_tpu.train import TrainingOperator
+
+    class TinyDecoderOperator(TrainingOperator):
+        def setup(self, config):
+            cfg = TINY_SHARE
+            tokens = jax.random.randint(jax.random.key(1), (2, 64), 0,
+                                        cfg.vocab_size)
+            self.register(
+                model_init=lambda key: (decoder.init(key, cfg),
+                                        decoder.counters_init(cfg)),
+                loss_fn=lambda p, s, b: decoder.stateful_loss(p, s, b, cfg),
+                optimizer=optax.adamw(3e-4), stateful=True)
+            self.register_data(train_loader=[tokens] * 3)
+
+    return TinyDecoderOperator
+
+
+def test_epoch_counters_reach_the_sync_span_without_a_sync():
+    """The operator zeroes the state's `epoch_counters` when an epoch
+    starts, the step adds to them on the device, and `train_epoch` reads
+    them once, after the losses."""
+    cfg = TINY_SHARE
+    op = _operator_cls()({}, 0, 1)
+    for steps in (3, 2):        # the second epoch starts from zero again
+        c = op.train_epoch(num_steps=steps)["counters"]
+        assert c["moe_steps"] == steps
+        assert c["moe_assignments"] == steps * cfg.n_layers * 128 * cfg.top_k
+        assert 0 < c["moe_assignments_held"] < c["moe_assignments"]
+        assert c["moe_assignments_dropped"] == 0
+        assert (c["moe_experts_held"], c["moe_experts_total"]) == (4, 8)
+        assert c["moe_expert_tokens_mean"] == pytest.approx(
+            c["moe_assignments_held"] / (steps * cfg.n_layers * 4))
+        assert c["moe_expert_tokens_max"] >= c["moe_expert_tokens_mean"]
+
+
+def test_counters_land_on_the_calls_span_tree(ray_start_shared):
+    """Through `Trainer.train()`: the `moe_*` counters are attributes of
+    the worker's `train.sync` span in `call_log()`, and the state pull
+    carries the counters' state like any other leaf."""
+    from ray_tpu.train import Trainer, call_log
+
+    tr = Trainer(_operator_cls(), num_workers=1)
+    try:
+        out = tr.train(num_steps=2)
+        sync = next(s for s in call_log()[-1]["spans"]
+                    if s["name"] == "train.sync")
+        assert sync["attrs"] == out["counters"]
+        assert sync["attrs"]["moe_steps"] == 2
+        assert sync["attrs"]["moe_assignments"] == 2 * 4 * 128 * 3
+        assert sync["attrs"]["moe_assignments_dropped"] == 0
+        assert (sync["attrs"]["moe_experts_held"],
+                sync["attrs"]["moe_experts_total"]) == (4, 8)
+        state = tr.state_dict()["model_state"]["epoch_counters"]
+        assert int(state["moe_steps"]) == 2
+    finally:
+        tr.shutdown(force=True)
