@@ -5,18 +5,27 @@ are scanned in blocks, the MXU does the two matmuls per block, and the
 running (max, denom) accumulators live in f32 — the standard flash
 schedule, written for the TPU memory hierarchy (HBM→VMEM via BlockSpecs).
 
-Backward uses recompute (custom_vjp whose bwd re-runs dense attention in
-checkpointed blocks) — flash-style memory: nothing but (q, k, v, o, lse) is
-saved. On CPU (tests) the kernel runs in interpret mode.
+Backward of the plain path (no window, equal head counts) is a kernel
+too, `flash_bwd_fused`: the forward under a gradient also writes the row
+log-sum-exp (float32, one number a query row and head), the residuals
+are (q, k, v, o, lse), and one pass over the score tiles at or below the
+diagonal rebuilds p = exp(s - lse) in VMEM and accumulates dq, dk and dv
+in float32 — no score tile, no float32 dk/dv carry ever reaches HBM, and
+the blocks the causal mask empties are skipped. MXU operands are in the
+inputs' dtype (p and ds cast to it, as the forward casts p); scores, lse,
+delta, ds and the accumulators float32. Sequences no tile divides (T % 8)
+take one checkpointed dense block, forward and backward. On CPU (tests)
+the kernels run in interpret mode.
 
 Two things beyond the plain causal kernel, both off by default: a
 sliding `window` (query i sees keys j with 0 <= i - j < window; the
-forward's K loop starts at the first block the window reaches, the
-backward gives a query block a key slice of fixed length window + block
-instead of all T), and grouped-query heads (k and v with fewer heads
-than q: query head g reads key/value head g // (H // H_kv), chosen in
-the BlockSpec index map, so no repeated K/V is ever written to HBM).
-With `window=None` and equal head counts the traced program is the one
+forward's K loop starts at the first block the window reaches; the
+backward there is XLA's, `_bwd_grouped`: a scan that gives a query block
+a key slice of fixed length window + block instead of all T), and
+grouped-query heads (k and v with fewer heads than q: query head g reads
+key/value head g // (H // H_kv), chosen in the BlockSpec index map, so
+no repeated K/V is ever written to HBM).
+With `window=None` and equal head counts the forward kernel is the one
 this file built before either existed.
 """
 
@@ -27,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private.accelerator import is_tpu
 from ray_tpu.ops.partition import over_leading_dim
@@ -34,10 +44,13 @@ from ray_tpu.ops.partition import over_leading_dim
 NEG_INF = -1e30
 # Query rows a step of the windowed / grouped backward takes.
 BWD_BLOCK_Q = 64
+# What `flash_bwd_fused` may hold in VMEM: q and do of a whole sequence
+# (twice: double-buffered), dq.T in float32, and the float32 tiles.
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                  scale: float, window: int | None = None):
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
+                  causal: bool, scale: float, window: int | None = None):
     qi = pl.program_id(1)
     q = q_ref[...]  # [block_q, d]
     t = k_ref.shape[0]
@@ -93,6 +106,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
     denom = jnp.where(l > 0, l, 1.0)
     o_ref[...] = (o / denom[:, None]).astype(o_ref.dtype)
+    if lse_ref:
+        # the row log-sum-exp the backward kernels rebuild p from, laid
+        # along the lanes ([1, block_q]) as they read it
+        lse_ref[0][...] = (m + jnp.log(denom)).reshape(1, block_q)
 
 
 def _flash_aligned(t: int, d: int, block_q: int, block_k: int) -> bool:
@@ -106,7 +123,10 @@ def _flash_aligned(t: int, d: int, block_q: int, block_k: int) -> bool:
 
 
 def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
-                    block_k: int, interpret: bool, window: int | None = None):
+                    block_k: int, interpret: bool, window: int | None = None,
+                    save_lse: bool = False):
+    """`save_lse` (the plain path under a gradient): returns (out, lse),
+    lse [B, H, T] float32 — None where the dense fallback ran."""
     b, t, h, d = q.shape
     plain = window is None and k.shape[2] == h
     if not plain and (not causal or h % k.shape[2]):
@@ -123,13 +143,14 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
                 " falling back to dense O(T^2) attention — pad the sequence"
                 " to a multiple of 8 for the pallas kernel", stacklevel=2)
         if plain:
-            return _dense_attention(q, k, v, causal, scale)
+            out = _dense_attention(q, k, v, causal, scale)
+            return (out, None) if save_lse else out
         return _dense_grouped(q, k, v, scale, 0, 0, window)
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     call = functools.partial(_flash_call, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k,
-                             interpret=interpret)
+                             interpret=interpret, save_lse=save_lse)
     if not plain:
         call = functools.partial(call, window=window)
     # batch rows are independent kernel instances: under a sharded jit
@@ -137,14 +158,23 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
     return over_leading_dim(call, (True, True, True))(q, k, v)
 
 
+def _fold(x):
+    """[B, T, H, D] -> [B*H, T, D], the layout the kernels walk."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _unfold(x, b):
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
 def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
-                block_k: int, interpret: bool, window: int | None = None):
+                block_k: int, interpret: bool, window: int | None = None,
+                save_lse: bool = False):
     b, t, h, d = q.shape
     h_kv = k.shape[2]
-    # fold batch and heads; layout [B*H, T, D]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h_kv, t, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h_kv, t, d)
+    qf, kf, vf = _fold(q), _fold(k), _fold(v)
 
     kernel = functools.partial(_flash_kernel, block_k=block_k,
                                causal=causal, scale=scale)
@@ -160,6 +190,14 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
 
         def kv_index(bh, qi):
             return ((bh // h) * h_kv + (bh % h) // group, 0, 0)
+    out_specs = pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))
+    out_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
+    if save_lse:
+        # one [1, block_q] row of float32 a grid step
+        out_specs = [out_specs, pl.BlockSpec(
+            (None, None, 1, block_q), lambda bh, qi: (bh, qi, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(
+            (b * h, t // block_q, 1, block_q), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=(b * h, t // block_q),
@@ -168,22 +206,25 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
             pl.BlockSpec((None, t, d), kv_index),
             pl.BlockSpec((None, t, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
     )(qf, kf, vf)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    if save_lse:
+        out, lse = out
+        return _unfold(out, b), lse.reshape(b, h, t)
+    return _unfold(out, b)
 
 
-def _dense_attention(q, k, v, causal, scale, q_offset=0, pad_mask=None):
-    """Reference/fallback path. q_offset shifts the causal mask (used by
-    the blockwise backward); pad_mask: [B, Tk] bool, True = real token."""
+def _dense_attention(q, k, v, causal, scale, pad_mask=None):
+    """Reference/fallback path. pad_mask: [B, Tk] bool, True = real
+    token."""
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = (q_offset + jnp.arange(tq))[:, None] >= jnp.arange(tk)[None, :]
+        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
         scores = jnp.where(mask[None, None], scores, NEG_INF)
     if pad_mask is not None:
         scores = jnp.where(pad_mask[:, None, None, :], scores, NEG_INF)
@@ -235,8 +276,14 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
 
 
 def _fwd(q, k, v, causal, scale, block_q, block_k, window):
-    out = flash_attention(q, k, v, causal, scale, block_q, block_k, window)
-    return out, (q, k, v)
+    if window is not None or k.shape[2] != q.shape[2]:
+        out = flash_attention(q, k, v, causal, scale, block_q, block_k, window)
+        return out, (q, k, v, None, None)
+    actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
+    out, lse = _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
+                               block_q=block_q, block_k=block_k,
+                               interpret=not is_tpu(), save_lse=True)
+    return out, (q, k, v, out, lse)
 
 
 def _bwd_grouped(scale, window, q, k, v, g):
@@ -309,47 +356,154 @@ def _bwd_grouped(scale, window, q, k, v, g):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+
+
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      dqt_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc, *,
+                      block_q: int, causal: bool, scale: float):
+    """One key block of the backward, in one pass over the query blocks
+    at or after it (all of them without the mask): dk and dv of its keys,
+    and its share of every dq. A tile has the KEYS along its rows —
+    p.T and ds.T, [block_k, block_q], rebuilt in VMEM from the saved
+    log-sum-exp — so `lse` and `delta` are read as rows along the lanes
+    and all three products are plain ones: dv += p.T @ do, dk += ds.T @ q,
+    dq.T += k.T @ ds.T. dq is accumulated transposed (a [d, block_q]
+    slab a query block: whole lanes at head size 64) over the grid's key
+    axis and written at its last step; every accumulator is float32
+    scratch, cast once. MXU operands in the inputs' dtype, as the forward
+    feeds them."""
+    ki = pl.program_id(1)
+    k = k_ref[...]   # [block_k, d]
+    v = v_ref[...]
+    kt = k.T
+    block_k = k.shape[0]
+
+    @pl.when(ki == 0)
+    def _():
+        dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    if causal:
+        # key position minus query position, for a tile at the origin
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+                 - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1))
+
+    def body(qi, _):
+        rows = pl.ds(qi * block_q, block_q)
+        q = q_ref[rows, :]   # [block_q, d]
+        do = do_ref[rows, :]
+        st = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * scale
+        if causal:
+            st = jnp.where(ahead <= qi * block_q - ki * block_k, st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[qi])   # lse, delta: [1, block_q]
+        dpt = jax.lax.dot_general(v, do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[qi])).astype(q.dtype)
+        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        dqt_acc[qi] += jnp.dot(kt, dst, preferred_element_type=jnp.float32)
+
+    # only the Q blocks whose last row reaches this K block's first key
+    first = ki * block_k // block_q if causal else 0
+    jax.lax.fori_loop(first, q_ref.shape[0] // block_q, body, None)
+    dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dqt_ref[...] = (dqt_acc[...] * scale).astype(dqt_ref.dtype)
+
+
+def _fit(t: int, target: int) -> int:
+    """The largest block of at most `target` rows, halving from it, that
+    divides t (t % 8 == 0 wherever the kernels run)."""
+    block = min(target, t)
+    while t % block:
+        block //= 2
+    return block
+
+
+def _bwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
+    """(block_q, block_k) of `flash_bwd_fused`, from what the call
+    observes. Read on the chip at head size 64, T 1024, bf16 (PR 32,
+    PERF.md): 512 x 512 fastest (3.81 ms a GPT-2-small layer at batch
+    32), 256 x 512 3 % and 256 x 256 12 % behind, 1024 on either side
+    11-15 % behind. No other head size or dtype has a reading on this
+    path yet, so they take the same tile (which compiles for float32
+    and for heads of 128 and 256)."""
+    block = _fit(t, 512)
+    return block, block
+
+
+def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
+                    interpret: bool):
+    """(dq, dk, dv) of the plain path in one kernel, `flash_bwd_fused`.
+    Scores, lse, delta, ds and the accumulators in float32; p and ds
+    cast to the inputs' dtype for the MXU, as the forward casts p."""
+    b, t, h, d = q.shape
+    block_q, block_k = _bwd_tiles(t, d, q.dtype)
+    # delta = rowsum(o * do): what the softmax's backward takes off dp
+    delta = jnp.einsum("bthd,bthd->bht", o.astype(jnp.float32),
+                       g.astype(jnp.float32))
+
+    num_q = t // block_q
+
+    def rows(x):
+        # [B, H, T] float32 as one [1, block_q] row a query block
+        return x.reshape(b * h, num_q, 1, block_q)
+
+    def kv_index(bh, ki):
+        return (bh, ki, 0)
+
+    whole = pl.BlockSpec((None, t, d), lambda bh, ki: (bh, 0, 0))
+    row_spec = pl.BlockSpec((None, num_q, 1, block_q),
+                            lambda bh, ki: (bh, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    dqt, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
+                          scale=scale),
+        grid=(b * h, t // block_k),
+        in_specs=[whole, whole, row_spec, row_spec, kv_spec, kv_spec],
+        out_specs=[pl.BlockSpec((None, num_q, d, block_q),
+                                lambda bh, ki: (bh, 0, 0, 0)),
+                   kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b * h, num_q, d, block_q), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h, t, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((num_q, d, block_q), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name="flash_bwd_fused",
+    )(_fold(q), _fold(g), rows(lse), rows(delta), _fold(k), _fold(v))
+    dq = dqt.reshape(b, h, num_q, d, block_q).transpose(
+        0, 2, 4, 1, 3).reshape(b, t, h, d)
+    return dq, _unfold(dk, b), _unfold(dv, b)
+
+
 def _bwd(causal, scale, block_q, block_k, window, residuals, g):
-    """Blockwise-remat backward: scan over Q blocks, each recomputing its
-    attention against full K/V and accumulating dk/dv. Peak extra memory is
-    one [B, H, block_q, T] score block (linear in T), not the full T×T
-    matrix — flash-style memory from only (q, k, v) residuals."""
-    q, k, v = residuals
+    q, k, v, o, lse = residuals
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     if window is not None or k.shape[2] != q.shape[2]:
         return _bwd_grouped(actual_scale, window, q, k, v, g)
-    b, t, h, d = q.shape
-    bq = min(block_q, t)
-
-    if t % bq:
-        # unaligned fallback: single checkpointed dense block
+    if lse is None:
+        # unaligned fallback, as the forward's: one checkpointed dense block
         def f(q, k, v):
             return _dense_attention(q, k, v, causal, actual_scale)
 
         _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
         return vjp(g)
-
-    n = t // bq
-    qb = jnp.moveaxis(q.reshape(b, n, bq, h, d), 1, 0)   # [n, B, bq, H, D]
-    gb = jnp.moveaxis(g.reshape(b, n, bq, h, d), 1, 0)
-
-    def body(carry, inp):
-        dk, dv = carry
-        i, q_blk, g_blk = inp
-
-        def f(q_blk, k, v):
-            return _dense_attention(q_blk, k, v, causal, actual_scale,
-                                    q_offset=i * bq)
-
-        _, vjp = jax.vjp(f, q_blk, k, v)
-        dq_blk, dk_i, dv_i = vjp(g_blk)
-        return (dk + dk_i, dv + dv_i), dq_blk
-
-    (dk, dv), dq = jax.lax.scan(
-        body, (jnp.zeros_like(k, jnp.float32), jnp.zeros_like(v, jnp.float32)),
-        (jnp.arange(n), qb, gb))
-    dq = jnp.moveaxis(dq, 0, 1).reshape(b, t, h, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    call = functools.partial(_flash_bwd_call, causal=causal,
+                             scale=actual_scale, interpret=not is_tpu())
+    # as the forward: each device takes its own rows of the batch
+    return over_leading_dim(call, (True,) * 6)(q, k, v, o, lse, g)
 
 
 flash_attention.defvjp(_fwd, _bwd)

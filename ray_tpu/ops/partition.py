@@ -38,8 +38,10 @@ def batch_sharded(mesh, batch_spec: P):
 
 
 def over_leading_dim(fn, split: tuple[bool, ...]):
-    """`fn(*args) -> array` with dimension 0 of the result and of every
-    arg flagged in `split` divided the way the declared batch is;
+    """`fn(*args) -> array, or a tuple of them` (the attention forward's
+    output and log-sum-exp, its backward's dq, dk, dv) with dimension 0
+    of every result and of every arg flagged in `split` divided the way
+    the declared batch is;
     unflagged args (weights) are whole on every device. Every mesh axis
     is manual inside (Mosaic accepts nothing less): over an axis the
     batch is not split on, devices repeat the same rows."""

@@ -116,13 +116,13 @@ def test_bert_pad_mask(key):
 
 
 def test_flash_backward_blockwise_matches_dense(key):
-    """The scan-over-Q-blocks backward equals the dense vjp."""
+    """The backward kernel (one block here) equals the dense vjp."""
     from ray_tpu.ops.attention import _dense_attention, flash_attention
     q, k, v = (jax.random.normal(kx, (2, 64, 2, 16), jnp.float32)
                for kx in jax.random.split(key, 3))
 
     def f_flash(q, k, v):
-        # block_q=16 → 4 blocks in the scan
+        # the forward in 4 blocks of 16
         return flash_attention(q, k, v, True, None, 16, 16).sum()
 
     def f_dense(q, k, v):
