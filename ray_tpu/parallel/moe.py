@@ -1,10 +1,19 @@
-"""Expert parallelism: Switch-style top-1 MoE with all_to_all dispatch over
-the `ep` mesh axis (capability absent from the reference, SURVEY §2.4).
+"""Expert layers, two of them (capability absent from the reference,
+SURVEY §2.4).
 
-Dense-dispatch formulation (einsum with one-hot dispatch/combine masks):
-no gathers/scatters with dynamic shapes, so everything tiles onto the MXU
-and the only cross-device traffic is two all_to_alls on [experts, capacity,
-model] buffers riding ICI.
+1. Switch-style top-1 MoE with all_to_all dispatch over the `ep` mesh
+   axis (`moe_apply`). Dense-dispatch formulation (einsum with one-hot
+   dispatch/combine masks): no gathers/scatters with dynamic shapes, so
+   everything tiles onto the MXU and the only cross-device traffic is
+   two all_to_alls on [experts, capacity, model] buffers riding ICI.
+   Pads every expert to a capacity and drops what does not fit.
+2. Dropless top-k experts over a HELD share of the experts
+   (`dropless_moe`, second half of the file): one chip's part of an
+   expert-parallel layer, run without its exchange. Two routing rules
+   (`ROUTING`): the softmax over the chosen k logits, and sigmoid
+   scores with a selection bias that moves the choice and not the
+   weights (`route_sigmoid_bias`; the bias is model state, moved by
+   `balance_bias`); gated ReLU or SiLU experts (`ACTIVATIONS`).
 """
 
 from __future__ import annotations
@@ -130,6 +139,41 @@ def route_topk(router_logits, top_k: int):
     return idx, jax.nn.softmax(top, axis=-1)
 
 
+ROUTING_EPS = 1e-6    # beside the chosen scores' sum
+
+
+def route_sigmoid_bias(router_logits, bias, top_k: int):
+    """router_logits: [N, E], bias: [E] float32 -> (experts [N, k],
+    weights [N, k] float32, moved): s = sigmoid(logits) over all E; the
+    experts are the k largest of s + bias; their weights are the
+    UNBIASED s of the chosen over (their sum + 1e-6). The bias takes
+    part in the choice only (arXiv:2408.15664): no gradient reaches it.
+    `moved` counts the assignments the bias changed: experts among the k
+    largest of s + bias that are not among the k largest of s."""
+    s = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias, top_k)
+    _, plain = jax.lax.top_k(s, top_k)
+    moved = (idx[:, :, None] != plain[:, None, :]).all(-1).sum()
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, chosen / (chosen.sum(-1, keepdims=True) + ROUTING_EPS), moved
+
+
+def balance_bias(bias, routed, rate: float):
+    """The loss-free balancing rule, once a step after the loss: an
+    expert that got fewer assignments than the mean is raised by
+    `rate`, one that got more is lowered. bias: [..., E]; routed:
+    [..., E], the assignments each of ALL E experts got from this
+    chip's tokens (a deployment sums them over the chips that share the
+    batch before this)."""
+    routed = routed.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        routed.mean(-1, keepdims=True) - routed)
+
+
+ROUTING = ("softmax_topk", "sigmoid_bias")
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 def group_by_expert(expert_idx, held: tuple[int, int],
                     tile: int = GMM_TILE) -> Grouping:
     """Lay the assignments that fall on experts [first, first + count)
@@ -225,28 +269,41 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
-                 held: tuple[int, int], tile: int = GMM_TILE):
-    """Top-k gated-ReLU experts over a held share, no token dropped.
+                 held: tuple[int, int], tile: int = GMM_TILE,
+                 activation: str = "relu", bias=None):
+    """Top-k gated experts over a held share, no token dropped.
 
     y: [N, D] tokens (compute dtype); router_logits: [N, n_experts], the
     router's output over ALL experts, float32; w_gate, w_up: [count, D,
     F] and w_down: [count, F, D], the held experts' weights. Returns
     (out [N, D]: sum over the chosen AND held experts e of
-    p_e * W_down,e (relu(W_gate,e y) * (W_up,e y)), counts): `counts`
+    p_e * W_down,e (act(W_gate,e y) * (W_up,e y)), counts): `counts`
     holds `expert_tokens` [count] (assignments each held expert got),
     `assignments` (N * k), `held` (those on held experts) and `dropped`
     (held assignments that found no row: 0, by construction, and
-    counted from the layout rather than assumed)."""
+    counted from the layout rather than assumed).
+
+    `activation`: a key of `ACTIVATIONS`. `bias` None: `route_topk`;
+    `bias` [n_experts] float32: `route_sigmoid_bias`, and `counts` also
+    holds `routed` [n_experts] (assignments each of ALL experts got,
+    what `balance_bias` reads) and `bias_moved`."""
     from ray_tpu.ops.moe_gmm import moe_gmm
 
-    idx, weights = route_topk(router_logits, top_k)
+    extra = {}
+    if bias is None:
+        idx, weights = route_topk(router_logits, top_k)
+    else:
+        idx, weights, moved = route_sigmoid_bias(router_logits, bias, top_k)
+        extra = {"routed": (idx[:, :, None] == jnp.arange(
+                     router_logits.shape[-1])).sum((0, 1), dtype=jnp.int32),
+                 "bias_moved": moved.astype(jnp.int32)}
     g = group_by_expert(idx, held, tile)
     f = w_gate.shape[-1]
     with jax.named_scope("experts"):
         x = _dispatch(y, g)
         gate_up = moe_gmm(x, jnp.concatenate([w_gate, w_up], axis=-1),
                           g.tile_group, g.n_tiles, tile)
-        act = jax.nn.relu(gate_up[:, :f]) * gate_up[:, f:]
+        act = ACTIVATIONS[activation](gate_up[:, :f]) * gate_up[:, f:]
         rows = moe_gmm(act, w_down, g.tile_group, g.n_tiles, tile)
         out = _combine(rows, weights, g)
     n_held = g.held.sum()
@@ -254,5 +311,5 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
         "expert_tokens": g.expert_tokens.astype(jnp.int32),
         "assignments": jnp.asarray(idx.size, jnp.int32),
         "held": n_held.astype(jnp.int32),
-        "dropped": (n_held - g.row_valid.sum()).astype(jnp.int32)}
+        "dropped": (n_held - g.row_valid.sum()).astype(jnp.int32), **extra}
     return out, counts

@@ -45,9 +45,11 @@ def on_tpu(monkeypatch):
     """jax.default_backend() is the CPU here, so the ops would choose
     interpret mode: steer them to Mosaic, in the test."""
     from ray_tpu.collective.backends import pallas_backend
-    from ray_tpu.ops import attention, batchnorm, layernorm, moe_gmm
+    from ray_tpu.ops import (attention, batchnorm, layernorm, moe_gmm,
+                             short_conv)
 
-    for mod in (attention, batchnorm, layernorm, moe_gmm, pallas_backend):
+    for mod in (attention, batchnorm, layernorm, moe_gmm, short_conv,
+                pallas_backend):
         monkeypatch.setattr(mod, "is_tpu", lambda: True)
 
 
@@ -159,6 +161,78 @@ def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
         spec((8192, 64), jnp.float32), spec((16, 2560, 768)),
         spec((16, 2560, 768)), spec((16, 768, 2560)))
     assert text.count("tpu_custom_call") == 6
+
+
+def test_sigmoid_routed_silu_experts_fwd_and_bwd(one_chip, on_tpu):
+    """The dropless expert layer at LFM2's widths (8 held experts of
+    2048 -> 1792, top-4 of 32 by sigmoid scores and a selection bias,
+    SiLU) over 8 192 tokens: the same six Mosaic products."""
+    from ray_tpu.parallel.moe import dropless_moe
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(y, r, w_gate, w_up, w_down, bias):
+        out, _ = dropless_moe(y, r, w_gate, w_up, w_down, top_k=4,
+                              held=(0, 8), activation="silu", bias=bias)
+        return out.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.grad(loss, (0, 1, 2, 3, 4)), spec((8192, 2048)),
+        spec((8192, 32), jnp.float32), spec((8, 2048, 1792)),
+        spec((8, 2048, 1792)), spec((8, 1792, 2048)),
+        spec((32,), jnp.float32))
+    assert text.count("tpu_custom_call") == 6
+
+
+def _short_conv_grads(bcx, taps, w):
+    from ray_tpu.ops import short_conv
+
+    return jax.grad(lambda a, b: (short_conv.short_conv(a, b).astype(
+        jnp.float32) * w).sum(), (0, 1))(bcx, taps)
+
+
+@pytest.mark.parametrize("t", [4096, 1000])
+def test_short_conv_fwd_and_bwd(one_chip, on_tpu, t):
+    """The gated short convolution at LFM2's width (three streams of
+    2048, 3 taps), whole tiles of 512 positions and a length that is
+    padded to them: one Mosaic call forward, one backward."""
+    from ray_tpu.ops import short_conv
+
+    bcx = jax.ShapeDtypeStruct((2, t, 3 * 2048), jnp.bfloat16,
+                               sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2, t, 2048), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(short_conv.short_conv, bcx, taps)
+    assert text.count("tpu_custom_call") == 1 and "short_conv" in text
+    text = _compiled_text(_short_conv_grads, bcx, taps, w)
+    assert text.count("tpu_custom_call") == 1 and "short_conv_bwd" in text
+
+
+def test_short_conv_under_a_sharded_jit(topo, on_tpu):
+    """Each device runs the convolution on its own sequences; the taps
+    are whole on every device and their gradient is summed over all."""
+    from ray_tpu.ops import partition
+    from ray_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.fsdp_mesh(topo.devices)
+    batch_spec = P(("data", "fsdp"))
+    rows = NamedSharding(mesh, batch_spec)
+    bcx = jax.ShapeDtypeStruct((8, 1024, 3 * 2048), jnp.bfloat16,
+                               sharding=rows)
+    w = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.bfloat16, sharding=rows)
+    taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32,
+                                sharding=NamedSharding(mesh, P()))
+
+    def declared(*args):
+        with partition.batch_sharded(mesh, batch_spec):
+            return _short_conv_grads(*args)
+
+    text = _compiled_text(declared, bcx, taps, w)
+    assert "short_conv_bwd" in text and "all-reduce" in text
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        _compiled_text(_short_conv_grads, bcx, taps, w)
 
 
 @pytest.mark.parametrize("norm", ["layernorm", "rmsnorm"])
