@@ -5,6 +5,16 @@ are scanned in blocks, the MXU does the two matmuls per block, and the
 running (max, denom) accumulators live in f32 — the standard flash
 schedule, written for the TPU memory hierarchy (HBM→VMEM via BlockSpecs).
 
+The forward's tile comes from the call's shape, as the backward's does:
+`flash_attention(..., block_q=None, block_k=None)` asks `fwd_tiles`, whose
+rule (`_fwd_tiles`: 512 x 512 wherever 512 divides T, then 768, 256, 128;
+all of T below a tile) was read on the chip at the shapes the GPT-2 cells
+run — the table is in its docstring, the readings in PERF.md — and a
+caller that passes numbers keeps them (`models/decoder.py` does). Which
+shapes reach the kernel is not the rule's to change: those that 128 x 128
+tiles took (T a multiple of 128, or of 8 below 128; head size a multiple
+of 8).
+
 Backward of the plain path (no window, equal head counts) is a kernel
 too, `flash_bwd_fused`: the forward under a gradient also writes the row
 log-sum-exp (float32, one number a query row and head), the residuals
@@ -32,6 +42,7 @@ this file built before either existed.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +51,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private.accelerator import is_tpu
 from ray_tpu.ops.partition import over_leading_dim
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 # Query rows a step of the windowed / grouped backward takes.
@@ -122,12 +135,14 @@ def _flash_aligned(t: int, d: int, block_q: int, block_k: int) -> bool:
             and block_q % 8 == 0 and block_k % 8 == 0 and d % 8 == 0)
 
 
-def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
-                    block_k: int, interpret: bool, window: int | None = None,
-                    save_lse: bool = False):
-    """`save_lse` (the plain path under a gradient): returns (out, lse),
-    lse [B, H, T] float32 — None where the dense fallback ran."""
+def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
+                    block_q: int | None, block_k: int | None, interpret: bool,
+                    window: int | None = None, save_lse: bool = False):
+    """`block_q`, `block_k`: None asks `fwd_tiles`. `save_lse` (the plain
+    path under a gradient): returns (out, lse), lse [B, H, T] float32 —
+    None where the dense fallback ran."""
     b, t, h, d = q.shape
+    block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k)
     plain = window is None and k.shape[2] == h
     if not plain and (not causal or h % k.shape[2]):
         raise ValueError(
@@ -148,6 +163,9 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float, block_q: int,
         return _dense_grouped(q, k, v, scale, 0, 0, window)
     block_q = min(block_q, t)
     block_k = min(block_k, t)
+    # once a traced call: the tile is static in the compiled program
+    logger.debug("flash_fwd %s %s: tiles %d x %d", q.shape, q.dtype, block_q,
+                 block_k)
     call = functools.partial(_flash_call, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k,
                              interpret=interpret, save_lse=save_lse)
@@ -263,12 +281,13 @@ def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: int | None = None, block_k: int | None = None,
                     window: int | None = None):
     """q: [B, T, H, D]; k, v: [B, T, H_kv, D] with H a multiple of H_kv
     (query head g reads key/value head g // (H // H_kv)). `window`:
     query i sees keys j with 0 <= i - j < window (needs causal).
-    Returns [B, T, H, D]."""
+    `block_q`, `block_k`: the forward kernel's tile; None asks
+    `fwd_tiles`, a number is taken as given. Returns [B, T, H, D]."""
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                            block_q=block_q, block_k=block_k,
@@ -437,6 +456,56 @@ def _bwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
     and for heads of 128 and 256)."""
     block = _fit(t, 512)
     return block, block
+
+
+def _fwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
+    """(block_q, block_k) of `flash_fwd`, from what the call observes:
+    the first of 512, 768, 256, 128 rows that divides t (all of t below
+    that), square. Read on the chip (PR 34, PERF.md section 6), the
+    kernel alone, causal, ms a call without / with the lse written:
+
+        tile       [384,1024,64] bf16  [160,1024,64] bf16  [96,1024,64] f32
+        128 x 128    6.42 / 6.44         2.59 / 2.61         1.71 / 1.72
+        256 x 256    3.18 / 3.90         1.26 / 1.58         0.91 / 1.10
+        256 x 512    2.43 / 3.12         0.95 / 1.24         0.76 / 0.91
+        512 x 512    2.38 / 3.09         0.93 / 1.23         0.71 / 0.89
+        512 x 1024   2.72 / 3.43         1.07 / 1.37         0.80 / 0.99
+        1024 x 1024  2.60 / 3.20         1.02 / 1.28         0.76 / 0.90
+
+    (the two GPT-2 cells' batch x heads, T 1024, head size 64). A tile
+    far from square loses (128 x 512 3.19, 512 x 128 4.51), and block_k
+    1024 spends its gain on the half-masked diagonal block. 512 x 512
+    is also first at T 512 (1.86; 256 x 256 2.35), 1536, 2048 (3.18;
+    256 x 256 4.85, 1024 x 1024 3.53) and 4096, and at head sizes 32,
+    128 and 256, so neither d nor dtype moves the rule yet. Where 512
+    does not divide, at T 768: 768 x 768 1.75, 256 x 256 2.06, 384 x 384
+    2.10, 128 x 128 3.92 (at T 1536 768 x 768 is 13 % behind 512 x 512,
+    256 x 256 45 %). Without the mask 1024 x 1024 reads 2.16 against
+    512 x 512's 2.67 and 128 x 128's 10.41: no caller runs full
+    attention at T 1024 or more, so the rule does not ask. The lse
+    column's 0.6-0.7 ms above block_q 128 is the probe's own
+    `lse.reshape(B, H, T)`; in a step whose backward tile is also 512
+    both forwards read 2.32 ms a call (`gpt2s_epoch`'s trace).
+
+    A t that 128 does not divide (nor t itself, below 128) never
+    reached the kernel: it answers 128 x 128, which `_flash_aligned`
+    refuses as it always has, and the call takes the dense path."""
+    if t % min(128, t):
+        return 128, 128
+    block = next(b for b in (512, 768, 256, 128) if t % min(b, t) == 0)
+    return block, block
+
+
+def fwd_tiles(t: int, d: int, dtype, block_q: int | None = None,
+              block_k: int | None = None) -> tuple[int, int]:
+    """The (block_q, block_k) `flash_attention` hands `flash_fwd` for a
+    call of sequence length t, head size d and `dtype`: the caller's
+    numbers where it passes them, `_fwd_tiles`' where it passes None.
+    Static per compiled shape, so this function is the record of which
+    tile a program runs."""
+    rule_q, rule_k = _fwd_tiles(t, d, dtype)
+    return (rule_q if block_q is None else block_q,
+            rule_k if block_k is None else block_k)
 
 
 def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,

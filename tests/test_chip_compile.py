@@ -68,6 +68,33 @@ def test_flash_attention_fwd(one_chip, on_tpu):
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("under_grad", [False, True],
+                         ids=["primal", "with-lse"])
+def test_flash_attention_fwd_at_the_rules_tiles(one_chip, on_tpu, under_grad):
+    """The forward at `gpt2s_epoch`'s shape with no tile given: one
+    kernel at `fwd_tiles`' 512 x 512 — a float32 score tile, its mask and
+    p of 512 x 512 in VMEM under the compiler's default limit — as the
+    first forward runs it and as the rematerialised one does, which also
+    writes the row log-sum-exp in rows of ITS block_q."""
+    from ray_tpu.ops import attention
+
+    b, t, h, d = 32, 1024, 12, 64
+    block_q, _ = attention.fwd_tiles(t, d, jnp.bfloat16)
+    assert block_q == 512
+    qkv = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    if under_grad:
+        def fn(q, k, v):
+            out, (*_, lse) = attention._fwd(q, k, v, True, None, None, None,
+                                            None)
+            return out, lse
+    else:
+        def fn(q, k, v):
+            return attention.flash_attention(q, k, v, True)
+    text = _compiled_text(fn, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") == 1 and "flash_fwd" in text
+    assert (f"f32[{b * h},{t // block_q},1,{block_q}]" in text) == under_grad
+
+
 def _attention_grads(q, k, v, w):
     from ray_tpu.ops import attention
 

@@ -34,11 +34,36 @@ def test_flash_attention_full():
                                rtol=2e-5)
 
 
-# (causal, T, the forward's block, the backward's (block_q, block_k) as
-# `_bwd_tiles` returns them — None: what the file chooses — dtype,
-# tolerance). Every case has a scale other than 1 (d = 8) and a random
-# cotangent, so each catches the `delta` term dropped (dq and dk wrong by
-# p * rowsum(o * do)) and the scale left off dq or dk; the causal cases
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+# T the rule's largest tile divides, T only a smaller one does (768) or
+# a multiple of it that is no power of two (1536), T below every tile
+@pytest.mark.parametrize("t", [1024, 768, 1536, 64])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_attention_at_the_rules_tiles(causal, t, dtype, tol):
+    """The forward with no tile given (what `models/transformer.py`
+    calls) runs the kernel at `fwd_tiles`' pair, against the dense
+    reference on the same inputs in float32."""
+    rng = np.random.default_rng(5)
+    b, h, d = 1, 2, 8
+    q, k, v = (jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
+               for _ in range(3))
+    assert attention._flash_aligned(t, d, *attention.fwd_tiles(t, d, dtype))
+    out = flash_attention(q, k, v, causal)
+    assert out.dtype == dtype
+    ref = _dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                           causal, d ** -0.5)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+
+# (causal, T, the forward's block — None: `fwd_tiles`' — the backward's
+# (block_q, block_k) as `_bwd_tiles` returns them — None: what the file
+# chooses — dtype, tolerance). Every case has a scale other than 1
+# (d = 8) and a random cotangent, so each catches the `delta` term
+# dropped (dq and dk wrong by p * rowsum(o * do)) and the scale left off
+# dq or dk; the causal cases
 # catch the diagonal block left unmasked (dk, dv gain rows of later
 # queries' keys); the cases of several blocks catch a query block
 # skipped by the causal loop bound (`first`), which rounds differently
@@ -52,6 +77,17 @@ GRAD_CASES = {
     "causal-4-query-blocks": (True, 64, 16, (16, 32), jnp.float32, 3e-5),
     "full-2-blocks": (False, 32, 16, (16, 16), jnp.float32, 3e-5),
     "full-4-blocks": (False, 64, 16, (16, 32), jnp.float32, 3e-5),
+    # the forward writes the lse as [B*H, T / block_q, 1, block_q] rows of
+    # ITS block_q and the backward reads [B, H, T] in rows of its own:
+    # the forward's tile above the backward's, below it, and the rule's
+    # own tile (512 at T 1024) over a backward of 256
+    "fwd-tile-above-bwd": (True, 64, 32, (16, 16), jnp.float32, 3e-5),
+    "fwd-tile-below-bwd": (True, 64, 16, (32, 32), jnp.float32, 3e-5),
+    "full-fwd-tile-above-bwd": (False, 64, 32, (16, 32), jnp.float32, 3e-5),
+    "rule-fwd-tile-above-bwd": (True, 1024, None, (256, 256), jnp.float32,
+                                3e-5),
+    # ... and where both files choose: 768 x 768 forward, 256 x 256 back
+    "rule-tiles-T768": (True, 768, None, None, jnp.float32, 3e-5),
     # bf16 operands on the MXU (p and ds cast to it), float32 elsewhere
     "causal-bf16": (True, 64, 16, (32, 16), jnp.bfloat16, 4e-2),
     # T % 8: forward and backward both take the dense fallback
@@ -87,6 +123,77 @@ def test_flash_attention_grad(case, monkeypatch):
         assert a.dtype == dtype
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
                                    atol=tol, rtol=tol, err_msg=name)
+
+
+# (T, head size, dtype) -> the tile `_fwd_tiles` answers: the shapes the
+# six cells and this tree's tests run, and T that 512 does not divide
+FWD_TILE_TABLE = {
+    # gpt2s_epoch, gpt2s_short_calls, gpt2l_fsdp4: [*, 1024, 12 | 20, 64]
+    (1024, 64, jnp.bfloat16): (512, 512),
+    (1024, 64, jnp.float32): (512, 512),      # their float32 reference
+    # smallthinker_ep4_seq8k, lfm2_ep4_seq4k, were they to ask
+    (8192, 128, jnp.bfloat16): (512, 512),
+    (4096, 64, jnp.bfloat16): (512, 512),
+    (512, 64, jnp.bfloat16): (512, 512),
+    (2048, 64, jnp.bfloat16): (512, 512),
+    (1536, 8, jnp.float32): (512, 512),
+    (2560, 64, jnp.bfloat16): (512, 512),
+    (768, 8, jnp.float32): (768, 768),        # 512 does not divide
+    (2304, 64, jnp.bfloat16): (768, 768),
+    (1280, 64, jnp.bfloat16): (256, 256),
+    # below a tile the kernel takes min(tile, T): all of T
+    (640, 64, jnp.bfloat16): (768, 768),
+    (256, 64, jnp.bfloat16): (512, 512),
+    (128, 16, jnp.bfloat16): (512, 512),      # transformer.TINY
+    (64, 8, jnp.float32): (512, 512),
+    (16, 8, jnp.float32): (512, 512),
+    # never the kernel's: 128 x 128, which `_flash_aligned` refuses
+    (1000, 64, jnp.bfloat16): (128, 128),
+    (192, 64, jnp.bfloat16): (128, 128),
+}
+
+
+@pytest.mark.parametrize("t, d, dtype", FWD_TILE_TABLE, ids=lambda x: str(
+    getattr(x, "__name__", x)))
+def test_fwd_tiles_table(t, d, dtype):
+    want = FWD_TILE_TABLE[t, d, dtype]
+    assert attention._fwd_tiles(t, d, dtype) == want
+    assert attention.fwd_tiles(t, d, dtype) == want
+    # a caller's numbers are kept, each on its own (the decoder family
+    # passes 256 x 512)
+    assert attention.fwd_tiles(t, d, dtype, 256, 512) == (256, 512)
+    assert attention.fwd_tiles(t, d, dtype, None, 64) == (want[0], 64)
+    assert attention.fwd_tiles(t, d, dtype, 64) == (64, want[1])
+
+
+@pytest.mark.parametrize("d", [8, 12, 64, 128])
+def test_fwd_tiles_move_no_shape_across_the_dense_line(d):
+    """The rule sends to the kernel exactly the shapes the 128 x 128
+    default sent there, and asks for no tile under 128 rows (below that
+    the kernel takes all of T, as it did)."""
+    for t in range(4, 4100, 4):
+        block_q, block_k = attention.fwd_tiles(t, d, jnp.bfloat16)
+        assert min(block_q, block_k) >= 128, t
+        assert (attention._flash_aligned(t, d, block_q, block_k)
+                == attention._flash_aligned(t, d, 128, 128)), t
+
+
+@pytest.mark.parametrize("t, d, warns", [(1000, 64, True), (20, 8, False),
+                                         (1024, 12, True)])
+def test_unaligned_shapes_still_take_the_dense_path(t, d, warns, recwarn):
+    """T 1000 (no tile of 128 rows divides), T 20 (T % 8) and head size
+    12 (d % 8) with no tile given: no kernel in the traced program,
+    forward or backward, and the warning the fallback gave from T 512."""
+    x = jax.ShapeDtypeStruct((1, t, 1, d), jnp.float32)
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: flash_attention(q, k, v, True).sum(), (0, 1, 2)))(
+            x, x, x))
+    assert "pallas_call" not in text
+    said = [str(w.message) for w in recwarn
+            if "not tile-aligned" in str(w.message)]
+    assert bool(said) == warns
+    if warns:
+        assert f"seq {t} / head_dim {d}" in said[0]
 
 
 def test_layernorm_matches():
