@@ -125,6 +125,15 @@ _ASYNC_TASK_ID: contextvars.ContextVar = contextvars.ContextVar(
     "ray_tpu_async_task_id", default=None)
 
 
+def _reply_spans(reply: dict, spans) -> None:
+    """A traced task's spans into its reply's metadata, and the count of
+    those `tracing.REPLY_SPANS_MAX` left out (`spans` is None untraced)."""
+    if spans:
+        reply["spans"] = list(spans)
+        if spans.dropped:
+            reply["spans_dropped"] = spans.dropped
+
+
 class _Lease:
     __slots__ = ("lease_id", "worker_id", "address", "conn", "inflight",
                  "raylet_conn", "last_used", "task_conn", "burst_channel")
@@ -1485,8 +1494,11 @@ class CoreWorker:
             if ctx is not None and t0 is not None:
                 # the ROOT span of this task's tree (children: queue_wait,
                 # lease_wait, raylet.lease, worker-side exec)
-                tracing.record_span("task.e2e", t0, now, ctx,
-                                    {"name": spec.get("name", "?")})
+                tracing.record_span(
+                    "task.e2e", t0, now, ctx,
+                    {"name": spec.get("name", "?"),
+                     "spans_dropped": (reply.get("spans_dropped", 0)
+                                       if isinstance(reply, dict) else 0)})
         if rec is not None and rec["pinned"]:
             self._release_pins(rec["pinned"])
         # Lineage shared by all plasma returns of this task: enough to
@@ -2756,8 +2768,7 @@ class CoreWorker:
             self._cancelled_tasks.discard(spec["task_id"])
         reply["exec_s"] = scope["exec_s"]
         reply["held_s"] = scope["held_s"]
-        if scope["spans"]:
-            reply["spans"] = scope["spans"]
+        _reply_spans(reply, scope["spans"])
         return reply
 
     @contextlib.contextmanager
@@ -2811,8 +2822,7 @@ class CoreWorker:
             # round trip without comparing cross-process clocks
             reply["exec_s"] = scope["exec_s"]
             reply["held_s"] = scope["held_s"]
-            if scope["spans"]:
-                reply["spans"] = scope["spans"]
+            _reply_spans(reply, scope["spans"])
         # a cancel that raced this execution leaves a marker nothing else
         # will ever consume — drop it so the set stays bounded
         self._cancelled_tasks.discard(spec["task_id"])

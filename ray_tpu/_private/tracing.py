@@ -255,13 +255,20 @@ def use(ctx: TraceContext | None):
 
 # Executing side: the spans recorded under one traced task, handed back
 # in its reply (core_worker._exec_scope). Bounded: what does not fit
-# still goes to the ProfileBuffer.
+# still goes to the ProfileBuffer, and is counted (`ReplySpans.dropped`,
+# the owner's `spans_dropped` on `task.e2e`).
 REPLY_SPANS_MAX = 256
 _REPLY: contextvars.ContextVar = contextvars.ContextVar(
     "ray_tpu_trace_reply", default=None)
 # Owning side: trees open in this process by hex trace id (open_tree).
 _trees: dict[str, list] = {}
 _annotating = False  # a jax profiler session runs in this process
+
+
+class ReplySpans(list):
+    """The rows one traced task hands back, and how many did not fit."""
+
+    dropped = 0
 
 
 @contextlib.contextmanager
@@ -271,7 +278,7 @@ def collect_reply(ctx: TraceContext | None):
     if ctx is None:
         yield None
         return
-    rows: list = []
+    rows = ReplySpans()
     token = _REPLY.set(rows)
     try:
         yield rows
@@ -327,8 +334,11 @@ def record_span(name: str, start: float, end: float,
             fields["psid"] = ctx.parent_id.hex()
         row = [name, start, end, fields]
         reply = _REPLY.get()
-        if reply is not None and len(reply) < REPLY_SPANS_MAX:
-            reply.append(row)
+        if reply is not None:
+            if len(reply) < REPLY_SPANS_MAX:
+                reply.append(row)
+            else:
+                reply.dropped += 1
         tree = _trees.get(fields["tid"]) if _trees else None
         if tree is not None:
             tree.append(row)

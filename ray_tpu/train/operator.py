@@ -616,7 +616,10 @@ class TrainingOperator:
 
     def _to_host(self, tree, counts: dict, ctx, room: int = 0):
         """`tree` with its arrays on the host; adds to `counts`
-        (`bytes`, `leaves`, `staged_bytes`, `shards`). Every leaf's
+        (`bytes`, `leaves`, `staged_bytes`, `shards`, and the seconds
+        `start_s` issuing transfers, `wait_s` blocked in `np.asarray`
+        on a device array — the link — and `join_s` writing shards into
+        the staging area). Every leaf's
         transfer is started before the first is waited for. With `room`
         (bytes, `_stage_room`) a leaf that has to be joined from shards
         is written, shard by shard as they arrive, into the staging area
@@ -626,8 +629,9 @@ class TrainingOperator:
         join go through `np.asarray` either way. At a trace's fine level
         every leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
         leaves, treedef = jax.tree.flatten(tree)
-        _start_transfers(leaves)
+        _start_transfers(leaves, counts)
         at = 0
+        clock = time.perf_counter
 
         def to_np(x):
             nonlocal at
@@ -644,13 +648,21 @@ class TrainingOperator:
             counts["bytes"] += x.nbytes
             counts["leaves"] += 1
             if not (room and _is_joined(x)):
-                return np.asarray(x)
+                t0 = clock()
+                out = np.asarray(x)
+                counts["wait_s"] += clock() - t0
+                return out
             out = self._staging(room)[at:at + x.nbytes].view(
                 x.dtype).reshape(x.shape)
             at += _padded(x.nbytes)
             for shard in x.addressable_shards:
                 if shard.replica_id == 0:   # each index once
-                    out[shard.index] = np.asarray(shard.data)
+                    t0 = clock()
+                    arrived = np.asarray(shard.data)
+                    t1 = clock()
+                    out[shard.index] = arrived
+                    counts["join_s"] += clock() - t1
+                    counts["wait_s"] += t1 - t0
                     counts["shards"] += 1
             counts["staged_bytes"] += x.nbytes
             return out
@@ -716,13 +728,13 @@ class TrainingOperator:
         he gets calls `state_dict` (or copies)."""
         from ray_tpu.train import snapshot as _snapshot
 
-        counts = _d2h_counts()
+        counts = dict(_d2h_counts(), piece=index)
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
             whole = _snapshot.cut(self._state_tree(drop), usable)
             part = self._to_host(whole.part(index), counts, ctx,
                                  room=_stage_room(whole))
-            _start_transfers(whole.part(index + 1))
+            _start_transfers(whole.part(index + 1), counts)
         return whole.reply(index, part)
 
     def load_state_dict(self, state: dict):
@@ -787,8 +799,10 @@ class TrainingOperator:
 
 def _d2h_counts() -> dict:
     """What a `train.snapshot.d2h` span counts: `staged_bytes` of
-    `bytes` were joined from `shards` shards in the staging area."""
-    return {"bytes": 0, "leaves": 0, "staged_bytes": 0, "shards": 0}
+    `bytes` were joined from `shards` shards in the staging area; the
+    span's seconds by what the worker did in them (`_to_host`)."""
+    return {"bytes": 0, "leaves": 0, "staged_bytes": 0, "shards": 0,
+            "start_s": 0.0, "wait_s": 0.0, "join_s": 0.0}
 
 
 def _is_joined(x) -> bool:
@@ -798,13 +812,16 @@ def _is_joined(x) -> bool:
             and not x.is_fully_replicated)
 
 
-def _start_transfers(leaves: list):
+def _start_transfers(leaves: list, counts: dict):
     """Start the device→host copy of every array in `leaves` (of a
-    sharded one: of each of its shards) without waiting for any."""
+    sharded one: of each of its shards) without waiting for any; the
+    seconds that took are added to `counts["start_s"]`."""
+    t0 = time.perf_counter()
     for x in leaves:
         if isinstance(x, jax.Array) and (x.is_fully_addressable
                                          or x.is_fully_replicated):
             x.copy_to_host_async()
+    counts["start_s"] += time.perf_counter() - t0
 
 
 def _padded(nbytes: int) -> int:

@@ -308,9 +308,10 @@ class Trainer:
         self.workers: list = []
         self._last_state: dict | None = None
         self._last_shards: list | None = None
-        # The two (state, shards) sets `_own` built last, newest first:
-        # the older one is the next copy's destination (train).
-        self._owned = ((None, None), (None, None))
+        # The two (state, shards, writes) sets `_own` built last, newest
+        # first: the older one is the next copy's destination (train);
+        # `writes` counts how often its buffers have been written.
+        self._owned = ((None, None, 0), (None, None, 0))
         self._start_workers(num_workers)
 
     # ------------------------------------------------------------------
@@ -619,21 +620,28 @@ class Trainer:
                 # parts are installed, and the sets turned, only once
                 # both are whole: a copy that raises changes nothing.
                 newer, older = self._owned
+                spare_state, spare_shards, writes = older
                 # sharded: the epoch-boundary snapshot is params (rank
                 # 0; identical everywhere) + ALL optimizer shards — the
                 # reshardable unit the elastic restore path consumes.
                 # Rank 0's own shard is never kept, so it stays where it
                 # is and the tree matches the spare's.
                 state = self._pull_state(
-                    self.workers[0], older[0], counts,
-                    drop=("opt_shard",) if self._sharded else ())
+                    self.workers[0], spare_state, counts,
+                    drop=("opt_shard",) if self._sharded else (),
+                    writes=writes)
                 shards = None
                 if self._sharded:
                     shards = _own(ray_tpu.get(
                         [w.opt_shard_state.remote() for w in self.workers],
-                        timeout=120), older[1])
+                        timeout=120), spare_shards, writes)
                 self._last_state, self._last_shards = state, shards
-                self._owned = ((state, shards), newer)
+                # a set no leaf of which went into the spare's buffers
+                # (a first call, a changed tree) is new: written once
+                reused = _written_into((state, shards),
+                                       (spare_state, spare_shards))
+                self._owned = ((state, shards, writes + 1 if reused else 1),
+                               newer)
         finally:
             if profile_dir:
                 # a worker restarted mid-call has no session (a no-op);
@@ -655,7 +663,7 @@ class Trainer:
     # ------------------------------------------------------------------
 
     def _pull_state(self, worker, spare=None, counts: dict | None = None,
-                    drop=()) -> dict:
+                    drop=(), writes: int = 0) -> dict:
         """`worker`'s training state, whole, in memory the driver owns.
         It crosses the object plane as the pieces `train/snapshot.py`
         cuts (a state the store holds is ONE piece): each goes
@@ -666,7 +674,12 @@ class Trainer:
         host while this side copies the last — as long as what is in
         the store or on its way there never exceeds what it holds.
         Nothing of `spare` or of the result is installed here: a piece
-        that raises leaves the caller's snapshot as it was."""
+        that raises leaves the caller's snapshot as it was. Inside a
+        trace the driver thread's time is tiled, a piece, by
+        `train.snapshot.wait` (blocked until the worker has put the
+        piece; `object.get` hangs under it) and `train.snapshot.copy`
+        (`_own`; `writes` is how often `spare`'s buffers were written
+        before)."""
         import jax
 
         from ray_tpu.train import snapshot
@@ -687,7 +700,10 @@ class Trainer:
         try:
             while pending:
                 index, ref = pending.popleft()
-                piece = ray_tpu.get(ref, timeout=120)
+                with tracing.span("train.snapshot.wait",
+                                  tracing.child_of_current(),
+                                  {"piece": index}, ambient=True):
+                    piece = ray_tpu.get(ref, timeout=120)
                 del ref
                 if index == 0:
                     treedef, ranges = piece["treedef"], piece["ranges"]
@@ -697,7 +713,8 @@ class Trainer:
                         spares = []    # a changed tree: every leaf is new
                 ask_ahead()                 # what fits beside this piece
                 first, stop = ranges[index]
-                leaves.extend(_own(piece["leaves"], spares[first:stop]))
+                leaves.extend(_own(piece["leaves"], spares[first:stop],
+                                   writes, piece=index))
                 piece = None                # the views die here
                 held -= sizes[index]
                 ask_ahead()                 # ... and what fits without it
@@ -824,7 +841,7 @@ class Trainer:
         self._release_gang()
 
 
-def _own(snapshot, spare=None):
+def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None):
     """A whole copy of `snapshot` in memory the driver owns, with no
     view into the object store left in it. What `get` returns are
     zero-copy views PINNED in the node's shared arena; a snapshot the
@@ -849,11 +866,17 @@ def _own(snapshot, spare=None):
     GPT-2-small), which was the peak before — the old snapshot was
     alive while the new one was built. The span's `reused_bytes` says
     how much went into the spare (0 on a Trainer's first two calls,
-    then = `bytes`)."""
+    then = `bytes`), its `dest_writes` how often the buffers it went
+    into had been written before: `writes`, the count the Trainer keeps
+    for the spare's set, or 0 where every array had to be allocated
+    (1 is a set's second write, a Trainer's calls 3 and 4: the
+    slow one). `piece` is the snapshot piece's index, if it is one."""
     import jax
     import numpy as np
 
-    counts = {"bytes": 0, "reused_bytes": 0}
+    counts = {"bytes": 0, "reused_bytes": 0, "dest_writes": writes}
+    if piece is not None:
+        counts["piece"] = piece
     spares = dict(jax.tree_util.tree_flatten_with_path(spare)[0])
 
     def own(path, x):
@@ -871,7 +894,21 @@ def _own(snapshot, spare=None):
 
     with tracing.span("train.snapshot.copy", tracing.child_of_current(),
                       counts):
-        return jax.tree_util.tree_map_with_path(own, snapshot)
+        out = jax.tree_util.tree_map_with_path(own, snapshot)
+        if counts["bytes"] and not counts["reused_bytes"]:
+            counts["dest_writes"] = 0
+        return out
+
+
+def _written_into(new, spare) -> bool:
+    """Whether `_own` copied a leaf of `new` into a buffer of `spare`
+    (it returns the spare's own array where it did)."""
+    import jax
+    import numpy as np
+
+    buffers = {id(x) for x in jax.tree.leaves(spare)
+               if isinstance(x, np.ndarray)}
+    return any(id(x) in buffers for x in jax.tree.leaves(new))
 
 
 def _reduce(results: list[dict]) -> dict:

@@ -6,6 +6,7 @@ host's four chips, the TPU resource is declared, the model is the tiny
 GPT with seeded weights, and the arena is 8 MiB so that a 20 MB state is
 several times what it holds."""
 
+import statistics
 import time
 
 import numpy as np
@@ -15,10 +16,12 @@ import jax
 import jax.numpy as jnp
 
 import ray_tpu
+from benchmark import boundary_path, span_log
 from ray_tpu._private import global_state
 from ray_tpu.train import Trainer, TrainingOperator, call_log
 from ray_tpu.train import operator as operator_mod
 from ray_tpu.train import snapshot
+from tests.conftest import scale_timeout
 
 P = jax.sharding.PartitionSpec
 
@@ -523,6 +526,230 @@ def test_nothing_is_staged_on_one_device(wide):
     assert len(parts) > 4
     assert all(p["staged_bytes"] == 0 and p["shards"] == 0 for p in parts)
     assert sum(p["bytes"] for p in parts) > 2 * ARENA
+
+
+# ---------------------------------------------------------------------
+# the boundary's critical path, piece by piece
+# ---------------------------------------------------------------------
+
+def _named(entry, name):
+    return [s for s in entry["spans"] if s["name"] == name]
+
+
+def test_every_piece_has_a_wait_a_d2h_and_a_copy_of_its_index(wide):
+    """The driver's `train.snapshot.wait` and `train.snapshot.copy` and
+    the worker's `train.snapshot.d2h` carry the piece's index, so a
+    reader matches the two processes' spans by piece, not by order."""
+    wide.train()
+    entry = call_log()[-1]
+    (snap,) = _named(entry, "train.snapshot")
+    indices = list(range(snap["attrs"]["pieces"]))
+    assert len(indices) > 4
+    for name in ("train.snapshot.wait", "train.snapshot.d2h",
+                 "train.snapshot.copy"):
+        assert sorted(s["attrs"]["piece"]
+                      for s in _named(entry, name)) == indices, name
+    waits = {s["span"]: s for s in _named(entry, "train.snapshot.wait")}
+    assert all(w["parent"] == snap["span"] for w in waits.values())
+    # the map + deserialise of a piece hangs under ITS wait (a piece
+    # under 100 KiB returns inline: no `object.get`), and lies inside it
+    gets = span_log.under(entry, "object.get", "train.snapshot")
+    assert 4 < len(gets) <= len(indices)
+    for get in gets:
+        wait = waits[get["parent"]]
+        assert wait["start"] <= get["start"] <= get["end"] <= wait["end"]
+    assert len({g["parent"] for g in gets}) == len(gets)
+    # the asked-ahead submits stay where they were: under the snapshot
+    assert all(s["parent"] == snap["span"] for s in _named(entry, "task.e2e")
+               if s["attrs"]["name"] == "TrainWorker.state_piece")
+
+
+def test_waits_and_copies_tile_the_drivers_thread(wide):
+    """On the driver's thread the wait and copy spans follow one another
+    and never overlap; what they leave of `train.snapshot` is the
+    submits, the tree handling and the last piece's release — stated
+    here as under a quarter of a second for 22 pieces (5 ms alone)."""
+    wide.train()
+    entry = call_log()[-1]
+    (snap,) = _named(entry, "train.snapshot")
+    tiles = sorted(_named(entry, "train.snapshot.wait")
+                   + _named(entry, "train.snapshot.copy"),
+                   key=lambda s: s["start"])
+    assert [s["name"] for s in tiles] == [
+        "train.snapshot.wait", "train.snapshot.copy"] * (len(tiles) // 2)
+    assert [s["attrs"]["piece"] for s in tiles] == [
+        i // 2 for i in range(len(tiles))]
+    assert snap["start"] <= tiles[0]["start"]
+    assert tiles[-1]["end"] <= snap["end"]
+    for before, after in zip(tiles, tiles[1:]):
+        assert before["end"] <= after["start"], (before, after)
+    remainder = (snap["end"] - snap["start"]
+                 - sum(s["end"] - s["start"] for s in tiles))
+    assert 0 <= remainder < scale_timeout(0.25)
+    # the reader's parts are the same tiling, and add up to the boundary
+    path = boundary_path.call_path(entry)
+    assert path["pieces"] == len(tiles) // 2
+    assert path["wait_s"] > 0 and path["get_s"] > 0 and path["copy_s"] > 0
+    assert path["wait_s"] + path["get_s"] + path["copy_s"] == pytest.approx(
+        sum(s["end"] - s["start"] for s in tiles))
+    assert 0 <= path["hops_s"] == pytest.approx(
+        path["boundary_s"] - path["wait_s"] - path["get_s"]
+        - path["copy_s"])
+
+
+def test_a_d2h_span_accounts_for_its_seconds(wide):
+    """`start_s` + `wait_s` + `join_s` are seconds INSIDE the span, and
+    on one device nothing is joined."""
+    wide.train()
+    parts = _named(call_log()[-1], "train.snapshot.d2h")
+    assert len(parts) > 4
+    for part in parts:
+        a = part["attrs"]
+        assert a["start_s"] > 0 and a["wait_s"] > 0 and a["join_s"] == 0
+        assert a["start_s"] + a["wait_s"] + a["join_s"] <= (
+            part["end"] - part["start"])
+
+
+def test_a_join_is_timed_where_a_leaf_is_split_over_devices(monkeypatch):
+    from ray_tpu._private import tracing
+
+    op = _operator(monkeypatch, 4)
+    op.train_batch(_tiny_pieces()[-1])
+    root = tracing.always_trace()
+    with tracing.open_tree(root) as rows, tracing.use(root):
+        _pull(op, usable=1 << 19)
+    parts = [r for r in rows if r[0] == "train.snapshot.d2h"]
+    assert [r[3]["piece"] for r in parts] == list(range(len(parts)))
+    assert len(parts) > 4
+    for _, start, end, a in parts:
+        assert a["start_s"] + a["wait_s"] + a["join_s"] <= end - start
+        # shards are written where something was staged, and only there
+        assert (a["join_s"] > 0) == (a["staged_bytes"] > 0) == (
+            a["shards"] > 0)
+    assert sum(r[3]["staged_bytes"] for r in parts) > 1 << 20
+
+
+def test_dest_writes_counts_each_buffer_sets_writes(host):
+    """0, 0 (both sets are allocated), 1, 1 (each set's second write:
+    the slow one on the chip machines), then 2, 2 ... — a count the
+    Trainer keeps with each set; a set that had to be allocated anew
+    (here: the spare's tree is not the state's) starts again at 0."""
+    tr = Trainer(Small, num_workers=1)
+    seen = []
+
+    def call():
+        tr.train()
+        (copy,) = _span(call_log()[-1], "train.snapshot.copy")
+        seen.append(copy["dest_writes"])
+        return copy
+
+    try:
+        for _ in range(6):
+            call()
+        assert seen == [0, 0, 1, 1, 2, 2]
+        assert [s[2] for s in tr._owned] == [3, 3]
+        newer, (state, shards, writes) = tr._owned
+        tr._owned = (newer, ({"w": state["params"]["w"]}, shards, writes))
+        copy = call()
+        assert copy["dest_writes"] == 0 and copy["reused_bytes"] == 0
+        assert [s[2] for s in tr._owned] == [1, 3]
+        # the untouched set goes on counting, the new one starts over
+        copy = call()
+        assert copy["dest_writes"] == 3
+        assert copy["reused_bytes"] == copy["bytes"]
+        assert call()["dest_writes"] == 1
+    finally:
+        tr.shutdown(force=True)
+
+
+def _snap_seconds(entry):
+    (snap,) = _named(entry, "train.snapshot")
+    return snap["end"] - snap["start"]
+
+
+def _as_the_parent_recorded(entry):
+    """`entry` less what this round of spans and counts added: the tree
+    the parent commit's program gives for the same call."""
+    waits = {s["span"]: s["parent"]
+             for s in _named(entry, "train.snapshot.wait")}
+    new = ("piece", "dest_writes", "start_s", "wait_s", "join_s",
+           "spans_dropped")
+    return {"trace_id": entry["trace_id"], "spans": [
+        dict(s, parent=waits.get(s["parent"], s["parent"]),
+             attrs={k: v for k, v in s["attrs"].items() if k not in new})
+        for s in entry["spans"] if s["span"] not in waits]}
+
+
+NEW_READERS = ("boundary_wait_s", "snapshot_link_wait_s", "snapshot_join_s",
+               "snapshot_worker_starved_s", "snapshot_copy_rewrite_s",
+               "first_pull_s")
+
+
+def test_the_boundary_readers_on_a_recorded_tree_and_on_the_parents(
+        host, monkeypatch):
+    """The six readers of `benchmark/boundary_path.py` on the log of a
+    run shaped like the benchmark's (`first`, `warm`, a window of four
+    calls), multi-piece; on the parent's trees five give None and
+    `first_pull_s`, which reads a span the parent has, its number."""
+    import ray_tpu.train
+    from benchmark import manifest
+
+    tr = Trainer(Wide, num_workers=1)
+    try:
+        before = len(call_log())
+        for _ in range(6):
+            tr.train()
+        log = call_log()[before:]
+    finally:
+        tr.shutdown(force=True)
+    walls = [s["end"] - s["start"] for e in log
+             for s in _named(e, "train.call")]
+    run = {"attempted": 6, "first": {"wall_s": walls[0]},
+           "calls": [{"wall_s": w} for w in walls[2:]]}
+    paths = [boundary_path.call_path(e) for e in log]
+    assert [p["dest_writes"] for p in paths] == [
+        [0], [0], [1], [1], [2], [2]]
+
+    def read(name):
+        return manifest.module("layer_metrics", name).read(run, None)
+
+    monkeypatch.setattr(ray_tpu.train, "call_log", lambda: list(log))
+    window = paths[2:]
+    median = statistics.median
+    assert read("boundary_wait_s") == median(p["wait_s"] for p in window) > 0
+    assert read("snapshot_link_wait_s") == median(
+        p["link_wait_s"] for p in window) > 0
+    assert read("snapshot_join_s") == 0.0       # one device
+    assert read("snapshot_worker_starved_s") == median(
+        p["starved_s"] for p in window) >= 0
+    # read from exactly the window's first two calls: each set's second
+    # write; `snapshot_copy_s` is the median over all four
+    assert read("snapshot_copy_rewrite_s") == median(
+        p["copy_s"] for p in window[:2])
+    assert read("first_pull_s") == _snap_seconds(log[0]) > 0
+    # the worker stands still only between its tasks: inside the pull
+    for p, e in zip(paths, log):
+        assert 0 <= p["starved_s"] < _snap_seconds(e)
+    # a window without a second write has no rewrite reading
+    monkeypatch.setattr(ray_tpu.train, "call_log", lambda: list(log[2:]))
+    late = {"attempted": 4, "first": {"wall_s": walls[2]},
+            "calls": [{"wall_s": w} for w in walls[4:]]}
+    assert manifest.module("layer_metrics", "snapshot_copy_rewrite_s").read(
+        late, None) is None
+    # the ring dropped the first call: no first pull
+    assert manifest.module("layer_metrics", "first_pull_s").read(
+        dict(late, attempted=6), None) is None
+    # the parent's trees: nothing to read but the span it already had
+    old = [_as_the_parent_recorded(e) for e in log]
+    assert "train.snapshot.wait" not in {
+        s["name"] for e in old for s in e["spans"]}
+    monkeypatch.setattr(ray_tpu.train, "call_log", lambda: list(old))
+    for name in NEW_READERS[:-1]:
+        assert read(name) is None, name
+    assert read("first_pull_s") == _snap_seconds(old[0])
+    # ... whose older readers read what they read before
+    from benchmark.layer_metrics import snapshot_pieces
+    assert snapshot_pieces.read(run, None) == paths[0]["pieces"]
 
 
 # ---------------------------------------------------------------------

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from benchmark import span_log
+from benchmark import boundary_path, span_log
 from ray_tpu._private import serialization, tracing
 from ray_tpu.train import Trainer, TrainingOperator, call_log
 from ray_tpu.train import trainer as trainer_mod
@@ -18,8 +18,8 @@ from tests.test_observability import (_assert_connected, _tree_of,
 
 # every span of a call, by the name ARCHITECTURE.md's catalogue lists
 CALL_SPANS = {"train.call", "train.epoch", "train.dispatch", "train.sync",
-              "train.snapshot", "train.snapshot.d2h", "object.return_put",
-              "object.get", "train.snapshot.copy"}
+              "train.snapshot", "train.snapshot.wait", "train.snapshot.d2h",
+              "object.return_put", "object.get", "train.snapshot.copy"}
 LEAF = "train.snapshot.d2h.leaf"
 
 
@@ -168,6 +168,19 @@ def test_parts_of_a_call_add_up(wide):
         assert copy["attrs"]["bytes"] == parts["bytes"]
         # how much of it went into the buffers of a retired snapshot
         assert 0 <= copy["attrs"]["reused_bytes"] <= parts["bytes"]
+        # the same boundary cut along the driver's thread: with ONE
+        # piece the wait is at most the worker's d2h and put and the
+        # hops (all of them on the chip, where the driver is in the wait
+        # before the worker has the task; here it may lose the CPU first)
+        path = boundary_path.call_path(entry)
+        assert path["pieces"] == 1
+        assert path["boundary_s"] == pytest.approx(call_s - parts["epoch_s"])
+        assert path["get_s"] == parts["get_s"]
+        assert path["copy_s"] == parts["copy_s"]
+        assert 0 <= path["wait_s"] <= (parts["d2h_s"] + parts["put_s"]
+                                       + parts["hop_s"] + 1e-3)
+        assert path["hops_s"] >= 0
+        assert path["link_wait_s"] + path["start_s"] <= parts["d2h_s"]
 
 
 def test_leaf_spans_only_when_the_call_is_traced(wide, tmp_path):
@@ -236,6 +249,35 @@ def test_return_put_only_for_plasma_returns(ray_start_shared):
     assert small.nbytes == 1000
 
 
+def test_spans_dropped_counts_what_a_reply_left_out(ray_start_shared):
+    """A reply carries at most `REPLY_SPANS_MAX` rows; the owner's
+    `task.e2e` says how many it left out, so a truncated tree says so."""
+    @ray_tpu.remote
+    def spans(n):
+        for _ in range(n):
+            with tracing.span("test.leaf", tracing.child_of_current()):
+                pass
+        return n
+
+    ctx = tracing.new_context()
+    with tracing.open_tree(ctx) as rows, tracing.use(ctx):
+        assert ray_tpu.get(spans.remote(10), timeout=60) == 10
+        few = list(rows)
+        del rows[:]
+        assert ray_tpu.get(spans.remote(300), timeout=60) == 300
+
+    def read(tree):
+        (e2e,) = [r for r in tree if r[0] == "task.e2e"]
+        return (e2e[3]["spans_dropped"],
+                sum(r[0] == "test.leaf" for r in tree),
+                sum(r[0] == "task" for r in tree))
+
+    assert read(few) == (0, 10, 1)
+    # 300 leaves and the `task` span itself, which closes last
+    assert read(rows) == (301 - tracing.REPLY_SPANS_MAX,
+                          tracing.REPLY_SPANS_MAX, 0)
+
+
 def test_sharded_call_carries_the_shard_pulls(ray_start_shared):
     tr = Trainer(ShardOperator, num_workers=2, sharded=True)
     try:
@@ -256,4 +298,8 @@ def test_sharded_call_carries_the_shard_pulls(ray_start_shared):
         assert any(s["parent"] == pull["span"] for s in d2h)
     # the state's copy-out and the shards'
     assert _names(entry).count("train.snapshot.copy") == 2
+    # ... of which only the state's is a piece: the readers of the
+    # boundary's path leave the shards' pull to the hops
+    path = boundary_path.call_path(entry)
+    assert path["pieces"] == 1 and path["hops_s"] > 0
     assert _names(entry).count("train.dispatch") == 2
