@@ -15,28 +15,32 @@ shapes reach the kernel is not the rule's to change: those that 128 x 128
 tiles took (T a multiple of 128, or of 8 below 128; head size a multiple
 of 8).
 
-Backward of the plain path (no window, equal head counts) is a kernel
-too, `flash_bwd_fused`: the forward under a gradient also writes the row
+The backward is a kernel too, ONE for every call the forward kernel
+takes, `flash_bwd_fused`: the forward under a gradient also writes the row
 log-sum-exp (float32, one number a query row and head), the residuals
-are (q, k, v, o, lse), and one pass over the score tiles at or below the
-diagonal rebuilds p = exp(s - lse) in VMEM and accumulates dq, dk and dv
+are (q, k, v, o, lse), and one pass over the score tiles inside the mask
+rebuilds p = exp(s - lse) in VMEM and accumulates dq, dk and dv
 in float32 — no score tile, no float32 dk/dv carry ever reaches HBM, and
-the blocks the causal mask empties are skipped. MXU operands are in the
+the blocks the mask empties are skipped. MXU operands are in the
 inputs' dtype (p and ds cast to it, as the forward casts p); scores, lse,
-delta, ds and the accumulators float32. Sequences no tile divides (T % 8)
-take one checkpointed dense block, forward and backward. On CPU (tests)
-the kernels run in interpret mode.
+delta, ds and the accumulators float32. Its tile is `_bwd_tiles`' (from
+T, head size and dtype; the table is in its docstring). Sequences no tile
+divides (T % 8) take one checkpointed dense block, forward and backward.
+On CPU (tests) the kernels run in interpret mode.
 
-Two things beyond the plain causal kernel, both off by default: a
-sliding `window` (query i sees keys j with 0 <= i - j < window; the
-forward's K loop starts at the first block the window reaches; the
-backward there is XLA's, `_bwd_grouped`: a scan that gives a query block
-a key slice of fixed length window + block instead of all T), and
+Two things beyond the plain causal kernel, both off by default and both
+static at trace time: a sliding `window` (query i sees keys j with
+0 <= i - j < window; the forward's K loop starts at the first block the
+window reaches, the backward's Q loop ends at the last block that still
+reaches the key block, and the tile's mask gains the second bound), and
 grouped-query heads (k and v with fewer heads than q: query head g reads
 key/value head g // (H // H_kv), chosen in the BlockSpec index map, so
-no repeated K/V is ever written to HBM).
-With `window=None` and equal head counts the forward kernel is the one
-this file built before either existed.
+no repeated K/V is ever written to HBM; the backward's grid gains a group
+axis between heads and key blocks, over which dk and dv of a key/value
+head are summed in float32 VMEM scratch of a whole sequence and written
+once). With `window=None` and equal head counts every such branch folds
+away: the traced program, forward and backward, is the one this file
+built before either existed.
 """
 
 from __future__ import annotations
@@ -55,10 +59,9 @@ from ray_tpu.ops.partition import over_leading_dim
 logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
-# Query rows a step of the windowed / grouped backward takes.
-BWD_BLOCK_Q = 64
 # What `flash_bwd_fused` may hold in VMEM: q and do of a whole sequence
-# (twice: double-buffered), dq.T in float32, and the float32 tiles.
+# (twice: double-buffered), dq.T in float32, the float32 tiles and,
+# under grouped heads, dk and dv of a whole sequence in float32.
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -109,9 +112,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
             last = (qi + 1) * block_q // block_k + (block_q % block_k != 0)
         num_k_active = jnp.minimum(num_k, last)
         # ... and, under a window, at or after the first block the
-        # block's first query still reaches (a row whose keys all lie in
-        # later blocks accumulates exp(0) there; the first real score
-        # rescales that to nothing, as a causal row's masked tail does)
+        # block's first query still reaches. A row whose keys all lie in
+        # later blocks leaves that block with m = NEG_INF and l = the
+        # block's width (exp(0) a masked score); its first real score
+        # rescales both by exp(NEG_INF - s) = 0.0 exactly, and every row
+        # has one (its own key), so o and m + log(l) are exact at the end
         first = 0 if window is None else jnp.maximum(
             0, qi * block_q - (window - 1)) // block_k
         o, m, l = jax.lax.fori_loop(first, num_k_active, body, (o0, m0, l0))
@@ -138,9 +143,9 @@ def _flash_aligned(t: int, d: int, block_q: int, block_k: int) -> bool:
 def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                     block_q: int | None, block_k: int | None, interpret: bool,
                     window: int | None = None, save_lse: bool = False):
-    """`block_q`, `block_k`: None asks `fwd_tiles`. `save_lse` (the plain
-    path under a gradient): returns (out, lse), lse [B, H, T] float32 —
-    None where the dense fallback ran."""
+    """`block_q`, `block_k`: None asks `fwd_tiles`. `save_lse` (under a
+    gradient): returns (out, lse), lse [B, H, T] float32 — None where
+    the dense fallback ran."""
     b, t, h, d = q.shape
     block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k)
     plain = window is None and k.shape[2] == h
@@ -157,10 +162,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                 f"flash_attention: seq {t} / head_dim {d} not tile-aligned;"
                 " falling back to dense O(T^2) attention — pad the sequence"
                 " to a multiple of 8 for the pallas kernel", stacklevel=2)
-        if plain:
-            out = _dense_attention(q, k, v, causal, scale)
-            return (out, None) if save_lse else out
-        return _dense_grouped(q, k, v, scale, 0, 0, window)
+        out = _dense_fallback(q, k, v, causal, scale, window)
+        return (out, None) if save_lse else out
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     # once a traced call: the tile is static in the compiled program
@@ -250,26 +253,31 @@ def _dense_attention(q, k, v, causal, scale, pad_mask=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v).astype(q.dtype)
 
 
-def _dense_grouped(q, k, v, scale, q_offset, k_offset, window):
-    """Causal attention of a query block against a key slice, by absolute
-    positions (`q_offset`, `k_offset`: the first row's and first key's),
-    under an optional window, with k and v of fewer heads than q (query
-    head g reads key/value head g // group). The backward's block and
-    the unaligned fallback; scores in float32."""
-    b, tq, h, d = q.shape
-    tk, h_kv = k.shape[1], k.shape[2]
-    qg = q.reshape(b, tq, h_kv, h // h_kv, d)
+def _dense_grouped(q, k, v, scale, window):
+    """Dense causal attention under an optional window, with k and v of
+    fewer heads than q (query head g reads key/value head g // group):
+    the unaligned fallback of that path; scores in float32."""
+    b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    qg = q.reshape(b, t, h_kv, h // h_kv, d)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                         preferred_element_type=jnp.float32) * scale
-    q_pos = (q_offset + jnp.arange(tq))[:, None]
-    k_pos = (k_offset + jnp.arange(tk))[None, :]
-    keep = q_pos >= k_pos
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = ahead >= 0
     if window is not None:
-        keep &= q_pos - k_pos < window
+        keep &= ahead < window
     scores = jnp.where(keep[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(b, tq, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, d).astype(q.dtype)
+
+
+def _dense_fallback(q, k, v, causal, scale, window):
+    """What a call no tile divides takes, forward and (checkpointed)
+    backward."""
+    if window is None and k.shape[2] == q.shape[2]:
+        return _dense_attention(q, k, v, causal, scale)
+    return _dense_grouped(q, k, v, scale, window)
 
 
 def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
@@ -295,84 +303,12 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
 
 
 def _fwd(q, k, v, causal, scale, block_q, block_k, window):
-    if window is not None or k.shape[2] != q.shape[2]:
-        out = flash_attention(q, k, v, causal, scale, block_q, block_k, window)
-        return out, (q, k, v, None, None)
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     out, lse = _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                                block_q=block_q, block_k=block_k,
-                               interpret=not is_tpu(), save_lse=True)
+                               interpret=not is_tpu(), window=window,
+                               save_lse=True)
     return out, (q, k, v, out, lse)
-
-
-def _bwd_grouped(scale, window, q, k, v, g):
-    """The backward under a window or grouped heads: scan over Q blocks,
-    each against the ONE key slice its mask can reach, accumulating
-    dk/dv into that slice. Past the window that slice is window + block
-    keys ending with the block's last row: work and the float32 score
-    tile are linear in the window, not in T. The blocks before that —
-    all of them under the causal mask alone — see every key up to their
-    last row, and go in up to four stages, each against the keys its
-    LAST block reaches (a quarter, a half, ... of the prefix: five
-    eighths of the work of giving each the whole prefix, where the mask
-    keeps a half). The block is this path's own (`BWD_BLOCK_Q`), not the
-    forward kernel's."""
-    b, t, h, d = q.shape
-    bq = min(BWD_BLOCK_Q, t)
-    if t % bq:
-        def f(q, k, v):
-            return _dense_grouped(q, k, v, scale, 0, 0, window)
-
-        _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
-        return vjp(g)
-    n = t // bq
-    # (first block, blocks, keys in the slice) of every stage
-    prefix = n if window is None else min(n, window // bq)
-    stages = next(s for s in (4, 2, 1) if prefix % s == 0)
-    plan = [(s * prefix // stages, prefix // stages,
-             (s + 1) * prefix // stages * bq)
-            for s in range(stages) if prefix]
-    if prefix < n:
-        plan.append((prefix, n - prefix,
-                     min(t, -(-(window - 1) // bq) * bq + bq)))
-    qb = jnp.moveaxis(q.reshape(b, n, bq, h, d), 1, 0)   # [n, B, bq, H, D]
-    gb = jnp.moveaxis(g.reshape(b, n, bq, h, d), 1, 0)
-
-    def stage(span):
-        def body(carry, inp):
-            dk, dv = carry
-            i, q_blk, g_blk = inp
-            lo = jnp.clip((i + 1) * bq - span, 0, t - span)
-            k_sl = jax.lax.dynamic_slice_in_dim(k, lo, span, axis=1)
-            v_sl = jax.lax.dynamic_slice_in_dim(v, lo, span, axis=1)
-
-            def f(q_blk, k_sl, v_sl):
-                return _dense_grouped(q_blk, k_sl, v_sl, scale, i * bq, lo,
-                                      window)
-
-            _, vjp = jax.vjp(f, q_blk, k_sl, v_sl)
-            dq_blk, dk_i, dv_i = vjp(g_blk)
-
-            def add(acc, part):
-                old = jax.lax.dynamic_slice_in_dim(acc, lo, span, axis=1)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    acc, old + part, lo, axis=1)
-
-            return (add(dk, dk_i), add(dv, dv_i)), dq_blk
-
-        return body
-
-    carry = (jnp.zeros_like(k, jnp.float32), jnp.zeros_like(v, jnp.float32))
-    dq = []
-    for first, count, span in plan:
-        carry, dq_stage = jax.lax.scan(
-            stage(span), carry, (first + jnp.arange(count),
-                                 qb[first:first + count],
-                                 gb[first:first + count]))
-        dq.append(dq_stage)
-    dk, dv = carry
-    dq = jnp.moveaxis(jnp.concatenate(dq), 0, 1).reshape(b, t, h, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
@@ -380,10 +316,12 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dqt_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc, *,
-                      block_q: int, causal: bool, scale: float):
+                      block_q: int, causal: bool, scale: float,
+                      window: int | None = None, group: int = 1):
     """One key block of the backward, in one pass over the query blocks
-    at or after it (all of them without the mask): dk and dv of its keys,
-    and its share of every dq. A tile has the KEYS along its rows —
+    at or after it (all of them without the mask; under a window only
+    those that still reach it): dk and dv of its keys, and its share of
+    every dq. A tile has the KEYS along its rows —
     p.T and ds.T, [block_k, block_q], rebuilt in VMEM from the saved
     log-sum-exp — so `lse` and `delta` are read as rows along the lanes
     and all three products are plain ones: dv += p.T @ do, dk += ds.T @ q,
@@ -391,19 +329,35 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     slab a query block: whole lanes at head size 64) over the grid's key
     axis and written at its last step; every accumulator is float32
     scratch, cast once. MXU operands in the inputs' dtype, as the forward
-    feeds them."""
-    ki = pl.program_id(1)
+    feeds them.
+
+    Grouped heads (`group` query heads a key/value head) put the group
+    between the head axis and the key axis of the grid: q, do, lse,
+    delta and dq.T are ONE query head's, k and v its key/value head's,
+    and dk, dv are summed over the group's query heads in float32
+    scratch of a whole sequence, a key block written when its last query
+    head has been through."""
+    grouped = group > 1
+    ki = pl.program_id(2 if grouped else 1)
     k = k_ref[...]   # [block_k, d]
     v = v_ref[...]
     kt = k.T
     block_k = k.shape[0]
+    # this key block's rows of the dk / dv accumulators
+    keys = pl.ds(ki * block_k, block_k) if grouped else ...
 
     @pl.when(ki == 0)
     def _():
         dqt_acc[...] = jnp.zeros_like(dqt_acc)
 
-    dk_acc[...] = jnp.zeros_like(dk_acc)
-    dv_acc[...] = jnp.zeros_like(dv_acc)
+    if grouped:
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            dk_acc[keys] = jnp.zeros((block_k, k.shape[1]), jnp.float32)
+            dv_acc[keys] = jnp.zeros((block_k, k.shape[1]), jnp.float32)
+    else:
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
     if causal:
         # key position minus query position, for a tile at the origin
         ahead = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
@@ -416,23 +370,39 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
         if causal:
-            st = jnp.where(ahead <= qi * block_q - ki * block_k, st, NEG_INF)
+            reach = qi * block_q - ki * block_k
+            keep = ahead <= reach
+            if window is not None:
+                keep &= ahead > reach - window
+            st = jnp.where(keep, st, NEG_INF)
         pt = jnp.exp(st - lse_ref[qi])   # lse, delta: [1, block_q]
         dpt = jax.lax.dot_general(v, do, _NT,
                                   preferred_element_type=jnp.float32)
         dst = (pt * (dpt - delta_ref[qi])).astype(q.dtype)
-        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
-        dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        dv_acc[keys] += jnp.dot(pt.astype(do.dtype), do,
+                                preferred_element_type=jnp.float32)
+        dk_acc[keys] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
         dqt_acc[qi] += jnp.dot(kt, dst, preferred_element_type=jnp.float32)
 
     # only the Q blocks whose last row reaches this K block's first key
     first = ki * block_k // block_q if causal else 0
-    jax.lax.fori_loop(first, q_ref.shape[0] // block_q, body, None)
-    dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+    last = q_ref.shape[0] // block_q
+    if window is not None:
+        # ... and whose first row is still within the window of its last
+        last = jnp.minimum(
+            last, ((ki + 1) * block_k + window - 2) // block_q + 1)
+    jax.lax.fori_loop(first, last, body, None)
 
-    @pl.when(ki == pl.num_programs(1) - 1)
+    def write():
+        dk_ref[...] = (dk_acc[keys] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[keys].astype(dv_ref.dtype)
+
+    if grouped:
+        pl.when(pl.program_id(1) == group - 1)(write)
+    else:
+        write()
+
+    @pl.when(ki == pl.num_programs(2 if grouped else 1) - 1)
     def _():
         dqt_ref[...] = (dqt_acc[...] * scale).astype(dqt_ref.dtype)
 
@@ -448,12 +418,31 @@ def _fit(t: int, target: int) -> int:
 
 def _bwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
     """(block_q, block_k) of `flash_bwd_fused`, from what the call
-    observes. Read on the chip at head size 64, T 1024, bf16 (PR 32,
-    PERF.md): 512 x 512 fastest (3.81 ms a GPT-2-small layer at batch
-    32), 256 x 512 3 % and 256 x 256 12 % behind, 1024 on either side
-    11-15 % behind. No other head size or dtype has a reading on this
-    path yet, so they take the same tile (which compiles for float32
-    and for heads of 128 and 256)."""
+    observes: 512 x 512, halved until it divides t (all of t below
+    that). Read on the chip, a layer's whole backward (the kernel, delta
+    and the layout copies around it), bf16, causal, ms a call:
+
+        tile        [3,8192,28|4,128]  the same,    [9,4096,32|8,64]
+                    window 4096        no window
+        128 x 512     27.07              33.31        30.50
+        256 x 256     22.52              28.11        26.02
+        256 x 512     20.85              25.45        23.61
+        512 x 256     21.41              25.98        24.48
+        512 x 512     20.18              24.45        22.73
+        512 x 1024    20.79              24.28        23.14
+        1024 x 512    21.42              24.78        23.37
+        1024 x 1024   20.69              23.92        22.78
+        the scan this path had: 100.8    212.5        214.8
+
+    (the expert cells' layers at their batch, PR 36, PERF.md section 6:
+    134, 148 and 68 TFLOP/s inside the mask at 512 x 512). At head size
+    64, T 1024 (`[32, 1024, 12, 64]`, PR 32): 512 x 512 3.81 ms,
+    256 x 512 3 % and 256 x 256 12 % behind, 1024 on either side 11-15 %
+    behind. From 512 rows up the tiles lie within 3 % of each other at
+    every shape read, the window moves nothing, and 1024 x 1024's 2 % at
+    8k without a window is a loss at T 1024: neither d, dtype nor the
+    window moves the rule yet (it compiles for float32 and for heads of
+    128 and 256)."""
     block = _fit(t, 512)
     return block, block
 
@@ -509,45 +498,71 @@ def fwd_tiles(t: int, d: int, dtype, block_q: int | None = None,
 
 
 def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
-                    interpret: bool):
-    """(dq, dk, dv) of the plain path in one kernel, `flash_bwd_fused`.
-    Scores, lse, delta, ds and the accumulators in float32; p and ds
-    cast to the inputs' dtype for the MXU, as the forward casts p."""
+                    interpret: bool, window: int | None = None):
+    """(dq, dk, dv) in one kernel, `flash_bwd_fused`. Scores, lse, delta,
+    ds and the accumulators in float32; p and ds cast to the inputs'
+    dtype for the MXU, as the forward casts p. The grid is (batch x
+    heads, key blocks); with fewer key/value heads than query heads it
+    is (batch x key/value heads, group, key blocks), and no K, V, dk or
+    dv of a repeated head ever reaches HBM."""
     b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    group = h // h_kv
     block_q, block_k = _bwd_tiles(t, d, q.dtype)
+    # once a traced call, as the forward says its own
+    logger.debug("flash_bwd_fused %s | %d %s: tiles %d x %d", q.shape, h_kv,
+                 q.dtype, block_q, block_k)
     # delta = rowsum(o * do): what the softmax's backward takes off dp
     delta = jnp.einsum("bthd,bthd->bht", o.astype(jnp.float32),
                        g.astype(jnp.float32))
 
     num_q = t // block_q
+    num_k = t // block_k
 
     def rows(x):
         # [B, H, T] float32 as one [1, block_q] row a query block
         return x.reshape(b * h, num_q, 1, block_q)
 
-    def kv_index(bh, ki):
-        return (bh, ki, 0)
+    # a grid step's (query head, key/value head, dk / dv block it writes)
+    if group == 1:
+        grid = (b * h, num_k)
 
-    whole = pl.BlockSpec((None, t, d), lambda bh, ki: (bh, 0, 0))
+        def at(bh, ki):
+            return bh, bh, ki
+    else:
+        grid = (b * h_kv, group, num_k)
+
+        # query head gi of key/value head bkv, in the folded layout; the
+        # output block stays the first until the group's last query
+        # head, which writes each: one write-back a block, of final sums
+        def at(bkv, gi, ki):
+            return bkv * group + gi, bkv, jnp.where(gi == group - 1, ki, 0)
+
+    whole = pl.BlockSpec((None, t, d), lambda *i: (at(*i)[0], 0, 0))
     row_spec = pl.BlockSpec((None, num_q, 1, block_q),
-                            lambda bh, ki: (bh, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+                            lambda *i: (at(*i)[0], 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d), lambda *i: (at(*i)[1], i[-1], 0))
+    dkv_spec = pl.BlockSpec((None, block_k, d), lambda *i: at(*i)[1:] + (0,))
+    # dk, dv scratch: a key block's, or under grouped heads the whole
+    # sequence's, summed over the group
+    kv_rows = block_k if group == 1 else t
     dqt, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
-                          scale=scale),
-        grid=(b * h, t // block_k),
+                          scale=scale, window=window, group=group),
+        grid=grid,
         in_specs=[whole, whole, row_spec, row_spec, kv_spec, kv_spec],
         out_specs=[pl.BlockSpec((None, num_q, d, block_q),
-                                lambda bh, ki: (bh, 0, 0, 0)),
-                   kv_spec, kv_spec],
+                                lambda *i: (at(*i)[0], 0, 0, 0)),
+                   dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, num_q, d, block_q), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, t, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((num_q, d, block_q), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((kv_rows, d), jnp.float32),
+                        pltpu.VMEM((kv_rows, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",) + ("arbitrary",) * (
+                len(grid) - 1),
             vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_fused",
@@ -560,17 +575,15 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
 def _bwd(causal, scale, block_q, block_k, window, residuals, g):
     q, k, v, o, lse = residuals
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
-    if window is not None or k.shape[2] != q.shape[2]:
-        return _bwd_grouped(actual_scale, window, q, k, v, g)
     if lse is None:
         # unaligned fallback, as the forward's: one checkpointed dense block
-        def f(q, k, v):
-            return _dense_attention(q, k, v, causal, actual_scale)
-
+        f = functools.partial(_dense_fallback, causal=causal,
+                              scale=actual_scale, window=window)
         _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
         return vjp(g)
     call = functools.partial(_flash_bwd_call, causal=causal,
-                             scale=actual_scale, interpret=not is_tpu())
+                             scale=actual_scale, interpret=not is_tpu(),
+                             window=window)
     # as the forward: each device takes its own rows of the batch
     return over_leading_dim(call, (True,) * 6)(q, k, v, o, lse, g)
 

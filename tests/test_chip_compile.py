@@ -123,6 +123,11 @@ def test_flash_attention_bwd(one_chip, on_tpu, shape):
     assert f"f32[{b},{h},128,{t}]" not in text   # the old backward's tile
 
 
+def _rel_err(a, r):
+    return float(jnp.linalg.norm(a.astype(jnp.float32) - r)
+                 / jnp.linalg.norm(r))
+
+
 @pytest.mark.parametrize("shape", CELL_SHAPES)
 def test_flash_attention_bwd_runs_on_the_chip(shape):
     """Runs only where the default backend is a TPU (the test tree pins
@@ -145,27 +150,88 @@ def test_flash_attention_bwd_runs_on_the_chip(shape):
             q, k, v, True, shape[-1] ** -0.5) * w).sum(), (0, 1, 2)))(
                 *(x.astype(jnp.float32) for x in (q, k, v)))
     for name, a, r in zip(("dq", "dk", "dv"), got, want):
-        err = float(jnp.linalg.norm(a.astype(jnp.float32) - r)
-                    / jnp.linalg.norm(r))
-        assert err < 0.01, (name, err)
+        assert _rel_err(a, r) < 0.01, (name, _rel_err(a, r))
 
 
-@pytest.mark.parametrize("window", [4096, None])
-def test_flash_attention_window_and_grouped_heads(one_chip, on_tpu, window):
-    """SmallThinker's widths at 8k: 28 query heads on 4 key/value heads
-    of 128, the forward kernel's 256 x 512 tiles, forward and backward
-    (the backward is XLA: one Mosaic call, the forward's)."""
+# the expert cells' attention layers, a sequence of each: SmallThinker's
+# window and full layers (28 | 4 heads of 128 at 8k), LFM2's (32 | 8 of
+# 64 at 4k); each with the forward tile its decoder passes
+GROUPED_SHAPES = {
+    "smallthinker-window": ((1, 8192, 28, 128), 4, 4096),
+    "smallthinker-full": ((1, 8192, 28, 128), 4, None),
+    "lfm2-full": ((1, 4096, 32, 64), 8, None),
+}
+
+
+def _grouped_grads(window):
     from ray_tpu.ops import attention
 
-    q = jax.ShapeDtypeStruct((1, 8192, 28, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16,
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: (attention.flash_attention(
+            q, k, v, True, None, 256, 512, window).astype(jnp.float32)
+            * w).sum(), (0, 1, 2))(q, k, v)
+
+    return grads
+
+
+@pytest.mark.parametrize("case", GROUPED_SHAPES)
+def test_flash_attention_window_and_grouped_heads(one_chip, on_tpu, case):
+    """The expert cells' widths under a gradient: the forward kernel at
+    the decoder's 256 x 512 tiles, writing the row log-sum-exp, and ONE
+    backward kernel — q, do and the float32 dq.T of one query head, dk
+    and dv of a whole sequence in float32, inside the kernel's own VMEM
+    limit — with nothing of the scan this path had left for XLA: no
+    float32 score tile of a 64-row block, no `dynamic-update-slice` into
+    a float32 dk / dv carry."""
+    shape, kv_heads, window = GROUPED_SHAPES[case]
+    b, t, h, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
                               sharding=one_chip)
-    text = _compiled_text(jax.value_and_grad(
-        lambda q, k, v: attention.flash_attention(
-            q, k, v, True, None, 256, 512, window).astype(
-                jnp.float32).sum(), (0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") == 1
+    w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(_grouped_grads(window), q, kv, kv, w)
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    assert "dynamic-update-slice" not in text and "while(" not in text
+    assert f"f32[{b},{kv_heads},{h // kv_heads},64," not in text
+
+
+@pytest.mark.parametrize("case", GROUPED_SHAPES)
+def test_flash_attention_window_and_grouped_bwd_runs_on_the_chip(case):
+    """Runs only on a TPU, as the plain path's above. dq, dk, dv of a
+    window / grouped-head call on bf16 inputs against dense masked
+    attention in float32 on the same inputs, a key/value head and its
+    query heads at a time (the float32 scores of 28 heads at 8k would
+    not fit the chip): the same measure and limit as the plain path."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import attention
+
+    shape, kv_heads, window = GROUPED_SHAPES[case]
+    b, t, h, d = shape
+    group = h // kv_heads
+    keys = jax.random.split(jax.random.key(h), 4)
+    q, w = (jax.random.normal(key, shape, jnp.float32) for key in keys[:2])
+    k, v = (jax.random.normal(key, (b, t, kv_heads, d), jnp.float32)
+            for key in keys[2:])
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = jax.jit(_grouped_grads(window))(q, k, v, w)
+
+    @jax.jit
+    def dense_grads(q, k, v, w):   # one key/value head and its group
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda q, k, v: (attention._dense_grouped(
+                q, k, v, d ** -0.5, window) * w).sum(), (0, 1, 2))(q, k, v)
+
+    want = [dense_grads(q[:, :, i * group:(i + 1) * group].astype(
+        jnp.float32), k[:, :, i:i + 1].astype(jnp.float32),
+        v[:, :, i:i + 1].astype(jnp.float32),
+        w[:, :, i * group:(i + 1) * group]) for i in range(kv_heads)]
+    want = [jnp.concatenate(part, axis=2) for part in zip(*want)]
+    errs = {name: _rel_err(a, r)
+            for name, a, r in zip(("dq", "dk", "dv"), got, want)}
+    print(case, errs)
+    assert max(errs.values()) < 0.01, errs
 
 
 def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):

@@ -192,37 +192,117 @@ def _dense(q, k, v, window):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
-@pytest.mark.parametrize("t,window,heads,kv_heads,block", [
-    (64, 16, 4, 2, 16),        # T above the window
-    (256, 48, 6, 2, 32),       # a window that is no multiple of the block
-    (64, 100, 4, 2, 16),       # T below the window
-    (64, None, 4, 2, 16),      # grouped heads alone (a full layer)
-    (256, None, 4, 2, 32),     # ... its backward in four stages of keys
-    (64, 16, 4, 4, 16),        # a window alone
-    (40, 16, 4, 2, 16),        # unaligned T: the dense fallback
-])
-def test_window_and_grouped_attention(t, window, heads, kv_heads, block):
+# name -> (T, window, query heads, key/value heads, the forward's
+# (block_q, block_k), the backward's as `_bwd_tiles` returns them — None:
+# what the file chooses, all of T at these sizes — dtype, tolerance).
+# Every case has a random cotangent and a scale other than 1.
+ATTENTION_CASES = {
+    "T-above-window": (64, 16, 4, 2, (16, 16), None, jnp.float32, 1e-5),
+    "window-no-multiple-of-the-block": (256, 48, 6, 2, (32, 32), None,
+                                        jnp.float32, 1e-5),
+    "T-below-window": (64, 100, 4, 2, (16, 16), None, jnp.float32, 1e-5),
+    "grouped-heads-alone": (64, None, 4, 2, (16, 16), None, jnp.float32,
+                            1e-5),
+    # dk / dv of a key/value head summed over its two query heads in
+    # each of four key blocks, under the causal mask alone (the loop's
+    # `first` bound with nothing taken off its end)
+    "grouped-heads-four-key-blocks": (256, None, 4, 2, (32, 32), (64, 64),
+                                      jnp.float32, 1e-5),
+    "window-alone": (64, 16, 4, 4, (16, 16), None, jnp.float32, 1e-5),
+    "unaligned-T-dense-fallback": (40, 16, 4, 2, (16, 16), None,
+                                   jnp.float32, 1e-5),
+    # a window (50) that is no multiple of either tile and ends inside a
+    # key block: the query loop's `last` bound (one block too few loses
+    # the window's oldest keys' dk / dv, one too many only costs time),
+    # the tile's second mask, and the lse of rows whose first key block
+    # in the forward is wholly masked (row 127 starts at key 78, its
+    # block's loop at key 32)
+    "window-ends-inside-a-key-block": (256, 50, 4, 2, (32, 32), (32, 64),
+                                       jnp.float32, 1e-5),
+    "window-ends-inside-a-query-block": (256, 50, 4, 2, (32, 32), (64, 32),
+                                         jnp.float32, 1e-5),
+    # SmallThinker's 28 | 4: a dk / dv that forgets a query head of the
+    # group, counts one twice, or reads another group's, shows
+    "group-of-7": (64, 24, 28, 4, (16, 32), (16, 32), jnp.float32, 2e-5),
+    "group-of-7-no-window": (64, None, 28, 4, (16, 32), (32, 16),
+                             jnp.float32, 2e-5),
+    # the backward's tile above, below and equal to the forward's (its
+    # 256 x 512 shape, at a sixteenth): the lse is written in rows of
+    # the forward's block_q and read in rows of the backward's
+    "bwd-tile-above-fwd": (128, 40, 4, 2, (16, 32), (32, 64), jnp.float32,
+                           1e-5),
+    "bwd-tile-below-fwd": (128, 40, 4, 2, (16, 32), (8, 16), jnp.float32,
+                           1e-5),
+    "bwd-tile-equal-fwd": (128, 40, 4, 2, (16, 32), (16, 32), jnp.float32,
+                           1e-5),
+    # bf16 operands on the MXU (p and ds cast to it), float32 elsewhere
+    "bf16": (128, 40, 6, 2, (16, 32), (32, 32), jnp.bfloat16, 6e-2),
+    "bf16-no-window": (128, None, 6, 2, (16, 32), (32, 32), jnp.bfloat16,
+                       6e-2),
+    # a window that masks nothing, over several blocks: the loop's end
+    # clipped to the sequence's
+    "window-at-least-T": (128, 128, 4, 2, (32, 32), (32, 32), jnp.float32,
+                          1e-5),
+    "window-beyond-T": (128, 1000, 4, 2, (32, 32), (16, 64), jnp.float32,
+                        1e-5),
+    # a window on the two-axis grid (no group to sum over)
+    "window-equal-heads": (128, 40, 4, 4, (32, 32), (32, 16), jnp.float32,
+                           1e-5),
+    "window-of-one-block": (128, 32, 4, 4, (32, 32), (32, 32), jnp.float32,
+                            1e-5),
+}
+
+
+@pytest.mark.parametrize("case", ATTENTION_CASES)
+def test_window_and_grouped_attention(case, monkeypatch):
     """`flash_attention` with `window` and grouped heads against dense
-    masked attention, forward and backward. float32 throughout: the
-    difference is the blockwise softmax's order of sums (measured
-    6e-7 forward, 1.5e-6 on a gradient)."""
+    masked attention, forward and backward (the one kernel
+    `flash_bwd_fused`, at the case's tile). In float32 the difference is
+    the blockwise softmax's order of sums (measured 6e-7 forward, 1.5e-6
+    on a gradient); the bf16 cases' reference reads the same bf16 inputs
+    in float32."""
+    from ray_tpu.ops import attention
+
+    t, window, heads, kv_heads, block, tiles, dtype, tol = (
+        ATTENTION_CASES[case])
+    if tiles is not None:
+        monkeypatch.setattr(attention, "_bwd_tiles", lambda *_: tiles)
     keys = jax.random.split(jax.random.key(0), 4)
-    q = jax.random.normal(keys[0], (2, t, heads, 16))
-    k = jax.random.normal(keys[1], (2, t, kv_heads, 16))
-    v = jax.random.normal(keys[2], (2, t, kv_heads, 16))
+    q = jax.random.normal(keys[0], (2, t, heads, 16)).astype(dtype)
+    k = jax.random.normal(keys[1], (2, t, kv_heads, 16)).astype(dtype)
+    v = jax.random.normal(keys[2], (2, t, kv_heads, 16)).astype(dtype)
     w = jax.random.normal(keys[3], (2, t, heads, 16))
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
 
     def ours(q, k, v):
-        return flash_attention(q, k, v, True, None, block, block, window)
+        return flash_attention(q, k, v, True, None, *block, window).astype(
+            jnp.float32)
 
     assert float(jnp.abs(jax.jit(ours)(q, k, v)
-                         - _dense(q, k, v, window)).max()) <= 5e-6
+                         - _dense(*f32, window)).max()) <= tol / 2
     got = jax.jit(jax.grad(lambda *a: (ours(*a) * w).sum(),
                            (0, 1, 2)))(q, k, v)
     want = jax.grad(lambda *a: (_dense(*a, window) * w).sum(),
-                    (0, 1, 2))(q, k, v)
-    for a, b in zip(got, want):
-        assert float(jnp.abs(a - b).max()) <= 1e-5
+                    (0, 1, 2))(*f32)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype
+        assert float(jnp.abs(a.astype(jnp.float32) - b).max()) <= tol, name
+
+
+@pytest.mark.parametrize("window, kv_heads", [(48, 2), (None, 2), (48, 4)])
+def test_window_and_grouped_gradient_is_two_kernels(window, kv_heads):
+    """Under a gradient a window / grouped-head call is the forward
+    kernel (writing the row log-sum-exp) and ONE backward kernel: no
+    scan of dense blocks, no third kernel."""
+    q = jax.ShapeDtypeStruct((2, 128, 4, 16), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 128, kv_heads, 16), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: flash_attention(q, k, v, True, None, 32, 64,
+                                        window).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, kv, kv))
+    assert text.count("pallas_call[") == 2
+    assert "name=flash_fwd" in text and "name=flash_bwd_fused" in text
+    assert "scan[" not in text and "dynamic_update_slice" not in text
 
 
 # sha256 of the jaxpr of value_and_grad(flash_attention) at GPT-tiny's

@@ -125,6 +125,39 @@ def test_flash_attention_grad(case, monkeypatch):
                                    atol=tol, rtol=tol, err_msg=name)
 
 
+@pytest.mark.parametrize("window, block", [
+    (50, (32, 32)),      # no multiple of the tile: row 127's keys start
+                         # at 78, its block's loop at key 32
+    (50, (16, 64)),      # a key block wider than the window
+    (33, (64, 16)),      # several wholly masked leading key blocks
+    (200, (32, 32)),
+    (None, (32, 64)),    # grouped heads alone
+])
+def test_forward_lse_under_a_window_and_grouped_heads(window, block):
+    """What the backward rebuilds p from: the row log-sum-exp the
+    forward saves under a window and grouped heads is the dense one over
+    the scores the mask keeps — also for a row whose first key block in
+    the kernel's loop is wholly masked (m = NEG_INF, l = the block's
+    width there; the first real score rescales both to exactly 0)."""
+    rng = np.random.default_rng(7)
+    b, t, h, h_kv, d = 2, 256, 4, 2, 8
+    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, t, h_kv, d)), jnp.float32)
+            for _ in range(2))
+    out, lse = attention._flash_fwd_impl(
+        q, k, v, causal=True, scale=d ** -0.5, block_q=block[0],
+        block_k=block[1], interpret=True, window=window, save_lse=True)
+    kr = jnp.repeat(k, h // h_kv, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr) * d ** -0.5
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = (ahead >= 0) & (ahead < (window or t))
+    want = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+    assert lse.shape == (b, h, t) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=1e-5,
+                               rtol=1e-5)
+    assert out.shape == q.shape
+
+
 # (T, head size, dtype) -> the tile `_fwd_tiles` answers: the shapes the
 # six cells and this tree's tests run, and T that 512 does not divide
 FWD_TILE_TABLE = {
@@ -164,6 +197,32 @@ def test_fwd_tiles_table(t, d, dtype):
     assert attention.fwd_tiles(t, d, dtype, 256, 512) == (256, 512)
     assert attention.fwd_tiles(t, d, dtype, None, 64) == (want[0], 64)
     assert attention.fwd_tiles(t, d, dtype, 64) == (64, want[1])
+
+
+# (T, head size, dtype) -> the tile `_bwd_tiles` answers: every cell's
+# shape (the window is no input of the rule), and T that 512 does not
+# divide, where the tile halves until it does
+BWD_TILE_TABLE = {
+    (1024, 64, jnp.bfloat16): (512, 512),     # the three GPT-2 cells
+    (8192, 128, jnp.bfloat16): (512, 512),    # smallthinker_ep4_seq8k
+    (4096, 64, jnp.bfloat16): (512, 512),     # lfm2_ep4_seq4k
+    (8192, 128, jnp.float32): (512, 512),
+    (768, 8, jnp.float32): (256, 256),
+    (1536, 64, jnp.bfloat16): (512, 512),
+    (1280, 64, jnp.bfloat16): (256, 256),
+    (640, 64, jnp.bfloat16): (128, 128),
+    (128, 16, jnp.bfloat16): (128, 128),      # below a tile: all of T
+    (64, 16, jnp.float32): (64, 64),
+    (40, 16, jnp.float32): (40, 40),          # never the kernel's: T % 8
+}
+
+
+@pytest.mark.parametrize("t, d, dtype", BWD_TILE_TABLE, ids=lambda x: str(
+    getattr(x, "__name__", x)))
+def test_bwd_tiles_table(t, d, dtype):
+    block_q, block_k = attention._bwd_tiles(t, d, dtype)
+    assert (block_q, block_k) == BWD_TILE_TABLE[t, d, dtype]
+    assert t % block_q == 0 and t % block_k == 0
 
 
 @pytest.mark.parametrize("d", [8, 12, 64, 128])
