@@ -1,0 +1,27 @@
+"""Kernels: how near the ``flash_fwd`` operations of the latent mixer
+run to the chip's roofline — max(FLOPs over the bf16 peak, bytes over
+the HBM peak of ``peaks.json``) over their traced time, in percent.
+FLOPs are the score and value products INSIDE the causal mask, 2 x (192
++ 128) a score (a kernel that visits masked blocks reads low), bytes q,
+k, v, o and the row log-sum-exp once a call, both from ``families/
+joyai.py::latent_attention_flops_bytes`` for the steps the traced call
+really ran (``steps`` on its ``train.dispatch`` span). A program without
+the kernel or the span gives None."""
+
+from benchmark.layer_metrics import expert_matmul_roofline_share as roofline
+from benchmark.layer_metrics import expert_matmul_time_share as time_share
+from benchmark.this_cell import this_cell, traced_call_attrs
+
+def share(host, trace, kernel: str, which: str):
+    own = time_share.seconds(trace, kernel)
+    cell = this_cell()
+    steps = (traced_call_attrs("train.dispatch") or {}).get("steps")
+    if own is None or cell is None or not steps:
+        return None
+    flops, nbytes = cell["family"].latent_attention_flops_bytes(
+        cell["model"], cell["workload"], steps)[which]
+    return roofline.roofline_share(host, flops, nbytes, own)
+
+
+def read(host, trace):
+    return share(host, trace, "flash_fwd", "fwd")

@@ -18,17 +18,44 @@ so far:
   depthwise causal convolution of `conv_taps` taps, * C), an output
   projection. Its state is `conv_taps - 1` rows a sequence, not keys
   and values.
+- mixer `"latent"`: causal attention over low-rank latents (MLA, as
+  trained: nothing is absorbed). The query is two products with an
+  RMSNorm of `q_lora_rank` between them; ONE product gives the
+  compressed keys-and-values (`kv_lora_rank`, normed) and a rotary key
+  of `qk_rope_dim` that every head shares; the latent's up-projection
+  gives each head a key part without positions (`qk_nope_dim`) and a
+  value (`v_head_dim`). A head's query and key are `[nope | rope]`,
+  `qk_nope_dim + qk_rope_dim` wide (192), its value and output
+  `v_head_dim` (128): `ops.flash_attention` with two widths, scale
+  1/sqrt(192). The rotary turn is on the rope part only, always, with
+  INTERLEAVED pairing (dimensions 2i and 2i + 1 turn together: the
+  kind's own, as rotate-half is the other kinds'); the shared key is
+  turned once and broadcast over the heads.
 - MLP `"experts"`: top-k routed gated experts without dropped tokens
   over a HELD share of the experts (`parallel/moe.py::dropless_moe`).
   The router reads the mixer's input or the MLP's (`cfg.router_input`);
   its rule is `cfg.routing`: the softmax over the chosen logits, or
   sigmoid scores with a selection bias, which is model state that the
   step moves after the loss (`stateful_loss`) and no gradient reaches.
+  `cfg.routed_scale` multiplies the routing weights; `cfg.d_shared` > 0
+  adds a SHARED expert of that width, one gated MLP every token takes,
+  beside the routed sum and outside the grouped matmul's rows.
 - MLP `"dense"`: one gated MLP of width `d_dense`.
 
 `cfg.activation` gates both MLP kinds; the head is the embedding's
 transpose (`cfg.tied_head`) or a matrix of its own. Norms are
 `ops.rmsnorm` (weight only).
+
+`cfg.mtp` = 1 adds a multi-token-prediction block after the last layer
+(`params["mtp"]`): position i's last hidden state (before the final
+norm) and the embedding of token i + 1, each normed, concatenated in
+that order and projected 2 D -> D, go through one more block of the
+last layer's kinds (weights, router and selection bias of its own) and
+a final norm of its own to the SAME head; it predicts token i + 2, and
+the loss is `CE_main + cfg.mtp_weight * CE_mtp`, each a mean over its
+own valid positions. The embedding and the head get both uses'
+gradients. The block's routing counts and its bias row come last among
+the MoE layers'.
 
 Block parameters are stacked PER LEAF over the layers that have the
 leaf, in layer order: a conv layer has no `wq`, a dense layer no
@@ -61,16 +88,16 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
-                                  balance_bias, dropless_moe)
+                                  balance_bias, dropless_moe, static_rows)
 
 ATTENTION_KINDS = ("full", "window")
-MIXER_KINDS = ATTENTION_KINDS + ("conv",)
+MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent")
 MLP_KINDS = ("experts", "dense")
 ROUTER_INPUTS = ("mixer", "mlp")
 
 # which layers hold a leaf: those whose mixer or MLP is of its group
 _GROUP = {"full": "attention", "window": "attention", "conv": "conv",
-          "experts": "experts", "dense": "dense"}
+          "latent": "latent", "experts": "experts", "dense": "dense"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,6 +135,15 @@ class DecoderConfig:
     d_dense: int = 0
     conv_taps: int = 3
     tied_head: bool = False
+    q_lora_rank: int = 0              # the latent mixer's ranks and widths
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0              # a head's key part without positions
+    qk_rope_dim: int = 0              # the rotary part; ONE key for all heads
+    v_head_dim: int = 0
+    d_shared: int = 0                 # a shared expert beside the routed
+    routed_scale: float = 1.0         # a factor on the routing weights
+    mtp: int = 0                      # multi-token-prediction blocks: 0 or 1
+    mtp_weight: float = 0.3           # lambda on the second loss term
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -123,8 +159,20 @@ class DecoderConfig:
                 and set(self.rotary + self.qk_norm) <= set(ATTENTION_KINDS)):
             raise ValueError(
                 f"layer kinds built so far: mixer {MIXER_KINDS} (rotary "
-                f"and qk_norm list attention kinds: {ATTENTION_KINDS}), "
+                f"and qk_norm list attention kinds: {ATTENTION_KINDS}; "
+                f"the latent mixer turns its rope part itself), "
                 f"mlp {MLP_KINDS}")
+        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                  self.qk_rope_dim, self.v_head_dim)
+        if "latent" in mixers and (min(latent) < 1 or self.qk_rope_dim % 2):
+            raise ValueError(
+                "the latent mixer needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_dim, qk_rope_dim (even) and v_head_dim: got "
+                f"{latent}")
+        if self.mtp not in (0, 1) or self.d_shared < 0:
+            raise ValueError(
+                f"mtp is 0 or 1 block (got {self.mtp}), d_shared the "
+                f"shared expert's width or 0 (got {self.d_shared})")
         if self.router_input not in ROUTER_INPUTS \
                 or self.routing not in ROUTING \
                 or self.activation not in ACTIVATIONS:
@@ -142,6 +190,13 @@ class DecoderConfig:
         lead = tuple(zip(self.lead_attention, self.lead_mlp))
         period = tuple(zip(self.attention, self.mlp))
         return lead + period * ((self.n_layers - len(lead)) // len(period))
+
+    @property
+    def moe_layers(self) -> int:
+        """The layers that route, the MTP block's last: the rows of the
+        selection bias and of the stacked counts."""
+        kinds = self.kinds
+        return sum(m == "experts" for _, m in kinds + kinds[-1:] * self.mtp)
 
 
 # Tiny configuration for tests and rehearsals: the period of four, 7-to-1
@@ -171,6 +226,19 @@ def _leaves(cfg: DecoderConfig) -> dict:
         if cfg.qk_norm:
             table.update(q_norm=("attention", (hd,), "one"),
                          k_norm=("attention", (hd,), "one"))
+    if "latent" in groups:
+        h, rope = cfg.n_heads, cfg.qk_rope_dim
+        table.update(
+            wq_a=("latent", (d, cfg.q_lora_rank), "normal"),
+            q_a_norm=("latent", (cfg.q_lora_rank,), "one"),
+            wq_b=("latent", (cfg.q_lora_rank, h * (cfg.qk_nope_dim + rope)),
+                  "normal"),
+            wkv_a=("latent", (d, cfg.kv_lora_rank + rope), "normal"),
+            kv_a_norm=("latent", (cfg.kv_lora_rank,), "one"),
+            wkv_b=("latent", (cfg.kv_lora_rank,
+                              h * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                   "normal"),
+            wo_latent=("latent", (h * cfg.v_head_dim, d), "normal"))
     if "conv" in groups:
         table.update(conv_in=("conv", (d, 3 * d), "normal"),
                      conv_taps=("conv", (cfg.conv_taps, d), "taps"),
@@ -180,6 +248,11 @@ def _leaves(cfg: DecoderConfig) -> dict:
                      w_gate=("experts", (count, d, f), "normal"),
                      w_up=("experts", (count, d, f), "normal"),
                      w_down=("experts", (count, f, d), "normal"))
+        if cfg.d_shared:
+            table.update(
+                ws_gate=("experts", (d, cfg.d_shared), "normal"),
+                ws_up=("experts", (d, cfg.d_shared), "normal"),
+                ws_down=("experts", (cfg.d_shared, d), "normal"))
     if "dense" in groups:
         table.update(w1=("dense", (d, cfg.d_dense), "normal"),
                      w3=("dense", (d, cfg.d_dense), "normal"),
@@ -198,11 +271,25 @@ def _layers_with(cfg: DecoderConfig, group: str, kinds=None) -> int:
 # The key a leaf is drawn from: one of `split(key, 12)`, the first ten
 # in the order the first configuration drew them (its seeded weights
 # are what its recorded losses were taken on), the later kinds' from
-# splits of the eleventh, the selection bias from the twelfth.
+# splits of the eleventh, the selection bias from the twelfth. The
+# kinds after those fold their place in `_NEWER` into the eleventh (a
+# longer `_LATER` would move the second configuration's weights), the
+# MTP block's leaves theirs into `fold_in(eleventh, _MTP_KEY)`.
 _KEY_OF = {name: i for i, name in enumerate((
     "embed", "wq", "wk", "wv", "wo", "router", "w_gate", "w_up", "w_down",
     "head"))}
 _LATER = ("conv_in", "conv_taps", "conv_out", "w1", "w3", "w2")
+_NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
+          "ws_down", "proj")
+_MTP_KEY = 1 << 16
+
+
+def _mtp_leaves(cfg: DecoderConfig) -> dict:
+    """The MTP block's own leaves: those of one layer of the last
+    layer's kinds."""
+    mine = ("layer", *(_GROUP[k] for k in cfg.kinds[-1]))
+    return {name: spec for name, spec in _leaves(cfg).items()
+            if spec[0] in mine}
 
 
 def init(key, cfg: DecoderConfig):
@@ -213,10 +300,17 @@ def init(key, cfg: DecoderConfig):
     keys = list(jax.random.split(key, 12))
     later = dict(zip(_LATER, jax.random.split(keys[10], len(_LATER))))
 
-    def draw(name, shape, how):
+    def draw(name, shape, how, mtp=False):
         if how == "one":
             return jnp.ones(shape)
-        k = keys[_KEY_OF[name]] if name in _KEY_OF else later[name]
+        if mtp:   # by the leaf's place among all the names there are
+            k = jax.random.fold_in(
+                jax.random.fold_in(keys[10], _MTP_KEY),
+                sorted(_leaves(cfg) | {"proj": ()}).index(name))
+        elif name in _NEWER:
+            k = jax.random.fold_in(keys[10], _NEWER.index(name))
+        else:
+            k = keys[_KEY_OF[name]] if name in _KEY_OF else later[name]
         if how == "taps":
             bound = cfg.conv_taps ** -0.5
             return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
@@ -232,13 +326,22 @@ def init(key, cfg: DecoderConfig):
     if not cfg.tied_head:
         params["head"] = draw("head", (cfg.d_model, cfg.vocab_size),
                               "normal")
+    if cfg.mtp:
+        d = cfg.d_model
+        params["mtp"] = {
+            "proj": draw("proj", (2 * d, d), "normal", mtp=True),
+            "norm_h": jnp.ones((d,)), "norm_e": jnp.ones((d,)),
+            "norm_f": jnp.ones((d,)),
+            "layer": {name: draw(name, shape, how, mtp=True)
+                      for name, (_, shape, how) in _mtp_leaves(cfg).items()}}
     return params
 
 
-def rope_tables(t: int, cfg: DecoderConfig):
-    """cos, sin [T, head_dim / 2] of position * theta ** (-2i / head_dim),
-    float32."""
-    half = cfg.head_dim // 2
+def rope_tables(t: int, cfg: DecoderConfig, dim: int | None = None):
+    """cos, sin [T, dim / 2] of position * theta ** (-2i / dim), float32;
+    `dim` the turned width: a head's (the default) or the latent mixer's
+    rope part."""
+    half = (cfg.head_dim if dim is None else dim) // 2
     inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
     return jnp.cos(angle), jnp.sin(angle)
@@ -263,6 +366,51 @@ def _head_norm(x, weight, eps: float):
     return (xf * inv * weight).astype(x.dtype)
 
 
+def _deinterleave(w, lead: int, dim: int):
+    """The columns of a projection whose every `lead + dim` outputs end
+    in a rope part of `dim`, that part reordered (0, 2, 4, .. | 1, 3,
+    5, ..): rotate-half on the product then turns the pairs (2i, 2i + 1)
+    of the original order, and a score, a sum over a query's and a
+    key's rope parts alike, does not see the reordering. w: [in, heads *
+    (lead + dim)]; the reordering is of the weight, once a pass, not of
+    the tokens."""
+    w3 = w.reshape(w.shape[0], -1, lead + dim)
+    turned = w3[:, :, lead:].reshape(*w3.shape[:2], dim // 2, 2)
+    return jnp.concatenate(
+        [w3[:, :, :lead], turned.swapaxes(2, 3).reshape(*w3.shape[:2], dim)],
+        axis=-1).reshape(w.shape)
+
+
+def _latent_attention(x, p, rope, cfg: DecoderConfig):
+    """The latent mixer on the first norm's output x [B, T, D] -> the
+    mixer's part of the residual [B, T, D]."""
+    b, t, _ = x.shape
+    h, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    r_kv, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    cast = functools.partial(jnp.asarray, dtype=x.dtype)
+    wq_b = _deinterleave(cast(p["wq_b"]), nope, rot)
+    wkv_a = _deinterleave(cast(p["wkv_a"]), r_kv, rot)
+    c_q = rmsnorm(x @ cast(p["wq_a"]), cast(p["q_a_norm"]), cfg.rms_eps)
+    q = (c_q @ wq_b).reshape(b, t, h, nope + rot)
+    kv_a = x @ wkv_a                       # [c_kv | the shared rotary key]
+    c_kv = rmsnorm(kv_a[..., :r_kv], cast(p["kv_a_norm"]), cfg.rms_eps)
+    # the up-projection's columns are a head's [k_nope | v]: two
+    # products, so that neither part is cut out of a 256-wide array
+    wkv_b = cast(p["wkv_b"]).reshape(r_kv, h, nope + dv)
+    k_nope = (c_kv @ wkv_b[:, :, :nope].reshape(r_kv, h * nope)).reshape(
+        b, t, h, nope)
+    v = (c_kv @ wkv_b[:, :, nope:].reshape(r_kv, h * dv)).reshape(
+        b, t, h, dv)
+    k_rope = _rope(kv_a[..., r_kv:].reshape(b, t, 1, rot), *rope)
+    q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], *rope)], -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, (b, t, h, rot))], -1)
+    # 192-wide q and k, 128-wide v and o; scale 1 / sqrt(192)
+    a = flash_attention(q, k, v, True, None, cfg.attn_block_q,
+                        cfg.attn_block_k, None)
+    return a.reshape(b, t, h * dv) @ cast(p["wo_latent"])
+
+
 def _router(x, p):
     with jax.named_scope("router"):
         # in float32, whichever norm's output it reads
@@ -284,6 +432,9 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
         with jax.named_scope("mixer_conv"):
             y = short_conv(x @ cast(p["conv_in"]), p["conv_taps"])
             h = h + y @ cast(p["conv_out"])
+    elif attention == "latent":
+        with jax.named_scope("attention_latent"):
+            h = h + _latent_attention(x, p, rope, cfg)
     else:
         with jax.named_scope("attention_" + attention):
             q = (x @ cast(p["wq"])).reshape(b, t, cfg.n_heads, hd)
@@ -309,28 +460,43 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
         y.reshape(b * t, d), logits, cast(p["w_gate"]), cast(p["w_up"]),
         cast(p["w_down"]), top_k=cfg.top_k, held=cfg.held,
         tile=cfg.gmm_tile, activation=cfg.activation,
-        bias=p.get("expert_bias"))
-    return h + m.reshape(b, t, d), counts
+        bias=p.get("expert_bias"), scale=cfg.routed_scale)
+    h = h + m.reshape(b, t, d)
+    if cfg.d_shared:
+        with jax.named_scope("mlp_shared"):
+            # what every chip of the deployment computes alike: every
+            # token, one plain gated MLP, no row of the grouped matmul
+            act = ACTIVATIONS[cfg.activation](y @ cast(p["ws_gate"]))
+            h = h + (act * (y @ cast(p["ws_up"]))) @ cast(p["ws_down"])
+    return h, counts
+
+
+def _rope_for(t: int, cfg: DecoderConfig):
+    """The one rotary table a configuration's mixers turn by: the
+    latent mixer's rope part, or a head."""
+    latent = "latent" in cfg.attention + cfg.lead_attention
+    return rope_tables(t, cfg, cfg.qk_rope_dim if latent else None)
+
+
+def _block(cfg: DecoderConfig, attention: str, mlp: str):
+    fn = functools.partial(_layer, cfg=cfg, attention=attention, mlp=mlp)
+    return jax.checkpoint(fn) if cfg.remat else fn
 
 
 def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     """tokens [B, T] -> (the last block's output [B, T, D], before the
     final norm; counts stacked over the MoE layers [layers, ...]).
     `bias`: the selection bias [MoE layers, n_experts], where the
-    routing has one."""
+    routing has one (the MTP block's row, the last, is not read here)."""
     kinds, lead, period = cfg.kinds, len(cfg.lead_attention), \
         len(cfg.attention)
     h = params["embed"][tokens].astype(cfg.dtype)
-    rope = rope_tables(tokens.shape[1], cfg)
+    rope = _rope_for(tokens.shape[1], cfg)
     group_of = {name: group for name, (group, _, _) in _leaves(cfg).items()}
     layers = params["layers"]
     if bias is not None:
-        layers = dict(layers, expert_bias=bias)
+        layers = dict(layers, expert_bias=bias[:-1] if cfg.mtp else bias)
         group_of["expert_bias"] = "experts"
-
-    def block(attention, mlp):
-        fn = functools.partial(_layer, cfg=cfg, attention=attention, mlp=mlp)
-        return jax.checkpoint(fn) if cfg.remat else fn
 
     def rows(stacks, at: int, before):
         """Layer `at`'s row of each leaf it has: its index in a leaf's
@@ -341,9 +507,9 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
 
     counts = []
     for at in range(lead):          # the leading layers, one by one
-        h, c = block(*kinds[at])(h, rows(layers, at, kinds[:at]), rope)
+        h, c = _block(cfg, *kinds[at])(h, rows(layers, at, kinds[:at]), rope)
         counts += [] if c is None else [jax.tree.map(lambda x: x[None], c)]
-    blocks = [block(*pair) for pair in kinds[lead:lead + period]]
+    blocks = [_block(cfg, *pair) for pair in kinds[lead:lead + period]]
 
     def one_period(h, p):
         counts = []
@@ -383,17 +549,74 @@ def apply(params, tokens, cfg: DecoderConfig, bias=None):
                    preferred_element_type=jnp.float32)
 
 
+def mtp_hidden(params, h, tokens, cfg: DecoderConfig, bias=None):
+    """The multi-token-prediction block. h: the last main block's output
+    [B, T, D] (before the final norm) -> (the block's output after ITS
+    final norm [B, T, D], whose row i predicts token i + 2; the block's
+    routing counts). Position i joins h_i with the embedding of token
+    i + 1; the last position, which has none, takes id 0 and is never
+    scored (its routing is counted, as every position's is). `bias`:
+    the whole selection bias; the block's row is the last."""
+    p, cast = params["mtp"], functools.partial(jnp.asarray, dtype=h.dtype)
+    with jax.named_scope("mtp"):
+        following = jnp.concatenate(
+            [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
+
+        def join(h, following, embed, p):
+            e = embed[following].astype(h.dtype)
+            both = jnp.concatenate(
+                [rmsnorm(h, cast(p["norm_h"]), cfg.rms_eps),
+                 rmsnorm(e, cast(p["norm_e"]), cfg.rms_eps)], axis=-1)
+            return both @ cast(p["proj"])
+
+        x = (jax.checkpoint(join) if cfg.remat else join)(
+            h, following, params["embed"], p)
+        layer = p["layer"] if bias is None else dict(
+            p["layer"], expert_bias=bias[-1])
+        x, counts = _block(cfg, *cfg.kinds[-1])(
+            x, layer, _rope_for(tokens.shape[1], cfg))
+        return rmsnorm(x, cast(p["norm_f"]), cfg.rms_eps), counts
+
+
+def mtp_apply(params, tokens, cfg: DecoderConfig, bias=None):
+    """tokens [B, T] -> the MTP head's float32 logits [B, T, vocab]; row
+    i predicts token i + 2 (tests and small sizes)."""
+    h, _ = hidden(params, tokens, cfg, bias)
+    x, _ = mtp_hidden(params, h, tokens, cfg, bias)
+    return jnp.dot(x, _head(params, cfg, x.dtype),
+                   preferred_element_type=jnp.float32)
+
+
 def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
     """Mean next-token cross-entropy over the B * (T - 1) positions that
     have a target -> (loss, counts). The mixers run at full T; the last
-    position's logits are never formed."""
-    b, t = tokens.shape
+    position's logits are never formed. With an MTP block the loss is
+    that mean plus `cfg.mtp_weight` times the block's mean over ITS
+    B * (T - 2) positions (token i + 2 from position i), `counts` gains
+    the block's row last and the two terms as `loss_main`, `loss_mtp`."""
     h, counts = hidden(params, tokens, cfg, bias)
     x = rmsnorm(h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
+    if not cfg.mtp:
+        return _mean_nll(x, tokens, 1, params, cfg)[0], counts
+    x_mtp, c = mtp_hidden(params, h, tokens, cfg, bias)
+    counts = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),
+                          counts, c)
+    main, head = _mean_nll(x, tokens, 1, params, cfg)
+    second, _ = _mean_nll(x_mtp, tokens, 2, params, cfg, head)
+    return main + cfg.mtp_weight * second, {
+        **counts, "loss_main": main, "loss_mtp": second}
+
+
+def _mean_nll(x, tokens, ahead: int, params, cfg: DecoderConfig, head=None):
+    """Row i of x [B, T, D] (normed) against token i + `ahead` -> (the
+    mean cross-entropy over the B * (T - ahead) positions that have a
+    target, the head in x's dtype: cast once, handed to a second call)."""
+    n = tokens.shape[0] * (tokens.shape[1] - ahead)
     with jax.named_scope("logits_loss"):
-        x = x[:, :-1].reshape(b * (t - 1), -1)
-        targets = tokens[:, 1:].reshape(b * (t - 1))
-        head = _head(params, cfg, x.dtype)
+        x = x[:, :-ahead].reshape(n, -1)
+        targets = tokens[:, ahead:].reshape(n)
+        if head is None:
+            head = _head(params, cfg, x.dtype)
         chunk = min(cfg.loss_chunk, x.shape[0])
         pad = -x.shape[0] % chunk
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -412,7 +635,7 @@ def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
 
         total, _ = lax.scan(body, jnp.zeros((), jnp.float32), tuple(
             z.reshape(-1, chunk, *z.shape[1:]) for z in (x, targets, weight)))
-        return total / (b * (t - 1)), counts
+        return total / n, head
 
 
 # ----------------------------------------------------------------------
@@ -436,14 +659,25 @@ def counters_init(cfg: DecoderConfig):
     expert the bias brought among the chosen) and `moe_bias_abs_max`
     (the largest bias after the step's move). The sums are float32
     (exact to 2**24, then to seven digits): int32 would wrap in an epoch
-    of 2**31 / (tokens x top_k x layers) steps."""
+    of 2**31 / (tokens x top_k x layers) steps. The MoE layers include
+    the MTP block, and a configuration that has one also counts
+    `loss_main` and `loss_mtp` (the last step's two terms, the second
+    before its weight), `moe_rows_static` (the rows the grouped matmul's
+    arrays hold, the worst case: `parallel/moe.py::static_rows` x MoE
+    layers x steps) and `moe_rows_filled` (those that held an
+    assignment). The configurations from before the block keep the state
+    tree their recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
     i32 = functools.partial(jnp.zeros, (), jnp.int32)
-    return {"epoch_counters": {
+    counters = {
         "moe_assignments": f32(), "moe_assignments_held": f32(),
         "moe_assignments_dropped": f32(), "moe_expert_tokens_max": i32(),
         "moe_expert_tokens_mean": f32(), "moe_experts_held": i32(),
-        "moe_experts_total": i32(), "moe_steps": i32()}}
+        "moe_experts_total": i32(), "moe_steps": i32()}
+    if cfg.mtp:
+        counters.update(loss_main=f32(), loss_mtp=f32(),
+                        moe_rows_static=f32(), moe_rows_filled=f32())
+    return {"epoch_counters": counters}
 
 
 def state_init(key, cfg: DecoderConfig):
@@ -460,7 +694,7 @@ def state_init(key, cfg: DecoderConfig):
         moe_assignments_bias_moved=f32(), moe_bias_abs_max=f32())
     state["expert_bias"] = cfg.init_std * jax.random.normal(
         jax.random.split(key, 12)[11],
-        (_layers_with(cfg, "experts"), cfg.n_experts), jnp.float32)
+        (cfg.moe_layers, cfg.n_experts), jnp.float32)
     return state
 
 
@@ -489,6 +723,14 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
         names.append(("moe_assignments_bias_moved", "bias_moved"))
     for name, key in names:
         new[name] = old[name] + counts[key].sum().astype(jnp.float32)
+    if cfg.mtp:
+        new.update(
+            loss_main=counts["loss_main"], loss_mtp=counts["loss_mtp"],
+            moe_rows_static=old["moe_rows_static"] + float(
+                cfg.moe_layers * static_rows(
+                    tokens.size * cfg.top_k, cfg.held[1], cfg.gmm_tile)),
+            moe_rows_filled=old["moe_rows_filled"] + (
+                counts["held"] - counts["dropped"]).sum().astype(jnp.float32))
     state = {**state, "epoch_counters": new}
     if bias is not None:
         bias = balance_bias(bias, counts["routed"], cfg.bias_rate)

@@ -41,6 +41,18 @@ head are summed in float32 VMEM scratch of a whole sequence and written
 once). With `window=None` and equal head counts every such branch folds
 away: the traced program, forward and backward, is the one this file
 built before either existed.
+
+A third, static in the shapes: q and k of one width and v of another
+(`[B, T, H, D_qk]`, `[B, T, H, D_v]` -> `[B, T, H, D_v]`; latent
+attention as trained has 192-wide queries and keys, a 128-wide part of
+which carries no position, and 128-wide values). The score products
+contract D_qk, the value product and o are D_v wide; dq (accumulated
+transposed, `[D_qk, block_q]` slabs) and dk are D_qk wide, dv D_v; no
+v, o, do or dv padded to D_qk is ever written to HBM (192 is no multiple
+of the 128 lanes: a 192-wide block sits on 256 lanes in VMEM, which is
+why the forward asks for its own VMEM limit there). The kernels keep
+their names, `flash_fwd` and `flash_bwd_fused`. With D_qk == D_v every
+such branch folds away too.
 """
 
 from __future__ import annotations
@@ -63,6 +75,8 @@ NEG_INF = -1e30
 # (twice: double-buffered), dq.T in float32, the float32 tiles and,
 # under grouped heads, dk and dv of a whole sequence in float32.
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
+# ... and the forward under two widths: k and v of a whole sequence
+_FWD_WIDE_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
@@ -70,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
     qi = pl.program_id(1)
     q = q_ref[...]  # [block_q, d]
     t = k_ref.shape[0]
-    d = q.shape[-1]
+    d = v_ref.shape[-1]   # o's width is v's; the scores contract q's
     block_q = q.shape[0]
 
     def body(ki, carry):
@@ -130,14 +144,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         lse_ref[0][...] = (m + jnp.log(denom)).reshape(1, block_q)
 
 
-def _flash_aligned(t: int, d: int, block_q: int, block_k: int) -> bool:
+def _flash_aligned(t: int, d: int, block_q: int, block_k: int,
+                   d_v: int | None = None) -> bool:
     """Mosaic constraints: K/V dynamic-slice starts must be provably
     8-aligned (sublane) and the lane dim 128-padded; unaligned shapes go
     through the dense path (short sequences — dense is fine there)."""
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     return (t % block_q == 0 and t % block_k == 0
-            and block_q % 8 == 0 and block_k % 8 == 0 and d % 8 == 0)
+            and block_q % 8 == 0 and block_k % 8 == 0 and d % 8 == 0
+            and (d_v or d) % 8 == 0)
 
 
 def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
@@ -147,14 +163,19 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
     gradient): returns (out, lse), lse [B, H, T] float32 — None where
     the dense fallback ran."""
     b, t, h, d = q.shape
-    block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k)
+    d_v = v.shape[-1]
+    if k.shape[-1] != d or v.shape[:3] != k.shape[:3]:
+        raise ValueError(
+            f"flash_attention: q {q.shape} and k {k.shape} share the score "
+            f"width, k and v {v.shape} batch, length and heads")
+    block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k, d_v)
     plain = window is None and k.shape[2] == h
     if not plain and (not causal or h % k.shape[2]):
         raise ValueError(
             "flash_attention: a window and grouped heads need causal=True, "
             f"and the {h} query heads a multiple of the {k.shape[2]} "
             "key/value heads")
-    if not _flash_aligned(t, d, block_q, block_k):
+    if not _flash_aligned(t, d, block_q, block_k, d_v):
         if t >= 512:
             import warnings
 
@@ -194,7 +215,7 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
                 block_k: int, interpret: bool, window: int | None = None,
                 save_lse: bool = False):
     b, t, h, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, d_v = k.shape[2], v.shape[3]
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
 
     kernel = functools.partial(_flash_kernel, block_k=block_k,
@@ -211,8 +232,13 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
 
         def kv_index(bh, qi):
             return ((bh // h) * h_kv + (bh % h) // group, 0, 0)
-    out_specs = pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))
-    out_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
+    out_specs = pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0))
+    out_shape = jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype)
+    # two widths (keys wider than values): a whole sequence of 192-wide
+    # keys sits in VMEM on 256 lanes, more than the default scope holds
+    # at 8k tokens; with one width the call is the one it always was
+    wide = {} if d_v == d else {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_FWD_WIDE_VMEM_LIMIT)}
     if save_lse:
         # one [1, block_q] row of float32 a grid step
         out_specs = [out_specs, pl.BlockSpec(
@@ -225,12 +251,13 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((None, t, d), kv_index),
-            pl.BlockSpec((None, t, d), kv_index),
+            pl.BlockSpec((None, t, d_v), kv_index),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
+        **wide,
     )(qf, kf, vf)
     if save_lse:
         out, lse = out
@@ -269,7 +296,7 @@ def _dense_grouped(q, k, v, scale, window):
     scores = jnp.where(keep[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
 
 
 def _dense_fallback(q, k, v, causal, scale, window):
@@ -291,11 +318,13 @@ def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
                     window: int | None = None):
-    """q: [B, T, H, D]; k, v: [B, T, H_kv, D] with H a multiple of H_kv
-    (query head g reads key/value head g // (H // H_kv)). `window`:
-    query i sees keys j with 0 <= i - j < window (needs causal).
-    `block_q`, `block_k`: the forward kernel's tile; None asks
-    `fwd_tiles`, a number is taken as given. Returns [B, T, H, D]."""
+    """q: [B, T, H, D_qk]; k: [B, T, H_kv, D_qk]; v: [B, T, H_kv, D_v]
+    with H a multiple of H_kv (query head g reads key/value head
+    g // (H // H_kv)); D_v may differ from D_qk. `scale`: None is
+    D_qk ** -0.5. `window`: query i sees keys j with 0 <= i - j < window
+    (needs causal). `block_q`, `block_k`: the forward kernel's tile; None
+    asks `fwd_tiles`, a number is taken as given. Returns
+    [B, T, H, D_v]."""
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                            block_q=block_q, block_k=block_k,
@@ -354,7 +383,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         @pl.when(pl.program_id(1) == 0)
         def _():
             dk_acc[keys] = jnp.zeros((block_k, k.shape[1]), jnp.float32)
-            dv_acc[keys] = jnp.zeros((block_k, k.shape[1]), jnp.float32)
+            dv_acc[keys] = jnp.zeros((block_k, v.shape[1]), jnp.float32)
     else:
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -416,7 +445,8 @@ def _fit(t: int, target: int) -> int:
     return block
 
 
-def _bwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
+def _bwd_tiles(t: int, d: int, dtype, d_v: int | None = None
+               ) -> tuple[int, int]:
     """(block_q, block_k) of `flash_bwd_fused`, from what the call
     observes: 512 x 512, halved until it divides t (all of t below
     that). Read on the chip, a layer's whole backward (the kernel, delta
@@ -440,14 +470,15 @@ def _bwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
     256 x 512 3 % and 256 x 256 12 % behind, 1024 on either side 11-15 %
     behind. From 512 rows up the tiles lie within 3 % of each other at
     every shape read, the window moves nothing, and 1024 x 1024's 2 % at
-    8k without a window is a loss at T 1024: neither d, dtype nor the
-    window moves the rule yet (it compiles for float32 and for heads of
-    128 and 256)."""
+    8k without a window is a loss at T 1024: neither d, dtype, the
+    window nor a value width `d_v` other than d moves the rule yet (it
+    compiles for float32, for heads of 128 and 256 and for 192 | 128)."""
     block = _fit(t, 512)
     return block, block
 
 
-def _fwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
+def _fwd_tiles(t: int, d: int, dtype, d_v: int | None = None
+               ) -> tuple[int, int]:
     """(block_q, block_k) of `flash_fwd`, from what the call observes:
     the first of 512, 768, 256, 128 rows that divides t (all of t below
     that), square. Read on the chip (PR 34, PERF.md section 6), the
@@ -478,7 +509,8 @@ def _fwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
 
     A t that 128 does not divide (nor t itself, below 128) never
     reached the kernel: it answers 128 x 128, which `_flash_aligned`
-    refuses as it always has, and the call takes the dense path."""
+    refuses as it always has, and the call takes the dense path. `d_v`,
+    the value width where it differs from d, does not move the rule."""
     if t % min(128, t):
         return 128, 128
     block = next(b for b in (512, 768, 256, 128) if t % min(b, t) == 0)
@@ -486,13 +518,14 @@ def _fwd_tiles(t: int, d: int, dtype) -> tuple[int, int]:
 
 
 def fwd_tiles(t: int, d: int, dtype, block_q: int | None = None,
-              block_k: int | None = None) -> tuple[int, int]:
+              block_k: int | None = None, d_v: int | None = None
+              ) -> tuple[int, int]:
     """The (block_q, block_k) `flash_attention` hands `flash_fwd` for a
     call of sequence length t, head size d and `dtype`: the caller's
     numbers where it passes them, `_fwd_tiles`' where it passes None.
     Static per compiled shape, so this function is the record of which
     tile a program runs."""
-    rule_q, rule_k = _fwd_tiles(t, d, dtype)
+    rule_q, rule_k = _fwd_tiles(t, d, dtype, d_v)
     return (rule_q if block_q is None else block_q,
             rule_k if block_k is None else block_k)
 
@@ -506,9 +539,9 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
     is (batch x key/value heads, group, key blocks), and no K, V, dk or
     dv of a repeated head ever reaches HBM."""
     b, t, h, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, d_v = k.shape[2], v.shape[3]
     group = h // h_kv
-    block_q, block_k = _bwd_tiles(t, d, q.dtype)
+    block_q, block_k = _bwd_tiles(t, d, q.dtype, d_v)
     # once a traced call, as the forward says its own
     logger.debug("flash_bwd_fused %s | %d %s: tiles %d x %d", q.shape, h_kv,
                  q.dtype, block_q, block_k)
@@ -538,11 +571,19 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
         def at(bkv, gi, ki):
             return bkv * group + gi, bkv, jnp.where(gi == group - 1, ki, 0)
 
-    whole = pl.BlockSpec((None, t, d), lambda *i: (at(*i)[0], 0, 0))
+    def whole(width):   # a query head's whole sequence: q, do
+        return pl.BlockSpec((None, t, width), lambda *i: (at(*i)[0], 0, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((None, block_k, width),
+                            lambda *i: (at(*i)[1], i[-1], 0))
+
+    def dkv_spec(width):
+        return pl.BlockSpec((None, block_k, width),
+                            lambda *i: at(*i)[1:] + (0,))
+
     row_spec = pl.BlockSpec((None, num_q, 1, block_q),
                             lambda *i: (at(*i)[0], 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), lambda *i: (at(*i)[1], i[-1], 0))
-    dkv_spec = pl.BlockSpec((None, block_k, d), lambda *i: at(*i)[1:] + (0,))
     # dk, dv scratch: a key block's, or under grouped heads the whole
     # sequence's, summed over the group
     kv_rows = block_k if group == 1 else t
@@ -550,16 +591,17 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
         functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
                           scale=scale, window=window, group=group),
         grid=grid,
-        in_specs=[whole, whole, row_spec, row_spec, kv_spec, kv_spec],
+        in_specs=[whole(d), whole(d_v), row_spec, row_spec, kv_spec(d),
+                  kv_spec(d_v)],
         out_specs=[pl.BlockSpec((None, num_q, d, block_q),
                                 lambda *i: (at(*i)[0], 0, 0, 0)),
-                   dkv_spec, dkv_spec],
+                   dkv_spec(d), dkv_spec(d_v)],
         out_shape=[jax.ShapeDtypeStruct((b * h, num_q, d, block_q), q.dtype),
                    jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h_kv, t, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((num_q, d, block_q), jnp.float32),
                         pltpu.VMEM((kv_rows, d), jnp.float32),
-                        pltpu.VMEM((kv_rows, d), jnp.float32)],
+                        pltpu.VMEM((kv_rows, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) + ("arbitrary",) * (
                 len(grid) - 1),

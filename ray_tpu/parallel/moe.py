@@ -13,7 +13,9 @@ SURVEY §2.4).
    (`ROUTING`): the softmax over the chosen k logits, and sigmoid
    scores with a selection bias that moves the choice and not the
    weights (`route_sigmoid_bias`; the bias is model state, moved by
-   `balance_bias`); gated ReLU or SiLU experts (`ACTIVATIONS`).
+   `balance_bias`), either times a `scale`; gated ReLU or SiLU experts
+   (`ACTIVATIONS`). A shared expert is not this layer's: the decoder
+   adds it as a plain MLP, outside the grouped matmul's rows.
 """
 
 from __future__ import annotations
@@ -146,7 +148,8 @@ def route_sigmoid_bias(router_logits, bias, top_k: int):
     """router_logits: [N, E], bias: [E] float32 -> (experts [N, k],
     weights [N, k] float32, moved): s = sigmoid(logits) over all E; the
     experts are the k largest of s + bias; their weights are the
-    UNBIASED s of the chosen over (their sum + 1e-6). The bias takes
+    UNBIASED s of the chosen over (their sum + 1e-6) (`dropless_moe`
+    multiplies them by its `scale`). The bias takes
     part in the choice only (arXiv:2408.15664): no gradient reaches it.
     `moved` counts the assignments the bias changed: experts among the k
     largest of s + bias that are not among the k largest of s."""
@@ -174,6 +177,15 @@ ROUTING = ("softmax_topk", "sigmoid_bias")
 ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
+def static_rows(assignments: int, count: int, tile: int = GMM_TILE) -> int:
+    """The rows the grouped matmul's arrays hold for a step of
+    `assignments` = tokens x top_k over `count` held experts: the worst
+    case, every assignment held here, in whole tiles, and a tile more an
+    expert for its padding. With a sixteenth of the experts held about
+    a sixteenth of them is filled."""
+    return -(-assignments // tile) * tile + count * tile
+
+
 def group_by_expert(expert_idx, held: tuple[int, int],
                     tile: int = GMM_TILE) -> Grouping:
     """Lay the assignments that fall on experts [first, first + count)
@@ -195,7 +207,7 @@ def group_by_expert(expert_idx, held: tuple[int, int],
     tiles = jnp.maximum(1, -(-sizes // tile))
     tile_end = jnp.cumsum(tiles)
     row_start = (tile_end - tiles) * tile
-    rows = -(-a // tile) * tile + count * tile
+    rows = static_rows(a, count, tile)
     row_of = jnp.where(is_held,
                        row_start[jnp.clip(local, 0, count - 1)] + rank, rows)
     assign_of_row = jnp.full((rows,), a, jnp.int32).at[row_of].set(
@@ -270,7 +282,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
                  held: tuple[int, int], tile: int = GMM_TILE,
-                 activation: str = "relu", bias=None):
+                 activation: str = "relu", bias=None, scale: float = 1.0):
     """Top-k gated experts over a held share, no token dropped.
 
     y: [N, D] tokens (compute dtype); router_logits: [N, n_experts], the
@@ -286,7 +298,9 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
     `activation`: a key of `ACTIVATIONS`. `bias` None: `route_topk`;
     `bias` [n_experts] float32: `route_sigmoid_bias`, and `counts` also
     holds `routed` [n_experts] (assignments each of ALL experts got,
-    what `balance_bias` reads) and `bias_moved`."""
+    what `balance_bias` reads) and `bias_moved`. `scale`: a factor on
+    the routing weights after their normalisation (a model's
+    `routed_scaling_factor`; at 1 nothing is traced for it)."""
     from ray_tpu.ops.moe_gmm import moe_gmm
 
     extra = {}
@@ -297,6 +311,8 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
         extra = {"routed": (idx[:, :, None] == jnp.arange(
                      router_logits.shape[-1])).sum((0, 1), dtype=jnp.int32),
                  "bias_moved": moved.astype(jnp.int32)}
+    if scale != 1:
+        weights = weights * scale
     g = group_by_expert(idx, held, tile)
     f = w_gate.shape[-1]
     with jax.named_scope("experts"):

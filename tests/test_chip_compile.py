@@ -234,6 +234,98 @@ def test_flash_attention_window_and_grouped_bwd_runs_on_the_chip(case):
     assert max(errs.values()) < 0.01, errs
 
 
+# the latent mixer's call: 192-wide queries and keys, 128-wide values
+LATENT = (1, 8192, 32, 192, 128)
+
+
+def _latent_shapes(one_chip):
+    b, t, h, d_qk, d_v = LATENT
+    qk = jax.ShapeDtypeStruct((b, t, h, d_qk), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, t, h, d_v), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, h, d_v), jnp.float32, sharding=one_chip)
+    return qk, v, w
+
+
+@pytest.mark.parametrize("tiles", [(256, 512), (None, None)],
+                         ids=["decoder-tiles", "rule-tiles"])
+def test_flash_attention_two_widths_fwd(one_chip, on_tpu, tiles):
+    """The forward at 8192 x 32 heads with 192-wide q and k and 128-wide
+    v: one kernel, a whole sequence of k (on 256 lanes) and v in VMEM
+    under the call's own limit, an output 128 wide — nothing 192 wide
+    leaves it."""
+    from ray_tpu.ops import attention
+
+    b, t, h, _, d_v = LATENT
+    qk, v, _ = _latent_shapes(one_chip)
+    text = _compiled_text(lambda q, k, v: attention.flash_attention(
+        q, k, v, True, None, *tiles), qk, qk, v)
+    assert text.count("tpu_custom_call") == 1 and "flash_fwd" in text
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and " custom-call(" in line)
+    assert f"bf16[{b * h},{t},{d_v}]" in call.split(" custom-call(")[0]
+    assert "pad(" not in text
+
+
+def test_flash_attention_two_widths_bwd(one_chip, on_tpu):
+    """... and under a gradient: the forward writing the row log-sum-exp
+    and ONE backward kernel whose dv is 128 wide and whose dq.T and dk
+    are 192 wide; no v, o, do or dv padded to the key width: the only
+    192-wide arrays are q, k, dq and dk and their folded copies."""
+    import re
+
+    b, t, h, d_qk, d_v = LATENT
+    qk, v, w = _latent_shapes(one_chip)
+    text = _compiled_text(_grouped_grads(None), qk, qk, v, w)
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    assert "pad(" not in text and "while(" not in text
+    bwd = next(line for line in text.splitlines()
+               if "flash_bwd_fused" in line and " custom-call(" in line)
+    out = bwd.split(" custom-call(")[0]
+    assert f"bf16[{b * h},{t},{d_v}]" in out          # dv
+    assert f"bf16[{b * h},{t},{d_qk}]" in out         # dk
+    assert f"bf16[{b * h},{t // 512},{d_qk},512]" in out   # dq.T
+    wide = set(re.findall(r"(?:bf16|f32)\[[0-9,]*\]", text))
+    wide = {x for x in wide if str(d_qk) in x.strip("]").split("[")[1]
+            .split(",")}
+    assert wide <= {f"bf16[{b},{t},{h},{d_qk}]", f"bf16[{b},{h},{t},{d_qk}]",
+                    f"bf16[{b * h},{t},{d_qk}]",
+                    f"bf16[{b * h},{t // 512},{d_qk},512]",
+                    f"bf16[{b},{h},{t // 512},{d_qk},512]"}, wide
+
+
+def test_flash_attention_two_widths_runs_on_the_chip():
+    """Runs only on a TPU. The output and dq, dk, dv of the latent
+    mixer's call on bf16 inputs against dense attention in float32, a
+    block of heads at a time: the plain path's measure and limit."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import attention
+
+    b, t, h, d_qk, d_v = LATENT
+    keys = jax.random.split(jax.random.key(h), 4)
+    q, k = (jax.random.normal(key, (b, t, h, d_qk), jnp.float32)
+            for key in keys[:2])
+    v, w = (jax.random.normal(key, (b, t, h, d_v), jnp.float32)
+            for key in keys[2:])
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = jax.jit(_grouped_grads(None))(q, k, v, w)
+
+    @jax.jit
+    def dense_grads(q, k, v, w):
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda q, k, v: (attention._dense_attention(
+                q, k, v, True, d_qk ** -0.5) * w).sum(), (0, 1, 2))(q, k, v)
+
+    want = [dense_grads(*(x[:, :, i:i + 4].astype(jnp.float32)
+                          for x in (q, k, v, w))) for i in range(0, h, 4)]
+    want = [jnp.concatenate(part, axis=2) for part in zip(*want)]
+    errs = {name: _rel_err(a, r)
+            for name, a, r in zip(("dq", "dk", "dv"), got, want)}
+    print("latent", errs)
+    assert max(errs.values()) < 0.01, errs
+
+
 def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
     """The dropless expert layer at SmallThinker's widths (16 held
     experts of 2560 -> 768, top-6 of 64) over 8 192 tokens: the forward
