@@ -614,13 +614,15 @@ class TrainingOperator:
     # checkpointing (reference: torch_trainer.py:543 save / :552 load)
     # ------------------------------------------------------------------
 
-    def _to_host(self, tree, counts: dict, ctx, room: int = 0):
+    def _to_host(self, tree, counts: dict, ctx, room: int = 0, ahead=()):
         """`tree` with its arrays on the host; adds to `counts`
         (`bytes`, `leaves`, `staged_bytes`, `shards`, and the seconds
         `start_s` issuing transfers, `wait_s` blocked in `np.asarray`
         on a device array — the link — and `join_s` writing shards into
         the staging area). Every leaf's
-        transfer is started before the first is waited for. With `room`
+        transfer is started before the first is waited for, and behind
+        them those of the arrays in `ahead`, which are NOT waited for:
+        the link takes transfers in the order issued. With `room`
         (bytes, `_stage_room`) a leaf that has to be joined from shards
         is written, shard by shard as they arrive, into the staging area
         — pages this operator has written before, where `np.asarray`
@@ -629,7 +631,7 @@ class TrainingOperator:
         join go through `np.asarray` either way. At a trace's fine level
         every leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
         leaves, treedef = jax.tree.flatten(tree)
-        _start_transfers(leaves, counts)
+        _start_transfers(leaves + list(ahead), counts)
         at = 0
         clock = time.perf_counter
 
@@ -716,9 +718,11 @@ class TrainingOperator:
     def state_piece(self, index: int, usable: int, drop=()) -> dict:
         """Piece `index` of the state as `train/snapshot.py` cuts it for
         a store that holds `usable` bytes: only this piece's leaves are
-        brought to the host (one `train.snapshot.d2h` span a piece), and
-        the next piece's transfers are started before this returns, so
-        they run under the caller's put of this one.
+        brought to the host (one `train.snapshot.d2h` span a piece).
+        Before this piece is waited for, the transfers of the TWO pieces
+        after it are started behind its own: the link moves more with a
+        second piece queued than with one alone, and they run under the
+        caller's put of this piece.
 
         Leaves joined from shards are views of the operator's staging
         area: they are good until the NEXT `state_piece` call and no
@@ -732,9 +736,9 @@ class TrainingOperator:
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
             whole = _snapshot.cut(self._state_tree(drop), usable)
-            part = self._to_host(whole.part(index), counts, ctx,
-                                 room=_stage_room(whole))
-            _start_transfers(whole.part(index + 1), counts)
+            part = self._to_host(
+                whole.part(index), counts, ctx, room=_stage_room(whole),
+                ahead=whole.part(index + 1) + whole.part(index + 2))
         return whole.reply(index, part)
 
     def load_state_dict(self, state: dict):

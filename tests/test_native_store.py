@@ -199,3 +199,49 @@ def test_arena_spill_overfill_and_recover():
             assert int(ray_tpu.get(ref, timeout=60)[1000]) == i
     finally:
         ray_tpu.shutdown()
+
+
+# ---------------------------------------------------------------------
+# put_serialized: what goes in comes out, through both backends
+# ---------------------------------------------------------------------
+
+@pytest.fixture(params=["native", "files"])
+def either_store(request, tmp_path):
+    from ray_tpu._private.object_store import LocalObjectStore
+
+    if request.param == "files":
+        yield LocalObjectStore(str(tmp_path / "files"))
+        return
+    s = NativeObjectStore(str(tmp_path / "arena"), capacity=32 << 20)
+    yield s
+    s.close()
+
+
+def _buffers(kind):
+    rng = np.random.default_rng(11)
+    if kind == "1KiB":
+        return [memoryview(rng.bytes(1 << 10))]
+    if kind == "1MiB":
+        return [memoryview(rng.bytes(1 << 20))]
+    if kind == "9MiB":      # large buffers of an odd size side by side
+        return [memoryview(rng.bytes((9 << 20) + 3)),
+                memoryview(rng.bytes(1 << 20))]
+    if kind == "many":      # a snapshot's reply: hundreds of small leaves
+        return [memoryview(rng.bytes(int(n)))
+                for n in rng.integers(1, 5000, size=1500)]
+    # shapes, formats, an empty and a read-only buffer side by side
+    a = rng.standard_normal((3, 4)).astype(np.float32)
+    return [memoryview(a), memoryview(b""), memoryview(rng.bytes(7)),
+            memoryview(np.arange(5, dtype=np.int16)),
+            memoryview(a.T.copy()).toreadonly()]
+
+
+@pytest.mark.parametrize("kind", ["1KiB", "1MiB", "9MiB", "many", "odd"])
+def test_put_serialized_reads_back_bit_equal(either_store, kind):
+    oid = ObjectID.from_random()
+    header, buffers = b"\x01header\x00", _buffers(kind)
+    want = header + b"".join(bytes(b) for b in buffers)
+    assert either_store.put_serialized(oid, header, buffers) == len(want)
+    out = either_store.get(oid)
+    assert bytes(out.view[:len(want)]) == want
+    out.close()

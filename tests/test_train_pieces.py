@@ -888,6 +888,111 @@ def test_one_staging_area_serves_every_piece_of_every_call(monkeypatch):
     assert areas[0].nbytes == room <= usable
 
 
+# ---------------------------------------------------------------------
+# the transfers of the two pieces after the one waited for are in flight
+# ---------------------------------------------------------------------
+
+def _issues(monkeypatch, op, usable):
+    """A spy on `_start_transfers`: for every call, the pieces (of the
+    cut for `usable`) whose leaves it was handed, in order."""
+    whole = snapshot.cut(op._state_tree(), usable)
+    piece_of = {id(x): i for i, (a, b) in enumerate(whole.ranges)
+                for x in whole.leaves[a:b]}
+    real, seen = operator_mod._start_transfers, []
+
+    def spy(leaves, counts):
+        pieces = [piece_of[id(x)] for x in leaves]
+        seen.append(sorted(set(pieces)))
+        assert pieces == sorted(pieces)     # the order the link takes
+        return real(leaves, counts)
+
+    monkeypatch.setattr(operator_mod, "_start_transfers", spy)
+    return seen, len(whole.ranges)
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_two_pieces_after_the_one_waited_for_are_in_flight(
+        monkeypatch, chips):
+    """Call k issues pieces k, k + 1 and k + 2 at once, in that order
+    and BEFORE it waits for piece k; what arrives is `state_dict()` bit
+    for bit, joined leaves included."""
+    op = _operator(monkeypatch, chips)
+    op.train_batch(_tiny_pieces()[-1])      # fresh arrays: none fetched
+    usable = 1 << 19
+    seen, pieces = _issues(monkeypatch, op, usable)
+    whole = snapshot.cut(op._state_tree(), usable)
+    fetched = []        # per issue: were the first piece's leaves here?
+    real = operator_mod._start_transfers
+
+    def spy(leaves, counts):
+        if len(fetched) < pieces:       # the pull's; not `state_dict`'s
+            first, stop = whole.ranges[len(fetched)]
+            fetched.append([x._npy_value is not None
+                            for x in whole.leaves[first:stop]
+                            if isinstance(x, jax.Array)])
+        return real(leaves, counts)
+
+    monkeypatch.setattr(operator_mod, "_start_transfers", spy)
+    copies, _ = _pull(op, usable)
+    assert pieces > 4 and _bits(copies) == _bits(op.state_dict())
+    assert seen[:pieces] == [list(range(k, min(k + 3, pieces)))
+                             for k in range(pieces)]
+    assert len(fetched) == pieces and not any(map(any, fetched))
+    assert (op._stage is not None) == (chips == 4)
+
+
+def test_a_pull_abandoned_midway_then_a_step_then_a_whole_pull(monkeypatch):
+    """The step donates arrays whose transfers are in flight: no error,
+    and the next pull brings the state the step left."""
+    op = _operator(monkeypatch, 4)
+    tokens = _tiny_pieces()[-1]
+    op.train_batch(tokens)
+    usable = 1 << 19
+    before = _bits(op.state_dict())
+    for index in (0, 1):        # pieces 2 and 3 are on their way
+        op.state_piece(index, usable)
+    op.train_batch(tokens)
+    copies, _ = _pull(op, usable)
+    assert _bits(copies) == _bits(op.state_dict()) != before
+    # ... and so after a load in the middle of a pull
+    op.state_piece(0, usable)
+    op.load_state_dict(op.state_dict())
+    copies, _ = _pull(op, usable)
+    assert _bits(copies) == _bits(op.state_dict())
+
+
+def test_pieces_asked_out_of_order_are_still_the_state(monkeypatch):
+    """Nothing is kept from one call to the next but transfers that have
+    been started: any piece of any cut can be asked at any time."""
+    op = _operator(monkeypatch, 4)
+    op.train_batch(_tiny_pieces()[-1])
+    leaves = jax.tree.leaves(op.state_dict())
+    first = op.state_piece(0, 1 << 19)
+    for index, usable in ((3, 1 << 19), (1, 1 << 20), (2, 1 << 19)):
+        piece, (count,) = _traced_d2h(
+            lambda: op.state_piece(index, usable))
+        at = piece["first"]
+        assert count["piece"] == index and count["wait_s"] > 0
+        assert _bits(piece["leaves"]) == _bits(
+            leaves[at:at + len(piece["leaves"])])
+    assert len(first["ranges"]) > 4
+
+
+def test_a_state_of_one_piece_issues_its_own_leaves_and_no_thread(
+        monkeypatch):
+    import threading
+
+    op = _operator(monkeypatch, 4)
+    op.train_batch(_tiny_pieces()[-1])
+    seen, pieces = _issues(monkeypatch, op, 1 << 30)
+    threads = threading.active_count()
+    piece, (count,) = _traced_d2h(lambda: op.state_piece(0, 1 << 30))
+    assert pieces == len(piece["ranges"]) == 1 and seen == [[0]]
+    assert threading.active_count() == threads
+    assert count["start_s"] + count["wait_s"] + count["join_s"] > 0
+    assert _bits(piece["leaves"]) == _bits(jax.tree.leaves(op.state_dict()))
+
+
 def test_a_sharded_state_of_several_pieces_reaches_the_driver_intact(
         host, monkeypatch):
     """The `wide` case on a lease of four chips: 30 MiB of four-way
