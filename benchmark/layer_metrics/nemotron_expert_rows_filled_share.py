@@ -1,0 +1,10 @@
+"""Experts: how much of the grouped matmul's static rows held an
+assignment — ``expert_rows_filled_share``'s reading of the
+``moe_rows_filled`` / ``moe_rows_static`` counters on ``train.sync``
+(summed over the four expert blocks and the call's steps), median over
+the window's calls, in percent, under a name of its own because that
+metric's entry lists its cell. With 8 of 128 experts held a sixteenth is
+filled at uniform routing; the rest is memory and elementwise passes the
+step pays for nothing."""
+
+from benchmark.layer_metrics.expert_rows_filled_share import read  # noqa: F401

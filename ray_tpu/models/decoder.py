@@ -1,10 +1,10 @@
 """A decoder assembled from a layer pattern.
 
-A layer is a MIXER and an MLP. The configuration names, layer by layer,
-the kinds of the leading layers (`lead_attention`, `lead_mlp`: a model's
-dense first layers, walked once) and of one period (`attention`, `mlp`),
-which repeats down the rest of the depth (ROADMAP D7). The kinds built
-so far:
+A layer is a MIXER and an MLP, or one of them alone. The configuration
+names, layer by layer, the kinds of the leading layers (`lead_attention`,
+`lead_mlp`: a model's dense first layers, walked once) and of one period
+(`attention`, `mlp`), which repeats down the rest of the depth (ROADMAP
+D7). The kinds built so far:
 
 - mixer `"full"`: causal attention; `"window"`: causal within a sliding
   window. Both with grouped-query heads, through `ops.flash_attention`
@@ -31,7 +31,27 @@ so far:
   INTERLEAVED pairing (dimensions 2i and 2i + 1 turn together: the
   kind's own, as rotate-half is the other kinds'); the shared key is
   turned once and broadcast over the heads.
-- MLP `"experts"`: top-k routed gated experts without dropped tokens
+- mixer `"ssm"`: a Mamba-2 state-space mixer (arXiv:2405.21060). ONE
+  input projection to `[z | xBC | dt]` (`ssm_heads * ssm_head_dim` |
+  that + 2 `ssm_groups * ssm_state` | `ssm_heads`; the leaf `ssm_in` is
+  kept `[outputs, d_model]`: that sum is no multiple of the 128 lanes,
+  and the device keeps a matrix whose minor dimension is not, after one
+  that is, transposed whatever shape it is given); a depthwise causal
+  convolution of `conv_taps` taps WITH bias and a SiLU over xBC (plain
+  `jnp`, left to XLA's fusion); `[x | B | C] = xBC`; `dt =
+  softplus(dt + dt_bias)`, `A = -exp(A_log)`, the scan of
+  `ops/ssd.py` in chunks of `ssm_chunk` (`S_t = exp(dt_t A) S_{t-1} +
+  dt_t x_t B_t^T`, `y_t = S_t C_t + D x_t`, a `[head_dim, state]`
+  float32 state a head, head h reading group `h // (heads / groups)`);
+  an RMSNorm over each GROUP's channels of `y * silu(z)` — the gate
+  before the norm — and an output projection. Its state is that
+  matrix a head and `conv_taps - 1` rows, whatever the length.
+- mixer `"none"` / MLP `"none"`: the layer is the other part alone — a
+  model whose blocks are each a mixer OR a feed-forward part with one
+  norm reads as such layers. It holds no leaf of the absent side (one
+  norm, not two), and no zeros stand in for it. A router that reads the
+  mixer's norm needs a mixer.
+- MLP `"experts"`: top-k routed experts without dropped tokens
   over a HELD share of the experts (`parallel/moe.py::dropless_moe`).
   The router reads the mixer's input or the MLP's (`cfg.router_input`);
   its rule is `cfg.routing`: the softmax over the chosen logits, or
@@ -40,9 +60,13 @@ so far:
   `cfg.routed_scale` multiplies the routing weights; `cfg.d_shared` > 0
   adds a SHARED expert of that width, one gated MLP every token takes,
   beside the routed sum and outside the grouped matmul's rows.
-- MLP `"dense"`: one gated MLP of width `d_dense`.
+- MLP `"dense"`: one MLP of width `d_dense`.
 
-`cfg.activation` gates both MLP kinds; the head is the embedding's
+`cfg.activation` is the MLP kinds' (and the shared expert's) activation;
+they are gated, `W_down (act(W_gate y) * (W_up y))`, unless `cfg.gated`
+is false: `W_down act(W_up y)`, two matrices and no gate leaf
+(`"relu2"`, the squared ReLU, is such a model's; its routed experts'
+`w_up` is `[held, d_expert, d_model]`, rows as `w_down`'s). The head is the embedding's
 transpose (`cfg.tied_head`) or a matrix of its own. Norms are
 `ops.rmsnorm` (weight only).
 
@@ -87,17 +111,28 @@ from jax import lax
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
 from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
 
 ATTENTION_KINDS = ("full", "window")
-MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent")
-MLP_KINDS = ("experts", "dense")
+MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent", "ssm", "none")
+MLP_KINDS = ("experts", "dense", "none")
 ROUTER_INPUTS = ("mixer", "mlp")
 
-# which layers hold a leaf: those whose mixer or MLP is of its group
+# which layers hold a leaf: those whose mixer or MLP is of its group;
+# group "layer": every layer; groups "mixer" and "mlp" (the two norms'
+# where some layer is one part alone): those that have that part
 _GROUP = {"full": "attention", "window": "attention", "conv": "conv",
-          "latent": "latent", "experts": "experts", "dense": "dense"}
+          "latent": "latent", "ssm": "ssm", "experts": "experts",
+          "dense": "dense"}
+
+
+def _groups_of(pair) -> tuple[str, ...]:
+    """The leaf groups a layer of kinds (mixer, mlp) holds."""
+    return ("layer",) + tuple(
+        g for part, kind in zip(("mixer", "mlp"), pair) if kind != "none"
+        for g in (part, _GROUP[kind]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,7 +166,8 @@ class DecoderConfig:
     router_input: str = "mixer"       # the norm whose output the router reads
     routing: str = "softmax_topk"     # parallel/moe.py::ROUTING
     bias_rate: float = 1e-3           # a step of the selection bias
-    activation: str = "relu"          # gates both MLP kinds
+    activation: str = "relu"          # of both MLP kinds
+    gated: bool = True                # False: W_down act(W_up y), no gate
     d_dense: int = 0
     conv_taps: int = 3
     tied_head: bool = False
@@ -144,6 +180,15 @@ class DecoderConfig:
     routed_scale: float = 1.0         # a factor on the routing weights
     mtp: int = 0                      # multi-token-prediction blocks: 0 or 1
     mtp_weight: float = 0.3           # lambda on the second loss term
+    ssm_heads: int = 0                # the ssm mixer: heads of ssm_head_dim,
+    ssm_head_dim: int = 0             # ... ssm_groups pairs of B and C of
+    ssm_groups: int = 1               # ... ssm_state; conv_taps taps
+    ssm_state: int = 0
+    ssm_chunk: int = SSD_CHUNK        # positions a chunk of the scan holds
+    ssm_dt_range: tuple[float, float, float] = (1e-3, 0.1, 1e-4)  # min, max,
+    #                                   floor of dt at initialisation
+    count_rows: bool = False          # the counters moe_rows_static /
+    #                                   _filled (with an MTP block: always)
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -161,7 +206,21 @@ class DecoderConfig:
                 f"layer kinds built so far: mixer {MIXER_KINDS} (rotary "
                 f"and qk_norm list attention kinds: {ATTENTION_KINDS}; "
                 f"the latent mixer turns its rope part itself), "
-                f"mlp {MLP_KINDS}")
+                f"mlp {MLP_KINDS}; \"none\" on one side only")
+        if any(pair == ("none", "none") or (
+                pair == ("none", "experts") and self.router_input == "mixer")
+               for pair in self.kinds):
+            raise ValueError(
+                "a layer is a mixer, an MLP or both: (none, none) is no "
+                "layer, and the router of (none, experts) reads the MLP's "
+                "norm (router_input \"mlp\")")
+        ssm = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+               self.ssm_state)
+        if "ssm" in mixers and (min(ssm) < 1
+                                or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                "the ssm mixer needs ssm_heads (a multiple of ssm_groups), "
+                f"ssm_head_dim, ssm_groups and ssm_state: got {ssm}")
         latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
                   self.qk_rope_dim, self.v_head_dim)
         if "latent" in mixers and (min(latent) < 1 or self.qk_rope_dim % 2):
@@ -212,11 +271,16 @@ TINY = DecoderConfig(
 def _leaves(cfg: DecoderConfig) -> dict:
     """name -> (group, shape of one layer's leaf, how it starts): every
     block leaf the configuration's kinds need. Group `"layer"`: every
-    layer has it."""
+    layer has it — the two norms, unless some layer is a mixer or an
+    MLP alone: then a norm's group is `"mixer"` or `"mlp"`, the layers
+    that have that part. An ungated configuration has no gate leaf
+    (`w_gate`, `ws_gate`, `w1`)."""
     d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_expert
-    count, groups = cfg.held[1], {_GROUP[k] for pair in cfg.kinds
-                                  for k in pair}
-    table = {"norm1": ("layer", (d,), "one"), "norm2": ("layer", (d,), "one")}
+    count, groups = cfg.held[1], {g for pair in cfg.kinds
+                                  for g in _groups_of(pair)}
+    alone = any("none" in pair for pair in cfg.kinds)
+    table = {"norm1": ("mixer" if alone else "layer", (d,), "one"),
+             "norm2": ("mlp" if alone else "layer", (d,), "one")}
     if "attention" in groups:
         table.update(
             wq=("attention", (d, cfg.n_heads * hd), "normal"),
@@ -243,6 +307,18 @@ def _leaves(cfg: DecoderConfig) -> dict:
         table.update(conv_in=("conv", (d, 3 * d), "normal"),
                      conv_taps=("conv", (cfg.conv_taps, d), "taps"),
                      conv_out=("conv", (d, d), "normal"))
+    if "ssm" in groups:
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        table.update(
+            ssm_in=("ssm", (inner + conv + cfg.ssm_heads, d), "normal"),
+            ssm_conv=("ssm", (cfg.conv_taps, conv), "taps"),
+            ssm_conv_bias=("ssm", (conv,), "taps"),
+            A_log=("ssm", (cfg.ssm_heads,), "A_log"),
+            D=("ssm", (cfg.ssm_heads,), "one"),
+            dt_bias=("ssm", (cfg.ssm_heads,), "dt_bias"),
+            ssm_norm=("ssm", (inner,), "one"),
+            ssm_out=("ssm", (inner, d), "normal"))
     if "experts" in groups:
         table.update(router=("experts", (d, cfg.n_experts), "normal"),
                      w_gate=("experts", (count, d, f), "normal"),
@@ -257,6 +333,11 @@ def _leaves(cfg: DecoderConfig) -> dict:
         table.update(w1=("dense", (d, cfg.d_dense), "normal"),
                      w3=("dense", (d, cfg.d_dense), "normal"),
                      w2=("dense", (cfg.d_dense, d), "normal"))
+    if not cfg.gated:
+        for gate in ("w_gate", "ws_gate", "w1"):
+            table.pop(gate, None)
+        if "experts" in groups:     # `dropless_moe`: rows are hidden units
+            table["w_up"] = ("experts", (count, f, d), "normal")
     return table
 
 
@@ -264,8 +345,7 @@ def _layers_with(cfg: DecoderConfig, group: str, kinds=None) -> int:
     """How many of `kinds` (default: all the layers) hold the leaves of
     `group`."""
     kinds = cfg.kinds if kinds is None else kinds
-    return sum(group == "layer" or group in (_GROUP[a], _GROUP[m])
-               for a, m in kinds)
+    return sum(group in _groups_of(pair) for pair in kinds)
 
 
 # The key a leaf is drawn from: one of `split(key, 12)`, the first ten
@@ -273,30 +353,36 @@ def _layers_with(cfg: DecoderConfig, group: str, kinds=None) -> int:
 # are what its recorded losses were taken on), the later kinds' from
 # splits of the eleventh, the selection bias from the twelfth. The
 # kinds after those fold their place in `_NEWER` into the eleventh (a
-# longer `_LATER` would move the second configuration's weights), the
-# MTP block's leaves theirs into `fold_in(eleventh, _MTP_KEY)`.
+# longer `_LATER` would move the second configuration's weights; a name
+# appended to `_NEWER` moves nobody's), the MTP block's leaves theirs
+# into `fold_in(eleventh, _MTP_KEY)`.
 _KEY_OF = {name: i for i, name in enumerate((
     "embed", "wq", "wk", "wv", "wo", "router", "w_gate", "w_up", "w_down",
     "head"))}
 _LATER = ("conv_in", "conv_taps", "conv_out", "w1", "w3", "w2")
 _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
-          "ws_down", "proj")
+          "ws_down", "proj", "ssm_in", "ssm_conv", "ssm_conv_bias", "A_log",
+          "dt_bias", "ssm_out")
 _MTP_KEY = 1 << 16
 
 
 def _mtp_leaves(cfg: DecoderConfig) -> dict:
     """The MTP block's own leaves: those of one layer of the last
     layer's kinds."""
-    mine = ("layer", *(_GROUP[k] for k in cfg.kinds[-1]))
+    mine = _groups_of(cfg.kinds[-1])
     return {name: spec for name, spec in _leaves(cfg).items()
             if spec[0] in mine}
 
 
 def init(key, cfg: DecoderConfig):
     """The parameter pytree: normal(0, init_std) matrices, norms at one,
-    the convolution's taps uniform in +-1/sqrt(taps); a block leaf is
-    stacked on axis 0 over the layers that have it, experts on axis 1
-    (the held ones only)."""
+    the convolutions' taps (and the ssm mixer's convolution bias)
+    uniform in +-1/sqrt(taps); the ssm mixer's `A_log` the log of a
+    uniform draw in [1, 16], its `dt_bias` the inverse softplus of a
+    log-uniform draw in `cfg.ssm_dt_range` (Mamba-2's own start: a head
+    forgets over 1 / (dt A), between a handful and a thousand
+    positions); a block leaf is stacked on axis 0 over the layers that
+    have it, experts on axis 1 (the held ones only)."""
     keys = list(jax.random.split(key, 12))
     later = dict(zip(_LATER, jax.random.split(keys[10], len(_LATER))))
 
@@ -314,6 +400,13 @@ def init(key, cfg: DecoderConfig):
         if how == "taps":
             bound = cfg.conv_taps ** -0.5
             return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
+        if how == "A_log":
+            return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1, 16))
+        if how == "dt_bias":
+            low, high, floor = cfg.ssm_dt_range
+            dt = jnp.maximum(floor, jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, jnp.log(low), jnp.log(high))))
+            return dt + jnp.log(-jnp.expm1(-dt))
         return jax.random.normal(k, shape, jnp.float32) * cfg.init_std
 
     params = {
@@ -411,6 +504,52 @@ def _latent_attention(x, p, rope, cfg: DecoderConfig):
     return a.reshape(b, t, h * dv) @ cast(p["wo_latent"])
 
 
+def _ssm_mixer(x, p, cfg: DecoderConfig):
+    """The state-space mixer on the first norm's output x [B, T, D] ->
+    (its part of the residual [B, T, D], {the most negative sum of dt A
+    over a chunk, the largest dt}: what the counters keep)."""
+    b, t, _ = x.shape
+    h, hp, gn = cfg.ssm_heads, cfg.ssm_head_dim, \
+        cfg.ssm_groups * cfg.ssm_state
+    inner, k = h * hp, cfg.conv_taps
+    cast = functools.partial(jnp.asarray, dtype=x.dtype)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    zxbcdt = x @ cast(p["ssm_in"]).T
+    z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * gn]
+    # the convolution: K shifted products, a bias, SiLU; float32 sums of
+    # the compute dtype's rows (the padded copy stays in that dtype)
+    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = sum(p["ssm_conv"][j] * f32(padded[:, j:j + t]) for j in range(k))
+    xbc = jax.nn.silu(conv + p["ssm_conv_bias"]).astype(x.dtype)
+    dt = jax.nn.softplus(f32(zxbcdt[..., 2 * inner + 2 * gn:])
+                         + p["dt_bias"])                   # [B, T, H]
+    a = -jnp.exp(p["A_log"])
+    y = ssd(xbc[..., :inner].reshape(b, t, h, hp), dt, a,
+            xbc[..., inner:inner + gn].reshape(b, t, cfg.ssm_groups, -1),
+            xbc[..., inner + gn:].reshape(b, t, cfg.ssm_groups, -1),
+            p["D"], cfg.ssm_chunk)
+    # the gate BEFORE the norm; statistics over each group's channels
+    gated = (f32(y).reshape(b, t, inner) * jax.nn.silu(f32(z))).reshape(
+        b, t, cfg.ssm_groups, -1)
+    normed = _head_norm(gated, p["ssm_norm"].reshape(cfg.ssm_groups, -1),
+                        cfg.rms_eps).reshape(b, t, inner).astype(x.dtype)
+    stats = lax.stop_gradient({
+        "ssm_log_decay_min": (dt * a).reshape(
+            b, t // cfg.ssm_chunk, cfg.ssm_chunk, h).sum(2).min(),
+        "ssm_dt_max": dt.max()})
+    return normed @ cast(p["ssm_out"]), stats
+
+
+def _mlp(y, p, cfg: DecoderConfig, up: str, down: str, gate: str):
+    """W_down (act(W_gate y) * (W_up y)), or W_down act(W_up y) where
+    the configuration is ungated."""
+    cast = functools.partial(jnp.asarray, dtype=y.dtype)
+    act = ACTIVATIONS[cfg.activation]
+    if not cfg.gated:
+        return act(y @ cast(p[up])) @ cast(p[down])
+    return (act(y @ cast(p[gate])) * (y @ cast(p[up]))) @ cast(p[down])
+
+
 def _router(x, p):
     with jax.named_scope("router"):
         # in float32, whichever norm's output it reads
@@ -421,21 +560,29 @@ def _router(x, p):
 def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
     """One block. h: [B, T, D] in compute dtype; p: the layer's row of
     every leaf its kinds have (and `expert_bias`, where the routing has
-    one) -> (h', the MoE layer's counts; None from a dense layer)."""
+    one) -> (h', what the layer counted beside it, one flat dict: the
+    expert layer's counts where it has experts, the scan's statistics
+    where its mixer is one, both or neither)."""
     b, t, d = h.shape
     hd = cfg.head_dim
     cast = functools.partial(jnp.asarray, dtype=h.dtype)
-    x = rmsnorm(h, cast(p["norm1"]), cfg.rms_eps)
+    found = {}
+    if attention != "none":
+        x = rmsnorm(h, cast(p["norm1"]), cfg.rms_eps)
     if mlp == "experts" and cfg.router_input == "mixer":
         logits = _router(x, p)
-    if attention == "conv":
+    if attention == "ssm":
+        with jax.named_scope("mixer_ssm"):
+            y, stats = _ssm_mixer(x, p, cfg)
+            h, found = h + y, {**found, **stats}
+    elif attention == "conv":
         with jax.named_scope("mixer_conv"):
             y = short_conv(x @ cast(p["conv_in"]), p["conv_taps"])
             h = h + y @ cast(p["conv_out"])
     elif attention == "latent":
         with jax.named_scope("attention_latent"):
             h = h + _latent_attention(x, p, rope, cfg)
-    else:
+    elif attention != "none":
         with jax.named_scope("attention_" + attention):
             q = (x @ cast(p["wq"])).reshape(b, t, cfg.n_heads, hd)
             k = (x @ cast(p["wk"])).reshape(b, t, cfg.n_kv_heads, hd)
@@ -449,26 +596,27 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
                 cfg.window if attention == "window" else None)
             h = h + a.reshape(b, t, cfg.n_heads * hd) @ cast(p["wo"])
-    y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
+    if mlp != "none":
+        y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
     if mlp == "dense":
         with jax.named_scope("mlp_dense"):
-            act = ACTIVATIONS[cfg.activation](y @ cast(p["w1"]))
-            return h + (act * (y @ cast(p["w3"]))) @ cast(p["w2"]), None
-    if cfg.router_input == "mlp":
-        logits = _router(y, p)
-    m, counts = dropless_moe(
-        y.reshape(b * t, d), logits, cast(p["w_gate"]), cast(p["w_up"]),
-        cast(p["w_down"]), top_k=cfg.top_k, held=cfg.held,
-        tile=cfg.gmm_tile, activation=cfg.activation,
-        bias=p.get("expert_bias"), scale=cfg.routed_scale)
-    h = h + m.reshape(b, t, d)
-    if cfg.d_shared:
-        with jax.named_scope("mlp_shared"):
-            # what every chip of the deployment computes alike: every
-            # token, one plain gated MLP, no row of the grouped matmul
-            act = ACTIVATIONS[cfg.activation](y @ cast(p["ws_gate"]))
-            h = h + (act * (y @ cast(p["ws_up"]))) @ cast(p["ws_down"])
-    return h, counts
+            h = h + _mlp(y, p, cfg, "w3", "w2", "w1")
+    elif mlp == "experts":
+        if cfg.router_input == "mlp":
+            logits = _router(y, p)
+        m, counts = dropless_moe(
+            y.reshape(b * t, d), logits,
+            cast(p["w_gate"]) if cfg.gated else None, cast(p["w_up"]),
+            cast(p["w_down"]), top_k=cfg.top_k, held=cfg.held,
+            tile=cfg.gmm_tile, activation=cfg.activation,
+            bias=p.get("expert_bias"), scale=cfg.routed_scale)
+        h, found = h + m.reshape(b, t, d), {**found, **counts}
+        if cfg.d_shared:
+            with jax.named_scope("mlp_shared"):
+                # what every chip of the deployment computes alike:
+                # every token, one plain MLP, no row of the grouped matmul
+                h = h + _mlp(y, p, cfg, "ws_up", "ws_down", "ws_gate")
+    return h, found
 
 
 def _rope_for(t: int, cfg: DecoderConfig):
@@ -487,7 +635,9 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     """tokens [B, T] -> (the last block's output [B, T, D], before the
     final norm; counts stacked over the MoE layers [layers, ...]).
     `bias`: the selection bias [MoE layers, n_experts], where the
-    routing has one (the MTP block's row, the last, is not read here)."""
+    routing has one (the MTP block's row, the last, is not read here).
+    With an ssm mixer the counts also hold `ssm_log_decay_min` and
+    `ssm_dt_max`, scalars over all its layers."""
     kinds, lead, period = cfg.kinds, len(cfg.lead_attention), \
         len(cfg.attention)
     h = params["embed"][tokens].astype(cfg.dtype)
@@ -501,22 +651,28 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     def rows(stacks, at: int, before):
         """Layer `at`'s row of each leaf it has: its index in a leaf's
         stack is the number of layers in `before` that have the leaf."""
-        mine = ("layer", *(_GROUP[k] for k in kinds[at]))
+        mine = _groups_of(kinds[at])
         return {name: stacks[name][_layers_with(cfg, group_of[name], before)]
                 for name in sorted(stacks) if group_of[name] in mine}
 
-    counts = []
+    def run(fn, h, p, into):
+        """What a block counted beside h, each key onto its own list."""
+        h, found = fn(h, p, rope)
+        for key, x in found.items():
+            into.setdefault(key, []).append(x)
+        return h
+
+    led = {}
     for at in range(lead):          # the leading layers, one by one
-        h, c = _block(cfg, *kinds[at])(h, rows(layers, at, kinds[:at]), rope)
-        counts += [] if c is None else [jax.tree.map(lambda x: x[None], c)]
+        h = run(_block(cfg, *kinds[at]), h, rows(layers, at, kinds[:at]), led)
+    led = {key: [x[None] for x in led[key]] for key in sorted(led)}
     blocks = [_block(cfg, *pair) for pair in kinds[lead:lead + period]]
 
     def one_period(h, p):
-        counts = []
+        into = {}
         for j, fn in enumerate(blocks):
-            h, c = fn(h, rows(p, lead + j, kinds[lead:lead + j]), rope)
-            counts += [] if c is None else [c]
-        return h, jax.tree.map(lambda *xs: jnp.stack(xs), *counts)
+            h = run(fn, h, rows(p, lead + j, kinds[lead:lead + j]), into)
+        return h, {key: jnp.stack(into[key]) for key in sorted(into)}
 
     def periods(name, per):
         """A leaf's stack past the leading layers' rows, by period."""
@@ -530,9 +686,17 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     h, scanned = lax.scan(one_period, h, {
         name: periods(name, per) for name, per in in_period.items() if per})
     scanned = jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), scanned)
-    if not counts:
-        return h, scanned
-    return h, jax.tree.map(lambda *xs: jnp.concatenate(xs), *counts, scanned)
+
+    def whole(key):
+        """The leading layers' rows, then the periods'."""
+        parts = led.get(key, []) + ([scanned[key]] if key in scanned else [])
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    counts = {key: whole(key) for key in sorted({*led, *scanned})}
+    if "ssm_dt_max" in counts:      # scalars over the layers that have one
+        counts.update(ssm_log_decay_min=counts["ssm_log_decay_min"].min(),
+                      ssm_dt_max=counts["ssm_dt_max"].max())
+    return h, counts
 
 
 def _head(params, cfg: DecoderConfig, dtype):
@@ -599,8 +763,9 @@ def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
     if not cfg.mtp:
         return _mean_nll(x, tokens, 1, params, cfg)[0], counts
     x_mtp, c = mtp_hidden(params, h, tokens, cfg, bias)
-    counts = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),
-                          counts, c)
+    counts = {**counts, **jax.tree.map(
+        lambda a, b: jnp.concatenate([a, b[None]]),
+        {name: counts[name] for name in c}, c)}
     main, head = _mean_nll(x, tokens, 1, params, cfg)
     second, _ = _mean_nll(x_mtp, tokens, 2, params, cfg, head)
     return main + cfg.mtp_weight * second, {
@@ -665,8 +830,14 @@ def counters_init(cfg: DecoderConfig):
     before its weight), `moe_rows_static` (the rows the grouped matmul's
     arrays hold, the worst case: `parallel/moe.py::static_rows` x MoE
     layers x steps) and `moe_rows_filled` (those that held an
-    assignment). The configurations from before the block keep the state
-    tree their recorded programs were lowered with."""
+    assignment); `cfg.count_rows` asks for the two rows counters without
+    one. A configuration with an ssm mixer counts `ssm_log_decay_min`
+    (the most negative sum of dt A over one chunk of the scan that any
+    head of any layer saw in the epoch: below about -87 a float32 chunk
+    forgets the state that entered it entirely) and `ssm_dt_max` (the
+    largest dt). The configurations from
+    before the block keep the state tree their recorded programs were
+    lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
     i32 = functools.partial(jnp.zeros, (), jnp.int32)
     counters = {
@@ -675,9 +846,26 @@ def counters_init(cfg: DecoderConfig):
         "moe_expert_tokens_mean": f32(), "moe_experts_held": i32(),
         "moe_experts_total": i32(), "moe_steps": i32()}
     if cfg.mtp:
-        counters.update(loss_main=f32(), loss_mtp=f32(),
-                        moe_rows_static=f32(), moe_rows_filled=f32())
+        counters.update(loss_main=f32(), loss_mtp=f32())
+    if cfg.mtp or cfg.count_rows:
+        counters.update(moe_rows_static=f32(), moe_rows_filled=f32())
+    if "ssm" in cfg.attention + cfg.lead_attention:
+        counters.update(ssm_log_decay_min=f32(), ssm_dt_max=f32())
     return {"epoch_counters": counters}
+
+
+def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
+    """What is known of a step on a batch [B, T] without running it, for
+    the `train.dispatch` span (`loss_fn.step_facts`, read by the
+    operator): with an ssm mixer `ssm_layers` and `ssm_chunks`, the
+    chunks the scan walks a step (layers x sequences x T / chunk; every
+    head walks each); nothing otherwise."""
+    layers = sum(a == "ssm" for a, _ in cfg.kinds)
+    if not layers:
+        return {}
+    b, t = batch_shape
+    return {"ssm_layers": layers,
+            "ssm_chunks": layers * b * (t // cfg.ssm_chunk)}
 
 
 def state_init(key, cfg: DecoderConfig):
@@ -724,8 +912,15 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
     for name, key in names:
         new[name] = old[name] + counts[key].sum().astype(jnp.float32)
     if cfg.mtp:
+        new.update(loss_main=counts["loss_main"],
+                   loss_mtp=counts["loss_mtp"])
+    if "ssm_dt_max" in old:
         new.update(
-            loss_main=counts["loss_main"], loss_mtp=counts["loss_mtp"],
+            ssm_log_decay_min=jnp.minimum(old["ssm_log_decay_min"],
+                                          counts["ssm_log_decay_min"]),
+            ssm_dt_max=jnp.maximum(old["ssm_dt_max"], counts["ssm_dt_max"]))
+    if "moe_rows_static" in old:
+        new.update(
             moe_rows_static=old["moe_rows_static"] + float(
                 cfg.moe_layers * static_rows(
                     tokens.size * cfg.top_k, cfg.held[1], cfg.gmm_tile)),

@@ -18,7 +18,9 @@ hold is undefined; the caller masks by row validity.
 Three kernels, named so the device trace carries them: `moe_gmm` (the
 forward product, and its rematerialised copy), `moe_gmm_dx` (the same
 walk against the transposed weight) and `moe_gmm_dw` (per expert
-x_g^T dy_g, accumulated in float32 over the expert's tiles). The shape
+x_g^T dy_g, accumulated in float32 over the expert's tiles). A weight
+kept as [G, N, K] (`moe_gmm(..., transposed=True)`) swaps the two walks
+under the same names. The shape
 follows `jax.experimental.pallas.ops.tpu.megablox`; aligning groups to
 tiles is what makes it this short.
 """
@@ -38,7 +40,16 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _divisor(dim: int, prefs: tuple[int, ...]) -> int:
-    """The first of `prefs` that divides `dim`; `dim` itself if none."""
+    """The lanes (or rows) of a weight block along `dim`: the first of
+    `prefs` that divides it, and where none does — 1856 = 14.5 x 128,
+    the first width to come that no lane tile divides — the WHOLE
+    dimension as one block, on purpose: a block that spans its array's
+    full extent needs no alignment, so no width is padded in HBM and no
+    remainder tile is masked; the cost is one block of that many lanes
+    in VMEM (at K = 2688 in bf16, double-buffered: 2 x 2688 x 1856 x 2 B
+    = 20 MB of the 64 the kernels ask for) and one grid step along it.
+    A width that some preference divides keeps the tile it had (1792
+    takes 256, 768 takes 384)."""
     return next((p for p in prefs if p <= dim and dim % p == 0), dim)
 
 
@@ -141,28 +152,34 @@ def _gmm_dw(x, dy, tile_group, n_tiles, *, tile: int, groups: int):
     )(tile_group, n_tiles, x, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def moe_gmm(x, w, tile_group, n_tiles, tile: int):
-    """x: [R, K] rows grouped by expert in tiles of `tile`; w: [G, K, N];
-    tile_group: int32 [R // tile], the expert of each tile; n_tiles:
-    int32 [1], the tiles that hold rows. Returns [R, N] in x's dtype;
-    rows of tiles past `n_tiles` are undefined."""
-    return _gmm(x, w, tile_group, n_tiles, tile=tile, transpose_rhs=False,
-                name="moe_gmm")
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def moe_gmm(x, w, tile_group, n_tiles, tile: int, transposed: bool = False):
+    """x: [R, K] rows grouped by expert in tiles of `tile`; w: [G, K, N],
+    or with `transposed` [G, N, K]: an expert's rows are its OUTPUTS
+    (how `parallel/moe.py::dropless_moe` keeps an ungated expert's
+    `w_up`, so that the model width is the minor dimension of both its
+    matrices). Nothing is transposed in HBM: the forward walks such a
+    weight as `moe_gmm_dx` walks the other form, and the reverse;
+    `moe_gmm_dw` takes its two operands in the other order. tile_group:
+    int32 [R // tile], the expert of each tile; n_tiles: int32 [1], the
+    tiles that hold rows. Returns [R, N] in x's dtype; rows of tiles
+    past `n_tiles` are undefined."""
+    return _gmm(x, w, tile_group, n_tiles, tile=tile,
+                transpose_rhs=transposed, name="moe_gmm")
 
 
-def _moe_gmm_fwd(x, w, tile_group, n_tiles, tile):
-    return moe_gmm(x, w, tile_group, n_tiles, tile), (x, w, tile_group,
-                                                      n_tiles)
+def _moe_gmm_fwd(x, w, tile_group, n_tiles, tile, transposed):
+    return moe_gmm(x, w, tile_group, n_tiles, tile, transposed), (
+        x, w, tile_group, n_tiles)
 
 
-def _moe_gmm_bwd(tile, res, dy):
+def _moe_gmm_bwd(tile, transposed, res, dy):
     x, w, tile_group, n_tiles = res
-    dx = _gmm(dy, w, tile_group, n_tiles, tile=tile, transpose_rhs=True,
-              name="moe_gmm_dx")
-    dw = _gmm_dw(x, dy, tile_group, n_tiles, tile=tile, groups=w.shape[0])
+    dx = _gmm(dy, w, tile_group, n_tiles, tile=tile,
+              transpose_rhs=not transposed, name="moe_gmm_dx")
+    pair = (dy, x) if transposed else (x, dy)
+    dw = _gmm_dw(*pair, tile_group, n_tiles, tile=tile, groups=w.shape[0])
     return dx, dw.astype(w.dtype), None, None
 
 
 moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)
-

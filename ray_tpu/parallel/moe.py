@@ -13,9 +13,11 @@ SURVEY §2.4).
    (`ROUTING`): the softmax over the chosen k logits, and sigmoid
    scores with a selection bias that moves the choice and not the
    weights (`route_sigmoid_bias`; the bias is model state, moved by
-   `balance_bias`), either times a `scale`; gated ReLU or SiLU experts
-   (`ACTIVATIONS`). A shared expert is not this layer's: the decoder
-   adds it as a plain MLP, outside the grouped matmul's rows.
+   `balance_bias`), either times a `scale`; experts gated under ReLU or
+   SiLU, or ungated (`w_gate` None: two matrices an expert, say under
+   the squared ReLU) (`ACTIVATIONS`). A shared expert is not this
+   layer's: the decoder adds it as a plain MLP, outside the grouped
+   matmul's rows.
 """
 
 from __future__ import annotations
@@ -174,7 +176,8 @@ def balance_bias(bias, routed, rate: float):
 
 
 ROUTING = ("softmax_topk", "sigmoid_bias")
-ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def static_rows(assignments: int, count: int, tile: int = GMM_TILE) -> int:
@@ -283,13 +286,22 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
                  held: tuple[int, int], tile: int = GMM_TILE,
                  activation: str = "relu", bias=None, scale: float = 1.0):
-    """Top-k gated experts over a held share, no token dropped.
+    """Top-k experts over a held share, no token dropped.
 
     y: [N, D] tokens (compute dtype); router_logits: [N, n_experts], the
     router's output over ALL experts, float32; w_gate, w_up: [count, D,
     F] and w_down: [count, F, D], the held experts' weights. Returns
     (out [N, D]: sum over the chosen AND held experts e of
-    p_e * W_down,e (act(W_gate,e y) * (W_up,e y)), counts): `counts`
+    p_e * W_down,e (act(W_gate,e y) * (W_up,e y)), counts) — with
+    `w_gate` None the experts are UNGATED, p_e * W_down,e act(W_up,e y):
+    one grouped product in and one out, nothing standing in for the
+    gate, and `w_up` is [count, F, D] as `w_down` is: an expert's rows
+    are its hidden units both ways, so the model width is every stored
+    weight's minor dimension whatever F is (the device keeps a leaf
+    whose minor dimension is no multiple of its 128 lanes while the one
+    before it is — F = 1856 — transposed anyway, the step then copies
+    the stack and its moments back and forth and the host gets a
+    strided view; PERF.md section 6, PR 39); `counts`
     holds `expert_tokens` [count] (assignments each held expert got),
     `assignments` (N * k), `held` (those on held experts) and `dropped`
     (held assignments that found no row: 0, by construction, and
@@ -314,12 +326,17 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
     if scale != 1:
         weights = weights * scale
     g = group_by_expert(idx, held, tile)
-    f = w_gate.shape[-1]
+    act_fn = ACTIVATIONS[activation]
     with jax.named_scope("experts"):
         x = _dispatch(y, g)
-        gate_up = moe_gmm(x, jnp.concatenate([w_gate, w_up], axis=-1),
-                          g.tile_group, g.n_tiles, tile)
-        act = ACTIVATIONS[activation](gate_up[:, :f]) * gate_up[:, f:]
+        if w_gate is None:
+            act = act_fn(moe_gmm(x, w_up, g.tile_group, g.n_tiles, tile,
+                                 True))
+        else:
+            f = w_gate.shape[-1]
+            gate_up = moe_gmm(x, jnp.concatenate([w_gate, w_up], axis=-1),
+                              g.tile_group, g.n_tiles, tile)
+            act = act_fn(gate_up[:, :f]) * gate_up[:, f:]
         rows = moe_gmm(act, w_down, g.tile_group, g.n_tiles, tile)
         out = _combine(rows, weights, g)
     n_held = g.held.sum()

@@ -82,6 +82,11 @@ class TrainingOperator:
             read ONCE, in `train.sync` with the losses: no sync inside
             the epoch. Each goes on the `train.sync` span under its key
             and into the epoch's result ("counters").
+            A `loss_fn` with an attribute `step_facts` — a function of
+            one batch that returns a flat dict of numbers known without
+            running the step (the layers of a kind, the chunks a scan
+            walks) — gets them on every `train.dispatch` span, reckoned
+            from the epoch's last batch.
 
         mesh: a jax Mesh (possibly GLOBAL, spanning worker processes via
             parallel.multihost) — the step runs SPMD over it and gradient
@@ -562,6 +567,9 @@ class TrainingOperator:
                         break
                 counts.update(steps=step, samples=samples,
                               **self._layout_facts())
+                facts = getattr(self._loss_fn, "step_facts", None)
+                if facts is not None and step:
+                    counts.update(facts(batch))
             # One sync for the whole epoch: the loop was async dispatch.
             counters = {}
             with _tracing.span("train.sync", _tracing.child_of_current(),

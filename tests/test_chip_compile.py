@@ -46,9 +46,9 @@ def on_tpu(monkeypatch):
     interpret mode: steer them to Mosaic, in the test."""
     from ray_tpu.collective.backends import pallas_backend
     from ray_tpu.ops import (attention, batchnorm, layernorm, moe_gmm,
-                             short_conv)
+                             short_conv, ssd)
 
-    for mod in (attention, batchnorm, layernorm, moe_gmm, short_conv,
+    for mod in (attention, batchnorm, layernorm, moe_gmm, short_conv, ssd,
                 pallas_backend):
         monkeypatch.setattr(mod, "is_tpu", lambda: True)
 
@@ -160,6 +160,8 @@ GROUPED_SHAPES = {
     "smallthinker-window": ((1, 8192, 28, 128), 4, 4096),
     "smallthinker-full": ((1, 8192, 28, 128), 4, None),
     "lfm2-full": ((1, 4096, 32, 64), 8, None),
+    # Nemotron's attention block: sixteen query heads a key/value head
+    "nemotron-full": ((1, 8192, 32, 128), 2, None),
 }
 
 
@@ -368,6 +370,131 @@ def test_sigmoid_routed_silu_experts_fwd_and_bwd(one_chip, on_tpu):
         spec((8, 2048, 1792)), spec((8, 1792, 2048)),
         spec((32,), jnp.float32))
     assert text.count("tpu_custom_call") == 6
+
+
+def test_ungated_experts_of_a_width_no_lane_tile_divides(one_chip, on_tpu):
+    """The dropless expert layer at Nemotron's widths (8 held UNGATED
+    squared-ReLU experts of 2688 -> 1856 -> 2688, top-6 of 128 by
+    sigmoid scores and a selection bias) over 8 192 tokens: two grouped
+    products forward (up, down: no gate half) and each one's two
+    gradients, six Mosaic calls, with the whole 1856 lanes or rows as a
+    weight block wherever the width is the blocked dimension (1856 =
+    14.5 x 128: no lane tile divides it, and nothing is padded)."""
+    from ray_tpu.parallel.moe import dropless_moe
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(y, r, w_up, w_down, bias):
+        out, _ = dropless_moe(y, r, None, w_up, w_down, top_k=6,
+                              held=(0, 8), activation="relu2", bias=bias,
+                              scale=2.5)
+        return out.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.grad(loss, (0, 1, 2, 3)), spec((8192, 2688)),
+        spec((8192, 128), jnp.float32), spec((8, 1856, 2688)),
+        spec((8, 1856, 2688)), spec((128,), jnp.float32))
+    assert text.count("tpu_custom_call") == 6
+    for name in ("moe_gmm", "moe_gmm_dx", "moe_gmm_dw"):
+        assert name in text
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_ssd_fwd_and_bwd(one_chip, on_tpu, batch):
+    """The Mamba-2 scan at Nemotron's shapes (8 192 positions, 64 heads
+    of 64 in 8 groups, state 128, chunks of 128): one Mosaic call
+    forward, which writes no state; under grad the forward that saves
+    each chunk's entering states ([B, 64, 64, 64, 128] float32) and one
+    backward call."""
+    from ray_tpu.ops import ssd
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    t, f32 = 8192, jnp.float32
+    args = (spec((batch, t, 64, 64)), spec((batch, t, 64), f32),
+            spec((64,), f32), spec((batch, t, 8, 128)),
+            spec((batch, t, 8, 128)), spec((64,), f32))
+    text = _compiled_text(ssd.ssd, *args)
+    assert text.count("tpu_custom_call") == 1 and "ssd_fwd" in text
+    assert f"f32[{batch},64,64,64,128]" not in text
+    text = _compiled_text(
+        jax.grad(lambda *a: ssd.ssd(*a).astype(f32).sum(),
+                 tuple(range(6))), *args)
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert f"f32[{batch},64,64,64,128]" in text
+
+
+def test_ssd_under_a_sharded_jit(topo, on_tpu):
+    """Each device runs the scan on its own sequences; A and D are whole
+    on every device and their gradients are summed over all."""
+    from ray_tpu.ops import partition, ssd
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "fsdp"))
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+    whole = NamedSharding(mesh, P())
+
+    def spec(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    t, f32, bf16 = 1024, jnp.float32, jnp.bfloat16
+    args = (spec((4, t, 64, 64), bf16, rows), spec((4, t, 64), f32, rows),
+            spec((64,), f32, whole), spec((4, t, 8, 128), bf16, rows),
+            spec((4, t, 8, 128), bf16, rows), spec((64,), f32, whole))
+
+    def grads(*a):
+        with partition.batch_sharded(mesh, P(("data", "fsdp"))):
+            return jax.grad(lambda *a: ssd.ssd(*a).astype(f32).sum(),
+                            tuple(range(6)))(*a)
+
+    text = _compiled_text(grads, *args)
+    assert text.count("tpu_custom_call") == 2
+    assert "bf16[1,1024,4096]" in text          # one sequence a device
+
+
+def _ssd_case():
+    """Seeded inputs at Nemotron's widths, 1024 positions: (x, dt, A, B,
+    C, D) with dt and A in Mamba-2's own ranges."""
+    keys = jax.random.split(jax.random.key(11), 6)
+    b, t = 1, 1024
+    x = jax.random.normal(keys[0], (b, t, 64, 64), jnp.float32)
+    dt = jnp.exp(jax.random.uniform(keys[1], (b, t, 64), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(0.1)))
+    a = -jax.random.uniform(keys[2], (64,), jnp.float32, 1, 16)
+    bm = jax.random.normal(keys[3], (b, t, 8, 128), jnp.float32)
+    cm = jax.random.normal(keys[4], (b, t, 8, 128), jnp.float32)
+    return x, dt, a, bm, cm, jnp.ones((64,)), jax.random.normal(
+        keys[5], (b, t, 64, 64), jnp.float32)
+
+
+def test_ssd_runs_on_the_chip():
+    """On a chip: the kernels' values and six gradients in bf16 against
+    the plain chunked form in float32, 1024 positions of Nemotron's
+    widths."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import ssd
+
+    *args, w = _ssd_case()
+    low = tuple(z.astype(jnp.bfloat16) if i in (0, 3, 4) else z
+                for i, z in enumerate(args))
+    exact = tuple(z.astype(jnp.float32) for z in low)
+
+    def both(fn, args):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+            tuple(range(6))))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        want, g_want = both(ssd.ssd_xla, exact)
+    got, g_got = both(ssd.ssd, low)
+    errs = {"y": abs(float(got - want)) / abs(float(want))}
+    for name, g, r in zip("x dt A B C D".split(), g_got, g_want):
+        errs[name] = _rel_err(g, r)
+    print("ssd", errs)
+    assert max(errs.values()) < 0.02, errs
 
 
 def _short_conv_grads(bcx, taps, w):
