@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import flash_attention, masked_attention
+from ray_tpu.ops.attention import (SAVED_ACROSS_REMAT, flash_attention,
+                                   masked_attention)
 from ray_tpu.ops.layernorm import layernorm
 
 
@@ -147,13 +148,22 @@ def _block(x, p, cfg: TransformerConfig, pad_mask=None):
         return x + y
 
 
+# `_block` under cfg.remat: everything in it is recomputed in the backward
+# but what the attention kernel produced. Its output and row log-sum-exp
+# (the backward kernel's residuals beside q, k, v) are stacked over the
+# layers, so `flash_fwd` runs once a step, not twice. The padded-batch
+# path has no kernel and no such names: there the policy saves nothing.
+_remat_block = jax.checkpoint(
+    _block, static_argnums=(2,),
+    policy=jax.checkpoint_policies.save_only_these_names(
+        *SAVED_ACROSS_REMAT))
+
+
 def encode(params, x, cfg: TransformerConfig, pad_mask=None):
     """The shared encoder trunk: scan the stacked blocks (remat per
     cfg.remat) then final layernorm. `params` is the full tree from init()
     (uses "blocks"/"lnf_w"/"lnf_b"). Used by GPT here and by bert/vit."""
-    block_fn = _block
-    if cfg.remat:
-        block_fn = jax.checkpoint(block_fn, static_argnums=(2,))
+    block_fn = _remat_block if cfg.remat else _block
 
     def scan_body(x, p):
         return block_fn(x, p, cfg, pad_mask), None

@@ -62,6 +62,7 @@ import logging
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -77,6 +78,13 @@ NEG_INF = -1e30
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
 # ... and the forward under two widths: k and v of a whole sequence
 _FWD_WIDE_VMEM_LIMIT = 48 * 1024 * 1024
+# The names the forward under a gradient gives the two residuals its
+# kernel produced: the output as the caller sees it ([B, T, H, D_v]) and
+# the row log-sum-exp as `_bwd` reads it ([B, H, T] float32). A block
+# rematerialised under `save_only_these_names(*SAVED_ACROSS_REMAT)` keeps
+# both and runs `flash_fwd` once a step (`models/transformer.py` does);
+# with no policy asking for them the names lower to nothing.
+SAVED_ACROSS_REMAT = ("flash_attention_out", "flash_attention_lse")
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
@@ -337,6 +345,9 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, window):
                                block_q=block_q, block_k=block_k,
                                interpret=not is_tpu(), window=window,
                                save_lse=True)
+    if lse is not None:   # the kernel ran: name what it produced
+        out = checkpoint_name(out, SAVED_ACROSS_REMAT[0])
+        lse = checkpoint_name(lse, SAVED_ACROSS_REMAT[1])
     return out, (q, k, v, out, lse)
 
 

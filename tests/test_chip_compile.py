@@ -328,6 +328,59 @@ def test_flash_attention_two_widths_runs_on_the_chip():
     assert max(errs.values()) < 0.01, errs
 
 
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-chip", "fsdp4"])
+def test_gpt_step_runs_the_forward_kernel_once(topo, on_tpu, sharded):
+    """A GPT step at GPT-2 small's widths (two layers), as the chip's
+    compiler leaves it: the block is rematerialised but for what
+    `flash_fwd` produced, so the forward loop holds the one forward
+    kernel and stacks its output and log-sum-exp over the layers, and
+    the backward loop holds `flash_bwd_fused` and no second forward."""
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    from ray_tpu.ops import partition
+    from ray_tpu.parallel import mesh as meshlib
+
+    cfg = dataclasses.replace(transformer.GPT2_SMALL, n_layers=2)
+    batch, seq = 8, 1024
+    params = jax.eval_shape(lambda key: transformer.init(key, cfg),
+                            jax.random.key(0))
+    if sharded:
+        mesh = meshlib.fsdp_mesh(topo.devices)
+        batch_spec = P(("data", "fsdp"))
+        where = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            meshlib.fsdp_param_specs(params, mesh),
+            is_leaf=lambda x: isinstance(x, P))
+        rows = NamedSharding(mesh, batch_spec)
+    else:
+        rows = SingleDeviceSharding(topo.devices[0])
+        where = jax.tree.map(lambda _: rows, params)
+    params = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        params, where)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rows)
+
+    def step(params, tokens):
+        grad = jax.value_and_grad(
+            lambda p: transformer.loss_fn(p, tokens, cfg))
+        if not sharded:
+            return grad(params)
+        with partition.batch_sharded(mesh, batch_spec):
+            return grad(params)
+
+    text = _compiled_text(step, params, tokens)
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("flash_fwd", "flash_bwd_fused"):
+        assert sum(f"/{name}/pallas_call" in k for k in kernels) == 1
+    per_chip = batch // (4 if sharded else 1)
+    heads, width = cfg.n_heads, cfg.head_dim
+    assert f"bf16[2,{per_chip},{seq},{heads},{width}]" in text
+    assert f"f32[2,{per_chip},{heads},{seq}]" in text
+
+
 def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
     """The dropless expert layer at SmallThinker's widths (16 held
     experts of 2560 -> 768, top-6 of 64) over 8 192 tokens: the forward

@@ -305,10 +305,15 @@ def test_flash_attention_refuses_widths_that_do_not_pair():
 PARENT_JAXPR = {
     "grouped-window": "c05dff12821ed4d69dcca4aeb4890f868754be1d557a756df515e4cfa564e7d9",
     "plain": "ae53d69a3129a30973a4efac128227349180302c5a77d7712494206cd13445f1"}
+# ... and with the two `name` equations `_fwd` gives the kernel's output
+# and log-sum-exp since PR 40: the text a trace has now. PARENT_JAXPR is
+# that text without them, so a change to the kernels still shows.
+NAMED_JAXPR = {
+    "grouped-window": "9218dd370a9e724d5dc31bcf81673301ed191629853f4dc3aeb5f36e783a2328",
+    "plain": "18c40e00b24971266a67ec6812d8d761812eb91a4f2b4e1779bedcdd175e36c0"}
 
 
-@pytest.mark.parametrize("case", list(PARENT_JAXPR))
-def test_equal_widths_trace_to_the_parents_program(case):
+def _equal_widths_jaxpr(case):
     h_kv, window = (2, 16) if case == "grouped-window" else (4, None)
     q = jnp.zeros((2, 64, 4, 16))
     kv = jnp.zeros((2, 64, h_kv, 16))
@@ -316,4 +321,15 @@ def test_equal_widths_trace_to_the_parents_program(case):
         lambda q, k, v: attention.flash_attention(
             q, k, v, True, None, 16, 32, window).sum(), (0, 1, 2)))(
                 q, kv, kv))
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_JAXPR[case]
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("case", list(PARENT_JAXPR))
+def test_equal_widths_trace_to_the_parents_program(case, monkeypatch):
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    assert _equal_widths_jaxpr(case) == PARENT_JAXPR[case]
+
+
+@pytest.mark.parametrize("case", list(NAMED_JAXPR))
+def test_equal_widths_trace_to_the_named_program(case):
+    assert _equal_widths_jaxpr(case) == NAMED_JAXPR[case]

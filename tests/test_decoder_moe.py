@@ -17,6 +17,7 @@ of `test_mutation_is_told_apart` moves (rotary off on one window layer:
 import dataclasses
 import functools
 import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ import pytest
 
 from benchmark.families import smallthinker_reference as reference
 from ray_tpu.models import decoder
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.parallel.moe import dropless_moe
 
@@ -311,6 +313,10 @@ def test_window_and_grouped_gradient_is_two_kernels(window, kv_heads):
 # output since PR 32, and that PR's backward kernel. Change it only with
 # a change MEANT to alter the kernels the GPT-2 cells run.
 PLAIN_JAXPR = "45053867e5bc47015ccbb83a40639f10dc9b1da77ccc23031e04f24da871a3c4"
+# ... and with the two `name` equations `_fwd` gives the kernel's output
+# and log-sum-exp since PR 40 (`attention.SAVED_ACROSS_REMAT`), which is
+# the text a trace has now; PLAIN_JAXPR is that text without them
+NAMED_JAXPR = "bec32480dfc7cea1a5b3f936967387c6754570a4e0312d6363a06d29ad02584c"
 
 
 def _plain_jaxpr(*extra):
@@ -320,13 +326,35 @@ def _plain_jaxpr(*extra):
             jnp.float32).sum(), (0, 1, 2)))(qkv, qkv, qkv))
 
 
-def test_window_none_is_the_program_the_gpt_cells_ran():
+def test_window_none_is_the_program_the_gpt_cells_ran(monkeypatch):
     """With `window=None` and equal head counts the traced program,
     forward and backward kernel, is the recorded one, text for text,
-    however the later arguments are spelled."""
+    however the later arguments are spelled: the kernels' text is the
+    one recorded before the residuals had names, once the names are
+    taken out."""
     text = _plain_jaxpr()
-    assert hashlib.sha256(text.encode()).hexdigest() == PLAIN_JAXPR
+    assert hashlib.sha256(text.encode()).hexdigest() == NAMED_JAXPR
     assert _plain_jaxpr(None, 128, 128, None) == text
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    unnamed = _plain_jaxpr()
+    assert hashlib.sha256(unnamed.encode()).hexdigest() == PLAIN_JAXPR
+
+
+def test_the_names_are_all_that_the_recorded_text_gained(monkeypatch):
+    """The two texts part in two `name` equations (and the letters of
+    the variables after them), nothing else: the same primitives in the
+    same order."""
+    def primitives(text):
+        return re.findall(r" = (\w+)[\[ ]", text)
+
+    named = _plain_jaxpr()
+    monkeypatch.setattr(attention, "checkpoint_name", lambda x, name: x)
+    unnamed = _plain_jaxpr()
+    assert [p for p in primitives(named) if p != "name"] == primitives(
+        unnamed)
+    assert primitives(named).count("name") == 2
+    assert sorted(re.findall(r"name\[name=(\w+)\]", named)) == sorted(
+        attention.SAVED_ACROSS_REMAT)
 
 
 TINY_SHARE = dataclasses.replace(decoder.TINY, held=(0, 4))
