@@ -16,8 +16,13 @@ probes again.
 
 from __future__ import annotations
 
+import errno
 import glob
+import logging
 import os
+import time
+
+logger = logging.getLogger("ray_tpu.accelerator")
 
 _GOOGLE_PCI_VENDOR = "0x1ae0"
 # TPU accelerator functions by PCI device id (v2/v3 .. v5e, v6e, 7x): the
@@ -34,15 +39,16 @@ def _read(path: str) -> str:
         return ""
 
 
-def count_tpu_chips() -> int:
-    """TPU chips this machine lets a process open, counted WITHOUT
-    loading libtpu or initialising JAX (either would claim the chip for
-    the caller). A chip is a PCI function with Google's vendor id and a
-    TPU device id whose device node exists: its VFIO group under
-    /dev/vfio (v5e and later), or an /dev/accel* node (earlier
-    generations). The PCI bus alone over-counts — a one-chip machine
-    cut from a four-chip host lists four functions and one node — and
-    an installed ``libtpu`` package is no evidence of a chip at all."""
+def tpu_device_nodes() -> list[str]:
+    """The device nodes of the TPU chips this machine lets a process
+    open, found WITHOUT loading libtpu or initialising JAX (either would
+    claim the chip for the caller). A chip is a PCI function with
+    Google's vendor id and a TPU device id whose device node exists: its
+    VFIO group under /dev/vfio (v5e and later), or an /dev/accel* node
+    (earlier generations). The PCI bus alone over-counts — a one-chip
+    machine cut from a four-chip host lists four functions and one node
+    — and an installed ``libtpu`` package is no evidence of a chip at
+    all."""
     functions = [
         os.path.dirname(vendor)
         for vendor in glob.glob("/sys/bus/pci/devices/*/vendor")
@@ -53,11 +59,66 @@ def count_tpu_chips() -> int:
         os.path.join(f, "iommu_group"))) for f in functions
         if os.path.exists(os.path.join(f, "iommu_group"))}
     if groups:
-        nodes = sum(os.path.exists(f"/dev/vfio/{g}") for g in groups)
+        nodes = [f"/dev/vfio/{g}" for g in sorted(groups)
+                 if os.path.exists(f"/dev/vfio/{g}")]
     else:  # a sysfs without group links: every numbered VFIO node
-        nodes = len(glob.glob("/dev/vfio/[0-9]*"))
-    return min(len(functions),
-               nodes or len(glob.glob("/dev/accel[0-9]*")))
+        nodes = sorted(glob.glob("/dev/vfio/[0-9]*"))
+    nodes = nodes or sorted(glob.glob("/dev/accel[0-9]*"))
+    return nodes[:len(functions)]
+
+
+def count_tpu_chips() -> int:
+    """What the ``TPU`` resource of a node is counted from."""
+    return len(tpu_device_nodes())
+
+
+# How long a chip-owning worker waits for device nodes that another
+# process still holds. A VFIO group opens once at a time, and a worker
+# that ended keeps its nodes until the kernel has unpinned its memory:
+# 17-24 s after a four-chip run (PERF.md section 6, PR 46).
+CHIP_WAIT_BOUND_S = 60.0
+
+
+def held_nodes(nodes: list[str]) -> list[str]:
+    """Those of `nodes` that cannot be opened because another process
+    holds them (EBUSY, and that error alone). Only the process that is
+    about to own the chips asks: an open node is busy to everyone else,
+    for the moment it stays open here."""
+    held = []
+    for node in nodes:
+        try:
+            os.close(os.open(node, os.O_RDWR))
+        except OSError as e:
+            if e.errno == errno.EBUSY:
+                held.append(node)
+    return held
+
+
+def wait_for_chips(probe, bound: float = CHIP_WAIT_BOUND_S,
+                   pause: float = 0.25) -> float:
+    """A chip-owning worker's wait for chips that are still being
+    released, before it initialises the backend: polls `probe()` (the
+    nodes still held) until it is empty or `bound` seconds have passed,
+    and returns the seconds that took, the first probe included (under
+    gVisor the open of a held node itself blocks for up to 3 s, and may
+    come back free) — next to nothing, with nothing logged, when the
+    chips are free at once. Past the bound the worker starts all the
+    same and fails with libtpu's own error, as it would have without the
+    wait."""
+    t0 = time.monotonic()
+    held = probe()
+    while held and time.monotonic() - t0 < bound:
+        time.sleep(pause)
+        held = probe()
+    waited = time.monotonic() - t0
+    if held:
+        logger.warning("waited %.1f s for the chip and %s still held by "
+                       "another process: starting all the same", waited,
+                       ", ".join(held))
+    elif waited >= 1.0:  # a probe of free nodes takes milliseconds
+        logger.info("waited %.1f s for the chip: its device nodes were "
+                    "still held by a process that was ending", waited)
+    return waited
 
 
 def tpu_worker_jax_platforms() -> str:

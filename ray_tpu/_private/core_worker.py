@@ -278,7 +278,7 @@ class CoreWorker:
         self._executing: dict[int, dict] = {}
         self._exec_seq = itertools.count(1)
         self._shutdown = False
-        self._exiting = False
+        self.before_user_code = None  # see _before_user_code
 
         # profiling (reference: core_worker profiling.h:28 — spans batched
         # to the GCS profile table; api.timeline() renders them)
@@ -336,7 +336,6 @@ class CoreWorker:
             "recover_object": self.h_recover_object,
             "add_borrow": self.h_add_borrow,
             "remove_borrow": self.h_remove_borrow,
-            "exit": self.h_exit,
             "checkpoint_actor": self.h_checkpoint_actor,
             "cancel_task": self.h_cancel_task,
             "get_stats": self.h_get_stats,
@@ -2855,6 +2854,7 @@ class CoreWorker:
                                           kind="cls")
                 self.actor_resources = common.ResourceSet.from_raw(
                     spec.get("resources") or {}).to_dict()
+                self._before_user_code()
                 self._actor_instance = cls(*args, **kwargs)
                 self._actor_id = ActorID(spec["actor_id"])
                 if spec.get("restore"):
@@ -2873,6 +2873,7 @@ class CoreWorker:
                 result = self._run_callable(method, args, kwargs)
             else:
                 fn = self.fetch_function(spec["fn_id"], spec["job_id"])
+                self._before_user_code()
                 result = self._run_callable(fn, args, kwargs)
             return self._pack_returns(spec, result)
         except exc.TaskCancelledError:
@@ -2918,6 +2919,17 @@ class CoreWorker:
             return
         if data is not None:
             hook(serialization.loads(data))
+
+    def _before_user_code(self):
+        """Once, in a chip-owning worker: the wait for chips that another
+        process is still releasing (`worker/main.py` sets it,
+        `accelerator.wait_for_chips`). Here and not at the worker's
+        start: only the user's code can claim the chips, so everything
+        before it (registering, the lease, loading what the task
+        imports) runs while the other process ends."""
+        wait, self.before_user_code = self.before_user_code, None
+        if wait is not None:
+            wait()
 
     def _run_callable(self, fn, args, kwargs):
         import inspect
@@ -3020,17 +3032,6 @@ class CoreWorker:
             return {"state": None}
         state = await asyncio.get_running_loop().run_in_executor(None, hook)
         return {"state": serialization.dumps(state)}
-
-    async def h_exit(self, conn, d):
-        self._exiting = True
-        self._shutdown = True
-
-        def _die():
-            time.sleep(0.1)
-            os._exit(0)
-
-        threading.Thread(target=_die, daemon=True).start()
-        return True
 
     async def h_cancel_task(self, conn, d):
         # Best-effort: only tasks still queued (not yet executing) can be

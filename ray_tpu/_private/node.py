@@ -45,27 +45,120 @@ def _wait_ready(ready_file: str, proc: subprocess.Popen, what: str,
     raise TimeoutError(f"{what} did not become ready in {timeout}s")
 
 
+# How long the end of a session may take. What takes the time is a
+# chip-owning worker: its device nodes close only when the kernel has
+# unpinned its staging area and DMA mappings, and until then the dying
+# process stays in the process table (PERF.md section 6, PR 46).
+SESSION_END_BOUND_S = 60.0
+
+
+class ProcessesStillAlive(RuntimeError):
+    """Processes that were told to end outlived the bound."""
+
+
+def _stat_fields(path: str) -> list[str] | None:
+    """The fields of a /proc ``stat`` file from the state on (state,
+    ppid, pgrp, ...); None when the task is gone."""
+    try:
+        with open(path) as f:
+            stat = f.read()
+    except OSError:
+        return None
+    # the command name is in brackets and may hold brackets and spaces
+    return stat[stat.rindex(")") + 2:].split()
+
+
+def has_left(pid: int) -> bool:
+    """True when `pid` has left the process table or is a zombie
+    awaiting its parent, and so holds no device node, mapping or socket.
+    A zombie LEADER is not enough: it reads ``Z``, with an empty
+    ``cmdline``, from the moment the process is told to die, while
+    another of its threads is still in its exit, and the descriptors
+    close with the last one (a worker that held four chips and 9 GB of
+    staging: 19-24 s later). The leader's thread count says when that is
+    over, on Linux and under gVisor alike; gVisor, which the chip
+    machines run, lists no ``/proc/<pid>/task`` for a zombie at all."""
+    return _left(_stat_fields(f"/proc/{pid}/stat"))
+
+
+def _left(fields: list[str] | None) -> bool:
+    return fields is None or (fields[0] in "ZX" and int(fields[17]) <= 1)
+
+
+def group_members(pgids) -> list[int]:
+    """The pids of the process groups `pgids` that have not left. A
+    service is its group's leader (`_spawn`) and a raylet's workers stay
+    in its group, so the group is everything the service started."""
+    pgids = {str(g) for g in pgids}
+    members = []
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        fields = _stat_fields(f"/proc/{pid}/stat")
+        if not _left(fields) and fields[2] in pgids:
+            members.append(int(pid))
+    return members
+
+
+def wait_until_left(alive, bound: float = SESSION_END_BOUND_S) -> float:
+    """THE wait for processes that were told to end: returns, with the
+    seconds it took, once `alive()` (the pids still in the process
+    table) is empty; past `bound` it raises, naming them."""
+    t0 = time.monotonic()
+    pause = 0.002
+    while left := alive():
+        waited = time.monotonic() - t0
+        if waited > bound:
+            named = []
+            for pid in left:
+                fields = _stat_fields(f"/proc/{pid}/stat")
+                named.append(f"{pid} (state {fields[0] if fields else '?'})")
+            raise ProcessesStillAlive(
+                f"{len(left)} process(es) still in the process table "
+                f"{waited:.1f} s after they were told to end: "
+                + ", ".join(named))
+        time.sleep(pause)
+        pause = min(pause * 2, 0.05)
+    return time.monotonic() - t0
+
+
+def end_process_groups(pgids, sig=signal.SIGKILL,
+                       bound: float = SESSION_END_BOUND_S) -> float:
+    """Signal every process group in `pgids` and wait until all their
+    members have left (`wait_until_left`)."""
+    pgids = list(pgids)
+    for pgid in pgids:
+        try:
+            os.killpg(pgid, sig)
+        except (ProcessLookupError, PermissionError):
+            pass
+    return wait_until_left(lambda: group_members(pgids), bound)
+
+
 class ServiceProcess:
     def __init__(self, name: str, proc: subprocess.Popen):
         self.name = name
         self.proc = proc
 
     def alive(self) -> bool:
-        return self.proc.poll() is None
+        # asked of the process table, not of `proc.poll()`: a service
+        # that died stays an unreaped zombie until `end_services`, so
+        # its pid, which names its group, cannot be given to another
+        return not has_left(self.proc.pid)
 
     def kill(self, sig=signal.SIGKILL):
-        if self.alive():
-            try:
-                os.killpg(os.getpgid(self.proc.pid), sig)
-            except (ProcessLookupError, PermissionError):
-                try:
-                    self.proc.kill()
-                except ProcessLookupError:
-                    pass
-        try:
-            self.proc.wait(timeout=5)
-        except subprocess.TimeoutExpired:
-            pass
+        end_services([self], sig)
+
+
+def end_services(services, sig=signal.SIGKILL) -> float:
+    """End services and everything they started; return, with the
+    seconds it took, when all of it has left the process table. A group
+    outlives a leader that exited by itself (a drained or fail-stopped
+    raylet's workers), so it is signalled whether or not the service is
+    alive; a service that was reaped here before is skipped."""
+    services = [svc for svc in services if svc.proc.returncode is None]
+    took = end_process_groups([svc.proc.pid for svc in services], sig)
+    for svc in services:
+        svc.proc.wait()  # a zombie by now: reaped at once
+    return took
 
 
 def _spawn(cmd: list[str], config: Config, name: str) -> ServiceProcess:
@@ -260,7 +353,7 @@ class Node:
                     if self._stopping:
                         continue
                     logger.warning("GCS exited (rc=%s); restarting on %s",
-                                   gcs.proc.returncode, self.gcs_address)
+                                   gcs.proc.poll(), self.gcs_address)
                     try:
                         new = restart_gcs(self.session_dir, self.config,
                                           self.gcs_address,
@@ -299,7 +392,7 @@ class Node:
             if index is None:
                 continue
             logger.warning("GCS shard %d exited (rc=%s); restarting on "
-                           "port %d", index, svc.proc.returncode, port)
+                           "port %d", index, svc.proc.poll(), port)
             try:
                 new, _addr = start_gcs_shard(self.session_dir, self.config,
                                              index, port=port)
@@ -318,18 +411,22 @@ class Node:
                     self.processes.append(new)
 
     def kill_all_processes(self):
+        """The one place a session ends. On return no process it started
+        (service or worker, registered or still starting) is left in the
+        process table other than as a zombie: its chips, arena mappings
+        and sockets are free."""
         self._stopping = True
-        for svc in reversed(self.processes):
-            svc.kill()
-        self.processes.clear()
-        if self.is_head:
-            # the session's shared memory goes with its services: the
-            # object store's arena file (2 GiB by default, as resident
-            # as the run made it) and the collective segments beside it
-            # are nobody's once the raylet is dead. A process that still
-            # maps the arena keeps its mapping until it exits.
-            shutil.rmtree(os.path.dirname(default_store_root(
-                self.session_dir)), ignore_errors=True)
+        services, self.processes = self.processes, []
+        try:
+            end_services(reversed(services))
+        finally:
+            if self.is_head:
+                # the session's shared memory goes with its services:
+                # the object store's arena file (2 GiB by default, as
+                # resident as the run made it) and the collective
+                # segments beside it are nobody's once they are dead
+                shutil.rmtree(os.path.dirname(default_store_root(
+                    self.session_dir)), ignore_errors=True)
 
     def kill_gcs(self):
         """Fault injection: kill the GCS process (it will be auto-restarted

@@ -10,6 +10,7 @@ from ray_tpu._private.config import Config, set_config
 from ray_tpu._private.node import (
     Node,
     ServiceProcess,
+    end_services,
     new_session_dir,
     start_gcs,
     start_gcs_shard,
@@ -103,12 +104,14 @@ class Cluster:
         return svc
 
     def shutdown(self):
-        for node in reversed(self.nodes):
-            node.kill()
-        self.nodes.clear()
+        """Every node, the GCS and its shards, ended together: one wait
+        (`node.end_services`), and on return none of them, nor anything
+        they started, is left in the process table."""
+        services = [node.svc for node in reversed(self.nodes)]
         if self.gcs_svc is not None:
-            self.gcs_svc.kill()
-            self.gcs_svc = None
-        for svc in self.shard_procs:
-            svc.kill()
-        self.shard_procs.clear()
+            services.append(self.gcs_svc)
+        services += self.shard_procs
+        self.nodes.clear()
+        self.gcs_svc = None
+        self.shard_procs = []
+        end_services(services)

@@ -35,6 +35,7 @@ from ray_tpu._private import tracing
 from ray_tpu._private.common import InsufficientResources, ResourceSet
 from ray_tpu._private.config import Config, get_config, set_config
 from ray_tpu._private.ids import NodeID, ObjectID
+from ray_tpu._private.node import has_left, wait_until_left
 from ray_tpu._private.object_store import make_store
 from ray_tpu.raylet import transfer
 
@@ -284,9 +285,12 @@ class Raylet:
         # initialise the TPU backend; every other worker is started with
         # JAX_PLATFORMS=cpu SET (an inherited "" or "tpu" would let JAX
         # auto-select the chip and take libtpu's lock away from the
-        # worker that was leased it). No probe here: a worker leased a
-        # chip that cannot be opened fails with libtpu's error. The
-        # worker echoes its flavour back at registration.
+        # worker that was leased it). Nothing is probed here: the
+        # TPU-flavour worker itself waits, bounded, for device nodes
+        # that a process that is ending still holds (`worker/main.py`,
+        # `accelerator.wait_for_chips`); a chip that cannot be opened
+        # for another reason fails with libtpu's error. The worker
+        # echoes its flavour back at registration.
         env["JAX_PLATFORMS"] = self.tpu_worker_platforms if tpu else "cpu"
         env["RAY_TPU_WORKER_FLAVOR"] = "tpu" if tpu else "cpu"
         cmd = [
@@ -301,10 +305,12 @@ class Raylet:
         # stderr lands in the worker's log file so crashes (uncaught
         # tracebacks, aborts) are diagnosable post-mortem.
         errf = open(log_file + ".err", "ab") if log_file else subprocess.DEVNULL
+        # no session of its own: a worker stays in this raylet's process
+        # group (the raylet leads one, `node._spawn`), which is how the
+        # node finds and ends everything the raylet started
         proc = subprocess.Popen(
             cmd, env=env,
-            stdout=subprocess.DEVNULL, stderr=errf,
-            start_new_session=True)
+            stdout=subprocess.DEVNULL, stderr=errf)
         if errf is not subprocess.DEVNULL:
             errf.close()
         self._starting_procs.append((proc, "tpu" if tpu else "cpu"))
@@ -1115,23 +1121,27 @@ class Raylet:
                 "task_channel": worker.task_channel}
 
     async def h_kill_actor_worker(self, conn, d):
+        """`kill()` is forceful, and done when it is answered: SIGKILL,
+        and the reply once the worker has left the process table (its
+        chips and arena mappings are free: `node.wait_until_left`) and
+        its lease is back. Nothing is left to a timer that would die
+        with this raylet when `shutdown()` is the caller's next line."""
         worker = self.workers.get(d["worker_id"])
         if worker is None:
             return False
         worker.conn.context["intended_exit"] = True
         try:
-            await worker.conn.notify("exit", {"reason": "killed"})
-        except Exception:
+            os.kill(worker.pid, signal.SIGKILL)
+        except ProcessLookupError:
             pass
-
-        async def _force_kill():
-            await asyncio.sleep(2.0)
-            try:
-                os.kill(worker.pid, 9)
-            except ProcessLookupError:
-                pass
-
-        asyncio.create_task(_force_kill())
+        took = await asyncio.get_running_loop().run_in_executor(
+            None, wait_until_left,
+            lambda: [] if has_left(worker.pid) else [worker.pid])
+        while (worker.worker_id in self.workers
+               and not self._shutting_down):
+            await asyncio.sleep(0.002)  # its connection's end, just behind
+        logger.info("killed worker pid=%d: it left the process table "
+                    "after %.2f s", worker.pid, took)
         return True
 
     async def h_actor_exiting(self, conn, d):

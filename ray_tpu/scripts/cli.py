@@ -20,7 +20,6 @@ import os
 import signal
 import subprocess
 import sys
-import time
 
 
 def _tmpdir() -> str:
@@ -147,28 +146,25 @@ def cmd_stop(args) -> int:
     if rec is None:
         print("no cluster record found; nothing to stop")
         return 0
-    killed = 0
-    for pid in rec.get("pids", []):
-        try:
-            os.killpg(os.getpgid(pid), signal.SIGTERM)
-            killed += 1
-        except (ProcessLookupError, PermissionError):
-            try:
-                os.kill(pid, signal.SIGTERM)
-                killed += 1
-            except (ProcessLookupError, PermissionError):
-                pass
-    time.sleep(0.5)
-    for pid in rec.get("pids", []):
-        try:
-            os.killpg(os.getpgid(pid), signal.SIGKILL)
-        except (ProcessLookupError, PermissionError):
-            pass
+    from ray_tpu._private.node import (
+        ProcessesStillAlive,
+        end_process_groups,
+        has_left,
+    )
+
+    # the recorded pids are the services, each the leader of the group
+    # that holds everything it started (a raylet's workers)
+    pids = rec.get("pids", [])
+    stopped = sum(not has_left(pid) for pid in pids)
+    try:
+        end_process_groups(pids, signal.SIGTERM, bound=5.0)
+    except ProcessesStillAlive:  # user code may hold SIGTERM off
+        end_process_groups(pids, signal.SIGKILL)
     try:
         os.unlink(_cluster_file())
     except FileNotFoundError:
         pass
-    print(f"stopped {killed} process group(s)")
+    print(f"stopped {stopped} process group(s)")
     return 0
 
 

@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 
 
 def main():
@@ -47,6 +48,17 @@ def main():
     from ray_tpu._private.log_utils import install_stdout_forwarder
 
     install_stdout_forwarder(cw)
+    if (os.environ.get("RAY_TPU_WORKER_FLAVOR") == "tpu"
+            and os.environ.get("JAX_PLATFORMS") != "cpu"):
+        # the chips this worker is leased may still be held by a worker
+        # that is ending (another session's, or a killed one's of this
+        # session): it waits for them, bounded, before the first user
+        # code runs, since that is what initialises the backend
+        from ray_tpu._private import accelerator
+
+        nodes = accelerator.tpu_device_nodes()
+        cw.before_user_code = lambda: accelerator.wait_for_chips(
+            lambda: accelerator.held_nodes(nodes))
     logging.getLogger("ray_tpu.worker").info(
         "worker %s registered with raylet %s",
         cw.worker_id.hex()[:8], args.raylet_address)

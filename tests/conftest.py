@@ -339,11 +339,11 @@ def leak_check(request):
 
     if global_state.get_core_worker() is not None:
         return  # a (module-scoped) cluster is legitimately still up
-    # Covers the slowest legitimate death (only ever waited out when
-    # something is still dying — the loop exits as soon as the diff is
-    # clean): a worker spawned just before teardown pays its jax import
-    # (~2s) plus fast-fail dials to the dead gcs/raylet, and force-kill
-    # paths (actor kill grace) add a couple seconds on a loaded box.
+    # A net under `shutdown()`, which since PR 46 returns only when its
+    # processes have left the process table: the wait below is entered
+    # only by a test that ends a cluster some other way (a raylet that
+    # drained or fail-stopped by itself leaves its workers to their own
+    # exit), and the loop exits as soon as the diff is clean.
     deadline = time.monotonic() + scale_timeout(20)
     leaked = {}
     while True:

@@ -354,7 +354,21 @@ def test_cli_state_stack_doctor(ray_start_regular, capsys):
         return 1
 
     ref = snooze.remote(scale_timeout(6))
-    time.sleep(scale_timeout(1.5))  # let it reach a worker
+
+    def executing_for():
+        snap = debug_state.collect_via_rpc(addr)
+        return max((task["age_s"]
+                    for _, proc in debug_state.iter_processes(snap)
+                    if proc.get("role") == "worker"
+                    for task in proc.get("executing") or ()), default=0.0)
+
+    # the doctor below flags what has been executing for its floor of
+    # 0.5 s: wait for that, not for a guess at how long a worker takes to
+    # start (a sleep of 1.5 s here left 0.0 to 0.4 s over the floor)
+    deadline = time.monotonic() + scale_timeout(30)
+    while executing_for() < 0.6:
+        assert time.monotonic() < deadline, "snooze never reached a worker"
+        time.sleep(0.05)
 
     assert cli.main(["state", "--address", addr]) == 0
     out = capsys.readouterr().out

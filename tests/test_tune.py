@@ -147,17 +147,44 @@ def test_median_stopping(ray_start_shared, tmp_path):
     assert [iters[x] for x in (3, 3.1, 2.9)] == [horizon] * 3
 
 
-def test_pbt_perturbs_and_improves(ray_start_shared):
-    class Noisy(tune.Trainable):
+def test_pbt_perturbs_and_improves(ray_start_shared, tmp_path):
+    class Gated(tune.Trainable):
+        """PBT needs a coexisting population, and gets it from two gates
+        in `gate` (a directory) instead of from step times: nobody takes
+        a second step before all four have taken their first, and the
+        good trials take their tenth (the one after a checkpoint, so a
+        donor is never asked to save while it waits) only once the
+        hopeless one has been restored from a donor. Which actor the box
+        runs first decides nothing."""
+
         def setup(self, config):
             self.level = 0.0
+            self.steps = 0
+            self.gate = config["gate"]
+            self.hopeless = config["rate"] < 0.1
 
-        def step(self):
+        def _wait_for(self, prefix, n):
+            import os
             import time
 
-            # PBT needs a coexisting population: step time must dominate
-            # actor-startup stagger (true for any real training workload).
-            time.sleep(0.25)
+            deadline = time.monotonic() + 120
+            while (sum(f.startswith(prefix) for f in os.listdir(self.gate))
+                   < n and time.monotonic() < deadline):
+                time.sleep(0.02)
+
+        def _mark(self, name):
+            import os
+
+            open(os.path.join(self.gate, name), "w").close()
+
+        def step(self):
+            self.steps += 1
+            if self.steps == 1:
+                self._mark(f"started-{self.config['rate']}")
+            elif self.steps == 2:
+                self._wait_for("started-", 4)
+            elif self.steps == 10 and not self.hopeless:
+                self._wait_for("restored", 1)
             self.level += self.config["rate"]
             return {"level": self.level}
 
@@ -166,6 +193,7 @@ def test_pbt_perturbs_and_improves(ray_start_shared):
 
         def load_checkpoint(self, state):
             self.level = state["level"]
+            self._mark("restored")
 
         def reset_config(self, new_config):
             return True
@@ -174,15 +202,18 @@ def test_pbt_perturbs_and_improves(ray_start_shared):
         metric="level", mode="max", perturbation_interval=3,
         hyperparam_mutations={"rate": tune.uniform(0.1, 1.0)}, seed=0)
     analysis = tune.run(
-        Noisy,
-        config={"rate": tune.grid_search([0.01, 0.02, 0.9, 1.0])},
+        Gated,
+        config={"rate": tune.grid_search([0.01, 0.8, 0.9, 1.0]),
+                "gate": str(tmp_path)},
         stop={"training_iteration": 12},
         scheduler=pbt, checkpoint_freq=3,
         metric="level", mode="max")
     assert pbt.perturbations >= 1
-    # losers adopted winner configs: final rates should cluster high
+    # the loser adopted a winner's configuration, explored (x0.8 at the
+    # least, or drawn anew from 0.1 up), and its level with it
     rates = sorted(t.config["rate"] for t in analysis.trials)
-    assert rates[0] > 0.02 or rates[1] > 0.02
+    assert rates[0] >= 0.1
+    assert min(t.last_result["level"] for t in analysis.trials) > 0.8
 
 
 def test_trial_failure_raises(ray_start_shared):
