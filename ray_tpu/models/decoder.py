@@ -67,8 +67,13 @@ they are gated, `W_down (act(W_gate y) * (W_up y))`, unless `cfg.gated`
 is false: `W_down act(W_up y)`, two matrices and no gate leaf
 (`"relu2"`, the squared ReLU, is such a model's; its routed experts'
 `w_up` is `[held, d_expert, d_model]`, rows as `w_down`'s). The head is the embedding's
-transpose (`cfg.tied_head`) or a matrix of its own. Norms are
-`ops.rmsnorm` (weight only).
+transpose (`cfg.tied_head`) or a matrix of its own, `[d_model, vocab]`
+or, with `cfg.head_rows`, `[vocab, d_model]` as the embedding is: a
+vocabulary slice that is no multiple of the 128 lanes is then nobody's
+minor dimension (the device keeps a leaf whose minor dimension is not,
+after one that is, transposed whatever shape it is given, and the step
+copies it and its moments back and forth: PERF.md section 6, PR 39).
+Norms are `ops.rmsnorm` (weight only).
 
 `cfg.mtp` = 1 adds a multi-token-prediction block after the last layer
 (`params["mtp"]`): position i's last hidden state (before the final
@@ -93,6 +98,25 @@ loss is taken in chunks of tokens, each chunk's logits recomputed in the
 backward pass: at 16 k tokens over 38 k vocabulary rows the float32
 logits alone would be 2.5 GB.
 
+`cfg.diffusion_block` = b > 0 trains the model by BLOCK DIFFUSION
+instead (SDAR, arXiv:2510.06303; the training pass is BD3-LM's,
+arXiv:2503.09573): the objective is a property of the configuration, as
+the layer kinds are. A sequence of L tokens is cut into blocks of b;
+each block draws a masking rate, its tokens are masked at that rate,
+and ONE pass over `[x_0 ; x_t]` — the clean sequence, then its noised
+copy: 2 L rows, both halves at positions 0 .. L - 1 — predicts every
+masked token from its block's noised tokens, seen both ways, and the
+CLEAN tokens of the blocks before it (`ops.flash_attention`'s
+`diffusion` mask; mixers `full` and `none` only, no MTP block). The
+final norm, the head and the chunked loss run over the noised half
+alone; there is no shift: row i predicts token i. The noise is drawn
+INSIDE the step (`diffusion_inputs`), from `fold_in(key(noise_seed),
+noise_step)`: two int32 of model state beside the counters — the seed
+drawn once from the key the weights are drawn from, the step moved by
+`stateful_loss` and, not being an epoch counter, never reset — so a
+snapshot carries where the noise stands. With `diffusion_block` 0 none
+of this is traced and a configuration's program is what it was.
+
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
 scores, the selection bias, the head norms and every norm's statistics
 are float32.
@@ -108,7 +132,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import diffusion_tiles, flash_attention
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
@@ -189,6 +213,9 @@ class DecoderConfig:
     #                                   floor of dt at initialisation
     count_rows: bool = False          # the counters moe_rows_static /
     #                                   _filled (with an MTP block: always)
+    diffusion_block: int = 0          # > 0: trained by block diffusion over
+    #                                   blocks of this many tokens
+    head_rows: bool = False           # the untied head kept [vocab, d_model]
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -228,6 +255,14 @@ class DecoderConfig:
                 "the latent mixer needs q_lora_rank, kv_lora_rank, "
                 "qk_nope_dim, qk_rope_dim (even) and v_head_dim: got "
                 f"{latent}")
+        if self.diffusion_block and (
+                self.diffusion_block < 0 or self.mtp
+                or not mixers <= {"full", "none"}):
+            raise ValueError(
+                "block diffusion (diffusion_block > 0) is built for the "
+                "mixers \"full\" and \"none\" and without an MTP block: a "
+                "window, a convolution or a scan would run across the "
+                "[clean ; noised] halves")
         if self.mtp not in (0, 1) or self.d_shared < 0:
             raise ValueError(
                 f"mtp is 0 or 1 block (got {self.mtp}), d_shared the "
@@ -364,6 +399,7 @@ _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "ws_down", "proj", "ssm_in", "ssm_conv", "ssm_conv_bias", "A_log",
           "dt_bias", "ssm_out")
 _MTP_KEY = 1 << 16
+_NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
 
 def _mtp_leaves(cfg: DecoderConfig) -> dict:
@@ -417,8 +453,9 @@ def init(key, cfg: DecoderConfig):
         "norm_f": jnp.ones((cfg.d_model,)),
     }
     if not cfg.tied_head:
-        params["head"] = draw("head", (cfg.d_model, cfg.vocab_size),
-                              "normal")
+        shape = (cfg.d_model, cfg.vocab_size)
+        params["head"] = draw(
+            "head", shape[::-1] if cfg.head_rows else shape, "normal")
     if cfg.mtp:
         d = cfg.d_model
         params["mtp"] = {
@@ -430,13 +467,23 @@ def init(key, cfg: DecoderConfig):
     return params
 
 
-def rope_tables(t: int, cfg: DecoderConfig, dim: int | None = None):
-    """cos, sin [T, dim / 2] of position * theta ** (-2i / dim), float32;
-    `dim` the turned width: a head's (the default) or the latent mixer's
-    rope part."""
+def rope_tables(positions, cfg: DecoderConfig, dim: int | None = None):
+    """cos, sin [T, dim / 2] of position * theta ** (-2i / dim), float32,
+    for the T `positions` given (float32: a row's place in ITS sequence,
+    which under block diffusion is not its row); `dim` the turned width:
+    a head's (the default) or the latent mixer's rope part."""
+    return _turned(_rope_rates(cfg, dim), positions)
+
+
+def _rope_rates(cfg: DecoderConfig, dim: int | None = None):
+    """theta ** (-2i / dim), [dim / 2]: what a unit of position turns
+    each pair by."""
     half = (cfg.head_dim if dim is None else dim) // 2
-    inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    return cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def _turned(rates, positions):
+    angle = positions[:, None] * rates[None, :]
     return jnp.cos(angle), jnp.sin(angle)
 
 
@@ -594,7 +641,8 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
                 q, k = _rope(q, *rope), _rope(k, *rope)
             a = flash_attention(
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
-                cfg.window if attention == "window" else None)
+                cfg.window if attention == "window" else None,
+                cfg.diffusion_block or None)
             h = h + a.reshape(b, t, cfg.n_heads * hd) @ cast(p["wo"])
     if mlp != "none":
         y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
@@ -620,10 +668,18 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
 
 
 def _rope_for(t: int, cfg: DecoderConfig):
-    """The one rotary table a configuration's mixers turn by: the
-    latent mixer's rope part, or a head."""
+    """The one rotary table a configuration's mixers turn the t rows
+    by: the latent mixer's rope part, or a head. Row i stands at
+    position i; under block diffusion the rows are two copies of t / 2
+    positions and both halves turn alike."""
     latent = "latent" in cfg.attention + cfg.lead_attention
-    return rope_tables(t, cfg, cfg.qk_rope_dim if latent else None)
+    # the rates before the positions: the order the recorded programs
+    # of the configurations that turn were traced in
+    rates = _rope_rates(cfg, cfg.qk_rope_dim if latent else None)
+    if cfg.diffusion_block:
+        return _turned(rates, jnp.tile(
+            jnp.arange(t // 2, dtype=jnp.float32), 2))
+    return _turned(rates, jnp.arange(t, dtype=jnp.float32))
 
 
 def _block(cfg: DecoderConfig, attention: str, mlp: str):
@@ -700,8 +756,11 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
 
 
 def _head(params, cfg: DecoderConfig, dtype):
-    return (params["embed"].T if cfg.tied_head
-            else params["head"]).astype(dtype)
+    """[d_model, vocab] in `dtype`: the embedding's transpose, the head
+    leaf, or its transpose where the leaf is kept outputs-as-rows."""
+    if cfg.tied_head or cfg.head_rows:
+        return params["embed" if cfg.tied_head else "head"].T.astype(dtype)
+    return params["head"].astype(dtype)
 
 
 def apply(params, tokens, cfg: DecoderConfig, bias=None):
@@ -782,25 +841,85 @@ def _mean_nll(x, tokens, ahead: int, params, cfg: DecoderConfig, head=None):
         targets = tokens[:, ahead:].reshape(n)
         if head is None:
             head = _head(params, cfg, x.dtype)
-        chunk = min(cfg.loss_chunk, x.shape[0])
-        pad = -x.shape[0] % chunk
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        weight = jnp.pad(jnp.ones_like(targets, jnp.float32), (0, pad))
-        targets = jnp.pad(targets, (0, pad))
+        return _nll_sum(x, targets, None, head, cfg) / n, head
 
-        @jax.checkpoint
-        def nll_sum(x, targets, weight):
-            logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
-            nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-                logits, targets[:, None], axis=-1)[:, 0]
-            return (nll * weight).sum()
 
-        def body(total, part):
-            return total + nll_sum(*part), None
+def _nll_sum(x, targets, weight, head, cfg: DecoderConfig):
+    """The cross-entropy of row i of x [n, D] (normed) against
+    `targets[i]`, times `weight[i]` (None: one), summed over the rows in
+    chunks of `cfg.loss_chunk`, each chunk's logits recomputed in the
+    backward pass."""
+    chunk = min(cfg.loss_chunk, x.shape[0])
+    pad = -x.shape[0] % chunk
+    x = jnp.pad(x, ((0, pad), (0, 0)))
+    weight = jnp.pad(jnp.ones_like(targets, jnp.float32)
+                     if weight is None else weight, (0, pad))
+    targets = jnp.pad(targets, (0, pad))
 
-        total, _ = lax.scan(body, jnp.zeros((), jnp.float32), tuple(
-            z.reshape(-1, chunk, *z.shape[1:]) for z in (x, targets, weight)))
-        return total / n, head
+    @jax.checkpoint
+    def nll_sum(x, targets, weight):
+        logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
+        nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+            logits, targets[:, None], axis=-1)[:, 0]
+        return (nll * weight).sum()
+
+    def body(total, part):
+        return total + nll_sum(*part), None
+
+    total, _ = lax.scan(body, jnp.zeros((), jnp.float32), tuple(
+        z.reshape(-1, chunk, *z.shape[1:]) for z in (x, targets, weight)))
+    return total
+
+
+# ----------------------------------------------------------------------
+# block diffusion: the noise, the doubled input, the loss
+# ----------------------------------------------------------------------
+
+NOISE_FLOOR = 1e-3      # the least masking rate a block draws
+
+
+def diffusion_inputs(tokens, cfg: DecoderConfig, noise_seed, noise_step):
+    """The noise of step `noise_step` on tokens [B, L] -> (the model's
+    input `[x_0 ; x_t]` [B, 2 L], masked [B, L] bool, p [B, L] float32).
+    With `k0, k1 = split(fold_in(key(noise_seed), noise_step))`: a block
+    draws `t = uniform(k0)` and masks at rate `p = (1 - NOISE_FLOOR) t +
+    NOISE_FLOOR` (one rate a block of `cfg.diffusion_block` tokens, the
+    linear schedule with its floor); a token is masked where
+    `uniform(k1) < p`; a masked token reads MASK, the slice's last id,
+    which the data never draws."""
+    b, length = tokens.shape
+    block = cfg.diffusion_block
+    if length % block:
+        raise ValueError(f"{length} tokens are not whole blocks of {block}")
+    k0, k1 = jax.random.split(jax.random.fold_in(
+        jax.random.key(noise_seed), noise_step))
+    t = jax.random.uniform(k0, (b, length // block), jnp.float32)
+    p = jnp.repeat((1 - NOISE_FLOOR) * t + NOISE_FLOOR, block, axis=1)
+    masked = jax.random.uniform(k1, (b, length), jnp.float32) < p
+    noised = jnp.where(masked, cfg.vocab_size - 1, tokens)
+    return jnp.concatenate([tokens, noised], axis=1), masked, p
+
+
+def diffusion_loss(params, tokens, cfg: DecoderConfig, noise_seed,
+                   noise_step, bias=None):
+    """The block-diffusion loss of one step's noise on tokens [B, L]:
+    `sum over masked (1 / p) CE(logits of the noised row i, x_0[i]) /
+    (B L)` — no shift — and the counts, which gain `diffusion_masked`
+    (the masked tokens) and `diffusion_weight_max` (the largest 1 / p
+    that met one). 2 L rows go through the blocks; the final norm, the
+    head and the chunked loss see the noised half only."""
+    b, length = tokens.shape
+    doubled, masked, p = diffusion_inputs(tokens, cfg, noise_seed,
+                                          noise_step)
+    h, counts = hidden(params, doubled, cfg, bias)
+    x = rmsnorm(h[:, length:], params["norm_f"].astype(h.dtype), cfg.rms_eps)
+    weight = jnp.where(masked, 1.0 / p, 0.0)
+    with jax.named_scope("logits_loss"):
+        total = _nll_sum(x.reshape(b * length, -1), tokens.reshape(-1),
+                         weight.reshape(-1), _head(params, cfg, x.dtype), cfg)
+    return total / (b * length), {
+        **counts, "diffusion_masked": masked.sum().astype(jnp.float32),
+        "diffusion_weight_max": weight.max()}
 
 
 # ----------------------------------------------------------------------
@@ -835,9 +954,15 @@ def counters_init(cfg: DecoderConfig):
     (the most negative sum of dt A over one chunk of the scan that any
     head of any layer saw in the epoch: below about -87 a float32 chunk
     forgets the state that entered it entirely) and `ssm_dt_max` (the
-    largest dt). The configurations from
-    before the block keep the state tree their recorded programs were
-    lowered with."""
+    largest dt). A configuration trained by block diffusion counts
+    `diffusion_masked` (tokens that were masked, summed over the steps),
+    `diffusion_targets` (tokens that could have been: B x L a step; the
+    quotient's expectation is (1 + NOISE_FLOOR) / 2) and
+    `diffusion_weight_max` (the largest 1 / p that met a masked token in
+    the epoch: what one target can weigh), and its state holds, OUTSIDE
+    the epoch counters, `noise_seed` and `noise_step` (`state_init`).
+    The configurations from before the block keep the state tree their
+    recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
     i32 = functools.partial(jnp.zeros, (), jnp.int32)
     counters = {
@@ -851,6 +976,9 @@ def counters_init(cfg: DecoderConfig):
         counters.update(moe_rows_static=f32(), moe_rows_filled=f32())
     if "ssm" in cfg.attention + cfg.lead_attention:
         counters.update(ssm_log_decay_min=f32(), ssm_dt_max=f32())
+    if cfg.diffusion_block:
+        counters.update(diffusion_masked=f32(), diffusion_targets=f32(),
+                        diffusion_weight_max=f32())
     return {"epoch_counters": counters}
 
 
@@ -859,13 +987,26 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     the `train.dispatch` span (`loss_fn.step_facts`, read by the
     operator): with an ssm mixer `ssm_layers` and `ssm_chunks`, the
     chunks the scan walks a step (layers x sequences x T / chunk; every
-    head walks each); nothing otherwise."""
-    layers = sum(a == "ssm" for a, _ in cfg.kinds)
-    if not layers:
-        return {}
+    head walks each); under block diffusion `diffusion_block`,
+    `diffusion_rows` (rows through the blocks a step: B x 2 L) and
+    `attention_tiles_visited` / `attention_tiles_plane` (the score tiles
+    the forward kernel's loops walk of one head's 2 L x 2 L plane, and
+    the plane's: `ops.attention.diffusion_tiles`, the kernel's own
+    bounds); nothing otherwise."""
     b, t = batch_shape
-    return {"ssm_layers": layers,
-            "ssm_chunks": layers * b * (t // cfg.ssm_chunk)}
+    facts = {}
+    layers = sum(a == "ssm" for a, _ in cfg.kinds)
+    if layers:
+        facts.update(ssm_layers=layers,
+                     ssm_chunks=layers * b * (t // cfg.ssm_chunk))
+    if cfg.diffusion_block:
+        visited, plane = diffusion_tiles(
+            2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)
+        facts.update(diffusion_block=cfg.diffusion_block,
+                     diffusion_rows=b * 2 * t,
+                     attention_tiles_visited=visited,
+                     attention_tiles_plane=plane)
+    return facts
 
 
 def state_init(key, cfg: DecoderConfig):
@@ -873,8 +1014,17 @@ def state_init(key, cfg: DecoderConfig):
     `expert_bias` [MoE layers, n_experts] float32, seeded normal(0,
     init_std) from the same key as the parameters (a checkpoint's biases
     are not zero; at zero the first step would not see the rule), and
-    its two counters."""
+    its two counters; under block diffusion `noise_seed` (int32, drawn
+    here from the same key: what `--seed` the weights came from, the
+    noise comes from) and `noise_step` (int32, 0: the steps whose noise
+    has been drawn)."""
     state = counters_init(cfg)
+    if cfg.diffusion_block:
+        state.update(
+            noise_seed=jax.random.randint(
+                jax.random.fold_in(key, _NOISE_KEY), (), 0,
+                jnp.iinfo(jnp.int32).max, jnp.int32),
+            noise_step=jnp.zeros((), jnp.int32))
     if cfg.routing != "sigmoid_bias":
         return state
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
@@ -892,7 +1042,12 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
     is one, makes its step after the loss (`parallel/moe.py::
     balance_bias`)."""
     bias = state.get("expert_bias")
-    loss, counts = loss_fn(params, tokens, cfg, bias)
+    if cfg.diffusion_block:
+        loss, counts = diffusion_loss(params, tokens, cfg,
+                                      state["noise_seed"],
+                                      state["noise_step"], bias)
+    else:
+        loss, counts = loss_fn(params, tokens, cfg, bias)
     old = state["epoch_counters"]
     steps = old["moe_steps"] + 1
     tokens_mean = counts["expert_tokens"].astype(jnp.float32).mean()
@@ -919,14 +1074,24 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
             ssm_log_decay_min=jnp.minimum(old["ssm_log_decay_min"],
                                           counts["ssm_log_decay_min"]),
             ssm_dt_max=jnp.maximum(old["ssm_dt_max"], counts["ssm_dt_max"]))
+    if cfg.diffusion_block:
+        new.update(
+            diffusion_masked=old["diffusion_masked"]
+            + counts["diffusion_masked"],
+            diffusion_targets=old["diffusion_targets"] + float(tokens.size),
+            diffusion_weight_max=jnp.maximum(old["diffusion_weight_max"],
+                                             counts["diffusion_weight_max"]))
     if "moe_rows_static" in old:
+        rows = tokens.size * (2 if cfg.diffusion_block else 1)
         new.update(
             moe_rows_static=old["moe_rows_static"] + float(
                 cfg.moe_layers * static_rows(
-                    tokens.size * cfg.top_k, cfg.held[1], cfg.gmm_tile)),
+                    rows * cfg.top_k, cfg.held[1], cfg.gmm_tile)),
             moe_rows_filled=old["moe_rows_filled"] + (
                 counts["held"] - counts["dropped"]).sum().astype(jnp.float32))
     state = {**state, "epoch_counters": new}
+    if cfg.diffusion_block:
+        state["noise_step"] = state["noise_step"] + 1
     if bias is not None:
         bias = balance_bias(bias, counts["routed"], cfg.bias_rate)
         new["moe_bias_abs_max"] = jnp.abs(bias).max()

@@ -53,6 +53,30 @@ of the 128 lanes: a 192-wide block sits on 256 lanes in VMEM, which is
 why the forward asks for its own VMEM limit there). The kernels keep
 their names, `flash_fwd` and `flash_bwd_fused`. With D_qk == D_v every
 such branch folds away too.
+
+A fourth, static at trace time and off by default: the block-diffusion
+mask (`diffusion=b`; BD3-LM's training pass, arXiv:2503.09573). The T
+rows are a sequence of L = T / 2 positions twice over, `[clean 0..L) ;
+noised 0..L)`, cut into blocks of b positions. Its rule: a clean query
+at position i sees the clean keys j with j // b <= i // b; a noised
+query at i sees the clean keys with j // b < i // b and the noised keys
+with j // b == i // b; a clean query sees no noised key. `_diffusion_
+reach` says it once, for a scalar, a column of a tile or a whole plane:
+where the clean keys a row sees end, and where its b noised keys start.
+The loop bounds it moves: the forward's K loop becomes two, over the
+clean key blocks before the tile's last row's reach and over the noised
+key blocks its rows' own blocks touch (none for a clean tile); the
+backward's Q loop becomes two, over the clean query blocks from the
+key block's first block on and over the noised query blocks that see
+it (later blocks' for a clean key block, its own blocks' for a noised
+one). A quarter of the plane and its diagonal are walked where causal
+walks a half (`diffusion_tiles` counts them from the same bounds), the
+grouped heads share K and V as they do without it, and the kernels keep
+their names. A tile must lie in one half (L a multiple of both tile
+sides) and L be whole blocks; else the dense fallback, which builds the
+same mask from the same function. With `diffusion=None` every such
+branch folds away: the traced program is the one this file built
+before.
 """
 
 from __future__ import annotations
@@ -65,6 +89,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+import numpy as np
 
 from ray_tpu._private.accelerator import is_tpu
 from ray_tpu.ops.partition import over_leading_dim
@@ -87,13 +113,75 @@ _FWD_WIDE_VMEM_LIMIT = 48 * 1024 * 1024
 SAVED_ACROSS_REMAT = ("flash_attention_out", "flash_attention_lse")
 
 
+def _diffusion_reach(row, half: int, block: int, where=jnp.where):
+    """The block-diffusion mask, said once. For query rows `row` (a
+    scalar, a column or row of a tile, a whole `arange`: any integers)
+    of a `[clean ; noised]` sequence of `half` positions each, in blocks
+    of `block`: (where the clean keys a row sees end, the first of the
+    `block` noised keys it sees). A clean query at position i sees the
+    clean keys before its block's end and no noised key (the empty
+    range `[-block, 0)`); a noised one the clean keys before its
+    block's START and its own block of the noised half. So key row j is
+    seen where `j < end or start <= j < start + block`."""
+    noised = row >= half
+    position = row - where(noised, half, 0)
+    first = position - position % block      # its block's first position
+    return (where(noised, first, first + block),
+            where(noised, half + first, -block))
+
+
+def _diffusion_keep(key_row, reach, block: int):
+    """A tile's share of the mask: `key_row` the keys' rows, `reach`
+    `_diffusion_reach` of the queries' rows, shaped to broadcast."""
+    end, start = reach
+    return (key_row < end) | ((key_row >= start) & (key_row < start + block))
+
+
+def _diffusion_key_blocks(qi, block_q: int, block_k: int, half: int,
+                          block: int, where=jnp.where):
+    """The key blocks a query block's rows see part of: `[0, clean)`
+    and `[first, last)` of the noised half (empty for a clean query
+    block). The last row reaches furthest into the clean half; the
+    first and the last row's own blocks bound the noised keys."""
+    _, low = _diffusion_reach(qi * block_q, half, block, where)
+    end, high = _diffusion_reach((qi + 1) * block_q - 1, half, block, where)
+    noised = qi * block_q >= half
+    return ((end + block_k - 1) // block_k,
+            where(noised, low // block_k, 0),
+            where(noised, (high + block + block_k - 1) // block_k, 0))
+
+
+def _diffusion_query_blocks(ki, block_q: int, block_k: int, half: int,
+                            block: int, where=jnp.where):
+    """The query blocks that see part of a key block: `[first, half /
+    block_q)` of the clean queries (none for a noised key block) and
+    `[low, high)` of the noised ones. A clean key is seen by the clean
+    queries from its block's first position on and by the noised
+    queries from the NEXT block's first position on; a noised key by
+    its own block's noised queries."""
+    noised = ki * block_k >= half
+    j0 = ki * block_k - where(noised, half, 0)   # the first key's position
+    j1 = j0 + block_k - 1                        # ... and the last one's
+    first = j0 - j0 % block          # the first key's block's first position
+    end = j1 - j1 % block + block    # the last key's block's end
+    return (where(noised, half, first) // block_q,
+            (half + where(noised, first, first + block)) // block_q,
+            where(noised, half + end + block_q - 1, 2 * half) // block_q)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
-                  causal: bool, scale: float, window: int | None = None):
+                  causal: bool, scale: float, window: int | None = None,
+                  diffusion: int | None = None):
     qi = pl.program_id(1)
     q = q_ref[...]  # [block_q, d]
     t = k_ref.shape[0]
     d = v_ref.shape[-1]   # o's width is v's; the scores contract q's
     block_q = q.shape[0]
+    if diffusion is not None:
+        # what each of the tile's query rows sees: a column a grid step
+        reach = _diffusion_reach(
+            qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0), t // 2, diffusion)
 
     def body(ki, carry):
         o, m, l = carry
@@ -102,7 +190,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
+        if diffusion is not None:
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(_diffusion_keep(k_pos, reach, diffusion), s,
+                          NEG_INF)
+        elif causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -125,7 +218,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     num_k = t // block_k
-    if causal:
+    if diffusion is not None:
+        # the clean key blocks the tile's last row reaches into, then
+        # the noised ones its rows' own blocks touch. A row that sees
+        # nothing in a block leaves it with m = NEG_INF, as under the
+        # window below, and every row sees its own key in the end
+        clean, first, last = _diffusion_key_blocks(
+            qi, block_q, block_k, t // 2, diffusion)
+        o, m, l = jax.lax.fori_loop(first, last, body, jax.lax.fori_loop(
+            0, clean, body, (o0, m0, l0)))
+    elif causal:
         # only scan K blocks at or before this Q block
         if block_q % block_k:
             # the block holding this Q block's last row, exactly
@@ -164,9 +266,16 @@ def _flash_aligned(t: int, d: int, block_q: int, block_k: int,
             and (d_v or d) % 8 == 0)
 
 
+def _diffusion_tiled(t: int, *sides: int) -> bool:
+    """Under the block-diffusion mask a tile lies in one half of the
+    rows: every tile side divides L = t / 2."""
+    return all(side <= t // 2 and t // 2 % side == 0 for side in sides)
+
+
 def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                     block_q: int | None, block_k: int | None, interpret: bool,
-                    window: int | None = None, save_lse: bool = False):
+                    window: int | None = None, save_lse: bool = False,
+                    diffusion: int | None = None):
     """`block_q`, `block_k`: None asks `fwd_tiles`. `save_lse` (under a
     gradient): returns (out, lse), lse [B, H, T] float32 — None where
     the dense fallback ran."""
@@ -177,13 +286,21 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
             f"flash_attention: q {q.shape} and k {k.shape} share the score "
             f"width, k and v {v.shape} batch, length and heads")
     block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k, d_v)
-    plain = window is None and k.shape[2] == h
+    plain = window is None and k.shape[2] == h and diffusion is None
     if not plain and (not causal or h % k.shape[2]):
         raise ValueError(
-            "flash_attention: a window and grouped heads need causal=True, "
+            "flash_attention: a window, grouped heads and the "
+            "block-diffusion mask need causal=True, "
             f"and the {h} query heads a multiple of the {k.shape[2]} "
             "key/value heads")
-    if not _flash_aligned(t, d, block_q, block_k, d_v):
+    if diffusion is not None and (window is not None or diffusion < 1
+                                  or t % (2 * diffusion)):
+        raise ValueError(
+            f"flash_attention: diffusion={diffusion} cuts the two halves "
+            f"of {t} rows into whole blocks, and takes no window")
+    if not _flash_aligned(t, d, block_q, block_k, d_v) or (
+            diffusion is not None
+            and not _diffusion_tiled(t, block_q, block_k)):
         if t >= 512:
             import warnings
 
@@ -191,7 +308,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                 f"flash_attention: seq {t} / head_dim {d} not tile-aligned;"
                 " falling back to dense O(T^2) attention — pad the sequence"
                 " to a multiple of 8 for the pallas kernel", stacklevel=2)
-        out = _dense_fallback(q, k, v, causal, scale, window)
+        out = _dense_fallback(q, k, v, causal, scale, window, diffusion)
         return (out, None) if save_lse else out
     block_q = min(block_q, t)
     block_k = min(block_k, t)
@@ -203,6 +320,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                              interpret=interpret, save_lse=save_lse)
     if not plain:
         call = functools.partial(call, window=window)
+    if diffusion is not None:
+        call = functools.partial(call, diffusion=diffusion)
     # batch rows are independent kernel instances: under a sharded jit
     # each device runs the kernel on its own [b, T, H, D] slice
     return over_leading_dim(call, (True, True, True))(q, k, v)
@@ -221,7 +340,7 @@ def _unfold(x, b):
 
 def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
                 block_k: int, interpret: bool, window: int | None = None,
-                save_lse: bool = False):
+                save_lse: bool = False, diffusion: int | None = None):
     b, t, h, d = q.shape
     h_kv, d_v = k.shape[2], v.shape[3]
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
@@ -230,6 +349,8 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
                                causal=causal, scale=scale)
     if window is not None:
         kernel = functools.partial(kernel, window=window)
+    if diffusion is not None:
+        kernel = functools.partial(kernel, diffusion=diffusion)
     if h_kv == h:
         def kv_index(bh, qi):
             return (bh, 0, 0)
@@ -288,8 +409,29 @@ def _dense_attention(q, k, v, causal, scale, pad_mask=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v).astype(q.dtype)
 
 
-def _dense_grouped(q, k, v, scale, window):
-    """Dense causal attention under an optional window, with k and v of
+def block_diffusion_mask(t: int, block: int):
+    """[t, t] booleans: which key rows (second axis) a query row sees
+    under the block-diffusion mask over `[clean ; noised]` halves of
+    t / 2 positions in blocks of `block`."""
+    rows = jnp.arange(t)
+    return _diffusion_keep(rows[None, :], _diffusion_reach(
+        rows[:, None], t // 2, block), block)
+
+
+def diffusion_tiles(t: int, block: int, block_q: int, block_k: int
+                    ) -> tuple[int, int]:
+    """(score tiles the forward kernel's two K loops walk over one
+    head's T x T plane, the plane's tiles), from the bounds the kernel
+    itself uses, reckoned in numpy."""
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    clean, first, last = _diffusion_key_blocks(
+        np.arange(t // block_q), block_q, block_k, t // 2, block, np.where)
+    return int((clean + last - first).sum()), (t // block_q) * (t // block_k)
+
+
+def _dense_grouped(q, k, v, scale, window, diffusion=None):
+    """Dense causal attention under an optional window (or under the
+    block-diffusion mask in its place), with k and v of
     fewer heads than q (query head g reads key/value head g // group):
     the unaligned fallback of that path; scores in float32."""
     b, t, h, d = q.shape
@@ -301,18 +443,20 @@ def _dense_grouped(q, k, v, scale, window):
     keep = ahead >= 0
     if window is not None:
         keep &= ahead < window
+    if diffusion is not None:
+        keep = block_diffusion_mask(t, diffusion)
     scores = jnp.where(keep[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
     return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
 
 
-def _dense_fallback(q, k, v, causal, scale, window):
+def _dense_fallback(q, k, v, causal, scale, window, diffusion=None):
     """What a call no tile divides takes, forward and (checkpointed)
     backward."""
-    if window is None and k.shape[2] == q.shape[2]:
+    if window is None and k.shape[2] == q.shape[2] and diffusion is None:
         return _dense_attention(q, k, v, causal, scale)
-    return _dense_grouped(q, k, v, scale, window)
+    return _dense_grouped(q, k, v, scale, window, diffusion)
 
 
 def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
@@ -322,29 +466,33 @@ def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
     return _dense_attention(q, k, v, causal, scale, pad_mask=pad_mask)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
-                    window: int | None = None):
+                    window: int | None = None, diffusion: int | None = None):
     """q: [B, T, H, D_qk]; k: [B, T, H_kv, D_qk]; v: [B, T, H_kv, D_v]
     with H a multiple of H_kv (query head g reads key/value head
     g // (H // H_kv)); D_v may differ from D_qk. `scale`: None is
     D_qk ** -0.5. `window`: query i sees keys j with 0 <= i - j < window
     (needs causal). `block_q`, `block_k`: the forward kernel's tile; None
-    asks `fwd_tiles`, a number is taken as given. Returns
+    asks `fwd_tiles`, a number is taken as given. `diffusion`: the T
+    rows are `[clean ; noised]` copies of T / 2 positions in blocks of
+    that many, under the block-diffusion mask in the causal one's place
+    (the header has its rule; needs causal, takes no window). Returns
     [B, T, H, D_v]."""
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=not is_tpu(), window=window)
+                           interpret=not is_tpu(), window=window,
+                           diffusion=diffusion)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, window):
+def _fwd(q, k, v, causal, scale, block_q, block_k, window, diffusion=None):
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     out, lse = _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                                block_q=block_q, block_k=block_k,
                                interpret=not is_tpu(), window=window,
-                               save_lse=True)
+                               save_lse=True, diffusion=diffusion)
     if lse is not None:   # the kernel ran: name what it produced
         out = checkpoint_name(out, SAVED_ACROSS_REMAT[0])
         lse = checkpoint_name(lse, SAVED_ACROSS_REMAT[1])
@@ -357,7 +505,8 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dqt_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc, *,
                       block_q: int, causal: bool, scale: float,
-                      window: int | None = None, group: int = 1):
+                      window: int | None = None, group: int = 1,
+                      diffusion: int | None = None):
     """One key block of the backward, in one pass over the query blocks
     at or after it (all of them without the mask; under a window only
     those that still reach it): dk and dv of its keys, and its share of
@@ -398,7 +547,11 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     else:
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-    if causal:
+    if diffusion is not None:
+        half = q_ref.shape[0] // 2
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 0)
+    elif causal:
         # key position minus query position, for a tile at the origin
         ahead = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
                  - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1))
@@ -409,7 +562,14 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         do = do_ref[rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if causal:
+        if diffusion is not None:
+            # what each of the tile's queries sees: a row along the lanes
+            reach = _diffusion_reach(
+                qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_q), 1), half, diffusion)
+            st = jnp.where(_diffusion_keep(k_pos, reach, diffusion), st,
+                           NEG_INF)
+        elif causal:
             reach = qi * block_q - ki * block_k
             keep = ahead <= reach
             if window is not None:
@@ -424,9 +584,17 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_acc[keys] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
         dqt_acc[qi] += jnp.dot(kt, dst, preferred_element_type=jnp.float32)
 
-    # only the Q blocks whose last row reaches this K block's first key
-    first = ki * block_k // block_q if causal else 0
-    last = q_ref.shape[0] // block_q
+    if diffusion is not None:
+        # the clean query blocks from this key block's first block on,
+        # then the noised ones that see it
+        first, low, high = _diffusion_query_blocks(
+            ki, block_q, block_k, half, diffusion)
+        jax.lax.fori_loop(first, half // block_q, body, None)
+        first, last = low, high
+    else:
+        # only the Q blocks whose last row reaches this K block's first key
+        first = ki * block_k // block_q if causal else 0
+        last = q_ref.shape[0] // block_q
     if window is not None:
         # ... and whose first row is still within the window of its last
         last = jnp.minimum(
@@ -542,7 +710,8 @@ def fwd_tiles(t: int, d: int, dtype, block_q: int | None = None,
 
 
 def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
-                    interpret: bool, window: int | None = None):
+                    interpret: bool, window: int | None = None,
+                    diffusion: int | None = None):
     """(dq, dk, dv) in one kernel, `flash_bwd_fused`. Scores, lse, delta,
     ds and the accumulators in float32; p and ds cast to the inputs'
     dtype for the MXU, as the forward casts p. The grid is (batch x
@@ -552,7 +721,9 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
     b, t, h, d = q.shape
     h_kv, d_v = k.shape[2], v.shape[3]
     group = h // h_kv
-    block_q, block_k = _bwd_tiles(t, d, q.dtype, d_v)
+    # under the block-diffusion mask a tile lies in one half of the rows
+    block_q, block_k = _bwd_tiles(t if diffusion is None else t // 2, d,
+                                  q.dtype, d_v)
     # once a traced call, as the forward says its own
     logger.debug("flash_bwd_fused %s | %d %s: tiles %d x %d", q.shape, h_kv,
                  q.dtype, block_q, block_k)
@@ -600,7 +771,8 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
     kv_rows = block_k if group == 1 else t
     dqt, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
-                          scale=scale, window=window, group=group),
+                          scale=scale, window=window, group=group,
+                          diffusion=diffusion),
         grid=grid,
         in_specs=[whole(d), whole(d_v), row_spec, row_spec, kv_spec(d),
                   kv_spec(d_v)],
@@ -625,18 +797,19 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
     return dq, _unfold(dk, b), _unfold(dv, b)
 
 
-def _bwd(causal, scale, block_q, block_k, window, residuals, g):
+def _bwd(causal, scale, block_q, block_k, window, diffusion, residuals, g):
     q, k, v, o, lse = residuals
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     if lse is None:
         # unaligned fallback, as the forward's: one checkpointed dense block
         f = functools.partial(_dense_fallback, causal=causal,
-                              scale=actual_scale, window=window)
+                              scale=actual_scale, window=window,
+                              diffusion=diffusion)
         _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
         return vjp(g)
     call = functools.partial(_flash_bwd_call, causal=causal,
                              scale=actual_scale, interpret=not is_tpu(),
-                             window=window)
+                             window=window, diffusion=diffusion)
     # as the forward: each device takes its own rows of the batch
     return over_leading_dim(call, (True,) * 6)(q, k, v, o, lse, g)
 

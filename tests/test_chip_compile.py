@@ -236,6 +236,73 @@ def test_flash_attention_window_and_grouped_bwd_runs_on_the_chip(case):
     assert max(errs.values()) < 0.01, errs
 
 
+# SDAR's attention layer: a sequence of 4096 positions as [clean ;
+# noised] rows, 8 query heads a key/value head, blocks of 4
+DIFFUSION = ((1, 8192, 32, 128), 4, 4)
+
+
+def _diffusion_grads(q, k, v, w):
+    from ray_tpu.ops import attention
+
+    return jax.grad(lambda q, k, v: (attention.flash_attention(
+        q, k, v, True, None, 256, 512, None, DIFFUSION[2]).astype(
+            jnp.float32) * w).sum(), (0, 1, 2))(q, k, v)
+
+
+def test_flash_attention_block_diffusion(one_chip, on_tpu):
+    """The block-diffusion mask at the cell's widths under a gradient:
+    the same two kernels, their two loops each and the mask's integer
+    arithmetic (a remainder by the block length on a column of a tile)
+    accepted by Mosaic, nothing left to XLA."""
+    shape, kv_heads, _ = DIFFUSION
+    b, t, h, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
+                              sharding=one_chip)
+    w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(_diffusion_grads, q, kv, kv, w)
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    assert "dynamic-update-slice" not in text and "while(" not in text
+
+
+def test_flash_attention_block_diffusion_runs_on_the_chip():
+    """Runs only on a TPU. The output and dq, dk, dv of the masked call
+    on bf16 inputs against dense attention under `block_diffusion_mask`
+    in float32, a key/value head and its eight query heads at a time:
+    the measure and limit of the other paths."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import attention
+
+    shape, kv_heads, block = DIFFUSION
+    b, t, h, d = shape
+    group = h // kv_heads
+    keys = jax.random.split(jax.random.key(h + block), 4)
+    q, w = (jax.random.normal(key, shape, jnp.float32) for key in keys[:2])
+    k, v = (jax.random.normal(key, (b, t, kv_heads, d), jnp.float32)
+            for key in keys[2:])
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = jax.jit(_diffusion_grads)(q, k, v, w)
+
+    @jax.jit
+    def dense_grads(q, k, v, w):   # one key/value head and its group
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda q, k, v: (attention._dense_grouped(
+                q, k, v, d ** -0.5, None, block) * w).sum(), (0, 1, 2))(
+                    q, k, v)
+
+    want = [dense_grads(q[:, :, i * group:(i + 1) * group].astype(
+        jnp.float32), k[:, :, i:i + 1].astype(jnp.float32),
+        v[:, :, i:i + 1].astype(jnp.float32),
+        w[:, :, i * group:(i + 1) * group]) for i in range(kv_heads)]
+    want = [jnp.concatenate(part, axis=2) for part in zip(*want)]
+    errs = {name: _rel_err(a, r)
+            for name, a, r in zip(("dq", "dk", "dv"), got, want)}
+    print("block-diffusion", errs)
+    assert max(errs.values()) < 0.01, errs
+
+
 # the latent mixer's call: 192-wide queries and keys, 128-wide values
 LATENT = (1, 8192, 32, 192, 128)
 

@@ -197,7 +197,8 @@ def test_shares_add_up_to_the_uncut_layer():
                 for k in ("w_gate", "w_up", "w_down")})
             out, counts = jax.jit(functools.partial(
                 decoder._layer, cfg=share, mlp="experts", attention=mixer))(
-                    h, mine, decoder.rope_tables(64, share))
+                    h, mine, decoder.rope_tables(
+                        jnp.arange(64, dtype=jnp.float32), share))
             assert int(counts["dropped"]) == 0
             assert (counts["routed"] == n).all()
             total = total + (out[0] - mixer_and_residual)

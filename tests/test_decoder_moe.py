@@ -121,7 +121,8 @@ def test_shares_add_up_to_the_uncut_layer(windowed):
         out, counts = jax.jit(functools.partial(
             decoder._layer, cfg=share, mlp="experts",
             attention="window" if windowed else "full"))(
-                h, mine, decoder.rope_tables(64, share))
+                h, mine, decoder.rope_tables(
+                    jnp.arange(64, dtype=jnp.float32), share))
         assert int(counts["dropped"]) == 0
         total = total + (out[0] - attention_and_residual)
     assert float(jnp.abs(total - whole).max()) <= LOGIT_ATOL
