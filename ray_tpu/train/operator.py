@@ -19,6 +19,7 @@ register :187, train_epoch :437), redesigned jax-first:
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Any, Callable, Iterable
 
 import jax
@@ -100,6 +101,8 @@ class TrainingOperator:
         self._facts = None      # _layout_facts, reckoned at the first epoch
         self._loading = None    # a state arriving in pieces (_LoadPlan)
         self._stage = None      # the staging area (_staging), once needed
+        self._joiners = None    # the join's threads (_join_pool), likewise
+        self._join_width = 0    # ... and how many they are
         self._loss_fn = loss_fn
         self._eval_fn = eval_fn
         self._optimizer = optimizer
@@ -624,18 +627,26 @@ class TrainingOperator:
 
     def _to_host(self, tree, counts: dict, ctx, room: int = 0, ahead=()):
         """`tree` with its arrays on the host; adds to `counts`
-        (`bytes`, `leaves`, `staged_bytes`, `shards`, and the seconds
-        `start_s` issuing transfers, `wait_s` blocked in `np.asarray`
-        on a device array — the link — and `join_s` writing shards into
-        the staging area). Every leaf's
+        (`bytes`, `leaves`, `staged_bytes`, `shards`, `join_threads`,
+        and the WALL seconds of the caller: `start_s` issuing transfers,
+        `wait_s` waiting for a device array to arrive — the link — and
+        `join_s` in which a shard was being written into the staging
+        area). Every leaf's
         transfer is started before the first is waited for, and behind
         them those of the arrays in `ahead`, which are NOT waited for:
         the link takes transfers in the order issued. With `room`
         (bytes, `_stage_room`) a leaf that has to be joined from shards
-        is written, shard by shard as they arrive, into the staging area
-        — pages this operator has written before, where `np.asarray`
-        joins into freshly mapped ones — and comes back as a VIEW of it,
-        good until the next call with `room`. Leaves with nothing to
+        is written into the staging area — pages this operator has
+        written before, where `np.asarray` joins into freshly mapped
+        ones — each shard waited for and written by a thread of its own
+        (`_join_pool`), beside one another, and comes back as a VIEW of
+        it, good until the next call with `room`: every shard's write
+        has ended, or its exception is raised here, before the next
+        leaf is looked at. Of such a leaf's wall seconds `join_s` takes
+        those in which at least one shard was being written (the union
+        of the writes) and `wait_s` the rest, every thread still waiting
+        for its shard; `join_threads` is the most shards one leaf had
+        written off this thread. Leaves with nothing to
         join go through `np.asarray` either way. At a trace's fine level
         every leaf above 1 MiB gets a `train.snapshot.d2h.leaf` span."""
         leaves, treedef = jax.tree.flatten(tree)
@@ -657,23 +668,24 @@ class TrainingOperator:
                 x = multihost_utils.process_allgather(x)
             counts["bytes"] += x.nbytes
             counts["leaves"] += 1
+            t0 = clock()
             if not (room and _is_joined(x)):
-                t0 = clock()
                 out = np.asarray(x)
                 counts["wait_s"] += clock() - t0
                 return out
             out = self._staging(room)[at:at + x.nbytes].view(
                 x.dtype).reshape(x.shape)
             at += _padded(x.nbytes)
-            for shard in x.addressable_shards:
-                if shard.replica_id == 0:   # each index once
-                    t0 = clock()
-                    arrived = np.asarray(shard.data)
-                    t1 = clock()
-                    out[shard.index] = arrived
-                    counts["join_s"] += clock() - t1
-                    counts["wait_s"] += t1 - t0
-                    counts["shards"] += 1
+            shards = [s for s in x.addressable_shards
+                      if s.replica_id == 0]     # each index once
+            pool = self._join_pool(len(shards))
+            writes = [pool.submit(_write_shard, out, s) for s in shards]
+            wait(writes)        # all of them: none writes after a raise
+            joined = _union_s([w.result() for w in writes])
+            counts["join_s"] += joined
+            counts["wait_s"] += clock() - t0 - joined
+            counts["shards"] += len(shards)
+            counts["join_threads"] = max(counts["join_threads"], len(shards))
             counts["staged_bytes"] += x.nbytes
             return out
 
@@ -692,10 +704,26 @@ class TrainingOperator:
     def _staging(self, room: int) -> np.ndarray:
         """The staging area: at least `room` bytes of host memory this
         operator keeps, piece after piece and call after call, so the
-        pages a join writes are resident from the second use on."""
+        pages a join writes are resident from the second use on. The
+        join's threads (`_join_pool`) write into it, each its own
+        shard's slice; between two calls of `_to_host` nobody does."""
         if self._stage is None or self._stage.nbytes < room:
             self._stage = np.empty(room, np.uint8)
         return self._stage
+
+    def _join_pool(self, width: int) -> ThreadPoolExecutor:
+        """The join's threads: as many as the widest leaf joined so far
+        had shards, one to a shard. They are this operator's, kept
+        beside the staging area from the first joined leaf on (a state
+        has hundreds of leaves: not a start and a join each), idle
+        between leaves, and never made where nothing is joined."""
+        if width > self._join_width:
+            if self._joiners is not None:
+                self._joiners.shutdown()
+            self._joiners = ThreadPoolExecutor(
+                width, thread_name_prefix="train-join")
+            self._join_width = width
+        return self._joiners
 
     def _state_tree(self, drop=()) -> dict:
         """The training state with its arrays where they are (on the
@@ -733,7 +761,9 @@ class TrainingOperator:
         caller's put of this piece.
 
         Leaves joined from shards are views of the operator's staging
-        area: they are good until the NEXT `state_piece` call and no
+        area, written there by the operator's own threads, a shard each
+        (`_join_pool`), all of which have ended when this returns: they
+        are good until the NEXT `state_piece` call and no
         longer. That is what the actor's lane gives: `TrainWorker` runs
         one method at a time and the runtime has serialised and copied a
         reply into the store before it starts the next. Who keeps what
@@ -811,10 +841,34 @@ class TrainingOperator:
 
 def _d2h_counts() -> dict:
     """What a `train.snapshot.d2h` span counts: `staged_bytes` of
-    `bytes` were joined from `shards` shards in the staging area; the
-    span's seconds by what the worker did in them (`_to_host`)."""
+    `bytes` were joined from `shards` shards in the staging area, at
+    most `join_threads` of them at once, each on a thread of the
+    operator's (0: nothing was joined); the span's WALL seconds by what
+    the worker's chain did in them (`_to_host`), never thread-seconds:
+    `start_s` + `wait_s` + `join_s` stay inside the span."""
     return {"bytes": 0, "leaves": 0, "staged_bytes": 0, "shards": 0,
-            "start_s": 0.0, "wait_s": 0.0, "join_s": 0.0}
+            "join_threads": 0, "start_s": 0.0, "wait_s": 0.0, "join_s": 0.0}
+
+
+def _write_shard(out: np.ndarray, shard) -> tuple:
+    """Wait for `shard` (the link) and write it into its slice of the
+    joined leaf `out`; when the write started and ended. One of the
+    join's threads runs this (`_join_pool`), and both halves leave the
+    GIL: the wait inside jax, the write in numpy's raw copy — for the
+    extension dtypes too (`bfloat16`: no `NPY_NEEDS_PYAPI` flag)."""
+    arrived = np.asarray(shard.data)
+    t0 = time.perf_counter()
+    out[shard.index] = arrived
+    return t0, time.perf_counter()
+
+
+def _union_s(intervals: list) -> float:
+    """Seconds covered by at least one of the (start, end) `intervals`."""
+    covered, done = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        covered += max(0.0, end - max(start, done))
+        done = max(done, end)
+    return covered
 
 
 def _is_joined(x) -> bool:

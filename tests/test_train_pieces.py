@@ -7,6 +7,7 @@ GPT with seeded weights, and the arena is 8 MiB so that a 20 MB state is
 several times what it holds."""
 
 import statistics
+import threading
 import time
 
 import numpy as np
@@ -629,6 +630,133 @@ def test_a_join_is_timed_where_a_leaf_is_split_over_devices(monkeypatch):
     assert sum(r[3]["staged_bytes"] for r in parts) > 1 << 20
 
 
+def _join_threads():
+    return {t for t in threading.enumerate()
+            if t.name.startswith("train-join")}
+
+
+def _shard_writes(monkeypatch, before=lambda out, shard: None):
+    """A spy on `_write_shard`: the name of the thread that wrote each
+    shard, in the order the writes ENDED; `before(out, shard)` runs on
+    that thread ahead of the real write."""
+    import threading
+
+    real, names = operator_mod._write_shard, []
+
+    def spy(out, shard):
+        try:
+            before(out, shard)
+            return real(out, shard)
+        finally:
+            names.append(threading.current_thread().name)
+
+    monkeypatch.setattr(operator_mod, "_write_shard", spy)
+    return names
+
+
+@pytest.mark.parametrize("case", ["first_dim", "later_dim", "replicated",
+                                  "partly_replicated", "widths_differ"])
+def test_the_shards_of_a_leaf_are_joined_by_a_thread_each(monkeypatch, case):
+    """The shards of one leaf are waited for and written BESIDE one
+    another, on threads the operator keeps, and what comes back is what
+    `np.asarray` of the whole leaf gives; `join_threads` says how many,
+    and the span's three counts stay wall seconds inside it."""
+    from ray_tpu._private import tracing
+
+    op = _operator(monkeypatch, 4)
+    params, shards = _sharded_cases(op._mesh)[case]
+    op.params, op.model_state, op.opt_state = params, {}, {}
+    arrays = jax.tree.leaves(params)
+    widths = [len({s.index for s in x.addressable_shards})
+              for x in arrays
+              if isinstance(x, jax.Array) and not x.is_fully_replicated]
+    assert sum(widths) == shards
+    # every shard of a leaf (told by its shape) waits until all of them
+    # are in flight: only a thread a shard gets past this
+    gates = {x.shape: threading.Barrier(w, timeout=scale_timeout(30))
+             for x, w in zip(arrays, widths)}
+    names = _shard_writes(
+        monkeypatch, before=lambda out, shard: gates[out.shape].wait())
+    had = _join_threads()
+    root = tracing.always_trace()
+    with tracing.open_tree(root) as rows, tracing.use(root):
+        piece = op.state_piece(0, 1 << 30)
+    (_, start, end, counts), = [r for r in rows
+                                if r[0] == "train.snapshot.d2h"]
+    got = piece["leaves"][-len(arrays):]    # after epoch, global_step
+    assert _bits(got) == _bits([np.asarray(x) for x in arrays])
+    assert counts["shards"] == shards == len(names)
+    assert counts["join_threads"] == max(widths, default=0)
+    assert counts["start_s"] + counts["wait_s"] + counts["join_s"] <= (
+        end - start)
+    assert (counts["join_s"] > 0) == (counts["staged_bytes"] > 0) == (
+        counts["join_threads"] > 0)
+    # never the caller's thread, and no more threads than the widest
+    # leaf has shards — none at all where nothing is joined
+    assert all(n.startswith("train-join") for n in names)
+    assert len(_join_threads() - had) == max(widths, default=0)
+    assert (op._joiners is None) == (not widths)
+    # a second pull finds the threads it needs
+    mine = _join_threads() - had
+    op.state_piece(0, 1 << 30)
+    assert _join_threads() - had == mine
+
+
+def test_a_shard_that_fails_raises_in_the_caller_after_every_write_ended(
+        monkeypatch):
+    """An exception on one of the join's threads reaches `state_piece`'s
+    caller, not before the leaf's other shards are written (nobody
+    writes into the staging area behind the caller's back), and the
+    next pull works."""
+    op = _operator(monkeypatch, 4)
+    op.train_batch(_tiny_pieces()[-1])
+    usable = 1 << 19
+    calls = []
+
+    def third_fails(out, shard):
+        calls.append(shard)
+        if len(calls) == 3:
+            raise RuntimeError("the link dropped a shard")
+
+    names = _shard_writes(monkeypatch, before=third_fails)
+    with pytest.raises(RuntimeError, match="the link dropped a shard"):
+        _pull(op, usable)
+    # the failing leaf's four shards were all taken up and all ended
+    assert len(calls) == len(names) == 4
+    monkeypatch.undo()
+    (copies, _), parts = _traced_d2h(lambda: _pull(op, usable))
+    assert _bits(copies) == _bits(op.state_dict())
+    assert {p["join_threads"] for p in parts} == {4}
+
+
+def test_on_one_device_no_thread_is_ever_started(monkeypatch):
+    op = _operator(monkeypatch, 1)
+    tokens = _tiny_pieces()[-1]
+    before = set(threading.enumerate())
+    for _ in range(2):
+        op.train_batch(tokens)
+        (copies, _), parts = _traced_d2h(lambda: _pull(op, 1 << 19))
+        assert _bits(copies) == _bits(op.state_dict())
+        assert len(parts) > 4
+        assert {p["join_threads"] for p in parts} == {0}
+        assert {p["join_s"] for p in parts} == {0.0}
+    assert op._joiners is None and op._stage is None
+    assert set(threading.enumerate()) <= before
+
+
+# numpy's NPY_NEEDS_PYAPI: a dtype whose copy loops hold the GIL
+NEEDS_PYAPI = 0x10
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16, np.float16,
+                                   np.int8, np.int32, np.uint32])
+def test_a_shards_write_leaves_the_gil_for_every_dtype_a_state_keeps(dtype):
+    """`out[index] = arrived` is numpy's raw copy for these, extension
+    dtypes included: the join's threads write beside one another."""
+    dtype = np.dtype(dtype)
+    assert not dtype.flags & NEEDS_PYAPI and not dtype.hasobject
+
+
 def test_dest_writes_counts_each_buffer_sets_writes(host):
     """0, 0 (both sets are allocated), 1, 1 (each set's second write:
     the slow one on the chip machines), then 2, 2 ... — a count the
@@ -809,11 +937,17 @@ def _sharded_cases(mesh):
             {"w": put((8, 9), P("fsdp"), on=two_by_four)}, 4),
         "replicated": ({"w": put((8, 6), P()),
                         "host": np.arange(5.0)}, 0),
+        # a narrow leaf, then a wider one: the join's threads grow
+        "widths_differ": (
+            {"a": put((6, 3), P("fsdp"),
+                      on=Mesh(np.array(jax.devices()[:2]), ("fsdp",))),
+             "b": put((16, 5), P(("data", "fsdp")), on=two_by_four)}, 10),
     }
 
 
 @pytest.mark.parametrize("case", ["first_dim", "later_dim", "odd_sizes",
-                                  "partly_replicated", "replicated"])
+                                  "partly_replicated", "replicated",
+                                  "widths_differ"])
 def test_a_joined_leaf_equals_np_asarray_bit_for_bit(monkeypatch, case):
     op = _operator(monkeypatch, 4)
     params, shards = _sharded_cases(op._mesh)[case]
@@ -984,6 +1118,7 @@ def test_a_state_of_one_piece_issues_its_own_leaves_and_no_thread(
 
     op = _operator(monkeypatch, 4)
     op.train_batch(_tiny_pieces()[-1])
+    op.state_piece(0, 1 << 30)      # the join's threads are there from now
     seen, pieces = _issues(monkeypatch, op, 1 << 30)
     threads = threading.active_count()
     piece, (count,) = _traced_d2h(lambda: op.state_piece(0, 1 << 30))
