@@ -7,8 +7,11 @@ AdamW: 1.39 GiB of 2 GiB); GPT-2-large's 9.3 GB is not. So the state
 always moves as a sequence of PIECES, each a run of whole leaves in tree
 order (`jax.tree.flatten`), bounded by a budget derived from what the
 store holds before it spills (`usable_bytes`: an observable, not an
-option). A state that fits is one piece: the degenerate case of the same
-code. The worker's side of both directions is here; the driver's is
+option). A state that fits the store is cut by the same budget as one
+that does not (GPT-2-small: four pieces), so that the link, the
+worker's put and the driver's copy run beside each other; one smaller
+than a single budget is one piece: the degenerate case of the same code.
+The worker's side of both directions is here; the driver's is
 `Trainer._pull_state` / `_push_state`.
 """
 
@@ -19,9 +22,8 @@ from typing import Any, NamedTuple
 import jax
 import numpy as np
 
-# When a state needs several pieces, each is at most this share of what
-# the store holds: the worker can then put the next one (or two) while
-# the driver still copies the last.
+# A piece is at most this share of what the store holds: the worker can
+# then put the next one (or two) while the driver still copies the last.
 PIECE_SHARE = 4
 
 
@@ -41,13 +43,11 @@ def leaf_bytes(x) -> int:
 
 
 def plan(sizes: list[int], usable: int) -> list[tuple[int, int]]:
-    """Leaf index ranges [first, stop), in order, covering `sizes`. All
-    of it is ONE piece when it fits `usable`; else runs of whole leaves
-    of at most `usable // PIECE_SHARE` bytes. A leaf larger than that is
+    """Leaf index ranges [first, stop), in order, covering `sizes`:
+    runs of whole leaves of at most `usable // PIECE_SHARE` bytes,
+    whether or not all of it fits `usable`. A leaf larger than that is
     a piece of its own; one larger than `usable` cannot cross at all."""
-    if sum(sizes) <= usable:
-        return [(0, len(sizes))]
-    largest = max(sizes)
+    largest = max(sizes, default=0)
     if largest > usable:
         raise ValueError(
             f"a leaf of the training state is {largest} bytes, more than "

@@ -666,7 +666,7 @@ class Trainer:
                     drop=(), writes: int = 0) -> dict:
         """`worker`'s training state, whole, in memory the driver owns.
         It crosses the object plane as the pieces `train/snapshot.py`
-        cuts (a state the store holds is ONE piece): each goes
+        cuts (also a state the store would hold whole): each goes
         device→host and into the arena on the worker, out through `_own`
         into `spare`'s leaves here (a tree an earlier pull built, see
         `_own`), and is released. The driver asks ahead — the actor runs

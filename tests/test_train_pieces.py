@@ -96,15 +96,28 @@ class Wide(TrainingOperator):
 
 
 class Small(TrainingOperator):
-    """One (256, 128) weight under adam: 0.4 MB of state, one piece."""
+    """`weights` (one unless configured) (256, 256) matrices under adam,
+    0.75 MiB of state each: one is under the 1.6 MiB budget of the 8 MiB
+    store and a single piece, four lie between one budget and the
+    6.4 MiB the store holds whole."""
 
     def setup(self, config):
         import optax
 
-        self.register(
-            model_init=lambda rng: {"w": jax.random.normal(rng, (256, 128))},
-            loss_fn=lambda p, b: jnp.mean((b @ p["w"]) ** 2),
-            optimizer=optax.adam(1e-2))
+        count = config.get("weights", 1)
+
+        def model_init(rng):
+            return {f"w{i}": jax.random.normal(key, (256, 256)) / 16
+                    for i, key in enumerate(jax.random.split(rng, count))}
+
+        def loss_fn(params, batch):
+            x = batch
+            for i in range(count):
+                x = jnp.tanh(x @ params[f"w{i}"])
+            return jnp.mean(x ** 2)
+
+        self.register(model_init=model_init, loss_fn=loss_fn,
+                      optimizer=optax.adam(1e-2))
         self.register_data(
             train_loader=[np.ones((4, 256), np.float32)] * 2)
 
@@ -121,6 +134,15 @@ def host():
 
 def _span(entry, name):
     return [s["attrs"] for s in entry["spans"] if s["name"] == name]
+
+
+def _wait_released(used, seconds=10):
+    """The store's `used` back at `used` (bounded wait): nothing pinned."""
+    store = global_state.require_core_worker().store
+    deadline = time.monotonic() + seconds
+    while store.stats()["used"] > used and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert store.stats()["used"] <= used
 
 
 def _bits(tree):
@@ -454,20 +476,46 @@ def test_a_killed_worker_restores_onto_the_mesh_with_equal_losses(host):
 # a snapshot crosses in pieces
 # ---------------------------------------------------------------------
 
-def test_the_plan():
-    mib = 1 << 20
-    usable = 8 * mib
-    # what fits is one piece, whatever its leaves
-    assert snapshot.plan([3 * mib, 4 * mib, mib], usable) == [(0, 3)]
-    assert snapshot.plan([], usable) == [(0, 0)]
-    # else runs of whole leaves of at most a quarter of what fits; a
-    # larger leaf is a piece of its own
-    sizes = [mib, mib, mib, 3 * mib, 4, mib, 2 * mib, mib]
-    assert snapshot.plan(sizes, usable) == [
-        (0, 2), (2, 3), (3, 4), (4, 6), (6, 7), (7, 8)]
-    with pytest.raises(ValueError, match="larger_than|more than the "
-                                         "object store's arena"):
-        snapshot.plan([mib, 9 * mib], usable)
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("sizes, ranges", [
+    # under one budget (a quarter of the 8 MiB that fit): one piece
+    ([MIB, 4, MIB // 2], [(0, 3)]),
+    ([2 * MIB], [(0, 1)]),
+    # between one budget and what fits: cut all the same
+    ([3 * MIB, 4 * MIB, MIB], [(0, 1), (1, 2), (2, 3)]),
+    ([MIB, MIB, MIB, 4, MIB, MIB], [(0, 2), (2, 4), (4, 6)]),
+    # more than fits: the ranges the rule gave while what fits was one
+    # piece (the parent's own output, as literals): a leaf over the
+    # budget is a piece of its own
+    ([MIB, MIB, MIB, 3 * MIB, 4, MIB, 2 * MIB, MIB],
+     [(0, 2), (2, 3), (3, 4), (4, 6), (6, 7), (7, 8)]),
+    ([4, 4, 6 * MIB, 6 * MIB, 1536 * 1024, MIB // 2, MIB, 6 * MIB, 8],
+     [(0, 2), (2, 3), (3, 4), (4, 6), (6, 7), (7, 8), (8, 9)]),
+    # nothing at all is still a piece
+    ([], [(0, 0)]),
+    # a leaf the store cannot hold crosses in no plan, whatever the rest
+    ([MIB, 9 * MIB], None),
+    ([9 * MIB], None),
+], ids=["under_a_budget", "one_leaf_of_a_budget", "fits_leaves_over_budget",
+        "fits_runs_of_leaves", "over_usable", "over_usable_stacks",
+        "empty", "leaf_over_usable", "lone_leaf_over_usable"])
+def test_the_plan(sizes, ranges):
+    usable = 8 * MIB
+    budget = usable // snapshot.PIECE_SHARE
+    if ranges is None:
+        with pytest.raises(ValueError, match="more than the object "
+                                             "store's arena"):
+            snapshot.plan(sizes, usable)
+        return
+    assert snapshot.plan(sizes, usable) == ranges
+    # contiguous, in order, whole; a piece over the budget is one leaf
+    assert [first for first, _ in ranges] == [0] + [
+        stop for _, stop in ranges[:-1]]
+    assert ranges[-1][1] == len(sizes)
+    for first, stop in ranges:
+        assert sum(sizes[first:stop]) <= budget or stop - first == 1
 
 
 def test_usable_bytes_reads_the_store(host):
@@ -513,11 +561,7 @@ def test_a_state_several_arenas_large_crosses_in_pieces(wide):
     # bit-identical: pulled again (fresh buffers), the same bits
     assert _bits(wide.state_dict()) == _bits(state)
     # nothing of it is left in the arena
-    deadline = time.monotonic() + 10
-    store = global_state.require_core_worker().store
-    while store.stats()["used"] > used and time.monotonic() < deadline:
-        time.sleep(0.05)
-    assert store.stats()["used"] <= used
+    _wait_released(used)
 
 
 def test_nothing_is_staged_on_one_device(wide):
@@ -777,7 +821,7 @@ def test_dest_writes_counts_each_buffer_sets_writes(host):
         assert seen == [0, 0, 1, 1, 2, 2]
         assert [s[2] for s in tr._owned] == [3, 3]
         newer, (state, shards, writes) = tr._owned
-        tr._owned = (newer, ({"w": state["params"]["w"]}, shards, writes))
+        tr._owned = (newer, ({"w": state["params"]["w0"]}, shards, writes))
         copy = call()
         assert copy["dest_writes"] == 0 and copy["reused_bytes"] == 0
         assert [s[2] for s in tr._owned] == [1, 3]
@@ -1208,7 +1252,7 @@ def test_a_piece_that_raises_changes_nothing(wide, monkeypatch):
     assert _bits(wide.state_dict()) == _bits(wide._last_state)
 
 
-def test_a_state_that_fits_crosses_in_one_piece(host):
+def test_a_state_under_one_budget_crosses_in_one_piece(host):
     tr = Trainer(Small, num_workers=1)
     try:
         for _ in range(3):
@@ -1221,6 +1265,60 @@ def test_a_state_that_fits_crosses_in_one_piece(host):
         for name in ("train.snapshot.d2h", "object.return_put",
                      "object.get"):
             assert len(_span(entry, name)) == 1, name
+    finally:
+        tr.shutdown(force=True)
+
+
+def test_a_state_the_store_holds_whole_crosses_in_pieces_too(host):
+    """Between one budget and what the store holds before it spills the
+    state is cut like a larger one, so that link, put and copy overlap
+    (GPT-2-small's 1.39 GiB in the default 2 GiB store)."""
+    cw = global_state.require_core_worker()
+    used, usable = cw.store.stats()["used"], snapshot.usable_bytes(cw)
+    tr = Trainer(Small, num_workers=1, config={"weights": 4})
+    try:
+        for _ in range(3):
+            tr.train()
+        entry = call_log()[-1]
+        (snap,) = _span(entry, "train.snapshot")
+        assert usable // snapshot.PIECE_SHARE < snap["bytes"] < usable
+        assert snap["pieces"] > 1
+        for name in ("train.snapshot.d2h", "train.snapshot.wait",
+                     "train.snapshot.copy"):
+            assert sorted(s["piece"] for s in _span(entry, name)) == list(
+                range(snap["pieces"])), name
+        copies = _span(entry, "train.snapshot.copy")
+        assert sum(c["bytes"] for c in copies) == snap["bytes"]
+        assert all(c["reused_bytes"] == c["bytes"] for c in copies)
+        # the worker's own state, whole, by the road that cuts nothing
+        pulled = tr._last_state
+        want = _bits(ray_tpu.get(tr.workers[0].state_dict.remote()))
+        assert _bits(pulled) == want
+        assert _bits(tr.state_dict()) == want
+        # the reader still tiles the call along the driver's thread
+        path = boundary_path.call_path(entry)
+        tiles = (_named(entry, "train.snapshot.wait")
+                 + _named(entry, "train.snapshot.copy"))
+        assert path["pieces"] == snap["pieces"]
+        assert path["wait_s"] + path["get_s"] + path["copy_s"] == (
+            pytest.approx(sum(s["end"] - s["start"] for s in tiles)))
+        # ... and what is left of the boundary is hops, not a gap
+        assert 0 <= path["hops_s"] < scale_timeout(0.25)
+        # back the same way: load_state_dict, then the elastic restore
+        saved = tr.state_dict()
+        tr.train()
+        assert _bits(tr.state_dict()) != _bits(saved)
+        tr.load_state_dict(saved)
+        assert _bits(tr.state_dict()) == _bits(saved)
+        assert _bits(ray_tpu.get(
+            tr.workers[0].state_dict.remote())) == _bits(saved)
+        ray_tpu.kill(tr.workers[0])
+        tr.train()
+        assert _span(call_log()[-1], "train.epoch")[0]["attempts"] == 2
+        assert tr._last_state["epoch"] == saved["epoch"] + 1
+        # nothing of all that is left pinned in the arena
+        pulled = want = saved = None
+        _wait_released(used)
     finally:
         tr.shutdown(force=True)
 
@@ -1276,12 +1374,8 @@ def test_a_return_nobody_waits_for_leaves_the_store(host):
         time.sleep(0.5)
         return np.ones(1 << 20, np.uint8)
 
-    store = global_state.require_core_worker().store
-    used = store.stats()["used"]
+    used = global_state.require_core_worker().store.stats()["used"]
     ref = slow_megabyte.remote()
     del ref
-    deadline = time.monotonic() + 20
     time.sleep(1.0)
-    while store.stats()["used"] > used and time.monotonic() < deadline:
-        time.sleep(0.05)
-    assert store.stats()["used"] <= used
+    _wait_released(used, seconds=19)
