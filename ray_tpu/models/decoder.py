@@ -60,7 +60,10 @@ D7). The kinds built so far:
   `cfg.routed_scale` multiplies the routing weights; `cfg.d_shared` > 0
   adds a SHARED expert of that width, one gated MLP every token takes,
   beside the routed sum and outside the grouped matmul's rows.
-- MLP `"dense"`: one MLP of width `d_dense`.
+- MLP `"dense"`: one MLP of width `d_dense`. A pattern WITHOUT an
+  `"experts"` layer (a dense model through and through) names none of
+  `n_experts`, `top_k`, `d_expert`, `held`, holds no router or expert
+  leaf, makes none of the `moe_*` counters and counts no routing.
 
 `cfg.activation` is the MLP kinds' (and the shared expert's) activation;
 they are gated, `W_down (act(W_gate y) * (W_up y))`, unless `cfg.gated`
@@ -73,7 +76,9 @@ vocabulary slice that is no multiple of the 128 lanes is then nobody's
 minor dimension (the device keeps a leaf whose minor dimension is not,
 after one that is, transposed whatever shape it is given, and the step
 copies it and its moments back and forth: PERF.md section 6, PR 39).
-Norms are `ops.rmsnorm` (weight only).
+Norms are `ops.rmsnorm` (weight only). `cfg.sandwich` gives every layer
+two more (`norm1_post`, `norm2_post`): the mixer's output and the MLP's
+are each normed BEFORE the residual sum, `h + RMSNorm(part(RMSNorm(h)))`.
 
 `cfg.mtp` = 1 adds a multi-token-prediction block after the last layer
 (`params["mtp"]`): position i's last hidden state (before the final
@@ -117,9 +122,32 @@ drawn once from the key the weights are drawn from, the step moved by
 snapshot carries where the noise stands. With `diffusion_block` 0 none
 of this is traced and a configuration's program is what it was.
 
+`cfg.loops` = T > 1 makes the model a LOOPED one (Ouro,
+arXiv:2510.25741): the whole stack of layers is walked T times a
+forward pass with ONE set of weights, the final norm after every walk,
+its output the next walk's input and the head's (nothing is re-injected
+between walks). `lax.scan` over the walks goes AROUND the scan over the
+periods and the weights are constants of both: a period is still traced
+once, the step holds one compute-dtype copy of the weight stacks, and
+the backward pass adds each walk's gradient stack into ONE float32
+stack a leaf (the sum over the walks is the gradient of a shared
+weight); each block is rematerialised as ever, so a block input is
+kept a layer and walk. `cfg.exit_gate` adds a gate
+(`params["exit_gate"]`: a weight `[d_model]` and a bias, one for all
+walks, float32) that reads every walk's normed output: `lam_t =
+sigmoid(w . x_t + b)`, survival `S_t = prod over j <= t of (1 -
+lam_j)`, the exit distribution `p(t) = lam_t S_(t-1)`, the last walk
+taking what is left; and the loss becomes the expected-exit loss,
+`sum over t of p(t) CE_t - cfg.exit_beta H(p)` a token, T passes of the
+chunked head in one scan over the walks with a weight a token that
+carries its gradient to the gate (`loop_loss`). Built for layers that
+count nothing (no `experts`, no `ssm`), without an MTP block or block
+diffusion. With `loops` 1, no sandwich and no gate none of this is
+traced and a configuration's program is what it was.
+
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
-scores, the selection bias, the head norms and every norm's statistics
-are float32.
+scores, the selection bias, the head norms, the exit gate and every
+norm's statistics are float32.
 """
 
 from __future__ import annotations
@@ -171,10 +199,12 @@ class DecoderConfig:
     mlp: tuple[str, ...]              # one period, a kind per layer
     window: int
     rope_theta: float
-    n_experts: int                    # the router's outputs: ALL experts
-    top_k: int
-    d_expert: int
-    held: tuple[int, int]             # (first, count): the experts held here
+    n_experts: int = 0                # the router's outputs: ALL experts
+    top_k: int = 0
+    d_expert: int = 0
+    held: tuple[int, int] = (0, 0)    # (first, count): the experts held
+    #                                   here; a pattern without an
+    #                                   `experts` layer needs none of the four
     rms_eps: float = 1e-6
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
@@ -216,6 +246,14 @@ class DecoderConfig:
     diffusion_block: int = 0          # > 0: trained by block diffusion over
     #                                   blocks of this many tokens
     head_rows: bool = False           # the untied head kept [vocab, d_model]
+    loops: int = 1                    # walks of the whole stack a forward
+    #                                   pass, one set of weights
+    sandwich: bool = False            # an RMSNorm AFTER the mixer and after
+    #                                   the MLP too, before each residual sum
+    exit_gate: bool = False           # a gate after every walk, and the
+    #                                   expected-exit loss over the walks
+    exit_beta: float = 0.0            # ... minus this times the entropy of
+    #                                   the exit distribution
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -274,9 +312,30 @@ class DecoderConfig:
                 f"router_input is one of {ROUTER_INPUTS}, routing of "
                 f"{ROUTING}, activation of {tuple(ACTIVATIONS)}")
         first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_experts:
-            raise ValueError(f"held {self.held} is no share of "
-                             f"{self.n_experts} experts")
+        if self.moe_layers and (first < 0 or count < 1
+                                or first + count > self.n_experts):
+            raise ValueError(
+                f"held {self.held} is no share of {self.n_experts} experts: "
+                "a pattern with an \"experts\" layer names the router's "
+                "outputs (n_experts), top_k, d_expert and the (first, "
+                "count) it holds; a dense pattern (no \"experts\" layer) "
+                "leaves all four out")
+        if self.loops < 1 or (self.loops > 1 and (
+                self.moe_layers or "ssm" in mixers or self.mtp
+                or self.diffusion_block)):
+            raise ValueError(
+                f"loops is the walks of the stack, 1 or more (got "
+                f"{self.loops}); a stack walked more than once is built for "
+                "layers that count nothing (no \"experts\" MLP, no "
+                "\"ssm\" mixer), without an MTP block or block diffusion")
+        if self.exit_gate and self.loops < 2:
+            raise ValueError(
+                "exit_gate (the exit distribution over the walks) needs "
+                "loops > 1")
+        if self.sandwich and self.d_shared:
+            raise ValueError(
+                "the sandwich norm reads ONE output of the MLP: not built "
+                "beside a shared expert (d_shared)")
 
     @property
     def kinds(self) -> tuple[tuple[str, str], ...]:
@@ -308,14 +367,17 @@ def _leaves(cfg: DecoderConfig) -> dict:
     block leaf the configuration's kinds need. Group `"layer"`: every
     layer has it — the two norms, unless some layer is a mixer or an
     MLP alone: then a norm's group is `"mixer"` or `"mlp"`, the layers
-    that have that part. An ungated configuration has no gate leaf
-    (`w_gate`, `ws_gate`, `w1`)."""
+    that have that part; `cfg.sandwich` adds `norm1_post` and
+    `norm2_post` with the same groups. An ungated configuration has no
+    gate leaf (`w_gate`, `ws_gate`, `w1`)."""
     d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_expert
     count, groups = cfg.held[1], {g for pair in cfg.kinds
                                   for g in _groups_of(pair)}
     alone = any("none" in pair for pair in cfg.kinds)
     table = {"norm1": ("mixer" if alone else "layer", (d,), "one"),
              "norm2": ("mlp" if alone else "layer", (d,), "one")}
+    if cfg.sandwich:    # the norms after the mixer and after the MLP
+        table.update(norm1_post=table["norm1"], norm2_post=table["norm2"])
     if "attention" in groups:
         table.update(
             wq=("attention", (d, cfg.n_heads * hd), "normal"),
@@ -397,7 +459,7 @@ _KEY_OF = {name: i for i, name in enumerate((
 _LATER = ("conv_in", "conv_taps", "conv_out", "w1", "w3", "w2")
 _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "ws_down", "proj", "ssm_in", "ssm_conv", "ssm_conv_bias", "A_log",
-          "dt_bias", "ssm_out")
+          "dt_bias", "ssm_out", "exit_gate")
 _MTP_KEY = 1 << 16
 _NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
@@ -418,7 +480,8 @@ def init(key, cfg: DecoderConfig):
     log-uniform draw in `cfg.ssm_dt_range` (Mamba-2's own start: a head
     forgets over 1 / (dt A), between a handful and a thousand
     positions); a block leaf is stacked on axis 0 over the layers that
-    have it, experts on axis 1 (the held ones only)."""
+    have it, experts on axis 1 (the held ones only); the exit gate's
+    weight normal(0, init_std) as a matrix is, its bias zero."""
     keys = list(jax.random.split(key, 12))
     later = dict(zip(_LATER, jax.random.split(keys[10], len(_LATER))))
 
@@ -456,6 +519,10 @@ def init(key, cfg: DecoderConfig):
         shape = (cfg.d_model, cfg.vocab_size)
         params["head"] = draw(
             "head", shape[::-1] if cfg.head_rows else shape, "normal")
+    if cfg.exit_gate:   # Linear(d_model, 1) with bias, one for all walks
+        params["exit_gate"] = {
+            "w": draw("exit_gate", (cfg.d_model,), "normal"),
+            "b": jnp.zeros(())}
     if cfg.mtp:
         d = cfg.d_model
         params["mtp"] = {
@@ -614,6 +681,14 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
     hd = cfg.head_dim
     cast = functools.partial(jnp.asarray, dtype=h.dtype)
     found = {}
+
+    def joined(h, y, post: str):
+        """The residual sum; under `cfg.sandwich` of the part's output
+        normed by the layer's `post` leaf."""
+        if cfg.sandwich:
+            y = rmsnorm(y, cast(p[post]), cfg.rms_eps)
+        return h + y
+
     if attention != "none":
         x = rmsnorm(h, cast(p["norm1"]), cfg.rms_eps)
     if mlp == "experts" and cfg.router_input == "mixer":
@@ -621,14 +696,14 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
     if attention == "ssm":
         with jax.named_scope("mixer_ssm"):
             y, stats = _ssm_mixer(x, p, cfg)
-            h, found = h + y, {**found, **stats}
+            h, found = joined(h, y, "norm1_post"), {**found, **stats}
     elif attention == "conv":
         with jax.named_scope("mixer_conv"):
             y = short_conv(x @ cast(p["conv_in"]), p["conv_taps"])
-            h = h + y @ cast(p["conv_out"])
+            h = joined(h, y @ cast(p["conv_out"]), "norm1_post")
     elif attention == "latent":
         with jax.named_scope("attention_latent"):
-            h = h + _latent_attention(x, p, rope, cfg)
+            h = joined(h, _latent_attention(x, p, rope, cfg), "norm1_post")
     elif attention != "none":
         with jax.named_scope("attention_" + attention):
             q = (x @ cast(p["wq"])).reshape(b, t, cfg.n_heads, hd)
@@ -643,12 +718,13 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
                 cfg.window if attention == "window" else None,
                 cfg.diffusion_block or None)
-            h = h + a.reshape(b, t, cfg.n_heads * hd) @ cast(p["wo"])
+            h = joined(h, a.reshape(b, t, cfg.n_heads * hd) @ cast(p["wo"]),
+                       "norm1_post")
     if mlp != "none":
         y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
     if mlp == "dense":
         with jax.named_scope("mlp_dense"):
-            h = h + _mlp(y, p, cfg, "w3", "w2", "w1")
+            h = joined(h, _mlp(y, p, cfg, "w3", "w2", "w1"), "norm2_post")
     elif mlp == "experts":
         if cfg.router_input == "mlp":
             logits = _router(y, p)
@@ -658,7 +734,8 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
             cast(p["w_down"]), top_k=cfg.top_k, held=cfg.held,
             tile=cfg.gmm_tile, activation=cfg.activation,
             bias=p.get("expert_bias"), scale=cfg.routed_scale)
-        h, found = h + m.reshape(b, t, d), {**found, **counts}
+        h = joined(h, m.reshape(b, t, d), "norm2_post")
+        found = {**found, **counts}
         if cfg.d_shared:
             with jax.named_scope("mlp_shared"):
                 # what every chip of the deployment computes alike:
@@ -693,7 +770,12 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     `bias`: the selection bias [MoE layers, n_experts], where the
     routing has one (the MTP block's row, the last, is not read here).
     With an ssm mixer the counts also hold `ssm_log_decay_min` and
-    `ssm_dt_max`, scalars over all its layers."""
+    `ssm_dt_max`, scalars over all its layers.
+
+    With `cfg.loops` = T > 1 the same layers are walked T times, the
+    final norm after EVERY walk, its output the next walk's input and
+    the head's: -> (every walk's NORMED output [T, B, T_seq, D], no
+    counts: such layers count nothing)."""
     kinds, lead, period = cfg.kinds, len(cfg.lead_attention), \
         len(cfg.attention)
     h = params["embed"][tokens].astype(cfg.dtype)
@@ -718,41 +800,61 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
             into.setdefault(key, []).append(x)
         return h
 
-    led = {}
-    for at in range(lead):          # the leading layers, one by one
-        h = run(_block(cfg, *kinds[at]), h, rows(layers, at, kinds[:at]), led)
-    led = {key: [x[None] for x in led[key]] for key in sorted(led)}
-    blocks = [_block(cfg, *pair) for pair in kinds[lead:lead + period]]
+    def stack(h):
+        """One walk of the layers, top down -> (h, counts)."""
+        led = {}
+        for at in range(lead):          # the leading layers, one by one
+            h = run(_block(cfg, *kinds[at]), h,
+                    rows(layers, at, kinds[:at]), led)
+        led = {key: [x[None] for x in led[key]] for key in sorted(led)}
+        blocks = [_block(cfg, *pair) for pair in kinds[lead:lead + period]]
 
-    def one_period(h, p):
-        into = {}
-        for j, fn in enumerate(blocks):
-            h = run(fn, h, rows(p, lead + j, kinds[lead:lead + j]), into)
-        return h, {key: jnp.stack(into[key]) for key in sorted(into)}
+        def one_period(h, p):
+            into = {}
+            for j, fn in enumerate(blocks):
+                h = run(fn, h, rows(p, lead + j, kinds[lead:lead + j]), into)
+            return h, {key: jnp.stack(into[key]) for key in sorted(into)}
 
-    def periods(name, per):
-        """A leaf's stack past the leading layers' rows, by period."""
-        x = layers[name]
-        led = _layers_with(cfg, group_of[name], kinds[:lead])
-        return (x[led:] if led else x).reshape(-1, per, *x.shape[1:])
+        def periods(name, per):
+            """A leaf's stack past the leading layers' rows, by period."""
+            x = layers[name]
+            led = _layers_with(cfg, group_of[name], kinds[:lead])
+            return (x[led:] if led else x).reshape(-1, per, *x.shape[1:])
 
-    in_period = {name: _layers_with(cfg, group_of[name],
-                                    kinds[lead:lead + period])
-                 for name in sorted(layers)}
-    h, scanned = lax.scan(one_period, h, {
-        name: periods(name, per) for name, per in in_period.items() if per})
-    scanned = jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), scanned)
+        in_period = {name: _layers_with(cfg, group_of[name],
+                                        kinds[lead:lead + period])
+                     for name in sorted(layers)}
+        h, scanned = lax.scan(one_period, h, {
+            name: periods(name, per) for name, per in in_period.items()
+            if per})
+        scanned = jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]),
+                               scanned)
 
-    def whole(key):
-        """The leading layers' rows, then the periods'."""
-        parts = led.get(key, []) + ([scanned[key]] if key in scanned else [])
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        def whole(key):
+            """The leading layers' rows, then the periods'."""
+            parts = led.get(key, []) + (
+                [scanned[key]] if key in scanned else [])
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    counts = {key: whole(key) for key in sorted({*led, *scanned})}
-    if "ssm_dt_max" in counts:      # scalars over the layers that have one
-        counts.update(ssm_log_decay_min=counts["ssm_log_decay_min"].min(),
-                      ssm_dt_max=counts["ssm_dt_max"].max())
-    return h, counts
+        counts = {key: whole(key) for key in sorted({*led, *scanned})}
+        if "ssm_dt_max" in counts:      # scalars over the layers that have one
+            counts.update(ssm_log_decay_min=counts["ssm_log_decay_min"].min(),
+                          ssm_dt_max=counts["ssm_dt_max"].max())
+        return h, counts
+
+    if cfg.loops == 1:
+        return stack(h)
+
+    def walk(h, _):
+        x = rmsnorm(stack(h)[0], params["norm_f"].astype(h.dtype),
+                    cfg.rms_eps)
+        return x, x
+
+    # a scan over the walks around the scan over the periods: the weights
+    # are constants of both, so a period is traced once whatever `loops`
+    # and the backward pass adds each walk's gradient stack into ONE
+    _, walks = lax.scan(walk, h, None, length=cfg.loops)
+    return walks, {}
 
 
 def _head(params, cfg: DecoderConfig, dtype):
@@ -765,9 +867,11 @@ def _head(params, cfg: DecoderConfig, dtype):
 
 def apply(params, tokens, cfg: DecoderConfig, bias=None):
     """tokens [B, T] -> float32 logits [B, T, vocab] (whole: for tests
-    and small sizes; the loss below never builds them at once)."""
+    and small sizes; the loss below never builds them at once); with
+    `cfg.loops` > 1 every walk's, [loops, B, T, vocab]."""
     h, _ = hidden(params, tokens, cfg, bias)
-    x = rmsnorm(h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
+    x = h if cfg.loops > 1 else rmsnorm(
+        h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
     return jnp.dot(x, _head(params, cfg, x.dtype),
                    preferred_element_type=jnp.float32)
 
@@ -816,8 +920,11 @@ def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
     position's logits are never formed. With an MTP block the loss is
     that mean plus `cfg.mtp_weight` times the block's mean over ITS
     B * (T - 2) positions (token i + 2 from position i), `counts` gains
-    the block's row last and the two terms as `loss_main`, `loss_mtp`."""
+    the block's row last and the two terms as `loss_main`, `loss_mtp`.
+    With `cfg.loops` > 1 the loss is `loop_loss`'s."""
     h, counts = hidden(params, tokens, cfg, bias)
+    if cfg.loops > 1:
+        return loop_loss(h, tokens, params, cfg)
     x = rmsnorm(h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
     if not cfg.mtp:
         return _mean_nll(x, tokens, 1, params, cfg)[0], counts
@@ -848,12 +955,14 @@ def _nll_sum(x, targets, weight, head, cfg: DecoderConfig):
     """The cross-entropy of row i of x [n, D] (normed) against
     `targets[i]`, times `weight[i]` (None: one), summed over the rows in
     chunks of `cfg.loss_chunk`, each chunk's logits recomputed in the
-    backward pass."""
+    backward pass. A `weight` [n, k] gives k sums of the one
+    cross-entropy, [k]; a gradient reaches the weight as it does x."""
     chunk = min(cfg.loss_chunk, x.shape[0])
     pad = -x.shape[0] % chunk
     x = jnp.pad(x, ((0, pad), (0, 0)))
-    weight = jnp.pad(jnp.ones_like(targets, jnp.float32)
-                     if weight is None else weight, (0, pad))
+    if weight is None:
+        weight = jnp.ones_like(targets, jnp.float32)
+    weight = jnp.pad(weight, ((0, pad),) + ((0, 0),) * (weight.ndim - 1))
     targets = jnp.pad(targets, (0, pad))
 
     @jax.checkpoint
@@ -861,14 +970,75 @@ def _nll_sum(x, targets, weight, head, cfg: DecoderConfig):
         logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
         nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
             logits, targets[:, None], axis=-1)[:, 0]
-        return (nll * weight).sum()
+        return (nll.reshape(nll.shape + (1,) * (weight.ndim - 1))
+                * weight).sum(0)
 
     def body(total, part):
         return total + nll_sum(*part), None
 
-    total, _ = lax.scan(body, jnp.zeros((), jnp.float32), tuple(
-        z.reshape(-1, chunk, *z.shape[1:]) for z in (x, targets, weight)))
+    total, _ = lax.scan(body, jnp.zeros(weight.shape[1:], jnp.float32),
+                        tuple(z.reshape(-1, chunk, *z.shape[1:])
+                              for z in (x, targets, weight)))
     return total
+
+
+# ----------------------------------------------------------------------
+# a stack walked several times: the exit gate and the expected-exit loss
+# ----------------------------------------------------------------------
+
+def exit_distribution(walks, params):
+    """Every walk's normed output [T, B, L, D] -> p [T, B, L] float32,
+    the distribution over the walks a token exits after: the gate's
+    `lam_t = sigmoid(w . x_t + b)` (ONE gate, float32), the survival
+    `S_t = prod over j <= t of (1 - lam_j)`, `p(t) = lam_t S_(t-1)` for
+    t < T and `p(T) = S_(T-1)`: the last walk takes what is left, its
+    own lam is never used, and the T sum to one."""
+    gate = params["exit_gate"]
+    with jax.named_scope("exit_gate"):
+        lam = jax.nn.sigmoid(jnp.dot(
+            walks[:-1].astype(jnp.float32), gate["w"],
+            precision=lax.Precision.HIGHEST) + gate["b"])
+        survival = jnp.cumprod(1.0 - lam, axis=0)          # S_1 .. S_(T-1)
+        before = jnp.concatenate([jnp.ones_like(lam[:1]), survival[:-1]])
+        return jnp.concatenate([lam * before, survival[-1:]])
+
+
+def loop_loss(walks, tokens, params, cfg: DecoderConfig):
+    """The loss of a stack walked T = `cfg.loops` times, from `hidden`'s
+    [T, B, L, D] -> (loss, counts). With `l_t[i]` walk t's next-token
+    cross-entropy at position i (the last position unscored, n = B (L -
+    1) targets): under `cfg.exit_gate` the expected-exit loss `sum over i
+    of (sum over t of p_i(t) l_t[i] - cfg.exit_beta H(p_i)) / n`, `H` the
+    entropy of `exit_distribution`'s p at the token — T passes of the
+    chunked head, ONE scan over the walks, the weight a token carrying
+    its gradient to the gate and, through the gate's input, to every
+    walk before it; without a gate the last walk's mean cross-entropy.
+    The counts, for the epoch counters (no gradient): `loop_nll` [T]
+    (sum over i of l_t[i]), `exit_mass` [T] (sum of p(t)),
+    `exit_entropy` (sum of H)."""
+    t, b, length, _ = walks.shape
+    n = b * (length - 1)
+    with jax.named_scope("logits_loss"):
+        head = _head(params, cfg, walks.dtype)
+        targets = tokens[:, 1:].reshape(n)
+        if not cfg.exit_gate:
+            x = walks[-1, :, :-1].reshape(n, -1)
+            return _nll_sum(x, targets, None, head, cfg) / n, {}
+        scored = walks[:, :, :-1]
+        p = exit_distribution(scored, params).reshape(t, n)
+        entropy = -(p * jnp.log(jnp.maximum(
+            p, jnp.finfo(jnp.float32).tiny))).sum(0)
+
+        def one_walk(_, part):
+            x, p_t = part       # the weighted sum, and the plain one
+            return None, _nll_sum(x, targets, jnp.stack(
+                [p_t, jnp.ones_like(p_t)], axis=1), head, cfg)
+
+        _, sums = lax.scan(one_walk, None, (scored.reshape(t, n, -1), p))
+    loss = (sums[:, 0].sum() - cfg.exit_beta * entropy.sum()) / n
+    return loss, lax.stop_gradient({
+        "loop_nll": sums[:, 1], "exit_mass": p.sum(1),
+        "exit_entropy": entropy.sum()})
 
 
 # ----------------------------------------------------------------------
@@ -931,7 +1101,8 @@ def counters_init(cfg: DecoderConfig):
     """The model state of the operator's stateful form: `{"epoch_counters":
     {...}}`, scalars the step updates on the device, zeroed by the
     operator when an epoch starts and read once in `train.sync`, each
-    onto that span under its key:
+    onto that span under its key. A pattern with an `experts` layer
+    counts:
 
     `moe_assignments` (tokens x top_k x MoE layers x steps),
     `moe_assignments_held` (those that fell on a held expert),
@@ -961,15 +1132,29 @@ def counters_init(cfg: DecoderConfig):
     `diffusion_weight_max` (the largest 1 / p that met a masked token in
     the epoch: what one target can weigh), and its state holds, OUTSIDE
     the epoch counters, `noise_seed` and `noise_step` (`state_init`).
-    The configurations from before the block keep the state tree their
-    recorded programs were lowered with."""
+    A DENSE pattern (no `experts` layer anywhere) makes none of the
+    `moe_*` counters and counts no routing. A stack walked `cfg.loops` =
+    T > 1 times under an exit gate counts, float32 sums over the epoch's
+    steps: `loop_nll_1` .. `loop_nll_T` (walk t's cross-entropy summed
+    over the tokens scored, before any weight), `exit_mass_1` ..
+    `exit_mass_T` (the exit distribution's p(t) summed over them: the T
+    add up to `loop_targets`), `exit_entropy` (its entropy summed; at
+    most `loop_targets` x log T) and `loop_targets` (tokens scored: B x
+    (L - 1) a step). The configurations from before each of these keep
+    the state tree their recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
     i32 = functools.partial(jnp.zeros, (), jnp.int32)
-    counters = {
-        "moe_assignments": f32(), "moe_assignments_held": f32(),
-        "moe_assignments_dropped": f32(), "moe_expert_tokens_max": i32(),
-        "moe_expert_tokens_mean": f32(), "moe_experts_held": i32(),
-        "moe_experts_total": i32(), "moe_steps": i32()}
+    counters = {}
+    if cfg.moe_layers:
+        counters.update({
+            "moe_assignments": f32(), "moe_assignments_held": f32(),
+            "moe_assignments_dropped": f32(), "moe_expert_tokens_max": i32(),
+            "moe_expert_tokens_mean": f32(), "moe_experts_held": i32(),
+            "moe_experts_total": i32(), "moe_steps": i32()})
+    if cfg.exit_gate:
+        counters.update({f"{name}_{t + 1}": f32() for t in range(cfg.loops)
+                         for name in ("loop_nll", "exit_mass")},
+                        exit_entropy=f32(), loop_targets=f32())
     if cfg.mtp:
         counters.update(loss_main=f32(), loss_mtp=f32())
     if cfg.mtp or cfg.count_rows:
@@ -992,9 +1177,15 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     `attention_tiles_visited` / `attention_tiles_plane` (the score tiles
     the forward kernel's loops walk of one head's 2 L x 2 L plane, and
     the plane's: `ops.attention.diffusion_tiles`, the kernel's own
-    bounds); nothing otherwise."""
+    bounds); where the stack is walked more than once `loops`,
+    `layer_passes` (loops x layers: the blocks a step runs forward) and
+    `head_passes` (passes of the chunked head: one a walk under an exit
+    gate, else one); nothing otherwise."""
     b, t = batch_shape
     facts = {}
+    if cfg.loops > 1:
+        facts.update(loops=cfg.loops, layer_passes=cfg.loops * cfg.n_layers,
+                     head_passes=cfg.loops if cfg.exit_gate else 1)
     layers = sum(a == "ssm" for a, _ in cfg.kinds)
     if layers:
         facts.update(ssm_layers=layers,
@@ -1036,19 +1227,8 @@ def state_init(key, cfg: DecoderConfig):
     return state
 
 
-def stateful_loss(params, state, tokens, cfg: DecoderConfig):
-    """`loss_fn` in the operator's stateful form: the step's counts go
-    into the state's running ones, and the selection bias, where there
-    is one, makes its step after the loss (`parallel/moe.py::
-    balance_bias`)."""
-    bias = state.get("expert_bias")
-    if cfg.diffusion_block:
-        loss, counts = diffusion_loss(params, tokens, cfg,
-                                      state["noise_seed"],
-                                      state["noise_step"], bias)
-    else:
-        loss, counts = loss_fn(params, tokens, cfg, bias)
-    old = state["epoch_counters"]
+def _routing_counters(old, counts, cfg: DecoderConfig, bias) -> dict:
+    """The `moe_*` counters after a step that counted `counts`."""
     steps = old["moe_steps"] + 1
     tokens_mean = counts["expert_tokens"].astype(jnp.float32).mean()
     new = {
@@ -1066,6 +1246,32 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
         names.append(("moe_assignments_bias_moved", "bias_moved"))
     for name, key in names:
         new[name] = old[name] + counts[key].sum().astype(jnp.float32)
+    return new
+
+
+def stateful_loss(params, state, tokens, cfg: DecoderConfig):
+    """`loss_fn` in the operator's stateful form: the step's counts go
+    into the state's running ones, and the selection bias, where there
+    is one, makes its step after the loss (`parallel/moe.py::
+    balance_bias`). A dense pattern counts no routing; a looped one
+    under an exit gate counts what `loop_loss` found."""
+    bias = state.get("expert_bias")
+    if cfg.diffusion_block:
+        loss, counts = diffusion_loss(params, tokens, cfg,
+                                      state["noise_seed"],
+                                      state["noise_step"], bias)
+    else:
+        loss, counts = loss_fn(params, tokens, cfg, bias)
+    old = state["epoch_counters"]
+    new = _routing_counters(old, counts, cfg, bias) if cfg.moe_layers else {}
+    if cfg.exit_gate:
+        for name in ("loop_nll", "exit_mass"):
+            new.update({f"{name}_{t + 1}": old[f"{name}_{t + 1}"]
+                        + counts[name][t] for t in range(cfg.loops)})
+        new.update(
+            exit_entropy=old["exit_entropy"] + counts["exit_entropy"],
+            loop_targets=old["loop_targets"] + float(
+                tokens.shape[0] * (tokens.shape[1] - 1)))
     if cfg.mtp:
         new.update(loss_main=counts["loss_main"],
                    loss_mtp=counts["loss_mtp"])

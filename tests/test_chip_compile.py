@@ -768,3 +768,50 @@ def test_pallas_ring_tier_is_refused_by_the_chips_compiler(topo, on_tpu,
                              sharding=NamedSharding(mesh, spec))
     with pytest.raises(Exception, match="Loads are only allowed on VMEM"):
         ops.allreduce(x, ReduceOp.SUM)
+
+
+def test_looped_decoder_step_fits_the_chip_at_the_batch_shipped(
+        one_chip, on_tpu, tmp_path):
+    """The cell `ouro_d8_loop4_seq4k`'s fused step (loss, gradients,
+    AdamW, donated state) at the published widths and the batch its file
+    ships, compiled for the described chip: at most 13.5 GiB by the
+    buffer assignment (the cells' batch rule), and ONE trace of the
+    period whatever the walks — the forward kernel twice (a block and
+    its rematerialised copy), the backward once, not once a walk."""
+    import glob
+    import re
+
+    from benchmark import manifest
+
+    cell = manifest.cell("ouro_d8_loop4_seq4k")
+    p = cell["family"].pieces(cell["model"], cell["workload"], 7)
+    params, state = jax.eval_shape(p.model_init, jax.random.key(0))
+    opt = jax.eval_shape(p.optimizer.init, params)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def fused(params, mstate, opt_state, batch):
+        (loss, mstate), grads = jax.value_and_grad(
+            p.loss_fn, has_aux=True)(params, mstate, batch)
+        updates, opt_state = p.optimizer.update(grads, opt_state, params)
+        params = jax.tree.map(lambda a, u: a + u, params, updates)
+        return params, mstate, opt_state, loss
+
+    compiled = jax.jit(fused, donate_argnums=(0, 1, 2)).lower(
+        on_chip(params), on_chip(state), on_chip(opt),
+        on_chip(p.batch)).compile(compiler_options={
+            "xla_dump_to": str(tmp_path), "xla_dump_hlo_as_text": True})
+    reports = glob.glob(str(tmp_path / "*memory-usage-report.txt"))
+    assert reports
+    used = max(int(re.search(r"Total bytes used: (\d+)",
+                             open(r).read()).group(1)) for r in reports)
+    assert used <= 13.5 * 2 ** 30, used / 2 ** 30
+    assert cell["workload"]["aot_step_GiB"][
+        str(cell["workload"]["batch"])] == pytest.approx(used / 2 ** 30,
+                                                         abs=0.05)
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("/flash_fwd/pallas_call" in k for k in kernels) == 2
+    assert sum("/flash_bwd_fused/pallas_call" in k for k in kernels) == 1
