@@ -272,6 +272,8 @@ class CoreWorker:
         self._actor_reorder: dict[bytes, dict] = {}  # caller -> {next, heap}
         self._async_loop: rpc.EventLoopThread | None = None
         self._exec_pool = None  # ThreadPoolExecutor when max_concurrency>1
+        self._lanes: dict = {}  # one-thread pools by name (_task_lane)
+        self._lanes_lock = threading.Lock()
         # live-execution registry (debug_state): tasks currently inside
         # _exec_scope on any execution lane, keyed by a per-entry token
         # (GIL-atomic dict ops; no lock on the execution hot path)
@@ -2437,7 +2439,35 @@ class CoreWorker:
             return
         # actor tasks keep strict seq order even when args are pending
         M_EXEC_HOPS.inc()
+        lane = self._task_lane(spec)
+        if lane is not None:
+            lane.submit(lambda: complete(self._execute_task(spec)))
+            return
         self._exec_queue.put((spec, complete))
+
+    def _task_lane(self, spec):
+        """An actor may name a LANE for a call (`task_lane(method_name)`
+        -> a name or None, asked when the call ARRIVES): a thread of its
+        own on which the calls of that name run, in arrival order among
+        themselves, beside whatever the dispatcher is running — it may be
+        inside an earlier call for as long as that takes (TrainWorker:
+        the pieces of a held state, pulled while an epoch runs). None —
+        an actor that names no lanes, every other call — is the actor's
+        one lane: the dispatcher's queue."""
+        if spec["type"] != common.ACTOR_TASK or self._actor_instance is None:
+            return None
+        name = getattr(type(self._actor_instance), "task_lane", None)
+        if name is not None:
+            name = name(self._actor_instance, spec["method_name"])
+        if name is None:
+            return None
+        with self._lanes_lock:
+            lane = self._lanes.get(name)
+            if lane is None:
+                lane = self._lanes[name] = (
+                    concurrent.futures.ThreadPoolExecutor(
+                        1, thread_name_prefix=f"actor-lane-{name}"))
+        return lane
 
     def _dispatch_when_args_ready(self, spec, complete):
         waiting = []

@@ -18,6 +18,7 @@ register :187, train_epoch :437), redesigned jax-first:
 
 from __future__ import annotations
 
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Any, Callable, Iterable
@@ -34,6 +35,12 @@ from ray_tpu._private import tracing as _tracing
 _LEAF_SPAN_BYTES = 1 << 20
 # joined leaves start on multiples of this in the staging area
 _STAGE_ALIGN = 64
+# A copy of the state is held beside the step (`_room_to_hold`) only if
+# this share of every device's memory stays free of both: the allocator
+# needs slack, and the step's peak is one reading, not a bound.
+_HOLD_MARGIN = 1 / 16
+# how long an epoch's end waits for the pull of the last held copy
+_HOLD_WAIT_S = 600.0
 
 
 class TrainingOperator:
@@ -103,6 +110,10 @@ class TrainingOperator:
         self._stage = None      # the staging area (_staging), once needed
         self._joiners = None    # the join's threads (_join_pool), likewise
         self._join_width = 0    # ... and how many they are
+        self._holds = None      # the room rule's answer (_room_to_hold)
+        self._held = None       # the copy taken at the last epoch's end
+        self._pull_open = False     # ... is being pulled (state_piece)
+        self._held_cv = threading.Condition()
         self._loss_fn = loss_fn
         self._eval_fn = eval_fn
         self._optimizer = optimizer
@@ -315,6 +326,14 @@ class TrainingOperator:
             return update(unravel(flat_grads), opt_state, params)
 
         self._apply_step = jax.jit(apply_step, donate_argnums=(0, 1))
+
+        # The held copy (`_hold`): the whole state once more on the
+        # devices, laid out as it is; nothing donated.
+        def copy_state(params, mstate, opt_state):
+            return jax.tree.map(jnp.copy, (params, mstate, opt_state))
+
+        self._copy_out = self._fused_out and self._fused_out[:3]
+        self._copy_state = jax.jit(copy_state, out_shardings=self._copy_out)
         if self._sharded:
             ws = self.world_size
             pad = self._pad_numel - self._numel
@@ -587,6 +606,7 @@ class TrainingOperator:
             if profile_dir:
                 self.stop_profile()
         self.epoch += 1
+        held = self._hold()
         out = {
             "epoch": self.epoch,
             "batch_count": len(losses),
@@ -597,7 +617,126 @@ class TrainingOperator:
         }
         if self._epoch_counters:
             out["counters"] = counters
+        if held:
+            out["held_epoch"] = self.epoch
         return out
+
+    # ------------------------------------------------------------------
+    # the held copy: the state pulled beside the NEXT epoch
+    # ------------------------------------------------------------------
+
+    def _device_memory(self) -> list:
+        """`memory_stats()` of every device the state lives on (None
+        where the backend keeps no count: the CPU)."""
+        devices = {d for x in jax.tree.leaves(
+            (self.params, self.model_state, self.opt_state))
+            if isinstance(x, jax.Array)
+            for d in x.sharding.addressable_devices}
+        return [d.memory_stats() for d in devices]
+
+    def _room_to_hold(self) -> bool:
+        """THE RULE, read once, after the first epoch: a second copy of
+        the state is held on the devices iff, on every device the state
+        lives on, the state's bytes on the fullest device fit beside
+        the most the device has held so far — the step's peak: live
+        buffers, or what is live now plus the runtime's reservation for
+        programs' temporaries, whichever is more — with `_HOLD_MARGIN`
+        of its memory to spare. Nothing else decides it: no name, no
+        size chosen for a cell, no option. A backend that keeps no
+        count (the CPU) has no room; neither has an operator that does
+        not own its whole state (a host-collective group's rank)."""
+        if self.world_size != 1 or self._sharded:
+            return False
+        need = self._layout_facts()["state_bytes_fullest_chip"]
+        stats = self._device_memory()
+        for s in stats:
+            if (not s or s.get("bytes_limit") is None
+                    or s.get("peak_bytes_in_use") is None):
+                return False
+            peak = max(s["peak_bytes_in_use"], s.get("bytes_in_use", 0)
+                       + s.get("bytes_reserved", 0))
+            if peak + need + _HOLD_MARGIN * s["bytes_limit"] \
+                    > s["bytes_limit"]:
+                return False
+        return bool(stats)
+
+    @property
+    def holds_state(self) -> bool:
+        """A copy of the state, as an epoch left it, is on the devices."""
+        return self._held is not None
+
+    def _hold(self) -> bool:
+        """At an epoch's end, where the devices have the room
+        (`_room_to_hold`): one jitted copy of the whole state, beside
+        the live one, which `state_piece(of_epoch=)` reads while the
+        NEXT epoch's steps donate and overwrite the live buffers. The
+        copy is dispatched, not waited for: the device runs it before
+        the next step. A copy that was pulled went when its last piece
+        was read (`end_pull`); one that still is being pulled (the pull
+        may outlast its epoch) is waited for, one nobody asked for is
+        let go first: never two copies. With no room this is one
+        attribute read: no span, no program."""
+        if self._holds is None:
+            self._holds = self._room_to_hold()
+        if not self._holds:
+            return False
+        counts = {"epoch": self.epoch,
+                  "bytes": self._layout_facts()["state_bytes"]}
+        with _tracing.span("train.hold", _tracing.child_of_current(),
+                           counts):
+            t0 = time.perf_counter()
+            with self._held_cv:
+                if not self._held_cv.wait_for(
+                        lambda: not self._pull_open, _HOLD_WAIT_S):
+                    raise RuntimeError(
+                        "the pull of the held state of epoch "
+                        f"{self._held['epoch']} has not ended after "
+                        f"{_HOLD_WAIT_S:.0f} s (Trainer: end_pull)")
+                self._held = None
+            t1 = time.perf_counter()
+            copy = self._cached_step("hold", "state", self._copy_state,
+                                     out_shardings=self._copy_out)
+            params, mstate, opt_state = copy(
+                self.params, self.model_state, self.opt_state)
+            # of the span: waiting for a pull to end (and letting an
+            # unread copy go), dispatching the copy (the first: building)
+            counts.update(wait_s=t1 - t0, copy_s=time.perf_counter() - t1)
+            self._held = {
+                "params": params, "model_state": mstate,
+                "epoch": self.epoch, "global_step": self.global_step,
+                "opt_state": opt_state}
+        return True
+
+    def expect_pull(self, of_epoch: int) -> bool:
+        """The copy held of `of_epoch` is about to be pulled (the driver
+        says so with the epoch it submits before the first piece): the
+        pull is open from now, so an epoch that ends before the first
+        piece has even arrived keeps the copy for it."""
+        with self._held_cv:
+            if self._held is not None and self._held["epoch"] == of_epoch:
+                self._pull_open = True
+        return self._pull_open
+
+    def end_pull(self) -> bool:
+        """The pull of the held copy is over (its last piece closes it
+        by itself; a driver whose pull failed half-way says so here):
+        nobody reads the copy again, so it goes now — its device buffers
+        and the host copies jax keeps of arrays it has converted, off
+        the epoch's thread, which finds little to free at its end (the
+        last piece's leaves, still on their way into the store)."""
+        with self._held_cv:
+            self._held = None
+            self._pull_open = False
+            self._held_cv.notify_all()
+        # jax frees what its own threads let go of (the host buffers of
+        # finished transfers) in the NEXT jitted call of the process,
+        # whichever thread makes it: 68 ms for GPT-2 small's 1.4 GB
+        # inside the epoch's own `_hold`, read in a kept profile. Here
+        # it is the pull's thread that pays.
+        from jax._src.lib import xla_client
+
+        xla_client._xla.collect_garbage()
+        return True
 
     def validate(self, num_steps: int | None = None) -> dict:
         if self._val_loader is None:
@@ -751,10 +890,16 @@ class TrainingOperator:
         with _tracing.span("train.snapshot.d2h", ctx, counts):
             return self._to_host(self._state_tree(), counts, ctx)
 
-    def state_piece(self, index: int, usable: int, drop=()) -> dict:
+    def state_piece(self, index: int, usable: int, drop=(),
+                    of_epoch: int | None = None) -> dict:
         """Piece `index` of the state as `train/snapshot.py` cuts it for
         a store that holds `usable` bytes: only this piece's leaves are
         brought to the host (one `train.snapshot.d2h` span a piece).
+        With `of_epoch`, of the copy HELD since that epoch's end
+        (`_hold`) and not of the live state, which the next epoch may be
+        stepping on meanwhile; the held copy stays until its last piece
+        has been read (or `end_pull`), and a copy of another epoch is
+        an error, never another epoch's bytes.
         Before this piece is waited for, the transfers of the TWO pieces
         after it are started behind its own: the link moves more with a
         second piece queued than with one alone, and they run under the
@@ -765,19 +910,51 @@ class TrainingOperator:
         (`_join_pool`), all of which have ended when this returns: they
         are good until the NEXT `state_piece` call and no
         longer. That is what the actor's lane gives: `TrainWorker` runs
-        one method at a time and the runtime has serialised and copied a
-        reply into the store before it starts the next. Who keeps what
-        he gets calls `state_dict` (or copies)."""
+        one `state_piece` at a time and the runtime has serialised and
+        copied a reply into the store before it starts the next (an
+        epoch that runs beside them, on the actor's other lane, touches
+        neither the area nor the held copy). Who keeps what he gets
+        calls `state_dict` (or copies)."""
+        if of_epoch is not None:
+            return self._held_piece(index, usable, drop, of_epoch)
+        return self._piece(self._state_tree(drop), index, usable)[0]
+
+    def _piece(self, tree: dict, index: int, usable: int) -> tuple:
+        """Piece `index` of `tree` (the live state's, or the held
+        copy's), brought to the host; and whether it is the last."""
         from ray_tpu.train import snapshot as _snapshot
 
         counts = dict(_d2h_counts(), piece=index)
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
-            whole = _snapshot.cut(self._state_tree(drop), usable)
+            whole = _snapshot.cut(tree, usable)
             part = self._to_host(
                 whole.part(index), counts, ctx, room=_stage_room(whole),
                 ahead=whole.part(index + 1) + whole.part(index + 2))
-        return whole.reply(index, part)
+        return whole.reply(index, part), index >= len(whole.ranges) - 1
+
+    def _held_piece(self, index, usable, drop, of_epoch) -> dict:
+        """`state_piece` of the held copy: the same cut, the same
+        transfers, the same span; the pull is open from its first piece
+        to its last (or to one that raises), and `_hold` does not touch
+        the copy meanwhile."""
+        with self._held_cv:
+            held = self._held
+            if held is None or held["epoch"] != of_epoch:
+                raise ValueError(
+                    f"no state is held of epoch {of_epoch} (held: "
+                    f"{held and held['epoch']})")
+            self._pull_open = True
+        last = True
+        try:
+            reply, last = self._piece(
+                {k: v for k, v in held.items() if k not in drop},
+                index, usable)
+            return reply
+        finally:
+            if last:
+                held = None     # the arrays die with the copy
+                self.end_pull()
 
     def load_state_dict(self, state: dict):
         leaves, treedef = jax.tree.flatten(state)
@@ -1003,6 +1180,7 @@ class _LoadPlan:
                 op.load_opt_shard(sh)
         op.epoch = rest["epoch"]
         op.global_step = rest["global_step"]
+        op._held = None     # a copy of the state this one replaced
 
 
 def _batch_size(batch) -> int:

@@ -16,6 +16,7 @@ import pickle
 import time
 import traceback
 import uuid
+from typing import NamedTuple
 
 import cloudpickle
 
@@ -59,6 +60,17 @@ def call_log() -> list[dict]:
     return out
 
 
+class _Pending(NamedTuple):
+    """A `train()` call whose state the worker holds and nobody has
+    pulled yet: the call's number, the worker's epoch after it (which
+    copy `state_piece(of_epoch=)` reads) and the steps it ran (what the
+    elastic restore runs again if the copy dies with its worker)."""
+
+    call: int
+    epoch: int
+    num_steps: int | None
+
+
 class TrainWorker(CollectiveActorMixin):
     """Actor wrapping a TrainingOperator (reference:
     distributed_torch_runner.py DistributedTorchRunner)."""
@@ -86,8 +98,27 @@ class TrainWorker(CollectiveActorMixin):
             group_name=self._group_name)
         return True
 
-    def train_epoch(self, num_steps=None, profile_dir=None):
+    def train_epoch(self, num_steps=None, profile_dir=None, pull_of=None):
+        """`pull_of`: the driver pulls the copy held of that epoch while
+        this one runs; said here, before the first step, so that however
+        short the epoch its end finds the pull open (`expect_pull`)."""
+        if pull_of is not None:
+            self.operator.expect_pull(pull_of)
         return self.operator.train_epoch(num_steps, profile_dir=profile_dir)
+
+    def task_lane(self, method_name):
+        """Where the runtime runs a call (`core_worker._task_lane`: a
+        name is a thread of its own, None the actor's one lane). While
+        the operator holds a copy of its state the `state_piece` calls
+        run on the lane `pull`, one at a time and in order: the driver
+        pulls the held copy while the NEXT epoch runs where every epoch
+        runs, on the actor's one lane, and no dispatch of it queues
+        behind a piece. An operator that holds nothing — no room, the
+        CPU — never has a second lane."""
+        if method_name in ("state_piece", "end_pull") and getattr(
+                self.operator, "holds_state", False):
+            return "pull"
+        return None
 
     def start_profile(self, profile_dir):
         """Trainer.train(profile_dir=) brackets the worker's side of the
@@ -105,8 +136,10 @@ class TrainWorker(CollectiveActorMixin):
     def state_dict(self):
         return self.operator.state_dict()
 
-    def state_piece(self, index, usable, drop=()):
-        """One piece of the operator's state (`train/snapshot.py`). An
+    def state_piece(self, index, usable, drop=(), of_epoch=None):
+        """One piece of the operator's state (`train/snapshot.py`); with
+        `of_epoch`, of the copy the operator holds since that epoch's
+        end (only an operator that said it holds one is asked so). An
         operator that only has `state_dict` gives its host tree, cut the
         same way."""
         from ray_tpu._private import global_state
@@ -116,11 +149,17 @@ class TrainWorker(CollectiveActorMixin):
         usable = min(usable, snapshot.usable_bytes(
             global_state.require_core_worker()))
         own = getattr(self.operator, "state_piece", None)
+        if of_epoch is not None:
+            return own(index, usable, drop, of_epoch)
         if own is not None:
             return own(index, usable, drop)
         state = self.operator.state_dict()
         return snapshot.piece({k: v for k, v in state.items()
                                if k not in drop}, index, usable)
+
+    def end_pull(self):
+        """The driver's pull of the held copy failed half-way."""
+        return self.operator.end_pull()
 
     def load_state_piece(self, first, leaves, treedef=None):
         """The other direction: leaves [first, ...) of a state, in
@@ -211,7 +250,28 @@ class TrainWorker(CollectiveActorMixin):
 
 class Trainer:
     """Data-parallel trainer with elastic fault tolerance (reference:
-    torch_trainer.py:39)."""
+    torch_trainer.py:39).
+
+    Every `train()` call ends with a whole snapshot of the training
+    state installed in the driver, which the elastic restore consumes.
+    Where the worker's devices have no room for a second copy of the
+    state (`TrainingOperator._room_to_hold`; the CPU; a group of
+    several workers) it is this call's: pulled after the epoch, as
+    ever. Where they have, the worker HOLDS a copy at the epoch's end,
+    `train()` returns without pulling, and the NEXT call pulls it
+    beside its own epoch: while call k + 1 runs the installed snapshot
+    is call k - 1's, from the moment the pull lands call k's, and call
+    k + 1 returns with call k's installed — one call older than
+    without. A worker lost before the pull has landed takes the held
+    copy with it: the restore goes back to the installed snapshot and
+    runs up to TWO calls again (the one whose copy was lost, with its
+    own `num_steps`, then the one under way) where it runs one without.
+    What a caller asks for is never stale: `state_dict()`, `save()` and
+    `shutdown()` (not `force=True`, which kills) pull a pending copy
+    first, `load_state_dict()` / `load()` drop it. A pull that raises
+    installs nothing, deferred or not. The very first call, and any
+    call that finds the installed snapshot more than a call behind
+    (after a restore), pulls at once."""
 
     def __init__(self, training_operator_cls, *, num_workers: int = 1,
                  config: dict | None = None,
@@ -312,6 +372,12 @@ class Trainer:
         # first: the older one is the next copy's destination (train);
         # `writes` counts how often its buffers have been written.
         self._owned = ((None, None, 0), (None, None, 0))
+        # train() calls made; the call whose state is installed
+        # (`_last_state`; None: none yet); the call whose state the
+        # worker holds, not pulled yet (a _Pending, see the class)
+        self._calls = 0
+        self._snapshot_of: int | None = None
+        self._pending: _Pending | None = None
         self._start_workers(num_workers)
 
     # ------------------------------------------------------------------
@@ -458,6 +524,14 @@ class Trainer:
             ray_tpu.get([w.load_opt_shard.remote(s)
                          for w, s in zip(self.workers, shards)],
                         timeout=self._setup_timeout)
+        if self._pending is not None:
+            # The state of the last call was held on a worker that is
+            # gone, and never pulled: the snapshot just installed is a
+            # call older. That call again, as it was made (cleared only
+            # once it has run: a worker lost in it is restored again).
+            ray_tpu.get([w.train_epoch.remote(self._pending.num_steps)
+                         for w in self.workers], timeout=600)
+            self._pending = None
 
     def _kill_workers(self):
         for w in self.workers + self._ingest_actors:
@@ -536,7 +610,9 @@ class Trainer:
     _MAX_PLANNED_REGANGS = 8
 
     def _run_with_retries(self, fn_name: str, num_steps,
-                          counts: dict | None = None, **kw):
+                          counts: dict | None = None, refs=None, **kw):
+        """`refs`: the first attempt's calls, if the caller has
+        submitted them already (`_epoch_beside_pull`)."""
         attempt = 0
         planned_regangs = 0
         while True:
@@ -545,9 +621,10 @@ class Trainer:
             try:
                 if not self.workers:
                     raise exc.WorkerCrashedError("worker group is empty")
+                first, refs = refs, None
                 return ray_tpu.get(
-                    [getattr(w, fn_name).remote(num_steps, **kw)
-                     for w in self.workers],
+                    first or [getattr(w, fn_name).remote(num_steps, **kw)
+                              for w in self.workers],
                     timeout=600)
             except (exc.ActorDiedError, exc.WorkerCrashedError,
                     exc.GetTimeoutError):
@@ -585,11 +662,16 @@ class Trainer:
         """One epoch (or `num_steps`) on every worker, then the
         epoch-boundary snapshot the elastic restore consumes. The call
         is ONE trace rooted at `train.call` (always recorded; kept in
-        `call_log()`); `profile_dir` brackets the workers' whole side of
+        `call_log()`; `call` counts this Trainer's calls from 1, and
+        `train.snapshot` says which call's state it pulled, `of_call`,
+        and whether beside this call's epoch, `deferred`: the class
+        docstring); `profile_dir` brackets the workers' whole side of
         the call — epoch, snapshot, return put — with a jax profiler
         session, and turns the per-leaf spans of the snapshot on."""
         root = tracing.always_trace(fine=bool(profile_dir))
-        counts = {"num_steps": num_steps, "workers": len(self.workers)}
+        self._calls += 1
+        counts = {"num_steps": num_steps, "workers": len(self.workers),
+                  "call": self._calls}
         with tracing.open_tree(root) as rows:
             try:
                 with tracing.span("train.call", root, counts, ambient=True):
@@ -603,45 +685,24 @@ class Trainer:
             ray_tpu.get([w.start_profile.remote(profile_dir)
                          for w in self.workers], timeout=120)
         try:
-            counts = {}
-            with tracing.span("train.epoch", tracing.child_of_current(),
-                              counts, ambient=True):
-                results = self._run_with_retries("train_epoch", num_steps,
-                                                 counts)
-            counts = {}
-            with tracing.span("train.snapshot", tracing.child_of_current(),
-                              counts, ambient=True):
-                # Two sets of buffers take turns: this call's views are
-                # copied into the set `_own` built two calls ago. Only
-                # trees `_own` built are in `_owned`, so a caller's
-                # arrays (load_state_dict, load) are never written to;
-                # neither is the newer set, which is the installed
-                # snapshot unless the caller's took its place. Both
-                # parts are installed, and the sets turned, only once
-                # both are whole: a copy that raises changes nothing.
-                newer, older = self._owned
-                spare_state, spare_shards, writes = older
-                # sharded: the epoch-boundary snapshot is params (rank
-                # 0; identical everywhere) + ALL optimizer shards — the
-                # reshardable unit the elastic restore path consumes.
-                # Rank 0's own shard is never kept, so it stays where it
-                # is and the tree matches the spare's.
-                state = self._pull_state(
-                    self.workers[0], spare_state, counts,
-                    drop=("opt_shard",) if self._sharded else (),
-                    writes=writes)
-                shards = None
-                if self._sharded:
-                    shards = _own(ray_tpu.get(
-                        [w.opt_shard_state.remote() for w in self.workers],
-                        timeout=120), spare_shards, writes)
-                self._last_state, self._last_shards = state, shards
-                # a set no leaf of which went into the spare's buffers
-                # (a first call, a changed tree) is new: written once
-                reused = _written_into((state, shards),
-                                       (spare_state, spare_shards))
-                self._owned = ((state, shards, writes + 1 if reused else 1),
-                               newer)
+            if self._pending is None:
+                counts = {}
+                with tracing.span("train.epoch", tracing.child_of_current(),
+                                  counts, ambient=True):
+                    results = self._run_with_retries(
+                        "train_epoch", num_steps, counts)
+            else:
+                results = self._epoch_beside_pull(num_steps)
+            # a worker that holds a copy of this call's state says so
+            # (one worker that owns its whole state: operator._hold)
+            held = [r.pop("held_epoch", None) for r in results]
+            if (len(held) == 1 and held[0] is not None
+                    and self._snapshot_of == self._calls - 1):
+                # ... and the installed snapshot is the last call's:
+                # this call's is pulled beside the next epoch
+                self._pending = _Pending(self._calls, held[0], num_steps)
+            else:
+                self._snapshot(self._calls)
         finally:
             if profile_dir:
                 # a worker restarted mid-call has no session (a no-op);
@@ -653,6 +714,89 @@ class Trainer:
                     pass
         return _reduce(results) if reduce_results else results
 
+    def _snapshot(self, of_call: int, of_epoch: int | None = None):
+        """Pull the state `of_call` left and install it: the worker's
+        live state, or with `of_epoch` the copy it holds since that
+        epoch's end (`deferred` on the span: beside this call's epoch,
+        or a drain)."""
+        counts = {"deferred": int(of_epoch is not None), "of_call": of_call}
+        with tracing.span("train.snapshot", tracing.child_of_current(),
+                          counts, ambient=True):
+            # Two sets of buffers take turns: this call's views are
+            # copied into the set `_own` built two calls ago. Only
+            # trees `_own` built are in `_owned`, so a caller's
+            # arrays (load_state_dict, load) are never written to;
+            # neither is the newer set, which is the installed
+            # snapshot unless the caller's took its place. Both
+            # parts are installed, and the sets turned, only once
+            # both are whole: a copy that raises changes nothing.
+            newer, older = self._owned
+            spare_state, spare_shards, writes = older
+            # sharded: the epoch-boundary snapshot is params (rank
+            # 0; identical everywhere) + ALL optimizer shards — the
+            # reshardable unit the elastic restore path consumes.
+            # Rank 0's own shard is never kept, so it stays where it
+            # is and the tree matches the spare's.
+            state = self._pull_state(
+                self.workers[0], spare_state, counts,
+                drop=("opt_shard",) if self._sharded else (),
+                writes=writes, of_epoch=of_epoch)
+            shards = None
+            if self._sharded:
+                shards = _own(ray_tpu.get(
+                    [w.opt_shard_state.remote() for w in self.workers],
+                    timeout=120), spare_shards, writes)
+            self._last_state, self._last_shards = state, shards
+            self._snapshot_of = of_call
+            # a set no leaf of which went into the spare's buffers
+            # (a first call, a changed tree) is new: written once
+            reused = _written_into((state, shards),
+                                   (spare_state, spare_shards))
+            self._owned = ((state, shards, writes + 1 if reused else 1),
+                           newer)
+
+    def _epoch_beside_pull(self, num_steps) -> list:
+        """A call that finds the last call's state held: the epoch is
+        submitted first, the held copy pulled and installed while it
+        runs (the pieces on a lane of their own: `TrainWorker.task_lane`),
+        then the epoch waited for. A
+        worker lost under the pull takes the copy with it: the epoch's
+        own retries restore the group, and the restore runs the lost
+        call again (`_restore_state`). Any other failure of the pull
+        is the caller's, as ever; the epoch under way is left to end
+        and the worker told to let go of the copy."""
+        pending = self._pending
+        ctx, counts, start = tracing.child_of_current(), {}, time.time()
+        with tracing.use(ctx):      # its tasks hang under `train.epoch`
+            refs = [w.train_epoch.remote(num_steps, pull_of=pending.epoch)
+                    for w in self.workers]
+        try:
+            self._snapshot(pending.call, pending.epoch)
+            self._pending = None
+        except BaseException as e:
+            for w in self.workers:  # the epoch's end waits for the pull
+                try:
+                    w.end_pull.remote()
+                except exc.RayTpuError:
+                    pass
+            lost = isinstance(e, (exc.ActorDiedError, exc.WorkerCrashedError)
+                              ) or (isinstance(e, exc.RayTpuError)
+                                    and self._gang_interrupted()[0])
+            if not lost:
+                self._pending = None
+                raise
+        with tracing.span("train.epoch", ctx, counts, ambient=True,
+                          start=start):
+            return self._run_with_retries("train_epoch", num_steps, counts,
+                                          refs=refs)
+
+    def _drain(self):
+        """Pull the state the worker still holds of the last call, now:
+        afterwards the installed snapshot is that call's."""
+        if self._pending is not None:
+            self._snapshot(self._pending.call, self._pending.epoch)
+            self._pending = None
+
     def validate(self, num_steps: int | None = None,
                  reduce_results: bool = True):
         results = self._run_with_retries("validate", num_steps)
@@ -663,15 +807,19 @@ class Trainer:
     # ------------------------------------------------------------------
 
     def _pull_state(self, worker, spare=None, counts: dict | None = None,
-                    drop=(), writes: int = 0) -> dict:
-        """`worker`'s training state, whole, in memory the driver owns.
+                    drop=(), writes: int = 0,
+                    of_epoch: int | None = None) -> dict:
+        """`worker`'s training state (with `of_epoch`: the copy it holds
+        since that epoch's end), whole, in memory the driver owns.
         It crosses the object plane as the pieces `train/snapshot.py`
         cuts (also a state the store would hold whole): each goes
         device→host and into the arena on the worker, out through `_own`
         into `spare`'s leaves here (a tree an earlier pull built, see
         `_own`), and is released. The driver asks ahead — the actor runs
-        the calls in order, so the worker brings the next piece to the
-        host while this side copies the last — as long as what is in
+        the `state_piece` calls in order, one at a time (on a lane of
+        their own where a held copy is pulled beside an epoch:
+        `TrainWorker.task_lane`), so the worker brings the next piece to
+        the host while this side copies the last — as long as what is in
         the store or on its way there never exceeds what it holds.
         Nothing of `spare` or of the result is installed here: a piece
         that raises leaves the caller's snapshot as it was. Inside a
@@ -685,15 +833,16 @@ class Trainer:
         from ray_tpu.train import snapshot
 
         usable = snapshot.usable_bytes(global_state.require_core_worker())
+        which = () if of_epoch is None else (of_epoch,)
         pending = collections.deque(
-            [(0, worker.state_piece.remote(0, usable, drop))])
+            [(0, worker.state_piece.remote(0, usable, drop, *which))])
         asked, held, leaves, ranges, sizes = 1, 0, [], (), ()
 
         def ask_ahead():
             nonlocal asked, held
             while asked < len(ranges) and held + sizes[asked] <= usable:
                 pending.append((asked, worker.state_piece.remote(
-                    asked, usable, drop)))
+                    asked, usable, drop, *which)))
                 held += sizes[asked]
                 asked += 1
 
@@ -750,10 +899,13 @@ class Trainer:
             del part    # out of the arena before the next piece goes in
 
     def state_dict(self) -> dict:
+        self._drain()
         return self._pull_state(self.workers[0])
 
     def load_state_dict(self, state: dict):
         self._last_state = state
+        # the workers drop a copy they hold of the state this replaces
+        self._pending, self._snapshot_of = None, self._calls
         self._push_state(self.workers, state)
 
     def save(self, path: str) -> str:
@@ -762,7 +914,7 @@ class Trainer:
         cross-node workers, the bulk transfer channel) and the driver
         writes one file per shard plus a small index manifest at `path`
         — no full replicated optimizer blob ever assembles anywhere."""
-        if not self._sharded:
+        if not self._sharded:   # state_dict() drains a pending pull
             with open(path, "wb") as f:
                 pickle.dump(self.state_dict(), f)
             return path
@@ -826,6 +978,10 @@ class Trainer:
         if force:
             self._kill_workers()
             return
+        try:    # what the workers hold of the last call comes home first
+            self._drain()
+        except exc.RayTpuError:
+            pass
         for w in self.workers:
             try:
                 w.shutdown.remote()
@@ -850,7 +1006,12 @@ def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None):
     arena, made the second `train()` fail in the worker's put).
 
     `spare` is a tree an earlier `_own` built and nothing uses any more
-    (`Trainer.train`: the snapshot retired a call ago). A leaf is copied
+    (`Trainer._snapshot`: the snapshot retired an install ago — a call
+    ago where every call pulls its own state, and just the same where
+    the pull is deferred to the next call's epoch: the sets turn when a
+    snapshot is installed, whichever call's it is, so the installed one
+    is never a destination while a deferred pull writes beside an epoch
+    either). A leaf is copied
     INTO the spare's leaf at the same path when that is an owned,
     writable array of the same shape, dtype and strides — a flat copy
     into pages already mapped and resident, where a fresh array over

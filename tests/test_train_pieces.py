@@ -1260,7 +1260,8 @@ def test_a_state_under_one_budget_crosses_in_one_piece(host):
         entry = call_log()[-1]
         (snap,) = _span(entry, "train.snapshot")
         (copy,) = _span(entry, "train.snapshot.copy")
-        assert snap == {"pieces": 1, "bytes": copy["bytes"]}
+        assert snap == {"deferred": 0, "of_call": 3, "pieces": 1,
+                        "bytes": copy["bytes"]}
         assert copy["reused_bytes"] == copy["bytes"] > 100 * 1024
         for name in ("train.snapshot.d2h", "object.return_put",
                      "object.get"):

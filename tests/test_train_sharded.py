@@ -280,6 +280,44 @@ def test_sharded_bit_exact_vs_replicated(ray_start_shared):
                               jax.tree.leaves(st["params"])])
 
 
+class RoomyWideAdam(WideAdamOperator):
+    """On devices with room for a second copy of the state (the room
+    rule's seam: tests/test_train_deferred.py)."""
+
+    def _device_memory(self):
+        return [{"bytes_limit": 1 << 34, "peak_bytes_in_use": 1 << 30}]
+
+
+@pytest.mark.parametrize("sharded", [True, False],
+                         ids=["zero", "replicated"])
+def test_a_group_of_several_workers_never_defers_its_pull(ray_start_shared,
+                                                          sharded):
+    """A rank of a host-collective group does not own its whole state
+    (ZeRO: the optimizer's shards come from every worker): whatever room
+    its device has, nothing is held and every call pulls its own state,
+    whole, after its epoch — the pull is deferred whole or not at all,
+    and here not at all."""
+    from ray_tpu.train import call_log
+
+    tr = Trainer(RoomyWideAdam, num_workers=2, sharded=sharded)
+    try:
+        for call in (1, 2, 3):
+            out = tr.train(reduce_results=False)
+            assert all("held_epoch" not in r for r in out)
+            assert tr._pending is None and tr._snapshot_of == call
+            assert tr._last_state["epoch"] == call
+            entry = call_log()[-1]
+            names = [s["name"] for s in entry["spans"]]
+            assert "train.hold" not in names
+            (snap,) = [s["attrs"] for s in entry["spans"]
+                       if s["name"] == "train.snapshot"]
+            assert snap["deferred"] == 0 and snap["of_call"] == call
+            if sharded:
+                assert len(tr._last_shards) == 2
+    finally:
+        tr.shutdown(force=True)
+
+
 def test_sharded_optimizer_memory_gauge(ray_start_shared):
     def gauge(tr):
         return max(ray_tpu.get(

@@ -125,6 +125,49 @@ def test_buffers_alternate_and_reuse_from_the_third_call(
     assert not sets[0] & sets[1]
 
 
+class HoldingOp(Op):
+    """`Op` on a device with room for a second copy of its state (the
+    room rule's seam: tests/test_train_deferred.py)."""
+
+    def _device_memory(self):
+        return [{"bytes_limit": 1 << 34, "peak_bytes_in_use": 1 << 30}]
+
+
+def test_buffers_alternate_when_the_pull_is_a_call_late(ray_start_shared):
+    """A held copy is pulled beside the next call's epoch: the two sets
+    still take turns, one install at a time, and the installed snapshot
+    — a call older now — is never a destination."""
+    tr = Trainer(HoldingOp, num_workers=1)
+    try:
+        copies, sets, epochs = [], [], []
+        for _ in range(6):
+            installed = _addresses(tr) if tr._last_state else set()
+            tr.train()
+            entry = call_log()[-1]
+            copies.append([(c["reused_bytes"], c["bytes"])
+                           for c in _copies(entry)])
+            sets.append(_addresses(tr))
+            epochs.append(tr._last_state["epoch"])
+            snaps = [s["attrs"] for s in entry["spans"]
+                     if s["name"] == "train.snapshot"]
+            assert [s["deferred"] for s in snaps] == (
+                [0] if len(sets) == 1 else [] if len(sets) == 2 else [1])
+            if len(sets) > 2:       # written beside: never the installed
+                assert not installed & sets[-1]
+        assert _bits(tr._last_state) != _workers_snapshot(tr)  # a call old
+        assert tr._pending is None      # ... until asked (state_dict)
+        assert tr._last_state["epoch"] == 6
+    finally:
+        tr.shutdown(force=True)
+    assert epochs == [1, 1, 2, 3, 4, 5]
+    # call 1 pulls at once, call 2 not at all, call 3 into a new set,
+    # from call 4 on everything goes into the other set's buffers
+    size = copies[0][0][1]
+    assert copies == [[(0, size)], [], [(0, size)]] + [[(size, size)]] * 3
+    assert sets[0] == sets[1] == sets[3] == sets[5]
+    assert sets[2] == sets[4] and not sets[0] & sets[2]
+
+
 def test_snapshot_is_whole_owned_and_leaves_the_arena(warm):
     for _ in range(3):
         used = _store_used()

@@ -105,7 +105,9 @@ def test_one_call_is_one_tree_across_driver_and_worker(wide):
     ids = {s["span"] for s in entry["spans"]}
     roots = [s for s in entry["spans"] if s["parent"] not in ids]
     assert [r["name"] for r in roots] == ["train.call"]
-    assert roots[0]["attrs"] == {"num_steps": 2, "workers": 1}
+    assert roots[0]["attrs"] == {"num_steps": 2, "workers": 1,
+                                 "call": wide._calls}   # counted from 1
+    assert wide._calls >= 1
     dispatch = next(s for s in entry["spans"]
                     if s["name"] == "train.dispatch")
     held = dispatch["attrs"]["state_bytes"]   # whole, on the one device
@@ -114,7 +116,9 @@ def test_one_call_is_one_tree_across_driver_and_worker(wide):
         "state_bytes_fullest_chip": held, "state_bytes_split_leading": 0}
     snapshot = next(s for s in entry["spans"]
                     if s["name"] == "train.snapshot")
-    assert snapshot["attrs"] == {"pieces": 1, "bytes": held}
+    # this call's own state, pulled after its epoch (nothing is held)
+    assert snapshot["attrs"] == {"deferred": 0, "of_call": wide._calls,
+                                 "pieces": 1, "bytes": held}
     epoch = next(s for s in entry["spans"] if s["name"] == "train.epoch")
     assert epoch["attrs"] == {"attempts": 1}
     assert LEAF not in _names(entry)   # the fine level is off
