@@ -95,7 +95,7 @@ def held_nodes(nodes: list[str]) -> list[str]:
 
 
 def wait_for_chips(probe, bound: float = CHIP_WAIT_BOUND_S,
-                   pause: float = 0.25) -> float:
+                   pause: float = 0.25, facts: dict | None = None) -> float:
     """A chip-owning worker's wait for chips that are still being
     released, before it initialises the backend: polls `probe()` (the
     nodes still held) until it is empty or `bound` seconds have passed,
@@ -104,9 +104,11 @@ def wait_for_chips(probe, bound: float = CHIP_WAIT_BOUND_S,
     come back free) — next to nothing, with nothing logged, when the
     chips are free at once. Past the bound the worker starts all the
     same and fails with libtpu's own error, as it would have without the
-    wait."""
+    wait. `facts`, if given, takes `held` (how many nodes the first probe
+    found held) and `waited_s`: the `worker.chip_wait` span's attributes."""
     t0 = time.monotonic()
     held = probe()
+    first = len(held)
     while held and time.monotonic() - t0 < bound:
         time.sleep(pause)
         held = probe()
@@ -118,6 +120,8 @@ def wait_for_chips(probe, bound: float = CHIP_WAIT_BOUND_S,
     elif waited >= 1.0:  # a probe of free nodes takes milliseconds
         logger.info("waited %.1f s for the chip: its device nodes were "
                     "still held by a process that was ending", waited)
+    if facts is not None:
+        facts.update(held=first, waited_s=round(waited, 4))
     return waited
 
 

@@ -412,6 +412,78 @@ def state() -> dict:
 
 
 # ---------------------------------------------------------------------------
+# what jax itself timed inside a resolution
+# ---------------------------------------------------------------------------
+
+# jax.monitoring's duration events -> the attribute that sums them on
+# the `jax.compile` / `compile.load` span. `backend_s` is the XLA
+# compile OR the load from jax's persistent cache; the retrieval event
+# comes only with a persistent-cache hit.
+_JAX_TIMED = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
+_resolving = 0                  # resolutions open in this process
+_timed = threading.local()      # .events: the open resolution's, by thread
+
+
+def _on_jax_duration(event, duration, **_):
+    """The ONE jax.monitoring listener, registered only while a
+    resolution is open (`_jax_timings`): keeps the event as an interval
+    that ends now. jax times a jit traced inside another's trace once
+    more, inside the outer's interval: the outermost alone is kept."""
+    attr = _JAX_TIMED.get(event)
+    events = getattr(_timed, "events", None)
+    if attr is None or events is None:
+        return
+    end = time.time()
+    start = end - duration
+    kept = [iv for iv in events.get(attr, ()) if iv[0] < start]
+    events[attr] = kept + [(start, end)]
+
+
+@contextlib.contextmanager
+def _jax_timings():
+    """Listen to jax's own compile timings for the duration of one
+    resolution, in the resolving thread."""
+    global _resolving
+    import jax.monitoring
+
+    with _lock:
+        _resolving += 1
+        if _resolving == 1:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+    outer, _timed.events = getattr(_timed, "events", None), {}
+    try:
+        yield
+    finally:
+        _timed.events = outer
+        with _lock:
+            _resolving -= 1
+            if not _resolving:
+                jax.monitoring.unregister_event_duration_listener(
+                    _on_jax_duration)
+
+
+def _jax_timed(since: float) -> dict:
+    """What jax timed in the open resolution from `since` on, as span
+    attributes; an event jax did not emit leaves its attribute out."""
+    out = {}
+    for attr, intervals in (getattr(_timed, "events", None) or {}).items():
+        inside = [b - a for a, b in intervals if a >= since - 1e-3]
+        if inside:
+            out[attr] = round(sum(inside), 4)
+            if attr == "backend_s":
+                out["programs"] = len(inside)
+    if "backend_s" in out:
+        out["persistent_hit"] = int("cache_retrieval_s" in out)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # the seam wrapper
 # ---------------------------------------------------------------------------
 
@@ -436,7 +508,14 @@ class CachedFunction:
       before this cache existed.
 
     Either way later calls go through one resolved function attribute —
-    the wrapper adds a single `is None` check to the steady state."""
+    the wrapper adds a single `is None` check to the steady state.
+
+    Inside a trace a resolution is spans, each with the seam's `key`:
+    `compile.fingerprint` (`text_bytes`), `compile.lookup` (`hit`,
+    `bytes`), `compile.load` (a hit, through its first dispatch),
+    `compile.export` (`error` 1 where the export or the store raised)
+    and `jax.compile` (the first dispatch of a miss); all but the lookup
+    with what jax itself timed inside them (`_jax_timed`)."""
 
     def __init__(self, seam: str, parts, jitted, donate_argnums=(),
                  out_shardings=None, record_key: str | None = None,
@@ -468,7 +547,16 @@ class CachedFunction:
         with self._lock:
             if self._fn is not None:
                 return self._fn(*args)
-            return self._resolve(args)
+            with _jax_timings():
+                return self._resolve(args)
+
+    def _span(self, name: str, counts: dict):
+        """One part of the resolution as a child of the ambient trace
+        (none outside a trace); `counts` is read when it ends."""
+        from ray_tpu._private import tracing
+
+        counts["key"] = self._record_key
+        return tracing.span(name, tracing.child_of_current(), counts)
 
     def _resolve(self, args):
         if not enabled():
@@ -479,74 +567,102 @@ class CachedFunction:
             try:
                 import jax
 
-                jaxpr = jax.make_jaxpr(self._jitted)(*args)
-                parts = parts + (hashlib.sha256(
-                    str(jaxpr).encode()).hexdigest()[:16],)
+                traced = {}
+                with self._span("compile.fingerprint", traced):
+                    t0 = time.time()
+                    text = str(jax.make_jaxpr(self._jitted)(*args))
+                    parts = parts + (hashlib.sha256(
+                        text.encode()).hexdigest()[:16],)
+                    # the rest of the span: the jaxpr printed and hashed
+                    traced.update(_jax_timed(t0), text_bytes=len(text))
             except Exception:
                 # can't prove computation identity -> never share
                 M_ERRORS.inc()
                 self.resolved = "disabled"
                 return self._first_dispatch(args)
-        key = make_key(self.seam, parts)
-        blob = lookup(key)
+        found = {"hit": 0, "bytes": 0}
+        with self._span("compile.lookup", found):
+            key = make_key(self.seam, parts)
+            blob = lookup(key)
+            if blob is not None:
+                found.update(hit=1, bytes=len(blob))
         if blob is not None:
-            t0 = time.time()
-            fn = None
-            try:
-                import jax
-                from jax import export as _export
-
-                exported = _export.deserialize(bytearray(blob))
-                fn = self._jit_exported(exported)
-                if self.donate_argnums:
-                    # dispatching a donated jit consumes the input
-                    # buffers — AOT-compile the deserialized module
-                    # first so a stale/corrupt/incompatible blob fails
-                    # HERE, with the inputs intact and the re-trace
-                    # fallback below still possible
-                    fn = fn.lower(*args).compile()
-            except Exception:
-                # a stale/corrupt/incompatible blob: typed error count,
-                # then the normal trace path — never user-visible
-                M_ERRORS.inc()
-                fn = None
-            if fn is not None:
+            loaded = {"ok": 0}
+            with self._span("compile.load", loaded):
+                t0 = time.time()
                 try:
-                    out = fn(*args)
-                except Exception:
-                    M_ERRORS.inc()
-                    if self.donate_argnums:
-                        # the executable compiled but failed at RUN
-                        # time with the inputs already donated; the
-                        # fallback would dispatch on deleted buffers —
-                        # surface the real execution error instead
-                        raise
-                    fn = None
-                else:
-                    self._fn = fn
-                    self.resolved = "hit"
-                    M_HITS.inc()
-                    M_LOAD_S.observe(time.time() - t0)
-                    record_hit(key)
+                    out = self._load(key, blob, args)
+                finally:
+                    loaded.update(_jax_timed(t0))
+                if self._fn is not None:
+                    loaded["ok"] = 1
                     return out
         M_MISSES.inc()
         self.resolved = "miss"
         fn = None
+        exported = {"error": 0, "bytes": 0}
+        with self._span("compile.export", exported):
+            t0 = time.time()
+            try:
+                from jax import export as _export
+
+                module = _export.export(self._jitted)(*args)
+                blob = module.serialize()
+                exported["bytes"] = len(blob)
+                store(key, blob, seam=self.seam, parts=parts)
+                # dispatch THROUGH the exported module, as a later
+                # process will on a hit: both then hand XLA the same
+                # program, so the restarted process's compile is a hit
+                # in JAX's own persistent cache too. Dispatching the
+                # original jit here made every warm restart pay one full
+                # XLA compile (the exported wrapper is a different
+                # program to XLA's cache).
+                fn = self._jit_exported(module)
+            except Exception:
+                M_ERRORS.inc()
+                exported["error"] = 1
+            exported.update(_jax_timed(t0))
+        return self._first_dispatch(args, fn)
+
+    def _load(self, key: str, blob: bytes, args):
+        """A hit: the stored module deserialised, compiled and
+        dispatched once. Sets `_fn` and returns the outputs, or leaves
+        `_fn` None (a blob that will not load: the caller re-traces)."""
+        t0 = time.time()
         try:
             from jax import export as _export
 
-            exported = _export.export(self._jitted)(*args)
-            store(key, exported.serialize(), seam=self.seam, parts=parts)
-            # dispatch THROUGH the exported module, as a later process
-            # will on a hit: both then hand XLA the same program, so
-            # the restarted process's compile is a hit in JAX's own
-            # persistent cache too. Dispatching the original jit here
-            # made every warm restart pay one full XLA compile (the
-            # exported wrapper is a different program to XLA's cache).
+            exported = _export.deserialize(bytearray(blob))
             fn = self._jit_exported(exported)
+            if self.donate_argnums:
+                # dispatching a donated jit consumes the input
+                # buffers — AOT-compile the deserialized module
+                # first so a stale/corrupt/incompatible blob fails
+                # HERE, with the inputs intact and the re-trace
+                # fallback still possible
+                fn = fn.lower(*args).compile()
+        except Exception:
+            # a stale/corrupt/incompatible blob: typed error count,
+            # then the normal trace path — never user-visible
+            M_ERRORS.inc()
+            return None
+        try:
+            out = fn(*args)
         except Exception:
             M_ERRORS.inc()
-        return self._first_dispatch(args, fn)
+            if self.donate_argnums:
+                # the executable compiled but failed at RUN time with
+                # the inputs already donated; the fallback would
+                # dispatch on deleted buffers — surface the real
+                # execution error instead
+                raise
+            return None
+        self._fn = fn
+        self.resolved = "hit"
+        M_HITS.inc()
+        M_LOAD_S.observe(time.time() - t0)
+        record_hit(key)
+        return out
 
     def _jit_exported(self, exported):
         import jax
@@ -570,6 +686,7 @@ class CachedFunction:
         fn = fn if fn is not None else self._jitted
         t0 = time.time()
         out = fn(*args)
-        _profiling.record_compile(self._record_key, t0, time.time())
+        _profiling.record_compile(self._record_key, t0, time.time(),
+                                  _jax_timed(t0))
         self._fn = fn
         return out

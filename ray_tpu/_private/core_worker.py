@@ -2880,12 +2880,20 @@ class CoreWorker:
                 _fp.fire_strict("worker.exec")
             args, kwargs = self._resolve_args(spec["args"])
             if spec["type"] == common.ACTOR_CREATION_TASK:
+                arrived = time.time()
                 cls = self.fetch_function(spec["fn_id"], spec["job_id"],
                                           kind="cls")
                 self.actor_resources = common.ResourceSet.from_raw(
                     spec.get("resources") or {}).to_dict()
+                loaded = time.time()
                 self._before_user_code()
                 self._actor_instance = cls(*args, **kwargs)
+                # creation comes through the GCS and carries no trace
+                # context: the actor's first traced call takes this home
+                tracing.pending(
+                    "worker.actor_init", arrived, time.time(),
+                    {"name": spec.get("name", "?"),
+                     "load_s": round(loaded - arrived, 4)})
                 self._actor_id = ActorID(spec["actor_id"])
                 if spec.get("restore"):
                     # relocated/restarted incarnation: a drained-away
@@ -2956,10 +2964,14 @@ class CoreWorker:
         `accelerator.wait_for_chips`). Here and not at the worker's
         start: only the user's code can claim the chips, so everything
         before it (registering, the lease, loading what the task
-        imports) runs while the other process ends."""
+        imports) runs while the other process ends. Kept as the pending
+        span `worker.chip_wait` (`tracing.pending`)."""
         wait, self.before_user_code = self.before_user_code, None
         if wait is not None:
-            wait()
+            start = time.time()
+            facts = wait()  # the span's attributes (`waited_s`, `held`)
+            tracing.pending("worker.chip_wait", start, time.time(),
+                            facts if isinstance(facts, dict) else None)
 
     def _run_callable(self, fn, args, kwargs):
         import inspect

@@ -46,10 +46,12 @@ _compile_recent: collections.deque = collections.deque(maxlen=256)
 _compile_lock = threading.Lock()
 
 
-def record_compile(key: str, start: float, end: float) -> None:
+def record_compile(key: str, start: float, end: float,
+                   timed: dict | None = None) -> None:
     """Record one observed jit compile: metrics + a `jax.compile` span
     (joining the ambient trace when one is active) + the recent window
-    the doctor reads."""
+    the doctor reads. `timed`: what jax itself timed inside the interval
+    (`compile_cache._jax_timed`), added to the span's attributes."""
     from ray_tpu._private import tracing
 
     seconds = max(0.0, end - start)
@@ -60,7 +62,7 @@ def record_compile(key: str, start: float, end: float) -> None:
     tracing.record_span("jax.compile", start, end,
                         tracing.child_of_current(),
                         {"name": f"jax.compile {key}", "key": key,
-                         "compile_s": round(seconds, 4)})
+                         **(timed or {})})
 
 
 def compile_state() -> dict:

@@ -28,6 +28,13 @@ with `time.time()`: a tree is comparable within one host only. While a
 jax profiler session runs in the process (`set_annotating`), `span()`
 also enters a `jax.profiler.TraceAnnotation` of the same name, so the
 host spans sit in the `.xplane.pb` beside the device's operations.
+
+What a process did before any context reached it — a worker's spawn,
+boot, wait for its chips and actor constructor — is kept as a few
+PENDING rows (`pending`) and recorded as children of the first traced
+task the process runs (`collect_reply`), so it rides home in that
+task's reply: a worker group's start is one tree in the driver
+(`ray_tpu.train.start_log()`).
 """
 
 from __future__ import annotations
@@ -265,6 +272,21 @@ _trees: dict[str, list] = {}
 _annotating = False  # a jax profiler session runs in this process
 
 
+# Rows of the time before this process had a context, until the first
+# traced task claims them (`pending`, `collect_reply`). A handful a
+# process; what does not fit is left out.
+PENDING_MAX = 16
+_pending: list = []
+
+
+def pending(name: str, start: float, end: float,
+            extra: dict | None = None) -> None:
+    """Keep one span of this process's life before any trace context
+    existed; the first traced task it runs records it as its child."""
+    if len(_pending) < PENDING_MAX:
+        _pending.append((name, start, end, extra))
+
+
 class ReplySpans(list):
     """The rows one traced task hands back, and how many did not fit."""
 
@@ -281,6 +303,12 @@ def collect_reply(ctx: TraceContext | None):
     rows = ReplySpans()
     token = _REPLY.set(rows)
     try:
+        if _pending:    # the process's first traced task: see pending()
+            with _lock:
+                claimed = _pending[:]
+                del _pending[:]
+            for name, start, end, extra in claimed:
+                record_span(name, start, end, child(ctx), extra)
         yield rows
     finally:
         try:

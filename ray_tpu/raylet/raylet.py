@@ -293,6 +293,9 @@ class Raylet:
         # echoes its flavour back at registration.
         env["JAX_PLATFORMS"] = self.tpu_worker_platforms if tpu else "cpu"
         env["RAY_TPU_WORKER_FLAVOR"] = "tpu" if tpu else "cpu"
+        # the `worker.spawn` span's start (`worker/main.py`): this
+        # moment to the child's `main`, one host, one clock
+        env["RAY_TPU_WORKER_SPAWNED_AT"] = repr(time.time())
         cmd = [
             sys.executable, "-m", "ray_tpu.worker.main",
             "--raylet-address", self.address,

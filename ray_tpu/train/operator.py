@@ -30,6 +30,7 @@ from jax.flatten_util import ravel_pytree
 
 from ray_tpu._private import profiling as _profiling
 from ray_tpu._private import tracing as _tracing
+from ray_tpu.train import sharding as _shard
 
 # snapshot leaves above this get a span of their own at the fine level
 _LEAF_SPAN_BYTES = 1 << 20
@@ -57,10 +58,23 @@ class TrainingOperator:
         self._val_loader = None
         self.epoch = 0
         self.global_step = 0
+        # `train.setup.user`: setup()'s own seconds, as the stretches of
+        # it that are not register()'s (which closes one and opens the
+        # next: `_user_span`)
+        self._user_since = time.time()
         self.setup(self.config)
+        self._user_span()
         if not self._registered:
             raise RuntimeError(
                 "TrainingOperator.setup() must call self.register(...)")
+
+    def _user_span(self) -> float:
+        """Record the user's stretch of `setup` that ends now; returns
+        now, on the spans' clock."""
+        now = time.time()
+        _tracing.record_span("train.setup.user", self._user_since, now,
+                             _tracing.child_of_current())
+        return now
 
     # ------------------------------------------------------------------
     # user surface
@@ -104,6 +118,7 @@ class TrainingOperator:
             PartitionSpec for batches (default P('dp'): rows over the
             data axis).
         """
+        since = self._user_span()
         self._registered = True
         self._facts = None      # _layout_facts, reckoned at the first epoch
         self._loading = None    # a state arriving in pieces (_LoadPlan)
@@ -118,11 +133,36 @@ class TrainingOperator:
         self._eval_fn = eval_fn
         self._optimizer = optimizer
         self._stateful = stateful
-        if stateful:
-            self.params, self.model_state = model_init(jax.random.key(seed))
-        else:
-            self.params = model_init(jax.random.key(seed))
-            self.model_state = None
+        # `train.setup.init` and `train.setup.place` each end when the
+        # device has done what they dispatched: the two waits move the
+        # rest of initialisation from the first step, which waited for
+        # the same arrays, into set-up, where it has a name
+        # (`wait_s`: how long each wait took, so how much was moved)
+        made = {}
+        with _tracing.span("train.setup.init", _tracing.child_of_current(),
+                           made, start=since):
+            if stateful:
+                self.params, self.model_state = model_init(
+                    jax.random.key(seed))
+            else:
+                self.params = model_init(jax.random.key(seed))
+                self.model_state = None
+            made.update(_waited_for((self.params, self.model_state)),
+                        bytes=_shard.opt_nbytes(
+                            (self.params, self.model_state)))
+        placed = {}
+        with _tracing.span("train.setup.place", _tracing.child_of_current(),
+                           placed):
+            self._place(optimizer, mesh, param_spec, batch_spec)
+            state = (self.params, self.model_state, self.opt_state)
+            placed.update(_waited_for(state),
+                          state_bytes=_shard.opt_nbytes(state))
+        self._user_since = time.time()
+
+    def _place(self, optimizer, mesh, param_spec, batch_spec):
+        """register()'s second half: the state onto its devices (a mesh,
+        asked for or derived from the lease), the optimizer's state made
+        beside it, the jitted steps built."""
         self._epoch_counters = (isinstance(self.model_state, dict)
                                 and "epoch_counters" in self.model_state)
         if self._epoch_counters and any(
@@ -199,7 +239,6 @@ class TrainingOperator:
                     else jax.device_put(x, to_sharding(None)),
                     self.opt_state)
         from ray_tpu.train import metrics as _tm
-        from ray_tpu.train import sharding as _shard
 
         _tm.OPT_SHARD_BYTES.set(_shard.opt_nbytes(self.opt_state))
         self._build_steps()
@@ -211,8 +250,6 @@ class TrainingOperator:
         span of the padded flat param bucket (layout: train/sharding.py).
         The step becomes reducescatter(grads) → local shard update →
         allgather(params)."""
-        from ray_tpu.train import sharding as _shard
-
         flat, _ = ravel_pytree(self.params)
         self._numel = int(flat.size)
         self._pad_numel = _shard.padded_numel(self._numel, self.world_size)
@@ -583,6 +620,8 @@ class TrainingOperator:
                     # with ingest_wait_s (observed inside IngestStream's
                     # get) this answers "is training input-bound?"
                     _tm.STEP_DISPATCH_S.observe(now - t_step)
+                    if not step:    # where jax's deferred garbage lands
+                        counts["first_dispatch_s"] = now - t_step
                     t_step = now
                     step += 1
                     if num_steps is not None and step >= num_steps:
@@ -1181,6 +1220,14 @@ class _LoadPlan:
         op.epoch = rest["epoch"]
         op.global_step = rest["global_step"]
         op._held = None     # a copy of the state this one replaced
+
+
+def _waited_for(tree) -> dict:
+    """Block until the device has made every array of `tree`; the
+    seconds that took, as a span's `wait_s`."""
+    t0 = time.perf_counter()
+    jax.block_until_ready(tree)
+    return {"wait_s": round(time.perf_counter() - t0, 4)}
 
 
 def _batch_size(batch) -> int:

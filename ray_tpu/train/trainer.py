@@ -38,6 +38,12 @@ CALL_LOG_MAX = 256
 _call_log: collections.deque = collections.deque(maxlen=CALL_LOG_MAX)
 
 
+# ... and of its last worker-group starts (start_log()): a log of its
+# own, because readers find a call in `_call_log` by its position.
+START_LOG_MAX = 32
+_start_log: collections.deque = collections.deque(maxlen=START_LOG_MAX)
+
+
 def call_log() -> list[dict]:
     """The finished span trees of this process's last `Trainer.train()`
     calls (at most 256, oldest first). One entry a call: `trace_id` and
@@ -47,8 +53,23 @@ def call_log() -> list[dict]:
     `train.call`; the worker's spans arrive in the task replies, so an
     entry is whole when `train()` returns (ARCHITECTURE.md, "Span
     catalogue")."""
+    return _entries(_call_log)
+
+
+def start_log() -> list[dict]:
+    """The finished span trees of this process's last worker-group
+    starts (at most 32, oldest first; entries as `call_log()`'s): one a
+    `Trainer(...)` and one more a restart of its group. Root:
+    `train.start`; what a new worker did before it had a trace context
+    (`worker.spawn`, `worker.boot`, `worker.chip_wait`,
+    `worker.actor_init`) and its `train.setup` arrive in the reply of
+    the first call it runs."""
+    return _entries(_start_log)
+
+
+def _entries(log) -> list[dict]:
     out = []
-    for trace_id, rows in list(_call_log):
+    for trace_id, rows in list(log):
         spans = []
         for name, start, end, fields in list(rows):
             attrs = {k: v for k, v in fields.items()
@@ -85,17 +106,38 @@ class TrainWorker(CollectiveActorMixin):
         self.operator = None
 
     def setup_operator(self):
-        if self._config.get("multihost"):
-            # Join the group's global jax runtime BEFORE the operator's
-            # first backend use; the operator then sees jax.devices() =
-            # the whole group and builds a global mesh.
-            from ray_tpu.parallel import multihost
+        """`train.setup`: the first traced call a new worker runs, so
+        its reply also takes the worker's own start home
+        (`tracing.pending`)."""
+        with tracing.span("train.setup", tracing.child_of_current(),
+                          ambient=True):
+            if self._config.get("multihost"):
+                # Join the group's global jax runtime BEFORE the
+                # operator's first backend use; the operator then sees
+                # jax.devices() = the whole group and builds a global
+                # mesh.
+                from ray_tpu.parallel import multihost
 
-            multihost.initialize(self._group_name, self._world_size,
-                                 self._rank)
-        self.operator = self._operator_cls(
-            self._config, self._rank, self._world_size,
-            group_name=self._group_name)
+                multihost.initialize(self._group_name, self._world_size,
+                                     self._rank)
+            from ray_tpu.train.operator import TrainingOperator
+
+            if (isinstance(self._operator_cls, type)
+                    and issubclass(self._operator_cls, TrainingOperator)):
+                # the first backend use, made here so that libtpu's
+                # initialisation is a span and not the head of the
+                # user's `model_init`
+                facts = {}
+                with tracing.span("train.setup.backend",
+                                  tracing.child_of_current(), facts):
+                    import jax
+
+                    devices = jax.devices()
+                    facts.update(platform=devices[0].platform,
+                                 devices=len(devices))
+            self.operator = self._operator_cls(
+                self._config, self._rank, self._world_size,
+                group_name=self._group_name)
         return True
 
     def train_epoch(self, num_steps=None, profile_dir=None, pull_of=None):
@@ -433,7 +475,27 @@ class Trainer:
         self._pg = None
 
     def _start_workers(self, num_workers: int):
+        """One generation of the group, as ONE trace rooted at
+        `train.start` (always recorded; kept in `start_log()`). A tree
+        of its own even where a `train()` call restarts the group:
+        `in_call` then names that call's trace."""
         self._generation += 1
+        counts = {"generation": self._generation, "workers": num_workers,
+                  "restored": 0}
+        in_call = tracing.current_id()
+        if in_call is not None:
+            counts["in_call"] = in_call
+        with tracing.use(None):
+            root = tracing.always_trace()
+        with tracing.open_tree(root) as rows:
+            try:
+                with tracing.span("train.start", root, counts,
+                                  ambient=True):
+                    self._start_traced(num_workers, counts)
+            finally:
+                _start_log.append((root.trace_id.hex(), rows))
+
+    def _start_traced(self, num_workers: int, counts: dict):
         group_name = f"sgd_{self._uid}_g{self._generation}"
         # cloudpickle: operator classes defined in __main__ or notebooks
         # serialize by value (stdlib pickle would import-by-reference and
@@ -469,7 +531,14 @@ class Trainer:
                     timeout=self._setup_timeout)
         self._active_workers = num_workers
         self._start_ingest(num_workers)
-        self._restore_state()
+        if (self._last_state is not None or self._pending is not None
+                or (self._sharded and self._last_shards)):
+            counts["restored"] = 1
+            restored = {}
+            with tracing.span("train.start.restore",
+                              tracing.child_of_current(), restored,
+                              ambient=True):
+                restored["bytes"] = self._restore_state()
 
     def _start_ingest(self, num_workers: int):
         """One DatasetShard actor per rank; every generation re-shards
@@ -499,8 +568,10 @@ class Trainer:
         """Re-install training state into a freshly started generation:
         params/progress broadcast once over the data plane, then (in
         sharded mode) per-rank optimizer shards — re-partitioned to the
-        new world size when it changed, never a replicated blob."""
+        new world size when it changed, never a replicated blob. Returns
+        the bytes of the state it pushed (`train.start.restore`)."""
         num_workers = len(self.workers)
+        pushed = 0
         if self._last_state is not None:
             if (num_workers > 1 and self._backend == "host"
                     and not self._config.get("multihost")):
@@ -508,11 +579,12 @@ class Trainer:
                 # driver ships ONE copy to rank 0; the group's shm/ring
                 # transport fans it out node-locally (the elastic-resize
                 # restore used to pickle the state num_workers times).
-                self._push_state(self.workers[:1], self._last_state)
+                pushed = self._push_state(self.workers[:1],
+                                          self._last_state)
                 ray_tpu.get([w.sync_state.remote(0) for w in self.workers],
                             timeout=self._setup_timeout)
             else:
-                self._push_state(self.workers, self._last_state)
+                pushed = self._push_state(self.workers, self._last_state)
         if self._sharded and self._last_shards:
             shards = self._last_shards
             if len(shards) != num_workers:
@@ -532,6 +604,7 @@ class Trainer:
             ray_tpu.get([w.train_epoch.remote(self._pending.num_steps)
                          for w in self.workers], timeout=600)
             self._pending = None
+        return pushed
 
     def _kill_workers(self):
         for w in self.workers + self._ingest_actors:
@@ -882,21 +955,22 @@ class Trainer:
         """The other direction (`load_state_dict`, the elastic restore):
         `state` to every worker in `workers`, cut the same way, one put
         a piece whatever the number of workers; the next piece goes when
-        every worker has placed the last."""
+        every worker has placed the last. Returns the state's bytes."""
         import jax
 
         from ray_tpu.train import snapshot
 
         leaves, treedef = jax.tree.flatten(state)
         usable = snapshot.usable_bytes(global_state.require_core_worker())
-        for first, stop in snapshot.plan(
-                [snapshot.leaf_bytes(x) for x in leaves], usable):
+        sizes = [snapshot.leaf_bytes(x) for x in leaves]
+        for first, stop in snapshot.plan(sizes, usable):
             part = ray_tpu.put(leaves[first:stop])
             ray_tpu.get(
                 [w.load_state_piece.remote(
                     first, part, treedef if first == 0 else None)
                  for w in workers], timeout=self._setup_timeout)
             del part    # out of the arena before the next piece goes in
+        return sum(sizes)
 
     def state_dict(self) -> dict:
         self._drain()

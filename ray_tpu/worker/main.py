@@ -7,9 +7,11 @@ from __future__ import annotations
 import argparse
 import logging
 import os
+import time
 
 
 def main():
+    entered = time.time()
     parser = argparse.ArgumentParser()
     parser.add_argument("--raylet-address", required=True)
     parser.add_argument("--gcs-address", required=True)
@@ -57,12 +59,38 @@ def main():
         from ray_tpu._private import accelerator
 
         nodes = accelerator.tpu_device_nodes()
-        cw.before_user_code = lambda: accelerator.wait_for_chips(
-            lambda: accelerator.held_nodes(nodes))
+
+        def chip_wait() -> dict:
+            facts = {}
+            accelerator.wait_for_chips(
+                lambda: accelerator.held_nodes(nodes), facts=facts)
+            return facts
+
+        cw.before_user_code = chip_wait
     logging.getLogger("ray_tpu.worker").info(
         "worker %s registered with raylet %s",
         cw.worker_id.hex()[:8], args.raylet_address)
+    _note_start(entered)
     cw.run_task_execution_loop()
+
+
+def _note_start(entered: float) -> None:
+    """This process's life so far as two pending spans, which the first
+    traced task it runs takes home (`tracing.pending`): `worker.spawn`,
+    the raylet's `Popen` (its stamp in our environment) to `main`
+    entered — the interpreter's start and the import of `ray_tpu` —
+    and `worker.boot`, from there to registered with the raylet."""
+    from ray_tpu._private import tracing
+
+    who = {"flavor": os.environ.get("RAY_TPU_WORKER_FLAVOR", "cpu"),
+           "pid": os.getpid()}
+    try:
+        spawned = float(os.environ["RAY_TPU_WORKER_SPAWNED_AT"])
+    except (KeyError, ValueError):
+        pass    # started by hand: no stamp, no spawn span
+    else:
+        tracing.pending("worker.spawn", spawned, entered, who)
+    tracing.pending("worker.boot", entered, time.time(), who)
 
 
 if __name__ == "__main__":

@@ -230,6 +230,100 @@ def test_donated_hit_path_validates_before_consuming(cache_sandbox):
     np.testing.assert_array_equal(np.asarray(out2), np.full(8, 2.0))
 
 
+def _resolution_spans(cf, *args):
+    """One call of `cf` inside a trace: its spans by name, each with
+    its attributes less the ids."""
+    from ray_tpu._private import tracing
+
+    root = tracing.new_context()
+    with tracing.open_tree(root) as rows, tracing.use(root):
+        cf(*args)
+    return {name: {k: v for k, v in fields.items()
+                   if k not in ("tid", "sid", "psid")}
+            for name, _, _, fields in rows}
+
+
+@pytest.mark.parametrize("case", ["miss_then_hit", "export_raises"])
+def test_a_resolution_is_spans_and_listens_only_while_open(cache_sandbox,
+                                                           monkeypatch,
+                                                           case):
+    """A first call's parts are `compile.*` spans of the ambient trace
+    with the seam's `key`: a miss looks up (`hit` 0), exports (`error`
+    0) and dispatches under `jax.compile`, which carries what jax timed
+    inside it; the next process's call looks up (`hit` 1) and loads; an
+    export that raises says `error` 1 and the call is served by the
+    plain jit. The one jax.monitoring listener lives only inside a
+    resolution."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    def listeners():
+        return monitoring.get_event_duration_listeners().count(
+            _cc._on_jax_duration)
+
+    live = []
+
+    def fn(a, b):
+        live.append(listeners())    # while the step is being traced
+        return a * b + 1.0
+
+    x = jnp.ones((8,), jnp.float32)
+    parts = ("spans", case, "f32", "8")
+    key = "unit.spans:" + ":".join(parts)
+    if case == "export_raises":
+        from jax import export as _export
+
+        def refuse(*a, **kw):
+            raise TypeError("an unregistered pytree node")
+
+        monkeypatch.setattr(_export, "export", refuse)
+    e0 = _cc.M_ERRORS.snapshot()["value"]
+    assert listeners() == 0
+    cf = _cc.CachedFunction("unit.spans", parts, jax.jit(fn),
+                            fingerprint_computation=True)
+    spans = _resolution_spans(cf, x, x)
+    assert listeners() == 0 and live and set(live) == {1}
+    assert cf.resolved == "miss"
+    assert {n: s["key"] for n, s in spans.items()} == dict.fromkeys(
+        ["compile.fingerprint", "compile.lookup", "compile.export",
+         "jax.compile"], key)
+    assert (spans["compile.lookup"]["hit"],
+            spans["compile.lookup"]["bytes"]) == (0, 0)
+    timed = spans["jax.compile"]
+    assert timed["programs"] >= 1 and timed["backend_s"] > 0
+    assert timed["persistent_hit"] == 0     # the test tree: cache off
+    assert 0 <= timed["lower_s"] and timed["trace_s"] >= 0
+    if case == "export_raises":
+        assert spans["compile.export"]["error"] == 1
+        assert spans["compile.export"]["bytes"] == 0
+        assert _cc.M_ERRORS.snapshot()["value"] == e0 + 1
+        return
+    assert spans["compile.export"]["error"] == 0
+    stored = spans["compile.export"]["bytes"]
+    assert stored > 0
+
+    # a second process's first call: the same seam, resolved afresh
+    cf2 = _cc.CachedFunction("unit.spans", parts, jax.jit(fn),
+                             fingerprint_computation=True)
+    spans = _resolution_spans(cf2, x, x)
+    assert cf2.resolved == "hit" and listeners() == 0
+    assert set(spans) == {"compile.fingerprint", "compile.lookup",
+                          "compile.load"}
+    assert (spans["compile.lookup"]["hit"],
+            spans["compile.lookup"]["bytes"]) == (1, stored)
+    assert spans["compile.load"]["ok"] == 1
+    assert spans["compile.load"]["programs"] >= 1
+    # resolved: later calls open no resolution and record no span
+    assert _resolution_spans(cf2, x, x) == {}
+    # outside any trace a resolution still resolves, and leaves no
+    # listener behind
+    cf3 = _cc.CachedFunction("unit.spans", parts, jax.jit(fn),
+                             fingerprint_computation=True)
+    cf3(x, x)
+    assert cf3.resolved == "hit" and listeners() == 0
+
+
 # ---------------------------------------------------------------------------
 # gang layer: restart round-trip + failpoint chaos
 # ---------------------------------------------------------------------------

@@ -2,7 +2,8 @@
 _private/tracing.py): the call is ONE trace rooted at `train.call`,
 crossing the driver and the worker, whole in `call_log()` when the call
 returns (the worker's spans ride the task replies) and in the GCS trace
-table after the flush. CPU, tiny models."""
+table after the flush. A worker group's start is a tree of its own,
+rooted at `train.start`, in `start_log()`. CPU, tiny models."""
 
 import numpy as np
 import pytest
@@ -10,7 +11,7 @@ import pytest
 import ray_tpu
 from benchmark import boundary_path, span_log
 from ray_tpu._private import serialization, tracing
-from ray_tpu.train import Trainer, TrainingOperator, call_log
+from ray_tpu.train import Trainer, TrainingOperator, call_log, start_log
 from ray_tpu.train import trainer as trainer_mod
 from tests.conftest import scale_timeout
 from tests.test_observability import (_assert_connected, _tree_of,
@@ -79,6 +80,27 @@ def _names(entry):
     return [s["name"] for s in entry["spans"]]
 
 
+def _ids(log):
+    return [entry["trace_id"] for entry in log]
+
+
+def _one(entry, name):
+    (span,) = [s for s in entry["spans"] if s["name"] == name]
+    return span
+
+
+@pytest.fixture(scope="module")
+def ray_start_shared():
+    """The shared cluster with one DECLARED chip: a worker leased it is
+    spawned for the lease (no pool, no earlier task), so a start's tree
+    holds the worker's whole life."""
+    ray_tpu.init(num_cpus=8, num_tpus=1)
+    try:
+        yield
+    finally:
+        ray_tpu.shutdown()
+
+
 def _leaf_bytes(tree) -> int:
     import jax
 
@@ -111,7 +133,10 @@ def test_one_call_is_one_tree_across_driver_and_worker(wide):
     dispatch = next(s for s in entry["spans"]
                     if s["name"] == "train.dispatch")
     held = dispatch["attrs"]["state_bytes"]   # whole, on the one device
+    first = dispatch["attrs"]["first_dispatch_s"]  # of the loop's seconds
+    assert 0 < first <= dispatch["end"] - dispatch["start"]
     assert held > 0 and dispatch["attrs"] == {
+        "first_dispatch_s": first,
         "steps": 2, "samples": 16, "chips": 1, "state_bytes": held,
         "state_bytes_fullest_chip": held, "state_bytes_split_leading": 0}
     snapshot = next(s for s in entry["spans"]
@@ -307,3 +332,175 @@ def test_sharded_call_carries_the_shard_pulls(ray_start_shared):
     path = boundary_path.call_path(entry)
     assert path["pieces"] == 1 and path["hops_s"] > 0
     assert _names(entry).count("train.dispatch") == 2
+
+
+# ---------------------------------------------------------------------------
+# a worker group's start: one tree, rooted at `train.start`
+# ---------------------------------------------------------------------------
+
+# the worker's life up to its first call, then that call
+START_ORDER = ["worker.spawn", "worker.boot", "worker.actor_init",
+               "train.setup"]
+SETUP_PARTS = ["train.setup.backend", "train.setup.user",
+               "train.setup.init", "train.setup.place"]
+
+
+def _in_order_inside(entry, names, root):
+    spans = [_one(entry, n) for n in names]
+    assert root["start"] <= spans[0]["start"]
+    assert spans[-1]["end"] <= root["end"]
+    for a, b in zip(spans, spans[1:]):
+        assert a["start"] <= a["end"] <= b["start"], (a["name"], b["name"])
+    return spans
+
+
+def test_a_start_is_one_tree_in_a_log_of_its_own(ray_start_shared):
+    calls, starts = _ids(call_log()), _ids(start_log())
+    tr = Trainer(WideOperator, num_workers=1, use_tpu=True)
+    try:
+        # ONE entry, and not among the calls: readers find a call in
+        # `call_log()` by its position (the ring of calls may be full)
+        assert _ids(start_log())[:-1] == starts
+        assert _ids(call_log()) == calls
+        entry = start_log()[-1]
+        tr.train(num_steps=1)
+        assert _ids(start_log())[:-1] == starts
+        assert _ids(call_log())[:-1] in (calls, calls[1:])  # a full ring
+        first_call = call_log()[-1]
+        assert first_call["trace_id"] not in calls + _ids(start_log())
+    finally:
+        tr.shutdown(force=True)
+    ids = {s["span"] for s in entry["spans"]}
+    roots = [s for s in entry["spans"] if s["parent"] not in ids]
+    assert [r["name"] for r in roots] == ["train.start"]
+    root = roots[0]
+    assert root["attrs"] == {"generation": 1, "workers": 1, "restored": 0}
+    assert "train.start.restore" not in _names(entry)
+    spawn, boot, init, setup = _in_order_inside(entry, START_ORDER, root)
+    assert spawn["end"] == boot["start"]    # one stamp: `main` entered
+    assert spawn["attrs"]["flavor"] == boot["attrs"]["flavor"] == "tpu"
+    assert spawn["attrs"]["pid"] == boot["attrs"]["pid"] > 0
+    assert init["attrs"]["name"] == "TrainWorker"
+    assert 0 <= init["attrs"]["load_s"] <= init["end"] - init["start"]
+    # what the process did before it had a context hangs under the
+    # first traced task it ran, beside that task's own spans
+    task = next(s for s in entry["spans"] if s["name"] == "task"
+                and s["attrs"]["name"] == "TrainWorker.setup_operator")
+    assert {s["parent"] for s in (spawn, boot, init, setup)} == {
+        task["span"]}
+    # inside `train.setup`, its parts tile it: the backend, the user's
+    # `setup` on both sides of `register`, the state made and placed
+    parts = sorted((s for s in entry["spans"] if s["name"] in SETUP_PARTS),
+                   key=lambda s: s["start"])
+    assert [s["name"] for s in parts] == [
+        "train.setup.backend", "train.setup.user", "train.setup.init",
+        "train.setup.place", "train.setup.user"]
+    assert {s["parent"] for s in parts} == {setup["span"]}
+    for a, b in zip(parts, parts[1:]):
+        assert a["end"] <= b["start"] + 1e-6
+    named = sum(s["end"] - s["start"] for s in parts)
+    assert named >= 0.95 * (setup["end"] - setup["start"])
+    backend, _, made, placed, _ = parts
+    assert backend["attrs"] == {"platform": "cpu", "devices": 8}
+    weights = 300_000 * 4 + 4 * 4
+    # each closes on a wait for the device, and says how long it waited
+    assert made["attrs"] == {"bytes": weights,
+                             "wait_s": made["attrs"]["wait_s"]}
+    assert 0 <= made["attrs"]["wait_s"] <= made["end"] - made["start"]
+    # adam: two moments a weight and a count beside them
+    assert placed["attrs"] == {"state_bytes": 3 * weights + 4,
+                               "wait_s": placed["attrs"]["wait_s"]}
+    # the first call's tree holds the step's resolution, part by part
+    compiles = [s for s in first_call["spans"]
+                if s["name"].startswith(("compile.", "jax.compile"))]
+    assert {s["name"] for s in compiles} >= {
+        "compile.fingerprint", "compile.lookup", "jax.compile"}
+    assert {s["attrs"]["key"] for s in compiles} == {
+        "train.step:fused:8x4,8x4"}
+    dispatch = _one(first_call, "train.dispatch")
+    took = dispatch["end"] - dispatch["start"]
+    assert 0 < dispatch["attrs"]["first_dispatch_s"] <= took
+
+
+def test_a_restart_is_a_second_tree_with_the_restore(ray_start_shared):
+    tr = Trainer(WideOperator, num_workers=1, use_tpu=True, max_retries=2)
+    try:
+        tr.train(num_steps=1)
+        last_call, starts = call_log()[-1]["trace_id"], _ids(start_log())
+        state_bytes = _leaf_bytes(tr._last_state)
+        ray_tpu.kill(tr.workers[0])
+        tr.train(num_steps=1)
+        assert _ids(call_log())[-2] == last_call    # one call more
+        assert _ids(start_log())[:-1] == starts     # one start more
+        entry, call = start_log()[-1], call_log()[-1]
+    finally:
+        tr.shutdown(force=True)
+    root = _one(entry, "train.start")
+    # a tree of its own, which names the call that restarted the group
+    assert root["parent"] is None and root["attrs"] == {
+        "generation": 2, "workers": 1, "restored": 1,
+        "in_call": call["trace_id"]}
+    assert entry["trace_id"] != call["trace_id"]
+    assert not {"train.start", "train.setup", "worker.spawn"} & set(
+        _names(call))
+    assert _one(call, "train.epoch")["attrs"] == {"attempts": 2}
+    _, _, _, setup = _in_order_inside(entry, START_ORDER, root)
+    restore = _one(entry, "train.start.restore")
+    assert restore["parent"] == root["span"]
+    assert restore["attrs"] == {"bytes": state_bytes}
+    assert setup["end"] <= restore["start"] <= restore["end"] <= root["end"]
+    # the pieces it pushed are its children's children
+    loads = [s for s in span_log.under(entry, "task", "train.start.restore")
+             if s["attrs"]["name"] == "TrainWorker.load_state_piece"]
+    assert loads
+
+
+def test_pending_rows_go_home_with_the_first_traced_task():
+    """What a process did before it had a trace context (`tracing.
+    pending`) becomes children of the first traced task it runs: once,
+    bounded, and not of an untraced task."""
+    assert not tracing._pending
+    tracing.pending("worker.boot", 1.0, 2.5, {"pid": 7})
+    with tracing.collect_reply(None) as rows:
+        pass
+    assert rows is None and len(tracing._pending) == 1
+    ctx = tracing.new_context()
+    with tracing.collect_reply(ctx) as rows:
+        pass
+    assert [(r[0], r[1], r[2]) for r in rows] == [("worker.boot", 1.0, 2.5)]
+    assert rows[0][3]["psid"] == ctx.span_id.hex()
+    assert rows[0][3]["tid"] == ctx.trace_id.hex()
+    assert rows[0][3]["pid"] == 7
+    with tracing.collect_reply(tracing.new_context()) as again:
+        pass
+    assert not again and not tracing._pending
+    for i in range(3 * tracing.PENDING_MAX):
+        tracing.pending("worker.boot", 0.0, float(i))
+    assert len(tracing._pending) == tracing.PENDING_MAX
+    del tracing._pending[:]
+
+
+def test_the_chip_wait_is_a_pending_span_with_what_it_found():
+    from types import SimpleNamespace
+
+    from ray_tpu._private import accelerator
+    from ray_tpu._private.core_worker import CoreWorker
+
+    probes = iter([["/dev/vfio/0", "/dev/vfio/1"], ["/dev/vfio/1"], []])
+
+    def chip_wait():
+        facts = {}
+        accelerator.wait_for_chips(lambda: next(probes), pause=0.01,
+                                   facts=facts)
+        return facts
+
+    assert not tracing._pending
+    worker = SimpleNamespace(before_user_code=chip_wait)
+    try:
+        CoreWorker._before_user_code(worker)
+        CoreWorker._before_user_code(worker)    # once
+        ((name, start, end, facts),) = tracing._pending
+    finally:
+        del tracing._pending[:]
+    assert name == "worker.chip_wait" and facts["held"] == 2
+    assert 0.02 <= facts["waited_s"] <= end - start + 1e-3
