@@ -350,11 +350,13 @@ def annotating() -> bool:
 
 
 def record_span(name: str, start: float, end: float,
-                ctx: TraceContext | None, extra: dict | None = None) -> None:
+                ctx: TraceContext | None, extra: dict | None = None):
     """Record one span into the bound ProfileBuffer. With ctx=None this
     degrades to a plain profile event (no trace linkage) — used by the
-    unconditional task-execution event."""
+    unconditional task-execution event. Returns the span's row as the
+    open trees and replies keep it (None without a context)."""
     fields = dict(extra) if extra else {}
+    row = None
     if ctx is not None:
         fields["tid"] = ctx.trace_id.hex()
         fields["sid"] = ctx.span_id.hex()
@@ -371,6 +373,17 @@ def record_span(name: str, start: float, end: float,
         if tree is not None:
             tree.append(row)
     _get_buffer().record(name, start, end, fields)
+    return row
+
+
+def record_late(rows: list, name: str, start: float, end: float,
+                ctx: TraceContext, extra: dict | None = None) -> None:
+    """`record_span` for work that may outlive the tree that began it
+    (another thread's): the row is kept in `rows`, an `open_tree`'s
+    list, whether or not that tree is still open."""
+    row = record_span(name, start, end, ctx, extra)
+    if not any(kept is row for kept in rows):
+        rows.append(row)
 
 
 @contextlib.contextmanager

@@ -570,6 +570,20 @@ class TrainingOperator:
                 facts["mesh"] = [int(n) for n in self._mesh.shape.values()]
         return facts
 
+    def snapshot_bytes(self) -> dict:
+        """What one snapshot of this worker's state takes on the host:
+        `state_bytes` as `_layout_facts` has them, how many of them are
+        this rank's optimizer shard (`opt_shard_bytes`: the sharded
+        schedule, where the driver keeps every rank's; else 0) and the
+        number of `leaves` they come in. `TrainWorker.setup_operator`
+        replies with it, so the driver can reserve its buffer sets
+        before the first state arrives."""
+        state = (self.params, self.model_state, self.opt_state)
+        return {"state_bytes": self._layout_facts()["state_bytes"],
+                "opt_shard_bytes": (_shard.opt_nbytes(self.opt_state)
+                                    if self._sharded else 0),
+                "leaves": len(jax.tree.leaves(state))}
+
     def start_profile(self, profile_dir: str) -> bool:
         """Start a jax profiler session in this process; while it runs,
         the tracing spans recorded here are also host annotations in

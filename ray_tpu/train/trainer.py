@@ -12,7 +12,10 @@ local device mesh, so ICI collectives come from XLA, not this layer)."""
 from __future__ import annotations
 
 import collections
+import os
 import pickle
+import resource
+import threading
 import time
 import traceback
 import uuid
@@ -92,6 +95,233 @@ class _Pending(NamedTuple):
     num_steps: int | None
 
 
+# A buffer set's bytes are made resident a chunk at a time, each chunk
+# written this often by one thread before a copy may land in it: what a
+# page needs on the chip machines before it is written at the steady
+# rate (`_Reserve`).
+RESERVE_CHUNK = 32 << 20
+RESERVE_WRITES = 2
+_PAGE = 4096
+_LEAF_AT = 16       # a reserved leaf's offset within its page (`take`)
+
+
+def _dense_strides(x) -> tuple:
+    """The strides `np.array(x)` gives its copy: `x`'s own where `x` is
+    dense in some order of its axes (a leaf the device keeps transposed
+    stays so), else those of a dense array whose axes follow the order
+    of `x`'s strides."""
+    order = sorted(range(x.ndim), key=lambda i: (-abs(x.strides[i]), i))
+    strides, step = [0] * x.ndim, x.itemsize
+    for axis in reversed(order):
+        strides[axis] = step
+        step *= x.shape[axis]
+    dense = all(s == t for s, t, n in zip(strides, x.strides, x.shape)
+                if n > 1)
+    return x.strides if dense else tuple(strides)
+
+
+class _BufferSet(NamedTuple):
+    """One of the two sets of host buffers a Trainer's snapshots land in
+    by turns: the trees `_own` built last in it (None: none yet), how
+    many snapshots have been copied into it, and the bytes reserved for
+    it (None where the workers could not say how large a state is)."""
+
+    state: dict | None = None
+    shards: list | None = None
+    writes: int = 0
+    reserve: "_Reserve | None" = None
+
+
+class _Reserve:
+    """The bytes of ONE of the Trainer's two buffer sets, reserved when
+    the workers have said how large a state is and before one lands in
+    them: `_own` takes a leaf's destination from here when the leaf
+    arrives (`take`), and the Trainer's own threads (`_Reserver`) make
+    the bytes resident meanwhile, front to back.
+
+    Why: on the chip machines (gVisor) a page costs on its first TWO
+    writes, however it was allocated — `np.empty`, numpy's huge-page
+    advice on or off, `np.zeros`, a plain anonymous mapping: 1.0 GB/s,
+    then 2.3 GB/s, then 19 GB/s from the third write on (ISSUE 54's
+    probe, `PERF.md` §5) — and one thread pays that in full, while four
+    threads writing a GiB between them make it resident in 0.31 s and
+    leave the next write at 16 GB/s, a second round (0.03 s) at 19.
+    A snapshot copied by the driver's one thread into arrays of its
+    own making paid both prices inside `train()` calls 1 to 4."""
+
+    def __init__(self, nbytes: int):
+        import numpy as np
+
+        self.bytes = np.empty(nbytes, np.uint8)
+        # bytes handed out as destinations: a mark `Trainer._snapshot`
+        # puts back when a pull installs nothing
+        self.taken = 0
+        # bytes from the front that no thread has still to write: all
+        # of them until a `_Reserver` begins on the reservation, whole
+        # chunks while its threads run, all again once they have ended
+        self.ready = self.bytes.nbytes
+        self._chunks_done: set = set()
+        self._changed = threading.Condition()
+
+    def take(self, x):
+        """A destination for the leaf `x` in bytes nobody has taken —
+        its shape and dtype, the layout `np.array(x)` would give it —
+        or None where `x` does not fit what is left. It starts 16 bytes
+        into a page, where `np.array` puts a large leaf (glibc's header
+        in front of a mapping of its own): on these hosts a copy whose
+        destination lies 16 to some 600 bytes past its source, pages
+        apart, runs at 4–5 GB/s where every other runs at 19 (loads
+        that wait for the stores just before them: `PERF.md` §5), the
+        arena hands leaves out 300 to 2800 bytes into their pages, and
+        a destination at a page's head is never just past one of
+        those — placed by the source's own offset, 2–10 % of a steady
+        copy's bytes drew that lot."""
+        import numpy as np
+
+        at = self.bytes.ctypes.data + self.taken
+        first = self.taken + (_LEAF_AT - at) % _PAGE
+        if not x.nbytes or first + x.nbytes > self.bytes.nbytes:
+            return None
+        self.taken = first + x.nbytes
+        return np.ndarray(x.shape, x.dtype, buffer=self.bytes, offset=first,
+                          strides=_dense_strides(x))
+
+    def seal(self) -> None:
+        """The set's first snapshot is installed: what it left is never
+        handed out (a later tree's leaves are allocated, as ever)."""
+        self.taken = self.bytes.nbytes
+
+    def holds(self, leaf) -> bool:
+        """Whether `leaf` is an array `take` made."""
+        return leaf.base is self.bytes
+
+    def wait_for(self, leaf=None) -> float:
+        """Return once `leaf`'s bytes (None: all of them) are resident
+        and no thread writes them any more; the seconds that took (0.0:
+        they were ahead)."""
+        stop = self.bytes.nbytes if leaf is None else (
+            leaf.ctypes.data - self.bytes.ctypes.data + leaf.nbytes)
+        if self.ready >= stop:
+            return 0.0
+        start = time.perf_counter()
+        with self._changed:
+            self._changed.wait_for(lambda: self.ready >= stop)
+        return time.perf_counter() - start
+
+    def chunks(self) -> int:
+        return -(-self.bytes.nbytes // RESERVE_CHUNK)
+
+    def write(self, chunk: int) -> bool:
+        """Make one chunk resident (a reserver thread; leaves the GIL);
+        True when that was the reservation's last."""
+        part = self.bytes[chunk * RESERVE_CHUNK:(chunk + 1) * RESERVE_CHUNK]
+        for _ in range(RESERVE_WRITES):
+            part[:] = 0
+        with self._changed:
+            self._chunks_done.add(chunk)
+            while self.ready < self.bytes.nbytes and (
+                    self.ready // RESERVE_CHUNK in self._chunks_done):
+                self.ready = min(self.ready + RESERVE_CHUNK,
+                                 self.bytes.nbytes)
+            self._changed.notify_all()
+            return len(self._chunks_done) == self.chunks()
+
+    def release(self) -> None:
+        """No thread writes here any more: everything may be copied
+        into, resident or not."""
+        with self._changed:
+            self.ready = self.bytes.nbytes
+            self._changed.notify_all()
+
+
+class _Reserver:
+    """The threads that make a Trainer's reservations resident, beside
+    whatever the caller's thread does: the first set's from the moment
+    the workers have started (`begin(0)`: the first pull needs it, and
+    until then the driver mostly waits for the first step to be traced
+    and loaded), the second's from the Trainer's second `train()` call
+    (`begin(1)`: its pull is the first to need it, and pages made
+    resident any earlier would be memory the parent of this code never
+    held between the two calls; that call waits for the set to be
+    whole before it pulls, or before it returns where it pulls
+    nothing). Each reservation is one span
+    `train.snapshot.reserve` (`set`, `bytes`, `writes` a page,
+    `threads`, `minor_faults` of the process meanwhile), a child of the
+    `train.start` that reserved it and kept in that tree's `rows`
+    though it ends after the tree has closed."""
+
+    def __init__(self, reserves: list, ctx, rows: list):
+        self._reserves = reserves
+        self._ctx, self._rows = ctx, rows
+        self._work: collections.deque = collections.deque()
+        self._began: dict = {}
+        self._lock = threading.Lock()
+        self._stopped = False
+        self._threads: list = []
+
+    def begin(self, k: int) -> None:
+        """Start making set `k` resident (once; a no-op afterwards)."""
+        with self._lock:
+            if self._stopped or k in self._began:
+                return
+            chunks = self._reserves[k].chunks()
+            count = min(_reserve_threads(), chunks)
+            self._began[k] = (time.time(), _minor_faults(), count)
+            self._reserves[k].ready = 0     # a copy waits from here on
+            self._work.extend((k, chunk) for chunk in range(chunks))
+        threads = [threading.Thread(target=self._run, daemon=True,
+                                    name=f"train-reserve-{k}-{n}")
+                   for n in range(count)]
+        self._threads += threads
+        for t in threads:
+            t.start()
+
+    def _run(self):
+        while True:
+            with self._lock:
+                if self._stopped or not self._work:
+                    return
+                k, chunk = self._work.popleft()
+            if self._reserves[k].write(chunk):
+                self._record(k)
+
+    def _record(self, k: int):
+        start, faults, count = self._began[k]
+        self._began[k] = None
+        tracing.record_late(
+            self._rows, "train.snapshot.reserve", start, time.time(),
+            tracing.child(self._ctx),
+            {"set": k, "bytes": self._reserves[k].ready,
+             "writes": RESERVE_WRITES, "threads": count,
+             "minor_faults": _minor_faults() - faults})
+
+    def stop(self) -> None:
+        """End the threads (each after the chunk it is writing) and let
+        go of what they had not reached or begun: idempotent."""
+        with self._lock:
+            self._stopped = True
+        for t in self._threads:
+            t.join()
+        self._threads = []
+        for k, began in list(self._began.items()):
+            if began is not None:   # begun and not whole: what there is
+                self._record(k)
+        for reserve in self._reserves:
+            reserve.release()
+
+
+def _reserve_threads() -> int:
+    """Half the cores this process may run on, eight at most: six on
+    the one-chip machines' 13, where 8 GiB are written twice in 1.8 s
+    by six threads or eight, 2.5 s by four, 5.8 s by two and 13.4 s by
+    one (ISSUE 54's probe, `PERF.md` §5)."""
+    return max(1, min(8, len(os.sched_getaffinity(0)) // 2))
+
+
+def _minor_faults() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
 class TrainWorker(CollectiveActorMixin):
     """Actor wrapping a TrainingOperator (reference:
     distributed_torch_runner.py DistributedTorchRunner)."""
@@ -138,7 +368,10 @@ class TrainWorker(CollectiveActorMixin):
             self.operator = self._operator_cls(
                 self._config, self._rank, self._world_size,
                 group_name=self._group_name)
-        return True
+        # what one snapshot of this worker's state takes in the driver
+        # (`_BufferSet`), where the operator can say
+        sizes = getattr(self.operator, "snapshot_bytes", None)
+        return sizes() if sizes is not None else True
 
     def train_epoch(self, num_steps=None, profile_dir=None, pull_of=None):
         """`pull_of`: the driver pulls the copy held of that epoch while
@@ -410,10 +643,12 @@ class Trainer:
         self.workers: list = []
         self._last_state: dict | None = None
         self._last_shards: list | None = None
-        # The two (state, shards, writes) sets `_own` built last, newest
-        # first: the older one is the next copy's destination (train);
-        # `writes` counts how often its buffers have been written.
-        self._owned = ((None, None, 0), (None, None, 0))
+        # The two buffer sets (`_BufferSet`), the one written last
+        # first: the other is the next copy's destination (train). The
+        # first group's start reserves their bytes and has threads of
+        # the Trainer's own make them resident (`_reserve_sets`).
+        self._owned = (_BufferSet(), _BufferSet())
+        self._reserver: _Reserver | None = None
         # train() calls made; the call whose state is installed
         # (`_last_state`; None: none yet); the call whose state the
         # worker holds, not pulled yet (a _Pending, see the class)
@@ -491,11 +726,11 @@ class Trainer:
             try:
                 with tracing.span("train.start", root, counts,
                                   ambient=True):
-                    self._start_traced(num_workers, counts)
+                    self._start_traced(num_workers, counts, rows)
             finally:
                 _start_log.append((root.trace_id.hex(), rows))
 
-    def _start_traced(self, num_workers: int, counts: dict):
+    def _start_traced(self, num_workers: int, counts: dict, rows: list):
         group_name = f"sgd_{self._uid}_g{self._generation}"
         # cloudpickle: operator classes defined in __main__ or notebooks
         # serialize by value (stdlib pickle would import-by-reference and
@@ -527,8 +762,11 @@ class Trainer:
                 transport=self._collective_transport,
                 # ICI_RING reservations carry the derived transport tier
                 placement_group=self._pg)
-        ray_tpu.get([w.setup_operator.remote() for w in self.workers],
-                    timeout=self._setup_timeout)
+        sizes = ray_tpu.get([w.setup_operator.remote()
+                             for w in self.workers],
+                            timeout=self._setup_timeout)
+        if self._reserver is None:  # a restarted group: the sets exist
+            self._reserve_sets(sizes, rows)
         self._active_workers = num_workers
         self._start_ingest(num_workers)
         if (self._last_state is not None or self._pending is not None
@@ -539,6 +777,32 @@ class Trainer:
                               tracing.child_of_current(), restored,
                               ambient=True):
                 restored["bytes"] = self._restore_state()
+
+    def _reserve_sets(self, sizes: list, rows: list):
+        """Reserve the bytes of both buffer sets from what the workers
+        said of their state (`TrainingOperator.snapshot_bytes`; an
+        operator of another kind says nothing, and the sets are
+        allocated leaf by leaf as states arrive) and start the threads
+        that make the first resident, the one the first pull lands in
+        (the second's start with the second call: `_Reserver`). The
+        sharded schedule's snapshot is rank 0's state less its
+        optimizer shard, and every rank's shard."""
+        if not all(isinstance(s, dict) for s in sizes):
+            return
+        nbytes = sizes[0]["state_bytes"] - sizes[0]["opt_shard_bytes"] + sum(
+            s["opt_shard_bytes"] for s in sizes)
+        # a leaf starts at a fixed offset within a page (`_Reserve.take`)
+        nbytes += _PAGE * (sizes[0]["leaves"] + sum(
+            s["leaves"] for s in sizes[1:] if s["opt_shard_bytes"]))
+        try:
+            first, second = _Reserve(nbytes), _Reserve(nbytes)
+        except MemoryError:
+            return
+        self._reserver = _Reserver([first, second], tracing.current(), rows)
+        self._reserver.begin(0)
+        newer, older = self._owned
+        self._owned = (newer._replace(reserve=second),
+                       older._replace(reserve=first))
 
     def _start_ingest(self, num_workers: int):
         """One DatasetShard actor per rank; every generation re-shards
@@ -754,6 +1018,12 @@ class Trainer:
                 _call_log.append((root.trace_id.hex(), rows))
 
     def _train_traced(self, num_steps, reduce_results, profile_dir):
+        if self._calls == 2 and self._reserver is not None:
+            # the first pull that lands in the second set is this
+            # call's or, where this call's state is held, the next's,
+            # beside its epoch: then this call ends when the set is
+            # whole (below), so that no later call finds it half made
+            self._reserver.begin(1)
         if profile_dir:
             ray_tpu.get([w.start_profile.remote(profile_dir)
                          for w in self.workers], timeout=120)
@@ -776,6 +1046,10 @@ class Trainer:
                 self._pending = _Pending(self._calls, held[0], num_steps)
             else:
                 self._snapshot(self._calls)
+            if self._calls == 2:
+                for reserve in (s.reserve for s in self._owned):
+                    if reserve is not None:
+                        reserve.wait_for()
         finally:
             if profile_dir:
                 # a worker restarted mid-call has no session (a no-op);
@@ -804,29 +1078,50 @@ class Trainer:
             # parts are installed, and the sets turned, only once
             # both are whole: a copy that raises changes nothing.
             newer, older = self._owned
-            spare_state, spare_shards, writes = older
-            # sharded: the epoch-boundary snapshot is params (rank
-            # 0; identical everywhere) + ALL optimizer shards — the
-            # reshardable unit the elastic restore path consumes.
-            # Rank 0's own shard is never kept, so it stays where it
-            # is and the tree matches the spare's.
-            state = self._pull_state(
-                self.workers[0], spare_state, counts,
-                drop=("opt_shard",) if self._sharded else (),
-                writes=writes, of_epoch=of_epoch)
-            shards = None
-            if self._sharded:
-                shards = _own(ray_tpu.get(
-                    [w.opt_shard_state.remote() for w in self.workers],
-                    timeout=120), spare_shards, writes)
+            reserve = older.reserve
+            taken = reserve.taken if reserve is not None else 0
+            # A pull does not start before its set is whole: the
+            # threads that write it and the worker's chain into pages
+            # of its own slow each other by more than either takes
+            # (set 1 beside the second call's pull: 6.4-7.0 s for the
+            # 1.8 s of writing, 5.0-5.5 s for the 2.7 s of pull).
+            waited = reserve.wait_for() if reserve is not None else 0.0
+            try:
+                # sharded: the epoch-boundary snapshot is params (rank
+                # 0; identical everywhere) + ALL optimizer shards — the
+                # reshardable unit the elastic restore path consumes.
+                # Rank 0's own shard is never kept, so it stays where
+                # it is and the tree matches the spare's.
+                state = self._pull_state(
+                    self.workers[0], older.state, counts,
+                    drop=("opt_shard",) if self._sharded else (),
+                    writes=older.writes, of_epoch=of_epoch,
+                    reserve=reserve, waited=waited)
+                shards = None
+                if self._sharded:
+                    shards = _own(ray_tpu.get(
+                        [w.opt_shard_state.remote() for w in self.workers],
+                        timeout=120), older.shards, older.writes,
+                        reserve=reserve)
+            except BaseException:
+                # what this pull took of the reservation is nobody's
+                if reserve is not None:
+                    reserve.taken = taken
+                raise
             self._last_state, self._last_shards = state, shards
             self._snapshot_of = of_call
             # a set no leaf of which went into the spare's buffers
-            # (a first call, a changed tree) is new: written once
+            # (a first call, a changed tree) is new: written once —
+            # and done with a reservation none of it lies in
             reused = _written_into((state, shards),
-                                   (spare_state, spare_shards))
-            self._owned = ((state, shards, writes + 1 if reused else 1),
-                           newer)
+                                   (older.state, older.shards))
+            if reserve is not None:
+                reserve.seal()
+                if not _lies_in((state, shards), reserve):
+                    reserve = None
+            self._owned = (_BufferSet(
+                state, shards, older.writes + 1 if reused else 1, reserve),
+                newer)
 
     def _epoch_beside_pull(self, num_steps) -> list:
         """A call that finds the last call's state held: the epoch is
@@ -881,14 +1176,16 @@ class Trainer:
 
     def _pull_state(self, worker, spare=None, counts: dict | None = None,
                     drop=(), writes: int = 0,
-                    of_epoch: int | None = None) -> dict:
+                    of_epoch: int | None = None, reserve=None,
+                    waited: float = 0.0) -> dict:
         """`worker`'s training state (with `of_epoch`: the copy it holds
         since that epoch's end), whole, in memory the driver owns.
         It crosses the object plane as the pieces `train/snapshot.py`
         cuts (also a state the store would hold whole): each goes
         device→host and into the arena on the worker, out through `_own`
-        into `spare`'s leaves here (a tree an earlier pull built, see
-        `_own`), and is released. The driver asks ahead — the actor runs
+        into `spare`'s leaves here (a tree an earlier pull built, or
+        bytes of `reserve` where it built none: see `_own`), and is
+        released. The driver asks ahead — the actor runs
         the `state_piece` calls in order, one at a time (on a lane of
         their own where a held copy is pulled beside an epoch:
         `TrainWorker.task_lane`), so the worker brings the next piece to
@@ -936,7 +1233,8 @@ class Trainer:
                 ask_ahead()                 # what fits beside this piece
                 first, stop = ranges[index]
                 leaves.extend(_own(piece["leaves"], spares[first:stop],
-                                   writes, piece=index))
+                                   writes, piece=index, reserve=reserve,
+                                   waited=waited if index == 0 else 0.0))
                 piece = None                # the views die here
                 held -= sizes[index]
                 ask_ahead()                 # ... and what fits without it
@@ -1051,11 +1349,13 @@ class Trainer:
     def shutdown(self, force: bool = False):
         if force:
             self._kill_workers()
+            self._end_reserver()
             return
         try:    # what the workers hold of the last call comes home first
             self._drain()
         except exc.RayTpuError:
             pass
+        self._end_reserver()
         for w in self.workers:
             try:
                 w.shutdown.remote()
@@ -1071,7 +1371,13 @@ class Trainer:
         self._release_gang()
 
 
-def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None):
+    def _end_reserver(self):
+        if self._reserver is not None:
+            self._reserver.stop()
+
+
+def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None,
+         reserve=None, waited: float = 0.0):
     """A whole copy of `snapshot` in memory the driver owns, with no
     view into the object store left in it. What `get` returns are
     zero-copy views PINNED in the node's shared arena; a snapshot the
@@ -1086,46 +1392,70 @@ def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None):
     snapshot is installed, whichever call's it is, so the installed one
     is never a destination while a deferred pull writes beside an epoch
     either). A leaf is copied
-    INTO the spare's leaf at the same path when that is an owned,
-    writable array of the same shape, dtype and strides — a flat copy
-    into pages already mapped and resident, where a fresh array over
-    glibc's mmap threshold takes a page fault per 4 KiB. (Strides, not
-    C-order: on a TPU some leaves arrive transposed, ResNet's `fc_w`
-    for one, and `np.array` keeps their layout.) Any other leaf — a
-    first call, a changed tree, optimizer shards re-partitioned to
-    another world size — is allocated, so the copy is bit-identical
-    either way. `spare` is only ever written to, never returned as a
-    whole: a copy that raises half-way leaves a half-written spare and
-    the installed snapshot untouched. In steady state the driver holds
+    INTO the spare's leaf at the same path when that is a writable
+    array of the same shape, dtype and strides that is the driver's own
+    — it owns its data, or `reserve` holds it. (Strides, not C-order:
+    on a TPU some leaves arrive transposed, ResNet's `fc_w` for one,
+    and the copy keeps their layout.) A leaf the spare has no place for
+    takes its destination from `reserve`, the bytes the Trainer
+    reserved for this set before any state arrived (`_Reserve`), and
+    waits there for the Trainer's threads to have made them resident
+    and written them as often as a page needs before it takes a write
+    at the steady rate: on the chip machines a page's first write runs
+    at 1.0 GB/s and its second at 2.3 where the third runs at 19,
+    under `np.empty`, `np.zeros` and a plain mapping alike, and
+    `np.array(x)` on the driver's one thread paid both inside a pull.
+    Any other leaf — no reservation, a changed tree, optimizer shards
+    re-partitioned to another world size — is allocated with
+    `np.array(x)`, so the copy is bit-identical either way. `spare` and
+    `reserve` are only ever written to, never returned as a whole: a
+    copy that raises half-way leaves a half-written spare and the
+    installed snapshot untouched. In steady state the driver holds
     two sets of buffers (2 x the snapshot's bytes: 2.8 GiB for
     GPT-2-small), which was the peak before — the old snapshot was
     alive while the new one was built. The span's `reused_bytes` says
-    how much went into the spare (0 on a Trainer's first two calls,
-    then = `bytes`), its `dest_writes` how often the buffers it went
-    into had been written before: `writes`, the count the Trainer keeps
-    for the spare's set, or 0 where every array had to be allocated
-    (1 is a set's second write, a Trainer's calls 3 and 4: the
-    slow one). `piece` is the snapshot piece's index, if it is one."""
+    how much went into the spare or the reservation (= `bytes` from a
+    Trainer's first call on; 0 where every array had to be allocated),
+    `reserve_wait_s` how long the copy stood waiting for bytes the
+    reserving threads had not reached (0.0: they were ahead; a pull's
+    first copy carries what the pull waited for its set to be whole
+    before it asked for a piece: `waited`), its
+    `dest_writes` how many SNAPSHOTS had been copied into the set
+    before: `writes`, the count the Trainer keeps for it, or 0 where
+    every array had to be allocated (0, 0, 1, 1, 2 ... over a
+    Trainer's calls). `piece` is the snapshot piece's index, if it is
+    one."""
     import jax
     import numpy as np
 
-    counts = {"bytes": 0, "reused_bytes": 0, "dest_writes": writes}
+    counts = {"bytes": 0, "reused_bytes": 0, "dest_writes": writes,
+              "reserve_wait_s": waited}
     if piece is not None:
         counts["piece"] = piece
     spares = dict(jax.tree_util.tree_flatten_with_path(spare)[0])
+
+    def reserved(dst) -> bool:
+        return reserve is not None and reserve.holds(dst)
 
     def own(path, x):
         if not isinstance(x, np.ndarray):
             return x
         counts["bytes"] += x.nbytes
         dst = spares.get(path)
-        if (isinstance(dst, np.ndarray) and dst.flags.owndata
-                and dst.flags.writeable and dst.shape == x.shape
-                and dst.dtype == x.dtype and dst.strides == x.strides):
-            np.copyto(dst, x)
-            counts["reused_bytes"] += x.nbytes
-            return dst
-        return np.array(x)
+        if dst is None and reserve is not None:
+            dst = reserve.take(x)       # the set's first snapshot
+        elif not (isinstance(dst, np.ndarray)
+                  and (dst.flags.owndata or reserved(dst))
+                  and dst.flags.writeable and dst.shape == x.shape
+                  and dst.dtype == x.dtype and dst.strides == x.strides):
+            dst = None
+        if dst is None:
+            return np.array(x)
+        if reserved(dst):
+            counts["reserve_wait_s"] += reserve.wait_for(dst)
+        np.copyto(dst, x)
+        counts["reused_bytes"] += x.nbytes
+        return dst
 
     with tracing.span("train.snapshot.copy", tracing.child_of_current(),
                       counts):
@@ -1133,6 +1463,15 @@ def _own(snapshot, spare=None, writes: int = 0, piece: int | None = None):
         if counts["bytes"] and not counts["reused_bytes"]:
             counts["dest_writes"] = 0
         return out
+
+
+def _lies_in(tree, reserve) -> bool:
+    """Whether a leaf of `tree` is one `reserve` handed out."""
+    import jax
+    import numpy as np
+
+    return any(isinstance(x, np.ndarray) and reserve.holds(x)
+               for x in jax.tree.leaves(tree))
 
 
 def _written_into(new, spare) -> bool:

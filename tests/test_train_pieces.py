@@ -19,9 +19,11 @@ import jax.numpy as jnp
 import ray_tpu
 from benchmark import boundary_path, span_log
 from ray_tpu._private import global_state
-from ray_tpu.train import Trainer, TrainingOperator, call_log
+from ray_tpu.train import (Trainer, TrainingOperator, call_log,
+                           start_log)
 from ray_tpu.train import operator as operator_mod
 from ray_tpu.train import snapshot
+from ray_tpu.train import trainer as trainer_mod
 from tests.conftest import scale_timeout
 
 P = jax.sharding.PartitionSpec
@@ -553,11 +555,14 @@ def test_a_state_several_arenas_large_crosses_in_pieces(wide):
         assert sum(s["bytes"] for s in _span(
             entry, "train.snapshot.d2h")) == snap["bytes"]
         reused.append(sum(c["reused_bytes"] for c in copies))
-    assert reused == [0, 0, snap["bytes"], snap["bytes"]]  # as PR 26 left it
+    # into bytes the Trainer already had from its first call on (PR 54:
+    # before, the first two calls' leaves were allocated as they came)
+    assert reused == [snap["bytes"]] * 4
     state = wide._last_state
+    reserve = wide._owned[0].reserve
     for x in jax.tree.leaves(state):
         if isinstance(x, np.ndarray):
-            assert x.flags.owndata and x.flags.writeable
+            assert reserve.holds(x) and x.flags.writeable
     # bit-identical: pulled again (fresh buffers), the same bits
     assert _bits(wide.state_dict()) == _bits(state)
     # nothing of it is left in the arena
@@ -802,10 +807,12 @@ def test_a_shards_write_leaves_the_gil_for_every_dtype_a_state_keeps(dtype):
 
 
 def test_dest_writes_counts_each_buffer_sets_writes(host):
-    """0, 0 (both sets are allocated), 1, 1 (each set's second write:
-    the slow one on the chip machines), then 2, 2 ... — a count the
-    Trainer keeps with each set; a set that had to be allocated anew
-    (here: the spare's tree is not the state's) starts again at 0."""
+    """0, 0 (each set's first snapshot: into the bytes reserved for it,
+    so `reused_bytes` = `bytes` from the first call on), 1, 1 (each
+    set's second), then 2, 2 ... — a count of SNAPSHOTS the Trainer
+    keeps with each set; a set that had to be allocated anew (here: the
+    spare's tree is not the state's, and the reservation is taken)
+    starts again at 0 and lets go of its reservation."""
     tr = Trainer(Small, num_workers=1)
     seen = []
 
@@ -817,14 +824,23 @@ def test_dest_writes_counts_each_buffer_sets_writes(host):
 
     try:
         for _ in range(6):
-            call()
+            copy = call()
+            assert copy["reused_bytes"] == copy["bytes"] > 0
+            assert copy["reserve_wait_s"] >= 0.0
         assert seen == [0, 0, 1, 1, 2, 2]
-        assert [s[2] for s in tr._owned] == [3, 3]
-        newer, (state, shards, writes) = tr._owned
-        tr._owned = (newer, ({"w": state["params"]["w0"]}, shards, writes))
+        assert [s.writes for s in tr._owned] == [3, 3]
+        assert all(s.reserve is not None for s in tr._owned)
+        newer, older = tr._owned
+        tr._owned = (newer, older._replace(
+            state={"w": older.state["params"]["w0"]}))
         copy = call()
         assert copy["dest_writes"] == 0 and copy["reused_bytes"] == 0
-        assert [s[2] for s in tr._owned] == [1, 3]
+        assert [s.writes for s in tr._owned] == [1, 3]
+        # a changed tree is allocated as ever: its leaves own their data
+        # and the set has let go of the reservation none of them is in
+        assert tr._owned[0].reserve is None
+        assert all(x.flags.owndata for x in jax.tree.leaves(tr._last_state)
+                   if isinstance(x, np.ndarray))
         # the untouched set goes on counting, the new one starts over
         copy = call()
         assert copy["dest_writes"] == 3
@@ -832,6 +848,243 @@ def test_dest_writes_counts_each_buffer_sets_writes(host):
         assert call()["dest_writes"] == 1
     finally:
         tr.shutdown(force=True)
+
+
+# ---------------------------------------------------------------------
+# the two sets are reserved, and made resident, before a state lands
+# ---------------------------------------------------------------------
+
+def _reserver_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("train-reserve")]
+
+
+@pytest.fixture
+def held_back(monkeypatch):
+    """The reserving threads stand still until the test lets them go:
+    an Event they wait for before they write their first chunk."""
+    go = threading.Event()
+    real = trainer_mod._Reserve.write
+
+    def write(self, chunk):
+        assert go.wait(scale_timeout(60))
+        return real(self, chunk)
+
+    monkeypatch.setattr(trainer_mod._Reserve, "write", write)
+    try:
+        yield go
+    finally:
+        go.set()
+
+
+def _reserve_spans(entry):
+    return sorted((s for s in entry["spans"]
+                   if s["name"] == "train.snapshot.reserve"),
+                  key=lambda s: s["attrs"]["set"])
+
+
+def test_the_sets_are_reserved_at_the_start_and_made_resident_beside_it(
+        host, held_back, monkeypatch):
+    """`Trainer(...)` returns before a byte is resident; a pull that
+    arrives early waits for its set to be whole, its first copy says
+    how long, and the right bytes are installed; the second set is
+    begun by the second call, whose pull is the first to land there,
+    and whole when that call returns; each set's reservation is one
+    span of the START's tree, ending after that tree closed."""
+    tr = Trainer(Small, num_workers=1, config={"weights": 4})
+    try:
+        entry = start_log()[-1]
+        assert _reserve_spans(entry) == []      # none has ended
+        assert len(_reserver_threads()) >= 1
+        first, second = tr._owned[1].reserve, tr._owned[0].reserve
+        state_bytes = 4 * 3 * 256 * 256 * 4 + 4     # adam: two moments
+        assert first.bytes.nbytes == second.bytes.nbytes >= state_bytes
+        assert first.bytes.nbytes <= state_bytes + 4096 * 14
+        # the first is being made resident, the second not begun: a
+        # copy would wait for the one and never for the other
+        assert first.ready == 0 and second.ready == second.bytes.nbytes
+        real_wait = trainer_mod._Reserve.wait_for
+
+        def wait_for(self, leaf=None):  # let go once the pull waits
+            if not held_back.is_set():
+                threading.Timer(0.25, held_back.set).start()
+            return real_wait(self, leaf)
+
+        monkeypatch.setattr(trainer_mod._Reserve, "wait_for", wait_for)
+        tr.train()
+        call = call_log()[-1]
+        copies = _span(call, "train.snapshot.copy")
+        assert len(copies) > 1      # several pieces
+        assert copies[0]["reserve_wait_s"] >= 0.2
+        assert all(c["reused_bytes"] == c["bytes"]
+                   and c["dest_writes"] == 0 for c in copies)
+        assert sum(c["bytes"] for c in copies) == state_bytes
+        assert all(first.holds(x) for x in jax.tree.leaves(tr._last_state)
+                   if isinstance(x, np.ndarray))
+        assert _bits(tr.state_dict()) == _bits(tr._last_state)
+        for t in _reserver_threads():
+            t.join(scale_timeout(30))
+        (a,) = _reserve_spans(start_log()[-1])   # the second: not begun
+        assert second.ready == second.bytes.nbytes
+        tr.train()
+        copies = _span(call_log()[-1], "train.snapshot.copy")
+        assert all(c["reused_bytes"] == c["bytes"] and c["dest_writes"] == 0
+                   and c["reserve_wait_s"] >= 0.0 for c in copies)
+        assert all(second.holds(x) for x in jax.tree.leaves(tr._last_state)
+                   if isinstance(x, np.ndarray))
+        assert second.ready == second.bytes.nbytes  # whole at its end
+        for t in _reserver_threads():
+            t.join(scale_timeout(30))
+        a, b = _reserve_spans(start_log()[-1])
+        (root,) = [s for s in start_log()[-1]["spans"]
+                   if s["name"] == "train.start"]
+        for k, span in enumerate((a, b)):
+            assert span["parent"] == root["span"]
+            assert span["end"] > root["end"] and span["start"] < span["end"]
+            assert span["attrs"] == {
+                "set": k, "bytes": first.bytes.nbytes,
+                "writes": trainer_mod.RESERVE_WRITES,
+                "threads": span["attrs"]["threads"],
+                "minor_faults": span["attrs"]["minor_faults"]}
+            assert span["attrs"]["threads"] >= 1
+            assert span["attrs"]["minor_faults"] >= 0
+        (second_call,) = [s for s in call_log()[-1]["spans"]
+                          if s["name"] == "train.call"]
+        assert a["end"] <= second_call["start"] <= b["start"]
+        # from here on nothing waits
+        for _ in range(3):
+            tr.train()
+            assert all(c["reserve_wait_s"] == 0.0 for c in _span(
+                call_log()[-1], "train.snapshot.copy"))
+    finally:
+        tr.shutdown(force=True)
+    assert _reserver_threads() == []
+
+
+def test_shutdown_leaves_no_reserver_thread(host, held_back):
+    """... also one that had not written a byte; and a restarted group
+    reserves nothing anew: the Trainer has its sets."""
+    tr = Trainer(Small, num_workers=1)
+    try:
+        reserver, sets = tr._reserver, [s.reserve for s in tr._owned]
+        assert _reserver_threads()
+        held_back.set()
+        starts = len(start_log())
+        ray_tpu.kill(tr.workers[0])
+        tr._kill_workers()
+        tr._resize_worker_group()
+        assert len(start_log()) == starts + 1 or starts == 32
+        assert tr._reserver is reserver
+        assert [s.reserve for s in tr._owned] == sets
+        assert len(start_log()[-1]["spans"]) > 1    # the restart's tree
+        assert _reserve_spans(start_log()[-1]) == []
+    finally:
+        tr.shutdown(force=True)
+    assert _reserver_threads() == []
+    tr = Trainer(Small, num_workers=1)
+    tr.shutdown()           # the graceful one too
+    assert _reserver_threads() == []
+
+
+@pytest.mark.parametrize("fail_at", [0, 2])
+def test_a_first_copy_that_raises_leaves_the_reservation_usable(
+        host, monkeypatch, fail_at):
+    """PR 26's guarantee on a Trainer's FIRST call, whose leaves come
+    out of the reservation: a copy that raises half-way installs
+    nothing and hands back what it took, and the next pull lands where
+    this one would have."""
+    tr = Trainer(Small, num_workers=1, config={"weights": 4})
+    real, calls = np.copyto, []
+
+    def copyto(dst, src, *a, **kw):
+        calls.append(dst)
+        if len(calls) == fail_at + 1:
+            raise MemoryError("injected: the copy-out's leaf failed")
+        return real(dst, src, *a, **kw)
+
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(np, "copyto", copyto)
+            with pytest.raises(MemoryError, match="injected"):
+                tr.train()
+        assert len(calls) == fail_at + 1
+        assert tr._last_state is None and tr._snapshot_of is None
+        newer, older = tr._owned
+        assert older.state is None and older.writes == 0
+        assert older.reserve.taken == 0 and newer.reserve.taken == 0
+        tr.train()
+        copies = _span(call_log()[-1], "train.snapshot.copy")
+        assert all(c["reused_bytes"] == c["bytes"]
+                   and c["dest_writes"] == 0 for c in copies)
+        assert all(older.reserve.holds(x)
+                   for x in jax.tree.leaves(tr._last_state)
+                   if isinstance(x, np.ndarray))
+        assert _bits(tr.state_dict()) == _bits(tr._last_state)
+    finally:
+        tr.shutdown(force=True)
+
+
+def test_state_dict_and_a_loaded_state_stay_the_callers(host):
+    """`state_dict()` returns arrays that own their data (nothing of a
+    reservation); arrays a caller loads are never written, before the
+    first pull or after, and never become a set's."""
+    tr = Trainer(Small, num_workers=1)
+    try:
+        theirs = tr.state_dict()
+        arrays = [x for x in jax.tree.leaves(theirs)
+                  if isinstance(x, np.ndarray)]
+        assert arrays and all(x.flags.owndata and x.base is None
+                              for x in arrays)
+        tr.load_state_dict(theirs)      # before any pull
+        before = _bits(theirs)
+        for _ in range(3):
+            tr.train()
+            assert _bits(theirs) == before
+            ours = [x for x in jax.tree.leaves(tr._last_state)
+                    if isinstance(x, np.ndarray)]
+            assert not any(np.shares_memory(x, y)
+                           for x in arrays for y in ours)
+            assert all(any(s.reserve.holds(y) for s in tr._owned)
+                       for y in ours)
+        again = tr.state_dict()
+        assert all(x.flags.owndata for x in jax.tree.leaves(again)
+                   if isinstance(x, np.ndarray))
+        assert not any(s.reserve.holds(x) for s in tr._owned
+                       for x in jax.tree.leaves(again)
+                       if isinstance(x, np.ndarray))
+    finally:
+        tr.shutdown(force=True)
+
+
+@pytest.mark.parametrize("order", ["C", "F", "permuted", "padded",
+                                   "reversed", "scalar"])
+def test_a_reserved_leaf_has_the_layout_np_array_gives(order):
+    """`_Reserve.take` against `np.array(x)`: the same strides wherever
+    `x` is dense in some order of its axes (a leaf the device keeps
+    transposed stays transposed), a dense copy's otherwise; 16 bytes
+    into a page, as `np.array` puts a large leaf; one after the other; None when the bytes run
+    out."""
+    x = np.arange(2 * 3 * 4, dtype=np.float32).reshape(2, 3, 4)
+    x = {"C": x, "F": np.asfortranarray(x),
+         "permuted": x.transpose(1, 2, 0), "padded": x[:, :, :3],
+         "reversed": x[::-1], "scalar": np.array(3, np.float32)}[order]
+    reserve = trainer_mod._Reserve(2 * (x.nbytes + 4096))
+    dst = reserve.take(x)
+    assert reserve.holds(dst) and not dst.flags.owndata
+    assert dst.shape == x.shape and dst.dtype == x.dtype
+    assert dst.strides == np.array(x).strides
+    assert dst.ctypes.data % 4096 == 16     # as a mapping of its own
+    np.copyto(dst, x)
+    assert dst.tobytes() == x.tobytes()
+    other = reserve.take(x)
+    assert other.ctypes.data >= dst.ctypes.data + x.nbytes
+    assert not np.shares_memory(other, dst)
+    assert reserve.take(np.zeros(8192, np.uint8)) is None   # no room left
+    reserve.seal()
+    assert reserve.take(np.zeros((), np.uint8)) is None
+    # a reservation nobody makes resident never holds a copy up
+    assert reserve.wait_for(other) == 0.0
+    assert trainer_mod._Reserve(8192).take(np.zeros((0, 3))) is None
 
 
 def _snap_seconds(entry):

@@ -1,11 +1,15 @@
 """The epoch-boundary snapshot keeps its host buffers (train/trainer.py
 `_own`, `Trainer.train`): two sets of driver-owned buffers alternate,
 call k is copied into the buffers of the snapshot call k - 1 retired.
-What must hold whatever the buffers do: `_last_state` is a whole,
-bit-identical, driver-owned copy of THAT call's state; it is never a
-destination; a caller's arrays are never written to; reuse is decided
-leaf by leaf (owned, writable, same shape, dtype and strides). CPU,
-tiny models."""
+Both sets' bytes are reserved when the workers have started, before a
+state lands in them (`_Reserve`), so every call's copy goes into bytes
+the Trainer already has — the first two into leaves taken from the
+reservation as they arrive. What must hold whatever the buffers do:
+`_last_state` is a whole, bit-identical, driver-owned copy of THAT
+call's state; it is never a destination; a caller's arrays are never
+written to; reuse is decided leaf by leaf (the Trainer's own — owning
+its data or held by the set's reservation —, writable, same shape,
+dtype and strides). CPU, tiny models."""
 
 import sys
 import time
@@ -62,6 +66,13 @@ def _addresses(tr) -> set:
             for x in _arrays((tr._last_state, tr._last_shards)).values()}
 
 
+def _is_the_trainers(tr, x) -> bool:
+    """An array only the Trainer writes: it owns its data, or it is a
+    leaf one of the two sets' reservations handed out."""
+    return (x.flags.owndata and x.base is None) or any(
+        s.reserve is not None and s.reserve.holds(x) for s in tr._owned)
+
+
 def _copies(entry) -> list[dict]:
     return [s["attrs"] for s in entry["spans"] if s["name"] == COPY]
 
@@ -100,7 +111,7 @@ def warm(request, ray_start_shared):
 
 @pytest.mark.parametrize("sharded", [False, True],
                          ids=["replicated", "sharded"])
-def test_buffers_alternate_and_reuse_from_the_third_call(
+def test_buffers_alternate_and_every_call_lands_in_bytes_already_there(
         ray_start_shared, sharded):
     tr = _make(sharded)
     try:
@@ -118,8 +129,9 @@ def test_buffers_alternate_and_reuse_from_the_third_call(
                 assert "opt_shard" not in tr._last_state
     finally:
         tr.shutdown(force=True)
-    # nothing on the first two calls (0), everything from the third on
-    assert reused == [[0] * len(copies)] * 2 + [[True] * len(copies)] * 3
+    # everything from the first call on (PR 54: before, nothing went
+    # into a buffer that was there on a Trainer's first two calls)
+    assert reused == [[True] * len(copies)] * 5
     # exactly two sets of buffers, taking turns
     assert sets[0] == sets[2] == sets[4] and sets[1] == sets[3]
     assert not sets[0] & sets[1]
@@ -154,16 +166,23 @@ def test_buffers_alternate_when_the_pull_is_a_call_late(ray_start_shared):
                 [0] if len(sets) == 1 else [] if len(sets) == 2 else [1])
             if len(sets) > 2:       # written beside: never the installed
                 assert not installed & sets[-1]
+            if len(sets) == 2:
+                # the second call held its state and pulled nothing; it
+                # returned with the set the NEXT pull lands in whole, so
+                # no pull beside an epoch finds its set half made
+                assert all(s.reserve.ready == s.reserve.bytes.nbytes
+                           for s in tr._owned)
         assert _bits(tr._last_state) != _workers_snapshot(tr)  # a call old
         assert tr._pending is None      # ... until asked (state_dict)
         assert tr._last_state["epoch"] == 6
     finally:
         tr.shutdown(force=True)
     assert epochs == [1, 1, 2, 3, 4, 5]
-    # call 1 pulls at once, call 2 not at all, call 3 into a new set,
-    # from call 4 on everything goes into the other set's buffers
+    # call 1 pulls at once, call 2 not at all, call 3 into the other
+    # set: every pull goes into bytes the Trainer had reserved
     size = copies[0][0][1]
-    assert copies == [[(0, size)], [], [(0, size)]] + [[(size, size)]] * 3
+    assert copies == [[(size, size)], [], [(size, size)]] + [
+        [(size, size)]] * 3
     assert sets[0] == sets[1] == sets[3] == sets[5]
     assert sets[2] == sets[4] and not sets[0] & sets[2]
 
@@ -175,7 +194,7 @@ def test_snapshot_is_whole_owned_and_leaves_the_arena(warm):
         kept = (warm._last_state, warm._last_shards)
         assert _bits(kept) == _workers_snapshot(warm)
         for path, x in _arrays(kept).items():
-            assert x.flags.owndata and x.base is None, path
+            assert _is_the_trainers(warm, x), path
             assert x.flags.writeable and x.flags.c_contiguous, path
         # the return's arena block went with the views (the owner's
         # delete rides the raylet: give it a moment)

@@ -195,8 +195,11 @@ def test_parts_of_a_call_add_up(wide):
         copy = next(s for s in entry["spans"]
                     if s["name"] == "train.snapshot.copy")
         assert copy["attrs"]["bytes"] == parts["bytes"]
-        # how much of it went into the buffers of a retired snapshot
-        assert 0 <= copy["attrs"]["reused_bytes"] <= parts["bytes"]
+        # all of it went into bytes the Trainer had before the state
+        # came (a retired snapshot's buffers, or its set's reservation),
+        # and the span says how long it waited for them to be resident
+        assert copy["attrs"]["reused_bytes"] == parts["bytes"]
+        assert copy["attrs"]["reserve_wait_s"] >= 0.0
         # the same boundary cut along the driver's thread: with ONE
         # piece the wait is at most the worker's d2h and put and the
         # hops (all of them on the chip, where the driver is in the wait
@@ -455,7 +458,54 @@ def test_a_restart_is_a_second_tree_with_the_restore(ray_start_shared):
     assert loads
 
 
-def test_pending_rows_go_home_with_the_first_traced_task():
+def test_a_late_row_is_kept_in_a_tree_that_has_closed():
+    """`tracing.record_late`: a span another thread ends after the
+    tree that began it has closed (`train.snapshot.reserve` under
+    `train.start`) is still that tree's — once, open tree or not."""
+    root = tracing.new_context()
+    with tracing.open_tree(root) as rows:
+        early = tracing.child(root)
+        tracing.record_late(rows, "early", 1.0, 2.0, early, {"set": 0})
+    late = tracing.child(root)
+    tracing.record_late(rows, "late", 1.5, 3.0, late, {"set": 1})
+    assert [(r[0], r[1], r[2], r[3]["set"]) for r in rows] == [
+        ("early", 1.0, 2.0, 0), ("late", 1.5, 3.0, 1)]
+    for row, ctx in zip(rows, (early, late)):
+        assert row[3]["tid"] == root.trace_id.hex()
+        assert row[3]["sid"] == ctx.span_id.hex()
+        assert row[3]["psid"] == root.span_id.hex()
+
+
+def test_a_start_with_no_training_operator_reserves_nothing(
+        ray_start_shared):
+    """An operator that cannot say how large its state is (not a
+    `TrainingOperator`): no reservation, no thread, no span — its
+    snapshots are allocated as they arrive."""
+    tr = Trainer(NoOp, num_workers=1)
+    try:
+        assert tr._reserver is None
+        assert all(s.reserve is None for s in tr._owned)
+        assert "train.snapshot.reserve" not in _names(start_log()[-1])
+    finally:
+        tr.shutdown(force=True)
+
+
+@pytest.fixture
+def no_pending_rows():
+    """`tracing._pending` is the PROCESS's: a test that ran before in
+    this pytest process (another of this file, or of another file under
+    `--dist loadfile`) may have left rows there — a core worker's
+    `_before_user_code`, a boot stamp. Empty for the test, put back
+    after it."""
+    kept = tracing._pending[:]
+    del tracing._pending[:]
+    try:
+        yield
+    finally:
+        tracing._pending[:] = kept
+
+
+def test_pending_rows_go_home_with_the_first_traced_task(no_pending_rows):
     """What a process did before it had a trace context (`tracing.
     pending`) becomes children of the first traced task it runs: once,
     bounded, and not of an untraced task."""
@@ -480,7 +530,8 @@ def test_pending_rows_go_home_with_the_first_traced_task():
     del tracing._pending[:]
 
 
-def test_the_chip_wait_is_a_pending_span_with_what_it_found():
+def test_the_chip_wait_is_a_pending_span_with_what_it_found(
+        no_pending_rows):
     from types import SimpleNamespace
 
     from ray_tpu._private import accelerator
