@@ -12,7 +12,25 @@ D7). The kinds built so far:
   and k, outside the kernel) and an RMSNorm over each head of q and k
   before them are properties of an attention kind: `cfg.rotary` and
   `cfg.qk_norm` list the kinds that have them (by default `"window"`
-  turns and nothing is normed).
+  turns and nothing is normed). `cfg.by_kind` gives each attention
+  kind a HEAD COUNT and a ROTARY RULE of its own (`AttentionKind`;
+  Laguna: 48 query heads on full layers and 64 under a window of 512,
+  8 key/value heads of 128 on both): `wq`, `wo` and the gate's `wg`
+  then cannot share a stack and are kept one a kind, `wq_full` /
+  `wq_window` .. stacked over that kind's layers (`wk`, `wv` and the
+  norms stay one stack over all); a kind turns the first `rope_dim`
+  dimensions of a head (rotate-half within them) and the rest pass;
+  its rates are `theta ** (-2i / rope_dim)`, or under YaRN (factor s,
+  extending from L0 positions, beta_fast, beta_slow) `theta ** (-2i /
+  d) ((1 - ramp_i) + ramp_i / s)` with `c(n) = d ln(L0 / (2 pi n)) /
+  (2 ln theta)`, `low = max(floor(c(beta_fast)), 0)`, `high =
+  min(ceil(c(beta_slow)), d - 1)`, `ramp_i = clip((i - low) / (high -
+  low), 0, 1)`, and cos and sin times `rope_scale`; `_rope_for` makes
+  a table a kind. `cfg.attn_gate` adds a per-head output gate, `a_h <-
+  sigmoid(x . w_g)_h a_h` before `wo`, reading the layer's normed
+  input: a leaf `wg` `[d_model, heads]`, the product and the gate
+  float32. A configuration that names no kind and no gate keeps its
+  parameter tree, its seeded weights and its traced program.
 - mixer `"conv"`: no attention at all. An input projection to three
   streams, the gated short convolution of `ops/short_conv.py` (B * x, a
   depthwise causal convolution of `conv_taps` taps, * C), an output
@@ -146,21 +164,23 @@ diffusion. With `loops` 1, no sandwich and no gate none of this is
 traced and a configuration's program is what it was.
 
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
-scores, the selection bias, the head norms, the exit gate and every
-norm's statistics are float32.
+scores, the selection bias, the head norms, the exit gate, the
+attention's output gate and every norm's statistics are float32.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import diffusion_tiles, flash_attention
+from ray_tpu.ops.attention import (diffusion_tiles, flash_attention,
+                                   window_scores)
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
@@ -181,10 +201,30 @@ _GROUP = {"full": "attention", "window": "attention", "conv": "conv",
 
 
 def _groups_of(pair) -> tuple[str, ...]:
-    """The leaf groups a layer of kinds (mixer, mlp) holds."""
+    """The leaf groups a layer of kinds (mixer, mlp) holds; an attention
+    layer also the group of its own kind (`"full"`, `"window"`: the
+    leaves whose shape follows a kind's head count, `cfg.by_kind`)."""
     return ("layer",) + tuple(
         g for part, kind in zip(("mixer", "mlp"), pair) if kind != "none"
-        for g in (part, _GROUP[kind]))
+        for g in (part, _GROUP[kind]) + (kind,) * (kind in ATTENTION_KINDS))
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What an attention kind has of its own where a configuration names
+    its kinds (`DecoderConfig.by_kind`): the query heads, and the rotary
+    rule — theta, the TURNED width (the first `rope_dim` dimensions of a
+    head turn, rotate-half within them, the rest pass; 0: none turn),
+    YaRN's blend of interpolated and extrapolated rates, a factor on cos
+    and sin."""
+    n_heads: int
+    rope_theta: float = 1e4
+    rope_dim: int = 0
+    yarn: tuple[float, int, float, float] | None = None   # factor, the
+    #                                   positions it extends from,
+    #                                   beta_fast, beta_slow
+    rope_scale: float = 1.0           # on cos and sin (YaRN's
+    #                                   attention_factor)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,6 +294,12 @@ class DecoderConfig:
     #                                   expected-exit loss over the walks
     exit_beta: float = 0.0            # ... minus this times the entropy of
     #                                   the exit distribution
+    by_kind: tuple[tuple[str, AttentionKind], ...] = ()   # head count and
+    #                                   rotary rule by attention kind; then
+    #                                   n_heads, rope_theta and rotary are
+    #                                   not read for those layers
+    attn_gate: bool = False           # a per-head sigmoid gate on the
+    #                                   attention's output, before wo
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -336,6 +382,26 @@ class DecoderConfig:
             raise ValueError(
                 "the sandwich norm reads ONE output of the MLP: not built "
                 "beside a shared expert (d_shared)")
+        named = tuple(kind for kind, _ in self.by_kind)
+        if self.by_kind and (
+                "latent" in mixers or len(set(named)) != len(named)
+                or set(named) != mixers & set(ATTENTION_KINDS)
+                or any(rule.n_heads % self.n_kv_heads or rule.rope_dim % 2
+                       or not 0 <= rule.rope_dim <= self.head_dim
+                       for _, rule in self.by_kind)):
+            raise ValueError(
+                f"by_kind names each attention kind of the pattern once "
+                f"(got {named} for {sorted(mixers & set(ATTENTION_KINDS))})"
+                f", its heads a multiple of the {self.n_kv_heads} key/value "
+                f"heads, its turned width even and at most a head's "
+                f"{self.head_dim}; not built beside the latent mixer, which "
+                "turns its rope part itself")
+        if self.attn_gate and (self.mtp or self.loops > 1
+                               or not mixers & set(ATTENTION_KINDS)):
+            raise ValueError(
+                "attn_gate gates the heads of the mixers \"full\" and "
+                "\"window\" and counts how open it is: not built for an "
+                "MTP block or a stack walked more than once")
 
     @property
     def kinds(self) -> tuple[tuple[str, str], ...]:
@@ -343,6 +409,17 @@ class DecoderConfig:
         lead = tuple(zip(self.lead_attention, self.lead_mlp))
         period = tuple(zip(self.attention, self.mlp))
         return lead + period * ((self.n_layers - len(lead)) // len(period))
+
+    def heads_of(self, kind: str) -> int:
+        """The query heads of an attention layer of `kind`."""
+        return dict(self.by_kind)[kind].n_heads if self.by_kind \
+            else self.n_heads
+
+    def leaf_of(self, leaf: str, kind: str) -> str:
+        """The name of a leaf whose shape follows the head count (`wq`,
+        `wo`, the gate's `wg`) on a layer of attention `kind`: one stack
+        for all attention layers, or under `by_kind` one a kind."""
+        return f"{leaf}_{kind}" if self.by_kind else leaf
 
     @property
     def moe_layers(self) -> int:
@@ -380,10 +457,20 @@ def _leaves(cfg: DecoderConfig) -> dict:
         table.update(norm1_post=table["norm1"], norm2_post=table["norm2"])
     if "attention" in groups:
         table.update(
-            wq=("attention", (d, cfg.n_heads * hd), "normal"),
             wk=("attention", (d, cfg.n_kv_heads * hd), "normal"),
-            wv=("attention", (d, cfg.n_kv_heads * hd), "normal"),
-            wo=("attention", (cfg.n_heads * hd, d), "normal"))
+            wv=("attention", (d, cfg.n_kv_heads * hd), "normal"))
+        # the leaves a head count shapes: one stack over the attention
+        # layers, or one a kind over that kind's layers
+        for group in ATTENTION_KINDS if cfg.by_kind else ("attention",):
+            if group not in groups:
+                continue
+            heads = cfg.heads_of(group)
+            table.update({
+                cfg.leaf_of("wq", group): (group, (d, heads * hd), "normal"),
+                cfg.leaf_of("wo", group): (group, (heads * hd, d), "normal")})
+            if cfg.attn_gate:
+                table[cfg.leaf_of("wg", group)] = (group, (d, heads),
+                                                   "normal")
         if cfg.qk_norm:
             table.update(q_norm=("attention", (hd,), "one"),
                          k_norm=("attention", (hd,), "one"))
@@ -459,7 +546,8 @@ _KEY_OF = {name: i for i, name in enumerate((
 _LATER = ("conv_in", "conv_taps", "conv_out", "w1", "w3", "w2")
 _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "ws_down", "proj", "ssm_in", "ssm_conv", "ssm_conv_bias", "A_log",
-          "dt_bias", "ssm_out", "exit_gate")
+          "dt_bias", "ssm_out", "exit_gate", "wg", "wq_full", "wo_full",
+          "wg_full", "wq_window", "wo_window", "wg_window")
 _MTP_KEY = 1 << 16
 _NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
@@ -549,6 +637,34 @@ def _rope_rates(cfg: DecoderConfig, dim: int | None = None):
     return cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
 
 
+def _kind_rates(rule: AttentionKind):
+    """What a unit of position turns each pair of a kind's turned width
+    d = `rule.rope_dim` by, [d / 2]: theta ** (-2i / d), or under YaRN
+    (Hugging Face's `_compute_yarn_parameters`, `truncate` on) its blend
+    with the interpolated rate. With `c(n) = d ln(L0 / (2 pi n)) / (2 ln
+    theta)` — the pair that turns n times over the L0 positions the
+    scaling extends from — `low = max(floor(c(beta_fast)), 0)`, `high =
+    min(ceil(c(beta_slow)), d - 1)`, `ramp_i = clip((i - low) / (high -
+    low), 0, 1)`: `theta ** (-2i / d) * ((1 - ramp_i) + ramp_i /
+    factor)`. The pairs that turn fast keep their rate, the slow ones
+    turn `factor` times slower."""
+    half = rule.rope_dim // 2
+    i = jnp.arange(half, dtype=jnp.float32)
+    rates = rule.rope_theta ** (-i / half)
+    if rule.yarn is None:
+        return rates
+    factor, original, fast, slow = rule.yarn
+
+    def pair_turning(n):
+        return rule.rope_dim * math.log(original / (2 * math.pi * n)) / (
+            2 * math.log(rule.rope_theta))
+
+    low = max(math.floor(pair_turning(fast)), 0)
+    high = min(math.ceil(pair_turning(slow)), rule.rope_dim - 1)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return rates * (1.0 - ramp) + rates / factor * ramp
+
+
 def _turned(rates, positions):
     angle = positions[:, None] * rates[None, :]
     return jnp.cos(angle), jnp.sin(angle)
@@ -556,7 +672,13 @@ def _turned(rates, positions):
 
 def _rope(x, cos, sin):
     """Rotate-half pairing: dimension i turns with dimension i + half.
-    x: [B, T, H, hd]; computed in float32, returned in x's dtype."""
+    x: [B, T, H, hd]; computed in float32, returned in x's dtype. Tables
+    narrower than half a head turn the head's first 2 x their width and
+    the rest passes as it is."""
+    if 2 * cos.shape[-1] < x.shape[-1]:
+        width = 2 * cos.shape[-1]
+        return jnp.concatenate(
+            [_rope(x[..., :width], cos, sin), x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:]
@@ -706,19 +828,33 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
             h = joined(h, _latent_attention(x, p, rope, cfg), "norm1_post")
     elif attention != "none":
         with jax.named_scope("attention_" + attention):
-            q = (x @ cast(p["wq"])).reshape(b, t, cfg.n_heads, hd)
+            heads = cfg.heads_of(attention)
+            leaf = functools.partial(cfg.leaf_of, kind=attention)
+            q = (x @ cast(p[leaf("wq")])).reshape(b, t, heads, hd)
             k = (x @ cast(p["wk"])).reshape(b, t, cfg.n_kv_heads, hd)
             v = (x @ cast(p["wv"])).reshape(b, t, cfg.n_kv_heads, hd)
             if attention in cfg.qk_norm:
                 q = _head_norm(q, p["q_norm"], cfg.rms_eps)
                 k = _head_norm(k, p["k_norm"], cfg.rms_eps)
-            if attention in cfg.rotary:
-                q, k = _rope(q, *rope), _rope(k, *rope)
+            if cfg.by_kind:     # the kind's own table, where it turns
+                table = rope.get(attention)
+            else:
+                table = rope if attention in cfg.rotary else None
+            if table is not None:
+                q, k = _rope(q, *table), _rope(k, *table)
             a = flash_attention(
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
                 cfg.window if attention == "window" else None,
                 cfg.diffusion_block or None)
-            h = joined(h, a.reshape(b, t, cfg.n_heads * hd) @ cast(p["wo"]),
+            if cfg.attn_gate:
+                # a_h <- sigmoid(x . w_g)_h a_h: float32, as the router is
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x.astype(jnp.float32), p[leaf("wg")],
+                    precision=lax.Precision.HIGHEST))
+                found["attn_gate_sum_" + attention] = lax.stop_gradient(
+                    gate.sum())
+                a = (a.astype(jnp.float32) * gate[..., None]).astype(a.dtype)
+            h = joined(h, a.reshape(b, t, heads * hd) @ cast(p[leaf("wo")]),
                        "norm1_post")
     if mlp != "none":
         y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
@@ -746,17 +882,29 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
 
 def _rope_for(t: int, cfg: DecoderConfig):
     """The one rotary table a configuration's mixers turn the t rows
-    by: the latent mixer's rope part, or a head. Row i stands at
-    position i; under block diffusion the rows are two copies of t / 2
-    positions and both halves turn alike."""
+    by: the latent mixer's rope part, or a head; under `cfg.by_kind`
+    `{kind: table}` of the kinds that turn, each by its own rule
+    (`_kind_rates`, cos and sin times the rule's `rope_scale`). Row i
+    stands at position i; under block diffusion the rows are two copies
+    of t / 2 positions and both halves turn alike."""
     latent = "latent" in cfg.attention + cfg.lead_attention
     # the rates before the positions: the order the recorded programs
     # of the configurations that turn were traced in
-    rates = _rope_rates(cfg, cfg.qk_rope_dim if latent else None)
+    if cfg.by_kind:
+        rates = {kind: _kind_rates(rule) for kind, rule in cfg.by_kind
+                 if rule.rope_dim}
+    else:
+        rates = _rope_rates(cfg, cfg.qk_rope_dim if latent else None)
     if cfg.diffusion_block:
-        return _turned(rates, jnp.tile(
-            jnp.arange(t // 2, dtype=jnp.float32), 2))
-    return _turned(rates, jnp.arange(t, dtype=jnp.float32))
+        positions = jnp.tile(jnp.arange(t // 2, dtype=jnp.float32), 2)
+    else:
+        positions = jnp.arange(t, dtype=jnp.float32)
+    if not cfg.by_kind:
+        return _turned(rates, positions)
+    scale = {kind: rule.rope_scale for kind, rule in cfg.by_kind}
+    return {kind: tuple(x if scale[kind] == 1.0 else x * scale[kind]
+                        for x in _turned(rates[kind], positions))
+            for kind in rates}
 
 
 def _block(cfg: DecoderConfig, attention: str, mlp: str):
@@ -1140,8 +1288,13 @@ def counters_init(cfg: DecoderConfig):
     `exit_mass_T` (the exit distribution's p(t) summed over them: the T
     add up to `loop_targets`), `exit_entropy` (its entropy summed; at
     most `loop_targets` x log T) and `loop_targets` (tokens scored: B x
-    (L - 1) a step). The configurations from before each of these keep
-    the state tree their recorded programs were lowered with."""
+    (L - 1) a step). Under `cfg.attn_gate`, a kind of the pattern:
+    `attn_gate_sum_full` / `_window` (the gates sigmoid(x . w_g) summed
+    over tokens, heads, that kind's layers and the epoch's steps) and
+    `attn_gate_count_full` / `_window` (how many were summed: the
+    quotient is how open the gate stands, one half at seeded weights).
+    The configurations from before each of these keep the state tree
+    their recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
     i32 = functools.partial(jnp.zeros, (), jnp.int32)
     counters = {}
@@ -1164,7 +1317,17 @@ def counters_init(cfg: DecoderConfig):
     if cfg.diffusion_block:
         counters.update(diffusion_masked=f32(), diffusion_targets=f32(),
                         diffusion_weight_max=f32())
+    if cfg.attn_gate:
+        counters.update({f"attn_gate_{what}_{kind}": f32()
+                         for kind in _attention_layers(cfg)
+                         for what in ("sum", "count")})
     return {"epoch_counters": counters}
+
+
+def _attention_layers(cfg: DecoderConfig) -> dict:
+    """attention kind -> how many layers of the pattern have it."""
+    return {kind: n for kind in ATTENTION_KINDS
+            if (n := sum(a == kind for a, _ in cfg.kinds))}
 
 
 def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
@@ -1180,7 +1343,16 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     bounds); where the stack is walked more than once `loops`,
     `layer_passes` (loops x layers: the blocks a step runs forward) and
     `head_passes` (passes of the chunked head: one a walk under an exit
-    gate, else one); nothing otherwise."""
+    gate, else one); under `cfg.by_kind` `attention_heads_full` /
+    `_window` (the kinds the pattern has), `rope_scaling` (`"yarn:64"`:
+    the kinds' scaling rules, where one has one) and, with window
+    layers, `attention_window`, `window_scores_inside` (a step's score
+    entries inside causal AND window on those layers: batch x heads x
+    layers x the plane's, the forward's twice for its rematerialised
+    copy and the backward's once) and `window_scores_visited` (the
+    entries of the tiles `flash_fwd` and `flash_bwd_fused` walk for
+    them, from the kernels' own bounds: `ops.attention.window_scores`);
+    nothing otherwise."""
     b, t = batch_shape
     facts = {}
     if cfg.loops > 1:
@@ -1197,6 +1369,22 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
                      diffusion_rows=b * 2 * t,
                      attention_tiles_visited=visited,
                      attention_tiles_plane=plane)
+    if cfg.by_kind:
+        at = _attention_layers(cfg)
+        facts.update({f"attention_heads_{kind}": cfg.heads_of(kind)
+                      for kind in at})
+        scaled = [f"yarn:{rule.yarn[0]:g}" for _, rule in cfg.by_kind
+                  if rule.yarn]
+        if scaled:
+            facts["rope_scaling"] = ",".join(scaled)
+        if "window" in at:
+            inside, fwd, bwd = window_scores(
+                t, cfg.window, cfg.head_dim, cfg.dtype, cfg.attn_block_q,
+                cfg.attn_block_k)
+            planes = b * at["window"] * cfg.heads_of("window")
+            facts.update(attention_window=cfg.window,
+                         window_scores_inside=planes * 3 * inside,
+                         window_scores_visited=planes * (2 * fwd + bwd))
     return facts
 
 
@@ -1287,8 +1475,15 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
             diffusion_targets=old["diffusion_targets"] + float(tokens.size),
             diffusion_weight_max=jnp.maximum(old["diffusion_weight_max"],
                                              counts["diffusion_weight_max"]))
+    rows = tokens.size * (2 if cfg.diffusion_block else 1)
+    if cfg.attn_gate:
+        for kind, layers in _attention_layers(cfg).items():
+            new.update({
+                f"attn_gate_sum_{kind}": old[f"attn_gate_sum_{kind}"]
+                + counts[f"attn_gate_sum_{kind}"].sum(),
+                f"attn_gate_count_{kind}": old[f"attn_gate_count_{kind}"]
+                + float(rows * layers * cfg.heads_of(kind))})
     if "moe_rows_static" in old:
-        rows = tokens.size * (2 if cfg.diffusion_block else 1)
         new.update(
             moe_rows_static=old["moe_rows_static"] + float(
                 cfg.moe_layers * static_rows(

@@ -169,6 +169,43 @@ def _diffusion_query_blocks(ki, block_q: int, block_k: int, half: int,
             where(noised, half + end + block_q - 1, 2 * half) // block_q)
 
 
+def _causal_key_blocks(qi, block_q: int, block_k: int, num_k: int,
+                       window: int | None, minimum=jnp.minimum,
+                       maximum=jnp.maximum):
+    """(first, end) of the key blocks the forward's K loop walks for
+    query block `qi` under the causal mask and an optional window; `qi`
+    a traced scalar in the kernel, a numpy array in `window_scores`."""
+    # only scan K blocks at or before this Q block
+    if block_q % block_k:
+        # the block holding this Q block's last row, exactly
+        last = ((qi + 1) * block_q + block_k - 1) // block_k
+    else:   # the expression this kernel always had, text for text
+        last = (qi + 1) * block_q // block_k + (block_q % block_k != 0)
+    num_k_active = minimum(num_k, last)
+    # ... and, under a window, at or after the first block the
+    # block's first query still reaches. A row whose keys all lie in
+    # later blocks leaves that block with m = NEG_INF and l = the
+    # block's width (exp(0) a masked score); its first real score
+    # rescales both by exp(NEG_INF - s) = 0.0 exactly, and every row
+    # has one (its own key), so o and m + log(l) are exact at the end
+    first = 0 if window is None else maximum(
+        0, qi * block_q - (window - 1)) // block_k
+    return first, num_k_active
+
+
+def _causal_query_blocks(ki, block_q: int, block_k: int, last, causal: bool,
+                         window: int | None, minimum=jnp.minimum):
+    """(first, end) of the query blocks the backward's Q loop walks for
+    key block `ki`, `last` the end without a window: only the Q blocks
+    whose last row reaches this K block's first key and, under a window,
+    whose first row is still within the window of its last."""
+    first = ki * block_k // block_q if causal else 0
+    if window is not None:
+        last = minimum(
+            last, ((ki + 1) * block_k + window - 2) // block_q + 1)
+    return first, last
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
                   causal: bool, scale: float, window: int | None = None,
                   diffusion: int | None = None):
@@ -228,21 +265,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         o, m, l = jax.lax.fori_loop(first, last, body, jax.lax.fori_loop(
             0, clean, body, (o0, m0, l0)))
     elif causal:
-        # only scan K blocks at or before this Q block
-        if block_q % block_k:
-            # the block holding this Q block's last row, exactly
-            last = ((qi + 1) * block_q + block_k - 1) // block_k
-        else:   # the expression this kernel always had, text for text
-            last = (qi + 1) * block_q // block_k + (block_q % block_k != 0)
-        num_k_active = jnp.minimum(num_k, last)
-        # ... and, under a window, at or after the first block the
-        # block's first query still reaches. A row whose keys all lie in
-        # later blocks leaves that block with m = NEG_INF and l = the
-        # block's width (exp(0) a masked score); its first real score
-        # rescales both by exp(NEG_INF - s) = 0.0 exactly, and every row
-        # has one (its own key), so o and m + log(l) are exact at the end
-        first = 0 if window is None else jnp.maximum(
-            0, qi * block_q - (window - 1)) // block_k
+        first, num_k_active = _causal_key_blocks(
+            qi, block_q, block_k, num_k, window)
         o, m, l = jax.lax.fori_loop(first, num_k_active, body, (o0, m0, l0))
     else:
         o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
@@ -429,6 +453,32 @@ def diffusion_tiles(t: int, block: int, block_q: int, block_k: int
     return int((clean + last - first).sum()), (t // block_q) * (t // block_k)
 
 
+def window_scores(t: int, window: int, d: int, dtype, block_q: int | None,
+                  block_k: int | None) -> tuple[int, int, int]:
+    """Of one head's T x T score plane under causal AND `window`: (the
+    entries inside the mask, the entries of the tiles `flash_fwd`'s K
+    loop walks, those of the tiles `flash_bwd_fused`'s Q loop walks),
+    from the bounds and the tiles the kernels themselves use, reckoned
+    in numpy. A window of one tile side fills at best half of what is
+    walked: every 512 keys seen lie across two tiles. A shape the
+    kernels refuse takes the dense path, which walks the plane."""
+    seen = min(window, t)       # rows 0 .. seen - 1 see i + 1 keys
+    inside = seen * (seen + 1) // 2 + (t - seen) * seen
+    block_q, block_k = fwd_tiles(t, d, dtype, block_q, block_k)
+    if not _flash_aligned(t, d, block_q, block_k):
+        return inside, t * t, t * t
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    first, end = _causal_key_blocks(
+        np.arange(t // block_q), block_q, block_k, t // block_k, window,
+        np.minimum, np.maximum)
+    fwd = int((end - first).sum()) * block_q * block_k
+    block_q, block_k = _bwd_tiles(t, d, dtype)
+    first, end = _causal_query_blocks(
+        np.arange(t // block_k), block_q, block_k, t // block_q, True,
+        window, np.minimum)
+    return inside, fwd, int((end - first).sum()) * block_q * block_k
+
+
 def _dense_grouped(q, k, v, scale, window, diffusion=None):
     """Dense causal attention under an optional window (or under the
     block-diffusion mask in its place), with k and v of
@@ -592,13 +642,9 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         jax.lax.fori_loop(first, half // block_q, body, None)
         first, last = low, high
     else:
-        # only the Q blocks whose last row reaches this K block's first key
-        first = ki * block_k // block_q if causal else 0
-        last = q_ref.shape[0] // block_q
-    if window is not None:
-        # ... and whose first row is still within the window of its last
-        last = jnp.minimum(
-            last, ((ki + 1) * block_k + window - 2) // block_q + 1)
+        # (the block-diffusion mask takes no window)
+        first, last = _causal_query_blocks(
+            ki, block_q, block_k, q_ref.shape[0] // block_q, causal, window)
     jax.lax.fori_loop(first, last, body, None)
 
     def write():
