@@ -31,6 +31,7 @@ from jax.flatten_util import ravel_pytree
 from ray_tpu._private import profiling as _profiling
 from ray_tpu._private import tracing as _tracing
 from ray_tpu.train import sharding as _shard
+from ray_tpu.train import snapshot as _snapshot
 
 # snapshot leaves above this get a span of their own at the fine level
 _LEAF_SPAN_BYTES = 1 << 20
@@ -125,7 +126,9 @@ class TrainingOperator:
         self._stage = None      # the staging area (_staging), once needed
         self._joiners = None    # the join's threads (_join_pool), likewise
         self._join_width = 0    # ... and how many they are
-        self._holds = None      # the room rule's answer (_room_to_hold)
+        self._room = None       # the room rule's answer (_room_to_hold)
+        self._usable = None     # the budget the state was last cut by
+        self._held_from = {}    # ... and where the held part begins
         self._held = None       # the copy taken at the last epoch's end
         self._pull_open = False     # ... is being pulled (state_piece)
         self._held_cv = threading.Condition()
@@ -365,10 +368,12 @@ class TrainingOperator:
         self._apply_step = jax.jit(apply_step, donate_argnums=(0, 1))
 
         # The held copy (`_hold`): the whole state once more on the
-        # devices, laid out as it is; nothing donated.
+        # devices, laid out as it is; nothing donated. A part of it
+        # goes through the same function as (leaves, None, None).
         def copy_state(params, mstate, opt_state):
             return jax.tree.map(jnp.copy, (params, mstate, opt_state))
 
+        self._copy_fn = copy_state
         self._copy_out = self._fused_out and self._fused_out[:3]
         self._copy_state = jax.jit(copy_state, out_shardings=self._copy_out)
         if self._sharded:
@@ -557,7 +562,7 @@ class TrainingOperator:
                     continue
                 whole += x.nbytes
                 shard_shape = x.sharding.shard_shape(x.shape)
-                shard = int(np.prod(shard_shape)) * x.dtype.itemsize
+                shard = _shard_bytes(x)
                 for d in x.sharding.addressable_devices:
                     held[d] = held.get(d, 0) + shard
                 if x.ndim >= 2 and shard_shape[0] < x.shape[0]:
@@ -670,8 +675,9 @@ class TrainingOperator:
         }
         if self._epoch_counters:
             out["counters"] = counters
-        if held:
+        if held:    # ... and from which leaf of the state on (0: whole)
             out["held_epoch"] = self.epoch
+            out["held_from"] = self._held["first"]
         return out
 
     # ------------------------------------------------------------------
@@ -687,53 +693,89 @@ class TrainingOperator:
             for d in x.sharding.addressable_devices}
         return [d.memory_stats() for d in devices]
 
-    def _room_to_hold(self) -> bool:
-        """THE RULE, read once, after the first epoch: a second copy of
-        the state is held on the devices iff, on every device the state
-        lives on, the state's bytes on the fullest device fit beside
-        the most the device has held so far — the step's peak: live
-        buffers, or what is live now plus the runtime's reservation for
-        programs' temporaries, whichever is more — with `_HOLD_MARGIN`
-        of its memory to spare. Nothing else decides it: no name, no
-        size chosen for a cell, no option. A backend that keeps no
-        count (the CPU) has no room; neither has an operator that does
-        not own its whole state (a host-collective group's rank)."""
+    def _room_to_hold(self) -> int:
+        """THE RULE, read once, after the first epoch: the bytes of a
+        second copy of the state that fit on every device the state
+        lives on, beside the most the device has held so far — the
+        step's peak: live buffers, or what is live now plus the
+        runtime's reservation for programs' temporaries, whichever is
+        more — with `_HOLD_MARGIN` of its memory to spare; the least
+        over the devices. Nothing else decides it: no name, no size
+        chosen for a cell, no option. A backend that keeps no count
+        (the CPU) has no room; neither has an operator that does not
+        own its whole state (a host-collective group's rank)."""
         if self.world_size != 1 or self._sharded:
-            return False
-        need = self._layout_facts()["state_bytes_fullest_chip"]
-        stats = self._device_memory()
-        for s in stats:
+            return 0
+        room = None
+        for s in self._device_memory():
             if (not s or s.get("bytes_limit") is None
                     or s.get("peak_bytes_in_use") is None):
-                return False
+                return 0
             peak = max(s["peak_bytes_in_use"], s.get("bytes_in_use", 0)
                        + s.get("bytes_reserved", 0))
-            if peak + need + _HOLD_MARGIN * s["bytes_limit"] \
-                    > s["bytes_limit"]:
-                return False
-        return bool(stats)
+            free = int(s["bytes_limit"] - peak
+                       - _HOLD_MARGIN * s["bytes_limit"])
+            room = free if room is None else min(room, free)
+        return max(room or 0, 0)
+
+    def _held_part(self) -> tuple | None:
+        """What of the state is held in that room: (its first leaf in
+        tree order, its bytes), or None where nothing is. The whole
+        (leaf 0) where the state's bytes on the fullest device fit;
+        else the run of WHOLE pieces at the tail of the cut the state
+        last crossed by (`snapshot.plan` under `state_piece`'s
+        `usable`) whose bytes on the fullest device fit: the driver
+        pulls those beside the next epoch and only the pieces before
+        them at once. Before the state has crossed once nobody knows
+        the cut, and a part is not held: a Trainer's first call pulls
+        everything at once anyway."""
+        room = self._room
+        if not room:
+            return None
+        facts = self._layout_facts()
+        if room >= facts["state_bytes_fullest_chip"]:
+            return 0, facts["state_bytes"]
+        usable = self._usable
+        if usable is None:
+            return None
+        if usable not in self._held_from:
+            leaves = jax.tree.leaves(self._state_tree())
+            sizes = [_snapshot.leaf_bytes(x) for x in leaves]
+            on_chip = [_shard_bytes(x) for x in leaves]
+            first, fit = len(leaves), 0
+            for a, b in reversed(_snapshot.plan(sizes, usable)):
+                if fit + sum(on_chip[a:b]) > room:
+                    break
+                first, fit = a, fit + sum(on_chip[a:b])
+            self._held_from[usable] = (
+                (first, sum(sizes[first:])) if first < len(leaves) else None)
+        return self._held_from[usable]
 
     @property
     def holds_state(self) -> bool:
-        """A copy of the state, as an epoch left it, is on the devices."""
+        """A copy of the state (or of its tail), as an epoch left it,
+        is on the devices."""
         return self._held is not None
 
     def _hold(self) -> bool:
         """At an epoch's end, where the devices have the room
-        (`_room_to_hold`): one jitted copy of the whole state, beside
-        the live one, which `state_piece(of_epoch=)` reads while the
-        NEXT epoch's steps donate and overwrite the live buffers. The
-        copy is dispatched, not waited for: the device runs it before
-        the next step. A copy that was pulled went when its last piece
-        was read (`end_pull`); one that still is being pulled (the pull
-        may outlast its epoch) is waited for, one nobody asked for is
-        let go first: never two copies. With no room this is one
-        attribute read: no span, no program."""
-        if self._holds is None:
-            self._holds = self._room_to_hold()
-        if not self._holds:
+        (`_room_to_hold`, `_held_part`): one jitted copy of the state,
+        or of the pieces at its tail that fit, beside the live one,
+        which `state_piece(of_epoch=)` reads while the NEXT epoch's
+        steps donate and overwrite the live buffers. The copy is
+        dispatched, not waited for: the device runs it before the next
+        step. A copy that was pulled went when its last piece was read
+        (`end_pull`); one that still is being pulled (the pull may
+        outlast its epoch) is waited for, one nobody asked for is let
+        go first: never two copies. With room for no piece this is one
+        attribute read and a comparison: no span, no program."""
+        if self._room is None:
+            self._room = self._room_to_hold()
+        part = self._held_part()
+        if part is None:
             return False
-        counts = {"epoch": self.epoch,
+        first, held_bytes = part
+        counts = {"epoch": self.epoch, "held_bytes": held_bytes,
                   "bytes": self._layout_facts()["state_bytes"]}
         with _tracing.span("train.hold", _tracing.child_of_current(),
                            counts):
@@ -747,18 +789,47 @@ class TrainingOperator:
                         f"{_HOLD_WAIT_S:.0f} s (Trainer: end_pull)")
                 self._held = None
             t1 = time.perf_counter()
-            copy = self._cached_step("hold", "state", self._copy_state,
-                                     out_shardings=self._copy_out)
-            params, mstate, opt_state = copy(
-                self.params, self.model_state, self.opt_state)
+            tree = self._state_tree()
+            leaves, treedef = jax.tree.flatten(tree)
+            if first:
+                arrays = [x for x in leaves[first:]
+                          if isinstance(x, jax.Array)]
+                copied = iter(self._copy_program(first, arrays)(
+                    arrays, None, None)[0])
+                tail = [next(copied) if isinstance(x, jax.Array) else x
+                        for x in leaves[first:]]
+            else:
+                params, mstate, opt_state = self._copy_program(0, ())(
+                    self.params, self.model_state, self.opt_state)
+                tail = jax.tree.leaves(dict(
+                    tree, params=params, model_state=mstate,
+                    opt_state=opt_state))
             # of the span: waiting for a pull to end (and letting an
             # unread copy go), dispatching the copy (the first: building)
             counts.update(wait_s=t1 - t0, copy_s=time.perf_counter() - t1)
             self._held = {
-                "params": params, "model_state": mstate,
-                "epoch": self.epoch, "global_step": self.global_step,
-                "opt_state": opt_state}
+                "epoch": self.epoch, "first": first, "leaves": tail,
+                "treedef": treedef,
+                "sizes": [_snapshot.leaf_bytes(x) for x in leaves]}
         return True
+
+    def _copy_program(self, first: int, arrays):
+        """The copy of the held part that begins at leaf `first`: the
+        whole state's (`first` 0) is one program over (params, model
+        state, optimizer state), a tail's the same function over
+        (`arrays`, None, None), each laid out as it lies."""
+        if not first:
+            return self._cached_step("hold", "state", self._copy_state,
+                                     out_shardings=self._copy_out)
+        fn = self._step_cache.get(("hold", f"from{first}"))
+        if fn is None:
+            out = self._copy_out and ([x.sharding for x in arrays],
+                                      None, None)
+            fn = self._cached_step(
+                "hold", f"from{first}",
+                jax.jit(self._copy_fn, out_shardings=out),
+                out_shardings=out)
+        return fn
 
     def expect_pull(self, of_epoch: int) -> bool:
         """The copy held of `of_epoch` is about to be pulled (the driver
@@ -968,25 +1039,36 @@ class TrainingOperator:
         epoch that runs beside them, on the actor's other lane, touches
         neither the area nor the held copy). Who keeps what he gets
         calls `state_dict` (or copies)."""
+        self._usable = usable   # the cut `_held_part` holds pieces of
         if of_epoch is not None:
-            return self._held_piece(index, usable, drop, of_epoch)
-        return self._piece(self._state_tree(drop), index, usable)[0]
+            if drop:
+                raise ValueError("a held copy is of the whole state")
+            return self._held_piece(index, usable, of_epoch)
+        held = self._held
+        return self._piece(_snapshot.cut(self._state_tree(drop), usable),
+                           index, held["first"] if held else 0)[0]
 
-    def _piece(self, tree: dict, index: int, usable: int) -> tuple:
-        """Piece `index` of `tree` (the live state's, or the held
-        copy's), brought to the host; and whether it is the last."""
-        from ray_tpu.train import snapshot as _snapshot
+    def _piece(self, whole, index: int, held_from: int = 0) -> tuple:
+        """Piece `index` of the cut `whole` (the live state's, or the
+        held copy's), brought to the host; and whether it is the last.
+        A piece that begins at leaf `held_from` or after (0: none is
+        held apart) is read from the held copy, not from this one: its
+        transfers are not started ahead."""
+        def ahead(i):
+            if i >= len(whole.ranges) or (
+                    held_from and whole.ranges[i][0] >= held_from):
+                return []
+            return whole.part(i)
 
         counts = dict(_d2h_counts(), piece=index)
         ctx = _tracing.child_of_current()
         with _tracing.span("train.snapshot.d2h", ctx, counts):
-            whole = _snapshot.cut(tree, usable)
             part = self._to_host(
                 whole.part(index), counts, ctx, room=_stage_room(whole),
-                ahead=whole.part(index + 1) + whole.part(index + 2))
+                ahead=ahead(index + 1) + ahead(index + 2))
         return whole.reply(index, part), index >= len(whole.ranges) - 1
 
-    def _held_piece(self, index, usable, drop, of_epoch) -> dict:
+    def _held_piece(self, index, usable, of_epoch) -> dict:
         """`state_piece` of the held copy: the same cut, the same
         transfers, the same span; the pull is open from its first piece
         to its last (or to one that raises), and `_hold` does not touch
@@ -1000,9 +1082,7 @@ class TrainingOperator:
             self._pull_open = True
         last = True
         try:
-            reply, last = self._piece(
-                {k: v for k, v in held.items() if k not in drop},
-                index, usable)
+            reply, last = self._piece(_held_cut(held, index, usable), index)
             return reply
         finally:
             if last:
@@ -1101,6 +1181,13 @@ def _union_s(intervals: list) -> float:
     return covered
 
 
+def _shard_bytes(x) -> int:
+    """The bytes of the leaf `x` on one of the devices that hold it."""
+    if not isinstance(x, jax.Array):
+        return 0
+    return int(np.prod(x.sharding.shard_shape(x.shape))) * x.dtype.itemsize
+
+
 def _is_joined(x) -> bool:
     """A leaf whose host copy is put together from several shards (one
     device, or every device holding it whole: nothing to join)."""
@@ -1122,6 +1209,20 @@ def _start_transfers(leaves: list, counts: dict):
 
 def _padded(nbytes: int) -> int:
     return -(-nbytes // _STAGE_ALIGN) * _STAGE_ALIGN
+
+
+def _held_cut(held: dict, index: int, usable: int):
+    """The whole state's cut with the held copy's leaves in their places
+    (`TrainingOperator._hold`: the leaves before `first` are not held,
+    and a piece of them is an error, never the live state's bytes)."""
+    first, sizes = held["first"], held["sizes"]
+    ranges = _snapshot.plan(sizes, usable)
+    if index < len(ranges) and ranges[index][0] < first:
+        raise ValueError(
+            f"piece {index} of the state of epoch {held['epoch']} is not "
+            f"held: the copy begins at leaf {first}")
+    return _snapshot.Cut([None] * first + held["leaves"], held["treedef"],
+                         sizes, ranges)
 
 
 def _stage_room(whole) -> int:

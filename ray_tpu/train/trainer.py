@@ -87,12 +87,65 @@ def _entries(log) -> list[dict]:
 class _Pending(NamedTuple):
     """A `train()` call whose state the worker holds and nobody has
     pulled yet: the call's number, the worker's epoch after it (which
-    copy `state_piece(of_epoch=)` reads) and the steps it ran (what the
-    elastic restore runs again if the copy dies with its worker)."""
+    copy `state_piece(of_epoch=)` reads), the steps it ran (what the
+    elastic restore runs again if the copy dies with its worker) and,
+    where the worker holds a PART of the state, the pull that has
+    brought the rest home already (a `_Pull`; None: the whole is held,
+    and nothing has crossed)."""
 
     call: int
     epoch: int
     num_steps: int | None
+    pull: "_Pull | None" = None
+
+
+class _Pull:
+    """One state on its way from a worker into the buffer set written
+    longest ago, in one part or in two (`Trainer._pull_state`): the
+    plan piece 0 brought, the leaves that have landed, and how much of
+    the set's reservation was taken before the first did."""
+
+    def __init__(self, spare: "_BufferSet"):
+        self.spare, self.reserve = spare, spare.reserve
+        self.taken = self.reserve.taken if self.reserve is not None else 0
+        self.treedef, self.ranges, self.sizes = None, (), ()
+        self.spares, self.leaves = [], []
+
+    def plan(self, piece: dict) -> None:
+        """Piece 0's reply: the state's tree and its cut."""
+        import jax
+
+        self.treedef, self.ranges = piece["treedef"], piece["ranges"]
+        self.sizes = piece["bytes"]
+        self.spares, spare_def = jax.tree.flatten(self.spare.state)
+        if spare_def != self.treedef:
+            self.spares = []    # a changed tree: every leaf is new
+
+    @property
+    def pieces(self) -> int:
+        """Pieces that have landed, in order from 0."""
+        return sum(1 for _, stop in self.ranges if stop <= len(self.leaves))
+
+    @property
+    def whole(self) -> bool:
+        return self.treedef is not None and self.pieces == len(self.ranges)
+
+    def stop(self, leaf: int | None) -> int:
+        """The piece that begins at `leaf` (None, or no piece does: one
+        past the last)."""
+        return next((i for i, (first, _) in enumerate(self.ranges)
+                     if first == leaf), len(self.ranges))
+
+    def state(self) -> dict:
+        import jax
+
+        return jax.tree.unflatten(self.treedef, self.leaves)
+
+    def drop(self) -> None:
+        """Nothing of this pull is installed: what it took of the
+        reservation is nobody's."""
+        if self.reserve is not None:
+            self.reserve.taken = self.taken
 
 
 # A buffer set's bytes are made resident a chunk at a time, each chunk
@@ -546,7 +599,19 @@ class Trainer:
     first, `load_state_dict()` / `load()` drop it. A pull that raises
     installs nothing, deferred or not. The very first call, and any
     call that finds the installed snapshot more than a call behind
-    (after a restore), pulls at once."""
+    (after a restore), pulls at once.
+
+    Where the devices have room for a PART of the state (the rule
+    answers in bytes: `TrainingOperator._held_part`), the worker holds
+    the pieces at the tail of the state's cut that fit; `train()` pulls
+    the pieces before them from the live state at once, into the buffer
+    set written longest ago, installs nothing, and returns; the next
+    call pulls the held pieces beside its epoch and installs the state
+    when its last piece has landed. The guarantee is the holding
+    worker's, no weaker: the installed snapshot is always whole and at
+    most one call older than without; a worker lost before the held
+    pieces have landed takes them with it, what had crossed at once is
+    dropped, and the restore runs up to two calls again."""
 
     def __init__(self, training_operator_cls, *, num_workers: int = 1,
                  config: dict | None = None,
@@ -867,7 +932,7 @@ class Trainer:
             # once it has run: a worker lost in it is restored again).
             ray_tpu.get([w.train_epoch.remote(self._pending.num_steps)
                          for w in self.workers], timeout=600)
-            self._pending = None
+            self._forget_pending()
         return pushed
 
     def _kill_workers(self):
@@ -1036,14 +1101,22 @@ class Trainer:
                         "train_epoch", num_steps, counts)
             else:
                 results = self._epoch_beside_pull(num_steps)
-            # a worker that holds a copy of this call's state says so
-            # (one worker that owns its whole state: operator._hold)
+            # a worker that holds a copy of this call's state says so,
+            # and from which leaf on (one worker that owns its whole
+            # state: operator._hold; leaf 0: all of it)
             held = [r.pop("held_epoch", None) for r in results]
+            held_from = [r.pop("held_from", 0) for r in results]
             if (len(held) == 1 and held[0] is not None
                     and self._snapshot_of == self._calls - 1):
                 # ... and the installed snapshot is the last call's:
-                # this call's is pulled beside the next epoch
-                self._pending = _Pending(self._calls, held[0], num_steps)
+                # what is held of this call's is pulled beside the next
+                # epoch, the pieces before it (a part is held) now
+                part = held_from[0]
+                pull = self._snapshot(self._calls,
+                                      held_from=part) if part else None
+                if pull is not None or not part:    # else: it is whole
+                    self._pending = _Pending(self._calls, held[0],
+                                             num_steps, pull)
             else:
                 self._snapshot(self._calls)
             if self._calls == 2:
@@ -1061,11 +1134,17 @@ class Trainer:
                     pass
         return _reduce(results) if reduce_results else results
 
-    def _snapshot(self, of_call: int, of_epoch: int | None = None):
+    def _snapshot(self, of_call: int, of_epoch: int | None = None,
+                  pull: "_Pull | None" = None,
+                  held_from: int | None = None) -> "_Pull | None":
         """Pull the state `of_call` left and install it: the worker's
         live state, or with `of_epoch` the copy it holds since that
         epoch's end (`deferred` on the span: beside this call's epoch,
-        or a drain)."""
+        or a drain). Where the worker holds a PART of the state (from
+        the leaf `held_from` on) the call pulls the pieces before it
+        from the live state and returns the `_Pull`, nothing installed;
+        the next hands it back with `of_epoch` for the held pieces.
+        None once the state is whole and installed."""
         counts = {"deferred": int(of_epoch is not None), "of_call": of_call}
         with tracing.span("train.snapshot", tracing.child_of_current(),
                           counts, ambient=True):
@@ -1078,35 +1157,38 @@ class Trainer:
             # parts are installed, and the sets turned, only once
             # both are whole: a copy that raises changes nothing.
             newer, older = self._owned
-            reserve = older.reserve
-            taken = reserve.taken if reserve is not None else 0
-            # A pull does not start before its set is whole: the
-            # threads that write it and the worker's chain into pages
-            # of its own slow each other by more than either takes
-            # (set 1 beside the second call's pull: 6.4-7.0 s for the
-            # 1.8 s of writing, 5.0-5.5 s for the 2.7 s of pull).
-            waited = reserve.wait_for() if reserve is not None else 0.0
+            waited = 0.0
+            if pull is None:
+                pull = _Pull(older)
+                # A pull does not start before its set is whole: the
+                # threads that write it and the worker's chain into
+                # pages of its own slow each other by more than either
+                # takes (set 1 beside the second call's pull: 6.4-7.0 s
+                # for the 1.8 s of writing, 5.0-5.5 s for the 2.7 s of
+                # pull).
+                if pull.reserve is not None:
+                    waited = pull.reserve.wait_for()
+            reserve = pull.reserve
             try:
                 # sharded: the epoch-boundary snapshot is params (rank
                 # 0; identical everywhere) + ALL optimizer shards — the
                 # reshardable unit the elastic restore path consumes.
                 # Rank 0's own shard is never kept, so it stays where
                 # it is and the tree matches the spare's.
-                state = self._pull_state(
-                    self.workers[0], older.state, counts,
+                self._pull_state(
+                    self.workers[0], pull, counts,
                     drop=("opt_shard",) if self._sharded else (),
-                    writes=older.writes, of_epoch=of_epoch,
-                    reserve=reserve, waited=waited)
-                shards = None
+                    of_epoch=of_epoch, waited=waited, held_from=held_from)
+                if not pull.whole:
+                    return pull
+                state, shards = pull.state(), None
                 if self._sharded:
                     shards = _own(ray_tpu.get(
                         [w.opt_shard_state.remote() for w in self.workers],
                         timeout=120), older.shards, older.writes,
                         reserve=reserve)
             except BaseException:
-                # what this pull took of the reservation is nobody's
-                if reserve is not None:
-                    reserve.taken = taken
+                pull.drop()
                 raise
             self._last_state, self._last_shards = state, shards
             self._snapshot_of = of_call
@@ -1122,12 +1204,14 @@ class Trainer:
             self._owned = (_BufferSet(
                 state, shards, older.writes + 1 if reused else 1, reserve),
                 newer)
+        return None
 
     def _epoch_beside_pull(self, num_steps) -> list:
         """A call that finds the last call's state held: the epoch is
-        submitted first, the held copy pulled and installed while it
-        runs (the pieces on a lane of their own: `TrainWorker.task_lane`),
-        then the epoch waited for. A
+        submitted first, the held copy (the whole state, or the pieces
+        the last call did not pull at once) pulled and installed while
+        it runs (the pieces on a lane of their own:
+        `TrainWorker.task_lane`), then the epoch waited for. A
         worker lost under the pull takes the copy with it: the epoch's
         own retries restore the group, and the restore runs the lost
         call again (`_restore_state`). Any other failure of the pull
@@ -1139,31 +1223,56 @@ class Trainer:
             refs = [w.train_epoch.remote(num_steps, pull_of=pending.epoch)
                     for w in self.workers]
         try:
-            self._snapshot(pending.call, pending.epoch)
-            self._pending = None
-        except BaseException as e:
-            for w in self.workers:  # the epoch's end waits for the pull
-                try:
-                    w.end_pull.remote()
-                except exc.RayTpuError:
-                    pass
-            lost = isinstance(e, (exc.ActorDiedError, exc.WorkerCrashedError)
-                              ) or (isinstance(e, exc.RayTpuError)
-                                    and self._gang_interrupted()[0])
-            if not lost:
-                self._pending = None
+            self._pull_pending()
+        except BaseException:
+            if self._pending is None:   # not the worker's loss
                 raise
         with tracing.span("train.epoch", ctx, counts, ambient=True,
                           start=start):
             return self._run_with_retries("train_epoch", num_steps, counts,
                                           refs=refs)
 
+    def _pull_pending(self):
+        """Pull what the worker holds of the last call's state and
+        install the state: afterwards nothing is pending. A pull that
+        raises installs nothing and the worker is told to let go of the
+        copy (an epoch's end waits for the pull); what the call had
+        pulled at once went with it. If it raised because the worker is
+        lost, the call stays pending for the restore to run again
+        (`_restore_state`); after any other failure the next call pulls
+        its own state at once."""
+        pending = self._pending
+        try:
+            self._snapshot(pending.call, pending.epoch, pending.pull)
+            self._pending = None
+        except BaseException as e:
+            for w in self.workers:
+                try:
+                    w.end_pull.remote()
+                except exc.RayTpuError:
+                    pass
+            self._pending = (pending._replace(pull=None)
+                             if self._worker_lost(e) else None)
+            raise
+
+    def _worker_lost(self, e: BaseException) -> bool:
+        """Whether a pull raised `e` because its worker is gone."""
+        return isinstance(e, (exc.ActorDiedError, exc.WorkerCrashedError)
+                          ) or (isinstance(e, exc.RayTpuError)
+                                and self._gang_interrupted()[0])
+
+    def _forget_pending(self):
+        """Nobody pulls the pending state any more (another state took
+        its place, or its worker is gone and the call has run again)."""
+        if self._pending is not None and self._pending.pull is not None:
+            self._pending.pull.drop()
+        self._pending = None
+
     def _drain(self):
-        """Pull the state the worker still holds of the last call, now:
+        """Pull what the worker still holds of the last call, now:
         afterwards the installed snapshot is that call's."""
         if self._pending is not None:
-            self._snapshot(self._pending.call, self._pending.epoch)
-            self._pending = None
+            self._pull_pending()
 
     def validate(self, num_steps: int | None = None,
                  reduce_results: bool = True):
@@ -1174,48 +1283,57 @@ class Trainer:
     # checkpointing
     # ------------------------------------------------------------------
 
-    def _pull_state(self, worker, spare=None, counts: dict | None = None,
-                    drop=(), writes: int = 0,
-                    of_epoch: int | None = None, reserve=None,
-                    waited: float = 0.0) -> dict:
+    def _pull_state(self, worker, pull: "_Pull | None" = None,
+                    counts: dict | None = None, drop=(),
+                    of_epoch: int | None = None, waited: float = 0.0,
+                    held_from: int | None = None) -> "_Pull":
         """`worker`'s training state (with `of_epoch`: the copy it holds
-        since that epoch's end), whole, in memory the driver owns.
+        since that epoch's end) into memory the driver owns: the pieces
+        `pull` has not got yet — all of them, or with `held_from` those
+        before the piece that begins at that leaf (the rest the worker
+        holds, for a later call with `of_epoch`).
         It crosses the object plane as the pieces `train/snapshot.py`
         cuts (also a state the store would hold whole): each goes
         device→host and into the arena on the worker, out through `_own`
-        into `spare`'s leaves here (a tree an earlier pull built, or
-        bytes of `reserve` where it built none: see `_own`), and is
-        released. The driver asks ahead — the actor runs
+        into the leaves of `pull.spare` here (a tree an earlier pull
+        built, or bytes of its reservation where it built none: see
+        `_own`), and is released. The driver asks ahead — the actor runs
         the `state_piece` calls in order, one at a time (on a lane of
         their own where a held copy is pulled beside an epoch:
         `TrainWorker.task_lane`), so the worker brings the next piece to
         the host while this side copies the last — as long as what is in
         the store or on its way there never exceeds what it holds.
-        Nothing of `spare` or of the result is installed here: a piece
+        Nothing of the spare or of the result is installed here: a piece
         that raises leaves the caller's snapshot as it was. Inside a
         trace the driver thread's time is tiled, a piece, by
         `train.snapshot.wait` (blocked until the worker has put the
         piece; `object.get` hangs under it) and `train.snapshot.copy`
-        (`_own`; `writes` is how often `spare`'s buffers were written
+        (`_own`; `writes` is how often the spare's buffers were written
         before)."""
-        import jax
-
         from ray_tpu.train import snapshot
 
         usable = snapshot.usable_bytes(global_state.require_core_worker())
         which = () if of_epoch is None else (of_epoch,)
-        pending = collections.deque(
-            [(0, worker.state_piece.remote(0, usable, drop, *which))])
-        asked, held, leaves, ranges, sizes = 1, 0, [], (), ()
+        pull = pull or _Pull(_BufferSet())
+        first = pull.pieces
+        # bytes asked for and not copied out yet; one past this part's
+        # last piece (a pull that begins at piece 0 learns both from it)
+        held = pull.sizes[first] if pull.ranges else 0
+        stop = pull.stop(held_from) if pull.ranges else 0
+        pending = collections.deque()
+
+        def ask(index):
+            pending.append((index, worker.state_piece.remote(
+                index, usable, drop, *which)))
+            return index + 1
 
         def ask_ahead():
             nonlocal asked, held
-            while asked < len(ranges) and held + sizes[asked] <= usable:
-                pending.append((asked, worker.state_piece.remote(
-                    asked, usable, drop, *which)))
-                held += sizes[asked]
-                asked += 1
+            while asked < stop and held + pull.sizes[asked] <= usable:
+                held += pull.sizes[asked]
+                asked = ask(asked)
 
+        asked = ask(first)
         try:
             while pending:
                 index, ref = pending.popleft()
@@ -1225,18 +1343,16 @@ class Trainer:
                     piece = ray_tpu.get(ref, timeout=120)
                 del ref
                 if index == 0:
-                    treedef, ranges = piece["treedef"], piece["ranges"]
-                    sizes, held = piece["bytes"], piece["bytes"][0]
-                    spares, spare_def = jax.tree.flatten(spare)
-                    if spare_def != treedef:
-                        spares = []    # a changed tree: every leaf is new
+                    pull.plan(piece)
+                    held, stop = pull.sizes[0], pull.stop(held_from)
                 ask_ahead()                 # what fits beside this piece
-                first, stop = ranges[index]
-                leaves.extend(_own(piece["leaves"], spares[first:stop],
-                                   writes, piece=index, reserve=reserve,
-                                   waited=waited if index == 0 else 0.0))
+                a, b = pull.ranges[index]
+                pull.leaves.extend(_own(
+                    piece["leaves"], pull.spares[a:b], pull.spare.writes,
+                    piece=index, reserve=pull.reserve,
+                    waited=waited if index == first else 0.0))
                 piece = None                # the views die here
-                held -= sizes[index]
+                held -= pull.sizes[index]
                 ask_ahead()                 # ... and what fits without it
         except BaseException as e:
             # whoever keeps the exception keeps its frames: let go of
@@ -1246,8 +1362,9 @@ class Trainer:
             traceback.clear_frames(e.__traceback__)
             raise
         if counts is not None:
-            counts.update(pieces=len(ranges), bytes=sum(sizes))
-        return jax.tree.unflatten(treedef, leaves)
+            counts.update(pieces=stop - first,
+                          bytes=sum(pull.sizes[first:stop]))
+        return pull
 
     def _push_state(self, workers: list, state: dict):
         """The other direction (`load_state_dict`, the elastic restore):
@@ -1272,12 +1389,13 @@ class Trainer:
 
     def state_dict(self) -> dict:
         self._drain()
-        return self._pull_state(self.workers[0])
+        return self._pull_state(self.workers[0]).state()
 
     def load_state_dict(self, state: dict):
         self._last_state = state
         # the workers drop a copy they hold of the state this replaces
-        self._pending, self._snapshot_of = None, self._calls
+        self._forget_pending()
+        self._snapshot_of = self._calls
         self._push_state(self.workers, state)
 
     def save(self, path: str) -> str:

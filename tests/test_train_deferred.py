@@ -1,9 +1,11 @@
 """The state pull beside the NEXT epoch (train/trainer.py
-`_epoch_beside_pull`, train/operator.py `_hold` / `_room_to_hold`,
-`TrainWorker.task_lane`). Where the worker's devices have room for a
-second copy of the state, the worker holds one at the epoch's end,
-`train()` returns without pulling, and the next call pulls the held copy
-while its own epoch runs. CPU: `memory_stats()` is None there, so the
+`_epoch_beside_pull`, train/operator.py `_hold` / `_room_to_hold` /
+`_held_part`, `TrainWorker.task_lane`). Where the worker's devices have
+room for a second copy of the state, the worker holds one at the epoch's
+end, `train()` returns without pulling, and the next call pulls the held
+copy while its own epoch runs; where they have room for a part, the
+pieces at the cut's tail that fit are held and pulled so, and only the
+pieces before them at once. CPU: `memory_stats()` is None there, so the
 room rule is reached through its one seam, `_device_memory`, which
 `Roomy` fakes from its config; everything else is the program's own
 path: two processes, the object store, the actor's lanes."""
@@ -19,10 +21,13 @@ import pytest
 
 import ray_tpu
 from jax._src import monitoring
+from benchmark import boundary_path
+from benchmark.layer_metrics import snapshot_held_share
 from benchmark.layer_metrics import snapshot_hidden_share
 from ray_tpu import exceptions as exc
 from ray_tpu.train import Trainer, TrainingOperator, call_log
 from ray_tpu.train import operator as operator_mod
+from ray_tpu.train import snapshot as snapshot_mod
 from ray_tpu.train import trainer as trainer_mod
 
 GIB = 1 << 30
@@ -73,6 +78,18 @@ class Roomy(TrainingOperator):
 ROOM = {"bytes_limit": 16 * GIB, "peak_bytes_in_use": 10 * GIB,
         "bytes_in_use": 2 * GIB, "bytes_reserved": 6 * GIB}
 FULL = dict(ROOM, peak_bytes_in_use=15 * GIB)
+MIB = 1 << 20
+STATE = 3 * MIB + 4             # Roomy's: 12 leaves of 256 KiB and a count
+
+
+def _room(nbytes):
+    """A device that has `nbytes` of room by the rule."""
+    return {"bytes_limit": 16 * GIB, "bytes_in_use": 0, "bytes_reserved": 0,
+            "peak_bytes_in_use": 15 * GIB - nbytes}
+
+
+# the second of the two pieces (six leaves) fits; not one leaf fits
+PART, SLIVER = _room(7 * MIB // 4), _room(MIB // 8)
 
 
 @pytest.fixture(scope="module")
@@ -132,6 +149,13 @@ def deferred(runtime):
     return rows, call_log()[-len(STEPS):]
 
 
+@pytest.fixture(scope="module")
+def part(runtime):
+    """A device with room for the second of the state's two pieces."""
+    rows = _run(PART)
+    return rows, call_log()[-len(STEPS):]
+
+
 # ---------------------------------------------------------------------
 # the rule
 # ---------------------------------------------------------------------
@@ -142,29 +166,40 @@ def _operator(memory, **config):
     return Roomy(config, 0, 1)
 
 
-@pytest.mark.parametrize("memory, holds", [
-    (ROOM, True),
-    (FULL, False),                              # the peak leaves no room
-    (dict(ROOM, bytes_in_use=9 * GIB), False),  # live + reserved does
+@pytest.mark.parametrize("memory, room", [
+    (ROOM, 5 * GIB),
+    (FULL, 0),                                  # the peak leaves no room
+    (dict(ROOM, bytes_in_use=9 * GIB), 0),      # live + reserved does
     # the margin is a share of the device, whatever the state's size
-    (dict(ROOM, peak_bytes_in_use=15 * GIB - 2 * (1 << 20),
-          bytes_in_use=0, bytes_reserved=0), False),
+    (dict(ROOM, peak_bytes_in_use=15 * GIB - 2 * MIB,
+          bytes_in_use=0, bytes_reserved=0), 2 * MIB),
     (dict(ROOM, peak_bytes_in_use=14 * GIB, bytes_in_use=0,
-          bytes_reserved=0), True),
-    (None, False),                              # the CPU: no count kept
-    ({}, False),
-    ({"bytes_limit": 16 * GIB}, False),         # ... or half a count
-    ({"peak_bytes_in_use": GIB}, False),
-    ("absent", False),                          # this backend's own
+          bytes_reserved=0), GIB),
+    (_room(STATE), STATE),                      # to the byte
+    (_room(STATE - 1), STATE - 1),
+    (PART, 7 * MIB // 4),
+    (SLIVER, MIB // 8),
+    (None, 0),                                  # the CPU: no count kept
+    ({}, 0),
+    ({"bytes_limit": 16 * GIB}, 0),             # ... or half a count
+    ({"peak_bytes_in_use": GIB}, 0),
+    ("absent", 0),                              # this backend's own
 ])
-def test_the_rule_reads_the_devices_memory_and_nothing_else(memory, holds):
+def test_the_rule_reads_the_devices_memory_and_nothing_else(memory, room):
+    """The rule answers in bytes; a first epoch ends holding the whole
+    state where that fits, and nothing where it does not: which pieces
+    a part is made of nobody knows before the state has crossed once."""
     op = _operator(memory)
-    assert op._room_to_hold() is holds
+    assert op._room_to_hold() == room
+    assert type(op._room_to_hold()) is int
+    holds = room >= STATE
     out = op.train_epoch(num_steps=1)
+    assert op._room == room
     assert op.holds_state is holds
-    assert ("held_epoch" in out) is holds
+    assert ("held_epoch" in out) is ("held_from" in out) is holds
     if holds:
-        assert out["held_epoch"] == op.epoch == 1
+        assert out["held_epoch"] == op.epoch == 1 and out["held_from"] == 0
+        assert op._held_part() == (0, STATE)
 
 
 def test_the_rule_is_read_once_after_the_first_epoch(monkeypatch):
@@ -173,10 +208,76 @@ def test_the_rule_is_read_once_after_the_first_epoch(monkeypatch):
     real = Roomy._device_memory
     monkeypatch.setattr(Roomy, "_device_memory",
                         lambda self: reads.append(1) or real(self))
-    assert op._holds is None and not op.holds_state     # nothing yet
+    assert op._room is None and not op.holds_state      # nothing yet
     for _ in range(3):
         op.train_epoch(num_steps=1)
-    assert len(reads) == 1 and op._holds is True
+    assert len(reads) == 1 and op._room == 5 * GIB
+
+
+def _cut(op, usable):
+    leaves = jax.tree.leaves(op._state_tree())
+    sizes = [snapshot_mod.leaf_bytes(x) for x in leaves]
+    return sizes, snapshot_mod.plan(sizes, usable)
+
+
+@pytest.mark.parametrize("usable, room, pieces, held", [
+    # a piece a leaf (12 of 256 KiB behind the first: the count's)
+    (MIB, 0, 13, 0),
+    (MIB, MIB // 4 - 1, 13, 0),             # not one piece fits
+    (MIB, MIB // 4, 13, 1),                 # to the byte
+    (MIB, MIB, 13, 4),
+    (MIB, STATE - 1, 13, 12),               # all but the first
+    (MIB, STATE, 13, 13),                   # ... and the whole
+    # six leaves a piece, as through the tests' 8 MiB store
+    (8 * MIB * 4 // 5, 3 * MIB // 2 - 1, 2, 0),
+    (8 * MIB * 4 // 5, 3 * MIB // 2, 2, 1),
+    (8 * MIB * 4 // 5, STATE - 1, 2, 1),    # whole pieces only
+    (8 * MIB * 4 // 5, STATE, 2, 2),
+    (64 * MIB, STATE - 1, 1, 0),            # one piece: all or nothing
+])
+def test_the_held_part_is_whole_pieces_at_the_tail_of_the_cut(
+        usable, room, pieces, held):
+    op = _operator(_room(room))
+    op.train_epoch(num_steps=1)
+    if room < STATE:
+        assert not op.holds_state           # the cut is not known yet
+    list(map(np.asarray, op.state_piece(0, usable)["leaves"]))
+    sizes, ranges = _cut(op, usable)
+    assert len(ranges) == pieces and sum(sizes) == STATE
+    out = op.train_epoch(num_steps=1)
+    if not held:
+        assert op._held_part() is None and not op.holds_state
+        assert "held_epoch" not in out and "held_from" not in out
+        assert [k for k in op._step_cache if k[0] == "hold"] == []
+        return
+    first = ranges[pieces - held][0]
+    assert out["held_epoch"] == 2 and out["held_from"] == first
+    assert op._held_part() == (first, sum(sizes[first:]))
+    assert sum(sizes[first:]) <= room
+    if held < pieces:       # one piece more would not have fitted
+        assert sum(sizes[ranges[pieces - held - 1][0]:]) > room
+    kept = op._held
+    assert kept["first"] == first and kept["sizes"] == sizes
+    assert len(kept["leaves"]) == len(sizes) - first
+    # one program, of the held leaves only: the whole state's where all
+    # of it is held, else one named by where the part begins
+    assert [k for k in op._step_cache if k[0] == "hold"] == [
+        ("hold", f"from{first}" if first else "state")]
+    live = _bits(op.state_dict())
+    op.train_batch(np.ones((4, 256), np.float32))   # the next epoch
+    got = []
+    for index in range(pieces - held, pieces):
+        assert op.holds_state
+        got += op.state_piece(index, usable, (), 2)["leaves"]
+    assert not op.holds_state               # read to its last piece: gone
+    assert _bits(got) == live[first:] != _bits(op.state_dict())[first:]
+    if held < pieces:
+        # a piece before the part is an error, never the live state's
+        # bytes (and, as any piece that raises, the end of the pull)
+        op.train_epoch(num_steps=1)
+        with pytest.raises(ValueError, match="is not held"):
+            op.state_piece(pieces - held - 1, usable, (), 3)
+        assert not op.holds_state and not op._pull_open
 
 
 @pytest.mark.parametrize("why", ["several workers", "sharded update"])
@@ -186,7 +287,8 @@ def test_an_operator_that_does_not_own_its_whole_state_never_holds(
         op = Roomy({"memory": ROOM}, 0, 2, group_name="none")
     else:
         op = Roomy({"memory": ROOM, "sharded_update": True}, 0, 1)
-    assert op._room_to_hold() is False
+    assert op._room_to_hold() == 0
+    assert op._hold() is False and not op.holds_state
 
 
 def test_the_state_is_measured_on_its_fullest_device(monkeypatch):
@@ -202,10 +304,13 @@ def test_the_state_is_measured_on_its_fullest_device(monkeypatch):
     tight = {"bytes_limit": limit, "bytes_in_use": 0, "bytes_reserved": 0,
              "peak_bytes_in_use": limit - limit // 16 - quarter}
     op.config["memory"] = tight
-    assert op._room_to_hold() is True
+    op._room = op._room_to_hold()
+    assert op._room == quarter
+    assert op._held_part() == (0, op._layout_facts()["state_bytes"])
     op.config["memory"] = dict(tight, peak_bytes_in_use=tight[
         "peak_bytes_in_use"] + 1)
-    assert op._room_to_hold() is False
+    op._room = op._room_to_hold()
+    assert op._room == quarter - 1 and op._held_part() is None
 
 
 # ---------------------------------------------------------------------
@@ -217,10 +322,11 @@ def test_the_held_copy_outlives_the_steps_that_donate_the_live_state():
     op.train_epoch(num_steps=2)
     held = op._held
     before = _bits(op.state_dict())
-    assert held["epoch"] == 1 and held["global_step"] == 2
+    assert held["epoch"] == 1 and held["first"] == 0
+    assert held["leaves"][:2] == [1, 2]     # the epoch, the global step
     live = jax.tree.leaves((op.params, op.opt_state))
-    mine = jax.tree.leaves((held["params"], held["opt_state"]))
-    assert not {id(x) for x in live} & {id(x) for x in mine}
+    assert len(held["leaves"]) == 2 + len(live)
+    assert not {id(x) for x in live} & {id(x) for x in held["leaves"]}
     for _ in range(2):      # the next epoch's steps, under way
         op.train_batch(np.ones((4, 256), np.float32))
     usable = 8 << 20
@@ -372,11 +478,14 @@ def test_with_no_room_the_step_is_the_parents_and_no_program_is_added():
     batch = np.ones((4, 256), np.float32)
     _built(FULL)                    # this process's first: jax's own too
     # (built from one line: a program's text carries its call sites)
-    (plain, built_plain), (holder, built_holder) = [
-        _built(memory) for memory in (FULL, ROOM)]
+    (plain, built_plain), (holder, built_holder), (sliver, built_sliver) = [
+        _built(memory) for memory in (FULL, ROOM, SLIVER)]
     assert list(plain._step_cache) == [("fused", "4x256")]
     assert plain._held is None and not plain.holds_state
     assert built_plain[1] == 0      # nothing in a second epoch
+    # room for less than a piece is no room: the same, to the program
+    assert list(sliver._step_cache) == [("fused", "4x256")]
+    assert sliver._held is None and built_sliver == built_plain
     assert sorted(holder._step_cache) == [("fused", "4x256"),
                                           ("hold", "state")]
     assert built_holder == [built_plain[0] + 1, 0]
@@ -392,7 +501,7 @@ def test_with_no_room_the_step_is_the_parents_and_no_program_is_added():
     assert holder._step_cache[("hold", "state")].donate_argnums == ()
 
 
-@pytest.mark.parametrize("memory", ["absent", None, FULL])
+@pytest.mark.parametrize("memory", ["absent", None, FULL, SLIVER])
 def test_with_no_room_a_call_has_exactly_the_parents_spans(runtime, memory):
     tr = _trainer(memory)
     try:
@@ -419,9 +528,10 @@ def test_with_no_room_a_call_has_exactly_the_parents_spans(runtime, memory):
 # with room: the same snapshots, a call later
 # ---------------------------------------------------------------------
 
-def test_deferred_and_immediate_pulls_give_the_same_snapshots(immediate,
-                                                              deferred):
-    (rows, final), _ = deferred
+@pytest.mark.parametrize("held", ["deferred", "part"])
+def test_deferred_and_immediate_pulls_give_the_same_snapshots(
+        immediate, held, request):
+    (rows, final), _ = request.getfixturevalue(held)
     plain, plain_final = immediate
     assert [r[0] for r in rows] == [r[0] for r in plain] == [1, 2, 3, 4, 5, 6]
     by_epoch = {epoch: bits for _, epoch, bits in plain}
@@ -450,7 +560,8 @@ def test_a_deferred_call_pulls_the_last_calls_state_beside_its_epoch(
         assert snap["deferred"] == 1 and snap["of_call"] == call - 1
         assert snap["pieces"] == 2
         (hold,) = _attrs(entry, "train.hold")
-        assert hold["epoch"] == call and hold["bytes"] == snap["bytes"]
+        assert hold["epoch"] == call
+        assert hold["held_bytes"] == hold["bytes"] == snap["bytes"]
         # the epoch's task and the pieces' overlap: two lanes
         by = {s["span"]: s for s in entry["spans"]}
         epoch_task, = [s for s in entry["spans"] if s["name"] == "task"
@@ -471,10 +582,58 @@ def test_a_deferred_call_pulls_the_last_calls_state_beside_its_epoch(
                    if s["name"] != "train.call")
 
 
-def test_the_installed_snapshot_during_a_call_and_after_it(runtime,
-                                                           monkeypatch):
+def test_a_part_held_call_pulls_the_rest_at_once_and_the_part_beside_the_next(
+        part):
+    _, entries = part
+    first, second, *later = entries
+    # call 1: everything at once, nothing held (the cut is not known)
+    assert _names(first) - FIRST_CALL_ONLY == PARENT_SPANS
+    (snap,) = _attrs(first, "train.snapshot")
+    assert (snap["deferred"], snap["pieces"], snap["bytes"]) == (0, 2, STATE)
+    tail = 3 * MIB // 2         # the second piece: six leaves
+    for call, entry in enumerate([second] + later, 2):
+        (hold,) = _attrs(entry, "train.hold")
+        assert (hold["epoch"], hold["bytes"], hold["held_bytes"]) == (
+            call, STATE, tail)
+        *beside, now = _attrs(entry, "train.snapshot")
+        # this call's state: the first piece from the live state, at
+        # once; nothing installed by it
+        assert (now["deferred"], now["of_call"], now["pieces"],
+                now["bytes"]) == (0, call, 1, STATE - tail)
+        if call == 2:
+            assert beside == []
+            continue
+        # ... and the last call's: the held piece, beside the epoch
+        (then,) = beside
+        assert (then["deferred"], then["of_call"], then["pieces"],
+                then["bytes"]) == (1, call - 1, 1, tail)
+        assert _names(entry) == PARENT_SPANS | {"train.hold"}
+        epoch_task, = [s for s in entry["spans"] if s["name"] == "task"
+                       and s["attrs"]["name"] == "TrainWorker.train_epoch"]
+        pieces = [s for s in entry["spans"] if s["name"] == "task"
+                  and s["attrs"]["name"] == "TrainWorker.state_piece"]
+        assert len(pieces) == 2
+        assert pieces[0]["start"] < epoch_task["end"] <= pieces[1]["start"]
+        d2h = sorted(_attrs(entry, "train.snapshot.d2h"),
+                     key=lambda a: a["piece"])
+        assert [(a["piece"], a["bytes"]) for a in d2h] == [
+            (0, STATE - tail), (1, tail)]
+        # the benchmark's tiling of the driver's thread still reads a
+        # row a piece: the two parts' pieces carry different indexes
+        # (the held piece's wait lies beside the epoch, not behind it)
+        path = boundary_path.call_path(entry)
+        assert path["pieces"] == 2 and path["boundary_s"] > 0
+        by = {s["span"]: s for s in entry["spans"]}
+        assert all(s["parent"] in by for s in entry["spans"]
+                   if s["name"] != "train.call")
+
+
+@pytest.mark.parametrize("memory", [ROOM, PART], ids=["whole", "part"])
+def test_the_installed_snapshot_during_a_call_and_after_it(
+        runtime, monkeypatch, memory):
     """While call k + 1 runs, the installed snapshot is call k - 1's
-    until the pull of call k's lands; call k's after it."""
+    until the pull of call k's lands; call k's after it — also where a
+    call has pulled a part of its state at once: that installs nothing."""
     seen = []
     real = Trainer._pull_state
 
@@ -483,23 +642,35 @@ def test_the_installed_snapshot_during_a_call_and_after_it(runtime,
                      self._snapshot_of, kw.get("of_epoch")))
         return real(self, *a, **kw)
 
-    tr = _trainer(ROOM)
+    tr = _trainer(memory)
     try:
         tr.train(num_steps=1)
         monkeypatch.setattr(Trainer, "_pull_state", pull)
         for call in (2, 3, 4):
             tr.train(num_steps=1)
             assert tr._last_state["epoch"] == tr._snapshot_of == call - 1
-            assert tr._pending == trainer_mod._Pending(call, call, 1)
+            assert tr._pending[:3] == (call, call, 1)
+            if memory is ROOM:
+                assert tr._pending.pull is None
+            else:       # what crossed at once waits in the older set
+                assert tr._pending.pull.pieces == 1
+                assert not tr._pending.pull.whole
+                assert tr._pending.pull.spare is tr._owned[1]
     finally:
         tr.shutdown(force=True)
     # (call, installed epoch, installed call, copy asked for) at the pull
-    assert seen == [(3, 1, 1, 2), (4, 2, 2, 3)]
+    assert [s for s in seen if s[3] is not None] == [(3, 1, 1, 2),
+                                                     (4, 2, 2, 3)]
+    assert [s for s in seen if s[3] is None] == (
+        [] if memory is ROOM else [(2, 1, 1, None), (3, 2, 2, None),
+                                   (4, 3, 3, None)])
 
 
+@pytest.mark.parametrize("memory", [ROOM, PART], ids=["whole", "part"])
 @pytest.mark.parametrize("how", ["state_dict", "save", "shutdown"])
-def test_what_a_caller_asks_for_is_never_stale(runtime, tmp_path, how):
-    tr = _trainer(ROOM)
+def test_what_a_caller_asks_for_is_never_stale(runtime, tmp_path, how,
+                                               memory):
+    tr = _trainer(memory)
     try:
         for _ in range(3):
             tr.train(num_steps=1)
@@ -522,25 +693,35 @@ def test_what_a_caller_asks_for_is_never_stale(runtime, tmp_path, how):
         tr.shutdown(force=True)
 
 
-def test_loading_a_state_drops_the_pending_pull(runtime):
-    tr = _trainer(ROOM)
+@pytest.mark.parametrize("memory", [ROOM, PART], ids=["whole", "part"])
+def test_loading_a_state_drops_the_pending_pull(runtime, memory):
+    tr = _trainer(memory)
     try:
         tr.train(num_steps=1)
         first = tr.state_dict()
         tr.train(num_steps=2)
         assert tr._pending is not None
+        # a part pulled at once took its leaves' bytes of the older
+        # set's reservation, which nothing has landed in before
+        taken = tr._owned[1].reserve.taken
+        assert (taken > 0) is (memory is PART)
         tr.load_state_dict(first)
-        assert tr._pending is None
+        assert tr._pending is None and tr._owned[1].reserve.taken == 0
         out = tr.train(num_steps=2)     # no copy of epoch 2 is asked for
         assert int(out["epoch"]) == 2 and tr._last_state is first
-        assert tr._pending == trainer_mod._Pending(3, 2, 2)
+        assert tr._pending[:3] == (3, 2, 2)
         assert tr.state_dict()["global_step"] == 3
     finally:
         tr.shutdown(force=True)
 
 
-def test_a_deferred_pull_that_raises_installs_nothing(runtime, monkeypatch):
-    tr = _trainer(ROOM)
+@pytest.mark.parametrize("memory, fails_at", [(ROOM, 9), (PART, 3)],
+                         ids=["whole", "part"])
+def test_a_deferred_pull_that_raises_installs_nothing(runtime, monkeypatch,
+                                                      memory, fails_at):
+    """... in the held copy's second piece (the whole state held) or in
+    its only one (a part: the first crossed at the last call's end)."""
+    tr = _trainer(memory)
     try:
         for _ in range(3):
             tr.train(num_steps=1)
@@ -550,7 +731,7 @@ def test_a_deferred_pull_that_raises_installs_nothing(runtime, monkeypatch):
 
         def copyto(dst, src, *a, **kw):
             calls.append(dst)
-            if len(calls) == 9:     # in the second piece
+            if len(calls) == fails_at:
                 raise MemoryError("injected: a piece's copy-out failed")
             return real(dst, src, *a, **kw)
 
@@ -560,6 +741,7 @@ def test_a_deferred_pull_that_raises_installs_nothing(runtime, monkeypatch):
                 tr.train(num_steps=1)
         assert tr._last_state is before and tr._owned is owned
         assert _bits(tr._last_state) == bits and tr._snapshot_of == 2
+        assert tr._pending is None      # with what had crossed at once
         # the epoch under way ended (the worker let go of the copy) and
         # the trainer goes on: the next call finds the snapshot two
         # calls behind and pulls at once
@@ -573,15 +755,17 @@ def test_a_deferred_pull_that_raises_installs_nothing(runtime, monkeypatch):
         tr.shutdown(force=True)
 
 
+@pytest.mark.parametrize("memory", [ROOM, PART], ids=["whole", "part"])
 @pytest.mark.parametrize("kill_before", [3, 4, 6])
 def test_a_worker_killed_between_hold_and_pull_reaches_the_straight_state(
-        immediate, runtime, kill_before):
-    """The worker dies holding call k's state, unpulled: the restore
-    goes back to call k - 1's snapshot and runs call k again, with call
-    k's steps, then the call under way."""
+        immediate, runtime, kill_before, memory):
+    """The worker dies holding call k's state (or, the rest pulled at
+    once, a part of it), unpulled: the restore goes back to call k - 1's
+    snapshot and runs call k again, with call k's steps, then the call
+    under way."""
     plain, plain_final = immediate
     by_epoch = {epoch: bits for _, epoch, bits in plain}
-    rows, final = _run(ROOM, kill_before=kill_before, max_retries=2)
+    rows, final = _run(memory, kill_before=kill_before, max_retries=2)
     assert [r[0] for r in rows] == [1, 2, 3, 4, 5, 6]
     assert final == plain_final
     for _, epoch, bits in rows:
@@ -597,16 +781,17 @@ def test_a_worker_killed_between_hold_and_pull_reaches_the_straight_state(
     assert _attrs(entry, "train.epoch")[0]["attempts"] == 2
 
 
+@pytest.mark.parametrize("memory", [ROOM, PART], ids=["whole", "part"])
 def test_a_worker_lost_outside_train_runs_the_unpulled_call_again(
-        immediate, runtime):
+        immediate, runtime, memory):
     """`validate()` (any call that restores the group) finds the worker
     dead while a pull is pending: the restore runs that call again."""
     plain, _ = immediate
-    tr = _trainer(ROOM, max_retries=2)
+    tr = _trainer(memory, max_retries=2)
     try:
         for n in STEPS[:3]:
             tr.train(num_steps=n)
-        assert tr._pending == trainer_mod._Pending(3, 3, STEPS[2])
+        assert tr._pending[:3] == (3, 3, STEPS[2])
         ray_tpu.kill(tr.workers[0])
         tr._resize_worker_group()
         assert tr._pending is None and tr._snapshot_of == 2
@@ -720,7 +905,7 @@ def test_a_call_on_a_lane_runs_beside_the_actors_own_lane(runtime):
 # the benchmark's reader
 # ---------------------------------------------------------------------
 
-def _entry(epoch, snapshots, wall=None):
+def _entry(epoch, snapshots, wall=None, holds=()):
     lo, hi = epoch
     spans = [{"name": "train.call", "start": 0.0,
               "end": wall if wall is not None else hi, "span": "r",
@@ -732,7 +917,22 @@ def _entry(epoch, snapshots, wall=None):
     for i, (start, end, attrs) in enumerate(snapshots):
         spans.append({"name": "train.snapshot", "start": start, "end": end,
                       "span": f"p{i}", "parent": "r", "attrs": attrs})
+    for i, attrs in enumerate(holds):
+        spans.append({"name": "train.hold", "start": hi, "end": hi,
+                      "span": f"h{i}", "parent": "r", "attrs": attrs})
     return {"trace_id": "t", "spans": spans}
+
+
+def _window(monkeypatch, entries):
+    """The call log of a run whose window is `entries`, and its host
+    record."""
+    log = [_entry((0, 1), [], 1.0)] * 2 + entries   # first, warm, window
+    monkeypatch.setattr(trainer_mod, "_call_log", [
+        (e["trace_id"], [[s["name"], s["start"], s["end"], dict(
+            s["attrs"], sid=s["span"], psid=s["parent"])]
+            for s in e["spans"]]) for e in log])
+    return {"attempted": len(log),
+            "calls": [{"wall_s": e["spans"][0]["end"]} for e in entries]}
 
 
 @pytest.mark.parametrize("entries, share", [
@@ -750,12 +950,29 @@ def _entry(epoch, snapshots, wall=None):
 ])
 def test_snapshot_hidden_share_reads_the_deferred_pulls(monkeypatch,
                                                         entries, share):
-    log = [_entry((0, 1), [], 1.0)] * 2 + entries   # first, warm, window
-    monkeypatch.setattr(trainer_mod, "_call_log", [
-        (e["trace_id"], [[s["name"], s["start"], s["end"], dict(
-            s["attrs"], sid=s["span"], psid=s["parent"])]
-            for s in e["spans"]]) for e in log])
-    host = {"attempted": len(log),
-            "calls": [{"wall_s": e["spans"][0]["end"]} for e in entries]}
-    got = snapshot_hidden_share.read(host, None)
+    got = snapshot_hidden_share.read(_window(monkeypatch, entries), None)
+    assert got == (None if share is None else pytest.approx(share))
+
+
+def _held(*shares):
+    """A window of calls whose `train.hold` says it held `share` of
+    1000 bytes (None: the call has no such span; "?": it does not say)."""
+    return [_entry((0.0, 1.0), [], 1.0, holds=[] if share is None else [
+        {"bytes": 1000} if share == "?" else
+        {"bytes": 1000, "held_bytes": share}]) for share in shares]
+
+
+@pytest.mark.parametrize("entries, share", [
+    (_held(1000, 1000, 1000), 100.0),       # the whole state held
+    (_held(290, 290, 290, 290), 29.0),      # a part
+    (_held(None, None, None), 0.0),         # nothing held: no such span
+    # the median over the calls: one that held nothing counts 0
+    (_held(None, 300, 300), 30.0),
+    (_held(None, None, 300), 0.0),
+    # the parent's span does not say: nothing to read
+    (_held("?", "?"), None),
+    ([], None),
+])
+def test_snapshot_held_share_reads_the_holds(monkeypatch, entries, share):
+    got = snapshot_held_share.read(_window(monkeypatch, entries), None)
     assert got == (None if share is None else pytest.approx(share))
