@@ -26,11 +26,16 @@ D7). The kinds built so far:
   (2 ln theta)`, `low = max(floor(c(beta_fast)), 0)`, `high =
   min(ceil(c(beta_slow)), d - 1)`, `ramp_i = clip((i - low) / (high -
   low), 0, 1)`, and cos and sin times `rope_scale`; `_rope_for` makes
-  a table a kind. `cfg.attn_gate` adds a per-head output gate, `a_h <-
-  sigmoid(x . w_g)_h a_h` before `wo`, reading the layer's normed
-  input: a leaf `wg` `[d_model, heads]`, the product and the gate
-  float32. A configuration that names no kind and no gate keeps its
-  parameter tree, its seeded weights and its traced program.
+  a table a kind. `cfg.attn_gate` `"head"` adds a per-head output gate,
+  `a_h <- sigmoid(x . w_g)_h a_h` before `wo`, reading the layer's
+  normed input: a leaf `wg` `[d_model, heads]`, the product and the
+  gate float32. `"element"` makes that gate ELEMENTWISE instead
+  (Qwen3-Next): the query projection is doubled, `[query | gate] = x
+  W_q` split a head (`wq` `[d_model, 2 heads head_dim]`, no leaf
+  `wg`), and `a <- a * sigmoid(gate)`, a value a head DIMENSION (the
+  sigmoid and the product float32; the `attn_gate_*` counters then
+  count elements). A configuration that names no kind and no gate keeps
+  its parameter tree, its seeded weights and its traced program.
 - mixer `"conv"`: no attention at all. An input projection to three
   streams, the gated short convolution of `ops/short_conv.py` (B * x, a
   depthwise causal convolution of `conv_taps` taps, * C), an output
@@ -64,6 +69,25 @@ D7). The kinds built so far:
   an RMSNorm over each GROUP's channels of `y * silu(z)` — the gate
   before the norm — and an output projection. Its state is that
   matrix a head and `conv_taps - 1` rows, whatever the length.
+- mixer `"delta"`: Gated DeltaNet linear attention (arXiv:2412.06464,
+  as Qwen3-Next has it). ONE product `[q | k | v | z] = x W_qkvz`
+  (`delta_in`: `delta_key_heads * delta_key_dim` twice, then
+  `delta_value_heads * delta_value_dim` twice) and one `[b | a] = x
+  W_ba` (`delta_ba`, kept `[outputs, d_model]` as `ssm_in` is: twice
+  the value heads is no multiple of the 128 lanes); a depthwise causal
+  convolution of `conv_taps` taps WITHOUT bias and a SiLU over `[q | k
+  | v]` (plain `jnp`); `beta = sigmoid(b)`, `g = -exp(A_log) *
+  softplus(a + dt_bias)` a value head, float32; q and k L2-normalised a
+  head (`x * rsqrt(sum x^2 + 1e-6)`), q times `1 / sqrt(key dim)`; key
+  head j serves the value heads `j r .. (j + 1) r - 1`, `r` = value
+  heads / key heads; the gated delta rule of `ops/gated_delta.py` in
+  chunks of 64 (`S' = exp(g_t) S_{t-1}`, `S_t = S' + k_t
+  (beta_t (v_t - S'^T k_t))^T`, `o_t = S_t^T q_t`, a `[key dim, value
+  dim]` float32 state a value head, from zero at position 0); then
+  `RMSNorm(o) * w_n * silu(z)` a head — the norm BEFORE the gate, a
+  plain weight `delta_norm` at one whatever `cfg.norm_plus_one` says,
+  float32 — and an output projection `delta_out`. Its state is that
+  matrix a head and `conv_taps - 1` rows, whatever the length.
 - mixer `"none"` / MLP `"none"`: the layer is the other part alone — a
   model whose blocks are each a mixer OR a feed-forward part with one
   norm reads as such layers. It holds no leaf of the absent side (one
@@ -77,7 +101,9 @@ D7). The kinds built so far:
   step moves after the loss (`stateful_loss`) and no gradient reaches.
   `cfg.routed_scale` multiplies the routing weights; `cfg.d_shared` > 0
   adds a SHARED expert of that width, one gated MLP every token takes,
-  beside the routed sum and outside the grouped matmul's rows.
+  beside the routed sum and outside the grouped matmul's rows;
+  `cfg.shared_gate` puts a gate on it, `sigmoid(y . w_s)` a token (a
+  leaf `ws_token_gate` `[d_model]`, the product and the gate float32).
 - MLP `"dense"`: one MLP of width `d_dense`. A pattern WITHOUT an
   `"experts"` layer (a dense model through and through) names none of
   `n_experts`, `top_k`, `d_expert`, `held`, holds no router or expert
@@ -94,7 +120,11 @@ vocabulary slice that is no multiple of the 128 lanes is then nobody's
 minor dimension (the device keeps a leaf whose minor dimension is not,
 after one that is, transposed whatever shape it is given, and the step
 copies it and its moments back and forth: PERF.md section 6, PR 39).
-Norms are `ops.rmsnorm` (weight only). `cfg.sandwich` gives every layer
+Norms are `ops.rmsnorm` (weight only); under `cfg.norm_plus_one` they
+have the form `x / sqrt(mean(x^2) + eps) * (1 + w)` (the two a layer,
+the final one, q's and k's head norms; `_gain` adds the one in float32
+before any cast) and their `w` starts seeded normal(0, init_std), not at
+one. `cfg.sandwich` gives every layer
 two more (`norm1_post`, `norm2_post`): the mixer's output and the MLP's
 are each normed BEFORE the residual sum, `h + RMSNorm(part(RMSNorm(h)))`.
 
@@ -116,7 +146,11 @@ leaf the stacks are `[n_layers, ...]`. The leading layers are walked
 one by one; `lax.scan` walks whole periods with the period's layers
 unrolled inside its body, each taking its own row of each stack, so
 every layer's kind is static and the depth costs one trace of a period.
-Each block is rematerialised in the backward pass (`cfg.remat`). The
+Each block is rematerialised in the backward pass (`cfg.remat`; with
+`"parts"` a block's mixer and its MLP each under a checkpoint of its
+own, so that the step never holds both parts' residuals: the delta
+mixer's float32 convolution output and entering states beside the
+expert block's static rows were the peak of the Qwen3-Next step). The
 loss is taken in chunks of tokens, each chunk's logits recomputed in the
 backward pass: at 16 k tokens over 38 k vocabulary rows the float32
 logits alone would be 2.5 GB.
@@ -165,7 +199,9 @@ traced and a configuration's program is what it was.
 
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
 scores, the selection bias, the head norms, the exit gate, the
-attention's output gate and every norm's statistics are float32.
+attention's output gate, the shared expert's gate, the delta rule's
+decay, write strength, L2 norms, state and gated norm, and every norm's
+statistics are float32.
 """
 
 from __future__ import annotations
@@ -181,6 +217,7 @@ from jax import lax
 
 from ray_tpu.ops.attention import (diffusion_tiles, flash_attention,
                                    window_scores)
+from ray_tpu.ops.gated_delta import CHUNK as DELTA_CHUNK, gated_delta
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
@@ -188,15 +225,17 @@ from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
 
 ATTENTION_KINDS = ("full", "window")
-MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent", "ssm", "none")
+MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent", "ssm", "delta", "none")
 MLP_KINDS = ("experts", "dense", "none")
 ROUTER_INPUTS = ("mixer", "mlp")
+ATTN_GATES = ("", "head", "element")
 
 # which layers hold a leaf: those whose mixer or MLP is of its group;
 # group "layer": every layer; groups "mixer" and "mlp" (the two norms'
 # where some layer is one part alone): those that have that part
 _GROUP = {"full": "attention", "window": "attention", "conv": "conv",
-          "latent": "latent", "ssm": "ssm", "experts": "experts",
+          "latent": "latent", "ssm": "ssm", "delta": "delta",
+          "experts": "experts",
           "dense": "dense"}
 
 
@@ -248,7 +287,8 @@ class DecoderConfig:
     rms_eps: float = 1e-6
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
-    remat: bool = True
+    remat: bool | str = True          # a block under one checkpoint;
+    #                                   "parts": its mixer and its MLP each
     attn_block_q: int = 256           # flash_attention's forward tiles
     attn_block_k: int = 512
     gmm_tile: int = GMM_TILE
@@ -298,8 +338,19 @@ class DecoderConfig:
     #                                   rotary rule by attention kind; then
     #                                   n_heads, rope_theta and rotary are
     #                                   not read for those layers
-    attn_gate: bool = False           # a per-head sigmoid gate on the
-    #                                   attention's output, before wo
+    attn_gate: str = ""               # a sigmoid gate on the attention's
+    #                                   output, before wo: "head" (a leaf
+    #                                   `wg`, a value a head) or "element"
+    #                                   (the second half of each head of a
+    #                                   doubled query projection)
+    norm_plus_one: bool = False       # norms of the form (1 + w): the two a
+    #                                   layer, the final one, q's and k's
+    shared_gate: bool = False         # sigmoid(x . w) a token on the shared
+    #                                   expert's output
+    delta_key_heads: int = 0          # the delta mixer: key heads of
+    delta_value_heads: int = 0        # ... delta_key_dim, value heads of
+    delta_key_dim: int = 0            # ... delta_value_dim (a multiple of
+    delta_value_dim: int = 0          # ... the key heads); conv_taps taps
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -366,14 +417,45 @@ class DecoderConfig:
                 "outputs (n_experts), top_k, d_expert and the (first, "
                 "count) it holds; a dense pattern (no \"experts\" layer) "
                 "leaves all four out")
+        delta = (self.delta_key_heads, self.delta_value_heads,
+                 self.delta_key_dim, self.delta_value_dim)
+        if "delta" in mixers and (
+                min(delta) < 1
+                or self.delta_value_heads % self.delta_key_heads):
+            raise ValueError(
+                "the delta mixer needs delta_key_heads, delta_value_heads "
+                "(a multiple of them), delta_key_dim and delta_value_dim: "
+                f"got {delta}")
+        if isinstance(self.attn_gate, bool):    # PR 55's spelling
+            object.__setattr__(self, "attn_gate",
+                               "head" if self.attn_gate else "")
+        if self.attn_gate not in ATTN_GATES:
+            raise ValueError(
+                f"attn_gate is one of {ATTN_GATES}: got {self.attn_gate!r}")
+        if self.shared_gate and not self.d_shared:
+            raise ValueError(
+                "shared_gate is a gate on the shared expert (d_shared)")
+        if self.remat not in (True, False, "parts") or (
+                self.remat == "parts" and self.router_input == "mixer"
+                and "experts" in self.mlp + self.lead_mlp):
+            raise ValueError(
+                'remat is True (a block under one checkpoint), False or '
+                '"parts" (its mixer and its MLP each; the router then '
+                f'reads the MLP\'s norm): got {self.remat!r}')
+        if self.mtp and (self.norm_plus_one or self.shared_gate
+                         or "delta" in mixers):
+            raise ValueError(
+                "the MTP block is not built beside norm_plus_one, "
+                "shared_gate or the delta mixer")
         if self.loops < 1 or (self.loops > 1 and (
-                self.moe_layers or "ssm" in mixers or self.mtp
+                self.moe_layers or mixers & {"ssm", "delta"} or self.mtp
                 or self.diffusion_block)):
             raise ValueError(
                 f"loops is the walks of the stack, 1 or more (got "
                 f"{self.loops}); a stack walked more than once is built for "
                 "layers that count nothing (no \"experts\" MLP, no "
-                "\"ssm\" mixer), without an MTP block or block diffusion")
+                "\"ssm\" or \"delta\" mixer), without an MTP block or block "
+                "diffusion")
         if self.exit_gate and self.loops < 2:
             raise ValueError(
                 "exit_gate (the exit distribution over the walks) needs "
@@ -451,8 +533,11 @@ def _leaves(cfg: DecoderConfig) -> dict:
     count, groups = cfg.held[1], {g for pair in cfg.kinds
                                   for g in _groups_of(pair)}
     alone = any("none" in pair for pair in cfg.kinds)
-    table = {"norm1": ("mixer" if alone else "layer", (d,), "one"),
-             "norm2": ("mlp" if alone else "layer", (d,), "one")}
+    # a norm of the form (1 + w) starts near zero, seeded: at zero the
+    # comparison with the reference would not see the form
+    one = "centred" if cfg.norm_plus_one else "one"
+    table = {"norm1": ("mixer" if alone else "layer", (d,), one),
+             "norm2": ("mlp" if alone else "layer", (d,), one)}
     if cfg.sandwich:    # the norms after the mixer and after the MLP
         table.update(norm1_post=table["norm1"], norm2_post=table["norm2"])
     if "attention" in groups:
@@ -465,15 +550,18 @@ def _leaves(cfg: DecoderConfig) -> dict:
             if group not in groups:
                 continue
             heads = cfg.heads_of(group)
+            # the elementwise gate is the second half of each head of wq
+            wide = 2 if cfg.attn_gate == "element" else 1
             table.update({
-                cfg.leaf_of("wq", group): (group, (d, heads * hd), "normal"),
+                cfg.leaf_of("wq", group): (group, (d, wide * heads * hd),
+                                           "normal"),
                 cfg.leaf_of("wo", group): (group, (heads * hd, d), "normal")})
-            if cfg.attn_gate:
+            if cfg.attn_gate == "head":
                 table[cfg.leaf_of("wg", group)] = (group, (d, heads),
                                                    "normal")
         if cfg.qk_norm:
-            table.update(q_norm=("attention", (hd,), "one"),
-                         k_norm=("attention", (hd,), "one"))
+            table.update(q_norm=("attention", (hd,), one),
+                         k_norm=("attention", (hd,), one))
     if "latent" in groups:
         h, rope = cfg.n_heads, cfg.qk_rope_dim
         table.update(
@@ -503,6 +591,19 @@ def _leaves(cfg: DecoderConfig) -> dict:
             dt_bias=("ssm", (cfg.ssm_heads,), "dt_bias"),
             ssm_norm=("ssm", (inner,), "one"),
             ssm_out=("ssm", (inner, d), "normal"))
+    if "delta" in groups:
+        keys = cfg.delta_key_heads * cfg.delta_key_dim
+        values = cfg.delta_value_heads * cfg.delta_value_dim
+        table.update(
+            # [q | k | v | z]; [b | a] kept [outputs, d_model] as ssm_in
+            # is: twice the value heads is no multiple of the 128 lanes
+            delta_in=("delta", (d, 2 * keys + 2 * values), "normal"),
+            delta_ba=("delta", (2 * cfg.delta_value_heads, d), "normal"),
+            delta_conv=("delta", (cfg.conv_taps, 2 * keys + values), "taps"),
+            delta_A_log=("delta", (cfg.delta_value_heads,), "delta_A_log"),
+            delta_dt_bias=("delta", (cfg.delta_value_heads,), "one"),
+            delta_norm=("delta", (cfg.delta_value_dim,), "one"),
+            delta_out=("delta", (values, d), "normal"))
     if "experts" in groups:
         table.update(router=("experts", (d, cfg.n_experts), "normal"),
                      w_gate=("experts", (count, d, f), "normal"),
@@ -513,6 +614,8 @@ def _leaves(cfg: DecoderConfig) -> dict:
                 ws_gate=("experts", (d, cfg.d_shared), "normal"),
                 ws_up=("experts", (d, cfg.d_shared), "normal"),
                 ws_down=("experts", (cfg.d_shared, d), "normal"))
+            if cfg.shared_gate:
+                table["ws_token_gate"] = ("experts", (d,), "normal")
     if "dense" in groups:
         table.update(w1=("dense", (d, cfg.d_dense), "normal"),
                      w3=("dense", (d, cfg.d_dense), "normal"),
@@ -547,7 +650,10 @@ _LATER = ("conv_in", "conv_taps", "conv_out", "w1", "w3", "w2")
 _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "ws_down", "proj", "ssm_in", "ssm_conv", "ssm_conv_bias", "A_log",
           "dt_bias", "ssm_out", "exit_gate", "wg", "wq_full", "wo_full",
-          "wg_full", "wq_window", "wo_window", "wg_window")
+          "wg_full", "wq_window", "wo_window", "wg_window", "delta_in",
+          "delta_ba", "delta_conv", "delta_A_log", "delta_out",
+          "ws_token_gate", "norm1", "norm2", "q_norm", "k_norm", "norm_f",
+          "norm1_post", "norm2_post")
 _MTP_KEY = 1 << 16
 _NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
@@ -569,7 +675,10 @@ def init(key, cfg: DecoderConfig):
     forgets over 1 / (dt A), between a handful and a thousand
     positions); a block leaf is stacked on axis 0 over the layers that
     have it, experts on axis 1 (the held ones only); the exit gate's
-    weight normal(0, init_std) as a matrix is, its bias zero."""
+    weight normal(0, init_std) as a matrix is, its bias zero; the delta
+    mixer's `delta_A_log` the log of a draw in (0, 16], its
+    `delta_dt_bias` and `delta_norm` one; under `cfg.norm_plus_one` the
+    (1 + w) norms' w normal(0, init_std)."""
     keys = list(jax.random.split(key, 12))
     later = dict(zip(_LATER, jax.random.split(keys[10], len(_LATER))))
 
@@ -589,6 +698,9 @@ def init(key, cfg: DecoderConfig):
             return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
         if how == "A_log":
             return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1, 16))
+        if how == "delta_A_log":    # A in (0, 16]
+            return jnp.log(16.0 - jax.random.uniform(k, shape, jnp.float32,
+                                                     0, 16))
         if how == "dt_bias":
             low, high, floor = cfg.ssm_dt_range
             dt = jnp.maximum(floor, jnp.exp(jax.random.uniform(
@@ -601,7 +713,8 @@ def init(key, cfg: DecoderConfig):
         "layers": {
             name: draw(name, (_layers_with(cfg, group), *shape), how)
             for name, (group, shape, how) in _leaves(cfg).items()},
-        "norm_f": jnp.ones((cfg.d_model,)),
+        "norm_f": draw("norm_f", (cfg.d_model,),
+                       "centred" if cfg.norm_plus_one else "one"),
     }
     if not cfg.tied_head:
         shape = (cfg.d_model, cfg.vocab_size)
@@ -695,6 +808,12 @@ def _head_norm(x, weight, eps: float):
     return (xf * inv * weight).astype(x.dtype)
 
 
+def _gain(w, cfg: "DecoderConfig"):
+    """A norm's weight as it multiplies: the leaf, or under
+    `cfg.norm_plus_one` one plus the leaf (float32, before any cast)."""
+    return 1.0 + w if cfg.norm_plus_one else w
+
+
 def _deinterleave(w, lead: int, dim: int):
     """The columns of a projection whose every `lead + dim` outputs end
     in a rope part of `dim`, that part reordered (0, 2, 4, .. | 1, 3,
@@ -740,6 +859,17 @@ def _latent_attention(x, p, rope, cfg: DecoderConfig):
     return a.reshape(b, t, h * dv) @ cast(p["wo_latent"])
 
 
+def _causal_conv(x, taps):
+    """A depthwise causal convolution over time. x: [B, T, C] in the
+    compute dtype, taps: [K, C] float32 -> [B, T, C] float32: K shifted
+    products, float32 sums of the compute dtype's rows (the padded copy
+    stays in that dtype); plain `jnp`, left to XLA's fusion."""
+    k, t = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(taps[j] * padded[:, j:j + t].astype(jnp.float32)
+               for j in range(k))
+
+
 def _ssm_mixer(x, p, cfg: DecoderConfig):
     """The state-space mixer on the first norm's output x [B, T, D] ->
     (its part of the residual [B, T, D], {the most negative sum of dt A
@@ -747,16 +877,14 @@ def _ssm_mixer(x, p, cfg: DecoderConfig):
     b, t, _ = x.shape
     h, hp, gn = cfg.ssm_heads, cfg.ssm_head_dim, \
         cfg.ssm_groups * cfg.ssm_state
-    inner, k = h * hp, cfg.conv_taps
+    inner = h * hp
     cast = functools.partial(jnp.asarray, dtype=x.dtype)
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     zxbcdt = x @ cast(p["ssm_in"]).T
     z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * gn]
-    # the convolution: K shifted products, a bias, SiLU; float32 sums of
-    # the compute dtype's rows (the padded copy stays in that dtype)
-    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-    conv = sum(p["ssm_conv"][j] * f32(padded[:, j:j + t]) for j in range(k))
-    xbc = jax.nn.silu(conv + p["ssm_conv_bias"]).astype(x.dtype)
+    # the convolution, a bias, SiLU
+    xbc = jax.nn.silu(_causal_conv(xbc, p["ssm_conv"])
+                      + p["ssm_conv_bias"]).astype(x.dtype)
     dt = jax.nn.softplus(f32(zxbcdt[..., 2 * inner + 2 * gn:])
                          + p["dt_bias"])                   # [B, T, H]
     a = -jnp.exp(p["A_log"])
@@ -774,6 +902,47 @@ def _ssm_mixer(x, p, cfg: DecoderConfig):
             b, t // cfg.ssm_chunk, cfg.ssm_chunk, h).sum(2).min(),
         "ssm_dt_max": dt.max()})
     return normed @ cast(p["ssm_out"]), stats
+
+
+def _delta_mixer(x, p, cfg: DecoderConfig):
+    """The gated-delta-rule mixer on the first norm's output x [B, T, D]
+    -> (its part of the residual [B, T, D], {the least sum of the log
+    decay over a chunk, the write strengths summed}: what the counters
+    keep)."""
+    b, t, _ = x.shape
+    g_heads, h = cfg.delta_key_heads, cfg.delta_value_heads
+    dk, dv = cfg.delta_key_dim, cfg.delta_value_dim
+    keys, values = g_heads * dk, h * dv
+    cast = functools.partial(jnp.asarray, dtype=x.dtype)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    qkvz = x @ cast(p["delta_in"])
+    ba = jnp.dot(x, cast(p["delta_ba"]).T,
+                 preferred_element_type=jnp.float32)           # [B, T, 2 H]
+    # the convolution over [q | k | v], no bias, SiLU
+    qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * keys + values],
+                                   p["delta_conv"]))
+
+    def unit(z):        # L2-normalised a head, float32
+        z = z.reshape(b, t, g_heads, dk)
+        return z * lax.rsqrt((z * z).sum(-1, keepdims=True) + 1e-6)
+
+    q = unit(qkv[..., :keys]) * dk ** -0.5
+    k = unit(qkv[..., keys:2 * keys])
+    beta = jax.nn.sigmoid(ba[..., :h])
+    g = -jnp.exp(p["delta_A_log"]) * jax.nn.softplus(
+        ba[..., h:] + p["delta_dt_bias"])
+    o = gated_delta(q.astype(x.dtype), k.astype(x.dtype),
+                    qkv[..., 2 * keys:].astype(x.dtype).reshape(b, t, h, dv),
+                    g, beta)
+    # the norm BEFORE the gate, a plain weight; statistics over a head
+    z = f32(qkvz[..., 2 * keys + values:]).reshape(b, t, h, dv)
+    gated = (_head_norm(f32(o), p["delta_norm"], cfg.rms_eps)
+             * jax.nn.silu(z)).reshape(b, t, values).astype(x.dtype)
+    stats = lax.stop_gradient({
+        "delta_log_decay_min": g.reshape(
+            b, t // DELTA_CHUNK, DELTA_CHUNK, h).sum(2).min(),
+        "delta_beta_sum": beta.sum()})
+    return gated @ cast(p["delta_out"]), stats
 
 
 def _mlp(y, p, cfg: DecoderConfig, up: str, down: str, gate: str):
@@ -802,22 +971,27 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
     b, t, d = h.shape
     hd = cfg.head_dim
     cast = functools.partial(jnp.asarray, dtype=h.dtype)
+    gain = functools.partial(_gain, cfg=cfg)
     found = {}
 
     def joined(h, y, post: str):
         """The residual sum; under `cfg.sandwich` of the part's output
         normed by the layer's `post` leaf."""
         if cfg.sandwich:
-            y = rmsnorm(y, cast(p[post]), cfg.rms_eps)
+            y = rmsnorm(y, cast(gain(p[post])), cfg.rms_eps)
         return h + y
 
     if attention != "none":
-        x = rmsnorm(h, cast(p["norm1"]), cfg.rms_eps)
+        x = rmsnorm(h, cast(gain(p["norm1"])), cfg.rms_eps)
     if mlp == "experts" and cfg.router_input == "mixer":
         logits = _router(x, p)
     if attention == "ssm":
         with jax.named_scope("mixer_ssm"):
             y, stats = _ssm_mixer(x, p, cfg)
+            h, found = joined(h, y, "norm1_post"), {**found, **stats}
+    elif attention == "delta":
+        with jax.named_scope("mixer_delta"):
+            y, stats = _delta_mixer(x, p, cfg)
             h, found = joined(h, y, "norm1_post"), {**found, **stats}
     elif attention == "conv":
         with jax.named_scope("mixer_conv"):
@@ -830,12 +1004,17 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
         with jax.named_scope("attention_" + attention):
             heads = cfg.heads_of(attention)
             leaf = functools.partial(cfg.leaf_of, kind=attention)
-            q = (x @ cast(p[leaf("wq")])).reshape(b, t, heads, hd)
+            q = x @ cast(p[leaf("wq")])
+            if cfg.attn_gate == "element":  # a head's [query | gate]
+                q = q.reshape(b, t, heads, 2 * hd)
+                q, gate_in = q[..., :hd], q[..., hd:]
+            else:
+                q = q.reshape(b, t, heads, hd)
             k = (x @ cast(p["wk"])).reshape(b, t, cfg.n_kv_heads, hd)
             v = (x @ cast(p["wv"])).reshape(b, t, cfg.n_kv_heads, hd)
             if attention in cfg.qk_norm:
-                q = _head_norm(q, p["q_norm"], cfg.rms_eps)
-                k = _head_norm(k, p["k_norm"], cfg.rms_eps)
+                q = _head_norm(q, gain(p["q_norm"]), cfg.rms_eps)
+                k = _head_norm(k, gain(p["k_norm"]), cfg.rms_eps)
             if cfg.by_kind:     # the kind's own table, where it turns
                 table = rope.get(attention)
             else:
@@ -846,7 +1025,13 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
                 cfg.window if attention == "window" else None,
                 cfg.diffusion_block or None)
-            if cfg.attn_gate:
+            if cfg.attn_gate == "element":
+                # a <- a o sigmoid(gate), a value a head dimension
+                gate = jax.nn.sigmoid(gate_in.astype(jnp.float32))
+                found["attn_gate_sum_" + attention] = lax.stop_gradient(
+                    gate.sum())
+                a = (a.astype(jnp.float32) * gate).astype(a.dtype)
+            elif cfg.attn_gate == "head":
                 # a_h <- sigmoid(x . w_g)_h a_h: float32, as the router is
                 gate = jax.nn.sigmoid(jnp.dot(
                     x.astype(jnp.float32), p[leaf("wg")],
@@ -857,7 +1042,7 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
             h = joined(h, a.reshape(b, t, heads * hd) @ cast(p[leaf("wo")]),
                        "norm1_post")
     if mlp != "none":
-        y = rmsnorm(h, cast(p["norm2"]), cfg.rms_eps)
+        y = rmsnorm(h, cast(gain(p["norm2"])), cfg.rms_eps)
     if mlp == "dense":
         with jax.named_scope("mlp_dense"):
             h = joined(h, _mlp(y, p, cfg, "w3", "w2", "w1"), "norm2_post")
@@ -876,7 +1061,16 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
             with jax.named_scope("mlp_shared"):
                 # what every chip of the deployment computes alike:
                 # every token, one plain MLP, no row of the grouped matmul
-                h = h + _mlp(y, p, cfg, "ws_up", "ws_down", "ws_gate")
+                shared = _mlp(y, p, cfg, "ws_up", "ws_down", "ws_gate")
+                if cfg.shared_gate:
+                    # sigmoid(y . w) a token: float32, as the router is
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        y.astype(jnp.float32), p["ws_token_gate"],
+                        precision=lax.Precision.HIGHEST))
+                    found["shared_gate_sum"] = lax.stop_gradient(gate.sum())
+                    shared = (shared.astype(jnp.float32)
+                              * gate[..., None]).astype(shared.dtype)
+                h = h + shared
     return h, found
 
 
@@ -909,7 +1103,21 @@ def _rope_for(t: int, cfg: DecoderConfig):
 
 def _block(cfg: DecoderConfig, attention: str, mlp: str):
     fn = functools.partial(_layer, cfg=cfg, attention=attention, mlp=mlp)
-    return jax.checkpoint(fn) if cfg.remat else fn
+    if cfg.remat != "parts" or "none" in (attention, mlp):
+        return jax.checkpoint(fn) if cfg.remat else fn
+    # the mixer and the MLP each under a checkpoint of its own: one
+    # part's residuals are gone before the other's backward makes its own
+    mixer = jax.checkpoint(functools.partial(
+        _layer, cfg=cfg, attention=attention, mlp="none"))
+    rest = jax.checkpoint(functools.partial(
+        _layer, cfg=cfg, attention="none", mlp=mlp))
+
+    def parts(h, p, rope):
+        h, counted = mixer(h, p, rope)
+        h, more = rest(h, p, rope)
+        return h, {**counted, **more}
+
+    return parts
 
 
 def hidden(params, tokens, cfg: DecoderConfig, bias=None):
@@ -918,7 +1126,8 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     `bias`: the selection bias [MoE layers, n_experts], where the
     routing has one (the MTP block's row, the last, is not read here).
     With an ssm mixer the counts also hold `ssm_log_decay_min` and
-    `ssm_dt_max`, scalars over all its layers.
+    `ssm_dt_max`, scalars over all its layers; with a delta mixer
+    `delta_log_decay_min` (a scalar) and `delta_beta_sum` a layer.
 
     With `cfg.loops` = T > 1 the same layers are walked T times, the
     final norm after EVERY walk, its output the next walk's input and
@@ -988,14 +1197,17 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
         if "ssm_dt_max" in counts:      # scalars over the layers that have one
             counts.update(ssm_log_decay_min=counts["ssm_log_decay_min"].min(),
                           ssm_dt_max=counts["ssm_dt_max"].max())
+        if "delta_log_decay_min" in counts:
+            counts["delta_log_decay_min"] = \
+                counts["delta_log_decay_min"].min()
         return h, counts
 
     if cfg.loops == 1:
         return stack(h)
 
     def walk(h, _):
-        x = rmsnorm(stack(h)[0], params["norm_f"].astype(h.dtype),
-                    cfg.rms_eps)
+        x = rmsnorm(stack(h)[0],
+                    _gain(params["norm_f"], cfg).astype(h.dtype), cfg.rms_eps)
         return x, x
 
     # a scan over the walks around the scan over the periods: the weights
@@ -1019,7 +1231,7 @@ def apply(params, tokens, cfg: DecoderConfig, bias=None):
     `cfg.loops` > 1 every walk's, [loops, B, T, vocab]."""
     h, _ = hidden(params, tokens, cfg, bias)
     x = h if cfg.loops > 1 else rmsnorm(
-        h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
+        h, _gain(params["norm_f"], cfg).astype(h.dtype), cfg.rms_eps)
     return jnp.dot(x, _head(params, cfg, x.dtype),
                    preferred_element_type=jnp.float32)
 
@@ -1073,7 +1285,8 @@ def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
     h, counts = hidden(params, tokens, cfg, bias)
     if cfg.loops > 1:
         return loop_loss(h, tokens, params, cfg)
-    x = rmsnorm(h, params["norm_f"].astype(h.dtype), cfg.rms_eps)
+    x = rmsnorm(h, _gain(params["norm_f"], cfg).astype(h.dtype),
+                cfg.rms_eps)
     if not cfg.mtp:
         return _mean_nll(x, tokens, 1, params, cfg)[0], counts
     x_mtp, c = mtp_hidden(params, h, tokens, cfg, bias)
@@ -1230,7 +1443,8 @@ def diffusion_loss(params, tokens, cfg: DecoderConfig, noise_seed,
     doubled, masked, p = diffusion_inputs(tokens, cfg, noise_seed,
                                           noise_step)
     h, counts = hidden(params, doubled, cfg, bias)
-    x = rmsnorm(h[:, length:], params["norm_f"].astype(h.dtype), cfg.rms_eps)
+    x = rmsnorm(h[:, length:], _gain(params["norm_f"], cfg).astype(h.dtype),
+                cfg.rms_eps)
     weight = jnp.where(masked, 1.0 / p, 0.0)
     with jax.named_scope("logits_loss"):
         total = _nll_sum(x.reshape(b * length, -1), tokens.reshape(-1),
@@ -1292,7 +1506,15 @@ def counters_init(cfg: DecoderConfig):
     `attn_gate_sum_full` / `_window` (the gates sigmoid(x . w_g) summed
     over tokens, heads, that kind's layers and the epoch's steps) and
     `attn_gate_count_full` / `_window` (how many were summed: the
-    quotient is how open the gate stands, one half at seeded weights).
+    quotient is how open the gate stands, one half at seeded weights;
+    under the `"element"` gate both count head dimensions too).
+    With a delta mixer: `delta_log_decay_min` (the least sum of the log
+    decay g over one chunk that any value head of any layer saw in the
+    epoch: what the kernels' masked exponent has to survive),
+    `delta_beta_sum` / `delta_beta_count` (the write strengths summed
+    over tokens, value heads, delta layers and steps, and how many).
+    Under `cfg.shared_gate`: `shared_gate_sum` / `shared_gate_count`
+    (the shared expert's gates over tokens, expert layers and steps).
     The configurations from before each of these keep the state tree
     their recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
@@ -1321,7 +1543,17 @@ def counters_init(cfg: DecoderConfig):
         counters.update({f"attn_gate_{what}_{kind}": f32()
                          for kind in _attention_layers(cfg)
                          for what in ("sum", "count")})
+    if _delta_layers(cfg):
+        counters.update(delta_log_decay_min=f32(), delta_beta_sum=f32(),
+                        delta_beta_count=f32())
+    if cfg.shared_gate:
+        counters.update(shared_gate_sum=f32(), shared_gate_count=f32())
     return {"epoch_counters": counters}
+
+
+def _delta_layers(cfg: DecoderConfig) -> int:
+    """The layers whose mixer is the delta rule."""
+    return sum(a == "delta" for a, _ in cfg.kinds)
 
 
 def _attention_layers(cfg: DecoderConfig) -> dict:
@@ -1335,7 +1567,9 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     the `train.dispatch` span (`loss_fn.step_facts`, read by the
     operator): with an ssm mixer `ssm_layers` and `ssm_chunks`, the
     chunks the scan walks a step (layers x sequences x T / chunk; every
-    head walks each); under block diffusion `diffusion_block`,
+    head walks each); with a delta mixer `delta_layers`, `delta_chunks`
+    (the same product at the rule's chunk of 64) and `delta_heads` (the
+    value heads that walk each); under block diffusion `diffusion_block`,
     `diffusion_rows` (rows through the blocks a step: B x 2 L) and
     `attention_tiles_visited` / `attention_tiles_plane` (the score tiles
     the forward kernel's loops walk of one head's 2 L x 2 L plane, and
@@ -1344,7 +1578,8 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     `layer_passes` (loops x layers: the blocks a step runs forward) and
     `head_passes` (passes of the chunked head: one a walk under an exit
     gate, else one); under `cfg.by_kind` `attention_heads_full` /
-    `_window` (the kinds the pattern has), `rope_scaling` (`"yarn:64"`:
+    `_window` (the kinds the pattern has), `rope_dim` (the turned width,
+    where ONE kind is named), `rope_scaling` (`"yarn:64"`:
     the kinds' scaling rules, where one has one) and, with window
     layers, `attention_window`, `window_scores_inside` (a step's score
     entries inside causal AND window on those layers: batch x heads x
@@ -1362,6 +1597,11 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     if layers:
         facts.update(ssm_layers=layers,
                      ssm_chunks=layers * b * (t // cfg.ssm_chunk))
+    layers = _delta_layers(cfg)
+    if layers:
+        facts.update(delta_layers=layers,
+                     delta_chunks=layers * b * (t // DELTA_CHUNK),
+                     delta_heads=cfg.delta_value_heads)
     if cfg.diffusion_block:
         visited, plane = diffusion_tiles(
             2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)
@@ -1373,6 +1613,8 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
         at = _attention_layers(cfg)
         facts.update({f"attention_heads_{kind}": cfg.heads_of(kind)
                       for kind in at})
+        if len(cfg.by_kind) == 1:   # one kind: one turned width to name
+            facts["rope_dim"] = cfg.by_kind[0][1].rope_dim
         scaled = [f"yarn:{rule.yarn[0]:g}" for _, rule in cfg.by_kind
                   if rule.yarn]
         if scaled:
@@ -1482,7 +1724,22 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
                 f"attn_gate_sum_{kind}": old[f"attn_gate_sum_{kind}"]
                 + counts[f"attn_gate_sum_{kind}"].sum(),
                 f"attn_gate_count_{kind}": old[f"attn_gate_count_{kind}"]
-                + float(rows * layers * cfg.heads_of(kind))})
+                + float(rows * layers * cfg.heads_of(kind) * (
+                    cfg.head_dim if cfg.attn_gate == "element" else 1))})
+    if "delta_beta_sum" in old:
+        new.update(
+            delta_log_decay_min=jnp.minimum(old["delta_log_decay_min"],
+                                            counts["delta_log_decay_min"]),
+            delta_beta_sum=old["delta_beta_sum"]
+            + counts["delta_beta_sum"].sum(),
+            delta_beta_count=old["delta_beta_count"] + float(
+                rows * _delta_layers(cfg) * cfg.delta_value_heads))
+    if cfg.shared_gate:
+        new.update(
+            shared_gate_sum=old["shared_gate_sum"]
+            + counts["shared_gate_sum"].sum(),
+            shared_gate_count=old["shared_gate_count"] + float(
+                rows * sum(m == "experts" for _, m in cfg.kinds)))
     if "moe_rows_static" in old:
         new.update(
             moe_rows_static=old["moe_rows_static"] + float(

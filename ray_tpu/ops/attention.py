@@ -104,6 +104,9 @@ NEG_INF = -1e30
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
 # ... and the forward under two widths: k and v of a whole sequence
 _FWD_WIDE_VMEM_LIMIT = 48 * 1024 * 1024
+# ... what k and v of a whole sequence, double-buffered, may take of the
+# compiler's default scope (16 MiB) beside q, o and the float32 tiles
+_FWD_KV_DEFAULT_SCOPE = 12 * 1024 * 1024
 # The names the forward under a gradient gives the two residuals its
 # kernel produced: the output as the caller sees it ([B, T, H, D_v]) and
 # the row log-sum-exp as `_bwd` reads it ([B, H, T] float32). A block
@@ -389,8 +392,11 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
     out_shape = jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype)
     # two widths (keys wider than values): a whole sequence of 192-wide
     # keys sits in VMEM on 256 lanes, more than the default scope holds
-    # at 8k tokens; with one width the call is the one it always was
-    wide = {} if d_v == d else {"compiler_params": pltpu.CompilerParams(
+    # at 8k tokens; so do k and v of ONE width from 256 x 8192 in bf16
+    # up (double-buffered: 4 t d bytes an item); below that the call is
+    # the one it always was
+    fits = d_v == d and 4 * t * d * q.dtype.itemsize <= _FWD_KV_DEFAULT_SCOPE
+    wide = {} if fits else {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=_FWD_WIDE_VMEM_LIMIT)}
     if save_lse:
         # one [1, block_q] row of float32 a grid step
