@@ -217,7 +217,8 @@ from jax import lax
 
 from ray_tpu.ops.attention import (diffusion_tiles, flash_attention,
                                    window_scores)
-from ray_tpu.ops.gated_delta import CHUNK as DELTA_CHUNK, gated_delta
+from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
+                                     paired_heads)
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
@@ -1568,9 +1569,12 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     operator): with an ssm mixer `ssm_layers` and `ssm_chunks`, the
     chunks the scan walks a step (layers x sequences x T / chunk; every
     head walks each); with a delta mixer `delta_layers`, `delta_chunks`
-    (the same product at the rule's chunk of 64) and `delta_heads` (the
-    value heads that walk each); under block diffusion `diffusion_block`,
-    `diffusion_rows` (rows through the blocks a step: B x 2 L) and
+    (the same product at the rule's chunk of 64), `delta_heads` (the
+    value heads that walk each) and `delta_heads_paired` (those of them
+    whose chunk inverse runs two to a product:
+    `ops.gated_delta.paired_heads` a key head); under block diffusion
+    `diffusion_block`, `diffusion_rows` (rows through the blocks a step:
+    B x 2 L) and
     `attention_tiles_visited` / `attention_tiles_plane` (the score tiles
     the forward kernel's loops walk of one head's 2 L x 2 L plane, and
     the plane's: `ops.attention.diffusion_tiles`, the kernel's own
@@ -1601,7 +1605,10 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     if layers:
         facts.update(delta_layers=layers,
                      delta_chunks=layers * b * (t // DELTA_CHUNK),
-                     delta_heads=cfg.delta_value_heads)
+                     delta_heads=cfg.delta_value_heads,
+                     delta_heads_paired=cfg.delta_key_heads * paired_heads(
+                         cfg.delta_value_heads // cfg.delta_key_heads,
+                         DELTA_CHUNK))
     if cfg.diffusion_block:
         visited, plane = diffusion_tiles(
             2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)

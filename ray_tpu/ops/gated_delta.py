@@ -33,20 +33,31 @@ rematerialised copy) and `gdr_bwd`. Both have the grid (sequence, KEY
 head, chunk), laid out as `ops/ssd.py` is: a grid step holds the H / G
 value heads that share one q and k — so K K^T and Q K^T are formed once
 for them, and the gradients of q and k are summed over them before they
-leave — and walks them one by one; the chunks are the innermost axis,
-walked in order, the heads' states `[H / G, K, V]` float32 in VMEM
-scratch. The backward walks the chunks in REVERSE with the state's
-gradient in that scratch. It reads each chunk's entering state, which
-the forward SAVES when it is differentiated: `[B, T / C, H, K, V]`
-float32, 268 MB a sequence of 8192 at 32 heads of 128 x 128, written
-once and read once, alive between a block's rematerialised forward and
-its backward only. A forward that is not differentiated writes none.
+leave — and walks them one by one (their inverses two by two); the
+chunks are the innermost axis, walked in order, the heads' states `[H /
+G, K, V]` float32 in VMEM scratch. The backward walks the chunks in
+REVERSE with the state's gradient in that scratch. It reads each chunk's
+entering state, which the forward SAVES when it is differentiated: `[B,
+T / C, H, K, V]` float32, 268 MB a sequence of 8192 at 32 heads of 128 x
+128, written once and read once, alive between a block's rematerialised
+forward and its backward only. A forward that is not differentiated
+writes none.
 
 `Tm`: A is strictly lower triangular, so nilpotent, and `(I + A)^-1 =
 (I - A)(I + A^2)(I + A^4) ..` ends after log2(C) factors: at C = 64 five
-squarings and five products of `[64, 64]`, float32 at precision
-"highest" (`_inverse`). Its gradient needs no product with dTm: from
-`V' = Tm R`, `dR = Tm^T dV'` and `dA = -Tm^T (dV' R^T) Tm^T = -dR V'^T`.
+squarings and five products of `[64, 64]` (`inverse_products`), float32
+at precision "highest" (`_inverse`) — six passes of the MXU each, whose
+time goes with the rows a product streams whatever part of the 128 x
+128 array its factors fill. So a key head's value heads go two by two
+side by side in the lanes (`_inverses`, `paired_heads`: while two chunks
+fit the 128 lanes and two heads are left; an odd head out goes alone):
+`[x_a | x_b]` against blockdiag(x_a, x_b), whose zeros add exact zeros
+to every sum, is both heads' product in the rows of one — ten products
+of `[64, 128] [128, 128]` a pair where twenty of `[64, 64]` ran. (`inv
+x'` and `x' x'` share their right-hand factor and could be one product
+of twice the rows: it streams the same rows and measured slower, PERF.md
+section 6, PR 58.) Tm's gradient needs no product with dTm: from `V' =
+Tm R`, `dR = Tm^T dV'` and `dA = -Tm^T (dV' R^T) Tm^T = -dR V'^T`.
 
 The running sums are made OUTSIDE the kernels, in XLA (a float32
 `cumsum` over a chunk, differentiated by it), and come in twice, as
@@ -84,12 +95,20 @@ _PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=64 * 1024 * 1024)
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
+_LANES = 128
 
 
 def inverse_products(chunk: int) -> int:
-    """The `[C, C]` products `_inverse` multiplies: a squaring and a
-    product a factor after the first."""
+    """The `[C, C]` products the doubling names: a squaring and a
+    product a factor after the first (whatever array they share)."""
     return 2 * max((chunk - 1).bit_length() - 1, 0)
+
+
+def paired_heads(heads: int, chunk: int) -> int:
+    """Of a key head's `heads` value heads, those whose inverses run two
+    to a product: heads are paired while two chunks fit the lanes and
+    there are two left to pair."""
+    return heads - heads % 2 if 2 * chunk <= _LANES else 0
 
 
 def _check(q, k, v, g, beta, chunk: int):
@@ -171,13 +190,42 @@ _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 def _inverse(a, eye):
     """(I + a)^-1 of a strictly lower triangular a [C, C], float32:
     with x = -a, (I + x)(I + x^2)(I + x^4) .. = the sum of x's powers,
-    which ends at x^(C - 1)."""
+    which ends at x^(C - 1). `a` [C, 2 C] is two heads' side by side in
+    the lanes (`eye` stays [C, C]): the factor on the right is then
+    blockdiag(x_a, x_b) — x stacked on itself in the rows, under a mask
+    — whose zeros add exact zeros to every sum."""
+    c, width = a.shape
+    if width == c:
+        def right(x):
+            return x
+    else:
+        eye = jnp.concatenate([eye, eye], axis=1)
+        row = lax.broadcasted_iota(jnp.int32, (2 * c, width), 0)
+        col = lax.broadcasted_iota(jnp.int32, (2 * c, width), 1)
+        same_head = (row < c) == (col < c)
+
+        def right(x):
+            return jnp.where(same_head, jnp.concatenate([x, x], axis=0), 0.0)
+
     x = -a
-    inv = eye + x
-    for _ in range(inverse_products(a.shape[0]) // 2):
-        x = _dot(x, x, _NN, _HIGHEST)
-        inv = inv + _dot(inv, x, _NN, _HIGHEST)
+    inv, factor = eye + x, right(x)
+    for _ in range(inverse_products(c) // 2):
+        x = _dot(x, factor, _NN, _HIGHEST)
+        factor = right(x)
+        inv = inv + _dot(inv, factor, _NN, _HIGHEST)
     return inv
+
+
+def _inverses(a, eye):
+    """Tm of each head of a grid step (`a`: the heads' A, [C, C] each):
+    the heads `paired_heads` pairs two to a product, the rest alone."""
+    c = eye.shape[0]
+    paired = paired_heads(len(a), c)
+    out = []
+    for j in range(0, paired, 2):
+        both = _inverse(jnp.concatenate(a[j:j + 2], axis=1), eye)
+        out += [both[:, :c], both[:, c:]]
+    return out + [_inverse(x, eye) for x in a[paired:]]
 
 
 def _chunk_parts(q_ref, k_ref, yr_ref, v_width: int):
@@ -197,18 +245,17 @@ def _chunk_parts(q_ref, k_ref, yr_ref, v_width: int):
         (row == col).astype(_F32), keep
 
 
-def _head_parts(j: int, yr_ref, yc_ref, bc_ref, kk, tri, strict, eye):
-    """Head j's decay mask D [C, C] (zero above the diagonal), A, its
-    inverse, beta, exp(y) and exp(y_C - y) as columns, exp(y_C)
-    [1, 1]."""
+def _head_parts(j: int, yr_ref, yc_ref, bc_ref, kk, tri, strict):
+    """Head j's decay mask D [C, C] (zero above the diagonal), the same
+    strictly below it, A, beta, exp(y) and exp(y_C - y) as columns,
+    exp(y_C) [1, 1]."""
     c = tri.shape[0]
     yr, yc = yr_ref[j:j + 1, :], yc_ref[:, j:j + 1]
     beta = bc_ref[:, j:j + 1]
     total = yr[:, c - 1:c]
     decay = jnp.exp(jnp.where(tri, yc - yr, -jnp.inf))
     lower = jnp.where(strict, decay, 0.0)
-    a = beta * kk * lower
-    return decay, lower, a, _inverse(a, eye), beta, jnp.exp(yc), \
+    return decay, lower, beta * kk * lower, beta, jnp.exp(yc), \
         jnp.exp(total - yc), jnp.exp(total)
 
 
@@ -225,10 +272,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, yr_ref, yc_ref, bc_ref, o_ref, *rest,
         q_ref, k_ref, yr_ref, dv)
     dtype = k.dtype
     cast = functools.partial(jnp.asarray, dtype=dtype)
-    for j in range(state.shape[0]):
+    parts = [_head_parts(j, yr_ref, yc_ref, bc_ref, kk, tri, strict)
+             for j in range(state.shape[0])]
+    for j, inv in enumerate(_inverses([p[2] for p in parts], eye)):
         lanes = pl.ds(j * dv, dv)
-        decay, _, _, inv, beta, e_y, w, _ = _head_parts(
-            j, yr_ref, yc_ref, bc_ref, kk, tri, strict, eye)
+        decay, _, _, beta, e_y, w, _ = parts[j]
         s = state[j]
         if entering is not None:
             entering[j] = s
@@ -260,10 +308,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, yr_ref, yc_ref, bc_ref, s_ref, do_ref,
     at_row = lax.broadcasted_iota(jnp.int32, (heads, c), 0)
     at_col = lax.broadcasted_iota(jnp.int32, (c, heads), 1)
     last = lax.broadcasted_iota(jnp.int32, (1, c), 1) == c - 1
-    for j in range(heads):
+    parts = [_head_parts(j, yr_ref, yc_ref, bc_ref, kk, tri, strict)
+             for j in range(heads)]
+    for j, inv in enumerate(_inverses([p[2] for p in parts], eye)):
         lanes = pl.ds(j * dv, dv)
-        decay, lower, a, inv, beta, e_y, w, e_total = _head_parts(
-            j, yr_ref, yc_ref, bc_ref, kk, tri, strict, eye)
+        decay, lower, a, beta, e_y, w, e_total = parts[j]
         s, ds = s_ref[j], dstate[j]
         sc, dsc = cast(s), cast(ds)
         # the forward's own values again

@@ -633,7 +633,8 @@ def test_gated_delta_fwd_and_bwd(one_chip, on_tpu, batch):
     """The gated delta rule at Qwen3-Next's shapes (8 192 positions in
     chunks of 64): one Mosaic call forward, which writes no state; under
     grad the forward that saves each chunk's entering states ([B, 128,
-    32, 128, 128] float32) and one backward call."""
+    32, 128, 128] float32) and one backward call, under the kernels' own
+    names (the trace's readers match on them)."""
     from ray_tpu.ops import gated_delta
 
     f32 = jnp.float32
@@ -647,6 +648,10 @@ def test_gated_delta_fwd_and_bwd(one_chip, on_tpu, batch):
     assert text.count("tpu_custom_call") == 2
     assert "gdr_fwd" in text and "gdr_bwd" in text
     assert f"f32[{batch},128,32,128,128]" in text
+    # and no dense fallback beside them: the plain chunked form would
+    # bring a triangular solve, a scan over the chunks and XLA's products
+    assert "triangular-solve" not in text and "while" not in text \
+        and " dot(" not in text and "convolution(" not in text
 
 
 def test_gated_delta_under_a_sharded_jit(topo, on_tpu):

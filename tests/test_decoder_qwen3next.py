@@ -121,7 +121,12 @@ def test_parameter_tree_state_and_facts():
         <= set(state["epoch_counters"])
     assert decoder.step_facts(cfg, (2, T)) == {
         "delta_layers": 3, "delta_chunks": 3 * 2 * (T // 64),
-        "delta_heads": 4, "attention_heads_full": 4, "rope_dim": 8}
+        "delta_heads": 4, "delta_heads_paired": 4,
+        "attention_heads_full": 4, "rope_dim": 8}
+    # three value heads a key head: the odd one's inverse runs alone
+    odd = decoder.step_facts(
+        dataclasses.replace(cfg, delta_value_heads=6), (2, T))
+    assert (odd["delta_heads"], odd["delta_heads_paired"]) == (6, 4)
     assert decoder.step_facts(decoder.TINY, (2, 64)) == {}
 
 
@@ -155,7 +160,8 @@ def test_the_cut_has_the_parameters_the_issue_counted():
         and shapes["layers"]["w_gate"].shape == (4, 32, 2048, 512)
     facts = decoder.step_facts(cfg, (2, 8192))
     assert facts == {"delta_layers": 3, "delta_chunks": 3 * 2 * 128,
-                     "delta_heads": 32, "attention_heads_full": 16,
+                     "delta_heads": 32, "delta_heads_paired": 32,
+                     "attention_heads_full": 16,
                      "rope_dim": 64}
 
 

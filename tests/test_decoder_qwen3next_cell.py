@@ -33,8 +33,9 @@ def test_cell_rehearses_on_the_cpu_to_its_end(tmp_path):
              for s in json.loads(log.read_text())[-1]["spans"]}
     facts, counted = spans["train.dispatch"], spans["train.sync"]
     assert (facts["delta_layers"], facts["delta_chunks"],
-            facts["delta_heads"], facts["attention_heads_full"],
-            facts["rope_dim"]) == (3, 3 * 2 * (128 // 64), 4, 4, 8)
+            facts["delta_heads"], facts["delta_heads_paired"],
+            facts["attention_heads_full"],
+            facts["rope_dim"]) == (3, 3 * 2 * (128 // 64), 4, 4, 4, 8)
     steps = counted["moe_steps"]
     assert counted["delta_beta_count"] == steps * 2 * 128 * 3 * 4
     assert counted["attn_gate_count_full"] == steps * 2 * 128 * 4 * 32

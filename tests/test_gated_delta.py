@@ -35,13 +35,14 @@ def recurrence(q, k, v, g, beta, initial_state=None):
     return o.swapaxes(0, 1)
 
 
-def case(chunks: int, chunk: int, decay: str, seed: int = 0):
-    """Seeded inputs: 2 sequences, 2 key heads of 8 each serving 2 value
-    heads of 16; k L2-normalised as the mixer's are. `decay`: "strong"
+def case(chunks: int, chunk: int, decay: str, seed: int = 0,
+         heads: int = 2):
+    """Seeded inputs: 2 sequences, 2 key heads of 8 each serving `heads`
+    value heads of 16; k L2-normalised as the mixer's are. `decay`: "strong"
     (a chunk's sum far below -87: the chunk forgets everything), "weak"
     (near zero) or "mixed" (Qwen3-Next's own range, A up to 16)."""
     keys = jax.random.split(jax.random.key(seed), 7)
-    b, t, groups, h, dk, dv = 2, chunks * chunk, 2, 4, 8, 16
+    b, t, groups, h, dk, dv = 2, chunks * chunk, 2, 2 * heads, 8, 16
     q = jax.random.normal(keys[0], (b, t, groups, dk), F32) * dk ** -0.5
     k = jax.random.normal(keys[1], (b, t, groups, dk), F32)
     k = k * lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-6)
@@ -61,12 +62,18 @@ def both(fn, args, w, **kw):
         tuple(range(len(args)))))(*args)
 
 
-@pytest.mark.parametrize("decay", ["strong", "weak", "mixed"])
-@pytest.mark.parametrize("chunks,chunk", [(2, 16), (3, 8), (2, 64)])
-@pytest.mark.parametrize("form", ["kernels", "xla"])
+SIZES = [(2, 16), (3, 8), (2, 64)]
+
+
+@pytest.mark.parametrize("form,chunks,chunk,decay,heads", [
+    (form, *size, decay, 2) for form in ("kernels", "xla") for size in SIZES
+    for decay in ("strong", "weak", "mixed")] + [
+    # a key head's one value head, and three: the odd head's inverse
+    # runs alone beside a pair's
+    ("kernels", *size, "mixed", heads) for heads in (1, 3) for size in SIZES])
 def test_values_and_gradients_match_the_recurrence(form, chunks, chunk,
-                                                   decay):
-    args, w = case(chunks, chunk, decay)
+                                                   decay, heads):
+    args, w = case(chunks, chunk, decay, heads=heads)
     fn = gd.gated_delta if form == "kernels" else gd.gated_delta_xla
     with jax.default_matmul_precision("highest"):
         want, g_want = both(recurrence, args, w)
@@ -147,6 +154,42 @@ def test_a_ragged_length_and_wrong_shapes_raise():
         gd.gated_delta(q, k, v, g[..., :3], beta, chunk=16)
     with pytest.raises(ValueError, match="whole chunks"):
         gd.gated_delta_xla(q, k, v, g, beta, chunk=24)
+
+
+def doubling(a, eye):
+    """`_inverse` as it stood before two heads shared a product: a
+    squaring and a product a factor, one head at a time."""
+    x = -a
+    inv = eye + x
+    for _ in range(gd.inverse_products(a.shape[0]) // 2):
+        x = gd._dot(x, x, gd._NN, gd._HIGHEST)
+        inv = inv + gd._dot(inv, x, gd._NN, gd._HIGHEST)
+    return inv
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+@pytest.mark.parametrize("heads", [1, 2, 3, 4])
+def test_every_heads_inverse_is_the_doublings(heads, chunk):
+    """Two heads side by side in the lanes against a block-diagonal
+    factor: each head's Tm is what the plain doubling gives of that
+    head's A (the factor's zeros may change how a sum is blocked here,
+    so not bitwise), the paired heads are those `paired_heads` counts,
+    and both are (I + A)^-1."""
+    assert gd.paired_heads(heads, chunk) == heads - heads % 2
+    assert gd.paired_heads(heads, 128) == 0
+    eye = jnp.eye(chunk)
+    a = [jnp.tril(jax.random.normal(jax.random.key(chunk + j),
+                                    (chunk, chunk)), -1) * 0.3
+         for j in range(heads)]
+    got = jax.jit(gd._inverses)(a, eye)
+    assert len(got) == heads
+    for a_j, tm in zip(a, got):
+        want = doubling(a_j, eye)
+        np.testing.assert_allclose(
+            tm, want, rtol=0, atol=1e-6 * float(jnp.abs(want).max()))
+        np.testing.assert_allclose(
+            tm, np.linalg.inv(np.asarray(eye + a_j, np.float64)),
+            rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("chunk,products", [(64, 10), (16, 6), (8, 4),
