@@ -129,7 +129,7 @@ class _Measured:
             step_s.append(time.perf_counter() - t0)
             losses.append(out["last_train_loss"])
         programs = self._programs - programs
-        if not hasattr(self, "_step_text"):  # one compile-cache load
+        if not hasattr(self, "_step_text"):  # lowered and compiled once
             self._step_text = self.compiled_step_text(
                 next(iter(self._train_loader)))
         text = self._step_text
@@ -476,7 +476,7 @@ def main(argv=None) -> int:
             lines = [phase_resnet(seed=args.seed),
                      phase_gpt(seed=args.seed),
                      # warm restarts: a NEW chip-owning process per phase
-                     # finds both compile caches populated
+                     # finds JAX's compile cache populated
                      phase_resnet(seed=args.seed, warmup=1, steps=0,
                                   cold=False),
                      phase_gpt(seed=args.seed, warmup=1, steps=0,

@@ -1870,14 +1870,6 @@ class CoreWorker:
             # jit-compile activity (profiling.record_compile seams): the
             # stall doctor's compile-storm signal rides this snapshot
             snap["jax_compiles"] = compiles
-        from ray_tpu._private import compile_cache as _cc
-
-        cache = _cc.state()
-        if cache["hits"] or cache["misses"] or cache["errors"]:
-            # persistent AOT compile-cache activity: the doctor's
-            # compile_cache_cold finding (restart re-traced despite a
-            # warm cache) reads this
-            snap["compile_cache"] = cache
         routers = _serve_router_debug()
         if routers:
             snap["routers"] = routers

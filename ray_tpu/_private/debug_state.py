@@ -555,36 +555,6 @@ def diagnose(snapshot: dict, metrics: dict | None = None, *,
                            f"({compiles['recent_s']:.1f}s wall, "
                            f"{compiles.get('total', 0)} total)"),
             })
-        cache = proc.get("compile_cache")
-        if (isinstance(cache, dict) and cache.get("enabled")
-                and cache.get("entries_preexisting", 0) > 0
-                and cache.get("misses", 0) > 0
-                and cache.get("hits", 0) == 0):
-            # compile_cache_cold: this process re-traced even though a
-            # warm on-disk cache PREDATING the process exists — a
-            # fingerprint drift (jax upgrade, topology change) or a
-            # key-schema mismatch; the restart paid the re-trace storm
-            # the cache exists to prevent. Gating on preexisting
-            # entries (not total: the index also holds blobs this very
-            # process just stored on its own misses) keeps a first-ever
-            # cold process from false-positiving. Age-less (a property
-            # of the process, not a stall).
-            findings.append({
-                "kind": "compile_cache_cold",
-                "process": label,
-                "stage": "compile",
-                "age_s": 0.0,
-                "threshold_s": 0.0,
-                "trace_id": "",
-                "trace_source": "",
-                "id": "",
-                "name": cache.get("dir", ""),
-                "detail": (f"{cache['misses']} cache misses with 0 hits "
-                           f"despite {cache['entries_preexisting']} "
-                           f"stored executables predating the process "
-                           f"(errors={cache.get('errors', 0)}): "
-                           f"restart re-traced despite a warm cache"),
-            })
         # topology_mismatch: a CREATED gang whose members span ICI
         # slices — its collectives pay DCN on every op even though a
         # same-slice placement may exist; age-less (a property of the

@@ -51,7 +51,7 @@ def record_compile(key: str, start: float, end: float,
     """Record one observed jit compile: metrics + a `jax.compile` span
     (joining the ambient trace when one is active) + the recent window
     the doctor reads. `timed`: what jax itself timed inside the interval
-    (`compile_cache._jax_timed`), added to the span's attributes."""
+    (`_jax_timed`), added to the span's attributes."""
     from ray_tpu._private import tracing
 
     seconds = max(0.0, end - start)
@@ -83,42 +83,121 @@ def compile_state() -> dict:
     }
 
 
+# jax.monitoring's duration events -> the attribute that sums them on
+# the `jax.compile` span. `backend_s` is the XLA compile OR the load
+# from jax's persistent cache; the retrieval event comes only with a
+# persistent-cache hit.
+_JAX_TIMED = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
+_first_dispatches = 0           # open in this process
+_timed = threading.local()      # .events: the open first dispatch's
+
+
+def _on_jax_duration(event, duration, **_):
+    """The ONE jax.monitoring listener, registered only while a first
+    dispatch is open (`_jax_timings`): keeps the event as an interval
+    that ends now. jax times a jit traced inside another's trace once
+    more, inside the outer's interval: the outermost alone is kept."""
+    attr = _JAX_TIMED.get(event)
+    events = getattr(_timed, "events", None)
+    if attr is None or events is None:
+        return
+    end = time.time()
+    start = end - duration
+    kept = [iv for iv in events.get(attr, ()) if iv[0] < start]
+    events[attr] = kept + [(start, end)]
+
+
+@contextlib.contextmanager
+def _jax_timings():
+    """Listen to jax's own compile timings for the duration of one
+    first dispatch, in the dispatching thread."""
+    global _first_dispatches
+    import jax.monitoring
+
+    with _compile_lock:
+        _first_dispatches += 1
+        if _first_dispatches == 1:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+    outer, _timed.events = getattr(_timed, "events", None), {}
+    try:
+        yield
+    finally:
+        _timed.events = outer
+        with _compile_lock:
+            _first_dispatches -= 1
+            if not _first_dispatches:
+                jax.monitoring.unregister_event_duration_listener(
+                    _on_jax_duration)
+
+
+def _jax_timed(since: float) -> dict:
+    """What jax timed in the open first dispatch from `since` on, as
+    span attributes; an event jax did not emit leaves its attribute
+    out."""
+    out = {}
+    for attr, intervals in (getattr(_timed, "events", None) or {}).items():
+        inside = [b - a for a, b in intervals if a >= since - 1e-3]
+        if inside:
+            out[attr] = round(sum(inside), 4)
+            if attr == "backend_s":
+                out["programs"] = len(inside)
+    if "backend_s" in out:
+        out["persistent_hit"] = int("cache_retrieval_s" in out)
+    return out
+
+
 class CompileProbe:
-    """First-dispatch-per-shape-class timer for jitted callables.
+    """One jitted callable whose FIRST dispatch is timed and recorded.
 
-    jit recompiles exactly when the traced shape class changes, so the
-    first dispatch of a new key carries the compile; `watch(key)` times
-    that first call and records it via record_compile (later calls of a
-    seen key cost one set lookup). The measured time includes the first
-    execution — the standard proxy when the runtime can't hook XLA
-    directly."""
+    The runtime's compile seams — the Trainer's steps and its eval, the
+    `_DeviceOps` collective bodies, the paged-KV donated update — keep
+    one probe a (program, shape class): jit compiles exactly when the
+    shape class is new, so the first dispatch of a probe carries the
+    trace, the lowering and the executable (compiled, or loaded from
+    JAX's persistent cache: `compile_cache.py`). It is recorded through
+    `record_compile` under `key`, with what jax itself timed inside it
+    (`trace_s`, `lower_s`, `backend_s`, `cache_retrieval_s`,
+    `persistent_hit`, `programs`); the measured interval includes the
+    first execution. A first dispatch that raises (a transient OOM, an
+    interrupt) proved no compile: nothing is recorded and the retry is
+    timed. Later calls cost one attribute check before the jit's own
+    call."""
 
-    def __init__(self, name: str):
-        self.name = name
-        self._seen: set = set()
+    def __init__(self, key: str, jitted, donate_argnums=()):
+        self.key = key
+        # what `jitted` donates, for the callers and tests that ask
+        self.donate_argnums = tuple(donate_argnums)
+        self._jitted = jitted
+        self._fn = None     # `jitted`, once a dispatch of it has returned
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
-    def watch(self, *key_parts):
-        key = ":".join(str(p) for p in key_parts)
+    def __call__(self, *args):
+        fn = self._fn
+        if fn is not None:
+            return fn(*args)
         with self._lock:
-            fresh = key not in self._seen
-            if fresh:
-                self._seen.add(key)
-        if not fresh:
-            yield False
-            return
-        t0 = time.time()
-        try:
-            yield True
-        except BaseException:
-            # a failed first dispatch (transient OOM, interrupt) did
-            # not prove a compile: un-mark the key so the retry is
-            # timed, and record nothing for the failed attempt
-            with self._lock:
-                self._seen.discard(key)
-            raise
-        record_compile(f"{self.name}:{key}", t0, time.time())
+            if self._fn is None:
+                with _jax_timings():
+                    t0 = time.time()
+                    out = self._jitted(*args)
+                    record_compile(self.key, t0, time.time(),
+                                   _jax_timed(t0))
+                self._fn = self._jitted
+                return out
+        return self._jitted(*args)
+
+    def compiled_text(self, *args) -> str:
+        """Optimised HLO of the program dispatched for `args` (lowering
+        consumes no donated buffer). What a chip run reads to check that
+        a kernel (`tpu_custom_call`) or a collective is really in the
+        step, not its XLA fallback."""
+        return self._jitted.lower(*args).compile().as_text()
 
 
 def shape_class(batch) -> str:

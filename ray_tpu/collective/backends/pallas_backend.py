@@ -301,9 +301,7 @@ class _PallasOps:
 
     def _jit(self, key, wrapper, out_specs=None):
         """First-call compile-recording cache, same contract as
-        _DeviceOps._jit (the persistent compile cache hooks the same
-        seam there; fused kernels re-trace per process — they are the
-        latency tier, their compiles are small)."""
+        _DeviceOps._jit."""
         fn = self._cache.get(key)
         if fn is None:
             from jax.sharding import PartitionSpec as P
@@ -314,19 +312,8 @@ class _PallasOps:
                 wrapper, self.mesh, P(self.axis, None),
                 out_specs if out_specs is not None
                 else P(self.axis, None)))
-
-            def first_call(*args, _jitted=jitted, _key=key):
-                import time as _time
-
-                t0 = _time.time()
-                out = _jitted(*args)
-                _profiling.record_compile(
-                    "pallas:" + ":".join(map(str, _key)),
-                    t0, _time.time())
-                self._cache[_key] = _jitted
-                return out
-
-            fn = self._cache[key] = first_call
+            fn = self._cache[key] = _profiling.CompileProbe(
+                "pallas:" + ":".join(map(str, key)), jitted)
         return fn
 
     @staticmethod

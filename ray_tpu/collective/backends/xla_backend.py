@@ -106,24 +106,17 @@ class _DeviceOps:
         if fn is None:
             from jax.sharding import PartitionSpec as P
 
-            from ray_tpu._private import compile_cache as _cc
+            from ray_tpu._private import profiling as _profiling
 
             jitted = jax.jit(_shard_map(
                 body, self.mesh, P(self.axis, None),
                 out_specs if out_specs is not None
                 else P(self.axis, None)))
-            # the persistent AOT cache fronts the compile seam: a warm
-            # restart deserializes the stored executable — a cache HIT
-            # records NO compile, so jax.compiles_total stays flat —
-            # while a cold process compiles, records it exactly as
-            # before, and exports + stores for the next generation.
-            # `key` already carries every compile-relevant input (op,
-            # dtype, shape-class, axis, world); the runtime fingerprint
-            # (jax version, backend, device kinds, process count) rides
-            # inside the cache key derivation.
-            fn = self._cache[key] = _cc.CachedFunction(
-                "collective", key, jitted,
-                record_key="collective:" + ":".join(map(str, key)))
+            # `key` carries every compile-relevant input (op, dtype,
+            # shape-class, axis, world); its first dispatch is recorded
+            # as one compile
+            fn = self._cache[key] = _profiling.CompileProbe(
+                "collective:" + ":".join(map(str, key)), jitted)
         return fn
 
     # -- exact bodies ---------------------------------------------------

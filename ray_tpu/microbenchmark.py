@@ -287,8 +287,6 @@ def main(seconds_per_case: float = 2.0) -> list[dict]:
 
     _serve_prefix(results)
 
-    _cold_gang_ttft(results)
-
     _train_sharded(results)
 
     ray_tpu.shutdown()
@@ -1239,130 +1237,6 @@ def _serve_prefix(results: list[dict], windows: int = 3,
     serve.shutdown()
 
 
-def _cold_gang_ttft(results: list[dict], pairs: int = 3):
-    """Serve gang restart TTFT, compile cache cold vs warm, PAIRED
-    (round 15): each pair clears the persistent AOT compile cache,
-    deploys a fresh streaming replica and times create_backend -> first
-    SSE token (the restart path a gang pays end-to-end: replica actor
-    spawn, engine build, kv-arena alloc, first decode-step dispatch),
-    then tears it down and repeats WITHOUT clearing — the second
-    replica's jax seams resolve against the executables the first one
-    stored. The warm arm's hit delta is counter-verified from the
-    shared on-disk index (the replica records hits into it), so the row
-    proves the cache engaged rather than assuming it."""
-    import http.client
-
-    import numpy as _np
-
-    from ray_tpu import serve
-    from ray_tpu._private import compile_cache as _cc
-    from ray_tpu.serve.engine import ShardedTokenLM
-    from ray_tpu.serve.streaming import iter_sse_lines
-
-    model = ShardedTokenLM.make(11, vocab=512, hidden=32, inner=64)
-    margs = (model.embed.copy(), model.w_up.copy(), model.w_out.copy())
-    client = serve.start(http=True)
-    port = client.http_port
-    seq = [0]
-
-    def _index_hits() -> int:
-        return sum(int(e.get("hits", 0))
-                   for e in _cc.read_index().values())
-
-    def restart_ttft() -> float:
-        """create_backend -> first streamed token, one fresh replica.
-        kv_backend=jax so the decode path runs the donated-arena jitted
-        update — the seam the persistent compile cache hooks (the numpy
-        default never compiles anything and the A/B would measure
-        nothing)."""
-        seq[0] += 1
-        name = f"bench_cg{seq[0]}"
-        t0 = time.perf_counter()
-        client.create_backend(
-            name, ShardedTokenLM, *margs,
-            config={"streaming": True, "max_decode_batch": 2,
-                    "max_waiting_sequences": 8, "kv_pages_total": 256,
-                    "kv_backend": "jax",
-                    "num_replicas": 1, "large_payload_threshold": 0})
-        client.create_endpoint(name, backend=name, route=f"/{name}",
-                               methods=["POST"])
-        ttft = None
-        deadline = time.time() + 120
-        while ttft is None and time.time() < deadline:
-            try:  # route table syncs asynchronously: retry until live
-                conn = http.client.HTTPConnection("127.0.0.1", port,
-                                                  timeout=15)
-                body = json.dumps({"prompt": [1, 3, 5], "max_tokens": 4,
-                                   "stream": True})
-                conn.request("POST", f"/{name}", body=body, headers={
-                    "Content-Type": "application/json",
-                    "Accept": "text/event-stream"})
-                resp = conn.getresponse()
-                if resp.status != 200:  # route not synced yet: a 404
-                    resp.read()         # body is NOT an SSE stream —
-                    conn.close()        # iterating it would block on
-                    time.sleep(0.1)     # the kept-alive socket
-                    continue
-                # drain to done (4 tokens): abandoning the stream early
-                # can wedge the proxy-side handler on the half-closed
-                # socket and stall the NEXT trial's request behind it
-                for ev, data in iter_sse_lines(resp.fp):
-                    if ev == "error":
-                        break
-                    if ttft is None and data.get("tokens"):
-                        ttft = time.perf_counter() - t0
-                    if ev == "done" or data.get("done"):
-                        break
-                conn.close()
-            except (http.client.HTTPException, OSError):
-                time.sleep(0.2)
-        client.delete_endpoint(name)
-        client.delete_backend(name)
-        # wait out the route-teardown sync so trial N+1 never races a
-        # stale route to the now-dead replica
-        gone = time.time() + 30
-        while time.time() < gone:
-            try:
-                conn = http.client.HTTPConnection("127.0.0.1", port,
-                                                  timeout=5)
-                conn.request("POST", f"/{name}",
-                             body=json.dumps({"prompt": [1]}),
-                             headers={"Content-Type": "application/json"})
-                status = conn.getresponse().status
-                conn.close()
-                if status == 404:
-                    break
-            except (http.client.HTTPException, OSError):
-                pass
-            time.sleep(0.1)
-        return ttft if ttft is not None else time.perf_counter() - t0
-
-    cold, warm, hit_deltas = [], [], []
-    for _ in range(pairs):
-        _cc.clear()
-        cold.append(restart_ttft())
-        h0 = _index_hits()
-        warm.append(restart_ttft())
-        hit_deltas.append(_index_hits() - h0)
-    cold_ms = float(_np.median(cold)) * 1000
-    warm_ms = float(_np.median(warm)) * 1000
-    results.append({
-        "name": "cold_gang_ttft",
-        "cold_ttft_ms": round(cold_ms, 1),
-        "warm_ttft_ms": round(warm_ms, 1),
-        "speedup_x": round(cold_ms / warm_ms, 3) if warm_ms else 0.0,
-        "warm_cache_hits_per_restart": float(_np.mean(hit_deltas)),
-        "pairs": pairs,
-        "cold_trials_ms": [round(t * 1000, 1) for t in cold],
-        "warm_trials_ms": [round(t * 1000, 1) for t in warm],
-    })
-    print(f"cold_gang_ttft: cold {cold_ms:.0f}ms vs warm {warm_ms:.0f}ms "
-          f"(x{cold_ms / max(warm_ms, 1e-9):.2f}, "
-          f"{float(_np.mean(hit_deltas)):.1f} cache hits/restart, "
-          f"median of {pairs} pairs)")
-    serve.shutdown()
-
-
 def _tracing_ab(results: list[dict]):
     """Distributed-tracing overhead A/B (the tier-1 microbench gate in
     test_observability reads these rows): tracing at the DEFAULT head
@@ -1795,7 +1669,6 @@ if __name__ == "__main__":
                   "serve_prefix": _serve_prefix,
                   "tracing": _tracing_ab, "state": _state_ab,
                   "collective": _collective_bench,
-                  "cold_gang": _cold_gang_ttft,
                   "placement_topology": _placement_topology,
                   "train_sharded": _train_sharded}
         if args.only not in groups:

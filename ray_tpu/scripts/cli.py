@@ -326,57 +326,6 @@ def cmd_timeline(args) -> int:
     return 0
 
 
-def cmd_compile_cache(args) -> int:
-    """Persistent AOT compile-cache contents: the GCS-mirrored index
-    when a cluster is reachable (cluster-wide view), else the local
-    on-disk index. --clear drops blobs + index (local and mirror)."""
-    import time as _time
-
-    from ray_tpu._private import compile_cache as _cc
-
-    addr = _gcs_address(args)
-    index = None
-    source = "local"
-    if addr:
-        try:
-            raw = _rpc_call(addr, "kv_get", {"key": _cc.KV_INDEX_KEY})
-            if raw:
-                index = json.loads(
-                    raw.decode() if isinstance(raw, bytes) else raw)
-                source = "gcs"
-        except Exception:
-            pass
-    if index is None:
-        index = _cc.read_index()
-    if args.clear:
-        n = _cc.clear()
-        if addr:
-            try:
-                _rpc_call(addr, "kv_del", {"key": _cc.KV_INDEX_KEY})
-            except Exception:
-                pass
-        print(f"cleared {n} cached executable(s) from {_cc.cache_dir()}")
-        return 0
-    if args.json:
-        print(json.dumps({"source": source, "dir": _cc.cache_dir(),
-                          "state": _cc.state(), "entries": index}))
-        return 0
-    if not index:
-        print(f"compile cache empty ({_cc.cache_dir()})")
-        return 0
-    print(f"compile cache ({source} index, {len(index)} entries, "
-          f"dir {_cc.cache_dir()}):")
-    now = _time.time()
-    for key in sorted(index, key=lambda k: -index[k].get("created", 0)):
-        e = index[key]
-        age = now - e.get("created", now)
-        parts = ":".join(e.get("parts", [])) or e.get("seam", "?")
-        print(f"  {key}  {e.get('seam', '?')}:{parts}  "
-              f"{e.get('size', 0)}B  age={age:.0f}s  "
-              f"hits={e.get('hits', 0)}")
-    return 0
-
-
 def cmd_trace(args) -> int:
     """Export the GCS trace table (causally-linked cross-process span
     trees, tracing.py) as Perfetto/chrome-trace JSON — the whole table,
@@ -1035,16 +984,6 @@ def main(argv=None) -> int:
     p = sub.add_parser("metrics", help="metric snapshots from gcs + raylets")
     p.add_argument("--address", default=None)
     p.set_defaults(fn=cmd_metrics)
-
-    p = sub.add_parser("compile-cache",
-                       help="persistent AOT compile-cache contents "
-                            "(key, size, age, hit count)")
-    p.add_argument("--address", default=None)
-    p.add_argument("--clear", action="store_true",
-                   help="drop every cached executable + the index")
-    p.add_argument("--json", action="store_true",
-                   help="machine-readable index + counters")
-    p.set_defaults(fn=cmd_compile_cache)
 
     p = sub.add_parser("trace",
                        help="export distributed-trace span trees "

@@ -715,15 +715,10 @@ def _make_donated_update():
     """Jitted single-row page write with the arena DONATED: XLA reuses
     the input buffer for the output, so the per-token update is in-place
     instead of an O(arena) copy (the jax path of `append`). The first
-    dispatch per arena shape resolves through the persistent AOT compile
-    cache (_private/compile_cache.py): a fresh serve replica whose arena
-    shape an earlier replica already compiled deserializes the stored
-    executable — no re-trace, no compile event — while a cold replica
-    compiles, records the event (the decode-step seam of the
-    jax.compile_s / recompile-storm plane), and populates the cache."""
+    dispatch per arena shape is recorded as a compile (the decode-step
+    seam of the jax.compile_s / recompile-storm plane)."""
     import jax
 
-    from ray_tpu._private import compile_cache as _cc
     from ray_tpu._private import profiling as _profiling
 
     def _update(pages, page, slot, row):
@@ -731,7 +726,7 @@ def _make_donated_update():
 
     jitted = jax.jit(_update, donate_argnums=(0,), static_argnums=())
     # the arena shape is fixed for the cache's lifetime but unknown
-    # until the first token, so the CachedFunction is built lazily on
+    # until the first token, so the CompileProbe is built lazily on
     # first dispatch (this runs per token inside the cache lock; the
     # steady state is one None check)
     state: dict = {"fn": None}
@@ -739,11 +734,8 @@ def _make_donated_update():
     def update(pages, page, slot, row):
         fn = state["fn"]
         if fn is None:
-            sc = _profiling.shape_class(pages)
-            fn = state["fn"] = _cc.CachedFunction(
-                "serve.kv_update", (sc, str(pages.dtype), row.shape[0]),
-                jitted, donate_argnums=(0,),
-                record_key="serve.kv_update:" + sc)
+            fn = state["fn"] = _profiling.CompileProbe(
+                "serve.kv_update:" + _profiling.shape_class(pages), jitted)
         return fn(pages, page, slot, row)
 
     return update

@@ -316,13 +316,6 @@ class TrainingOperator:
                 layout(self.opt_state),
                 jax.sharding.NamedSharding(self._mesh,
                                            jax.sharding.PartitionSpec()))
-        # compile observability (profiling.py): the first dispatch of a
-        # NEW batch shape class recompiles the jitted step — record it
-        # (jax.compiles_total / jax.compile_s / a `jax.compile` span) so
-        # a shape-churning loader reads as a recompile storm, not a
-        # mystery slowdown
-        self._compile_probe = _profiling.CompileProbe("train.step")
-
         # The step's two halves. The scopes are names in the device
         # trace ("forward_backward", "optimizer"), nothing else.
         def update(grads, opt_state, params):
@@ -392,13 +385,12 @@ class TrainingOperator:
 
             self._shard_apply = jax.jit(shard_apply, donate_argnums=(0, 1))
             self._pad_grads = jax.jit(lambda g: jnp.pad(g, (0, pad)))
-        # persistent AOT compile cache over the step seams: one
-        # CachedFunction per (step name, batch shape class), keyed
-        # additionally by a jaxpr hash of the USER computation
-        # (loss_fn/optimizer) so two models with identical shapes never
-        # share an executable. A restarted/elastically-resized worker
-        # whose shapes an earlier generation compiled loads instead of
-        # re-tracing — and records NO compile event.
+        # one CompileProbe per (step name, batch shape class): the first
+        # dispatch of a NEW shape class traces, lowers and compiles (or
+        # loads from JAX's persistent cache) — recorded once
+        # (jax.compiles_total / jax.compile_s / a `jax.compile` span
+        # with jax's own timings), so a shape-churning loader reads as a
+        # recompile storm, not a mystery slowdown
         self._step_cache = {}
 
         if self._eval_fn is not None:
@@ -473,22 +465,16 @@ class TrainingOperator:
             return multihost.shard_host_batch(batch, self._batch_sharding)
         return jax.device_put(batch, self._batch_sharding)
 
-    def _cached_step(self, name: str, shape_key: str, jitted, donate=(),
-                     out_shardings=None):
-        """The per-(step, shape-class) CachedFunction — compile
-        observability moves inside it: a persistent-cache HIT records no
-        compile event (jax.compiles_total stays flat on a warm restart),
-        a miss records exactly what CompileProbe.watch did before."""
-        from ray_tpu._private import compile_cache as _cc
-
+    def _cached_step(self, name: str, shape_key: str, jitted, donate=()):
+        """The per-(step, shape-class) CompileProbe around `jitted`:
+        its first dispatch is recorded as
+        `train.step:<name>:<shape_key>`."""
         key = (name, shape_key)
         fn = self._step_cache.get(key)
         if fn is None:
-            fn = self._step_cache[key] = _cc.CachedFunction(
-                "train.step", key, jitted, donate_argnums=donate,
-                out_shardings=out_shardings,
-                record_key=f"train.step:{name}:{shape_key}",
-                fingerprint_computation=True)
+            fn = self._step_cache[key] = _profiling.CompileProbe(
+                f"train.step:{name}:{shape_key}", jitted,
+                donate_argnums=donate)
         return fn
 
     def compiled_step_text(self, batch) -> str:
@@ -499,7 +485,7 @@ class TrainingOperator:
         if self._mesh is not None:
             name, batch = "fused-mesh", self._place_batch(batch)
         step = self._cached_step(name, shape_key, self._fused_step,
-                                 self._fused_donate, self._fused_out)
+                                 self._fused_donate)
         return step.compiled_text(self.params, self.model_state,
                                   self.opt_state, batch)
 
@@ -510,8 +496,7 @@ class TrainingOperator:
             # SPMD over the (global) mesh — no HOST allreduce.
             batch = self._place_batch(batch)
             step = self._cached_step("fused-mesh", shape_key,
-                                     self._fused_step, self._fused_donate,
-                                     self._fused_out)
+                                     self._fused_step, self._fused_donate)
             self.params, self.model_state, self.opt_state, loss = step(
                 self.params, self.model_state, self.opt_state, batch)
             return loss
@@ -819,16 +804,14 @@ class TrainingOperator:
         state, optimizer state), a tail's the same function over
         (`arrays`, None, None), each laid out as it lies."""
         if not first:
-            return self._cached_step("hold", "state", self._copy_state,
-                                     out_shardings=self._copy_out)
+            return self._cached_step("hold", "state", self._copy_state)
         fn = self._step_cache.get(("hold", f"from{first}"))
         if fn is None:
             out = self._copy_out and ([x.sharding for x in arrays],
                                       None, None)
             fn = self._cached_step(
                 "hold", f"from{first}",
-                jax.jit(self._copy_fn, out_shardings=out),
-                out_shardings=out)
+                jax.jit(self._copy_fn, out_shardings=out))
         return fn
 
     def expect_pull(self, of_epoch: int) -> bool:
@@ -870,11 +853,10 @@ class TrainingOperator:
         for step, batch in enumerate(self._val_loader):
             if self._mesh is not None:
                 batch = self._place_batch(batch)
-            with self._compile_probe.watch(
-                    "eval", _profiling.shape_class(batch)):
-                m = (self._jit_eval(self.params, self.model_state, batch)
-                     if self._stateful
-                     else self._jit_eval(self.params, batch))
+            evaluate = self._cached_step(
+                "eval", _profiling.shape_class(batch), self._jit_eval)
+            m = (evaluate(self.params, self.model_state, batch)
+                 if self._stateful else evaluate(self.params, batch))
             all_metrics.append({k: float(v) for k, v in m.items()})
             samples += _batch_size(batch)
             if num_steps is not None and step + 1 >= num_steps:

@@ -954,7 +954,7 @@ class Trainer:
         if not broken and len(self.workers) == self._num_workers:
             # No-op resize: the gang is intact at full strength — keep
             # it. Restarting here would pay a redundant state broadcast
-            # and drop every warm compile cache for nothing (the old
+            # and drop every compiled step for nothing (the old
             # path did exactly that). Wedged-but-alive groups still
             # terminate: the caller's retry budget bounds us.
             return

@@ -6,7 +6,6 @@ exercised without TPU hardware.
 """
 
 import os
-import tempfile
 
 # Ownership stamp for the leak check: every runtime process this pytest
 # process starts (GCS, raylets, workers — also workers re-parented to
@@ -37,16 +36,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # driver exits never pay the sweep.
 os.environ.setdefault("RAY_TPU_FINAL_SNAPSHOT", "1")
 
-# Hermetic persistent compile cache: a warm cache left by an earlier run
-# would flip compile-count assertions (cache hits record NO compile), so
-# every test session gets a fresh dir. Tests that exercise warm restarts
-# point RAY_TPU_COMPILE_CACHE_DIR at their own tmp_path instead. An xdist
-# worker inherits the controller's value and replaces it: a dir shared by
-# six workers is neither hermetic nor safe for the stray-temp-file check.
-if ("RAY_TPU_COMPILE_CACHE_DIR" not in os.environ
-        or os.environ.get("PYTEST_XDIST_WORKER")):
-    os.environ["RAY_TPU_COMPILE_CACHE_DIR"] = tempfile.mkdtemp(
-        prefix="ray_tpu_cc_test_")
 # JAX's own persistent cache stays off in the test tree (the repo-level
 # default, <repo>/.jax_cache, would make one run's compiles another's
 # hits); the tests of its placement set the variable themselves.
@@ -371,25 +360,6 @@ def leak_check(request):
     assert not leaked_segs, (
         f"test leaked /dev/shm collective segment(s) (now removed): "
         f"{sorted(leaked_segs)}{notes}")
-    # compile-cache hygiene: a .ctmp-* file in the cache dir means a
-    # writer died between mkstemp and os.replace — name it so the
-    # failure reads as the torn cache write it is
-    from ray_tpu._private import compile_cache as _cc
-
-    try:
-        stray = [os.path.join(_cc.cache_dir(), f)
-                 for f in os.listdir(_cc.cache_dir())
-                 if f.startswith(_cc.TMP_PREFIX)]
-    except FileNotFoundError:
-        stray = []
-    for path in stray:
-        try:
-            os.unlink(path)
-        except OSError:
-            pass
-    assert not stray, (
-        f"test leaked compile-cache temp file(s) (now removed — a "
-        f"cache writer died mid-store): {sorted(stray)}")
     # continuous-profiler hygiene: with no cluster held, this process
     # must not keep a sampler thread alive (ray_tpu.shutdown stops it;
     # a test that armed one directly must stop it too). Named so the

@@ -193,8 +193,7 @@ _CACHE_PROBE = """
 from ray_tpu._private import compile_cache
 where = compile_cache.enable_persistent_cache()
 import jax
-print("RESULT", where, jax.config.jax_compilation_cache_dir,
-      compile_cache.cache_dir())
+print("RESULT", where, jax.config.jax_compilation_cache_dir)
 """
 
 
@@ -203,15 +202,12 @@ def test_compile_cache_placement(placed, tmp_path):
     """JAX_COMPILATION_CACHE_DIR set: the cache is there and no code
     sets another; unset: the fixed <repo>/.jax_cache."""
     env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_COMPILATION_CACHE_DIR",
-                        "RAY_TPU_COMPILE_CACHE_DIR")}
+           if k != "JAX_COMPILATION_CACHE_DIR"}
     env["PYTHONPATH"] = REPO
     if placed:
         env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
     out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
                          capture_output=True, text=True, timeout=120)
     want = str(tmp_path) if placed else os.path.join(REPO, ".jax_cache")
-    # the export cache keeps its own fixed place beside the default
-    export = os.path.join(REPO, ".jax_cache", "export")
-    assert f"RESULT {want} {want} {export}" in out.stdout, \
+    assert f"RESULT {want} {want}\n" in out.stdout, \
         out.stdout + out.stderr

@@ -504,7 +504,6 @@ def test_metric_name_drift_gate(ray_start_regular):
     import ray_tpu.serve.http_proxy   # noqa: F401
     import ray_tpu.serve.replica      # noqa: F401
     import ray_tpu.serve.router       # noqa: F401
-    from ray_tpu._private import compile_cache  # noqa: F401
     from ray_tpu._private import profiling  # noqa: F401
     from ray_tpu.collective import metrics as _cmetrics  # noqa: F401
     from ray_tpu.gcs import shard           # noqa: F401
