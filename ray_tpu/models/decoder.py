@@ -216,7 +216,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import (diffusion_tiles, flash_attention,
-                                   window_scores)
+                                   forward_tiles, window_scores)
 from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
                                      paired_heads)
 from ray_tpu.ops.layernorm import rmsnorm
@@ -1591,9 +1591,34 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     copy and the backward's once) and `window_scores_visited` (the
     entries of the tiles `flash_fwd` and `flash_bwd_fused` walk for
     them, from the kernels' own bounds: `ops.attention.window_scores`);
+    with a layer that calls `flash_attention` (`full`, `window`,
+    `latent`) `attention_tiles_walked` and `attention_tiles_unmasked`
+    (the score tiles `flash_fwd` walks a step and those of them it runs
+    with no mask, over sequences, heads, layer passes, the MTP block and
+    a rematerialised block's second forward:
+    `ops.attention.forward_tiles`, the runs the kernel follows);
     nothing otherwise."""
     b, t = batch_shape
     facts = {}
+    unmasked = walked = 0
+    layers = [a for a, _ in cfg.kinds + cfg.kinds[-1:] * cfg.mtp]
+    for kind in ATTENTION_KINDS + ("latent",):
+        if kind not in layers:
+            continue
+        heads, width = (cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim) \
+            if kind == "latent" else (cfg.heads_of(kind), cfg.head_dim)
+        plain, all_ = forward_tiles(
+            2 * t if cfg.diffusion_block else t, width, cfg.dtype,
+            cfg.attn_block_q, cfg.attn_block_k,
+            cfg.window if kind == "window" else None,
+            cfg.diffusion_block or None)
+        # a pass of a block; a rematerialised one runs it twice
+        planes = (b * heads * layers.count(kind) * cfg.loops
+                  * (1 + bool(cfg.remat)))
+        unmasked, walked = unmasked + planes * plain, walked + planes * all_
+    if walked:
+        facts.update(attention_tiles_unmasked=unmasked,
+                     attention_tiles_walked=walked)
     if cfg.loops > 1:
         facts.update(loops=cfg.loops, layer_passes=cfg.loops * cfg.n_layers,
                      head_passes=cfg.loops if cfg.exit_gate else 1)

@@ -28,6 +28,22 @@ T, head size and dtype; the table is in its docstring). Sequences no tile
 divides (T % 8) take one checkpointed dense block, forward and backward.
 On CPU (tests) the kernels run in interpret mode.
 
+Under a mask the forward's K loop is runs of key blocks (`_key_runs`,
+the one statement of the walk, traced in the kernel and numpy for what
+is static of a shape): the blocks every entry of which the mask keeps
+run with no mask, the blocks it cuts masked, in the order of the keys —
+(the window's far edge) -> whole -> diagonal under the causal mask;
+whole -> cut, then the noised blocks, under the block-diffusion mask.
+What is static of a run over ALL query blocks chooses its form: a run
+no query block has is not traced; a run every query block has a block
+of gives its last block to straight-line code after the loop; a run
+that can hold several blocks takes `_BLOCKS_AN_ITERATION` an iteration.
+The same products in the same order with the same float32 sums: every
+output and log-sum-exp is the one masked loop's, bit for bit (read on
+the chip at eleven shapes, PERF.md section 6, PR 60; `_fwd_tiles` has
+the table). The backward's Q loop is one masked loop still: cut the
+same way it gained 1.2-1.6 %.
+
 Two things beyond the plain causal kernel, both off by default and both
 static at trace time: a sliding `window` (query i sees keys j with
 0 <= i - j < window; the forward's K loop starts at the first block the
@@ -114,6 +130,10 @@ _FWD_KV_DEFAULT_SCOPE = 12 * 1024 * 1024
 # both and runs `flash_fwd` once a step (`models/transformer.py` does);
 # with no policy asking for them the names lower to nothing.
 SAVED_ACROSS_REMAT = ("flash_attention_out", "flash_attention_lse")
+# How many key blocks one iteration of a run's loop takes where a query
+# block can have that many (read on the chip, `_fwd_tiles`: 2, 3 and 4
+# lie within 2.5 % of each other and 3 is first at six shapes of eight)
+_BLOCKS_AN_ITERATION = 3
 
 
 def _diffusion_reach(row, half: int, block: int, where=jnp.where):
@@ -145,11 +165,16 @@ def _diffusion_key_blocks(qi, block_q: int, block_k: int, half: int,
     """The key blocks a query block's rows see part of: `[0, clean)`
     and `[first, last)` of the noised half (empty for a clean query
     block). The last row reaches furthest into the clean half; the
-    first and the last row's own blocks bound the noised keys."""
-    _, low = _diffusion_reach(qi * block_q, half, block, where)
+    first and the last row's own blocks bound the noised keys. Ahead of
+    the three, `whole`: the clean key blocks `[0, whole)` end at or
+    before what the block's FIRST row reaches, so every row sees every
+    key of them; `[whole, clean)` and the noised ones are cut by the
+    mask."""
+    reach, low = _diffusion_reach(qi * block_q, half, block, where)
     end, high = _diffusion_reach((qi + 1) * block_q - 1, half, block, where)
     noised = qi * block_q >= half
-    return ((end + block_k - 1) // block_k,
+    return (reach // block_k,
+            (end + block_k - 1) // block_k,
             where(noised, low // block_k, 0),
             where(noised, (high + block + block_k - 1) // block_k, 0))
 
@@ -175,9 +200,13 @@ def _diffusion_query_blocks(ki, block_q: int, block_k: int, half: int,
 def _causal_key_blocks(qi, block_q: int, block_k: int, num_k: int,
                        window: int | None, minimum=jnp.minimum,
                        maximum=jnp.maximum):
-    """(first, end) of the key blocks the forward's K loop walks for
-    query block `qi` under the causal mask and an optional window; `qi`
-    a traced scalar in the kernel, a numpy array in `window_scores`."""
+    """(first, whole, diagonal, end) of the key blocks the forward's K
+    loop walks for query block `qi` under the causal mask and an
+    optional window: `[first, end)` is the walk, and `[whole, diagonal)`
+    of it the blocks every entry of which the mask keeps; `[first,
+    whole)` is cut by the window's far edge (empty without one),
+    `[diagonal, end)` by the diagonal. `qi` a traced scalar in the
+    kernel, a numpy array in `_key_runs`' other callers."""
     # only scan K blocks at or before this Q block
     if block_q % block_k:
         # the block holding this Q block's last row, exactly
@@ -185,15 +214,44 @@ def _causal_key_blocks(qi, block_q: int, block_k: int, num_k: int,
     else:   # the expression this kernel always had, text for text
         last = (qi + 1) * block_q // block_k + (block_q % block_k != 0)
     num_k_active = minimum(num_k, last)
+    # the blocks whose last key is at or before the block's FIRST row
+    # lie wholly under the diagonal
+    diagonal = (qi * block_q + 1) // block_k
+    if window is None:
+        return 0, 0, diagonal, num_k_active
     # ... and, under a window, at or after the first block the
     # block's first query still reaches. A row whose keys all lie in
     # later blocks leaves that block with m = NEG_INF and l = the
     # block's width (exp(0) a masked score); its first real score
     # rescales both by exp(NEG_INF - s) = 0.0 exactly, and every row
     # has one (its own key), so o and m + log(l) are exact at the end
-    first = 0 if window is None else maximum(
-        0, qi * block_q - (window - 1)) // block_k
-    return first, num_k_active
+    first = maximum(0, qi * block_q - (window - 1)) // block_k
+    # a block is wholly inside the window when its first key is within
+    # the window of the block's LAST row (a window narrower than the two
+    # tile sides leaves none: `whole` stops at `diagonal`)
+    whole = minimum(diagonal, (maximum(
+        0, (qi + 1) * block_q - window) + block_k - 1) // block_k)
+    return first, whole, diagonal, num_k_active
+
+
+def _key_runs(qi, t: int, block_q: int, block_k: int, window: int | None,
+              diffusion: int | None, xp=jnp):
+    """The forward's walk for query block `qi` under a mask, said once:
+    runs `(start, stop, masked)` of key blocks in the order they are
+    walked. A run with `masked` False holds only blocks every entry of
+    which the mask keeps; a masked run's blocks are cut by it. `qi` and
+    `xp`: a traced scalar and `jnp` in the kernel; `np.arange` of the
+    query blocks and `np` for what is static of a shape (`forward_tiles`,
+    and the kernel's own choice of each run's form)."""
+    if diffusion is not None:
+        whole, clean, first, last = _diffusion_key_blocks(
+            qi, block_q, block_k, t // 2, diffusion, xp.where)
+        return ((0, whole, False), (whole, clean, True),
+                (first, last, True))
+    first, whole, diagonal, end = _causal_key_blocks(
+        qi, block_q, block_k, t // block_k, window, xp.minimum, xp.maximum)
+    return ((first, whole, True), (whole, diagonal, False),
+            (diagonal, end, True))
 
 
 def _causal_query_blocks(ki, block_q: int, block_k: int, last, causal: bool,
@@ -207,6 +265,32 @@ def _causal_query_blocks(ki, block_q: int, block_k: int, last, causal: bool,
         last = minimum(
             last, ((ki + 1) * block_k + window - 2) // block_q + 1)
     return first, last
+
+
+def _walk(tile, start, stop, carry, most: int, fewest: int):
+    """`tile(ki, carry)` over the key blocks `[start, stop)` of one run,
+    in order; `most` and `fewest`: the blocks any query block of the
+    shape has in this run (static), which choose the loop's form."""
+    # a run every query block has a block of: its last block comes
+    # after the loop, straight-line (measured: the loop's exit and the
+    # grid step's end are scheduled around it, `_fwd_tiles`)
+    peeled = int(fewest > 0)
+    stop, most = stop - peeled, most - peeled
+    if most > 1:
+        # several blocks an iteration, the odd ones after
+        n = min(_BLOCKS_AN_ITERATION, most)
+
+        def several(j, carry):
+            for i in range(n):
+                carry = tile(start + n * j + i, carry)
+            return carry
+
+        groups = (stop - start) // n
+        carry = jax.lax.fori_loop(0, groups, several, carry)
+        carry = jax.lax.fori_loop(start + n * groups, stop, tile, carry)
+    elif most:      # (a run no query block has a block of is not traced)
+        carry = jax.lax.fori_loop(start, stop, tile, carry)
+    return tile(stop, carry) if peeled else carry
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
@@ -223,19 +307,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
             qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0), t // 2, diffusion)
 
-    def body(ki, carry):
+    def body(ki, carry, masked: bool = True):
         o, m, l = carry
         k = k_ref[pl.ds(ki * block_k, block_k), :]  # [block_k, d]
         v = v_ref[pl.ds(ki * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if diffusion is not None:
+        if masked and diffusion is not None:
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(_diffusion_keep(k_pos, reach, diffusion), s,
                           NEG_INF)
-        elif causal:
+        elif masked and causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -254,25 +338,30 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         o_new = o * corr[:, None] + pv
         return o_new, m_new, l_new
 
-    o0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    num_k = t // block_k
-    if diffusion is not None:
-        # the clean key blocks the tile's last row reaches into, then
-        # the noised ones its rows' own blocks touch. A row that sees
-        # nothing in a block leaves it with m = NEG_INF, as under the
-        # window below, and every row sees its own key in the end
-        clean, first, last = _diffusion_key_blocks(
-            qi, block_q, block_k, t // 2, diffusion)
-        o, m, l = jax.lax.fori_loop(first, last, body, jax.lax.fori_loop(
-            0, clean, body, (o0, m0, l0)))
-    elif causal:
-        first, num_k_active = _causal_key_blocks(
-            qi, block_q, block_k, num_k, window)
-        o, m, l = jax.lax.fori_loop(first, num_k_active, body, (o0, m0, l0))
+    carry = (jnp.zeros((block_q, d), jnp.float32),
+             jnp.full((block_q,), NEG_INF, jnp.float32),
+             jnp.zeros((block_q,), jnp.float32))
+    if not causal:      # no mask: the one loop it always was
+        carry = jax.lax.fori_loop(0, t // block_k, body, carry)
     else:
-        o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
+        # the walk as runs of key blocks, in the order of the keys: the
+        # blocks the mask keeps whole run with no mask (no iota, no
+        # comparison, no select: where(True, s, NEG_INF) is s), the
+        # blocks it cuts as they always did. A row that sees nothing in
+        # a block leaves it with m = NEG_INF (`_causal_key_blocks`),
+        # and every row sees its own key in the end. Same products,
+        # same order, same float32 sums as one masked loop over them
+        # all: every output is that loop's, bit for bit
+        runs = _key_runs(qi, t, block_q, block_k, window, diffusion)
+        # ... and the same bounds for every query block at once: what
+        # is static of each run chooses its form
+        static = _key_runs(np.arange(t // block_q), t, block_q, block_k,
+                           window, diffusion, np)
+        for (start, stop, masked), (lo, hi, _) in zip(runs, static):
+            carry = _walk(functools.partial(body, masked=masked), start,
+                          stop, carry, int(np.max(hi - lo)),
+                          int(np.min(hi - lo)))
+    o, m, l = carry
     denom = jnp.where(l > 0, l, 1.0)
     o_ref[...] = (o / denom[:, None]).astype(o_ref.dtype)
     if lse_ref:
@@ -448,13 +537,38 @@ def block_diffusion_mask(t: int, block: int):
         rows[:, None], t // 2, block), block)
 
 
+def forward_tiles(t: int, d: int, dtype, block_q: int | None,
+                  block_k: int | None, window: int | None = None,
+                  diffusion: int | None = None) -> tuple[int, int]:
+    """Of one head's T x T score plane under the causal mask (with its
+    `window`, or the block-diffusion mask in its place): (the tiles
+    `flash_fwd` runs with no mask, the tiles it walks), from the runs
+    the kernel itself follows (`_key_runs`), reckoned in numpy. (0, 0)
+    for a shape the kernel refuses: the dense path has no tiles."""
+    block_q, block_k = fwd_tiles(t, d, dtype, block_q, block_k)
+    if not _flash_aligned(t, d, block_q, block_k) or (
+            diffusion is not None
+            and not _diffusion_tiled(t, block_q, block_k)):
+        return 0, 0
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    runs = [(int(np.sum(stop - start)), masked)
+            for start, stop, masked in _key_runs(
+                np.arange(t // block_q), t, block_q, block_k, window,
+                diffusion, np)]
+    return (sum(n for n, masked in runs if not masked),
+            sum(n for n, _ in runs))
+
+
 def diffusion_tiles(t: int, block: int, block_q: int, block_k: int
                     ) -> tuple[int, int]:
     """(score tiles the forward kernel's two K loops walk over one
     head's T x T plane, the plane's tiles), from the bounds the kernel
-    itself uses, reckoned in numpy."""
+    itself uses, reckoned in numpy. Of the walked ones the clean key
+    blocks that end before what a query block's first row reaches run
+    with no mask, the rest of the clean ones and every noised one
+    masked: `forward_tiles` counts the two apart."""
     block_q, block_k = min(block_q, t), min(block_k, t)
-    clean, first, last = _diffusion_key_blocks(
+    _, clean, first, last = _diffusion_key_blocks(
         np.arange(t // block_q), block_q, block_k, t // 2, block, np.where)
     return int((clean + last - first).sum()), (t // block_q) * (t // block_k)
 
@@ -467,14 +581,18 @@ def window_scores(t: int, window: int, d: int, dtype, block_q: int | None,
     from the bounds and the tiles the kernels themselves use, reckoned
     in numpy. A window of one tile side fills at best half of what is
     walked: every 512 keys seen lie across two tiles. A shape the
-    kernels refuse takes the dense path, which walks the plane."""
+    kernels refuse takes the dense path, which walks the plane. Of the
+    forward's tiles those between the window's far edge and the diagonal
+    run with no mask (`forward_tiles` counts them; none under a window
+    narrower than the two tile sides together), the two edges masked;
+    the backward masks every tile it walks."""
     seen = min(window, t)       # rows 0 .. seen - 1 see i + 1 keys
     inside = seen * (seen + 1) // 2 + (t - seen) * seen
     block_q, block_k = fwd_tiles(t, d, dtype, block_q, block_k)
     if not _flash_aligned(t, d, block_q, block_k):
         return inside, t * t, t * t
     block_q, block_k = min(block_q, t), min(block_k, t)
-    first, end = _causal_key_blocks(
+    first, _, _, end = _causal_key_blocks(
         np.arange(t // block_q), block_q, block_k, t // block_k, window,
         np.minimum, np.maximum)
     fwd = int((end - first).sum()) * block_q * block_k
@@ -737,6 +855,38 @@ def _fwd_tiles(t: int, d: int, dtype, d_v: int | None = None
     column's 0.6-0.7 ms above block_q 128 is the probe's own
     `lse.reshape(B, H, T)`; in a step whose backward tile is also 512
     both forwards read 2.32 ms a call (`gpt2s_epoch`'s trace).
+
+    The loop's form (PR 60, PERF.md section 6; the kernel alone by the
+    device trace's own events, bf16, writing the lse, ms a call; every
+    output bit for bit the one masked loop's). `runs`: a loop a run, the
+    blocks the mask keeps whole with no mask. `no mask at all`: the one
+    loop with the mask taken off EVERY tile (wrong values: the most the
+    mask can cost). `last straight`: the last block after the loop, as
+    straight-line code, every block masked / the whole ones not. `2`,
+    `3`, `4`: that, and as many blocks an iteration of the whole run:
+
+        [B,T,H|H_kv,D] mask, tiles      one    runs   no mask  last straight    2      3      4
+                                        loop          at all   masked / cut
+        [3,8192,28|4,128] w 4096       12.372 12.823  11.997   12.118 / 11.733 11.235 10.928 11.231
+        [1,8192,32,192|128] causal      7.027  7.276   7.132    6.903 /  6.928  6.401  6.266  6.243
+        [1,8192,32|4,128] diffusion 4   3.921  4.002   3.647    3.917 /  3.830  3.565  3.521  3.550
+        [2,4096,16,128] causal          1.615  1.699   1.616    1.526 /  1.519  1.450  1.428  1.450
+        [32,1024,12,64] causal, 512^2   2.281  2.567   2.296    1.943 /  1.939  1.932  1.932  1.932
+        [2,8192,64|8,128] w 512         7.436    -       -        -      -      6.563  6.563  6.563
+        [2,8192,16|2,256] causal        8.968    -       -        -      -      8.152  7.920  7.871
+        [9,4096,32|8,64] causal        15.012    -       -        -      -     13.106 13.001 13.250
+
+    (256 x 512 tiles but the fifth row; the tree as shipped, three an
+    iteration, in a later call: 10.825, 6.282, 3.527, 1.420, 1.939,
+    6.470, 7.936, 13.046, and [2,8192,48|8,128] causal 16.754 -> 13.994,
+    [2,8192,32|2,128] 11.170 -> 9.332, [8,1024,20,64] 0.950 -> 0.808:
+    10-16 % at eleven shapes). The mask is all but free here:
+    taken off every tile it gives 3 % under a window, 7 % under block
+    diffusion and nothing under the plain diagonal, and a loop a run
+    LOSES 2-13 % to its zero- and one-trip loops. What pays is the
+    loop's form: the last block out of the loop (1.4-15 %), and three
+    blocks an iteration (4-6 % more at 8k); the unmasked run adds 3
+    points under a window and 2 under block diffusion.
 
     A t that 128 does not divide (nor t itself, below 128) never
     reached the kernel: it answers 128 x 128, which `_flash_aligned`

@@ -128,8 +128,9 @@ def test_loop_bounds_cover_the_mask_and_no_more_than_they_say(
     tiles = keep.reshape(t // bq, bq, t // bk, bk).any((1, 3))
     walked = np.zeros_like(tiles)
     for qi in range(t // bq):
-        clean, first, last = (int(x) for x in attention._diffusion_key_blocks(
-            qi, bq, bk, length, block, np.where))
+        _, clean, first, last = (
+            int(x) for x in attention._diffusion_key_blocks(
+                qi, bq, bk, length, block, np.where))
         walked[qi, :clean] = True
         walked[qi, first:last] = True
         assert clean <= first or first == last == 0

@@ -302,15 +302,18 @@ def test_flash_attention_refuses_widths_that_do_not_pair():
 # sha256 of the jaxpr of value_and_grad(flash_attention) on the parent
 # commit (c636c2d), at SmallThinker-tiny's grouped, windowed shape and at
 # the plain one: with one width the two-width code traces to that text
+# (recorded again at PR 60, whose forward kernel walks its key blocks in
+# runs: the text moved inside `flash_fwd`'s body alone, the backward
+# kernel's is the parent's, `_flash_bwd_call` traced beside it)
 PARENT_JAXPR = {
-    "grouped-window": "c05dff12821ed4d69dcca4aeb4890f868754be1d557a756df515e4cfa564e7d9",
-    "plain": "ae53d69a3129a30973a4efac128227349180302c5a77d7712494206cd13445f1"}
+    "grouped-window": "1e108832d27aafcfc76cd0b17d1c3da9bb5cea9fcbd5f28e25ab6e7014623e9a",
+    "plain": "e4cfb78bd75bf10f1a5936537707cbafcd6d37d7f5fd20fc33e471656ec990ed"}
 # ... and with the two `name` equations `_fwd` gives the kernel's output
 # and log-sum-exp since PR 40: the text a trace has now. PARENT_JAXPR is
 # that text without them, so a change to the kernels still shows.
 NAMED_JAXPR = {
-    "grouped-window": "9218dd370a9e724d5dc31bcf81673301ed191629853f4dc3aeb5f36e783a2328",
-    "plain": "18c40e00b24971266a67ec6812d8d761812eb91a4f2b4e1779bedcdd175e36c0"}
+    "grouped-window": "326ea4b8d84264a839a5431c6aa1df4187da251c51d590e8390b31758dffd111",
+    "plain": "51c446b3c9c2edafff32cb62026c4788173ed7ef988c0c359f42ed295d61f6c2"}
 
 
 def _equal_widths_jaxpr(case):

@@ -114,7 +114,8 @@ def test_parameter_tree_state_and_facts():
             "attention_window": 24, "rope_scaling": "yarn:8",
             "window_scores_inside": 2 * 3 * 8 * 3 * inside}
     assert facts["window_scores_visited"] > facts["window_scores_inside"]
-    assert decoder.step_facts(decoder.TINY, (2, 64)) == {}
+    assert set(decoder.step_facts(decoder.TINY, (2, 64))) == {
+        "attention_tiles_unmasked", "attention_tiles_walked"}
 
 
 def test_the_cut_has_the_parameters_the_issue_counted():
@@ -296,19 +297,21 @@ def test_window_scores_are_the_kernels_own_walk(t, window, block_q,
 # forward and backward pass, on a batch [1, 1024] at those widths (its
 # lowered text, twice the time to make, was compared by hand: CHANGES.md
 # PR 55); and, at the configuration's tiny preset, of the bytes of
-# every leaf seeded from key 0.
+# every leaf seeded from key 0. (The step's text recorded again at
+# PR 60: its `flash_fwd` walks the key blocks in runs; trees and seeded
+# weights are the parent's still.)
 RECORDED = {
     "smallthinker_21b_ep4": ("smallthinker_tiny", "e1534e3b726776c9",
-                             "5f157920f9c64a82", "a5041fed5e97536c"),
-    "lfm2_8b_a1b_ep4": ("lfm2_tiny", "f133c9bbc8c0c233", "142f4837432fb01f",
+                             "449e137972a5aacf", "a5041fed5e97536c"),
+    "lfm2_8b_a1b_ep4": ("lfm2_tiny", "f133c9bbc8c0c233", "f36997273c4777ba",
                         "84ce2cd017577a97"),
     "joyai_flash_ep16": ("joyai_tiny", "46f5b9c1c77f0640",
-                         "d9c59a50bfa1be98", "de6619f6bb1da7bd"),
+                         "074cdf19c2ed916c", "de6619f6bb1da7bd"),
     "nemotron3_nano_ep16": ("nemotron_tiny", "5413d275d800de61",
-                            "2412baad9856cdb8", "6d44cb1c0e611553"),
-    "sdar_30b_a3b_ep8": ("sdar_tiny", "e2daba414fb236f7", "47d6fc955f12a58e",
+                            "b821959606038e7b", "6d44cb1c0e611553"),
+    "sdar_30b_a3b_ep8": ("sdar_tiny", "e2daba414fb236f7", "f1279d532c3c1b60",
                          "ff712b1188c4e882"),
-    "ouro_2_6b_d8": ("ouro_tiny", "48c171503f96b155", "392480b13d8f335c",
+    "ouro_2_6b_d8": ("ouro_tiny", "48c171503f96b155", "5d3edb7e5e8438f2",
                      "42e69148bd51e030"),
 }
 

@@ -111,10 +111,14 @@ def test_the_tree_and_state_are_the_families():
         *(f"{name}_{t}" for name in ("loop_nll", "exit_mass")
           for t in (1, 2, 3)), "exit_entropy", "loop_targets"}
     assert not any(name.startswith("moe_") for name in counters)
-    assert decoder.step_facts(cfg, (2, LENGTH)) == {
-        "loops": 3, "layer_passes": 6, "head_passes": 3}
+    facts = decoder.step_facts(cfg, (2, LENGTH))
+    # ... and the forward's score tiles, every layer pass's
+    assert facts.pop("attention_tiles_walked") % 3 == 0 \
+        and facts.pop("attention_tiles_unmasked") % 3 == 0
+    assert facts == {"loops": 3, "layer_passes": 6, "head_passes": 3}
     # the configurations from before the loop keep their trees and facts
-    assert decoder.step_facts(decoder.TINY, (2, 64)) == {}
+    assert set(decoder.step_facts(decoder.TINY, (2, 64))) == {
+        "attention_tiles_unmasked", "attention_tiles_walked"}
     assert set(decoder.counters_init(decoder.TINY)["epoch_counters"]) == {
         "moe_assignments", "moe_assignments_held", "moe_assignments_dropped",
         "moe_expert_tokens_max", "moe_expert_tokens_mean",

@@ -312,12 +312,15 @@ def test_window_and_grouped_gradient_is_two_kernels(window, kv_heads):
 # shapes: the forward kernel as it was before `window` and grouped heads
 # existed (commit 7f398b5), writing the row log-sum-exp beside its
 # output since PR 32, and that PR's backward kernel. Change it only with
-# a change MEANT to alter the kernels the GPT-2 cells run.
-PLAIN_JAXPR = "45053867e5bc47015ccbb83a40639f10dc9b1da77ccc23031e04f24da871a3c4"
+# a change MEANT to alter the kernels the GPT-2 cells run. PR 60 was
+# one: the forward's K loop became runs (the whole block with no mask,
+# the diagonal's straight-line after it); both texts recorded again,
+# the backward kernel's unchanged.
+PLAIN_JAXPR = "2fcaab48d8700ea87606d75f0d0e1083d03fb1a053459e7f852512d9ee63b09d"
 # ... and with the two `name` equations `_fwd` gives the kernel's output
 # and log-sum-exp since PR 40 (`attention.SAVED_ACROSS_REMAT`), which is
 # the text a trace has now; PLAIN_JAXPR is that text without them
-NAMED_JAXPR = "bec32480dfc7cea1a5b3f936967387c6754570a4e0312d6363a06d29ad02584c"
+NAMED_JAXPR = "7d371dde2c1ad8c5e5a8a1b42222062adcb187942f7b70502bffb73ae4af0bb1"
 
 
 def _plain_jaxpr(*extra):

@@ -109,8 +109,12 @@ def test_layers_hold_no_leaf_of_the_absent_side():
     dt = jax.nn.softplus(params["layers"]["dt_bias"])
     assert 1 <= a.min() and a.max() <= 16
     assert 1e-3 * 0.999 <= dt.min() and dt.max() <= 0.1 * 1.001
-    assert decoder.step_facts(cfg, (2, 64)) == {"ssm_layers": 3,
-                                                "ssm_chunks": 3 * 2 * 4}
+    # (one attention layer of four heads, forward and rematerialised:
+    # 2 sequences x 4 x 2 planes of 4 x 2 tiles of 16 x 32)
+    assert decoder.step_facts(cfg, (2, 64)) == {
+        "ssm_layers": 3, "ssm_chunks": 3 * 2 * 4,
+        "attention_tiles_unmasked": 2 * 4 * 2 * 2,
+        "attention_tiles_walked": 2 * 4 * 2 * 6}
 
 
 @pytest.mark.parametrize("change,message", [

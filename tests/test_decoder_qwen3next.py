@@ -119,7 +119,10 @@ def test_parameter_tree_state_and_facts():
             "attn_gate_sum_full", "attn_gate_count_full", "shared_gate_sum",
             "shared_gate_count", "moe_rows_static", "moe_rows_filled"} \
         <= set(state["epoch_counters"])
-    assert decoder.step_facts(cfg, (2, T)) == {
+    facts = decoder.step_facts(cfg, (2, T))
+    assert facts.pop("attention_tiles_walked") \
+        > facts.pop("attention_tiles_unmasked") > 0
+    assert facts == {
         "delta_layers": 3, "delta_chunks": 3 * 2 * (T // 64),
         "delta_heads": 4, "delta_heads_paired": 4,
         "attention_heads_full": 4, "rope_dim": 8}
@@ -127,7 +130,8 @@ def test_parameter_tree_state_and_facts():
     odd = decoder.step_facts(
         dataclasses.replace(cfg, delta_value_heads=6), (2, T))
     assert (odd["delta_heads"], odd["delta_heads_paired"]) == (6, 4)
-    assert decoder.step_facts(decoder.TINY, (2, 64)) == {}
+    assert set(decoder.step_facts(decoder.TINY, (2, 64))) == {
+        "attention_tiles_unmasked", "attention_tiles_walked"}
 
 
 def test_the_cut_has_the_parameters_the_issue_counted():
@@ -159,6 +163,10 @@ def test_the_cut_has_the_parameters_the_issue_counted():
         and shapes["layers"]["wq_full"].shape == (1, 2048, 8192) \
         and shapes["layers"]["w_gate"].shape == (4, 32, 2048, 512)
     facts = decoder.step_facts(cfg, (2, 8192))
+    # one attention layer, its forward twice (`remat`): 2 sequences x 16
+    # heads x 2 planes of 32 x 16 tiles of 256 x 512 under the diagonal
+    assert (facts.pop("attention_tiles_unmasked"),
+            facts.pop("attention_tiles_walked")) == (64 * 240, 64 * 272)
     assert facts == {"delta_layers": 3, "delta_chunks": 3 * 2 * 128,
                      "delta_heads": 32, "delta_heads_paired": 32,
                      "attention_heads_full": 16,
@@ -317,9 +325,10 @@ def test_the_rotary_turns_a_quarter_of_a_head():
 # published widths; of the jaxpr of value_and_grad(stateful_loss), the
 # step's forward and backward pass, on a batch [1, 1024] at those
 # widths; and, at the configuration's tiny preset, of the bytes of every
-# leaf seeded from key 0.
+# leaf seeded from key 0. (The step's text recorded again at PR 60, as
+# that file's were: `flash_fwd`'s K loop in runs.)
 RECORDED = {
-    "laguna_xs2_d5": ("laguna_tiny", "adb7c9f1e7c37eee", "bf7c1a76403e3a51",
+    "laguna_xs2_d5": ("laguna_tiny", "adb7c9f1e7c37eee", "3a75b66fb28f7435",
                       "7cb46020d32fa542"),
 }
 

@@ -229,11 +229,17 @@ def test_stateful_loss_moves_the_step_and_counts_the_noise():
         == 3 * cfg.n_layers * 2 * tokens.size * cfg.top_k
     assert float(counters["moe_assignments_dropped"]) == 0
     facts = decoder.step_facts(cfg, tokens.shape)
+    # (the kernel's tiles a step, over sequences, heads, layers and the
+    # rematerialised forward: `attention_tiles_visited` a plane)
+    planes = tokens.shape[0] * cfg.n_heads * cfg.n_layers * 2
+    assert facts.pop("attention_tiles_walked") == planes * 6
+    assert 0 <= facts.pop("attention_tiles_unmasked") < planes * 6
     assert facts == {"diffusion_block": BLOCK,
                      "diffusion_rows": 2 * tokens.size,
                      "attention_tiles_visited": 6,
                      "attention_tiles_plane": 8}
-    assert decoder.step_facts(decoder.TINY, (2, 64)) == {}
+    assert set(decoder.step_facts(decoder.TINY, (2, 64))) == {
+        "attention_tiles_unmasked", "attention_tiles_walked"}
 
 
 def test_what_the_objective_is_not_built_for_is_refused():
