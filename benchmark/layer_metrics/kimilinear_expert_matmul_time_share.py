@@ -1,0 +1,9 @@
+"""Experts: device time in the grouped expert matmuls (``moe_gmm.N``,
+``moe_gmm_dx.N``, ``moe_gmm_dw.N``) over device busy time, in the
+traced steps: ``expert_matmul_time_share``'s reading, under a name of
+its own because that metric's entry lists its cells. 8 held experts of
+width 1024 on four of the five layers, eight assignments a token of
+which a thirty-second land here: what
+``kimilinear_expert_matmul_roofline_share`` has to be weighed against."""
+
+from benchmark.layer_metrics.expert_matmul_time_share import read  # noqa: F401

@@ -43,17 +43,22 @@ D7). The kinds built so far:
   and values.
 - mixer `"latent"`: causal attention over low-rank latents (MLA, as
   trained: nothing is absorbed). The query is two products with an
-  RMSNorm of `q_lora_rank` between them; ONE product gives the
+  RMSNorm of `q_lora_rank` between them — or, with `q_lora_rank` 0 (no
+  query rank), ONE direct product (a leaf `wq_latent` `[d_model, heads
+  x 192]`; no `wq_a`, `q_a_norm`, `wq_b`); ONE product gives the
   compressed keys-and-values (`kv_lora_rank`, normed) and a rotary key
   of `qk_rope_dim` that every head shares; the latent's up-projection
   gives each head a key part without positions (`qk_nope_dim`) and a
   value (`v_head_dim`). A head's query and key are `[nope | rope]`,
   `qk_nope_dim + qk_rope_dim` wide (192), its value and output
   `v_head_dim` (128): `ops.flash_attention` with two widths, scale
-  1/sqrt(192). The rotary turn is on the rope part only, always, with
+  1/sqrt(192). The rotary turn is on the rope part only, with
   INTERLEAVED pairing (dimensions 2i and 2i + 1 turn together: the
   kind's own, as rotate-half is the other kinds'); the shared key is
-  turned once and broadcast over the heads.
+  turned once and broadcast over the heads. The kind turns unless
+  `cfg.by_kind` names it with a turned width of 0 (Kimi Linear's
+  `mla_use_nope`: the 64 shared dimensions then carry no position and
+  no table is made for them).
 - mixer `"ssm"`: a Mamba-2 state-space mixer (arXiv:2405.21060). ONE
   input projection to `[z | xBC | dt]` (`ssm_heads * ssm_head_dim` |
   that + 2 `ssm_groups * ssm_state` | `ssm_heads`; the leaf `ssm_in` is
@@ -88,6 +93,26 @@ D7). The kinds built so far:
   plain weight `delta_norm` at one whatever `cfg.norm_plus_one` says,
   float32 — and an output projection `delta_out`. Its state is that
   matrix a head and `conv_taps - 1` rows, whatever the length.
+- mixer `"kda"`: Kimi Delta Attention (arXiv:2510.26692), the delta
+  rule with a decay a CHANNEL of the key. ONE product `[q | k | v] = x
+  W` (`kda_in`: `delta_key_heads * delta_key_dim` twice, then `*
+  delta_value_dim`; key and value heads are equal), a depthwise causal
+  convolution of `conv_taps` taps WITHOUT bias and a SiLU over it; q
+  and k L2-normalised a head, q times `1 / sqrt(key dim)`; ONE product
+  down to two low ranks of the key dim each (`kda_down`, `[f | g]`),
+  and from them up: `g = -exp(A_log)[h] * softplus(f W_f + dt_bias)` a
+  head and key CHANNEL (`kda_f_up`, `kda_A_log` a head, `kda_dt_bias` a
+  channel), float32, and the output gate `sigmoid(g W_g)` a value
+  channel (`kda_g_up`); `beta = sigmoid(x W_beta)` a head (`kda_beta`,
+  kept `[heads, d_model]` as `delta_ba` is); the rule of `ops/kda.py` in
+  chunks of 64 (`S' = Diag(exp(g_t)) S_{t-1}`, then the delta rule's
+  write and read; a `[key dim, value dim]` float32 state a head, from
+  zero at position 0); then `RMSNorm(o) * w_n * sigmoid(gate)` a head —
+  the norm BEFORE the gate, a plain weight `kda_norm` at one — and an
+  output projection `kda_out`. Its parts run under the scopes
+  `kda_projections`, `kda_conv`, `kda_rule`, `kda_gate_norm` inside
+  `mixer_kda`. Its state is that matrix a head and `conv_taps - 1`
+  rows, whatever the length.
 - mixer `"none"` / MLP `"none"`: the layer is the other part alone — a
   model whose blocks are each a mixer OR a feed-forward part with one
   norm reads as such layers. It holds no leaf of the absent side (one
@@ -199,9 +224,9 @@ traced and a configuration's program is what it was.
 
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
 scores, the selection bias, the head norms, the exit gate, the
-attention's output gate, the shared expert's gate, the delta rule's
-decay, write strength, L2 norms, state and gated norm, and every norm's
-statistics are float32.
+attention's output gate, the shared expert's gate, the delta rules'
+(`delta`, `kda`) decay, write strength, L2 norms, state and gated norm,
+and every norm's statistics are float32.
 """
 
 from __future__ import annotations
@@ -219,6 +244,7 @@ from ray_tpu.ops.attention import (diffusion_tiles, flash_attention,
                                    forward_tiles, window_scores)
 from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
                                      paired_heads)
+from ray_tpu.ops.kda import kda
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
@@ -226,7 +252,9 @@ from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
 
 ATTENTION_KINDS = ("full", "window")
-MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent", "ssm", "delta", "none")
+MIXER_KINDS = ATTENTION_KINDS + ("conv", "latent", "ssm", "delta", "none",
+                                 "kda")
+RECURRENT_KINDS = ("ssm", "delta", "kda")   # carry a state, count a decay
 MLP_KINDS = ("experts", "dense", "none")
 ROUTER_INPUTS = ("mixer", "mlp")
 ATTN_GATES = ("", "head", "element")
@@ -235,7 +263,7 @@ ATTN_GATES = ("", "head", "element")
 # group "layer": every layer; groups "mixer" and "mlp" (the two norms'
 # where some layer is one part alone): those that have that part
 _GROUP = {"full": "attention", "window": "attention", "conv": "conv",
-          "latent": "latent", "ssm": "ssm", "delta": "delta",
+          "latent": "latent", "ssm": "ssm", "delta": "delta", "kda": "kda",
           "experts": "experts",
           "dense": "dense"}
 
@@ -306,7 +334,8 @@ class DecoderConfig:
     d_dense: int = 0
     conv_taps: int = 3
     tied_head: bool = False
-    q_lora_rank: int = 0              # the latent mixer's ranks and widths
+    q_lora_rank: int = 0              # the latent mixer's ranks (0: no query
+    #                                   rank, one direct product) and widths
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0              # a head's key part without positions
     qk_rope_dim: int = 0              # the rotary part; ONE key for all heads
@@ -338,7 +367,8 @@ class DecoderConfig:
     by_kind: tuple[tuple[str, AttentionKind], ...] = ()   # head count and
     #                                   rotary rule by attention kind; then
     #                                   n_heads, rope_theta and rotary are
-    #                                   not read for those layers
+    #                                   not read for those layers; "latent"
+    #                                   with rope_dim 0: it turns nothing
     attn_gate: str = ""               # a sigmoid gate on the attention's
     #                                   output, before wo: "head" (a leaf
     #                                   `wg`, a value a head) or "element"
@@ -351,7 +381,9 @@ class DecoderConfig:
     delta_key_heads: int = 0          # the delta mixer: key heads of
     delta_value_heads: int = 0        # ... delta_key_dim, value heads of
     delta_key_dim: int = 0            # ... delta_value_dim (a multiple of
-    delta_value_dim: int = 0          # ... the key heads); conv_taps taps
+    delta_value_dim: int = 0          # ... the key heads); conv_taps taps;
+    #                                   the kda mixer reads the same four
+    #                                   (as many value heads as key heads)
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -368,7 +400,8 @@ class DecoderConfig:
             raise ValueError(
                 f"layer kinds built so far: mixer {MIXER_KINDS} (rotary "
                 f"and qk_norm list attention kinds: {ATTENTION_KINDS}; "
-                f"the latent mixer turns its rope part itself), "
+                f"the latent mixer turns its rope part unless by_kind "
+                f"names it with rope_dim 0), "
                 f"mlp {MLP_KINDS}; \"none\" on one side only")
         if any(pair == ("none", "none") or (
                 pair == ("none", "experts") and self.router_input == "mixer")
@@ -386,11 +419,13 @@ class DecoderConfig:
                 f"ssm_head_dim, ssm_groups and ssm_state: got {ssm}")
         latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
                   self.qk_rope_dim, self.v_head_dim)
-        if "latent" in mixers and (min(latent) < 1 or self.qk_rope_dim % 2):
+        if "latent" in mixers and (min(latent[1:]) < 1 or latent[0] < 0
+                                   or self.qk_rope_dim % 2):
             raise ValueError(
-                "the latent mixer needs q_lora_rank, kv_lora_rank, "
-                "qk_nope_dim, qk_rope_dim (even) and v_head_dim: got "
-                f"{latent}")
+                "the latent mixer needs kv_lora_rank, qk_nope_dim, "
+                "qk_rope_dim (even) and v_head_dim, and q_lora_rank or 0 "
+                "for no query rank (one direct product, a leaf "
+                f"`wq_latent`): got {latent}")
         if self.diffusion_block and (
                 self.diffusion_block < 0 or self.mtp
                 or not mixers <= {"full", "none"}):
@@ -418,6 +453,15 @@ class DecoderConfig:
                 "outputs (n_experts), top_k, d_expert and the (first, "
                 "count) it holds; a dense pattern (no \"experts\" layer) "
                 "leaves all four out")
+        if "kda" in mixers and (
+                min(self.delta_key_heads, self.delta_key_dim,
+                    self.delta_value_dim) < 1
+                or self.delta_value_heads != self.delta_key_heads):
+            raise ValueError(
+                "the kda mixer needs delta_key_heads, as many "
+                "delta_value_heads, delta_key_dim and delta_value_dim: got "
+                f"{self.delta_key_heads}, {self.delta_value_heads}, "
+                f"{self.delta_key_dim}, {self.delta_value_dim}")
         delta = (self.delta_key_heads, self.delta_value_heads,
                  self.delta_key_dim, self.delta_value_dim)
         if "delta" in mixers and (
@@ -444,19 +488,19 @@ class DecoderConfig:
                 '"parts" (its mixer and its MLP each; the router then '
                 f'reads the MLP\'s norm): got {self.remat!r}')
         if self.mtp and (self.norm_plus_one or self.shared_gate
-                         or "delta" in mixers):
+                         or mixers & {"delta", "kda"}):
             raise ValueError(
                 "the MTP block is not built beside norm_plus_one, "
-                "shared_gate or the delta mixer")
+                "shared_gate or the delta and kda mixers")
         if self.loops < 1 or (self.loops > 1 and (
-                self.moe_layers or mixers & {"ssm", "delta"} or self.mtp
+                self.moe_layers or mixers & set(RECURRENT_KINDS) or self.mtp
                 or self.diffusion_block)):
             raise ValueError(
                 f"loops is the walks of the stack, 1 or more (got "
                 f"{self.loops}); a stack walked more than once is built for "
                 "layers that count nothing (no \"experts\" MLP, no "
-                "\"ssm\" or \"delta\" mixer), without an MTP block or block "
-                "diffusion")
+                f"mixer of {RECURRENT_KINDS}), without an MTP block or "
+                "block diffusion")
         if self.exit_gate and self.loops < 2:
             raise ValueError(
                 "exit_gate (the exit distribution over the walks) needs "
@@ -466,9 +510,14 @@ class DecoderConfig:
                 "the sandwich norm reads ONE output of the MLP: not built "
                 "beside a shared expert (d_shared)")
         named = tuple(kind for kind, _ in self.by_kind)
+        rules = dict(self.by_kind)
         if self.by_kind and (
-                "latent" in mixers or len(set(named)) != len(named)
-                or set(named) != mixers & set(ATTENTION_KINDS)
+                len(set(named)) != len(named)
+                or set(named) - {"latent"} != mixers & set(ATTENTION_KINDS)
+                or ("latent" in mixers) != ("latent" in rules)
+                or ("latent" in rules and (
+                    rules["latent"].rope_dim
+                    or rules["latent"].n_heads != self.n_heads))
                 or any(rule.n_heads % self.n_kv_heads or rule.rope_dim % 2
                        or not 0 <= rule.rope_dim <= self.head_dim
                        for _, rule in self.by_kind)):
@@ -477,8 +526,10 @@ class DecoderConfig:
                 f"(got {named} for {sorted(mixers & set(ATTENTION_KINDS))})"
                 f", its heads a multiple of the {self.n_kv_heads} key/value "
                 f"heads, its turned width even and at most a head's "
-                f"{self.head_dim}; not built beside the latent mixer, which "
-                "turns its rope part itself")
+                f"{self.head_dim}; beside the latent mixer it names "
+                "\"latent\" too, with n_heads and rope_dim 0: a latent "
+                "mixer under by_kind turns nothing (without by_kind it "
+                "turns its rope part)")
         if self.attn_gate and (self.mtp or self.loops > 1
                                or not mixers & set(ATTENTION_KINDS)):
             raise ValueError(
@@ -565,11 +616,16 @@ def _leaves(cfg: DecoderConfig) -> dict:
                          k_norm=("attention", (hd,), one))
     if "latent" in groups:
         h, rope = cfg.n_heads, cfg.qk_rope_dim
+        if cfg.q_lora_rank:
+            table.update(
+                wq_a=("latent", (d, cfg.q_lora_rank), "normal"),
+                q_a_norm=("latent", (cfg.q_lora_rank,), "one"),
+                wq_b=("latent", (cfg.q_lora_rank,
+                                 h * (cfg.qk_nope_dim + rope)), "normal"))
+        else:   # no query rank: one direct product
+            table["wq_latent"] = (
+                "latent", (d, h * (cfg.qk_nope_dim + rope)), "normal")
         table.update(
-            wq_a=("latent", (d, cfg.q_lora_rank), "normal"),
-            q_a_norm=("latent", (cfg.q_lora_rank,), "one"),
-            wq_b=("latent", (cfg.q_lora_rank, h * (cfg.qk_nope_dim + rope)),
-                  "normal"),
             wkv_a=("latent", (d, cfg.kv_lora_rank + rope), "normal"),
             kv_a_norm=("latent", (cfg.kv_lora_rank,), "one"),
             wkv_b=("latent", (cfg.kv_lora_rank,
@@ -605,6 +661,22 @@ def _leaves(cfg: DecoderConfig) -> dict:
             delta_dt_bias=("delta", (cfg.delta_value_heads,), "one"),
             delta_norm=("delta", (cfg.delta_value_dim,), "one"),
             delta_out=("delta", (values, d), "normal"))
+    if "kda" in groups:
+        heads, rank = cfg.delta_key_heads, cfg.delta_key_dim
+        keys, values = heads * cfg.delta_key_dim, heads * cfg.delta_value_dim
+        table.update(
+            kda_in=("kda", (d, 2 * keys + values), "normal"),   # [q | k | v]
+            kda_conv=("kda", (cfg.conv_taps, 2 * keys + values), "taps"),
+            # down to the two low ranks, [decay | gate]; beta kept
+            # [heads, d_model] as delta_ba is
+            kda_down=("kda", (d, 2 * rank), "normal"),
+            kda_f_up=("kda", (rank, keys), "normal"),
+            kda_g_up=("kda", (rank, values), "normal"),
+            kda_beta=("kda", (heads, d), "normal"),
+            kda_A_log=("kda", (heads,), "A_log"),
+            kda_dt_bias=("kda", (keys,), "dt_bias"),
+            kda_norm=("kda", (cfg.delta_value_dim,), "one"),
+            kda_out=("kda", (values, d), "normal"))
     if "experts" in groups:
         table.update(router=("experts", (d, cfg.n_experts), "normal"),
                      w_gate=("experts", (count, d, f), "normal"),
@@ -654,7 +726,9 @@ _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "wg_full", "wq_window", "wo_window", "wg_window", "delta_in",
           "delta_ba", "delta_conv", "delta_A_log", "delta_out",
           "ws_token_gate", "norm1", "norm2", "q_norm", "k_norm", "norm_f",
-          "norm1_post", "norm2_post")
+          "norm1_post", "norm2_post", "wq_latent", "kda_in", "kda_conv",
+          "kda_down", "kda_f_up", "kda_g_up", "kda_beta", "kda_A_log",
+          "kda_dt_bias", "kda_out")
 _MTP_KEY = 1 << 16
 _NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
@@ -678,8 +752,12 @@ def init(key, cfg: DecoderConfig):
     have it, experts on axis 1 (the held ones only); the exit gate's
     weight normal(0, init_std) as a matrix is, its bias zero; the delta
     mixer's `delta_A_log` the log of a draw in (0, 16], its
-    `delta_dt_bias` and `delta_norm` one; under `cfg.norm_plus_one` the
-    (1 + w) norms' w normal(0, init_std)."""
+    `delta_dt_bias` and `delta_norm` one; the kda mixer's `kda_A_log`
+    and `kda_dt_bias` drawn as the ssm mixer's are (A in [1, 16] a head,
+    softplus(dt_bias) log-uniform in `cfg.ssm_dt_range` a channel: a
+    head's channels forget over a handful to thousands of positions),
+    its `kda_norm` one; under `cfg.norm_plus_one` the (1 + w) norms' w
+    normal(0, init_std)."""
     keys = list(jax.random.split(key, 12))
     later = dict(zip(_LATER, jax.random.split(keys[10], len(_LATER))))
 
@@ -832,15 +910,22 @@ def _deinterleave(w, lead: int, dim: int):
 
 def _latent_attention(x, p, rope, cfg: DecoderConfig):
     """The latent mixer on the first norm's output x [B, T, D] -> the
-    mixer's part of the residual [B, T, D]."""
+    mixer's part of the residual [B, T, D]. `rope`: the table its rope
+    part turns by, or None where it turns nothing."""
     b, t, _ = x.shape
     h, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     r_kv, dv = cfg.kv_lora_rank, cfg.v_head_dim
     cast = functools.partial(jnp.asarray, dtype=x.dtype)
-    wq_b = _deinterleave(cast(p["wq_b"]), nope, rot)
-    wkv_a = _deinterleave(cast(p["wkv_a"]), r_kv, rot)
-    c_q = rmsnorm(x @ cast(p["wq_a"]), cast(p["q_a_norm"]), cfg.rms_eps)
-    q = (c_q @ wq_b).reshape(b, t, h, nope + rot)
+
+    def paired(w, lead):    # a weight's rope columns, where they turn
+        return _deinterleave(cast(w), lead, rot) if rope else cast(w)
+
+    # no query rank: one direct product
+    wq = paired(p["wq_b" if cfg.q_lora_rank else "wq_latent"], nope)
+    wkv_a = paired(p["wkv_a"], r_kv)
+    c_q = rmsnorm(x @ cast(p["wq_a"]), cast(p["q_a_norm"]), cfg.rms_eps) \
+        if cfg.q_lora_rank else x
+    q = (c_q @ wq).reshape(b, t, h, nope + rot)
     kv_a = x @ wkv_a                       # [c_kv | the shared rotary key]
     c_kv = rmsnorm(kv_a[..., :r_kv], cast(p["kv_a_norm"]), cfg.rms_eps)
     # the up-projection's columns are a head's [k_nope | v]: two
@@ -850,8 +935,10 @@ def _latent_attention(x, p, rope, cfg: DecoderConfig):
         b, t, h, nope)
     v = (c_kv @ wkv_b[:, :, nope:].reshape(r_kv, h * dv)).reshape(
         b, t, h, dv)
-    k_rope = _rope(kv_a[..., r_kv:].reshape(b, t, 1, rot), *rope)
-    q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], *rope)], -1)
+    k_rope = kv_a[..., r_kv:].reshape(b, t, 1, rot)
+    if rope:
+        k_rope = _rope(k_rope, *rope)
+        q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], *rope)], -1)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, (b, t, h, rot))], -1)
     # 192-wide q and k, 128-wide v and o; scale 1 / sqrt(192)
@@ -946,6 +1033,54 @@ def _delta_mixer(x, p, cfg: DecoderConfig):
     return gated @ cast(p["delta_out"]), stats
 
 
+def _kda_mixer(x, p, cfg: DecoderConfig):
+    """The Kimi-Delta-Attention mixer on the first norm's output x [B,
+    T, D] -> (its part of the residual [B, T, D], {the least sum of the
+    log decay over a chunk, a chunk's sum's spread over a head's
+    channels summed, the write strengths and the output gates summed}:
+    what the counters keep)."""
+    b, t, _ = x.shape
+    h, dk, dv = cfg.delta_key_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    keys, values = h * dk, h * dv
+    cast = functools.partial(jnp.asarray, dtype=x.dtype)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    with jax.named_scope("kda_projections"):
+        qkv = x @ cast(p["kda_in"])
+        low = x @ cast(p["kda_down"])               # [decay | gate] ranks
+        decay_in = jnp.dot(low[..., :dk], cast(p["kda_f_up"]),
+                           preferred_element_type=jnp.float32)
+        gate_in = jnp.dot(low[..., dk:], cast(p["kda_g_up"]),
+                          preferred_element_type=jnp.float32)
+        beta = jax.nn.sigmoid(jnp.dot(x, cast(p["kda_beta"]).T,
+                                      preferred_element_type=jnp.float32))
+        # a head's A times a channel's softplus, float32, <= 0
+        g = -jnp.exp(p["kda_A_log"])[:, None] * jax.nn.softplus(
+            decay_in + p["kda_dt_bias"]).reshape(b, t, h, dk)
+    with jax.named_scope("kda_conv"):   # over [q | k | v], no bias, SiLU
+        qkv = jax.nn.silu(_causal_conv(qkv, p["kda_conv"]))
+
+        def unit(z):        # L2-normalised a head, float32
+            z = z.reshape(b, t, h, dk)
+            return z * lax.rsqrt((z * z).sum(-1, keepdims=True) + 1e-6)
+
+        q = (unit(qkv[..., :keys]) * dk ** -0.5).astype(x.dtype)
+        k = unit(qkv[..., keys:2 * keys]).astype(x.dtype)
+        v = qkv[..., 2 * keys:].astype(x.dtype).reshape(b, t, h, dv)
+    with jax.named_scope("kda_rule"):
+        o = kda(q, k, v, g, beta)
+    with jax.named_scope("kda_gate_norm"):
+        # the norm BEFORE the gate, a plain weight; statistics over a head
+        gate = jax.nn.sigmoid(gate_in).reshape(b, t, h, dv)
+        gated = (_head_norm(f32(o), p["kda_norm"], cfg.rms_eps)
+                 * gate).reshape(b, t, values).astype(x.dtype)
+    total = g.reshape(b, t // DELTA_CHUNK, DELTA_CHUNK, h, dk).sum(2)
+    stats = lax.stop_gradient({
+        "kda_log_decay_min": total.min(),
+        "kda_decay_spread_sum": (total.max(-1) - total.min(-1)).sum(),
+        "kda_beta_sum": beta.sum(), "kda_gate_sum": gate.sum()})
+    return gated @ cast(p["kda_out"]), stats
+
+
 def _mlp(y, p, cfg: DecoderConfig, up: str, down: str, gate: str):
     """W_down (act(W_gate y) * (W_up y)), or W_down act(W_up y) where
     the configuration is ungated."""
@@ -994,13 +1129,18 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
         with jax.named_scope("mixer_delta"):
             y, stats = _delta_mixer(x, p, cfg)
             h, found = joined(h, y, "norm1_post"), {**found, **stats}
+    elif attention == "kda":
+        with jax.named_scope("mixer_kda"):
+            y, stats = _kda_mixer(x, p, cfg)
+            h, found = joined(h, y, "norm1_post"), {**found, **stats}
     elif attention == "conv":
         with jax.named_scope("mixer_conv"):
             y = short_conv(x @ cast(p["conv_in"]), p["conv_taps"])
             h = joined(h, y @ cast(p["conv_out"]), "norm1_post")
     elif attention == "latent":
         with jax.named_scope("attention_latent"):
-            h = joined(h, _latent_attention(x, p, rope, cfg), "norm1_post")
+            table = rope.get("latent") if cfg.by_kind else rope
+            h = joined(h, _latent_attention(x, p, table, cfg), "norm1_post")
     elif attention != "none":
         with jax.named_scope("attention_" + attention):
             heads = cfg.heads_of(attention)
@@ -1128,7 +1268,9 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     routing has one (the MTP block's row, the last, is not read here).
     With an ssm mixer the counts also hold `ssm_log_decay_min` and
     `ssm_dt_max`, scalars over all its layers; with a delta mixer
-    `delta_log_decay_min` (a scalar) and `delta_beta_sum` a layer.
+    `delta_log_decay_min` (a scalar) and `delta_beta_sum` a layer; with
+    a kda mixer `kda_log_decay_min` (a scalar) and `kda_decay_spread_sum`,
+    `kda_beta_sum`, `kda_gate_sum` a layer.
 
     With `cfg.loops` = T > 1 the same layers are walked T times, the
     final norm after EVERY walk, its output the next walk's input and
@@ -1198,9 +1340,9 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
         if "ssm_dt_max" in counts:      # scalars over the layers that have one
             counts.update(ssm_log_decay_min=counts["ssm_log_decay_min"].min(),
                           ssm_dt_max=counts["ssm_dt_max"].max())
-        if "delta_log_decay_min" in counts:
-            counts["delta_log_decay_min"] = \
-                counts["delta_log_decay_min"].min()
+        for least in ("delta_log_decay_min", "kda_log_decay_min"):
+            if least in counts:
+                counts[least] = counts[least].min()
         return h, counts
 
     if cfg.loops == 1:
@@ -1514,6 +1656,15 @@ def counters_init(cfg: DecoderConfig):
     epoch: what the kernels' masked exponent has to survive),
     `delta_beta_sum` / `delta_beta_count` (the write strengths summed
     over tokens, value heads, delta layers and steps, and how many).
+    With a kda mixer, under names of its own: `kda_log_decay_min` (the
+    least sum of the log decay over one chunk that any CHANNEL of any
+    head and layer saw), `kda_decay_spread_sum` / `_count` (a chunk's
+    sum of the log decay, its largest over a head's channels minus its
+    least, summed over chunks, heads, kda layers and steps, and how
+    many: what a decay a head cannot have; zero would mean the channels
+    forget alike), `kda_beta_sum` / `_count` (the write strengths, over
+    tokens, heads, layers and steps) and `kda_gate_sum` / `_count` (the
+    output gates sigmoid(.), over tokens and value channels too).
     Under `cfg.shared_gate`: `shared_gate_sum` / `shared_gate_count`
     (the shared expert's gates over tokens, expert layers and steps).
     The configurations from before each of these keep the state tree
@@ -1549,12 +1700,21 @@ def counters_init(cfg: DecoderConfig):
                         delta_beta_count=f32())
     if cfg.shared_gate:
         counters.update(shared_gate_sum=f32(), shared_gate_count=f32())
+    if _kda_layers(cfg):
+        counters.update(kda_log_decay_min=f32(), **{
+            f"kda_{name}_{what}": f32() for what in ("sum", "count")
+            for name in ("decay_spread", "beta", "gate")})
     return {"epoch_counters": counters}
 
 
 def _delta_layers(cfg: DecoderConfig) -> int:
     """The layers whose mixer is the delta rule."""
     return sum(a == "delta" for a, _ in cfg.kinds)
+
+
+def _kda_layers(cfg: DecoderConfig) -> int:
+    """The layers whose mixer is the per-channel delta rule."""
+    return sum(a == "kda" for a, _ in cfg.kinds)
 
 
 def _attention_layers(cfg: DecoderConfig) -> dict:
@@ -1572,7 +1732,9 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     (the same product at the rule's chunk of 64), `delta_heads` (the
     value heads that walk each) and `delta_heads_paired` (those of them
     whose chunk inverse runs two to a product:
-    `ops.gated_delta.paired_heads` a key head); under block diffusion
+    `ops.gated_delta.paired_heads` a key head); with a kda mixer
+    `kda_layers`, `kda_chunks` and `kda_heads`, the same under names of
+    its own; under block diffusion
     `diffusion_block`, `diffusion_rows` (rows through the blocks a step:
     B x 2 L) and
     `attention_tiles_visited` / `attention_tiles_plane` (the score tiles
@@ -1634,6 +1796,11 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
                      delta_heads_paired=cfg.delta_key_heads * paired_heads(
                          cfg.delta_value_heads // cfg.delta_key_heads,
                          DELTA_CHUNK))
+    layers = _kda_layers(cfg)
+    if layers:
+        facts.update(kda_layers=layers,
+                     kda_chunks=layers * b * (t // DELTA_CHUNK),
+                     kda_heads=cfg.delta_key_heads)
     if cfg.diffusion_block:
         visited, plane = diffusion_tiles(
             2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)
@@ -1766,6 +1933,18 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
             + counts["delta_beta_sum"].sum(),
             delta_beta_count=old["delta_beta_count"] + float(
                 rows * _delta_layers(cfg) * cfg.delta_value_heads))
+    if "kda_beta_sum" in old:
+        heads = rows * _kda_layers(cfg) * cfg.delta_key_heads
+        new["kda_log_decay_min"] = jnp.minimum(
+            old["kda_log_decay_min"], counts["kda_log_decay_min"])
+        for name, count in (("decay_spread", heads // DELTA_CHUNK),
+                            ("beta", heads),
+                            ("gate", heads * cfg.delta_value_dim)):
+            new.update({
+                f"kda_{name}_sum": old[f"kda_{name}_sum"]
+                + counts[f"kda_{name}_sum"].sum(),
+                f"kda_{name}_count": old[f"kda_{name}_count"]
+                + float(count)})
     if cfg.shared_gate:
         new.update(
             shared_gate_sum=old["shared_gate_sum"]

@@ -45,10 +45,10 @@ def on_tpu(monkeypatch):
     """jax.default_backend() is the CPU here, so the ops would choose
     interpret mode: steer them to Mosaic, in the test."""
     from ray_tpu.collective.backends import pallas_backend
-    from ray_tpu.ops import (attention, batchnorm, gated_delta, layernorm,
-                             moe_gmm, short_conv, ssd)
+    from ray_tpu.ops import (attention, batchnorm, gated_delta, kda,
+                             layernorm, moe_gmm, short_conv, ssd)
 
-    for mod in (attention, batchnorm, gated_delta, layernorm, moe_gmm,
+    for mod in (attention, batchnorm, gated_delta, kda, layernorm, moe_gmm,
                 short_conv, ssd, pallas_backend):
         monkeypatch.setattr(mod, "is_tpu", lambda: True)
 
@@ -728,6 +728,83 @@ def test_gated_delta_runs_on_the_chip():
     for name, a, r in zip("q k v g beta".split(), g_got, g_want):
         errs[name] = _rel_err(a, r)
     print("gated_delta", errs)
+    assert max(errs.values()) < 0.03, errs
+
+
+def _kda_specs(batch, t, sharding):
+    """Kimi Linear's KDA mixer: 32 heads of 128 / 128; q, k, v in bf16,
+    the log decay a key channel and beta in float32."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    heads = spec((batch, t, 32, 128))
+    return (heads, heads, heads, spec((batch, t, 32, 128), jnp.float32),
+            spec((batch, t, 32), jnp.float32))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_kda_fwd_and_bwd(one_chip, on_tpu, batch):
+    """The per-channel delta rule at Kimi Linear's shapes (8 192
+    positions in chunks of 64, two heads a grid step): one Mosaic call
+    forward, which writes no state; under grad the forward that saves
+    each chunk's entering states and one backward call, under the
+    kernels' own names, and no dense fallback beside them."""
+    from ray_tpu.ops import kda
+
+    f32 = jnp.float32
+    args = _kda_specs(batch, 8192, one_chip)
+    text = _compiled_text(kda.kda, *args)
+    assert text.count("tpu_custom_call") == 1 and "kda_fwd" in text
+    assert f"f32[{batch},128,32,128,128]" not in text
+    text = _compiled_text(
+        jax.grad(lambda *a: kda.kda(*a).astype(f32).sum(), tuple(range(5))),
+        *args)
+    assert text.count("tpu_custom_call") == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert f"f32[{batch},128,32,128,128]" in text
+    assert "triangular-solve" not in text and "while" not in text \
+        and " dot(" not in text and "convolution(" not in text
+
+
+def test_kda_runs_on_the_chip():
+    """On a chip: the kernels' values and five gradients in bf16 against
+    the plain chunked form in float32, 512 positions of Kimi Linear's
+    widths, the decay a channel in its own range (A up to 16, so that
+    some channels lose hundreds in a chunk and others nothing)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import kda as kd
+
+    keys = jax.random.split(jax.random.key(17), 7)
+    b, t, h, f32 = 1, 512, 4, jnp.float32
+
+    def unit(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    q = unit(jax.random.normal(keys[0], (b, t, h, 128), f32)) * 128 ** -0.5
+    k = unit(jax.random.normal(keys[1], (b, t, h, 128), f32))
+    v = jax.random.normal(keys[2], (b, t, h, 128), f32)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (b, t, h), f32))
+    g = -jax.random.uniform(keys[4], (h, 1), f32, 1e-3, 16) \
+        * jax.nn.softplus(
+            jax.random.normal(keys[5], (b, t, h, 128), f32) * 2 - 2)
+    w = jax.random.normal(keys[6], (b, t, h, 128), f32)
+    low = tuple(z.astype(jnp.bfloat16) for z in (q, k, v)) + (g, beta)
+    exact = tuple(z.astype(f32) for z in low)
+
+    def both(fn, args):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a).astype(f32) * w).sum(),
+            tuple(range(5))))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        _, g_want = both(kd.kda_xla, exact)
+        o_want = jax.jit(kd.kda_xla)(*exact)
+    _, g_got = both(kd.kda, low)
+    errs = {"o": _rel_err(jax.jit(kd.kda)(*low), o_want)}
+    for name, a, r in zip("q k v g beta".split(), g_got, g_want):
+        errs[name] = _rel_err(a, r)
+    print("kda", errs)
     assert max(errs.values()) < 0.03, errs
 
 
