@@ -352,7 +352,8 @@ class DecoderConfig:
     ssm_dt_range: tuple[float, float, float] = (1e-3, 0.1, 1e-4)  # min, max,
     #                                   floor of dt at initialisation
     count_rows: bool = False          # the counters moe_rows_static /
-    #                                   _filled (with an MTP block: always)
+    #                                   _filled / _walked (with an MTP
+    #                                   block: always)
     diffusion_block: int = 0          # > 0: trained by block diffusion over
     #                                   blocks of this many tokens
     head_rows: bool = False           # the untied head kept [vocab, d_model]
@@ -1624,9 +1625,11 @@ def counters_init(cfg: DecoderConfig):
     `loss_main` and `loss_mtp` (the last step's two terms, the second
     before its weight), `moe_rows_static` (the rows the grouped matmul's
     arrays hold, the worst case: `parallel/moe.py::static_rows` x MoE
-    layers x steps) and `moe_rows_filled` (those that held an
-    assignment); `cfg.count_rows` asks for the two rows counters without
-    one. A configuration with an ssm mixer counts `ssm_log_decay_min`
+    layers x steps), `moe_rows_filled` (those that held an assignment)
+    and `moe_rows_walked` (the rows the expert blocks ran over: the
+    rung of `parallel/moe.py::row_ladder` each layer's routing chose,
+    summed the same way); `cfg.count_rows` asks for the three rows
+    counters without one. A configuration with an ssm mixer counts `ssm_log_decay_min`
     (the most negative sum of dt A over one chunk of the scan that any
     head of any layer saw in the epoch: below about -87 a float32 chunk
     forgets the state that entered it entirely) and `ssm_dt_max` (the
@@ -1685,7 +1688,8 @@ def counters_init(cfg: DecoderConfig):
     if cfg.mtp:
         counters.update(loss_main=f32(), loss_mtp=f32())
     if cfg.mtp or cfg.count_rows:
-        counters.update(moe_rows_static=f32(), moe_rows_filled=f32())
+        counters.update(moe_rows_static=f32(), moe_rows_filled=f32(),
+                        moe_rows_walked=f32())
     if "ssm" in cfg.attention + cfg.lead_attention:
         counters.update(ssm_log_decay_min=f32(), ssm_dt_max=f32())
     if cfg.diffusion_block:
@@ -1957,7 +1961,9 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
                 cfg.moe_layers * static_rows(
                     rows * cfg.top_k, cfg.held[1], cfg.gmm_tile)),
             moe_rows_filled=old["moe_rows_filled"] + (
-                counts["held"] - counts["dropped"]).sum().astype(jnp.float32))
+                counts["held"] - counts["dropped"]).sum().astype(jnp.float32),
+            moe_rows_walked=old["moe_rows_walked"]
+            + counts["rows_walked"].sum().astype(jnp.float32))
     state = {**state, "epoch_counters": new}
     if cfg.diffusion_block:
         state["noise_step"] = state["noise_step"] + 1

@@ -9,11 +9,14 @@ weight block is picked per tile from a scalar-prefetched table:
     out[r] = x[r] @ w[tile_group[r // tile]]        for r in active tiles
 
 `n_tiles` (a device scalar) says how many tiles hold rows; the static
-row count is the worst case (every assignment held here), and tiles past
-`n_tiles` are neither fetched, multiplied nor written: their block index
-is clamped to the last active tile's and the body is skipped, so the
-work follows the rows really routed here. What such rows of the output
-hold is undefined; the caller masks by row validity.
+row count is what the caller hands in — the rung of
+`parallel/moe.py::row_ladder` its routing chose: a prefix of the
+worst-case layout (every assignment held here) that covers the filled
+tiles — and tiles past `n_tiles` are neither fetched, multiplied nor
+written: their block index is clamped to the last active tile's and the
+body is skipped, so the work follows the rows really routed here. What
+such rows of the output hold is undefined; the caller masks by row
+validity, and by walking a short rung has few of them.
 
 Three kernels, named so the device trace carries them: `moe_gmm` (the
 forward product, and its rematerialised copy), `moe_gmm_dx` (the same

@@ -17,7 +17,12 @@ SURVEY §2.4).
    SiLU, or ungated (`w_gate` None: two matrices an expert, say under
    the squared ReLU) (`ACTIVATIONS`). A shared expert is not this
    layer's: the decoder adds it as a plain MLP, outside the grouped
-   matmul's rows.
+   matmul's rows. The rows are LAID OUT for the worst case, every
+   assignment held here (`static_rows`), filled from the front, and
+   WALKED as far as a layer's routing filled them: the block runs on
+   the smallest rung of `row_ladder` that holds the filled tiles,
+   chosen on the device a layer and step (`_expert_block`), the worst
+   case its top rung.
 """
 
 from __future__ import annotations
@@ -117,21 +122,28 @@ def moe_apply(x, router_w, w_in, w_out, *, mesh: Mesh,
 # multiplies each group by its expert (`ops/moe_gmm.py`) and combines by
 # routing weight. What the absent experts would add is left out: on one
 # chip the layer runs without its exchange, and nothing stands in for
-# the other chips.
+# the other chips. The gathers, the activation and the kernels' outputs
+# all have one row an assignment SLOT: sized for the worst case they
+# cost four to twenty times what the filled rows need, so the block
+# picks its row count from a short ladder by what was filled.
 
 GMM_TILE = 512     # rows a tile of the grouped matmul holds
 
 
 class Grouping(NamedTuple):
-    """Where every assignment of a step sits among the rows the grouped
-    matmul walks. Assignment a = token * k + slot."""
+    """Where every assignment of a step sits among the R = `static_rows`
+    rows the layout has. Assignment a = token * k + slot. The rows that
+    hold one are packed at the FRONT, expert by expert in whole tiles:
+    the first `n_tiles` tiles are all of the work, and the expert block
+    walks a prefix of the three [R]-sized arrays that covers them
+    (`row_ladder`)."""
 
     row_of: jax.Array         # [N, k] its row; meaningless unless `held`
     held: jax.Array           # [N, k] bool: it falls on an expert held here
     assign_of_row: jax.Array  # [R] the assignment in a row
     row_valid: jax.Array      # [R] bool: the row holds one
     tile_group: jax.Array     # [R // tile] the expert of each row tile
-    n_tiles: jax.Array        # [1] the tiles that hold rows
+    n_tiles: jax.Array        # [1] the tiles that hold rows, all in front
     expert_tokens: jax.Array  # [count] assignments each held expert got
 
 
@@ -181,12 +193,24 @@ ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
 
 
 def static_rows(assignments: int, count: int, tile: int = GMM_TILE) -> int:
-    """The rows the grouped matmul's arrays hold for a step of
+    """The rows the grouped matmul's layout is ALLOCATED for, a step of
     `assignments` = tokens x top_k over `count` held experts: the worst
     case, every assignment held here, in whole tiles, and a tile more an
     expert for its padding. With a sixteenth of the experts held about
-    a sixteenth of them is filled."""
+    a sixteenth of them is filled, and the block walks a rung of
+    `row_ladder` near that, not these."""
     return -(-assignments // tile) * tile + count * tile
+
+
+def row_ladder(assignments: int, count: int,
+               tile: int = GMM_TILE) -> tuple[int, ...]:
+    """The row counts the expert block can walk, from the shapes alone:
+    1/8, 2/8, 3/8 and 4/8 of the worst case's tiles, rounded up to
+    whole tiles, and the worst case (`static_rows`) as the top rung;
+    ascending, duplicates dropped, so a small call has fewer."""
+    worst = static_rows(assignments, count, tile) // tile
+    return tuple(tile * t for t in sorted(
+        {-(-worst * eighths // 8) for eighths in (1, 2, 3, 4)} | {worst}))
 
 
 def group_by_expert(expert_idx, held: tuple[int, int],
@@ -283,6 +307,91 @@ def _combine_bwd(res, dout):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _walk(rows: int, y, weights, g: Grouping, w_in, w_down, tile: int,
+          activation: str, gated: bool):
+    """The expert block on the first `rows` rows of the layout (whole
+    tiles, at least `g.n_tiles` of them: the filled rows are packed at
+    the front): dispatch, the grouped products around the activation,
+    combine. `w_in`: gate and up side by side, [count, D, 2F], or
+    ungated the up weights alone, [count, F, D]. -> [N, D]."""
+    from ray_tpu.ops.moe_gmm import moe_gmm
+
+    g = g._replace(assign_of_row=g.assign_of_row[:rows],
+                   row_valid=g.row_valid[:rows],
+                   tile_group=g.tile_group[:rows // tile])
+    act_fn = ACTIVATIONS[activation]
+    with jax.named_scope("experts"):
+        x = _dispatch(y, g)
+        if gated:
+            f = w_in.shape[-1] // 2
+            gate_up = moe_gmm(x, w_in, g.tile_group, g.n_tiles, tile)
+            act = act_fn(gate_up[:, :f]) * gate_up[:, f:]
+        else:
+            act = act_fn(moe_gmm(x, w_in, g.tile_group, g.n_tiles, tile,
+                                 True))
+        return _combine(moe_gmm(act, w_down, g.tile_group, g.n_tiles, tile),
+                        weights, g)
+
+
+# A rung's forward and its gradient, each under a `jax.jit` of its own:
+# nothing of the compiled step changes (XLA inlines the calls), but the
+# layers of a stack that call the block at one shape share ONE trace
+# and one lowering of each rung's kernels, where five rungs a layer
+# would otherwise be traced layer by layer.
+_rung = jax.jit(_walk, static_argnums=(0, 6, 7, 8))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 7, 8, 9))
+def _rung_bwd(rows: int, dout, y, weights, g: Grouping, w_in, w_down,
+              tile: int, activation: str, gated: bool):
+    return jax.vjp(
+        lambda y, weights, w_in, w_down: _walk(
+            rows, y, weights, g, w_in, w_down, tile, activation, gated),
+        y, weights, w_in, w_down)[1](dout)
+
+
+def _on_rung(fn, rung, ladder: tuple[int, ...], *operands, **static):
+    """One conditional, a branch a rung: `fn(rows, *operands)`."""
+    return jax.lax.switch(
+        rung, [functools.partial(fn, rows, **static) for rows in ladder],
+        *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _expert_block(y, weights, g: Grouping, rung, w_in, w_down, tile: int,
+                  activation: str, gated: bool, ladder: tuple[int, ...]):
+    """`_walk` over `ladder[rung]` rows, the rung a device scalar. The
+    residuals are the block's INPUTS and the backward takes the gradient
+    of the same rung's `_walk` inside its own branch, so no rung's
+    intermediates leave the conditional (differentiated straight
+    through, every rung's residuals would be outputs of the forward,
+    zeros for the rungs not taken), and a `jax.checkpoint` around the
+    block has nothing to recompute: its second forward is dead code."""
+    return _on_rung(_rung, rung, ladder, y, weights, g, w_in, w_down,
+                    tile=tile, activation=activation, gated=gated)
+
+
+def _expert_block_fwd(y, weights, g, rung, w_in, w_down, tile, activation,
+                      gated, ladder):
+    return (_expert_block(y, weights, g, rung, w_in, w_down, tile,
+                          activation, gated, ladder),
+            (y, weights, g, rung, w_in, w_down))
+
+
+def _expert_block_bwd(tile, activation, gated, ladder, res, dout):
+    y, weights, g, rung, w_in, w_down = res
+    # the barrier keeps what follows (a layer scan's write of each
+    # gradient into its stack) out of the branches: moved into them, a
+    # branch's output is the whole stack
+    dy, dweights, dw_in, dw_down = jax.lax.optimization_barrier(_on_rung(
+        _rung_bwd, rung, ladder, dout, y, weights, g, w_in, w_down,
+        tile=tile, activation=activation, gated=gated))
+    return dy, dweights, None, None, dw_in, dw_down
+
+
+_expert_block.defvjp(_expert_block_fwd, _expert_block_bwd)
+
+
 def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
                  held: tuple[int, int], tile: int = GMM_TILE,
                  activation: str = "relu", bias=None, scale: float = 1.0):
@@ -303,9 +412,18 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
     the stack and its moments back and forth and the host gets a
     strided view; PERF.md section 6, PR 39); `counts`
     holds `expert_tokens` [count] (assignments each held expert got),
-    `assignments` (N * k), `held` (those on held experts) and `dropped`
+    `assignments` (N * k), `held` (those on held experts), `dropped`
     (held assignments that found no row: 0, by construction, and
-    counted from the layout rather than assumed).
+    counted from the layout rather than assumed) and `rows_walked` (the
+    rows the block ran over).
+
+    The layout has `static_rows` rows, the worst case; the block —
+    dispatch, the grouped products, the activation, combine, and their
+    gradients — runs on the first `rows` of them, the smallest rung of
+    `row_ladder` whose tiles hold the `n_tiles` this call's routing
+    filled, picked by `lax.switch` on the device. With every
+    assignment held the top rung runs, which is the whole layout; the
+    values are the worst case's bit for bit on every rung.
 
     `activation`: a key of `ACTIVATIONS`. `bias` None: `route_topk`;
     `bias` [n_experts] float32: `route_sigmoid_bias`, and `counts` also
@@ -313,8 +431,6 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
     what `balance_bias` reads) and `bias_moved`. `scale`: a factor on
     the routing weights after their normalisation (a model's
     `routed_scaling_factor`; at 1 nothing is traced for it)."""
-    from ray_tpu.ops.moe_gmm import moe_gmm
-
     extra = {}
     if bias is None:
         idx, weights = route_topk(router_logits, top_k)
@@ -326,23 +442,22 @@ def dropless_moe(y, router_logits, w_gate, w_up, w_down, *, top_k: int,
     if scale != 1:
         weights = weights * scale
     g = group_by_expert(idx, held, tile)
-    act_fn = ACTIVATIONS[activation]
+    ladder = row_ladder(idx.size, held[1], tile)
+    rungs = jnp.asarray(ladder, jnp.int32)
+    # the smallest rung whose tiles hold every filled one
+    rung = jnp.searchsorted(rungs // tile, g.n_tiles[0]).astype(jnp.int32)
     with jax.named_scope("experts"):
-        x = _dispatch(y, g)
-        if w_gate is None:
-            act = act_fn(moe_gmm(x, w_up, g.tile_group, g.n_tiles, tile,
-                                 True))
-        else:
-            f = w_gate.shape[-1]
-            gate_up = moe_gmm(x, jnp.concatenate([w_gate, w_up], axis=-1),
-                              g.tile_group, g.n_tiles, tile)
-            act = act_fn(gate_up[:, :f]) * gate_up[:, f:]
-        rows = moe_gmm(act, w_down, g.tile_group, g.n_tiles, tile)
-        out = _combine(rows, weights, g)
+        # gate and up side by side, once a call and outside the branches:
+        # a rung takes the pair as one operand and hands back one gradient
+        w_in = w_up if w_gate is None else jnp.concatenate(
+            [w_gate, w_up], axis=-1)
+    out = _expert_block(y, weights, g, rung, w_in, w_down, tile, activation,
+                        w_gate is not None, ladder)
     n_held = g.held.sum()
     counts = {
         "expert_tokens": g.expert_tokens.astype(jnp.int32),
         "assignments": jnp.asarray(idx.size, jnp.int32),
         "held": n_held.astype(jnp.int32),
-        "dropped": (n_held - g.row_valid.sum()).astype(jnp.int32), **extra}
+        "dropped": (n_held - g.row_valid.sum()).astype(jnp.int32),
+        "rows_walked": rungs[rung], **extra}
     return out, counts

@@ -452,8 +452,9 @@ def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
     """The dropless expert layer at SmallThinker's widths (16 held
     experts of 2560 -> 768, top-6 of 64) over 8 192 tokens: the forward
     products and, under grad, both backward products of each are Mosaic
-    kernels the chip's compiler accepts."""
-    from ray_tpu.parallel.moe import dropless_moe
+    kernels the chip's compiler accepts, at every rung of the ladder of
+    row counts (`parallel/moe.py::row_ladder`: five here)."""
+    from ray_tpu.parallel.moe import dropless_moe, row_ladder
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -467,14 +468,18 @@ def test_grouped_expert_matmul_fwd_and_bwd(one_chip, on_tpu):
         jax.grad(loss, (0, 1, 2, 3, 4)), spec((8192, 2560)),
         spec((8192, 64), jnp.float32), spec((16, 2560, 768)),
         spec((16, 2560, 768)), spec((16, 768, 2560)))
-    assert text.count("tpu_custom_call") == 6
+    # the six of a rung's gradient (its forward products, run again
+    # inside the backward's branch, and each one's two gradients), once
+    # a rung of the ladder: the step runs one rung's
+    assert text.count("tpu_custom_call") == 6 * len(
+        row_ladder(8192 * 6, 16))
 
 
 def test_sigmoid_routed_silu_experts_fwd_and_bwd(one_chip, on_tpu):
     """The dropless expert layer at LFM2's widths (8 held experts of
     2048 -> 1792, top-4 of 32 by sigmoid scores and a selection bias,
-    SiLU) over 8 192 tokens: the same six Mosaic products."""
-    from ray_tpu.parallel.moe import dropless_moe
+    SiLU) over 8 192 tokens: the same six Mosaic products a rung."""
+    from ray_tpu.parallel.moe import dropless_moe, row_ladder
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -489,7 +494,7 @@ def test_sigmoid_routed_silu_experts_fwd_and_bwd(one_chip, on_tpu):
         spec((8192, 32), jnp.float32), spec((8, 2048, 1792)),
         spec((8, 2048, 1792)), spec((8, 1792, 2048)),
         spec((32,), jnp.float32))
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") == 6 * len(row_ladder(8192 * 4, 8))
 
 
 def test_ungated_experts_of_a_width_no_lane_tile_divides(one_chip, on_tpu):
@@ -497,10 +502,11 @@ def test_ungated_experts_of_a_width_no_lane_tile_divides(one_chip, on_tpu):
     squared-ReLU experts of 2688 -> 1856 -> 2688, top-6 of 128 by
     sigmoid scores and a selection bias) over 8 192 tokens: two grouped
     products forward (up, down: no gate half) and each one's two
-    gradients, six Mosaic calls, with the whole 1856 lanes or rows as a
-    weight block wherever the width is the blocked dimension (1856 =
-    14.5 x 128: no lane tile divides it, and nothing is padded)."""
-    from ray_tpu.parallel.moe import dropless_moe
+    gradients, six Mosaic calls a rung of the ladder, with the whole
+    1856 lanes or rows as a weight block wherever the width is the
+    blocked dimension (1856 = 14.5 x 128: no lane tile divides it, and
+    nothing is padded)."""
+    from ray_tpu.parallel.moe import dropless_moe, row_ladder
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -515,7 +521,7 @@ def test_ungated_experts_of_a_width_no_lane_tile_divides(one_chip, on_tpu):
         jax.grad(loss, (0, 1, 2, 3)), spec((8192, 2688)),
         spec((8192, 128), jnp.float32), spec((8, 1856, 2688)),
         spec((8, 1856, 2688)), spec((128,), jnp.float32))
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") == 6 * len(row_ladder(8192 * 6, 8))
     for name in ("moe_gmm", "moe_gmm_dx", "moe_gmm_dw"):
         assert name in text
 

@@ -146,6 +146,10 @@ def test_decoder_matches_reference(program, share):
     assert int(c["moe_rows_static"]) == MOE_LAYERS * static_rows(
         tokens.size * 3, HELD[share][1], cfg.gmm_tile)
     assert int(c["moe_rows_filled"]) == int(c["moe_assignments_held"])
+    # every layer walked a rung that holds what its routing filled
+    assert int(c["moe_rows_filled"]) <= int(c["moe_rows_walked"]) <= int(
+        c["moe_rows_static"])
+    assert int(c["moe_rows_walked"]) % cfg.gmm_tile == 0
     if share == "all":
         assert int(c["moe_assignments_held"]) == int(c["moe_assignments"])
     else:

@@ -219,6 +219,10 @@ def test_decoder_matches_reference(program, exact):
     assert int(c["moe_rows_static"]) == 4 * static_rows(
         tokens.size * 3, count, cfg.gmm_tile)
     assert int(c["moe_rows_filled"]) == int(c["moe_assignments_held"])
+    # every layer walked a rung that holds what its routing filled
+    assert int(c["moe_rows_filled"]) <= int(c["moe_rows_walked"]) <= int(
+        c["moe_rows_static"])
+    assert int(c["moe_rows_walked"]) % cfg.gmm_tile == 0
     # the selection bias made the reference's step
     np.testing.assert_allclose(
         new["expert_bias"],
@@ -330,11 +334,14 @@ def test_shares_add_up_to_the_uncut_layer(at):
 # and, at the configuration's tiny preset, of the bytes of every leaf
 # seeded from key 0 (`tests/test_decoder_qwen3next.py::RECORDED`'s
 # recipe; that file and `test_decoder_laguna.py` hold the seven before).
+# (All six recorded again at PR 62: the expert block walks a rung of
+# `parallel/moe.py::row_ladder` under a conditional, and the epoch
+# counters gained `moe_rows_walked`.)
 RECORDED = {
-    "qwen3next_80b_a3b_ep16": ("qwen3next_tiny", "b99aa08af6f2df9b",
-                               "4ddc0fde5405916d", "9e265e20af6cc73c"),
-    "joyai_flash_ep16": ("joyai_tiny", "46f5b9c1c77f0640",
-                         "074cdf19c2ed916c", "de6619f6bb1da7bd"),
+    "qwen3next_80b_a3b_ep16": ("qwen3next_tiny", "64c5cb2f911806e0",
+                               "3ce3877476b96c9f", "86542d77515d2e3e"),
+    "joyai_flash_ep16": ("joyai_tiny", "1e46c2acd199b0f8",
+                         "4a2c773ff3d2dc60", "462fab1b65ec7cc3"),
 }
 
 

@@ -170,6 +170,10 @@ def test_decoder_matches_reference(program):
     assert int(c["moe_rows_static"]) == 4 * static_rows(
         tokens.size * 3, count, cfg.gmm_tile)
     assert int(c["moe_rows_filled"]) == int(c["moe_assignments_held"])
+    # every layer walked a rung that holds what its routing filled
+    assert int(c["moe_rows_filled"]) <= int(c["moe_rows_walked"]) <= int(
+        c["moe_rows_static"])
+    assert int(c["moe_rows_walked"]) % cfg.gmm_tile == 0
     # the gate is computed, on every head of every layer of a kind, and
     # is not stuck at one half
     assert int(c["attn_gate_count_full"]) == tokens.size * 2 * 6
@@ -299,18 +303,23 @@ def test_window_scores_are_the_kernels_own_walk(t, window, block_q,
 # PR 55); and, at the configuration's tiny preset, of the bytes of
 # every leaf seeded from key 0. (The step's text recorded again at
 # PR 60: its `flash_fwd` walks the key blocks in runs; trees and seeded
-# weights are the parent's still.)
+# weights are the parent's still. At PR 62 the step's text of the five
+# with expert layers again — the block walks a rung of
+# `parallel/moe.py::row_ladder` under a conditional — and, of the three
+# that count rows (`joyai`, `nemotron3`, `sdar`), the tree and the seeded
+# bytes too: one more zero, `moe_rows_walked`, among the epoch counters;
+# `ouro_2_6b_d8`, which has no expert, keeps all three.)
 RECORDED = {
     "smallthinker_21b_ep4": ("smallthinker_tiny", "e1534e3b726776c9",
-                             "449e137972a5aacf", "a5041fed5e97536c"),
-    "lfm2_8b_a1b_ep4": ("lfm2_tiny", "f133c9bbc8c0c233", "f36997273c4777ba",
+                             "44fb3715a183b3ab", "a5041fed5e97536c"),
+    "lfm2_8b_a1b_ep4": ("lfm2_tiny", "f133c9bbc8c0c233", "ac4c17aaaf384d9c",
                         "84ce2cd017577a97"),
-    "joyai_flash_ep16": ("joyai_tiny", "46f5b9c1c77f0640",
-                         "074cdf19c2ed916c", "de6619f6bb1da7bd"),
-    "nemotron3_nano_ep16": ("nemotron_tiny", "5413d275d800de61",
-                            "b821959606038e7b", "6d44cb1c0e611553"),
-    "sdar_30b_a3b_ep8": ("sdar_tiny", "e2daba414fb236f7", "f1279d532c3c1b60",
-                         "ff712b1188c4e882"),
+    "joyai_flash_ep16": ("joyai_tiny", "1e46c2acd199b0f8",
+                         "4a2c773ff3d2dc60", "462fab1b65ec7cc3"),
+    "nemotron3_nano_ep16": ("nemotron_tiny", "693046ea382e87db",
+                            "74ee370537a170d3", "16e06d3e17e37deb"),
+    "sdar_30b_a3b_ep8": ("sdar_tiny", "809b8713238c2e2d", "e3c3e90babd2822b",
+                         "9648c599b28dfa6f"),
     "ouro_2_6b_d8": ("ouro_tiny", "48c171503f96b155", "5d3edb7e5e8438f2",
                      "42e69148bd51e030"),
 }

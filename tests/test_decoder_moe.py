@@ -28,6 +28,7 @@ from benchmark.families import smallthinker_reference as reference
 from ray_tpu.models import decoder
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import dropless_moe
 
 LOSS_RTOL = 5e-7
@@ -149,6 +150,177 @@ def test_no_token_is_dropped_under_a_biased_router():
     assert float(jnp.abs(out - want).max()) <= LOGIT_ATOL
     assert int(counts["expert_tokens"][5]) == n
     assert int(counts["dropped"]) == 0 and int(counts["held"]) == n * k
+
+
+# The ladder of row counts (`moe.row_ladder`). 128 tokens, top-2 of 16
+# experts, tiles of 8 rows: with two experts held the worst case is 34
+# tiles and the rungs stand at 5, 9, 13, 17 and 34. `crowd` tokens are
+# sent to the two held experts with both their slots and no other token
+# to either, so each held expert gets `crowd` rows: 2 * ceil(crowd / 8)
+# tiles are filled.
+# name -> (held, crowd, gated, routing bias, rung taken)
+LADDER_CASES = {
+    "under-an-eighth": ((4, 2), 16, True, False, 0),
+    "under-an-eighth-a-tile-short": ((4, 2), 17, True, False, 1),
+    "between-one-and-two-eighths": ((4, 2), 32, True, False, 1),
+    "between-two-and-three-eighths": ((4, 2), 48, True, True, 2),
+    "between-three-eighths-and-half": ((4, 2), 64, False, False, 3),
+    "above-half": ((4, 2), 72, True, False, 4),
+    "worst-case-a-held-share": ((4, 2), 128, True, False, 4),
+    "every-expert-held": ((0, 16), None, True, False, 4),
+    "every-expert-held-ungated-sigmoid-bias": ((0, 16), None, False, True, 4),
+}
+LADDER_N, LADDER_E, LADDER_K, LADDER_TILE = 128, 16, 2, 8
+
+
+def _ladder_inputs(held, crowd, gated):
+    n, d, f = LADDER_N, 32, 16
+    first, count = held
+    keys = jax.random.split(jax.random.key(11), 6)
+    y = jax.random.normal(keys[0], (n, d))
+    logits = jax.random.normal(keys[1], (n, LADDER_E))
+    if crowd is not None:
+        on_held = (jnp.arange(LADDER_E) >= first) & (
+            jnp.arange(LADDER_E) < first + count)
+        sent = jnp.arange(n) < crowd
+        logits = logits + 12.0 * jnp.where(sent[:, None], 1.0, -1.0) * on_held
+    if gated:
+        w_gate = jax.random.normal(keys[2], (count, d, f)) * 0.2
+        w_up = jax.random.normal(keys[3], (count, d, f)) * 0.2
+    else:       # the ungated form keeps `w_up` [count, F, D]
+        w_gate, w_up = None, jax.random.normal(keys[3], (count, f, d)) * 0.2
+    w_down = jax.random.normal(keys[4], (count, f, d)) * 0.2
+    return y, logits, w_gate, w_up, w_down, jax.random.normal(keys[5], (n, d))
+
+
+@pytest.mark.parametrize("case", LADDER_CASES)
+def test_a_rung_is_the_worst_case_bit_for_bit(case):
+    """The expert block on the rung its routing chose against the same
+    body (`moe._walk`, plainly differentiated) on the worst-case rows:
+    the output and the gradients of the tokens, the routing weights and
+    the weight stacks (gate and up as the one stack the block takes, and
+    down) are EQUAL, no token is dropped and `rows_walked`
+    is the expected rung's."""
+    held, crowd, gated, biased, rung = LADDER_CASES[case]
+    y, logits, w_gate, w_up, w_down, dout = _ladder_inputs(held, crowd, gated)
+    activation = "silu" if gated else "relu2"
+    bias = 1e-3 * jnp.arange(LADDER_E, dtype=jnp.float32) if biased else None
+    ladder = moe.row_ladder(LADDER_N * LADDER_K, held[1], LADDER_TILE)
+    assert len(ladder) == 5 and ladder[-1] == moe.static_rows(
+        LADDER_N * LADDER_K, held[1], LADDER_TILE)
+
+    if biased:
+        idx, weights, _ = moe.route_sigmoid_bias(logits, bias, LADDER_K)
+    else:
+        idx, weights = moe.route_topk(logits, LADDER_K)
+    g = moe.group_by_expert(idx, held, LADDER_TILE)
+    tiles = int(g.n_tiles[0])
+    assert ladder[rung] >= tiles * LADDER_TILE and (
+        rung == 0 or ladder[rung - 1] < tiles * LADDER_TILE)
+
+    # gate and up side by side, as `dropless_moe` hands them to the block
+    w_in = w_up if w_gate is None else jnp.concatenate([w_gate, w_up], -1)
+
+    def value_and_grads(block):
+        value, grads = jax.jit(jax.value_and_grad(
+            lambda *a: (block(*a) * dout).sum(), (0, 1, 2, 3)))(
+                y, weights, w_in, w_down)
+        return value, *grads
+
+    def ladder_block(y, weights, w_in, w_down):
+        return moe._expert_block(y, weights, g, jnp.int32(rung), w_in,
+                                 w_down, LADDER_TILE, activation, gated,
+                                 ladder)
+
+    def worst_case(y, weights, w_in, w_down):
+        return moe._walk(ladder[-1], y, weights, g, w_in, w_down,
+                         LADDER_TILE, activation, gated)
+
+    for name, a, b in zip(("out", "dy", "dweights", "dw_in", "dw_down"),
+                          value_and_grads(ladder_block),
+                          value_and_grads(worst_case)):
+        assert float(jnp.abs(b).max()) > 0, name
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+    out, counts = jax.jit(functools.partial(
+        dropless_moe, top_k=LADDER_K, held=held, tile=LADDER_TILE,
+        activation=activation, bias=bias))(y, logits, w_gate, w_up, w_down)
+    assert np.array_equal(np.asarray(out), np.asarray(jax.jit(worst_case)(
+        y, weights, w_in, w_down)))
+    assert int(counts["rows_walked"]) == ladder[rung]
+    assert int(counts["dropped"]) == 0
+    assert int(counts["held"]) == int(g.held.sum()) == (
+        LADDER_N * LADDER_K if crowd is None else 2 * crowd)
+
+
+def test_a_small_call_has_fewer_rungs():
+    """Whole tiles, ascending, the worst case on top, no rung twice."""
+    assert moe.row_ladder(180224 - 32 * 512, 32) == (
+        44 * 512, 88 * 512, 132 * 512, 176 * 512, 180224)
+    assert moe.row_ladder(16, 1, 8) == (8, 16, 24)
+    assert moe.row_ladder(4, 1, 8) == (8, 16)
+
+
+def test_a_checkpoint_recomputes_no_rung():
+    """The compiled gradient of the block under `jax.checkpoint` holds
+    TWO conditionals over the ladder, the forward's and the backward's:
+    the residuals are the block's inputs, so the checkpoint's second
+    forward is dead code and the grouped matmuls run as often as they
+    did."""
+    held = (4, 2)
+    y, logits, w_gate, w_up, w_down, dout = _ladder_inputs(held, 32, True)
+    ladder = moe.row_ladder(LADDER_N * LADDER_K, held[1], LADDER_TILE)
+
+    @jax.checkpoint
+    def block(y, logits, *w):
+        return dropless_moe(y, logits, *w, top_k=LADDER_K, held=held,
+                            tile=LADDER_TILE)[0]
+
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: (block(*a) * dout).sum(), (0, 1, 2, 3, 4))).lower(
+            y, logits, w_gate, w_up, w_down).compile().as_text()
+    over_the_ladder = [
+        line for line in text.splitlines() if " conditional(" in line
+        and "branch_computations={" in line
+        and line.split("branch_computations={")[1].split("}")[0].count(",")
+        == len(ladder) - 1]
+    assert len(over_the_ladder) == 2
+
+
+@pytest.mark.parametrize("walked, want", [
+    ([0.125, 0.125, 0.125], 12.5), ([0.25, 0.375, 0.375, 0.25], 31.25),
+    ([None, None], None)])
+def test_the_rows_walked_share_reads_the_sync_spans(monkeypatch, walked,
+                                                    want):
+    """`benchmark/layer_metrics/expert_rows_walked_share.py`:
+    `moe_rows_walked` over `moe_rows_static` on each window call's
+    `train.sync`, the median over the calls in percent; None, and no
+    error, where the spans carry no such counter (the program before the
+    ladder) or there is no log."""
+    from benchmark.layer_metrics import expert_rows_walked_share as reader
+    import ray_tpu.train as train
+
+    static = 4 * 8 * moe.static_rows(16384 * 8, 8)
+
+    def entry(t0, share):
+        attrs = {"moe_rows_static": float(static), "moe_rows_filled": 1e5}
+        if share is not None:
+            attrs["moe_rows_walked"] = share * static
+        return {"trace_id": str(t0), "spans": [
+            {"name": "train.call", "start": t0, "end": t0 + 5.0,
+             "span": "r", "parent": None, "attrs": {}},
+            {"name": "train.sync", "start": t0 + 1, "end": t0 + 2,
+             "span": "s", "parent": "r", "attrs": attrs}]}
+
+    # the run's log: `first`, `warm`, the window's calls, the traced one
+    log = [entry(10.0 * i, share) for i, share in enumerate(
+        [walked[0]] * 2 + walked + [walked[0]])]
+    monkeypatch.setattr(train, "call_log", lambda: log)
+    host = {"calls": [{"wall_s": 5.0}] * len(walked),
+            "attempted": len(log)}
+    got = reader.read(host, None)
+    assert got == want if want is None else got == pytest.approx(want)
+    assert reader.read({"calls": [], "attempted": 0}, None) is None
 
 
 MUTATIONS = {

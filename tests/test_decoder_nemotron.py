@@ -163,6 +163,9 @@ def test_decoder_matches_reference(program, share):
     assert float(counters["moe_rows_static"]) == 4 * moe.static_rows(
         2 * 64 * 3, HELD[share][1], cfg.gmm_tile)
     assert counters["moe_rows_filled"] == counters["moe_assignments_held"]
+    # every layer walked a rung that holds what its routing filled
+    assert counters["moe_rows_filled"] <= counters["moe_rows_walked"] <= (
+        counters["moe_rows_static"])
 
 
 @pytest.mark.parametrize("name", reference.MUTATIONS)

@@ -206,6 +206,10 @@ def test_decoder_matches_reference(program):
     assert int(c["moe_rows_static"]) == 4 * static_rows(
         tokens.size * 3, count, cfg.gmm_tile)
     assert int(c["moe_rows_filled"]) == int(c["moe_assignments_held"])
+    # every layer walked a rung that holds what its routing filled
+    assert int(c["moe_rows_filled"]) <= int(c["moe_rows_walked"]) <= int(
+        c["moe_rows_static"])
+    assert int(c["moe_rows_walked"]) % cfg.gmm_tile == 0
     # the gates and the write strength are computed, on every element,
     # and are not stuck at one half; the decay is seen
     assert int(c["attn_gate_count_full"]) == tokens.size * 4 * 32
@@ -326,10 +330,12 @@ def test_the_rotary_turns_a_quarter_of_a_head():
 # step's forward and backward pass, on a batch [1, 1024] at those
 # widths; and, at the configuration's tiny preset, of the bytes of every
 # leaf seeded from key 0. (The step's text recorded again at PR 60, as
-# that file's were: `flash_fwd`'s K loop in runs.)
+# that file's were: `flash_fwd`'s K loop in runs. All three again at
+# PR 62: the expert block walks a rung of `parallel/moe.py::row_ladder`
+# under a conditional, and the epoch counters gained `moe_rows_walked`.)
 RECORDED = {
-    "laguna_xs2_d5": ("laguna_tiny", "adb7c9f1e7c37eee", "3a75b66fb28f7435",
-                      "7cb46020d32fa542"),
+    "laguna_xs2_d5": ("laguna_tiny", "76d13b2cc2b33de0", "53920692078322ac",
+                      "c4a472490c300ce6"),
 }
 
 
