@@ -65,8 +65,8 @@ D7). The kinds built so far:
   kept `[outputs, d_model]`: that sum is no multiple of the 128 lanes,
   and the device keeps a matrix whose minor dimension is not, after one
   that is, transposed whatever shape it is given); a depthwise causal
-  convolution of `conv_taps` taps WITH bias and a SiLU over xBC (plain
-  `jnp`, left to XLA's fusion); `[x | B | C] = xBC`; `dt =
+  convolution of `conv_taps` taps WITH bias and a SiLU over xBC (one
+  operator, `ops/short_conv.py::mixer_conv`); `[x | B | C] = xBC`; `dt =
   softplus(dt + dt_bias)`, `A = -exp(A_log)`, the scan of
   `ops/ssd.py` in chunks of `ssm_chunk` (`S_t = exp(dt_t A) S_{t-1} +
   dt_t x_t B_t^T`, `y_t = S_t C_t + D x_t`, a `[head_dim, state]`
@@ -81,9 +81,10 @@ D7). The kinds built so far:
   W_ba` (`delta_ba`, kept `[outputs, d_model]` as `ssm_in` is: twice
   the value heads is no multiple of the 128 lanes); a depthwise causal
   convolution of `conv_taps` taps WITHOUT bias and a SiLU over `[q | k
-  | v]` (plain `jnp`); `beta = sigmoid(b)`, `g = -exp(A_log) *
-  softplus(a + dt_bias)` a value head, float32; q and k L2-normalised a
-  head (`x * rsqrt(sum x^2 + 1e-6)`), q times `1 / sqrt(key dim)`; key
+  | v]`, q and k L2-normalised a head (`x * rsqrt(sum x^2 + 1e-6)`), q
+  times `1 / sqrt(key dim)` (all of it `mixer_conv`, float32 inside and
+  cast once); `beta = sigmoid(b)`, `g = -exp(A_log) * softplus(a +
+  dt_bias)` a value head, float32; key
   head j serves the value heads `j r .. (j + 1) r - 1`, `r` = value
   heads / key heads; the gated delta rule of `ops/gated_delta.py` in
   chunks of 64 (`S' = exp(g_t) S_{t-1}`, `S_t = S' + k_t
@@ -97,8 +98,9 @@ D7). The kinds built so far:
   rule with a decay a CHANNEL of the key. ONE product `[q | k | v] = x
   W` (`kda_in`: `delta_key_heads * delta_key_dim` twice, then `*
   delta_value_dim`; key and value heads are equal), a depthwise causal
-  convolution of `conv_taps` taps WITHOUT bias and a SiLU over it; q
-  and k L2-normalised a head, q times `1 / sqrt(key dim)`; ONE product
+  convolution of `conv_taps` taps WITHOUT bias and a SiLU over it, q
+  and k L2-normalised a head, q times `1 / sqrt(key dim)` (`mixer_conv`,
+  as the delta mixer's); ONE product
   down to two low ranks of the key dim each (`kda_down`, `[f | g]`),
   and from them up: `g = -exp(A_log)[h] * softplus(f W_f + dt_bias)` a
   head and key CHANNEL (`kda_f_up`, `kda_A_log` a head, `kda_dt_bias` a
@@ -246,7 +248,7 @@ from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
                                      paired_heads)
 from ray_tpu.ops.kda import kda
 from ray_tpu.ops.layernorm import rmsnorm
-from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.ops.short_conv import mixer_conv, short_conv
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
 from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
@@ -948,17 +950,6 @@ def _latent_attention(x, p, rope, cfg: DecoderConfig):
     return a.reshape(b, t, h * dv) @ cast(p["wo_latent"])
 
 
-def _causal_conv(x, taps):
-    """A depthwise causal convolution over time. x: [B, T, C] in the
-    compute dtype, taps: [K, C] float32 -> [B, T, C] float32: K shifted
-    products, float32 sums of the compute dtype's rows (the padded copy
-    stays in that dtype); plain `jnp`, left to XLA's fusion."""
-    k, t = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return sum(taps[j] * padded[:, j:j + t].astype(jnp.float32)
-               for j in range(k))
-
-
 def _ssm_mixer(x, p, cfg: DecoderConfig):
     """The state-space mixer on the first norm's output x [B, T, D] ->
     (its part of the residual [B, T, D], {the most negative sum of dt A
@@ -971,9 +962,8 @@ def _ssm_mixer(x, p, cfg: DecoderConfig):
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     zxbcdt = x @ cast(p["ssm_in"]).T
     z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * gn]
-    # the convolution, a bias, SiLU
-    xbc = jax.nn.silu(_causal_conv(xbc, p["ssm_conv"])
-                      + p["ssm_conv_bias"]).astype(x.dtype)
+    with jax.named_scope("ssm_conv"):   # the convolution, a bias, SiLU
+        xbc = mixer_conv(xbc, p["ssm_conv"], p["ssm_conv_bias"])
     dt = jax.nn.softplus(f32(zxbcdt[..., 2 * inner + 2 * gn:])
                          + p["dt_bias"])                   # [B, T, H]
     a = -jnp.exp(p["A_log"])
@@ -1007,22 +997,17 @@ def _delta_mixer(x, p, cfg: DecoderConfig):
     qkvz = x @ cast(p["delta_in"])
     ba = jnp.dot(x, cast(p["delta_ba"]).T,
                  preferred_element_type=jnp.float32)           # [B, T, 2 H]
-    # the convolution over [q | k | v], no bias, SiLU
-    qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * keys + values],
-                                   p["delta_conv"]))
-
-    def unit(z):        # L2-normalised a head, float32
-        z = z.reshape(b, t, g_heads, dk)
-        return z * lax.rsqrt((z * z).sum(-1, keepdims=True) + 1e-6)
-
-    q = unit(qkv[..., :keys]) * dk ** -0.5
-    k = unit(qkv[..., keys:2 * keys])
+    # the convolution over [q | k | v], no bias, SiLU; q and k
+    # L2-normalised a head, q times dk ** -0.5
+    with jax.named_scope("delta_conv"):
+        qkv = mixer_conv(qkvz[..., :2 * keys + values], p["delta_conv"],
+                         None, 2 * keys, dk)
     beta = jax.nn.sigmoid(ba[..., :h])
     g = -jnp.exp(p["delta_A_log"]) * jax.nn.softplus(
         ba[..., h:] + p["delta_dt_bias"])
-    o = gated_delta(q.astype(x.dtype), k.astype(x.dtype),
-                    qkv[..., 2 * keys:].astype(x.dtype).reshape(b, t, h, dv),
-                    g, beta)
+    o = gated_delta(qkv[..., :keys].reshape(b, t, g_heads, dk),
+                    qkv[..., keys:2 * keys].reshape(b, t, g_heads, dk),
+                    qkv[..., 2 * keys:].reshape(b, t, h, dv), g, beta)
     # the norm BEFORE the gate, a plain weight; statistics over a head
     z = f32(qkvz[..., 2 * keys + values:]).reshape(b, t, h, dv)
     gated = (_head_norm(f32(o), p["delta_norm"], cfg.rms_eps)
@@ -1057,16 +1042,12 @@ def _kda_mixer(x, p, cfg: DecoderConfig):
         # a head's A times a channel's softplus, float32, <= 0
         g = -jnp.exp(p["kda_A_log"])[:, None] * jax.nn.softplus(
             decay_in + p["kda_dt_bias"]).reshape(b, t, h, dk)
-    with jax.named_scope("kda_conv"):   # over [q | k | v], no bias, SiLU
-        qkv = jax.nn.silu(_causal_conv(qkv, p["kda_conv"]))
-
-        def unit(z):        # L2-normalised a head, float32
-            z = z.reshape(b, t, h, dk)
-            return z * lax.rsqrt((z * z).sum(-1, keepdims=True) + 1e-6)
-
-        q = (unit(qkv[..., :keys]) * dk ** -0.5).astype(x.dtype)
-        k = unit(qkv[..., keys:2 * keys]).astype(x.dtype)
-        v = qkv[..., 2 * keys:].astype(x.dtype).reshape(b, t, h, dv)
+    with jax.named_scope("kda_conv"):   # over [q | k | v], no bias, SiLU;
+        # q and k L2-normalised a head, q times dk ** -0.5
+        qkv = mixer_conv(qkv, p["kda_conv"], None, 2 * keys, dk)
+        q = qkv[..., :keys].reshape(b, t, h, dk)
+        k = qkv[..., keys:2 * keys].reshape(b, t, h, dk)
+        v = qkv[..., 2 * keys:].reshape(b, t, h, dv)
     with jax.named_scope("kda_rule"):
         o = kda(q, k, v, g, beta)
     with jax.named_scope("kda_gate_norm"):

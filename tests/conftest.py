@@ -434,3 +434,27 @@ def ray_start_cluster_2_nodes():
         if cw is not None:
             cw.shutdown()
         cluster.shutdown()
+
+
+@pytest.fixture
+def plain_mixer_conv(monkeypatch):
+    """-> swap(): programs traced after it is called run the recurrent
+    mixers' convolution in its plain form
+    (`ops/short_conv.py::mixer_conv_xla`); it returns a list that gets, a
+    call, the channel block `mixer_conv` takes at that shape — 0 where
+    it would have been the plain form anyway."""
+    from ray_tpu.models import decoder
+    from ray_tpu.ops import short_conv
+
+    def swap():
+        blocks = []
+
+        def plain(x, taps, bias=None, n_unit=0, head=0):
+            blocks.append(short_conv._channel_block(*taps.shape[::-1],
+                                                    n_unit, head))
+            return short_conv.mixer_conv_xla(x, taps, bias, n_unit, head)
+
+        monkeypatch.setattr(decoder, "mixer_conv", plain)
+        return blocks
+
+    return swap

@@ -864,6 +864,66 @@ def test_short_conv_under_a_sharded_jit(topo, on_tpu):
         _compiled_text(_short_conv_grads, bcx, taps, w)
 
 
+# the three cells' convolutions, batch 2 x 8192: (C, bias, normalised
+# channels, head) — [q | k | v] of the KDA and gated-delta mixers, heads
+# of 128, and the state-space mixer's xBC with a bias
+MIXER_CONV_SHAPES = {
+    "kda": (12288, False, 8192, 128),
+    "delta": (8192, False, 4096, 128),
+    "ssm": (6144, True, 0, 0)}
+
+
+@pytest.mark.parametrize("mixer", list(MIXER_CONV_SHAPES))
+def test_mixer_conv_fwd_and_bwd(one_chip, on_tpu, mixer):
+    """The recurrent mixers' convolution at the cells' widths: none takes
+    the plain form; one Mosaic call forward and one backward, blocks of
+    2048 lanes whatever C. (The kernels choose interpret mode by
+    `short_conv.is_tpu`, read a call: `on_tpu` steers them with the
+    module.)"""
+    from ray_tpu.ops import short_conv
+
+    c, biased, n_unit, head = MIXER_CONV_SHAPES[mixer]
+    assert short_conv._channel_block(c, 4, n_unit, head) == 2048
+    x = jax.ShapeDtypeStruct((2, 8192, c), jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, c), jnp.float32, sharding=one_chip)
+    bias = [jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+            ] * biased
+
+    def fwd(x, taps, *bias):
+        return short_conv.mixer_conv(x, taps, *bias or (None,), n_unit, head)
+
+    def grads(x, taps, w, *bias):
+        return jax.grad(lambda *a: (fwd(*a).astype(jnp.float32) * w).sum(),
+                        tuple(range(2 + biased)))(x, taps, *bias)
+
+    text = _compiled_text(fwd, x, taps, *bias)
+    assert text.count("tpu_custom_call") == 1 and "mixer_conv" in text
+    text = _compiled_text(grads, x, taps, x, *bias)
+    assert text.count("tpu_custom_call") == 1 and "mixer_conv_bwd" in text
+
+
+def test_mixer_conv_under_a_sharded_jit(topo, on_tpu):
+    """Each device runs the convolution on its own sequences; taps and
+    bias are whole on every device and their gradients summed over all."""
+    from ray_tpu.ops import partition, short_conv
+    from ray_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.fsdp_mesh(topo.devices)
+    batch_spec = P(("data", "fsdp"))
+    rows, whole = NamedSharding(mesh, batch_spec), NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((8, 1024, 6144), jnp.bfloat16, sharding=rows)
+    taps = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=whole)
+    bias = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=whole)
+
+    def grads(x, taps, bias, w):
+        with partition.batch_sharded(mesh, batch_spec):
+            return jax.grad(lambda *a: (short_conv.mixer_conv(*a).astype(
+                jnp.float32) * w).sum(), (0, 1, 2))(x, taps, bias)
+
+    text = _compiled_text(grads, x, taps, bias, x)
+    assert "mixer_conv_bwd" in text and "all-reduce" in text
+
+
 @pytest.mark.parametrize("norm", ["layernorm", "rmsnorm"])
 def test_norm_fwd(one_chip, on_tpu, norm):
     from ray_tpu.ops import layernorm

@@ -242,6 +242,39 @@ def test_decoder_matches_reference(program, exact):
                  / c["kda_decay_spread_count"]) > 1.0
 
 
+def test_the_convolution_as_a_kernel_is_the_plain_forms(plain_mixer_conv):
+    """The tiny preset's first three layers [kda + dense, kda, latent]
+    with one KDA head of 128 behind the convolution (its own heads of 16
+    are no whole lanes: its programs run `mixer_conv_xla`), T 64: the
+    loss and every leaf's gradient with `mixer_conv`'s kernels are the
+    plain form's."""
+    model = dict(MODEL, num_hidden_layers=3, linear_attn_config=dict(
+        MODEL["linear_attn_config"], kda_layers=[1, 2], full_attn_layers=[3]))
+    cfg = dataclasses.replace(
+        kimi_linear.model_cfg(model), dtype=jnp.float32, delta_key_heads=1,
+        delta_value_heads=1, delta_key_dim=128, delta_value_dim=128)
+    key = jax.random.key(0)
+    params, state = decoder.init(key, cfg), decoder.state_init(key, cfg)
+    params["layers"]["kda_in"] = params["layers"]["kda_in"] * 4
+    tokens = jax.random.randint(jax.random.key(1), (2, 64), 0,
+                                cfg.vocab_size)
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: decoder.stateful_loss(p, state, tokens, cfg)[0]))(
+                params)
+
+    loss, grads = step()
+    tiled = plain_mixer_conv()
+    want_loss, want = step()
+    assert tiled and all(tiled)       # the first program ran the kernels
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        scale = float(jnp.abs(ref).max())
+        assert scale > 0 and float(jnp.abs(got - ref).max()) \
+            <= GRAD_RTOL * scale, path
+
+
 @pytest.mark.parametrize("name", reference.MUTATIONS)
 def test_mutation_is_told_apart(program, name):
     """A reference with one mechanism changed — the issue's five controls
@@ -336,10 +369,17 @@ def test_shares_add_up_to_the_uncut_layer(at):
 # recipe; that file and `test_decoder_laguna.py` hold the seven before).
 # (All six recorded again at PR 62: the expert block walks a rung of
 # `parallel/moe.py::row_ladder` under a conditional, and the epoch
-# counters gained `moe_rows_walked`.)
+# counters gained `moe_rows_walked`. At PR 64 the step's text of
+# `qwen3next_80b_a3b_ep16` again, its tree and seeded bytes the parent's
+# (f6294df): its delta mixers' convolution, SiLU and unit norms are
+# `ops/short_conv.py::mixer_conv`. `joyai_flash_ep16` keeps all three.
+# This file's own configuration joins them from here on: tree and seeded
+# bytes as that parent gave them, the step's text with the operator.)
 RECORDED = {
     "qwen3next_80b_a3b_ep16": ("qwen3next_tiny", "64c5cb2f911806e0",
-                               "3ce3877476b96c9f", "86542d77515d2e3e"),
+                               "436ba2b25318da6a", "86542d77515d2e3e"),
+    "kimilinear_48b_a3b_ep32": ("kimilinear_tiny", "7d1a3bc8d25037f1",
+                                "029ac954df25d363", "b30af6c1adb58c8f"),
     "joyai_flash_ep16": ("joyai_tiny", "1e46c2acd199b0f8",
                          "4a2c773ff3d2dc60", "462fab1b65ec7cc3"),
 }

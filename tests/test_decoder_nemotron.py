@@ -168,6 +168,24 @@ def test_decoder_matches_reference(program, share):
         counters["moe_rows_static"])
 
 
+def test_the_convolution_as_a_kernel_is_the_plain_forms(program,
+                                                        plain_mixer_conv):
+    """The preset's xBC is 128 channels, one lane tile: `program` ran
+    `mixer_conv`'s kernels. The loss and every leaf's gradient with the
+    plain form in their place are the same."""
+    cfg, params, state, tokens, _ = _setup(HELD["all"])
+    loss, _, grads, _ = program["all"]
+    tiled = plain_mixer_conv()
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p: decoder.stateful_loss(p, state, tokens, cfg)[0]))(params)
+    assert tiled and all(tiled)
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        assert float(jnp.abs(got - ref).max()) <= GRAD_RTOL * float(
+            jnp.abs(ref).max()), jax.tree_util.keystr(path)
+
+
 @pytest.mark.parametrize("name", reference.MUTATIONS)
 def test_mutation_is_told_apart(program, name):
     """An alternative the configuration did not take moves a logit by

@@ -173,6 +173,37 @@ def test_the_cut_has_the_parameters_the_issue_counted():
                      "rope_dim": 64}
 
 
+def test_the_convolution_as_a_kernel_is_the_plain_forms(plain_mixer_conv):
+    """The tiny preset with one key head and two value heads of 128
+    behind the convolution (its own key heads of 8 are no whole lanes:
+    its programs run `mixer_conv_xla`), T 64: the loss and every leaf's
+    gradient with `mixer_conv`'s kernels are the plain form's."""
+    cfg = dataclasses.replace(
+        qwen3_next.model_cfg(MODEL), dtype=jnp.float32, delta_key_heads=1,
+        delta_value_heads=2, delta_key_dim=128, delta_value_dim=128)
+    params, state = decoder.init(jax.random.key(0), cfg), \
+        decoder.counters_init(cfg)
+    params["layers"]["delta_in"] = params["layers"]["delta_in"] * 4
+    tokens = jax.random.randint(jax.random.key(1), (2, 64), 0,
+                                cfg.vocab_size)
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: decoder.stateful_loss(p, state, tokens, cfg)[0]))(
+                params)
+
+    loss, grads = step()
+    tiled = plain_mixer_conv()
+    want_loss, want = step()
+    assert tiled and all(tiled)       # the first program ran the kernels
+    assert abs(float(loss) - float(want_loss)) <= LOSS_RTOL * float(want_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        scale = float(jnp.abs(ref).max())
+        assert scale > 0 and float(jnp.abs(got - ref).max()) \
+            <= GRAD_RTOL * scale, path
+
+
+
 def test_decoder_matches_reference(program):
     """The loss, the logits, every leaf's gradient and the counters.
     Measured at the rule's own chunk of 64: the loss 2e-7 apart, a logit
