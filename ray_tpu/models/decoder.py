@@ -224,6 +224,36 @@ count nothing (no `experts`, no `ssm`), without an MTP block or block
 diffusion. With `loops` 1, no sandwich and no gate none of this is
 traced and a configuration's program is what it was.
 
+`cfg.index_topk` = k > 0 makes the `full` layers' attention LEARNED
+SPARSE (DeepSeek Sparse Attention's lightning indexer, as Keye-VL-2.0's
+language model has it): a property of the `full` kind, as `attn_gate`
+and `by_kind` are. Beside q, k, v the layer's normed input x, read as a
+CONSTANT, gives an indexer — `q_I = x W_qI` as `index_heads` heads of
+`index_dim`, ONE key head `k_I = LayerNorm(x W_kI)` (weight and bias:
+the leaf `index_k_norm`'s two rows), both turned rotate-half over their
+whole width, `w = (x W_w) index_heads ** -1/2 index_dim ** -1/2` float32
+(leaves `w_index_q`, `w_index_k`, `index_k_norm`, `w_index_w`, stacked
+over the `full` layers) — whose scores `I[t, s] = sum_j w[t, j] relu(q_I
+[t, j] . k_I[s])` choose, a query, its `min(t + 1, k)` best keys at or
+before it (`ops/sparse_index.py::index_select`: ties to the lower index,
+no gradient through the choice; scope `index_select`). The attention
+runs over those alone, one selection for every head
+(`ops.flash_attention(selected=)`), and the indexer learns from the
+attention it steered: `L_I`, the mean over rows and layers of the KL
+divergence of the heads' averaged probabilities (a constant) from the
+softmax of I over the selection (`index_kl`, scope `index_loss`). The
+loss is `CE + cfg.index_loss_weight L_I`; by the two stop-gradients the
+indexer's four leaves get `L_I`'s gradient alone and every other leaf
+the cross-entropy's alone; `counts` gains `loss_main` and `loss_index`.
+One algorithm, adapted by what it observes: for T <= k every causal key
+is selected, no plane is built and the plain causal kernel runs (the
+indexer's loss stays, over every causal key). The indexer's products are
+in `cfg.index_dtype` (None: `cfg.dtype`) with float32 sums; the ReLU, w,
+the sum over heads, the threshold and the loss float32. Not built beside
+an MTP block, block diffusion, a stack walked more than once or
+`by_kind`. With `index_topk` 0 none of this is traced and a
+configuration's program is what it was.
+
 Parameters are fp32, compute is `cfg.dtype`; the router's product, its
 scores, the selection bias, the head norms, the exit gate, the
 attention's output gate, the shared expert's gate, the delta rules'
@@ -249,6 +279,7 @@ from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
 from ray_tpu.ops.kda import kda
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import mixer_conv, short_conv
+from ray_tpu.ops.sparse_index import index_kl, index_select
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
 from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
@@ -387,6 +418,13 @@ class DecoderConfig:
     delta_value_dim: int = 0          # ... the key heads); conv_taps taps;
     #                                   the kda mixer reads the same four
     #                                   (as many value heads as key heads)
+    index_topk: int = 0               # > 0: the `full` layers' attention runs
+    #                                   over the keys a learned indexer
+    #                                   selects, this many a query
+    index_heads: int = 0              # the indexer's query heads of
+    index_dim: int = 0                # ... index_dim; ONE key head
+    index_loss_weight: float = 1.0    # on the indexer's loss beside the CE
+    index_dtype: Any = None           # of the indexer's products (None: dtype)
 
     def __post_init__(self):
         period, lead = len(self.attention), len(self.lead_attention)
@@ -533,6 +571,18 @@ class DecoderConfig:
                 "\"latent\" too, with n_heads and rope_dim 0: a latent "
                 "mixer under by_kind turns nothing (without by_kind it "
                 "turns its rope part)")
+        if self.index_topk and (
+                self.index_topk < 0 or min(self.index_heads,
+                                           self.index_dim) < 1
+                or self.index_dim % 2 or "full" not in mixers or self.mtp
+                or self.diffusion_block or self.loops > 1 or self.by_kind
+                or "full" not in self.rotary):
+            raise ValueError(
+                "index_topk (learned sparse attention on the \"full\" "
+                "layers) needs index_heads and an even index_dim, a "
+                "\"full\" layer that turns (rotary), and is not built "
+                "beside an MTP block, block diffusion, a stack walked more "
+                "than once or by_kind")
         if self.attn_gate and (self.mtp or self.loops > 1
                                or not mixers & set(ATTENTION_KINDS)):
             raise ValueError(
@@ -617,6 +667,14 @@ def _leaves(cfg: DecoderConfig) -> dict:
         if cfg.qk_norm:
             table.update(q_norm=("attention", (hd,), one),
                          k_norm=("attention", (hd,), one))
+        if cfg.index_topk:  # the indexer of the `full` layers
+            table.update(
+                w_index_q=("full", (d, cfg.index_heads * cfg.index_dim),
+                           "normal"),
+                w_index_k=("full", (d, cfg.index_dim), "normal"),
+                # its key's LayerNorm: the weight's row, then the bias's
+                index_k_norm=("full", (2, cfg.index_dim), "norm_bias"),
+                w_index_w=("full", (d, cfg.index_heads), "normal"))
     if "latent" in groups:
         h, rope = cfg.n_heads, cfg.qk_rope_dim
         if cfg.q_lora_rank:
@@ -731,7 +789,7 @@ _NEWER = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo_latent", "ws_gate", "ws_up",
           "ws_token_gate", "norm1", "norm2", "q_norm", "k_norm", "norm_f",
           "norm1_post", "norm2_post", "wq_latent", "kda_in", "kda_conv",
           "kda_down", "kda_f_up", "kda_g_up", "kda_beta", "kda_A_log",
-          "kda_dt_bias", "kda_out")
+          "kda_dt_bias", "kda_out", "w_index_q", "w_index_k", "w_index_w")
 _MTP_KEY = 1 << 16
 _NOISE_KEY = 1 << 17    # folded into the init key: the noise's seed
 
@@ -767,6 +825,8 @@ def init(key, cfg: DecoderConfig):
     def draw(name, shape, how, mtp=False):
         if how == "one":
             return jnp.ones(shape)
+        if how == "norm_bias":      # [..., (weight, bias), width]
+            return jnp.zeros(shape).at[..., 0, :].set(1.0)
         if mtp:   # by the leaf's place among all the names there are
             k = jax.random.fold_in(
                 jax.random.fold_in(keys[10], _MTP_KEY),
@@ -1063,6 +1123,30 @@ def _kda_mixer(x, p, cfg: DecoderConfig):
     return gated @ cast(p["kda_out"]), stats
 
 
+def _indexer(x, p, cfg: DecoderConfig):
+    """The indexer's inputs from the first norm's output x [B, T, D],
+    which it reads as a constant: (q_I [B, T, H_I, D_I] and k_I [B, T,
+    D_I] in `cfg.index_dtype`, both turned rotate-half over their whole
+    width at the rates of `cfg.rope_theta`; w [B, T, H_I] float32, times
+    H_I ** -0.5 D_I ** -0.5). k_I is ONE head under a LayerNorm with
+    weight and bias (`index_k_norm`'s two rows), float32 statistics."""
+    b, t, _ = x.shape
+    heads, dim = cfg.index_heads, cfg.index_dim
+    dtype = cfg.index_dtype or cfg.dtype
+    x = lax.stop_gradient(x).astype(dtype)
+    cast = functools.partial(jnp.asarray, dtype=dtype)
+    q = (x @ cast(p["w_index_q"])).reshape(b, t, heads, dim)
+    k = jnp.dot(x, cast(p["w_index_k"]), preferred_element_type=jnp.float32)
+    mean = k.mean(-1, keepdims=True)
+    var = ((k - mean) ** 2).mean(-1, keepdims=True)
+    k = ((k - mean) * lax.rsqrt(var + cfg.rms_eps) * p["index_k_norm"][0]
+         + p["index_k_norm"][1]).astype(dtype)
+    table = rope_tables(jnp.arange(t, dtype=jnp.float32), cfg, dim)
+    w = jnp.dot(x, cast(p["w_index_w"]), preferred_element_type=jnp.float32)
+    return (_rope(q, *table), _rope(k[:, :, None], *table)[:, :, 0],
+            w * (heads ** -0.5 * dim ** -0.5))
+
+
 def _mlp(y, p, cfg: DecoderConfig, up: str, down: str, gate: str):
     """W_down (act(W_gate y) * (W_up y)), or W_down act(W_up y) where
     the configuration is ungated."""
@@ -1144,10 +1228,35 @@ def _layer(h, p, rope, *, cfg: DecoderConfig, attention: str, mlp: str):
                 table = rope if attention in cfg.rotary else None
             if table is not None:
                 q, k = _rope(q, *table), _rope(k, *table)
+            selected = plane = lse = lse_i = None
+            if cfg.index_topk and attention == "full":
+                with jax.named_scope("index_scores"):
+                    index = _indexer(x, p, cfg)
+                if t > cfg.index_topk:   # else every causal key is selected
+                    with jax.named_scope("index_select"):
+                        plane, lse_i, tiles, beyond = index_select(
+                            *index, cfg.index_topk,
+                            (cfg.attn_block_q, cfg.attn_block_k))
+                    selected = (plane, tiles)
+                    found.update(
+                        index_pairs_selected=tiles.sum().astype(jnp.float32),
+                        index_pairs_beyond_window=beyond.sum().astype(
+                            jnp.float32),
+                        index_tiles_visited=(tiles > 0).sum().astype(
+                            jnp.float32))
             a = flash_attention(
                 q, k, v, True, None, cfg.attn_block_q, cfg.attn_block_k,
                 cfg.window if attention == "window" else None,
-                cfg.diffusion_block or None)
+                cfg.diffusion_block or None, selected)
+            if selected:    # and the rows' log-sum-exp over the selection
+                a, lse = a
+            if cfg.index_topk and attention == "full":
+                with jax.named_scope("index_loss"):
+                    # the indexer against the attention it steered, the
+                    # rows' sum: the one count a gradient passes through
+                    found["index_kl_sum"] = index_kl(
+                        *index, *lax.stop_gradient((q, k)), plane, lse,
+                        lse_i, hd ** -0.5)
             if cfg.attn_gate == "element":
                 # a <- a o sigmoid(gate), a value a head dimension
                 gate = jax.nn.sigmoid(gate_in.astype(jnp.float32))
@@ -1252,7 +1361,11 @@ def hidden(params, tokens, cfg: DecoderConfig, bias=None):
     `ssm_dt_max`, scalars over all its layers; with a delta mixer
     `delta_log_decay_min` (a scalar) and `delta_beta_sum` a layer; with
     a kda mixer `kda_log_decay_min` (a scalar) and `kda_decay_spread_sum`,
-    `kda_beta_sum`, `kda_gate_sum` a layer.
+    `kda_beta_sum`, `kda_gate_sum` a layer; under `cfg.index_topk`
+    `index_kl_sum` a layer (the rows' sum of the indexer's KL: the one
+    count a gradient passes through) and, where a selection is made,
+    `index_pairs_selected`, `index_pairs_beyond_window`,
+    `index_tiles_visited` a layer.
 
     With `cfg.loops` = T > 1 the same layers are walked T times, the
     final norm after EVERY walk, its output the next walk's input and
@@ -1412,6 +1525,13 @@ def loss_fn(params, tokens, cfg: DecoderConfig, bias=None):
         return loop_loss(h, tokens, params, cfg)
     x = rmsnorm(h, _gain(params["norm_f"], cfg).astype(h.dtype),
                 cfg.rms_eps)
+    if cfg.index_topk:
+        main = _mean_nll(x, tokens, 1, params, cfg)[0]
+        with jax.named_scope("index_loss"):
+            index = counts["index_kl_sum"].sum() / (
+                tokens.size * _index_layers(cfg))
+        return main + cfg.index_loss_weight * index, {
+            **counts, "loss_main": main, "loss_index": index}
     if not cfg.mtp:
         return _mean_nll(x, tokens, 1, params, cfg)[0], counts
     x_mtp, c = mtp_hidden(params, h, tokens, cfg, bias)
@@ -1651,6 +1771,19 @@ def counters_init(cfg: DecoderConfig):
     output gates sigmoid(.), over tokens and value channels too).
     Under `cfg.shared_gate`: `shared_gate_sum` / `shared_gate_count`
     (the shared expert's gates over tokens, expert layers and steps).
+    Under `cfg.index_topk`, float32 sums over batch rows, `full` layers
+    and the epoch's steps: `index_pairs_selected` / `index_pairs_causal`
+    (the pairs the indexer kept over those at or below the diagonal:
+    sum of min(t + 1, topk) over t (t + 1) / 2, that the selection
+    engages), `index_pairs_beyond_window` (selected pairs whose key lies
+    `index_topk` positions or more before the query: what a window of
+    that many keys cannot hold), `index_tiles_visited` /
+    `index_tiles_causal` (the forward kernel's score tiles that hold a
+    selected pair, which it runs, over the tiles of its causal walk:
+    what the selection saves on this chip), `index_kl_sum` /
+    `index_kl_count` (the indexer's KL summed over rows, and the rows),
+    and `loss_main`, `loss_index` (the last step's two terms, the second
+    before its weight).
     The configurations from before each of these keep the state tree
     their recorded programs were lowered with."""
     f32 = functools.partial(jnp.zeros, (), jnp.float32)
@@ -1689,7 +1822,21 @@ def counters_init(cfg: DecoderConfig):
         counters.update(kda_log_decay_min=f32(), **{
             f"kda_{name}_{what}": f32() for what in ("sum", "count")
             for name in ("decay_spread", "beta", "gate")})
+    if cfg.index_topk:
+        counters.update({name: f32() for name in _INDEX_COUNTERS},
+                        loss_main=f32(), loss_index=f32())
     return {"epoch_counters": counters}
+
+
+_INDEX_COUNTERS = (
+    "index_pairs_selected", "index_pairs_causal", "index_pairs_beyond_window",
+    "index_tiles_visited", "index_tiles_causal", "index_kl_sum",
+    "index_kl_count")
+
+
+def _index_layers(cfg: DecoderConfig) -> int:
+    """The layers whose attention an indexer steers."""
+    return sum(a == "full" for a, _ in cfg.kinds) if cfg.index_topk else 0
 
 
 def _delta_layers(cfg: DecoderConfig) -> int:
@@ -1743,8 +1890,11 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     (the score tiles `flash_fwd` walks a step and those of them it runs
     with no mask, over sequences, heads, layer passes, the MTP block and
     a rematerialised block's second forward:
-    `ops.attention.forward_tiles`, the runs the kernel follows);
-    nothing otherwise."""
+    `ops.attention.forward_tiles`, the runs the kernel follows); under
+    `cfg.index_topk` `index_topk`, `index_rows` (the query rows a step
+    selects keys for: batch x T x `full` layers, 0 where T <= topk) and
+    `index_tile` (`"256x512"`: the forward tile whose counts the
+    selection hands the kernels); nothing otherwise."""
     b, t = batch_shape
     facts = {}
     unmasked = walked = 0
@@ -1786,6 +1936,13 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
         facts.update(kda_layers=layers,
                      kda_chunks=layers * b * (t // DELTA_CHUNK),
                      kda_heads=cfg.delta_key_heads)
+    if cfg.index_topk:
+        facts.update(
+            index_topk=cfg.index_topk,
+            index_rows=b * t * _index_layers(cfg) if t > cfg.index_topk
+            else 0,
+            index_tile=f"{min(cfg.attn_block_q, t)}x"
+                       f"{min(cfg.attn_block_k, t)}")
     if cfg.diffusion_block:
         visited, plane = diffusion_tiles(
             2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)
@@ -1930,6 +2087,28 @@ def stateful_loss(params, state, tokens, cfg: DecoderConfig):
                 + counts[f"kda_{name}_sum"].sum(),
                 f"kda_{name}_count": old[f"kda_{name}_count"]
                 + float(count)})
+    if cfg.index_topk:
+        b, t = tokens.shape
+        layers = _index_layers(cfg)
+        causal = float(layers * b * (t * (t + 1) // 2))
+        tiles = float(layers * b * forward_tiles(
+            t, cfg.head_dim, cfg.dtype, cfg.attn_block_q,
+            cfg.attn_block_k)[1])
+        selects = "index_pairs_selected" in counts  # else T <= index_topk
+        step = {
+            "index_pairs_selected": counts["index_pairs_selected"].sum()
+            if selects else causal,
+            "index_pairs_causal": causal,
+            "index_pairs_beyond_window":
+            counts["index_pairs_beyond_window"].sum() if selects else 0.0,
+            "index_tiles_visited": counts["index_tiles_visited"].sum()
+            if selects else tiles,
+            "index_tiles_causal": tiles,
+            "index_kl_sum": lax.stop_gradient(counts["index_kl_sum"].sum()),
+            "index_kl_count": float(layers * b * t)}
+        new.update({name: old[name] + step[name] for name in step},
+                   loss_main=lax.stop_gradient(counts["loss_main"]),
+                   loss_index=lax.stop_gradient(counts["loss_index"]))
     if cfg.shared_gate:
         new.update(
             shared_gate_sum=old["shared_gate_sum"]

@@ -93,6 +93,28 @@ sides) and L be whole blocks; else the dense fallback, which builds the
 same mask from the same function. With `diffusion=None` every such
 branch folds away: the traced program is the one this file built
 before.
+
+A sixth, off by default: a mask that is DATA (`selected=(plane,
+tile_counts)`; learned sparse attention, `ops/sparse_index.py`). The
+plane `[B, T, T]` int8 says which keys at or before it a query of a
+batch row sees, the same for every head; it is cut into tiles outside
+the kernels (a query block's row of `[block_q, block_k]` tiles forward,
+a key block's column of `[block_k, block_q]` tiles — the keys along a
+tile's rows, as the backward has its scores — backward: two int8
+transposes in XLA, so no kernel slices a plane along its lanes). Both
+kernels keep the causal walk, mask a tile by `where(plane > 0, s,
+NEG_INF)` and, by a count a tile prefetched as scalars, SKIP a tile
+that holds no selected pair; a row's first selected score rescales
+whatever its empty tiles left behind by exp(NEG_INF - s) = 0.0, and
+every row selects a key. The kernels keep their names, grouped heads
+share K, V and the plane's tile, and the call also returns the rows'
+log-sum-exp over the selection (the indexer's loss rebuilds the
+probabilities from it). The forward keeps the runs' loop forms (the
+diagonal run's last block straight-line, three blocks an iteration
+before it) with the plane's tile on every block of both runs. A shape
+no tile divides takes the dense form over the same plane. With
+`selected=None` every such branch folds away, in both kernels and both
+calls: the traced program is the one this file built before.
 """
 
 from __future__ import annotations
@@ -293,9 +315,17 @@ def _walk(tile, start, stop, carry, most: int, fewest: int):
     return tile(stop, carry) if peeled else carry
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
-                  causal: bool, scale: float, window: int | None = None,
-                  diffusion: int | None = None):
+def _flash_kernel(*refs, block_k: int, causal: bool, scale: float,
+                  window: int | None = None, diffusion: int | None = None,
+                  heads: int | None = None):
+    """`heads` (None: no selection): under a selection plane the query
+    heads of a batch row; the refs then start with the tiles' counts (a
+    scalar prefetch) and hold the query block's row of the plane's tiles
+    after v."""
+    if heads is None:
+        q_ref, k_ref, v_ref, o_ref, *lse_ref = refs
+    else:
+        counts_ref, q_ref, k_ref, v_ref, plane_ref, o_ref, *lse_ref = refs
     qi = pl.program_id(1)
     q = q_ref[...]  # [block_q, d]
     t = k_ref.shape[0]
@@ -306,6 +336,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         reach = _diffusion_reach(
             qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0), t // 2, diffusion)
+    if heads is not None:
+        # this query block's row of the counts, one a key block
+        counted = ((pl.program_id(0) // heads) * pl.num_programs(1)
+                   + qi) * (t // block_k)
 
     def body(ki, carry, masked: bool = True):
         o, m, l = carry
@@ -314,7 +348,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if masked and diffusion is not None:
+        if heads is not None:
+            # the plane's tile is the mask (it holds no pair above the
+            # diagonal), the same for every head of the batch row
+            s = jnp.where(plane_ref[ki].astype(jnp.float32) > 0, s, NEG_INF)
+        elif masked and diffusion is not None:
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(_diffusion_keep(k_pos, reach, diffusion), s,
@@ -338,6 +376,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         o_new = o * corr[:, None] + pv
         return o_new, m_new, l_new
 
+    tile = body
+    if heads is not None:
+        # a tile that holds no selected pair is skipped. A row that has
+        # seen nothing yet carries m = NEG_INF and l = the masked entries
+        # it met; its first selected score rescales both by
+        # exp(NEG_INF - s) = 0.0 exactly, and every row selects a key
+        def tile(ki, carry, masked: bool = True):
+            return jax.lax.cond(counts_ref[counted + ki] > 0,
+                                functools.partial(body, ki), lambda c: c,
+                                carry)
+
     carry = (jnp.zeros((block_q, d), jnp.float32),
              jnp.full((block_q,), NEG_INF, jnp.float32),
              jnp.zeros((block_q,), jnp.float32))
@@ -351,14 +400,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
         # a block leaves it with m = NEG_INF (`_causal_key_blocks`),
         # and every row sees its own key in the end. Same products,
         # same order, same float32 sums as one masked loop over them
-        # all: every output is that loop's, bit for bit
+        # all: every output is that loop's, bit for bit. (Under a
+        # selection the runs are the causal ones and the plane cuts
+        # every block of both.)
         runs = _key_runs(qi, t, block_q, block_k, window, diffusion)
         # ... and the same bounds for every query block at once: what
         # is static of each run chooses its form
         static = _key_runs(np.arange(t // block_q), t, block_q, block_k,
                            window, diffusion, np)
         for (start, stop, masked), (lo, hi, _) in zip(runs, static):
-            carry = _walk(functools.partial(body, masked=masked), start,
+            carry = _walk(functools.partial(tile, masked=masked), start,
                           stop, carry, int(np.max(hi - lo)),
                           int(np.min(hi - lo)))
     o, m, l = carry
@@ -388,21 +439,42 @@ def _diffusion_tiled(t: int, *sides: int) -> bool:
     return all(side <= t // 2 and t // 2 % side == 0 for side in sides)
 
 
+def _kernel_tiles(t: int, d: int, d_v: int, dtype, block_q: int | None,
+                  block_k: int | None, diffusion: int | None = None):
+    """The forward kernel's tile for a call, or None where the shape
+    takes the dense path."""
+    block_q, block_k = fwd_tiles(t, d, dtype, block_q, block_k, d_v)
+    if not _flash_aligned(t, d, block_q, block_k, d_v) or (
+            diffusion is not None
+            and not _diffusion_tiled(t, block_q, block_k)):
+        return None
+    return min(block_q, t), min(block_k, t)
+
+
 def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                     block_q: int | None, block_k: int | None, interpret: bool,
                     window: int | None = None, save_lse: bool = False,
-                    diffusion: int | None = None):
+                    diffusion: int | None = None, selected=None):
     """`block_q`, `block_k`: None asks `fwd_tiles`. `save_lse` (under a
     gradient): returns (out, lse), lse [B, H, T] float32 — None where
-    the dense fallback ran."""
+    the dense fallback ran. `selected`: (plane, tile counts or None);
+    the call then returns (out, lse) whatever `save_lse`, the dense
+    form's lse where that ran."""
     b, t, h, d = q.shape
     d_v = v.shape[-1]
     if k.shape[-1] != d or v.shape[:3] != k.shape[:3]:
         raise ValueError(
             f"flash_attention: q {q.shape} and k {k.shape} share the score "
             f"width, k and v {v.shape} batch, length and heads")
-    block_q, block_k = fwd_tiles(t, d, q.dtype, block_q, block_k, d_v)
-    plain = window is None and k.shape[2] == h and diffusion is None
+    if selected is not None and (
+            not causal or window is not None or diffusion is not None
+            or selected[0].shape != (b, t, t)):
+        raise ValueError(
+            "flash_attention: a selection keeps the causal walk and takes "
+            "neither a window nor the block-diffusion mask; its plane "
+            f"{selected[0].shape} is [batch, T, T]")
+    plain = window is None and k.shape[2] == h and diffusion is None \
+        and selected is None
     if not plain and (not causal or h % k.shape[2]):
         raise ValueError(
             "flash_attention: a window, grouped heads and the "
@@ -414,9 +486,10 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
         raise ValueError(
             f"flash_attention: diffusion={diffusion} cuts the two halves "
             f"of {t} rows into whole blocks, and takes no window")
-    if not _flash_aligned(t, d, block_q, block_k, d_v) or (
-            diffusion is not None
-            and not _diffusion_tiled(t, block_q, block_k)):
+    tiles = _kernel_tiles(t, d, d_v, q.dtype, block_q, block_k, diffusion)
+    if tiles is None:
+        if selected is not None:
+            return _dense_selected(q, k, v, selected[0], scale)
         if t >= 512:
             import warnings
 
@@ -426,8 +499,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
                 " to a multiple of 8 for the pallas kernel", stacklevel=2)
         out = _dense_fallback(q, k, v, causal, scale, window, diffusion)
         return (out, None) if save_lse else out
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
+    block_q, block_k = tiles
     # once a traced call: the tile is static in the compiled program
     logger.debug("flash_fwd %s %s: tiles %d x %d", q.shape, q.dtype, block_q,
                  block_k)
@@ -440,7 +512,15 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, scale: float,
         call = functools.partial(call, diffusion=diffusion)
     # batch rows are independent kernel instances: under a sharded jit
     # each device runs the kernel on its own [b, T, H, D] slice
-    return over_leading_dim(call, (True, True, True))(q, k, v)
+    if selected is None:
+        return over_leading_dim(call, (True, True, True))(q, k, v)
+    plane, counts = selected
+    if counts is None or counts.shape[1:] != (t // block_q, t // block_k):
+        counts = tile_counts(plane, block_q, block_k)   # this call's tile's
+    return over_leading_dim(
+        lambda q, k, v, *selected: call(q, k, v, selected=selected,
+                                        save_lse=True),
+        (True,) * 5)(q, k, v, plane, counts)
 
 
 def _fold(x):
@@ -456,7 +536,8 @@ def _unfold(x, b):
 
 def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
                 block_k: int, interpret: bool, window: int | None = None,
-                save_lse: bool = False, diffusion: int | None = None):
+                save_lse: bool = False, diffusion: int | None = None,
+                selected=None):
     b, t, h, d = q.shape
     h_kv, d_v = k.shape[2], v.shape[3]
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
@@ -467,46 +548,66 @@ def _flash_call(q, k, v, *, causal: bool, scale: float, block_q: int,
         kernel = functools.partial(kernel, window=window)
     if diffusion is not None:
         kernel = functools.partial(kernel, diffusion=diffusion)
+    # (an index map's last argument under a selection: the counts' ref)
     if h_kv == h:
-        def kv_index(bh, qi):
+        def kv_index(bh, qi, *_):
             return (bh, 0, 0)
     else:
         # query head g reads key/value head g // group: consecutive
         # query heads of a group map to one block, fetched once
         group = h // h_kv
 
-        def kv_index(bh, qi):
+        def kv_index(bh, qi, *_):
             return ((bh // h) * h_kv + (bh % h) // group, 0, 0)
-    out_specs = pl.BlockSpec((None, block_q, d_v), lambda bh, qi: (bh, qi, 0))
+    out_specs = pl.BlockSpec((None, block_q, d_v),
+                             lambda bh, qi, *_: (bh, qi, 0))
     out_shape = jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype)
     # two widths (keys wider than values): a whole sequence of 192-wide
     # keys sits in VMEM on 256 lanes, more than the default scope holds
     # at 8k tokens; so do k and v of ONE width from 256 x 8192 in bf16
-    # up (double-buffered: 4 t d bytes an item); below that the call is
-    # the one it always was
-    fits = d_v == d and 4 * t * d * q.dtype.itemsize <= _FWD_KV_DEFAULT_SCOPE
+    # up (double-buffered: 4 t d bytes an item), beside a query block's
+    # row of a plane's tiles (int8, double-buffered) under a selection;
+    # below that the call is the one it always was
+    held = 4 * t * d * q.dtype.itemsize + (
+        0 if selected is None else 2 * block_q * t)
+    fits = d_v == d and held <= _FWD_KV_DEFAULT_SCOPE
     wide = {} if fits else {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=_FWD_WIDE_VMEM_LIMIT)}
     if save_lse:
         # one [1, block_q] row of float32 a grid step
         out_specs = [out_specs, pl.BlockSpec(
-            (None, None, 1, block_q), lambda bh, qi: (bh, qi, 0, 0))]
+            (None, None, 1, block_q), lambda bh, qi, *_: (bh, qi, 0, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct(
             (b * h, t // block_q, 1, block_q), jnp.float32)]
-    out = pl.pallas_call(
-        kernel,
+    grid = dict(
         grid=(b * h, t // block_q),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
             pl.BlockSpec((None, t, d), kv_index),
             pl.BlockSpec((None, t, d_v), kv_index),
         ],
-        out_specs=out_specs,
+        out_specs=out_specs)
+    operands = (qf, kf, vf)
+    if selected is not None:
+        plane, counts = selected
+        kernel = functools.partial(kernel, heads=h)
+        # a query block's row of the plane's tiles: the same block for
+        # every head of a batch row; a count a tile, prefetched
+        grid["in_specs"].append(pl.BlockSpec(
+            (None, None, t // block_k, block_q, block_k),
+            lambda bh, qi, *_: (bh // h, qi, 0, 0, 0)))
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        operands = (counts.reshape(-1), *operands,
+                    plane_tiles(plane, block_q, block_k, False))
+    out = pl.pallas_call(
+        kernel,
+        **grid,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
         **wide,
-    )(qf, kf, vf)
+    )(*operands)
     if save_lse:
         out, lse = out
         return _unfold(out, b), lse.reshape(b, h, t)
@@ -643,7 +744,8 @@ def masked_attention(q, k, v, pad_mask, causal=False, scale=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
-                    window: int | None = None, diffusion: int | None = None):
+                    window: int | None = None, diffusion: int | None = None,
+                    selected=None):
     """q: [B, T, H, D_qk]; k: [B, T, H_kv, D_qk]; v: [B, T, H_kv, D_v]
     with H a multiple of H_kv (query head g reads key/value head
     g // (H // H_kv)); D_v may differ from D_qk. `scale`: None is
@@ -652,35 +754,49 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
     asks `fwd_tiles`, a number is taken as given. `diffusion`: the T
     rows are `[clean ; noised]` copies of T / 2 positions in blocks of
     that many, under the block-diffusion mask in the causal one's place
-    (the header has its rule; needs causal, takes no window). Returns
-    [B, T, H, D_v]."""
+    (the header has its rule; needs causal, takes no window).
+    `selected`: `(plane, tile_counts)`, the mask as DATA — `plane` [B,
+    T, T] int8, nonzero where query t of the batch row sees key s (every
+    head the same; only pairs with s <= t, and at least one a row),
+    `tile_counts` [B, T / block_q, T / block_k] int32 its nonzero
+    entries a forward tile, or None (they are counted here); needs
+    causal, takes neither window nor diffusion; no gradient reaches
+    either. Returns [B, T, H, D_v]; under a selection (that, the rows'
+    log-sum-exp over their selected keys [B, H, T] float32, a constant:
+    what the indexer's loss rebuilds the probabilities from)."""
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     return _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                            block_q=block_q, block_k=block_k,
                            interpret=not is_tpu(), window=window,
-                           diffusion=diffusion)
+                           diffusion=diffusion, selected=selected)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, window, diffusion=None):
+def _fwd(q, k, v, causal, scale, block_q, block_k, window, diffusion=None,
+         selected=None):
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
     out, lse = _flash_fwd_impl(q, k, v, causal=causal, scale=actual_scale,
                                block_q=block_q, block_k=block_k,
                                interpret=not is_tpu(), window=window,
-                               save_lse=True, diffusion=diffusion)
-    if lse is not None:   # the kernel ran: name what it produced
+                               save_lse=True, diffusion=diffusion,
+                               selected=selected)
+    # (under a selection the dense form gives a log-sum-exp too)
+    kernel = lse is not None and (selected is None or _kernel_tiles(
+        q.shape[1], q.shape[3], v.shape[3], q.dtype, block_q,
+        block_k) is not None)
+    if kernel:   # the kernel ran: name what it produced
         out = checkpoint_name(out, SAVED_ACROSS_REMAT[0])
         lse = checkpoint_name(lse, SAVED_ACROSS_REMAT[1])
-    return out, (q, k, v, out, lse)
+    residuals = (q, k, v, out, lse if kernel else None, selected)
+    return (out if selected is None else (out, lse)), residuals
 
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 
 
-def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                      dqt_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc, *,
-                      block_q: int, causal: bool, scale: float,
+def _flash_bwd_kernel(*refs, block_q: int, causal: bool, scale: float,
                       window: int | None = None, group: int = 1,
-                      diffusion: int | None = None):
+                      diffusion: int | None = None,
+                      per_row: int | None = None):
     """One key block of the backward, in one pass over the query blocks
     at or after it (all of them without the mask; under a window only
     those that still reach it): dk and dv of its keys, and its share of
@@ -699,7 +815,19 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     delta and dq.T are ONE query head's, k and v its key/value head's,
     and dk, dv are summed over the group's query heads in float32
     scratch of a whole sequence, a key block written when its last query
-    head has been through."""
+    head has been through.
+
+    Under a selection plane (`per_row`: the grid's rows a batch row; None
+    without one) the refs start with the tiles' counts, a scalar
+    prefetch, and hold the key block's column of the plane's tiles, the
+    keys along a tile's rows, after v: the tile is the mask, and a tile
+    that holds no selected pair is skipped."""
+    if per_row is None:
+        (q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dqt_ref, dk_ref,
+         dv_ref, dqt_acc, dk_acc, dv_acc) = refs
+    else:
+        (counts_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+         plane_ref, dqt_ref, dk_ref, dv_ref, dqt_acc, dk_acc, dv_acc) = refs
     grouped = group > 1
     ki = pl.program_id(2 if grouped else 1)
     k = k_ref[...]   # [block_k, d]
@@ -721,7 +849,11 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     else:
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-    if diffusion is not None:
+    if per_row is not None:
+        # this key block's column of the counts, one a query block
+        counted = ((pl.program_id(0) // per_row) * pl.num_programs(
+            2 if grouped else 1) + ki) * (q_ref.shape[0] // block_q)
+    elif diffusion is not None:
         half = q_ref.shape[0] // 2
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 0)
@@ -736,7 +868,10 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         do = do_ref[rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if diffusion is not None:
+        if per_row is not None:
+            st = jnp.where(plane_ref[qi].astype(jnp.float32) > 0, st,
+                           NEG_INF)
+        elif diffusion is not None:
             # what each of the tile's queries sees: a row along the lanes
             reach = _diffusion_reach(
                 qi * block_q + jax.lax.broadcasted_iota(
@@ -757,6 +892,13 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                                 preferred_element_type=jnp.float32)
         dk_acc[keys] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
         dqt_acc[qi] += jnp.dot(kt, dst, preferred_element_type=jnp.float32)
+
+    if per_row is not None:
+        tile = body
+
+        def body(qi, _):
+            pl.when(counts_ref[counted + qi] > 0)(
+                functools.partial(tile, qi, None))
 
     if diffusion is not None:
         # the clean query blocks from this key block's first block on,
@@ -821,7 +963,17 @@ def _bwd_tiles(t: int, d: int, dtype, d_v: int | None = None
     every shape read, the window moves nothing, and 1024 x 1024's 2 % at
     8k without a window is a loss at T 1024: neither d, dtype, the
     window nor a value width `d_v` other than d moves the rule yet (it
-    compiles for float32, for heads of 128 and 256 and for 192 | 128)."""
+    compiles for float32, for heads of 128 and 256 and for 192 | 128).
+
+    At T = 16 384 (PR 65, my chip runs: `[1, 16384, 32 | 4, 128]` bf16,
+    the forward at 256 x 512 and this backward at 512 x 512, wall time
+    of forward + backward a call): 51.7 ms causal, 64.7 ms under a
+    selection plane that keeps 2048 keys a query — the backward alone
+    about 34 and 36 ms. Plain, q and do of a whole sequence
+    double-buffered (16 MiB), dq.T (8 MiB) and 16 MiB of float32 dk / dv
+    scratch stayed inside `_BWD_VMEM_LIMIT`; under a selection the key
+    block's column of the plane (8 MiB, double-buffered) did not, and
+    that form asks for `_BWD_SELECTED_VMEM_LIMIT`."""
     block = _fit(t, 512)
     return block, block
 
@@ -888,6 +1040,19 @@ def _fwd_tiles(t: int, d: int, dtype, d_v: int | None = None
     blocks an iteration (4-6 % more at 8k); the unmasked run adds 3
     points under a window and 2 under block diffusion.
 
+    At T = 16 384 (PR 65, my chip runs, `[1, 16384, 32 | 4, 128]` bf16,
+    wall time a call, writing the lse): causal 256 x 512 17.82 ms,
+    512 x 512 17.43; under a selection plane that keeps 2048 keys a
+    query (every causal tile holds a pair: none is skipped; the plane's
+    cut into tiles and the count a tile, 256 MiB of int8 through XLA,
+    are in the figure) 256 x 512 28.88 ms, 512 x 512 30.39: the plane's
+    tile, int8 widened to float32, compared and selected a head and
+    block, costs 62 % where no block runs unmasked (as ONE masked loop,
+    without the last block straight-line and three an iteration, the
+    same call read 30.65 and 32.57). K and V of a key head whole
+    (16 MiB double-buffered) and a query block's row of the plane's
+    tiles (8 MiB) stayed inside `_FWD_WIDE_VMEM_LIMIT`.
+
     A t that 128 does not divide (nor t itself, below 128) never
     reached the kernel: it answers 128 x 128, which `_flash_aligned`
     refuses as it always has, and the call takes the dense path. `d_v`,
@@ -911,7 +1076,7 @@ def fwd_tiles(t: int, d: int, dtype, block_q: int | None = None,
             rule_k if block_k is None else block_k)
 
 
-def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
+def _flash_bwd_call(q, k, v, o, lse, g, *plane, causal: bool, scale: float,
                     interpret: bool, window: int | None = None,
                     diffusion: int | None = None):
     """(dq, dk, dv) in one kernel, `flash_bwd_fused`. Scores, lse, delta,
@@ -919,7 +1084,8 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
     dtype for the MXU, as the forward casts p. The grid is (batch x
     heads, key blocks); with fewer key/value heads than query heads it
     is (batch x key/value heads, group, key blocks), and no K, V, dk or
-    dv of a repeated head ever reaches HBM."""
+    dv of a repeated head ever reaches HBM. `plane`: a selection's, or
+    nothing."""
     b, t, h, d = q.shape
     h_kv, d_v = k.shape[2], v.shape[3]
     group = h // h_kv
@@ -955,65 +1121,138 @@ def _flash_bwd_call(q, k, v, o, lse, g, *, causal: bool, scale: float,
         def at(bkv, gi, ki):
             return bkv * group + gi, bkv, jnp.where(gi == group - 1, ki, 0)
 
+    n = len(grid)   # (an index map's argument after these: the counts' ref)
+
     def whole(width):   # a query head's whole sequence: q, do
-        return pl.BlockSpec((None, t, width), lambda *i: (at(*i)[0], 0, 0))
+        return pl.BlockSpec((None, t, width),
+                            lambda *i: (at(*i[:n])[0], 0, 0))
 
     def kv_spec(width):
         return pl.BlockSpec((None, block_k, width),
-                            lambda *i: (at(*i)[1], i[-1], 0))
+                            lambda *i: (at(*i[:n])[1], i[n - 1], 0))
 
     def dkv_spec(width):
         return pl.BlockSpec((None, block_k, width),
-                            lambda *i: at(*i)[1:] + (0,))
+                            lambda *i: at(*i[:n])[1:] + (0,))
 
     row_spec = pl.BlockSpec((None, num_q, 1, block_q),
-                            lambda *i: (at(*i)[0], 0, 0, 0))
+                            lambda *i: (at(*i[:n])[0], 0, 0, 0))
     # dk, dv scratch: a key block's, or under grouped heads the whole
     # sequence's, summed over the group
     kv_rows = block_k if group == 1 else t
-    dqt, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, block_q=block_q, causal=causal,
-                          scale=scale, window=window, group=group,
-                          diffusion=diffusion),
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
+                               causal=causal, scale=scale, window=window,
+                               group=group, diffusion=diffusion)
+    spec = dict(
         grid=grid,
         in_specs=[whole(d), whole(d_v), row_spec, row_spec, kv_spec(d),
                   kv_spec(d_v)],
         out_specs=[pl.BlockSpec((None, num_q, d, block_q),
-                                lambda *i: (at(*i)[0], 0, 0, 0)),
+                                lambda *i: (at(*i[:n])[0], 0, 0, 0)),
                    dkv_spec(d), dkv_spec(d_v)],
+        scratch_shapes=[pltpu.VMEM((num_q, d, block_q), jnp.float32),
+                        pltpu.VMEM((kv_rows, d), jnp.float32),
+                        pltpu.VMEM((kv_rows, d_v), jnp.float32)])
+    operands = (_fold(q), _fold(g), rows(lse), rows(delta), _fold(k),
+                _fold(v))
+    if plane:
+        per_row = grid[0] // b
+        kernel = functools.partial(kernel, per_row=per_row)
+        # a key block's column of the plane's tiles, the same block for
+        # every head of a batch row; a count a tile, prefetched
+        spec["in_specs"].append(pl.BlockSpec(
+            (None, None, num_q, block_k, block_q),
+            lambda *i: (i[0] // per_row, i[n - 1], 0, 0, 0)))
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
+        operands = (
+            tile_counts(*plane, block_q, block_k).swapaxes(1, 2).reshape(-1),
+            *operands, plane_tiles(*plane, block_q, block_k, True))
+    dqt, dk, dv = pl.pallas_call(
+        kernel,
+        **spec,
         out_shape=[jax.ShapeDtypeStruct((b * h, num_q, d, block_q), q.dtype),
                    jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h_kv, t, d_v), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((num_q, d, block_q), jnp.float32),
-                        pltpu.VMEM((kv_rows, d), jnp.float32),
-                        pltpu.VMEM((kv_rows, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) + ("arbitrary",) * (
                 len(grid) - 1),
-            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+            vmem_limit_bytes=_BWD_SELECTED_VMEM_LIMIT if plane
+            else _BWD_VMEM_LIMIT),
         interpret=interpret,
         name="flash_bwd_fused",
-    )(_fold(q), _fold(g), rows(lse), rows(delta), _fold(k), _fold(v))
+    )(*operands)
     dq = dqt.reshape(b, h, num_q, d, block_q).transpose(
         0, 2, 4, 1, 3).reshape(b, t, h, d)
     return dq, _unfold(dk, b), _unfold(dv, b)
 
 
 def _bwd(causal, scale, block_q, block_k, window, diffusion, residuals, g):
-    q, k, v, o, lse = residuals
+    q, k, v, o, lse, selected = residuals
     actual_scale = scale if scale is not None else q.shape[-1] ** -0.5
+    # an integer input's cotangent; the selection's log-sum-exp is
+    # handed on as a constant
+    nothing = jax.tree.map(
+        lambda x: np.zeros(x.shape, jax.dtypes.float0), selected)
+    if selected is not None:
+        g, _ = g
     if lse is None:
         # unaligned fallback, as the forward's: one checkpointed dense block
-        f = functools.partial(_dense_fallback, causal=causal,
-                              scale=actual_scale, window=window,
-                              diffusion=diffusion)
+        if selected is None:
+            f = functools.partial(_dense_fallback, causal=causal,
+                                  scale=actual_scale, window=window,
+                                  diffusion=diffusion)
+        else:
+            def f(q, k, v):
+                return _dense_selected(q, k, v, selected[0], actual_scale)[0]
         _, vjp = jax.vjp(jax.checkpoint(f), q, k, v)
-        return vjp(g)
+        return (*vjp(g), nothing)
     call = functools.partial(_flash_bwd_call, causal=causal,
                              scale=actual_scale, interpret=not is_tpu(),
                              window=window, diffusion=diffusion)
+    plane = () if selected is None else selected[:1]
     # as the forward: each device takes its own rows of the batch
-    return over_leading_dim(call, (True,) * 6)(q, k, v, o, lse, g)
+    return (*over_leading_dim(call, (True,) * (6 + len(plane)))(
+        q, k, v, o, lse, g, *plane), nothing)
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+# What `flash_bwd_fused` may hold under a selection: `_BWD_VMEM_LIMIT`'s,
+# and the key block's column of the plane (T x block_k int8,
+# double-buffered)
+_BWD_SELECTED_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def plane_tiles(plane, block_q: int, block_k: int, keys_first: bool):
+    """[B, T, T] -> the plane cut into tiles a kernel indexes by their
+    leading dimensions: [B, T / bq, T / bk, bq, bk], or with the KEYS
+    first (the backward's layout: a tile has the keys along its rows)
+    [B, T / bk, T / bq, bk, bq]."""
+    b, t, _ = plane.shape
+    cut = plane.reshape(b, t // block_q, block_q, t // block_k, block_k)
+    return cut.transpose((0, 3, 1, 4, 2) if keys_first else (0, 1, 3, 2, 4))
+
+
+def tile_counts(plane, block_q: int, block_k: int):
+    """The selected pairs a tile of a plane (or of some rows of one),
+    [B, rows / bq, T / bk] int32."""
+    b, rows, t = plane.shape
+    return plane.reshape(b, rows // block_q, block_q, t // block_k,
+                         block_k).sum((2, 4), dtype=jnp.int32)
+
+
+def _dense_selected(q, k, v, plane, scale):
+    """Dense attention over the selection (grouped heads or not), and
+    the rows' log-sum-exp [B, H, T]: what a call no tile divides
+    takes."""
+    b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    scores = jnp.einsum("bqkgd,bskd->bkgqs",
+                        q.reshape(b, t, h_kv, h // h_kv, d), k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where((plane != 0)[:, None, None], scores, NEG_INF)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(scores, axis=-1), v)
+    return (out.reshape(b, t, h, v.shape[-1]).astype(q.dtype),
+            jax.lax.stop_gradient(jax.nn.logsumexp(
+                scores, axis=-1).reshape(b, h, t)))

@@ -46,10 +46,11 @@ def on_tpu(monkeypatch):
     interpret mode: steer them to Mosaic, in the test."""
     from ray_tpu.collective.backends import pallas_backend
     from ray_tpu.ops import (attention, batchnorm, gated_delta, kda,
-                             layernorm, moe_gmm, short_conv, ssd)
+                             layernorm, moe_gmm, short_conv, sparse_index,
+                             ssd)
 
     for mod in (attention, batchnorm, gated_delta, kda, layernorm, moe_gmm,
-                short_conv, ssd, pallas_backend):
+                short_conv, sparse_index, ssd, pallas_backend):
         monkeypatch.setattr(mod, "is_tpu", lambda: True)
 
 
@@ -811,6 +812,147 @@ def test_kda_runs_on_the_chip():
     for name, a, r in zip("q k v g beta".split(), g_got, g_want):
         errs[name] = _rel_err(a, r)
     print("kda", errs)
+    assert max(errs.values()) < 0.03, errs
+
+
+def _keye_specs(one_chip, t=16384):
+    """Keye's shapes at the cell's length: the attention's q, k, v (32
+    / 4 / 4 heads of 128), the indexer's q_I, k_I, w (16 heads of 64,
+    one key head) and a selection plane."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return (spec((1, t, 32, 128)), spec((1, t, 4, 128)),
+            spec((1, t, 4, 128)), spec((1, t, 16, 64)), spec((1, t, 64)),
+            spec((1, t, 16), jnp.float32), spec((1, t, t), jnp.int8))
+
+
+def test_index_select_at_the_cells_length(one_chip, on_tpu):
+    """The selection of 2048 keys a query over 16 384: the scores of a
+    strip are ONE Mosaic call, `index_scores`, inside the walk over the
+    strips; the plane comes out int8, and no [T, T] float32 array nor a
+    [T, 16, T] one is in the text."""
+    from ray_tpu.ops import sparse_index
+
+    _, _, _, q_i, k_i, w, _ = _keye_specs(one_chip)
+    text = _compiled_text(
+        lambda *a: sparse_index.index_select(*a, 2048, (256, 512)),
+        q_i, k_i, w)
+    assert text.count("tpu_custom_call") == 1 and "index_scores" in text
+    assert "s8[1,16384,16384]" in text and "f32[1,512,16384]" in text
+    assert "f32[1,16384,16384]" not in text
+    assert "[1,16384,16,16384]" not in text \
+        and "[1,16,16384,16384]" not in text
+
+
+def test_flash_attention_under_a_selection_at_the_cells_length(one_chip,
+                                                               on_tpu):
+    """`flash_fwd` and `flash_bwd_fused` with the plane as an input at
+    [1, 16 384, 32 | 4, 128]: K and V of a key head whole (16 MiB
+    double-buffered) beside a query block's row of the plane's tiles
+    forward; 16 MiB of float32 dk / dv scratch and a key block's column
+    of the plane backward, under the kernels' own names, no dense
+    fallback beside them."""
+    from ray_tpu.ops import attention
+
+    q, k, v, _, _, _, plane = _keye_specs(one_chip)
+
+    def fwd(q, k, v, plane):
+        return attention.flash_attention(q, k, v, True, None, 256, 512,
+                                         selected=(plane, None))[0]
+
+    text = _compiled_text(fwd, q, k, v, plane)
+    assert text.count("tpu_custom_call") == 1 and "flash_fwd" in text
+    text = _compiled_text(jax.grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(), (0, 1, 2)),
+        q, k, v, plane)
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    assert "f32[1,4,8,16384,16384]" not in text     # no dense scores
+
+
+def test_index_kl_at_the_cells_length(one_chip, on_tpu):
+    """The indexer's loss and its gradient in one Mosaic call,
+    `index_kl`, 512 x 512 tiles with every head of the attention's
+    query tile in VMEM; no strip of probabilities in the text."""
+    from ray_tpu.ops import sparse_index
+
+    q, k, _, q_i, k_i, w, plane = _keye_specs(one_chip)
+    lse = jax.ShapeDtypeStruct((1, 32, 16384), jnp.float32,
+                               sharding=one_chip)
+    lse_i = jax.ShapeDtypeStruct((1, 16384), jnp.float32, sharding=one_chip)
+    text = _compiled_text(jax.value_and_grad(
+        lambda q_i, k_i, w, *rest: sparse_index.index_kl(
+            q_i, k_i, w, *rest, 128 ** -0.5), (0, 1, 2)),
+        q_i, k_i, w, q, k, plane, lse, lse_i)
+    assert text.count("tpu_custom_call") == 1 and "index_kl" in text
+    assert "f32[1,4,8,64,16384]" not in text and "while" not in text
+
+
+def test_index_select_and_selected_attention_run_on_the_chip():
+    """On a chip: the selected SET against `lax.top_k`'s by the plain
+    road (float32 inputs: no rounding parts the two), and the attention
+    over it, values and gradients in bf16, against the dense form in
+    float32, at 2048 tokens and 512 keys a query."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the chip")
+    from ray_tpu.ops import attention, sparse_index
+
+    keys = jax.random.split(jax.random.key(23), 7)
+    b, t, f32 = 1, 2048, jnp.float32
+    q_i = jax.random.normal(keys[0], (b, t, 16, 64), f32)
+    k_i = jax.random.normal(keys[1], (b, t, 64), f32)
+    # (the model's factors on w, 16 ** -0.5 * 64 ** -0.5: at unit scale
+    # the scores' softmax is one key's and the loss's gradient, a
+    # difference of near-equal numbers, reads 5-6 % from the plain
+    # strips' on the chip)
+    w = jax.random.normal(keys[2], (b, t, 16), f32) / 32
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda *a: sparse_index.index_select(
+            *a, 512, (256, 512)))(q_i, k_i, w)
+        want = jax.jit(lambda *a: sparse_index.index_select_xla(
+            *a, 512, (256, 512)))(q_i, k_i, w)
+    agree = float((got[0] == want[0]).mean())
+    picked = int(got[0].sum())
+    print("index_select: planes agree on", agree, "pairs selected", picked)
+    assert picked == 512 * 513 // 2 + (t - 512) * 512
+    assert agree > 0.9999
+    q = jax.random.normal(keys[3], (b, t, 32, 128), f32)
+    k = jax.random.normal(keys[4], (b, t, 4, 128), f32)
+    v = jax.random.normal(keys[5], (b, t, 4, 128), f32)
+    weight = jax.random.normal(keys[6], (b, t, 32, 128), f32)
+    plane = got[0]
+
+    def both(fn, args):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a).astype(f32) * weight).sum(), (0, 1, 2)))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        _, g_want = both(lambda *a: attention._dense_selected(
+            *a, plane, 128 ** -0.5)[0], (q, k, v))
+    low = tuple(z.astype(jnp.bfloat16) for z in (q, k, v))
+    _, g_got = both(lambda *a: attention.flash_attention(
+        *a, True, None, 256, 512, selected=(plane, got[2]))[0], low)
+    errs = {name: _rel_err(a, r) for name, a, r in zip("qkv", g_got, g_want)}
+    print("selected attention", errs)
+    assert max(errs.values()) < 0.03, errs
+    # the indexer's loss and gradient: the kernel, fed the attention's
+    # own log-sum-exp, against the plain strips
+    lse = jax.jit(lambda *a: attention.flash_attention(
+        *a, True, None, 256, 512, selected=(plane, got[2]))[1])(*low)
+    scale = 128 ** -0.5
+    total, grads = jax.jit(lambda *a: sparse_index._kl_pass(
+        *a, plane, lse, got[1], scale, None))(
+            q_i.astype(jnp.bfloat16), k_i.astype(jnp.bfloat16), w, *low[:2])
+    # (the same rounded inputs: dI is a difference of near-equal
+    # probabilities, and inputs rounded apart move it by percents)
+    want_total, want_grads = jax.jit(lambda *a: sparse_index.index_kl_xla(
+        *a, plane, scale))(q_i.astype(jnp.bfloat16),
+                           k_i.astype(jnp.bfloat16), w, *low[:2])
+    errs = {name: _rel_err(a, r) for name, a, r in zip(
+        ("dq_i", "dk_i", "dw"), grads, want_grads)}
+    errs["kl"] = abs(float(total) - float(want_total)) / float(want_total)
+    print("index_kl", errs, float(total) / t)
     assert max(errs.values()) < 0.03, errs
 
 
