@@ -177,7 +177,10 @@ Each block is rematerialised in the backward pass (`cfg.remat`; with
 `"parts"` a block's mixer and its MLP each under a checkpoint of its
 own, so that the step never holds both parts' residuals: the delta
 mixer's float32 convolution output and entering states beside the
-expert block's static rows were the peak of the Qwen3-Next step). The
+expert block's static rows were the peak of the Qwen3-Next step). A
+rematerialised block keeps its input and nothing it computed but what
+`_kept_across_remat` names for its mixer: under `cfg.index_topk` the
+gradient that `index_kl`'s one pass made beside the loss (below). The
 loss is taken in chunks of tokens, each chunk's logits recomputed in the
 backward pass: at 16 k tokens over 38 k vocabulary rows the float32
 logits alone would be 2.5 GB.
@@ -210,8 +213,8 @@ periods and the weights are constants of both: a period is still traced
 once, the step holds one compute-dtype copy of the weight stacks, and
 the backward pass adds each walk's gradient stack into ONE float32
 stack a leaf (the sum over the walks is the gradient of a shared
-weight); each block is rematerialised as ever, so a block input is
-kept a layer and walk. `cfg.exit_gate` adds a gate
+weight); each block is rematerialised as without loops, so a block
+input is kept a layer and walk. `cfg.exit_gate` adds a gate
 (`params["exit_gate"]`: a weight `[d_model]` and a bias, one for all
 walks, float32) that reads every walk's normed output: `lam_t =
 sigmoid(w . x_t + b)`, survival `S_t = prod over j <= t of (1 -
@@ -241,7 +244,16 @@ runs over those alone, one selection for every head
 (`ops.flash_attention(selected=)`), and the indexer learns from the
 attention it steered: `L_I`, the mean over rows and layers of the KL
 divergence of the heads' averaged probabilities (a constant) from the
-softmax of I over the selection (`index_kl`, scope `index_loss`). The
+softmax of I over the selection (`index_kl`, scope `index_loss`).
+`index_kl` makes the loss and its gradient to q_I, k_I, w in ONE pass
+and its backward rule only scales the gradient, so a rematerialised
+block KEEPS those three arrays (`ops.sparse_index.KL_SAVED_ACROSS_REMAT`
+through `save_only_these_names`: at 16 384 tokens `[T, 16, 64]` bf16 +
+`[T, 64]` + `[T, 16]` = 35 MiB a layer, stacked by the layer scan): the
+recomputed copy of the pass then has no reader and is dead code, and the
+pass runs once a layer and step instead of twice. The attention's output
+and log-sum-exp are NOT kept: `flash_fwd` and `index_scores` run in both
+passes. The
 loss is `CE + cfg.index_loss_weight L_I`; by the two stop-gradients the
 indexer's four leaves get `L_I`'s gradient alone and every other leaf
 the cross-entropy's alone; `counts` gains `loss_main` and `loss_index`.
@@ -279,7 +291,8 @@ from ray_tpu.ops.gated_delta import (CHUNK as DELTA_CHUNK, gated_delta,
 from ray_tpu.ops.kda import kda
 from ray_tpu.ops.layernorm import rmsnorm
 from ray_tpu.ops.short_conv import mixer_conv, short_conv
-from ray_tpu.ops.sparse_index import index_kl, index_select
+from ray_tpu.ops.sparse_index import (KL_SAVED_ACROSS_REMAT, index_kl,
+                                      index_select)
 from ray_tpu.ops.ssd import CHUNK as SSD_CHUNK, ssd
 from ray_tpu.parallel.moe import (ACTIVATIONS, GMM_TILE, ROUTING,
                                   balance_bias, dropless_moe, static_rows)
@@ -1333,14 +1346,36 @@ def _rope_for(t: int, cfg: DecoderConfig):
             for kind in rates}
 
 
+def _kept_across_remat(cfg: DecoderConfig, attention: str) -> tuple:
+    """The names a rematerialised block with this mixer keeps: the
+    gradient to q_I, k_I and w that `index_kl`'s one pass made beside
+    the loss, where the block has an indexer's loss, so that the
+    recomputed copy of the pass is dead code; else none. The
+    attention's own two names are not among them: `flash_fwd` runs in
+    both passes."""
+    if cfg.index_topk and attention == "full":
+        return KL_SAVED_ACROSS_REMAT
+    return ()
+
+
+def _rematerialised(fn, cfg: DecoderConfig, attention: str):
+    """`fn` under `jax.checkpoint`; with no name to keep no policy is
+    passed, and the block traces as it always has."""
+    names = _kept_across_remat(cfg, attention)
+    if not names:
+        return jax.checkpoint(fn)
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
 def _block(cfg: DecoderConfig, attention: str, mlp: str):
     fn = functools.partial(_layer, cfg=cfg, attention=attention, mlp=mlp)
     if cfg.remat != "parts" or "none" in (attention, mlp):
-        return jax.checkpoint(fn) if cfg.remat else fn
+        return _rematerialised(fn, cfg, attention) if cfg.remat else fn
     # the mixer and the MLP each under a checkpoint of its own: one
     # part's residuals are gone before the other's backward makes its own
-    mixer = jax.checkpoint(functools.partial(
-        _layer, cfg=cfg, attention=attention, mlp="none"))
+    mixer = _rematerialised(functools.partial(
+        _layer, cfg=cfg, attention=attention, mlp="none"), cfg, attention)
     rest = jax.checkpoint(functools.partial(
         _layer, cfg=cfg, attention="none", mlp=mlp))
 
@@ -1894,7 +1929,10 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
     `cfg.index_topk` `index_topk`, `index_rows` (the query rows a step
     selects keys for: batch x T x `full` layers, 0 where T <= topk) and
     `index_tile` (`"256x512"`: the forward tile whose counts the
-    selection hands the kernels); nothing otherwise."""
+    selection hands the kernels) and `index_kl_runs` (the passes of
+    `index_kl` a step: one a `full` layer, two where a rematerialised
+    block does not keep what the pass made; the backward rule runs
+    none); nothing otherwise."""
     b, t = batch_shape
     facts = {}
     unmasked = walked = 0
@@ -1942,7 +1980,11 @@ def step_facts(cfg: DecoderConfig, batch_shape) -> dict:
             index_rows=b * t * _index_layers(cfg) if t > cfg.index_topk
             else 0,
             index_tile=f"{min(cfg.attn_block_q, t)}x"
-                       f"{min(cfg.attn_block_k, t)}")
+                       f"{min(cfg.attn_block_k, t)}",
+            # as `_block` builds the blocks: a second pass a layer where
+            # it is rematerialised and keeps nothing of the first
+            index_kl_runs=_index_layers(cfg) * (1 + (
+                bool(cfg.remat) and not _kept_across_remat(cfg, "full"))))
     if cfg.diffusion_block:
         visited, plane = diffusion_tiles(
             2 * t, cfg.diffusion_block, cfg.attn_block_q, cfg.attn_block_k)

@@ -38,10 +38,14 @@ summed over the rows. Its gradient reaches `q_I`, `k_I`, `w` alone:
 `dI[t, s] = softmax_S(I)[t, s] - p[t, s]` on `S_t`, through the ReLU.
 Value and gradient are made in ONE pass, the gradient kept as the
 custom derivative's residual: the backward pass multiplies it by the
-cotangent and walks nothing (under a rematerialised block the pass runs
-twice, the forward's and the recomputed one: both make the gradient,
-the first for nothing). Under a selection the pass is the Mosaic
-kernel `index_kl`, a (query tile, key tile) a grid step, the keys along
+cotangent and walks nothing. Under a rematerialised block the pass
+would run twice, the forward's and the recomputed one, and each throw
+half of what it made away: the forward rule NAMES the three gradient
+arrays (`KL_SAVED_ACROSS_REMAT`), a block that keeps those names across
+its remat (`models/decoder.py::_block`: 35 MiB a layer at 16 384
+tokens) runs the pass once, and the recomputed copy is dead code.
+Under a selection the pass is the Mosaic kernel `index_kl`,
+a (query tile, key tile) a grid step, the keys along
 a tile's rows as `flash_bwd_fused` has them: it rebuilds p from the
 attention's saved log-sum-exp (`exp(s_h - lse_h)` summed over the heads
 in VMEM), rebuilds the tile of I from `q_I`, `k_I`, `w` and the row's
@@ -66,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -361,6 +366,14 @@ def index_kl_xla(q_i, k_i, w, q, k, plane, scale: float,
 
 KL_TILE = 512                       # `index_kl`'s tile, both sides
 _KL_VMEM_LIMIT = 64 * 1024 * 1024   # every head's query tile, twice; dk_I
+# The names `index_kl`'s forward rule gives the gradient to q_I, k_I and
+# w, as the backward rule reads them: all its pass made but the loss,
+# which is the call's own value and no residual. A block rematerialised
+# under `save_only_these_names(*KL_SAVED_ACROSS_REMAT)` keeps the three
+# (`models/decoder.py::_block` does), its recomputed copy of the pass
+# has no reader left and the pass runs once a step; with no policy
+# asking for them the names lower to nothing.
+KL_SAVED_ACROSS_REMAT = ("index_kl_dq", "index_kl_dk", "index_kl_dw")
 
 
 def _index_kl_kernel(counts_ref, q_ref, lse_ref, k_ref, qi_ref, w_ref, ki_ref,
@@ -536,13 +549,17 @@ def index_kl(q_i, k_i, w, q, k, plane, lse, lse_i, scale: float,
     the attention's row log-sum-exp over it (`flash_attention`'s second
     result) and `lse_i` [B, T] float32 that of I (`index_select`'s) — or
     all three None where every causal key is selected. The gradient
-    reaches q_i, k_i and w alone."""
+    reaches q_i, k_i and w alone; the forward rule makes it beside the
+    loss and names its three arrays `KL_SAVED_ACROSS_REMAT`, for a
+    `jax.checkpoint` around the call to keep (else the pass runs again
+    in the recomputed copy)."""
     return _kl_pass(q_i, k_i, w, q, k, plane, lse, lse_i, scale, strip)[0]
 
 
 def _index_kl_fwd(q_i, k_i, w, q, k, plane, lse, lse_i, scale, strip):
     total, grads = _kl_pass(q_i, k_i, w, q, k, plane, lse, lse_i, scale,
                             strip)
+    grads = tuple(map(checkpoint_name, grads, KL_SAVED_ACROSS_REMAT))
     return total, (grads, q, k, plane, lse, lse_i)
 
 

@@ -12,6 +12,8 @@ the smallest mutation of `test_mutation_is_told_apart` moves."""
 
 import dataclasses
 import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -298,6 +300,99 @@ def test_stateful_loss_counts_the_selection():
     assert facts["index_topk"] == TOPK and facts["index_tile"] == "16x32" \
         and facts["index_rows"] == layers * b * LENGTH
     assert decoder.step_facts(cfg, (2, TOPK))["index_rows"] == 0
+
+
+KERNELS = ("index_kl", "index_scores", "flash_fwd", "flash_bwd_fused")
+
+
+def _kernels_a_layer(cfg):
+    """The kernels in the text of the step's gradient: the two layers
+    are one scanned period, so a layer's, forward and backward."""
+    _, params, state, tokens = _setup()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: decoder.stateful_loss(p, state, tokens, cfg)[0]))(params))
+    return {name: len(re.findall(rf"name={name}\n", text))
+            for name in KERNELS}
+
+
+def _keep_nothing(monkeypatch):
+    """The block as the parent commit (397ba61) checkpointed it: no
+    policy whatever it holds."""
+    monkeypatch.setattr(decoder, "_kept_across_remat", lambda cfg, a: ())
+
+
+@pytest.mark.parametrize("remat", [True, "parts", False])
+def test_the_indexers_loss_runs_once_a_layer(remat):
+    """A rematerialised block keeps what `index_kl`'s pass made, so its
+    recomputed copy holds the attention and the index scores again but
+    not the loss's kernel; `step_facts` says so from the same rule."""
+    cfg, twice = dataclasses.replace(_cfg(), remat=remat), 1 + bool(remat)
+    assert _kernels_a_layer(cfg) == {
+        "index_kl": 1, "index_scores": twice, "flash_fwd": twice,
+        "flash_bwd_fused": 1}
+    assert decoder.step_facts(cfg, (2, LENGTH))["index_kl_runs"] \
+        == cfg.n_layers == 2
+
+
+@pytest.mark.parametrize("remat", [True, "parts"])
+def test_without_the_policy_the_indexers_loss_runs_twice(remat,
+                                                         monkeypatch):
+    _keep_nothing(monkeypatch)
+    cfg = dataclasses.replace(_cfg(), remat=remat)
+    assert _kernels_a_layer(cfg) == {
+        "index_kl": 2, "index_scores": 2, "flash_fwd": 2,
+        "flash_bwd_fused": 1}
+    assert decoder.step_facts(cfg, (2, LENGTH))["index_kl_runs"] == 4
+
+
+def _mixer_part(h, p, rope):
+    """Value and gradient of a layer's mixer part as `_block` builds it
+    (the whole of what the policy touches: under "parts" it is this
+    function that is checkpointed), the indexer's loss among the terms."""
+    def value(h, p):
+        out, found = decoder._block(_cfg(), "full", "none")(h, p, rope)
+        return out.sum() + 0.5 * found["index_kl_sum"], found
+
+    return jax.jit(jax.value_and_grad(value, (0, 1), has_aux=True))(h, p)
+
+
+def test_what_is_kept_changes_no_bit(monkeypatch):
+    """Value, `index_kl_sum`, the counts and every gradient leaf of the
+    block that keeps the three arrays equal the unpoliced block's."""
+    cfg, params, _, tokens = _setup()
+    assert cfg.remat is True
+    row = {name: x[0] for name, x in params["layers"].items()}
+    given = (params["embed"][tokens[:1]], row,
+             decoder._rope_for(LENGTH, cfg))
+    kept = _mixer_part(*given)
+    _keep_nothing(monkeypatch)
+    plain = _mixer_part(*given)
+    assert float(kept[0][1]["index_kl_sum"]) > 0
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept),
+                            jax.tree.leaves(plain)):
+        assert a.tobytes() == b.tobytes(), jax.tree_util.keystr(path)
+    indexer = {name: float(jnp.abs(kept[1][1][name]).max())
+               for name in INDEXER}
+    assert min(indexer.values()) > 0      # the loss's gradient arrived
+
+
+def test_a_block_without_an_indexer_gets_no_policy(monkeypatch):
+    """No name to keep, no policy: `jax.checkpoint` is called as the
+    parent called it, for every kind but an indexed `full` one."""
+    calls = []
+    monkeypatch.setattr(
+        decoder.jax, "checkpoint",
+        lambda fn, **kw: calls.append(kw) or fn)
+    plain = dataclasses.replace(_cfg(), index_topk=0, index_heads=0,
+                                index_dim=0)
+    for cfg, mixer in ((plain, "full"), (_cfg(), "window"),
+                       (dataclasses.replace(plain, remat="parts"), "full")):
+        assert decoder._kept_across_remat(cfg, mixer) == ()
+        decoder._block(cfg, mixer, "experts")
+    assert calls == [{}, {}, {}, {}]    # "parts": the mixer, then the rest
+    decoder._block(dataclasses.replace(_cfg(), remat="parts"), "full",
+                   "experts")
+    assert [sorted(kw) for kw in calls[4:]] == [["policy"], []]
 
 
 def test_what_the_indexer_is_not_built_for_is_refused():

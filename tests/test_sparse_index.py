@@ -142,6 +142,34 @@ def test_index_kl_and_its_gradient(form, monkeypatch):
     assert not np.asarray(dq).any() and not np.asarray(dk).any()
 
 
+def test_the_forward_rule_names_the_gradient_its_pass_made(capsys):
+    """Under a gradient the three gradient arrays carry
+    `KL_SAVED_ACROSS_REMAT`'s names, in the shapes the backward rule
+    reads them: a checkpoint that keeps those names keeps exactly the
+    three beside what it was given, and the bare call (no gradient)
+    names nothing."""
+    q_i, k_i, w = _indexer()
+    q, k, _ = _qkv()
+
+    def loss(*x):   # every causal key: the plain form, cheap to trace
+        return sparse_index.index_kl(*x, q, k, None, None, None, 0.25, 32)
+
+    assert " name[" not in str(jax.make_jaxpr(loss)(q_i, k_i, w))
+    shapes = {"index_kl_dq": f"f32[{B},{T},{HEADS},{DIM}]",
+              "index_kl_dk": f"f32[{B},{T},{DIM}]",
+              "index_kl_dw": f"f32[{B},{T},{HEADS}]"}
+    assert tuple(shapes) == sparse_index.KL_SAVED_ACROSS_REMAT
+    jax.ad_checkpoint.print_saved_residuals(jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            *sparse_index.KL_SAVED_ACROSS_REMAT)), q_i, k_i, w)
+    saved = [line for line in capsys.readouterr().out.splitlines()
+             if "from the argument" not in line
+             and "from a constant" not in line]   # (q and k, closed over)
+    assert [line.split(" named ")[0] for line in saved] \
+        == list(shapes.values())
+    assert [line.split("'")[1] for line in saved] == list(shapes)
+
+
 @pytest.mark.parametrize("h_kv", [2, 4], ids=["grouped", "equal-heads"])
 def test_flash_attention_under_a_selection(h_kv):
     """Values and gradients against the dense form; batch row 1's plane
